@@ -21,7 +21,7 @@ runs best:
 The reference's *bbox-sampled* distorted crop resizes a different-shaped
 window per example — per-example dynamic shapes, which XLA cannot tile onto
 the MXU. The fixed-record random-crop + flip here is the classic alternative
-("VGG preprocessing" in the reference's own taxonomy,
+("VGG preprocessing" in the reference's own naming,
 ``imagenet_preprocessing.py:26-31``) and keeps every shape static; eval uses
 the standard center crop, no flip.
 """
@@ -213,7 +213,7 @@ class DeviceDatasetCache:
 
     Use :class:`AugmentingBatcher` + ``device_prefetch`` instead when the
     host->device link is fast enough to stream full batches (a real TPU VM's
-    PCIe); this class exists for weak links (remote storage, tunneled chips).
+    PCIe); this class exists for weak links (remote storage).
     """
 
     #: Default HBM budget for the record pool when ``pool_rows`` is unset —

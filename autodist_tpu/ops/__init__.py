@@ -6,10 +6,9 @@ from autodist_tpu.ops.fused_xent import fused_softmax_xent, matmul_logsumexp
 
 
 def mosaic_compiles() -> bool:
-    """True when pallas kernels compile natively on this backend (TPU-class
-    platforms). The single backend gate for callers choosing kernel-backed
-    configs — elsewhere pallas falls back to interpret mode, orders of
-    magnitude slower."""
+    """True when pallas kernels compile natively on this backend (TPU). The
+    single backend gate for callers choosing kernel-backed configs — on the
+    CPU backend pallas runs in interpret mode, orders of magnitude slower."""
     from autodist_tpu.ops.flash_attention import _use_interpret
     return not _use_interpret()
 

@@ -66,17 +66,13 @@ def maybe_initialize_multihost(cluster=None) -> bool:
 
 def _enable_cpu_collectives(jax) -> None:
     """Multiprocess SPMD on the CPU backend needs a cross-process collectives
-    implementation, and jax's default is ``none`` — every cross-process program
-    would fail with "Multiprocess computations aren't implemented on the CPU
-    backend". Select gloo (bundled with jaxlib) before the backend
-    initializes; a user's explicit choice (mpi, or an older jax without the
-    flag) is left alone."""
-    try:
-        if jax.config.read("jax_cpu_collectives_implementation") == "none":
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-            logging.info("CPU backend: enabled gloo cross-process collectives")
-    except AttributeError:  # jax build without the flag: nothing to select
-        pass
+    implementation; with ``none`` every cross-process program fails with
+    "Multiprocess computations aren't implemented on the CPU backend". Select
+    gloo (bundled with jaxlib) before the backend initializes; a user's
+    explicit choice (mpi) is left alone."""
+    if jax.config.jax_cpu_collectives_implementation == "none":
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
+        logging.info("CPU backend: enabled gloo cross-process collectives")
 
 
 def _externally_initialized() -> bool:

@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 
 from autodist_tpu import const
+from autodist_tpu.parallel import mesh as mesh_lib
 
 PyTree = object
 
@@ -450,23 +451,12 @@ def _pipe_mesh_and_specs(fn_name: str, mesh, axis: str, n_stages: int,
 
 
 def _ambient_mesh():
-    """The mesh in effect at trace time: the abstract-mesh context if set, else the
-    ``with mesh:`` physical-mesh context the runner steps under."""
-    abstract = jax.sharding.get_abstract_mesh()
-    if abstract is not None and not abstract.empty:
-        return abstract
-    try:
-        # No public accessor for the `with mesh:` context; degrade to the
-        # explicit-mesh error if a jax upgrade moves this.
-        from jax._src import mesh as mesh_lib
-        physical = mesh_lib.thread_resources.env.physical_mesh
-    except (ImportError, AttributeError):
-        physical = None
-    if physical is not None and not physical.empty:
-        return physical
-    raise RuntimeError(
-        "pipelined() needs a mesh: pass one explicitly or call inside a "
-        "`with mesh:` block (DistributedRunner.run steps under one)")
+    mesh = mesh_lib.ambient_mesh()
+    if mesh is None:
+        raise RuntimeError(
+            "pipelined() needs a mesh: pass one explicitly or call inside a "
+            "`with mesh:` block (DistributedRunner.run steps under one)")
+    return mesh
 
 
 def pipelined(stage_fn: Callable, n_stages: int, axis: str = const.MESH_AXIS_PIPE,

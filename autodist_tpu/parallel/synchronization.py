@@ -213,12 +213,6 @@ def make_grad_fn(sharding_plan: ShardingPlan, model_spec: ModelSpec, mesh: Mesh,
             aux = ()
         return grads, loss, aux, ef_state
 
-    # Which lowering a grad fn took, as an attribute: callers (and the test
-    # suite's `requires_shard_map` guard — the explicit path is the one thing
-    # here that needs `jax.shard_map`, absent from some jax builds) can ask
-    # without re-deriving the decision.
-    implicit.uses_shard_map = False
-
     if not use_explicit:
         return implicit
 
@@ -358,7 +352,6 @@ def make_grad_fn(sharding_plan: ShardingPlan, model_spec: ModelSpec, mesh: Mesh,
         )(params, batch, ef_state)
         return out
 
-    explicit.uses_shard_map = True
     return explicit
 
 

@@ -31,7 +31,7 @@ from autodist_tpu.model_spec import ModelSpec
 from autodist_tpu.parallel import synchronization
 from autodist_tpu.parallel.mesh import build_mesh
 from autodist_tpu.parallel.plan import ShardingPlan
-from autodist_tpu.utils import logging
+from autodist_tpu.utils import compile_cache, logging
 
 PyTree = Any
 
@@ -225,6 +225,9 @@ class DistributedRunner:
         self.plan = plan if plan is not None \
             else ShardingPlan.from_strategy(compiled_strategy, model_spec)
         self.mesh = mesh if mesh is not None else self._mesh_from_plan()
+        # The mesh exists, so the backend is up: place the persistent
+        # compile cache before this runner's first compile (no-op on CPU).
+        compile_cache.configure()
         if self.zero and not self.plan.is_async and not self.plan.zero:
             # Synchronous regimes take the SPMD lowering; the async/PS regime
             # keeps its plan and shards the server-side apply instead
@@ -715,6 +718,15 @@ class DistributedRunner:
                          f"{getattr(v, 'dtype', type(v).__name__)}"
                          f"{getattr(v, 'shape', ())}")
         return "|".join(parts)
+
+    def compiled_step(self, state: TrainState, sharded_batch: PyTree):
+        """The compiled plain-step executable at these args (``as_text()``,
+        ``cost_analysis()``, ``memory_analysis()``). After the step has run
+        once at this signature the re-lowering hits jit's executable cache;
+        before, this is the compile."""
+        fn = self._step_fns.get(None) or self._build_step(None)
+        with self.mesh:
+            return fn.lower(state, sharded_batch).compile()
 
     def _extract_program_cost(self, jitted, args, steps: int = 1):
         """XLA's static cost analysis for ``jitted`` at ``args`` as a plain

@@ -97,7 +97,7 @@ class PeakSpec:
 
     flops_per_s: Optional[float]
     membw_bytes_per_s: Optional[float]
-    source: str   # "env" | "device:<kind>" | "unknown"
+    source: str   # "env" | "device:<kind>" | "unknown" | "unknown:<kind>"
 
     def to_dict(self) -> Dict[str, Any]:
         return {"flops_per_s": self.flops_per_s,
@@ -130,8 +130,10 @@ def peak_spec(device=None) -> PeakSpec:
 
     ``AUTODIST_PEAK_FLOPS`` / ``AUTODIST_PEAK_MEMBW`` override either side
     (new hardware, calibrated peaks); otherwise both come from the device
-    kind's spec-sheet tables. CPU (and unknown kinds) yield ``None`` sides —
-    MFU against a meaningless peak would be noise."""
+    kind's spec-sheet tables. CPU yields ``None`` sides (MFU against a
+    meaningless peak would be noise) under source ``"unknown"``; an
+    accelerator kind missing from a table yields ``"unknown:<kind>"``, never
+    ``"device:<kind>"`` with an empty peak."""
     flops_env = str(const.ENV.AUTODIST_PEAK_FLOPS.val)
     membw_env = str(const.ENV.AUTODIST_PEAK_MEMBW.val)
     flops = _parse_peak(flops_env, "AUTODIST_PEAK_FLOPS")
@@ -161,8 +163,12 @@ def peak_spec(device=None) -> PeakSpec:
             break
     if flops_env or membw_env:
         source = "env"
-    elif kind:
+    elif kind and flops is not None and membw is not None:
         source = f"device:{kind}"
+    elif kind:
+        # An accelerator the tables do not know: say so in the source, so a
+        # measurement path can refuse it instead of printing "mfu": null.
+        source = f"unknown:{kind}"
     else:
         source = "unknown"
     return PeakSpec(flops, membw, source)

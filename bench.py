@@ -1,10 +1,14 @@
-"""Flagship benchmark: Transformer LM training throughput on the active platform.
+"""Flagship benchmark: Transformer LM training throughput on the TPU.
 
 Reproduces the reference's own measurement procedure (BASELINE.md): the lm1b
 words/sec hook (``examples/lm1b/lm1b_train.py:64-74`` printed wps per 100 steps)
 re-targeted at the flagship Transformer LM. Prints ONE JSON line:
 
-    {"metric": ..., "value": N, "unit": "tokens/s", "vs_baseline": N}
+    {"metric": ..., "value": N, "unit": "tokens/s", "device": {...}, "vs_baseline": N}
+
+Without a TPU the flagship mode is an error (exit 1, ``"ok": false``): there is
+no CPU shape fallback. ``chip_smoke.py`` builds the same trainer, and its
+``run(TINY, require_tpu=False)`` is the CPU rehearsal.
 
 The reference publishes no numeric table (figures only), so ``vs_baseline``
 normalizes against the BASELINE.md procedural target: V100-class per-device lm1b
@@ -27,26 +31,6 @@ def _baseline_path():
     import os
     return os.path.join(os.path.dirname(os.path.abspath(__file__)),
                         "PERF_BASELINE.json")
-
-
-def _append_trajectory(row: dict):
-    """Append one perf-history row to BENCH_TRAJECTORY.jsonl (repo root).
-
-    The BENCH_rNN.json artifacts are per-round snapshots that OVERWRITE each
-    other's story; this file is the append-only trajectory — one JSON line
-    per bench invocation (wall time, metric, rate, MFU, attribution shares
-    when the run measured them) so regressions are visible as a series, not
-    a pair. A write failure never breaks the bench (read-only checkouts run
-    it too)."""
-    import os
-    row = dict(row, t=time.strftime("%Y-%m-%dT%H:%M:%S"))
-    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        "BENCH_TRAJECTORY.jsonl")
-    try:
-        with open(path, "a") as f:
-            f.write(json.dumps(row, default=str) + "\n")
-    except OSError:
-        pass
 
 
 def legacy_wire_send(sock, obj):
@@ -514,7 +498,6 @@ def attr_overhead(steps: int = 120, log_every: int = 40, rounds: int = 3):
         rec = profiling.observe_period()
         observe_ms = min(observe_ms, (time.perf_counter() - t0) * 1e3)
     shares = rec["shares"] if rec else None
-    mfu = rec.get("mfu") if rec else None
     profile_path = profiling.maybe_write_profile()
 
     # Direct per-dispatch cost of the signature count.
@@ -570,10 +553,6 @@ def attr_overhead(steps: int = 120, log_every: int = 40, rounds: int = 3):
     except (OSError, KeyError, ValueError, TypeError, ZeroDivisionError):
         pass  # a missing/mangled snapshot must not break the bench
     print(json.dumps(result))
-    _append_trajectory({"metric": result["metric"],
-                        "steps_per_s": result["rows"]["disabled"],
-                        "unit": "steps/s", "mfu": mfu, "attr": shares,
-                        "overhead_pct": result["overhead_pct"]})
     return result
 
 
@@ -702,12 +681,6 @@ def mem_overhead(steps: int = 120, log_every: int = 40, rounds: int = 3):
     except (OSError, KeyError, ValueError, TypeError, ZeroDivisionError):
         pass  # a missing/mangled snapshot must not break the bench
     print(json.dumps(result))
-    _append_trajectory({"metric": result["metric"],
-                        "steps_per_s": result["rows"]["disabled"],
-                        "unit": "steps/s",
-                        "tag_ms": result["tag_ms"],
-                        "sample_ms": result["sample_ms"],
-                        "overhead_pct": result["overhead_pct"]})
     return result
 
 
@@ -844,12 +817,6 @@ def metrics_overhead(steps: int = 120, log_every: int = 40, rounds: int = 3):
     except (OSError, KeyError, ValueError, TypeError, ZeroDivisionError):
         pass  # a missing/mangled snapshot must not break the bench
     print(json.dumps(result))
-    _append_trajectory({"metric": result["metric"],
-                        "steps_per_s": result["rows"]["disabled"],
-                        "unit": "steps/s",
-                        "sample_ms": result["sample_ms"],
-                        "render_ms": result["render_ms"],
-                        "overhead_pct": result["overhead_pct"]})
     return result
 
 
@@ -1608,11 +1575,6 @@ def serve_fleet_bench(requests: int = 24, fleet_requests: int = 16,
     except (OSError, KeyError, ValueError, TypeError, ZeroDivisionError):
         pass  # a missing/mangled snapshot must not break the bench
     print(json.dumps(result))
-    _append_trajectory({"metric": result["metric"],
-                        "concurrency_ratio": concurrency_ratio,
-                        "fleet_rps_ratio": fleet_ratio,
-                        "fleet2_rps": fleet_rps[2],
-                        "kill_respawns": counts["respawns"]})
     return result
 
 
@@ -1810,12 +1772,6 @@ def autotune_bench(rounds: int = 3, steps: int = 48):
     except (OSError, KeyError, ValueError, TypeError, ZeroDivisionError):
         pass  # a missing/mangled snapshot must not break the bench
     print(json.dumps(result))
-    _append_trajectory({"metric": result["metric"],
-                        "steps_per_s": result["rows"]["tuned"],
-                        "unit": "steps/s", "plan": plan.name,
-                        "tuned_vs_default": result["tuned_vs_default"],
-                        "search_s": result["search_s"],
-                        "probed": plan.probed})
     return result
 
 
@@ -1969,12 +1925,6 @@ def data_plane_bench(steps: int = 96, log_every: int = 32, rounds: int = 3,
     except (OSError, KeyError, ValueError, TypeError, ZeroDivisionError):
         pass  # a missing/mangled snapshot must not break the bench
     print(json.dumps(result))
-    _append_trajectory({"metric": result["metric"],
-                        "steps_per_s": result["rows"]["prefetched"],
-                        "unit": "steps/s",
-                        "prefetch_vs_sync": result["prefetch_vs_sync"],
-                        "data_wait_share": result["data_wait_share"],
-                        "producer_wait_s": result["producer_wait_s"]})
     return result
 
 
@@ -2159,12 +2109,6 @@ def selfheal_bench(steps_per_worker: int = 60, crash_at: int = 25,
     except (OSError, KeyError, ValueError, TypeError, ZeroDivisionError):
         pass  # a missing/mangled snapshot must not break the bench
     print(json.dumps(result))
-    _append_trajectory({"metric": result["metric"],
-                        "steps_per_s": result["rows"]["post_eviction"],
-                        "unit": "steps/s",
-                        "post_vs_free": result["post_vs_free"],
-                        "evicted": rec["evicted"],
-                        "rejoined": rec["rejoined"]})
     return result
 
 
@@ -2290,11 +2234,6 @@ def wire_compress_bench(steps: int = 30, rounds: int = 3, dim: int = 512,
     except (OSError, KeyError, ValueError, TypeError, ZeroDivisionError):
         pass  # a missing/mangled snapshot must not break the bench
     print(json.dumps(result))
-    _append_trajectory({"metric": result["metric"],
-                        "steps_per_s": result["rows"]["int8_ef"],
-                        "unit": "steps/s",
-                        "compressed_vs_exact": result["compressed_vs_exact"],
-                        "bytes_saved": result["bytes_saved"]})
     return result
 
 
@@ -2486,55 +2425,49 @@ def main(argv=None):
         unroll_sweep(factors)
         return
 
+    import sys
+
     import jax
-    import jax.numpy as jnp
-    import optax
 
-    from autodist_tpu import AutoDist
-    from autodist_tpu.models import transformer_lm
-    from autodist_tpu.ops import mosaic_compiles
-    from autodist_tpu.strategy import AllReduce
+    import chip_smoke
+    from autodist_tpu.telemetry import profiling
+    from autodist_tpu.utils import compile_cache
+    from autodist_tpu.utils import flops as flops_util
 
-    platform = jax.devices()[0].platform
-    n_dev = len(jax.devices())
+    devices = jax.devices()
+    platform, n_dev = devices[0].platform, len(devices)
+    peaks = profiling.peak_spec(devices[0])
+    if platform != "tpu" or peaks.flops_per_s is None:
+        # No CPU fallback: a rate from another machine under this metric's
+        # name is worse than no rate. `chip_smoke.run(chip_smoke.TINY,
+        # require_tpu=False)` is the CPU rehearsal of the same path.
+        print(json.dumps({
+            "ok": False,
+            "error": f"the flagship benchmark needs a TPU with known peaks; "
+                     f"jax.devices()[0] is platform {platform!r}, device_kind "
+                     f"{devices[0].device_kind!r} (peaks: {peaks.source})"}))
+        sys.exit(1)
+    compile_cache.configure()
 
-    # lm1b-class flagship config; bf16 activations on accelerators.
-    on_accel = platform != "cpu"
-    cfg = transformer_lm.TransformerLMConfig(
-        vocab_size=32_000, d_model=512, n_heads=8, n_layers=6, d_ff=2048,
-        max_len=512, dtype=jnp.bfloat16 if on_accel else jnp.float32,
-        tied_output=False,
-        # Pallas fused head+loss (logits never materialized): measured faster
-        # than the XLA head at equal batch (410k vs 398k tokens/s at 256) AND
-        # it unlocks batch 384, which OOMs with materialized logits. Gated on
-        # the platforms whose Mosaic backend compiles the kernels — elsewhere
-        # (GPU) pallas would run in interpret mode and crater the bench.
-        fused_head=mosaic_compiles())
-    # Swept on a v5e chip: fused head 384/device = ~426k tokens/s vs 410k at
-    # 256 and 421k at 512; XLA head topped out at ~404k (bs 256; 384 OOMs);
-    # seq512 loses (346k at 128). Gradient accumulation on top (same 384-seq
-    # micro-batch, Adam applied once per ACCUM micro-batches) amortizes the
-    # optimizer + dispatch: 433.6k@2, 436.3k@3, 441.2k@8, plateau ~442k@16 —
-    # accum 8 (global batch 3072 seqs = 786k tokens, a standard large-batch
-    # LM config) ships as the flagship.
-    seq_len = 256 if on_accel else 64
-    accum = 8 if on_accel else 1
-    batch_size = (384 if on_accel else 8) * n_dev * accum
-
-    model, params = transformer_lm.init_params(cfg)
-    loss_fn = transformer_lm.make_loss_fn(model)
-    batch = transformer_lm.synthetic_batch(cfg, batch_size=batch_size, seq_len=seq_len)
-
-    ad = AutoDist(strategy_builder=AllReduce())
-    step = ad.function(loss_fn, params, optax.adam(1e-3), example_batch=batch,
-                       accumulation_steps=accum)
+    # lm1b-class flagship config (chip_smoke.FLAGSHIP), bf16 activations,
+    # Pallas fused head+loss: measured faster than the XLA head at equal
+    # batch (410k vs 398k tokens/s at 256) AND it unlocks batch 384, which
+    # OOMs with materialized logits. Swept on a v5e chip in round 5: fused
+    # head 384/device = ~426k tokens/s vs 410k at 256 and 421k at 512; XLA
+    # head topped out at ~404k (bs 256; 384 OOMs); seq512 loses (346k at 128).
+    # Gradient accumulation on top (same 384-seq micro-batch, Adam applied
+    # once per ACCUM micro-batches) amortizes the optimizer + dispatch:
+    # 433.6k@2, 436.3k@3, 441.2k@8, plateau ~442k@16 — accum 8 (global batch
+    # 3072 seqs = 786k tokens, a standard large-batch LM config) ships.
+    size = chip_smoke.FLAGSHIP
+    seq_len, accum = size.seq_len, size.accum
+    batch_size = size.micro_batch * n_dev * accum
+    cfg, _, batch, step = chip_smoke.build_flagship(size, batch_size, accum)
     # Device-resident batch: measure the chip, not the host link.
     batch = step.runner.shard_batch(batch)
 
-    # Warmup (compile + first dispatch), then timed steps. The final host read is
-    # the sync barrier: the last loss depends on the whole state chain, and a
-    # device->host transfer is a reliable completion fence even on experimental
-    # platforms where block_until_ready has proven optimistic.
+    # Warmup (compile + first dispatch), then timed steps. The final host read
+    # is the sync barrier: the last loss depends on the whole state chain.
     for _ in range(2):
         loss = step(batch)
     _ = float(loss)
@@ -2548,7 +2481,7 @@ def main(argv=None):
             for _ in range(args.profile):
                 loss = step(batch)
             _ = float(loss)  # completion fence inside the traced window
-    n_steps = 20 if on_accel else 3
+    n_steps = 20
     t0 = time.perf_counter()
     for _ in range(n_steps):
         loss = step(batch)
@@ -2561,10 +2494,9 @@ def main(argv=None):
 
     # MFU from the analytic per-token count (the fused pallas head is invisible
     # to XLA's flop analysis, so the compiled-module count would under-report).
-    from autodist_tpu.utils import flops as flops_util
     flops_per_token = flops_util.transformer_flops_per_token(
         cfg.d_model, cfg.n_layers, cfg.d_ff, cfg.vocab_size, seq_len)
-    mfu = flops_util.mfu(flops_per_token * tokens_per_sec / n_dev)
+    mfu = flops_per_token * per_device / peaks.flops_per_s
 
     result = {
         "metric": f"transformer_lm_train_tokens_per_sec ({platform} x{n_dev}, "
@@ -2572,65 +2504,51 @@ def main(argv=None):
                   f"bs{batch_size}={batch_size // accum}x{accum}accum)",
         "value": round(tokens_per_sec, 1),
         "unit": "tokens/s",
+        "device": {"platform": platform, "kind": devices[0].device_kind,
+                   "count": n_dev},
         "vs_baseline": round(per_device / BASELINE_TOKENS_PER_SEC_PER_DEVICE, 3),
         "flops_per_token": round(flops_per_token),
-        "mfu": round(mfu, 4) if mfu is not None else None,
+        "mfu": round(mfu, 4),
     }
     if trace_dir is not None:
         result["profile_trace"] = trace_dir
 
-    # Attribution postscript — AFTER the timed loop, so the trajectory row
-    # can say where the step's wall time goes without taxing the reported
-    # rate: a short profiled window (3 steps + one observe_period). The
-    # analytic per-token count stands in for XLA's where the fused pallas
-    # head hides flops from cost analysis. Best-effort: a diagnostics
-    # postscript must never fail the flagship measurement.
-    attr = None
+    # Attribution postscript — AFTER the timed loop, so the line can say
+    # where the step's wall time goes without taxing the reported rate: a
+    # short profiled window (3 steps + one observe_period). The analytic
+    # per-token count stands in for XLA's where the fused pallas head hides
+    # flops from cost analysis.
+    from autodist_tpu import telemetry
+    was_on = telemetry.enabled()
+    profiling.enable()
+    profiling.reset()
+    profiling.set_analytic_flops(flops_per_token * tokens_per_step)
+    profiling.observe_period()        # open a clean window
+    for _ in range(3):
+        loss = step(batch)
+    _ = float(loss)
+    rec = profiling.observe_period()
+    result["attr"] = rec["shares"] if rec else None
+    profiling.reset()
+    profiling.disable()
+    if not was_on:
+        telemetry.disable()
+    # Regression annotation vs the recorded best (PERF_BASELINE.json, round-5
+    # per-chip rates): warn on stderr past the threshold. Compared per device
+    # so a multi-chip aggregate can't mask a per-chip regression.
     try:
-        from autodist_tpu import telemetry
-        from autodist_tpu.telemetry import profiling
-        was_on = telemetry.enabled()
-        profiling.enable()
-        profiling.reset()
-        profiling.set_analytic_flops(flops_per_token * tokens_per_step)
-        profiling.observe_period()        # open a clean window
-        for _ in range(3):
-            loss = step(batch)
-        _ = float(loss)
-        rec = profiling.observe_period()
-        attr = rec["shares"] if rec else None
-        profiling.reset()
-        profiling.disable()
-        if not was_on:
-            telemetry.disable()
-    except Exception:  # noqa: BLE001
-        pass
-    _append_trajectory({"metric": result["metric"], "value": result["value"],
-                        "unit": "tokens/s", "mfu": result["mfu"],
-                        "attr": attr})
-    # Regression gate vs the recorded best (PERF_BASELINE.json): annotate the
-    # JSON line and warn on stderr past the threshold. Round-over-round drift
-    # was previously invisible (428.6k -> 425.8k went unremarked); this line
-    # makes a real 2-3% regression impossible to miss. CPU runs measure a
-    # different machine entirely — the recorded bests are chip rates.
-    if on_accel:
-        import sys
-        base_path = _baseline_path()
-        try:
-            with open(base_path) as f:
-                base = json.load(f)
-            best = base["rows"]["flagship"]["rate"]
-            threshold = base.get("threshold_pct", 2.0)
-            # The snapshot records PER-CHIP rates; compare per-device so a
-            # multi-chip aggregate can't mask a per-chip regression.
-            result["vs_best"] = round(per_device / best, 4)
-            if per_device < best * (1.0 - threshold / 100.0):
-                print(f"WARNING: flagship {per_device:,.0f} tokens/s/chip is "
-                      f"{100 * (1 - per_device / best):.1f}% below the "
-                      f"recorded best {best:,.0f} (threshold {threshold}%) — "
-                      f"see PERF_BASELINE.json", file=sys.stderr)
-        except (OSError, KeyError, ValueError, TypeError):
-            pass  # a missing/mangled snapshot must not break the bench
+        with open(_baseline_path()) as f:
+            base = json.load(f)
+        best = base["rows"]["flagship"]["rate"]
+        threshold = base.get("threshold_pct", 2.0)
+        result["vs_best"] = round(per_device / best, 4)
+        if per_device < best * (1.0 - threshold / 100.0):
+            print(f"WARNING: flagship {per_device:,.0f} tokens/s/chip is "
+                  f"{100 * (1 - per_device / best):.1f}% below the "
+                  f"recorded best {best:,.0f} (threshold {threshold}%) — "
+                  f"see PERF_BASELINE.json", file=sys.stderr)
+    except (OSError, KeyError, ValueError, TypeError):
+        pass  # a missing/mangled snapshot must not break the bench
     print(json.dumps(result))
 
 

@@ -20,7 +20,6 @@ from autodist_tpu.parallel.mesh import build_mesh
 from autodist_tpu.parallel.plan import ShardingPlan
 from autodist_tpu.resource_spec import ResourceSpec
 from autodist_tpu.strategy import AllReduce
-from shardmap_compat import requires_shard_map
 
 BATCH = 16
 SPEC_8 = ResourceSpec("nodes: [{address: localhost, tpus: 8, chief: true}]")
@@ -67,7 +66,6 @@ def _count_all_reduce(text):
     return sum("stablehlo.all_reduce" in l for l in text.splitlines())
 
 
-@requires_shard_map
 def test_group_bucketing_fuses_collectives():
     """chunk_size=4 puts all four 8x4 grads in one group: ONE concatenated
     collective (+1 for the loss) instead of four per-leaf ones."""
@@ -78,7 +76,6 @@ def test_group_bucketing_fuses_collectives():
     assert "tensor<128xbf16>" in fused     # 4 * (8*4) elements, bf16 on the wire
 
 
-@requires_shard_map
 def test_bucketing_is_value_exact():
     """The bf16 cast is elementwise, so bucketed and per-leaf lowerings produce
     identical gradients."""
@@ -88,7 +85,6 @@ def test_bucketing_is_value_exact():
         np.testing.assert_array_equal(np.asarray(g_flat[k]), np.asarray(g_fused[k]))
 
 
-@requires_shard_map
 def test_bucketing_with_error_feedback_value_exact():
     g_flat, _ = _grads_and_lowered(AllReduce(chunk_size=1, compressor="HorovodCompressorEF"))
     g_fused, text = _grads_and_lowered(AllReduce(chunk_size=4, compressor="HorovodCompressorEF"))
@@ -97,7 +93,6 @@ def test_bucketing_with_error_feedback_value_exact():
         np.testing.assert_array_equal(np.asarray(g_flat[k]), np.asarray(g_fused[k]))
 
 
-@requires_shard_map
 def test_dcn_spec_lowers_to_two_phase_reduce():
     """spec=DCN on a {data:2, reduce:4} mesh: the bucketed gradient reduce becomes
     two all-reduce phases (intra-slice then cross-slice); AUTO stays single-phase.

@@ -18,7 +18,6 @@ from autodist_tpu.model_spec import ModelSpec
 from autodist_tpu.proto import strategy_pb2
 from autodist_tpu.resource_spec import ResourceSpec
 from autodist_tpu.strategy import AllReduce, AutoStrategy
-from shardmap_compat import requires_shard_map
 
 AR = strategy_pb2.AllReduceSynchronizer
 
@@ -137,7 +136,6 @@ nodes:
     assert axes.get("data") == 2     # cross-node DCN tier
 
 
-@requires_shard_map
 def test_autostrategy_dcn_lowering_is_hierarchical():
     """End-to-end: the strategy AutoStrategy emits for a 2x4 multi-node spec
     actually lowers to the two-phase reduce (the knob is honored, not inert),

@@ -13,7 +13,6 @@ from autodist_tpu import AutoDist, const
 from autodist_tpu.strategy import AllReduce
 from autodist_tpu.utils.metrics import ThroughputMeter
 from autodist_tpu.utils import tracing
-from shardmap_compat import requires_shard_map
 
 
 def test_throughput_meter_periods_and_average():
@@ -88,7 +87,6 @@ def test_image_classifier_example():
     assert losses[-1] < losses[0]
 
 
-@requires_shard_map
 def test_sentiment_example_routes_embedding_to_ps():
     import examples.sentiment_classifier as sc
     losses = sc.main(steps=12)
@@ -127,14 +125,12 @@ def test_imagenet_benchmark_tiny():
     assert avg is None or avg >= 0
 
 
-@requires_shard_map
 def test_ncf_benchmark_tiny():
     import examples.benchmark.ncf as n
     avg = n.main(["--steps", "3", "--batch_size", "64", "--log_every", "2"])
     assert avg is None or avg >= 0
 
 
-@requires_shard_map
 def test_bert_benchmark_tiny():
     import examples.benchmark.bert as b
     avg = b.main(["--size", "tiny", "--steps", "3", "--batch_size", "8",

@@ -1,0 +1,172 @@
+"""Ask the chip's compiler, without the chip.
+
+Interpret mode runs the Pallas kernels' arithmetic but none of Mosaic's
+checks: tiling alignment, the 16 MiB scoped-VMEM limit, lowering of each op.
+libtpu compiles for a TPU that is described and not attached, so these cases
+compile the main path's kernels at real widths for one chip of a ``v5e:2x2``
+and assert that each lowered to a Mosaic custom call. Nothing executes: a
+pass here is not a chip run. Skipped where the topology cannot be described
+(no libtpu, or another process holds its lock).
+"""
+
+import importlib
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental import topologies
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import SingleDeviceSharding
+
+from autodist_tpu.ops import fused_xent as fx
+
+# ``autodist_tpu.ops.flash_attention`` the attribute is the function.
+fa = importlib.import_module("autodist_tpu.ops.flash_attention")
+
+
+@pytest.fixture(scope="module")
+def topology():
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe is a skip
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+
+
+@pytest.fixture(scope="module")
+def chip(topology):
+    return SingleDeviceSharding(topology.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def compile_not_interpret(monkeypatch):
+    """The backend is the CPU, so the kernels would pick interpret mode:
+    steer them to compile, here and not through an option of the program.
+    A compile for a described chip can be written to the persistent cache
+    but not read back without the chip, so the cache is off around it."""
+    monkeypatch.setattr(fa, "_use_interpret", lambda: False)
+    monkeypatch.setattr(fx, "_use_interpret", lambda: False)
+    was_enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was_enabled)
+    compilation_cache.reset_cache()
+
+
+def _compiled_text(fn, chip, *shapes) -> str:
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+            for shape, dtype in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("length,depth", [(2048, 64), (300, 64), (2048, 128)],
+                         ids=["L2048-D64", "ragged-L300", "D128"])
+def test_flash_attention_fwd_bwd_compiles(chip, length, depth):
+    qkv = ((4, length, 8, depth), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1, 2)), chip,
+                          qkv, qkv, qkv)
+    assert "tpu_custom_call" in text
+
+
+def test_flash_carry_variant_compiles(chip):
+    """The ring-attention local step: (acc, m, l) carry in and out."""
+    b, length, h, d = 1, 4096, 8, 64
+    qkv = ((b, length, h, d), jnp.bfloat16)
+
+    def step(q, k, v, acc, m, l):
+        return fa.flash_attention_with_carry(q, k, v, (acc, m, l), causal=True,
+                                             k_offset=length)
+
+    text = _compiled_text(step, chip, qkv, qkv, qkv,
+                          ((b, h, length, d), jnp.float32),
+                          ((b, h, length), jnp.float32),
+                          ((b, h, length), jnp.float32))
+    assert "tpu_custom_call" in text
+
+
+# Row count 2,048: the tiles, not the rows, decide what the compiler accepts,
+# and N=98,304 (the flagship) takes ten times as long for the same verdict.
+_XENT_ROWS = 2048
+
+
+@pytest.mark.parametrize("d,vocab,layout,table_dtype,shrinks", [
+    (512, 32_000, "dv", jnp.float32, False),     # the flagship head
+    (1024, 32_000, "vd", jnp.float32, True),
+    # The two that libtpu 0.0.34 refused at the default tiles (18.39M and
+    # 16.73M scoped against a 16M limit) until _fit_blocks counted the
+    # in-kernel temporaries.
+    (1024, 32_000, "dv", jnp.bfloat16, True),
+    (1024, 50_257, "vd", jnp.bfloat16, True),
+], ids=["flagship-d512-dv-f32", "d1024-vd-f32", "d1024-dv-bf16",
+        "d1024-vd-bf16-V50257"])
+def test_fused_xent_fwd_bwd_compiles(chip, d, vocab, layout, table_dtype,
+                                     shrinks):
+    table = (vocab, d) if layout == "vd" else (d, vocab)
+    tiles = fx._fit_blocks(d, fx.DEFAULT_N_BLOCK, fx.DEFAULT_V_BLOCK, 2,
+                           jnp.dtype(table_dtype).itemsize, backward=True)
+    assert (tiles != (fx.DEFAULT_N_BLOCK, fx.DEFAULT_V_BLOCK)) == shrinks
+
+    def loss(h, w, targets):
+        return fx.fused_softmax_xent(h, w, targets, w_layout=layout).mean()
+
+    text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1)), chip,
+                          ((_XENT_ROWS, d), jnp.bfloat16), (table, table_dtype),
+                          ((_XENT_ROWS,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+def test_fused_xent_forward_compiles_at_lm1b_vocab(chip):
+    """lm1b's exact 793,471-word vocabulary, softmax_w layout, forward."""
+    def nll(h, w, targets):
+        return fx.fused_softmax_xent(h, w, targets, w_layout="vd")
+
+    text = _compiled_text(nll, chip, ((_XENT_ROWS, 1024), jnp.bfloat16),
+                          ((793_471, 1024), jnp.float32),
+                          ((_XENT_ROWS,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("kernel", ["fused_xent", "flash_attention"])
+def test_kernels_compile_sharded_over_four_chips(topology, kernel):
+    """The compiler cannot partition a Mosaic kernel: under a mesh of several
+    devices the ops run it per device (``parallel.mesh.per_device``), the
+    batch split over the data axes, a model-sharded table gathered and its
+    gradient summed."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from autodist_tpu.parallel.mesh import build_mesh
+    from autodist_tpu.parallel.plan import DP_AXES
+
+    mesh = build_mesh(axes={"model": 2, "data": 2}, devices=topology.devices)
+
+    def struct(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    if kernel == "fused_xent":
+        def loss(h, w, targets):
+            return fx.fused_softmax_xent(h, w, targets).mean()
+
+        fn = jax.value_and_grad(loss, argnums=(0, 1))
+        args = (struct((_XENT_ROWS, 512), jnp.bfloat16, P(DP_AXES, None)),
+                struct((512, 32_000), jnp.float32, P(None, "model")),
+                struct((_XENT_ROWS,), jnp.int32, P(DP_AXES)))
+    else:
+        def loss(q, k, v):
+            return fa.flash_attention(q, k, v).astype(jnp.float32).sum()
+
+        fn = jax.value_and_grad(loss, argnums=(0, 1, 2))
+        args = (struct((4, 2048, 8, 64), jnp.bfloat16, P(DP_AXES)),) * 3
+    with mesh:
+        text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    if kernel == "fused_xent":   # the table's gradient crosses devices
+        assert "all-reduce" in text or "reduce-scatter" in text

@@ -12,7 +12,6 @@ import optax
 import pytest
 
 from autodist_tpu import AutoDist
-from shardmap_compat import requires_shard_map
 from autodist_tpu.strategy import (AllReduce, Parallax, PartitionedAR, PartitionedPS,
                                    PS, PSLoadBalancing, RandomAxisPartitionAR,
                                    UnevenPartitionedPS)
@@ -71,7 +70,6 @@ def test_loss_decreases_over_ten_steps():
     assert losses == sorted(losses, reverse=True)  # monotone for this convex problem
 
 
-@requires_shard_map
 def test_bf16_compressor_approximates_dense_update():
     batch = _data()
     ad = AutoDist(strategy_builder=AllReduce(compressor="HorovodCompressor"))
@@ -85,7 +83,6 @@ def test_bf16_compressor_approximates_dense_update():
     np.testing.assert_allclose(float(got["b"]), want_b, rtol=2e-2)
 
 
-@requires_shard_map
 def test_error_feedback_caught_up_after_many_steps():
     """EF compensates the bf16 rounding over time: parameters track the uncompressed
     run closely (reference compressor.py:120-143 semantics)."""
@@ -111,7 +108,6 @@ def test_linear_regression_example_runs():
     assert losses[-1] < losses[0]
 
 
-@requires_shard_map
 def test_multi_param_model_with_embedding_parallax():
     """Sparse embedding + dense layers under the Parallax hybrid, 2 steps."""
     rng = np.random.RandomState(0)

@@ -14,7 +14,6 @@ import pytest
 
 from autodist_tpu import AutoDist
 from autodist_tpu.strategy import AllReduce, Parallax, PartitionedPS, PS
-from shardmap_compat import requires_shard_map
 
 BATCH = 32
 
@@ -67,7 +66,6 @@ def test_accumulation_with_adam_matches():
                                    rtol=2e-6, atol=2e-6)
 
 
-@requires_shard_map
 def test_sparse_wire_accumulation_matches():
     """Parallax routes the embedding over the sparse wire path inside the scan."""
     rng = np.random.RandomState(3)
@@ -95,7 +93,6 @@ def test_sparse_wire_accumulation_matches():
         np.testing.assert_allclose(acc[k], full[k], rtol=2e-6, atol=2e-6)
 
 
-@requires_shard_map
 def test_compressed_accumulation_converges():
     """EF state threads through the micro scan (not value-exact by design)."""
     ad = AutoDist(strategy_builder=AllReduce(compressor="HorovodCompressorEF"))

@@ -24,7 +24,6 @@ from autodist_tpu import AutoDist
 from autodist_tpu.strategy import (AllReduce, AutoStrategy, Parallax, PartitionedAR,
                                    PartitionedPS, PS, PSLoadBalancing,
                                    RandomAxisPartitionAR, UnevenPartitionedPS)
-from shardmap_compat import skip_unless_shard_map
 
 BATCH = 16
 
@@ -192,7 +191,6 @@ def test_strategy_times_case(builder_cls, case_name, mesh_name):
                                              for k, v in batch.items()}))
     ad = AutoDist(MESHES[mesh_name], strategy_builder=builder_cls())
     step = ad.function(loss, params, optax.adam(3e-2), example_batch=batch)
-    skip_unless_shard_map(step.runner)  # sparse-wire combos need the explicit path
     losses = [float(step(batch)) for _ in range(8)]
     np.testing.assert_allclose(losses[0], expected0, rtol=1e-5, atol=1e-6,
                                err_msg=f"{builder_cls.__name__}/{case_name}/"
@@ -214,7 +212,6 @@ def test_strategy_times_case_with_accumulation(builder_cls, case_name):
     ad = AutoDist(strategy_builder=builder_cls())
     step = ad.function(loss, params, optax.adam(3e-2), example_batch=batch,
                        accumulation_steps=2)
-    skip_unless_shard_map(step.runner)  # sparse-wire combos need the explicit path
     losses = [float(step(batch)) for _ in range(8)]
     assert np.all(np.isfinite(losses)), losses
     assert losses[-1] < losses[0], (builder_cls.__name__, case_name, losses)

@@ -3,7 +3,6 @@ tiny shapes: single-mesh flash/dot path and the sequence-parallel (ring) path.
 The measured ceilings it reproduces on a chip are documented in the README."""
 
 import examples.long_context_lm as lc
-from shardmap_compat import requires_shard_map
 
 
 def test_long_context_example_single_mesh():
@@ -12,7 +11,6 @@ def test_long_context_example_single_mesh():
     assert rate > 0
 
 
-@requires_shard_map
 def test_long_context_example_sequence_parallel():
     rate = lc.main(["--seq_len", "256", "--batch_size", "4", "--steps", "2",
                     "--d_model", "64", "--n_layers", "2", "--vocab", "256",

@@ -7,7 +7,6 @@ from autodist_tpu import const
 from autodist_tpu.parallel.mesh import (STANDARD_AXES, build_mesh, single_device_mesh,
                                         standard_mesh_shape)
 from autodist_tpu.resource_spec import ResourceSpec
-from shardmap_compat import requires_shard_map
 
 
 def test_eight_virtual_devices_present():
@@ -53,7 +52,6 @@ def test_single_device_mesh():
     assert mesh.size == 1
 
 
-@requires_shard_map
 def test_psum_on_mesh_works():
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P

@@ -17,7 +17,6 @@ import pytest
 
 from autodist_tpu.data import DataLoader, mlm
 from autodist_tpu.data.text_corpus import Vocabulary
-from shardmap_compat import requires_shard_map
 
 
 def _write_corpus(path, n_words=4000, vocab=40, seed=0):
@@ -129,7 +128,6 @@ def test_masking_is_deterministic_and_fresh_per_batch(tmp_path):
     assert not np.array_equal(a[0]["mlm_positions"], a[1]["mlm_positions"])
 
 
-@requires_shard_map
 def test_bert_trains_from_disk(tmp_path):
     from autodist_tpu import AutoDist
     from autodist_tpu.models import bert
@@ -158,7 +156,6 @@ def test_bert_trains_from_disk(tmp_path):
     assert np.mean(losses[-5:]) < losses[0] - 0.5, losses
 
 
-@requires_shard_map
 def test_bert_eval_restores_and_scores(tmp_path, monkeypatch):
     """Train -> checkpoint -> `bert.py --eval --restore`: masked-LM accuracy
     on a cyclic (fully predictable) corpus is far above chance with the

@@ -11,7 +11,6 @@ import pytest
 from autodist_tpu import AutoDist
 from autodist_tpu.models import bert, ncf, resnet, transformer_lm, vgg
 from autodist_tpu.strategy import AllReduce, Parallax, PartitionedPS, PS
-from shardmap_compat import requires_shard_map
 
 TINY_LM = transformer_lm.TransformerLMConfig(
     vocab_size=128, d_model=32, n_heads=4, n_layers=2, d_ff=64, max_len=64,
@@ -152,7 +151,6 @@ def test_vgg_tiny_trains_partitioned_ps():
     assert np.isfinite(losses).all() and losses[-1] < losses[0]
 
 
-@requires_shard_map
 def test_bert_tiny_mlm_trains():
     cfg = bert.BertConfig(vocab_size=128, d_model=32, n_heads=4, n_layers=2,
                           d_ff=64, max_len=64, dtype=jnp.float32)
@@ -167,7 +165,6 @@ def test_bert_tiny_mlm_trains():
     assert losses[-1] < losses[0]
 
 
-@requires_shard_map
 def test_ncf_trains_parallax_sparse():
     cfg = ncf.NeuMFConfig(num_users=64, num_items=32, mf_dim=8, mlp_dims=(16, 8))
     model = ncf.NeuMF(cfg)

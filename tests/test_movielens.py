@@ -6,7 +6,6 @@ import numpy as np
 import pytest
 
 from autodist_tpu.data import movielens
-from shardmap_compat import requires_shard_map
 
 
 def _write_ratings(path, rows, sep=",", header=True):
@@ -158,7 +157,6 @@ def test_hit_rate_and_ndcg_oracle():
     np.testing.assert_allclose(hr, 10 / 19)  # NOT 1.0
 
 
-@requires_shard_map
 def test_ncf_example_trains_on_real_ratings(tmp_path):
     """End-to-end: the NCF benchmark trains on a ratings file and reports
     HR@10/NDCG@10 on the held-out items."""

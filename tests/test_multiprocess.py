@@ -19,7 +19,6 @@ import json
 import numpy as np
 
 import examples.multiprocess_linear_regression as mp_script
-from shardmap_compat import requires_shard_map
 
 
 def _expected_params():
@@ -191,7 +190,6 @@ def _run_matrix_config(tmp_path, config):
     return single, two
 
 
-@requires_shard_map
 def test_cross_process_ps_zero_sharded_opt_state(tmp_path):
     """PS/ZeRO across 2 real processes: Adam moments physically sharded along
     the reduce axis that spans the process boundary, training value-exact."""
@@ -211,7 +209,6 @@ def test_cross_process_partitioned_padded_uneven_storage(tmp_path):
     assert two["wu_shard_shapes"] == [[4, 4]]
 
 
-@requires_shard_map
 def test_cross_process_parallax_sparse_wire_with_ef(tmp_path):
     """Parallax + BF16_EF across 2 real processes: the explicit shard_map
     lowering — sparse (indices, rows) wire for the embedding, bf16 error
@@ -223,7 +220,6 @@ def test_cross_process_parallax_sparse_wire_with_ef(tmp_path):
     assert two["ef_params_dp"] == [4, 4, 4]
 
 
-@requires_shard_map
 def test_cross_process_hierarchical_dcn_reduce(tmp_path):
     """The DCN two-phase reduce laid out the way a real pod would be: inner
     `reduce` axis within each process's devices (ICI tier), outer `data` axis
@@ -264,7 +260,6 @@ def test_cross_process_partitioned_allreduce(tmp_path):
     assert two["w2_opt_shard_shapes"] == [[2, 4]]
 
 
-@requires_shard_map
 def test_cross_process_powersgd(tmp_path):
     """PowerSGD's factor pmeans (P/Q low-rank wire) across 2 real processes,
     exact vs the single-process run (deterministic QR + same shard count)."""
@@ -339,7 +334,6 @@ def _run_matrix_ckpt(tmp_path, monkeypatch, config):
     return saved, restored
 
 
-@requires_shard_map
 def test_cross_process_checkpoint_zero_opt_state(tmp_path, monkeypatch):
     """Save/kill/restore/continue with Adam moments physically sharded along
     the process-spanning reduce axis (the state device_get cannot assemble)."""
@@ -360,7 +354,6 @@ def test_cross_process_checkpoint_padded_uneven(tmp_path, monkeypatch):
     assert restored["wu_shard_shapes"] == [[4, 4]]
 
 
-@requires_shard_map
 def test_cross_process_train_loop_checkpoint_resume(tmp_path, monkeypatch):
     """training.train's own save path inside a real 2-process run: collective
     final save, then a fresh 2-process train() resumes from the latest
@@ -402,7 +395,6 @@ def test_cross_process_train_loop_checkpoint_resume(tmp_path, monkeypatch):
                                    rtol=1e-5, atol=1e-6, err_msg=k)
 
 
-@requires_shard_map
 def test_cross_process_ring_attention_sequence_parallel(tmp_path):
     """Long-context across REAL processes: a 4-way seq axis spanning the
     2-process boundary, so ring attention's K/V ppermute hops cross between

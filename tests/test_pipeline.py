@@ -12,7 +12,6 @@ from autodist_tpu.models import pipeline_lm
 from autodist_tpu.parallel.pipeline import pipelined, pipelined_value_and_grad
 from autodist_tpu.parallel.plan import ShardingPlan
 from autodist_tpu.strategy import Pipeline, StrategyCompiler
-from shardmap_compat import requires_shard_map
 
 TINY = pipeline_lm.PipelineLMConfig(
     vocab_size=64, d_model=16, n_heads=2, n_layers=4, d_ff=32, max_len=32,
@@ -31,7 +30,6 @@ def _pipe_mesh(n_stages=4):
     return build_mesh(axes={"pipe": n_stages, "data": -1})
 
 
-@requires_shard_map
 def test_gpipe_loop_matches_sequential_forward_and_grad():
     rng = np.random.RandomState(0)
     d, s, m = 8, 4, 6
@@ -76,7 +74,6 @@ def _onef_oneb_setup(s=4, m=6, d=8, seed=0):
     return w, head, x_mb, t_mb, stage_fn, tail_fn
 
 
-@requires_shard_map
 def test_onef_oneb_matches_gpipe_loss_and_grads():
     """1F1B returns the SAME mean loss and gradients (stage, tail, input) as
     GPipe + autodiff on the same stages — only the schedule differs."""
@@ -105,7 +102,6 @@ def test_onef_oneb_matches_gpipe_loss_and_grads():
                                rtol=1e-4, atol=1e-6)
 
 
-@requires_shard_map
 def test_onef_oneb_single_stage_degenerate():
     w, head, x_mb, t_mb, stage_fn, tail_fn = _onef_oneb_setup(s=1, m=4)
     from autodist_tpu.parallel.mesh import build_mesh
@@ -126,7 +122,6 @@ def test_onef_oneb_single_stage_degenerate():
     np.testing.assert_allclose(np.asarray(gx), np.asarray(gx_r), rtol=1e-4)
 
 
-@requires_shard_map
 def test_onef_oneb_memory_flat_in_microbatches():
     """The point of 1F1B: compiled temp memory stays ~flat as num_microbatches
     grows (live set O(n_stages)), while GPipe+autodiff's grows linearly
@@ -162,7 +157,6 @@ def test_onef_oneb_memory_flat_in_microbatches():
     assert onef_32 < gpipe_32 / 4, (onef_32, gpipe_32)
 
 
-@requires_shard_map
 def test_pipeline_lm_onef_oneb_full_model_grads():
     """The full-model 1F1B step returns the SAME loss and gradients — for
     embedding, positions, every block, final norm, and head — as
@@ -198,7 +192,6 @@ def test_pipeline_lm_onef_oneb_full_model_grads():
     assert losses[-1] < losses[0]
 
 
-@requires_shard_map
 def test_pipeline_lm_matches_sequential_apply():
     model, params = pipeline_lm.init_params(TINY)
     batch = pipeline_lm.synthetic_batch(TINY, batch_size=8, seq_len=16)
@@ -229,7 +222,6 @@ def test_pipeline_strategy_shards_block_stacks():
     assert plan.params["embed"].pspec == jax.sharding.PartitionSpec()
 
 
-@requires_shard_map
 def test_pipeline_lm_trains_end_to_end():
     model, params = pipeline_lm.init_params(TINY)
     loss_fn = pipeline_lm.make_loss_fn(model)
@@ -245,7 +237,6 @@ def test_pipeline_lm_trains_end_to_end():
     assert spec and spec[0] == "pipe"
 
 
-@requires_shard_map
 def test_pipeline_e2e_loss_matches_unsharded():
     model, params = pipeline_lm.init_params(TINY)
     loss_fn = pipeline_lm.make_loss_fn(model)
@@ -273,7 +264,6 @@ def test_pipelined_rejects_mesh_stage_mismatch():
         jax.jit(lambda w, x: f(w, x))(jnp.zeros((4, 2, 2)), jnp.zeros((2, 2, 2)))
 
 
-@requires_shard_map
 def test_interleaved_matches_plain_1f1b():
     """Interleaved 1F1B (v chunks per device) returns the SAME loss and
     gradients as plain 1F1B run with one device per virtual stage — only the
@@ -306,7 +296,6 @@ def test_interleaved_matches_plain_1f1b():
                                rtol=1e-4, atol=1e-6)
 
 
-@requires_shard_map
 def test_interleaved_deeper_and_chunks_one_degenerates():
     """v=4 chunks on 2 devices (8 virtual stages); and n_chunks=1 must equal
     plain 1F1B exactly (same schedule by construction)."""
@@ -354,7 +343,6 @@ def test_interleaved_deeper_and_chunks_one_degenerates():
     np.testing.assert_allclose(np.asarray(gx_o), np.asarray(gx_p), rtol=1e-5)
 
 
-@requires_shard_map
 def test_interleaved_wide_mesh_and_validation():
     """S=4 with v=2 (wide mesh x chunks); non-divisible microbatch counts are
     refused (a ragged final group would silently skip/double-process pairs);
@@ -397,7 +385,6 @@ def test_interleaved_wide_mesh_and_validation():
                        "gain": jnp.ones(())}, head, x_mb, t_mb)
 
 
-@requires_shard_map
 def test_blocks_execution_order_roundtrip():
     """Stored (device-major) <-> execution-order conversion round-trips, and
     sequential_apply(interleaved cfg) equals the n_chunks=1 model applied to
@@ -440,7 +427,6 @@ def test_interleave_chunk_layout_roundtrip():
     np.testing.assert_array_equal(np.asarray(back), np.asarray(x))
 
 
-@requires_shard_map
 def test_pipeline_lm_interleaved_full_model_grads():
     """The full-model INTERLEAVED step (n_chunks=2: 4 layers as 4 virtual
     stages on 2 devices) returns the same loss and gradients as autodiff

@@ -18,7 +18,6 @@ from autodist_tpu import AutoDist
 from autodist_tpu.parallel.synchronization import (EFState, PowerSGDState,
                                                    init_ef_state)
 from autodist_tpu.strategy import AllReduce
-from shardmap_compat import requires_shard_map
 
 BATCH = 16
 DIM_IN, DIM_OUT = 8, 4
@@ -71,7 +70,6 @@ def test_powersgd_rank_clamped_to_matrix_dims():
     assert state.ef_state["w"].q.shape == (DIM_OUT, DIM_OUT)
 
 
-@requires_shard_map
 def test_powersgd_loss_decreases():
     batch = _data()
     ad = AutoDist(strategy_builder=AllReduce(compressor="PowerSGDCompressor",
@@ -83,7 +81,6 @@ def test_powersgd_loss_decreases():
     assert losses[-1] < losses[0] * 0.15
 
 
-@requires_shard_map
 def test_powersgd_full_rank_with_ef_tracks_exact_run():
     """With warm-started Q, one power iteration per step, and error feedback, the
     full-rank PowerSGD run converges to the same parameters as the exact run."""
@@ -103,7 +100,6 @@ def test_powersgd_full_rank_with_ef_tracks_exact_run():
     np.testing.assert_allclose(w_psgd, w_ref, atol=5e-3)
 
 
-@requires_shard_map
 def test_powersgd_bias_syncs_exactly():
     """The 1-D bias bypasses factorization: after one step it must match the exact
     (uncompressed) update to float precision, whatever happens to the matrix."""
@@ -119,7 +115,6 @@ def test_powersgd_bias_syncs_exactly():
                                rtol=1e-5)
 
 
-@requires_shard_map
 def test_bf16_ef_residual_is_per_replica():
     """BF16_EF residuals carry a leading dp dim sharded over the data axes: each
     replica owns its own residual (the reference kept one residual per worker
@@ -153,7 +148,6 @@ def test_builder_accepts_powersgd_spellings(name):
     AllReduce(compressor=name)
 
 
-@requires_shard_map
 def test_ef_state_sized_by_actual_mesh_not_plan():
     """A strategy built for 8 devices can run on a smaller local mesh (the runner
     rebuilds it, runner.py:_mesh_from_plan); residuals must be sized per the mesh the
@@ -179,7 +173,6 @@ def test_ef_state_sized_by_actual_mesh_not_plan():
     assert state2.ef_state["w"].error.shape == (4, DIM_IN, DIM_OUT)
 
 
-@requires_shard_map
 def test_powersgd_matrix_without_state_raises():
     """A matrix POWER_SGD param whose ef leaf is not a PowerSGDState must raise, not
     silently fall back to uncompressed sync (mirror of the BF16_EF guard)."""

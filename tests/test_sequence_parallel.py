@@ -17,7 +17,6 @@ from autodist_tpu.models import transformer_lm
 from autodist_tpu.parallel.sequence import (create_sequence_parallel_session,
                                             make_sequence_parallel_loss_fn)
 from autodist_tpu.strategy import SequenceParallel
-from shardmap_compat import requires_shard_map
 
 SEQ = 32
 BATCH = 4
@@ -37,7 +36,6 @@ def _batch(cfg, seed=0):
                                           seed=seed)
 
 
-@requires_shard_map
 def test_sp_loss_and_grads_match_single_device():
     """SP loss/grads over a (data=2, seq=4) mesh == the plain single-shard model
     with identical parameters."""
@@ -63,7 +61,6 @@ def test_sp_loss_and_grads_match_single_device():
                                    rtol=2e-4, atol=2e-5)
 
 
-@requires_shard_map
 @pytest.mark.parametrize("tied", [False, True])
 def test_sp_fused_head_matches_plain_sp(tied):
     """The fused pallas head composes with sequence parallelism: same loss and
@@ -93,7 +90,6 @@ def test_sp_fused_head_matches_plain_sp(tied):
                                    rtol=5e-4, atol=5e-5)
 
 
-@requires_shard_map
 def test_sp_training_decreases_loss():
     model, params, cfg = _model("ring")
     batch = _batch(cfg)
@@ -108,7 +104,6 @@ def test_sp_training_decreases_loss():
     assert np.all(np.isfinite(losses))
 
 
-@requires_shard_map
 def test_sp_composes_with_data_parallelism():
     """seq=2 leaves data=4: batch shards over data, sequence over seq, same loss."""
     model_ring, params, cfg = _model("ring")
@@ -124,7 +119,6 @@ def test_sp_composes_with_data_parallelism():
     np.testing.assert_allclose(float(loss_fn(params, batch)), ref, rtol=1e-5)
 
 
-@requires_shard_map
 def test_sp_rejects_indivisible_sequence():
     model, params, cfg = _model("ring")
     ad = AutoDist(strategy_builder=SequenceParallel(seq_axis_size=4))
@@ -152,7 +146,6 @@ def test_sp_rejects_compressor():
         SequenceParallel(seq_axis_size=2, compressor="HorovodCompressor")
 
 
-@requires_shard_map
 def test_sp_rejects_sequence_beyond_max_len():
     """Out-of-range position offsets would silently clamp per-shard; the global
     length check fails loudly instead."""
@@ -167,7 +160,6 @@ def test_sp_rejects_sequence_beyond_max_len():
 
 # ------------------------------------------------------------------ Ulysses
 
-@requires_shard_map
 def test_ulysses_attention_matches_single_device():
     """All-to-all SP: seq-sharded ulysses attention == full attention."""
     from autodist_tpu.parallel.mesh import build_mesh
@@ -183,7 +175,6 @@ def test_ulysses_attention_matches_single_device():
     np.testing.assert_allclose(np.asarray(ul), np.asarray(ref), atol=2e-5)
 
 
-@requires_shard_map
 def test_ulysses_sp_loss_and_grads_match_single_device():
     """Full SP training path with attention_impl='ulysses'."""
     model_ul, params, cfg = _model("ulysses")
@@ -205,7 +196,6 @@ def test_ulysses_sp_loss_and_grads_match_single_device():
                                    rtol=2e-4, atol=2e-5)
 
 
-@requires_shard_map
 def test_ulysses_rejects_indivisible_heads():
     from autodist_tpu.parallel.mesh import build_mesh
     from autodist_tpu.parallel.ulysses import make_ulysses_attention_fn

@@ -20,7 +20,6 @@ from autodist_tpu.parallel import synchronization
 from autodist_tpu.parallel.mesh import build_mesh
 from autodist_tpu.parallel.plan import ShardingPlan
 from autodist_tpu.strategy import AllReduce, Parallax
-from shardmap_compat import requires_shard_map
 
 VOCAB, DIM, BATCH = 793, 8, 32
 LR = 0.1
@@ -64,7 +63,6 @@ def test_index_leaf_detected_and_wire_enabled():
     assert "w" not in plan.sparse_wire_params
 
 
-@requires_shard_map
 @pytest.mark.parametrize("builder_cls", [Parallax, AllReduce])
 @pytest.mark.parametrize("dup", [False, True], ids=["unique", "duplicates"])
 def test_sparse_sync_value_exact(builder_cls, dup):
@@ -88,7 +86,6 @@ def test_sparse_sync_value_exact(builder_cls, dup):
     np.testing.assert_allclose(float(loss), float(_loss(params, batch)), rtol=1e-5)
 
 
-@requires_shard_map
 def test_wire_carries_rows_not_matrix():
     """HLO proof of wire volume: the embedding gradient crosses as batch rows
     (all-gather of [local_batch, DIM] + indices); no vocab-sized all-reduce."""
@@ -106,7 +103,6 @@ def test_wire_carries_rows_not_matrix():
         assert f"{VOCAB},{DIM}" not in line.replace(" ", ""), line
 
 
-@requires_shard_map
 def test_end_to_end_parallax_training_with_sparse_wire():
     params, batch = _params(), _batch(with_duplicates=True)
     ad = AutoDist(strategy_builder=Parallax())
@@ -160,7 +156,6 @@ def test_two_index_leaves_disable_sparse_wire():
     assert detect_sparse_index_sources(loss, params, batch) == {}
 
 
-@requires_shard_map
 def test_negative_indices_value_exact():
     """jnp.take wraps negative indices; the wire format reproduces the wrap."""
     plan, model, mesh = _plan_and_mesh(Parallax())

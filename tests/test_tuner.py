@@ -7,7 +7,6 @@ import pytest
 
 from autodist_tpu.model_spec import ModelSpec
 from autodist_tpu.resource_spec import ResourceSpec
-from shardmap_compat import requires_shard_map
 from autodist_tpu.strategy import (AllReduce, PSLoadBalancing, Strategy,
                                    StrategyBuilder, TuneResult, tune_strategy)
 
@@ -126,7 +125,6 @@ def test_tuner_rejects_zero_warmup():
                       candidates=[AllReduce()], warmup_steps=0)
 
 
-@requires_shard_map
 def test_tuner_default_candidates_include_parallax_for_sparse():
     rng = np.random.RandomState(2)
     params = {"emb": rng.randn(50, 4).astype(np.float32),
