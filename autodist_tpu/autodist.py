@@ -17,9 +17,10 @@ Surface parity with reference ``autodist/autodist.py``:
 """
 
 import contextlib
+import time
 from typing import Any, Callable, Optional, Sequence, Union
 
-from autodist_tpu import const
+from autodist_tpu import const, telemetry
 from autodist_tpu.model_spec import ModelSpec
 from autodist_tpu.resource_spec import ResourceSpec
 from autodist_tpu.runner import DistributedRunner
@@ -100,18 +101,25 @@ class AutoDist:
         """Build (chief) or load (worker) the strategy (reference autodist.py:91-109)."""
         if self._strategy is not None:
             return self._strategy
-        if self.is_chief:
-            self._strategy = self._strategy_builder.build(model_spec, self._resource_spec)
-            path = self._strategy.serialize()
-            logging.info("Built strategy %s -> %s", self._strategy.id, path)
-        else:
-            strategy_id = const.ENV.AUTODIST_STRATEGY_ID.val
-            if not strategy_id:
-                raise RuntimeError(
-                    "Worker process has no AUTODIST_STRATEGY_ID; the coordinator "
-                    "must ship the chief's strategy id")
-            self._strategy = Strategy.deserialize(strategy_id)
-            logging.info("Loaded strategy %s (worker)", strategy_id)
+        # Once a build: its seconds go to the registry whether or not
+        # telemetry is on (set-up is paid on every fresh machine and restart).
+        t0 = time.perf_counter()
+        with telemetry.span("setup.strategy_build_s"):
+            if self.is_chief:
+                self._strategy = self._strategy_builder.build(
+                    model_spec, self._resource_spec)
+                path = self._strategy.serialize()
+                logging.info("Built strategy %s -> %s", self._strategy.id, path)
+            else:
+                strategy_id = const.ENV.AUTODIST_STRATEGY_ID.val
+                if not strategy_id:
+                    raise RuntimeError(
+                        "Worker process has no AUTODIST_STRATEGY_ID; the "
+                        "coordinator must ship the chief's strategy id")
+                self._strategy = Strategy.deserialize(strategy_id)
+                logging.info("Loaded strategy %s (worker)", strategy_id)
+        telemetry.counter("setup.strategy_build_s").inc(
+            time.perf_counter() - t0)
         return self._strategy
 
     def _compile(self, model_spec: ModelSpec) -> Strategy:

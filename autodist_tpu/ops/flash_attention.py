@@ -30,6 +30,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from autodist_tpu.ops.blockwise_attention import NEG_INF
+from autodist_tpu.ops.named_call import named_pallas_call
 
 # 512-blocks amortize grid/DMA overhead into MXU-sized matmuls: measured on a TPU
 # v5e chip (B=8 H=8 D=64, causal, fwd+bwd) flash@512 beats XLA's fused dot-product
@@ -141,8 +142,8 @@ def _flash_forward(q, k, v, causal: bool, q_block: int, k_block: int,
     kernel = functools.partial(_flash_kernel, lk=lk, q_block=bq, k_block=bk,
                                causal=causal, scale=scale)
     offs = jnp.zeros((2,), jnp.int32)
-    out, lse = pl.pallas_call(
-        kernel,
+    out, lse = named_pallas_call(
+        "flash_fwd", kernel,
         grid=(b * h, n_q, n_k),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -329,8 +330,8 @@ def _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, k_block,
     dkdv_kernel = functools.partial(
         _flash_bwd_dkdv_kernel, lk=lk, q_block=bq, k_block=bk, causal=causal,
         scale=scale)
-    dk, dv = pl.pallas_call(
-        dkdv_kernel,
+    dk, dv = named_pallas_call(
+        "flash_bwd_dkv", dkdv_kernel,
         grid=(b * h, n_k, n_q),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                   q_spec, q_spec, row_spec, row_spec, kv_spec, kv_spec],
@@ -352,8 +353,8 @@ def _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, k_block,
     dq_kernel = functools.partial(
         _flash_bwd_dq_kernel, lk=lk, q_block=bq, k_block=bk, causal=causal,
         scale=scale)
-    dq = pl.pallas_call(
-        dq_kernel,
+    dq = named_pallas_call(
+        "flash_bwd_dq", dq_kernel,
         grid=(b * h, n_q, n_k),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -477,8 +478,8 @@ def flash_attention_with_carry(q, k, v, carry=None, *, causal: bool = True,
     kernel = functools.partial(_flash_carry_kernel, lk=lk, q_block=bq, k_block=bk,
                                causal=causal, scale=scale)
     row_plane = pl.BlockSpec((1, n_q, bq), lambda bh, i, j: (bh, 0, 0))
-    acc, m, l = pl.pallas_call(
-        kernel,
+    acc, m, l = named_pallas_call(
+        "flash_carry", kernel,
         grid=(b * h, n_q, n_k),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),

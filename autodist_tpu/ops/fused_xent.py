@@ -56,6 +56,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from autodist_tpu.ops.blockwise_attention import NEG_INF
 from autodist_tpu.ops.flash_attention import _use_interpret
+from autodist_tpu.ops.named_call import named_pallas_call
 
 _LANES = 128
 DEFAULT_N_BLOCK = 512
@@ -217,7 +218,8 @@ def _forward(h, w, b, bn, bv, interpret, w_vd):
     bn, bv = _fit_blocks(h.shape[1], bn, bv, h.dtype.itemsize,
                          w.dtype.itemsize, backward=False)
     n, d, v, n_n, n_v = _shapes(h, w, bn, bv, w_vd)
-    lse = pl.pallas_call(
+    lse = named_pallas_call(
+        "xent_fwd",
         functools.partial(_fwd_kernel, n_v=n_v, w_vd=w_vd, bv=bv, v=v),
         grid=(n_n, n_v),
         in_specs=[
@@ -312,7 +314,8 @@ def _backward(h, w, b, lse, g, bn, bv, interpret, w_vd):
                     constant_values=_PAD_LSE).reshape(1, n_n, bn)
     g_p = jnp.pad(g.astype(jnp.float32), (0, n_n * bn - n)).reshape(1, n_n, bn)
 
-    dh = pl.pallas_call(
+    dh = named_pallas_call(
+        "xent_bwd_dh",
         functools.partial(_dh_kernel, n_v=n_v, w_vd=w_vd, bv=bv, v=v),
         grid=(n_n, n_v),
         in_specs=[
@@ -330,7 +333,8 @@ def _backward(h, w, b, lse, g, bn, bv, interpret, w_vd):
 
     dw_shape = (v, d) if w_vd else (d, v)
     dw_scratch = pltpu.VMEM((bv, d) if w_vd else (d, bv), jnp.float32)
-    dw, db = pl.pallas_call(
+    dw, db = named_pallas_call(
+        "xent_bwd_dw",
         functools.partial(_dwdb_kernel, n_n=n_n, w_vd=w_vd, bn=bn, bv=bv,
                           n=n, v=v),
         grid=(n_v, n_n),

@@ -1,0 +1,34 @@
+"""One name per Pallas kernel, carried where a device trace can read it.
+
+A Mosaic custom call reaches the profiler's ``XLA Ops`` line under the name
+XLA gives its HLO instruction, and XLA takes that from the innermost scope
+of the call's ``op_name`` (``jit(step)/jvp(attn)/pallas_call`` ran as
+``jvp_attn_``): whatever scope the call happened to sit in, renamed by
+autodiff's ``jvp``/``transpose`` wrappers and by a ``shard_map``. So every
+``pallas_call`` of this package goes through :func:`named_pallas_call`,
+which gives the kernel its ``name=`` (the Mosaic module's name) and calls it
+directly inside a ``jax.named_scope`` of the same name, innermost. Names are
+trace-time metadata: the compiled arithmetic is unchanged.
+"""
+
+import jax
+from jax.experimental import pallas as pl
+
+# Every kernel this package lowers, by the name its device events carry
+# (the benchmark's kernel readers and tests/test_device_names.py lean on these).
+KERNEL_NAMES = ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq", "flash_carry",
+                "xent_fwd", "xent_bwd_dh", "xent_bwd_dw")
+
+
+def named_pallas_call(name: str, kernel, **kwargs):
+    """``pl.pallas_call(kernel, name=name, **kwargs)`` whose call sits
+    directly inside ``jax.named_scope(name)``."""
+    if name not in KERNEL_NAMES:
+        raise ValueError(f"kernel name {name!r} is not in KERNEL_NAMES")
+    call = pl.pallas_call(kernel, name=name, **kwargs)
+
+    def run(*args):
+        with jax.named_scope(name):
+            return call(*args)
+
+    return run

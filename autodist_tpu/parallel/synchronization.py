@@ -249,7 +249,12 @@ def make_grad_fn(sharding_plan: ShardingPlan, model_spec: ModelSpec, mesh: Mesh,
         else:
             loss, grads = jax.value_and_grad(loss_fn)(params, batch)
             aux = ()
+        # The reduction only, under its own scope: it nests in the runner's
+        # ``step.grad``, so a device trace can tell the sync from the backward.
+        with jax.named_scope("step.grad_sync"):
+            return sync_fn(grads, loss, aux, ef_state, batch)
 
+    def sync_fn(grads, loss, aux, ef_state, batch):
         # ---- collect leaves in traversal order so buckets can span the tree ----
         collected = []
 

@@ -170,6 +170,31 @@ class _CompileProbe:
         return self._inner.__exit__(*exc)
 
 
+class _StepAnnotated:
+    """A dispatch span inside ``jax.profiler.StepTraceAnnotation("train",
+    step_num=...)``, which marks the step in a profiler session's own trace.
+    Constructed only in enabled mode
+    (:meth:`DistributedRunner._dispatch_span`)."""
+
+    __slots__ = ("_inner", "_step")
+
+    def __init__(self, inner, step_num: int):
+        self._inner = inner
+        self._step = jax.profiler.StepTraceAnnotation("train",
+                                                      step_num=step_num)
+
+    def __enter__(self):
+        self._step.__enter__()
+        self._inner.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        try:
+            return self._inner.__exit__(*exc)
+        finally:
+            self._step.__exit__(*exc)
+
+
 class DistributedRunner:
     """Compiles and runs the distributed train step for one (strategy, model).
 
@@ -181,6 +206,10 @@ class DistributedRunner:
     # regimes override to False: their parameter service applies gradients
     # host-step by host-step, so there is no on-device K-step program to fuse.
     supports_run_many = True
+    # Optimizer steps dispatched with telemetry on: the ``step_num`` of the
+    # dispatch's StepTraceAnnotation (``state.step`` lives on the device, and
+    # reading it would be a sync).
+    _annotated_steps = 0
 
     def __init__(self, compiled_strategy, model_spec: ModelSpec, loss_fn: Callable,
                  optimizer, mesh: Optional[Mesh] = None, has_aux: bool = False,
@@ -284,7 +313,17 @@ class DistributedRunner:
     def init(self, params: PyTree, rng: Optional[jax.Array] = None) -> TrainState:
         """Place initial state onto the mesh (reference ran initializers at session
         construction, runner.py:97-100). Params arrive at logical shapes; unevenly
-        partitioned ones are zero-padded to their physical storage shape here."""
+        partitioned ones are zero-padded to their physical storage shape here.
+        Every call books its seconds as ``setup.state_place_s`` and counts in
+        ``setup.state_place_calls``, whether or not telemetry is on."""
+        t0 = time.perf_counter()
+        with telemetry.span("setup.state_place_s"):
+            state = self._place_state(params)
+        telemetry.counter("setup.state_place_s").inc(time.perf_counter() - t0)
+        telemetry.counter("setup.state_place_calls").inc()
+        return state
+
+    def _place_state(self, params: PyTree) -> TrainState:
         params = self.plan.pad_params(params)
         opt_state = self._optimizer.init(params)
         ef_state = synchronization.init_ef_state(self.plan, params, mesh=self.mesh)
@@ -307,7 +346,12 @@ class DistributedRunner:
         the compiled program) when off. Single source of the step math:
         ``_build_step`` jits it directly and ``_build_many`` scans it — so the
         fused multi-step path can never drift numerically from the per-step
-        path."""
+        path.
+
+        The four phases of a step sit under ``jax.named_scope``s a device
+        trace can read: ``step.grad`` (with ``step.grad_sync`` inside it, or
+        after it under ZeRO), ``step.accumulate``, ``step.optimizer``. Names
+        are trace-time metadata; the compiled arithmetic does not change."""
         import jax.numpy as jnp
 
         optimizer = self._optimizer
@@ -336,14 +380,17 @@ class DistributedRunner:
 
             def micro(carry, i):
                 gsum, ef = carry
-                grads, loss, aux, ef = grad_fn(params, select(i), ef)
-                gsum = jax.tree_util.tree_map(jnp.add, gsum, grads)
+                with jax.named_scope("step.grad"):
+                    grads, loss, aux, ef = grad_fn(params, select(i), ef)
+                with jax.named_scope("step.accumulate"):
+                    gsum = jax.tree_util.tree_map(jnp.add, gsum, grads)
                 return (gsum, ef), (loss, aux)
 
             zeros = jax.tree_util.tree_map(jnp.zeros_like, params)
             (gsum, ef_state), (losses, auxes) = jax.lax.scan(
                 micro, (zeros, ef_state), jnp.arange(accum))
-            grads = jax.tree_util.tree_map(lambda g: g / accum, gsum)
+            with jax.named_scope("step.accumulate"):
+                grads = jax.tree_util.tree_map(lambda g: g / accum, gsum)
             # Aux contraction matches the accum=1 shapes: per-example aux — leading
             # dim == the micro-batch size — folds back to [B, ...] (same examples,
             # same params, so values are identical to full-batch evaluation);
@@ -363,23 +410,27 @@ class DistributedRunner:
                 grads, loss, aux, ef_state = accumulate(state.params, batch,
                                                         state.ef_state)
             else:
-                grads, loss, aux, ef_state = grad_fn(state.params, batch,
-                                                     state.ef_state)
+                with jax.named_scope("step.grad"):
+                    grads, loss, aux, ef_state = grad_fn(state.params, batch,
+                                                         state.ef_state)
             if zero_plan is not None:
                 # ZeRO weight-update sharding (arXiv 2004.13336): constraining
                 # the gradient to the opt-state shards makes XLA materialize it
                 # as a reduce-scatter; the optimizer update then runs on 1/dp
                 # of each parameter per device.
-                grads = zero_plan.constrain_update(mesh, grads)
-            updates, opt_state = optimizer.update(grads, state.opt_state, state.params)
-            if zero_plan is not None:
-                updates = zero_plan.constrain_update(mesh, updates)
-                opt_state = zero_plan.constrain_opt(mesh, opt_state)
-            params = optax.apply_updates(state.params, updates)
-            if zero_plan is not None:
-                # Back to the storage sharding — the all-gather closing the
-                # sharded update.
-                params = zero_plan.constrain_params(mesh, params)
+                with jax.named_scope("step.grad_sync"):
+                    grads = zero_plan.constrain_update(mesh, grads)
+            with jax.named_scope("step.optimizer"):
+                updates, opt_state = optimizer.update(grads, state.opt_state,
+                                                      state.params)
+                if zero_plan is not None:
+                    updates = zero_plan.constrain_update(mesh, updates)
+                    opt_state = zero_plan.constrain_opt(mesh, opt_state)
+                params = optax.apply_updates(state.params, updates)
+                if zero_plan is not None:
+                    # Back to the storage sharding — the all-gather closing
+                    # the sharded update.
+                    params = zero_plan.constrain_params(mesh, params)
             new_state = TrainState(step=state.step + 1, params=params,
                                    opt_state=opt_state, ef_state=ef_state,
                                    plan=state.plan)
@@ -818,16 +869,19 @@ class DistributedRunner:
         :class:`telemetry.profiling.ProgramCost` record, and — with the
         profiling plane active — the first dispatch pulls the compiled
         program's XLA cost analysis through ``cost_probe`` (the jitted fn
-        plus its args) into that record. Disabled mode short-circuits to
-        the shared no-op span."""
+        plus its args) into that record. Either span sits inside a
+        ``StepTraceAnnotation`` (:class:`_StepAnnotated`). Disabled mode
+        short-circuits to the shared no-op span."""
         if not telemetry.enabled():
             return telemetry.span(name)
         sig = self._compile_signature(kind, fetch_fn, batch)
         digest = format(zlib.crc32(sig.encode()), "08x")
         steps = int(span_args.get("steps", 1))
+        step_num = self._annotated_steps
+        self._annotated_steps += steps
         _profiling.note_dispatch(digest, kind, steps)
         if sig in self._compile_sigs:
-            return telemetry.span(name, **span_args)
+            return _StepAnnotated(telemetry.span(name, **span_args), step_num)
         self._compile_sigs.add(sig)
         cost_cb = None
         if cost_probe is not None and _profiling.active():
@@ -839,8 +893,9 @@ class DistributedRunner:
                     _d, _k, _s,
                     self._extract_program_cost(_fn, _a, steps=_s),
                     compile_s=compile_s)
-        return _CompileProbe(telemetry.span(
-            "jit.compile", kind=kind, sig=digest, **span_args), cost_cb)
+        return _StepAnnotated(_CompileProbe(telemetry.span(
+            "jit.compile", kind=kind, sig=digest, **span_args), cost_cb),
+            step_num)
 
     # ------------------------------------------------- compile-only cost probe
     def _abstract_state(self, params: PyTree) -> TrainState:

@@ -4,9 +4,10 @@ Two sinks for the two telemetry planes:
 
 - :func:`export_chrome_trace` writes the span ring buffer as a Chrome
   trace-event file (the ``{"traceEvents": [...]}`` object form) that loads in
-  ui.perfetto.dev or ``chrome://tracing`` — alongside a ``jax.profiler``
-  device trace for a host+device overlay (``utils/tracing.trace`` with
-  ``with_host_spans=True`` writes both; see docs/usage/observability.md).
+  ui.perfetto.dev or ``chrome://tracing``: the host timeline on its own, or
+  per worker for the cluster merge. For host spans beside a device trace no
+  export is needed: an enabled span is a ``jax.profiler.TraceAnnotation``, so
+  a profiler session's own trace holds it (docs/usage/observability.md).
 - :func:`emit_metrics` writes the metrics-registry snapshot as JSONL metric
   rows through the existing :mod:`autodist_tpu.utils.benchmark_logger` file
   sink (one ``metric.log`` line per instrument), so registry metrics land in
@@ -48,13 +49,12 @@ def opt_state_bytes(opt_state) -> int:
     return (max(per_dev.values()) if per_dev else 0) + host
 
 
-def chrome_trace_events(since_ns=None, pid: Optional[int] = None,
+def chrome_trace_events(pid: Optional[int] = None,
                         clock_offset_ns: int = 0) -> list:
     """The recorded spans as a list of Chrome trace-event dicts: one ``"M"``
     thread_name metadata event per recorded thread, then one ``"X"``
     (complete) event per span with microsecond ``ts``/``dur`` relative to the
-    ring's epoch. ``since_ns`` (a ``time.perf_counter_ns`` stamp) keeps only
-    spans that started at/after it — the traced-window filter.
+    ring's epoch.
 
     ``pid`` overrides the lane id (Chrome groups events by pid, so each
     worker exporting under its own lane id merges collision-free) and
@@ -63,7 +63,7 @@ def chrome_trace_events(since_ns=None, pid: Optional[int] = None,
     timeline with no post-hoc JSON rewriting (the cluster trace plane's
     :mod:`autodist_tpu.telemetry.cluster` computes the offsets)."""
     real_pid, epoch_ns, recorded, thread_names, _, _ = \
-        _spans._export_state(since_ns)
+        _spans._export_state()
     if pid is None:
         pid = real_pid
     events = []
@@ -85,15 +85,14 @@ def chrome_trace_events(since_ns=None, pid: Optional[int] = None,
     return events
 
 
-def export_chrome_trace(path: str, since_ns=None, pid: Optional[int] = None,
+def export_chrome_trace(path: str, pid: Optional[int] = None,
                         clock_offset_ns: int = 0) -> str:
     """Write the span ring buffer to ``path`` as Chrome trace-event JSON;
     returns ``path``. Safe to call repeatedly (each call snapshots the ring);
-    an empty ring writes a valid empty trace. ``since_ns`` restricts the
-    export to spans started at/after that ``perf_counter_ns`` stamp; ``pid``
-    and ``clock_offset_ns`` relabel/rebase the lane for merged multi-worker
-    timelines (see :func:`chrome_trace_events`)."""
-    doc = {"traceEvents": chrome_trace_events(since_ns, pid=pid,
+    an empty ring writes a valid empty trace. ``pid`` and ``clock_offset_ns``
+    relabel/rebase the lane for merged multi-worker timelines (see
+    :func:`chrome_trace_events`)."""
+    doc = {"traceEvents": chrome_trace_events(pid=pid,
                                               clock_offset_ns=clock_offset_ns),
            "displayTimeUnit": "ms"}
     with open(path, "w") as f:

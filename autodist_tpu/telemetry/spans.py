@@ -4,15 +4,18 @@
 the HOST do between dispatches" — data wait, feed sharding, gate round-trips,
 readback sync. This module records named wall-clock spans into a bounded
 in-memory ring buffer, exportable as Chrome trace-event JSON
-(:func:`autodist_tpu.telemetry.export_chrome_trace`) that loads in Perfetto
-next to the device trace (``docs/usage/observability.md`` shows the overlay
-workflow).
+(:func:`autodist_tpu.telemetry.export_chrome_trace`). An enabled span is also
+a ``jax.profiler.TraceAnnotation`` of its name, so whatever profiler session
+is recording (``utils/tracing.trace``, an operator's ``start_trace``) holds
+the span in the host plane of its own trace, on one clock with the device
+planes (``docs/usage/observability.md``).
 
 Cost contract: when telemetry is DISABLED (the default), :func:`span` performs
 exactly one attribute read and returns a shared no-op context manager — the
 instrumented hot paths (``runner.run``, the train loop, the PS client) pay
 nanoseconds per step, gated in ``bench.py --telemetry-overhead``. When
-enabled, a span costs two ``perf_counter_ns`` reads plus, under one
+enabled, a span costs two ``perf_counter_ns`` reads, one
+``TraceAnnotation`` (inert without a profiler session) plus, under one
 uncontended lock, two intern-table lookups and five deque appends (the ring
 is columnar — see :class:`_State` — so full-ring exports are C-speed; that
 side is gated by ``bench.py --trace-pull-overhead``).
@@ -49,10 +52,14 @@ class _State:
 
     __slots__ = ("enabled", "name_ids", "tid_ids", "ring_name", "ring_tid",
                  "ring_t0", "ring_dur", "ring_args", "thread_names", "lock",
-                 "epoch_ns")
+                 "epoch_ns", "annotation")
 
     def __init__(self, capacity: int):
         self.enabled = False
+        # ``jax.profiler.TraceAnnotation`` once :func:`enable` has found it
+        # (None in a jax-less tool): an enabled span also enters one, so any
+        # profiler session holds the span on the device trace's own clock.
+        self.annotation = None
         # Intern tables: name/tid -> dense id (insertion-ordered; the export
         # tables are list(...) of the keys). Bounded by the set of distinct
         # span names / threads, like thread_names.
@@ -103,14 +110,17 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """One live span: records ``(name, tid, t0_ns, dur_ns, args)`` on exit."""
+    """One live span: records ``(name, tid, t0_ns, dur_ns, args)`` on exit,
+    and is a ``jax.profiler.TraceAnnotation`` of its name meanwhile (a no-op
+    in C++ unless a profiler session is recording)."""
 
-    __slots__ = ("name", "args", "_t0")
+    __slots__ = ("name", "args", "_t0", "_annotation")
 
     def __init__(self, name: str, args: Optional[Dict[str, Any]]):
         self.name = name
         self.args = args
         self._t0 = 0
+        self._annotation = None
 
     def set(self, **args):
         """Merge args onto a LIVE span (recorded at exit) — for values that
@@ -123,11 +133,17 @@ class _Span:
         return self
 
     def __enter__(self):
+        annotation = _STATE.annotation
+        if annotation is not None:
+            self._annotation = annotation(self.name)
+            self._annotation.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter_ns()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         st = _STATE
         tid = threading.get_ident()
         # Recording takes the state lock: the five ring columns must append
@@ -185,6 +201,12 @@ def traced(name: Optional[str] = None, **args):
 
 def enable():
     """Turn span recording (and registry mirroring) on for this process."""
+    if _STATE.annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+            _STATE.annotation = TraceAnnotation
+        except ImportError:   # a jax-less tool: the ring alone
+            pass
     _STATE.enabled = True
 
 

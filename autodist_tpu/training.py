@@ -462,76 +462,84 @@ def _per_step_loop(runner, state: TrainState, feed, next_batch, batch_iter,
             # local steps.
             rate = meter.step(sync=loss)
             if rate is not None:
-                # The period's attribution closes HERE — after the meter's
-                # boundary sync recorded its readback span, before the
-                # snapshot below is emitted — so the train.attr.*/mfu
-                # gauges it books describe exactly this period.
-                attr = _profiling.observe_period(step_i + 1) \
-                    if _profiling.active() else None
-                # Async-PS runs append their transport accounting (zero-copy
-                # wire counters) so per-period logs show parameter/gradient
-                # traffic next to throughput. `q` is the input queue depth
-                # (the prefetch producer's fill with prefetch_depth > 0,
-                # else 0 — 0 under prefetch means the loader is not keeping
-                # up), `rb` the seconds this period spent blocked on
-                # device->host readback — together they say whether a slow
-                # period was compute, readback, or host-side stall, from
-                # the log line alone.
-                stats = getattr(runner, "wire_stats", None)
-                stats = stats() if callable(stats) else None
-                logging.info("train: step %d loss %.4f %.1f examples/s "
-                             "| q %d rb %.3fs%s%s",
-                             step_i + 1, float(loss), rate,
-                             feed.queue_depth() if feed is not None else 0,
-                             meter.last_readback_s,
-                             f" | {stats.format_line()}" if stats else "",
-                             _profiling.format_attr_line(attr))
-                # The period's throughput as a gauge: the fleet console
-                # (tools/adfleet.py) compares steps/s across processes off
-                # the status opcode, so the rate must live in the registry,
-                # not just the log line. One gauge set per log boundary.
-                telemetry.gauge("train.steps_per_s").set(
-                    round(rate / meter.batch_size, 4))
-                if telemetry.enabled():
-                    # Memory gauges first so the snapshot emitted below
-                    # carries this boundary's live-buffer/HBM readings (and
-                    # the opt-state footprint ZeRO sharding divides). The
-                    # census tags re-point at THIS boundary's state — the
-                    # step donates its inputs, so last boundary's claims
-                    # are dead weakrefs by now.
-                    _memplane.tag("params", state.params)
-                    _memplane.tag("opt_state", state.opt_state)
-                    telemetry.sample_device_memory(opt_state=state.opt_state)
-                    telemetry.emit_metrics(global_step=step_i + 1)
-                if monitor is not None:
-                    _observe_health(monitor, runner, step_i + 1,
-                                    jax.device_get(pending_losses), state)
-                    pending_losses = []
-                # Metric-history sample LAST at the boundary, so the sample
-                # (and the alert rules it evaluates) sees this period's
-                # attr/mfu/health/throughput gauges. An AlertHalt under
-                # AUTODIST_ALERT_ACTION=halt propagates from here — the
-                # train loop is the sampler a halt can actually stop — with
-                # the LIVE TrainState attached (the HealthHalt contract:
-                # a halt leaves the state checkpointable, not discarded).
-                try:
-                    _history.maybe_sample(step_i + 1)
-                except telemetry.AlertHalt as e:
-                    e.state = state
-                    raise
-                # The boundary closed HEALTHY (no health anomaly raised, no
-                # alert fired past this point): this state is a valid
-                # rollback target. push() DEEP-COPIES on device via the
-                # ring's copy_fn — the step donates its input buffers, so a
-                # bare reference would be deleted by the next dispatch.
-                if ring is not None:
-                    ring.push(step_i + 1, state)
+                # From the meter's return to the end of the boundary block:
+                # what the device waits for before the next feed. Its two
+                # children are what only a traced run pays (planes) and the
+                # caller's callback; the rest is what every run pays.
+                with telemetry.span("train.boundary"):
+                    # The period's attribution closes HERE — after the meter's
+                    # boundary sync recorded its readback span, before the
+                    # snapshot below is emitted — so the train.attr.*/mfu
+                    # gauges it books describe exactly this period.
+                    attr = _profiling.observe_period(step_i + 1) \
+                        if _profiling.active() else None
+                    # Async-PS runs append their transport accounting (zero-copy
+                    # wire counters) so per-period logs show parameter/gradient
+                    # traffic next to throughput. `q` is the input queue depth
+                    # (the prefetch producer's fill with prefetch_depth > 0,
+                    # else 0 — 0 under prefetch means the loader is not keeping
+                    # up), `rb` the seconds this period spent blocked on
+                    # device->host readback — together they say whether a slow
+                    # period was compute, readback, or host-side stall, from
+                    # the log line alone.
+                    stats = getattr(runner, "wire_stats", None)
+                    stats = stats() if callable(stats) else None
+                    logging.info("train: step %d loss %.4f %.1f examples/s "
+                                 "| q %d rb %.3fs%s%s",
+                                 step_i + 1, float(loss), rate,
+                                 feed.queue_depth() if feed is not None else 0,
+                                 meter.last_readback_s,
+                                 f" | {stats.format_line()}" if stats else "",
+                                 _profiling.format_attr_line(attr))
+                    # The period's throughput as a gauge: the fleet console
+                    # (tools/adfleet.py) compares steps/s across processes off
+                    # the status opcode, so the rate must live in the registry,
+                    # not just the log line. One gauge set per log boundary.
+                    telemetry.gauge("train.steps_per_s").set(
+                        round(rate / meter.batch_size, 4))
                     if telemetry.enabled():
-                        # Ring census: the deep-copied snapshot states are
-                        # pinned device memory nothing else accounts for.
-                        _memplane.tag("snapshots", ring.states())
-                if on_metrics is not None:
-                    on_metrics(step_i + 1, float(loss), rate)
+                        with telemetry.span("train.boundary.planes"):
+                            # Memory gauges first so the snapshot emitted below
+                            # carries this boundary's live-buffer/HBM readings (and
+                            # the opt-state footprint ZeRO sharding divides). The
+                            # census tags re-point at THIS boundary's state — the
+                            # step donates its inputs, so last boundary's claims
+                            # are dead weakrefs by now.
+                            _memplane.tag("params", state.params)
+                            _memplane.tag("opt_state", state.opt_state)
+                            telemetry.sample_device_memory(
+                                opt_state=state.opt_state)
+                            telemetry.emit_metrics(global_step=step_i + 1)
+                    if monitor is not None:
+                        _observe_health(monitor, runner, step_i + 1,
+                                        jax.device_get(pending_losses), state)
+                        pending_losses = []
+                    # Metric-history sample LAST at the boundary, so the sample
+                    # (and the alert rules it evaluates) sees this period's
+                    # attr/mfu/health/throughput gauges. An AlertHalt under
+                    # AUTODIST_ALERT_ACTION=halt propagates from here — the
+                    # train loop is the sampler a halt can actually stop — with
+                    # the LIVE TrainState attached (the HealthHalt contract:
+                    # a halt leaves the state checkpointable, not discarded).
+                    try:
+                        _history.maybe_sample(step_i + 1)
+                    except telemetry.AlertHalt as e:
+                        e.state = state
+                        raise
+                    # The boundary closed HEALTHY (no health anomaly raised, no
+                    # alert fired past this point): this state is a valid
+                    # rollback target. push() DEEP-COPIES on device via the
+                    # ring's copy_fn — the step donates its input buffers, so a
+                    # bare reference would be deleted by the next dispatch.
+                    if ring is not None:
+                        ring.push(step_i + 1, state)
+                        if telemetry.enabled():
+                            # Ring census: the deep-copied snapshot states are
+                            # pinned device memory nothing else accounts for.
+                            _memplane.tag("snapshots", ring.states())
+                    if on_metrics is not None:
+                        with telemetry.span("train.boundary.on_metrics"):
+                            on_metrics(step_i + 1, float(loss), rate)
         if (eval_every and (step_i + 1) % eval_every == 0
                 and not getattr(runner, "_is_remote_worker", False)):
             # Async remote workers skip: their local state is a compile-shapes
@@ -732,56 +740,64 @@ def _unrolled_loop(runner, state: TrainState, next_batch, batch_iter,
         if meter is not None:
             rate = meter.step_many(block.length, sync=losses)
             if rate is not None:
-                # Attribution closes at the same boundary the meter synced
-                # (readback span recorded), before emit_metrics ships the
-                # snapshot carrying the freshly-booked attr/mfu gauges.
-                attr = _profiling.observe_period(step_i) \
-                    if _profiling.active() else None
-                last = float(jax.device_get(losses)[-1])
-                # `q`: dispatch-ahead queue depth (0 means the host failed to
-                # stay ahead of the device — data-starved); `rb`: period
-                # seconds blocked on loss readback.
-                logging.info("train: step %d loss %.4f %.1f examples/s "
-                             "| q %d rb %.3fs%s",
-                             step_i, last, rate, queue_depth,
-                             meter.last_readback_s,
-                             _profiling.format_attr_line(attr))
-                # Steps/s gauge for the fleet console (same contract as the
-                # per-step loop: the registry carries the rate, not just
-                # the log line).
-                telemetry.gauge("train.steps_per_s").set(
-                    round(rate / meter.batch_size, 4))
-                if telemetry.enabled():
-                    # Memory gauges first so the emitted snapshot carries
-                    # this boundary's live-buffer/HBM readings (and the
-                    # opt-state footprint ZeRO sharding divides); census
-                    # tags re-pointed first, as in the per-step loop.
-                    _memplane.tag("params", state.params)
-                    _memplane.tag("opt_state", state.opt_state)
-                    telemetry.sample_device_memory(opt_state=state.opt_state)
-                    telemetry.emit_metrics(global_step=step_i)
-                if monitor is not None:
-                    flat = np.concatenate([np.asarray(l).reshape(-1) for l
-                                           in jax.device_get(pending_losses)])
-                    _observe_health(monitor, runner, step_i, flat, state)
-                    pending_losses = []
-                # History sample last: the alert tick sees this boundary's
-                # freshly-booked gauges (AlertHalt propagates with the live
-                # state attached, like the per-step loop).
-                try:
-                    _history.maybe_sample(step_i)
-                except telemetry.AlertHalt as e:
-                    e.state = state
-                    raise
-                # Healthy-boundary snapshot for the recover action (the
-                # per-step loop's contract: push() deep-copies on device to
-                # survive the step's buffer donation).
-                if ring is not None:
-                    ring.push(step_i, state)
+                # From the meter's return to the end of the boundary block:
+                # what the device waits for before the next feed. Its two
+                # children are what only a traced run pays (planes) and the
+                # caller's callback; the rest is what every run pays.
+                with telemetry.span("train.boundary"):
+                    # Attribution closes at the same boundary the meter synced
+                    # (readback span recorded), before emit_metrics ships the
+                    # snapshot carrying the freshly-booked attr/mfu gauges.
+                    attr = _profiling.observe_period(step_i) \
+                        if _profiling.active() else None
+                    last = float(jax.device_get(losses)[-1])
+                    # `q`: dispatch-ahead queue depth (0 means the host failed to
+                    # stay ahead of the device — data-starved); `rb`: period
+                    # seconds blocked on loss readback.
+                    logging.info("train: step %d loss %.4f %.1f examples/s "
+                                 "| q %d rb %.3fs%s",
+                                 step_i, last, rate, queue_depth,
+                                 meter.last_readback_s,
+                                 _profiling.format_attr_line(attr))
+                    # Steps/s gauge for the fleet console (same contract as the
+                    # per-step loop: the registry carries the rate, not just
+                    # the log line).
+                    telemetry.gauge("train.steps_per_s").set(
+                        round(rate / meter.batch_size, 4))
                     if telemetry.enabled():
-                        _memplane.tag("snapshots", ring.states())
-                if on_metrics is not None:
-                    on_metrics(step_i, last, rate)
+                        with telemetry.span("train.boundary.planes"):
+                            # Memory gauges first so the emitted snapshot carries
+                            # this boundary's live-buffer/HBM readings (and the
+                            # opt-state footprint ZeRO sharding divides); census
+                            # tags re-pointed first, as in the per-step loop.
+                            _memplane.tag("params", state.params)
+                            _memplane.tag("opt_state", state.opt_state)
+                            telemetry.sample_device_memory(
+                                opt_state=state.opt_state)
+                            telemetry.emit_metrics(global_step=step_i)
+                    if monitor is not None:
+                        flat = np.concatenate([np.asarray(l).reshape(-1) for l
+                                               in jax.device_get(pending_losses)])
+                        _observe_health(monitor, runner, step_i, flat, state)
+                        pending_losses = []
+                    # History sample last: the alert tick sees this boundary's
+                    # freshly-booked gauges (AlertHalt propagates with the live
+                    # state attached, like the per-step loop).
+                    try:
+                        _history.maybe_sample(step_i)
+                    except telemetry.AlertHalt as e:
+                        e.state = state
+                        raise
+                    # Healthy-boundary snapshot for the recover action (the
+                    # per-step loop's contract: push() deep-copies on device to
+                    # survive the step's buffer donation).
+                    if ring is not None:
+                        ring.push(step_i, state)
+                        if telemetry.enabled():
+                            _memplane.tag("snapshots", ring.states())
+                    if on_metrics is not None:
+                        with telemetry.span("train.boundary.on_metrics"):
+                            on_metrics(step_i, last, rate)
         if eval_every and step_i % eval_every == 0:
             with telemetry.span("train.eval"):
                 val = runner.evaluate(state, eval_batch, eval_fn)

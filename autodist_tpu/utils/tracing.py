@@ -9,18 +9,14 @@ Parity with reference §5.1:
   at each transform stage) map to :func:`dump_stage`: the jaxpr and StableHLO text
   of the train step at each compilation stage, written under ``graphs/<tag>/``.
 
-``trace(..., with_host_spans=True)`` additionally records the host-side
-telemetry spans (:mod:`autodist_tpu.telemetry`) for the traced window and
-writes them as ``host_spans_w<process-id>.json`` inside the same trace
-directory (the AUTODIST_PROCESS_ID suffix keeps per-worker files on a shared
-trace dir from overwriting each other) — open the profiler's
-``*.trace.json.gz`` and the host-span file(s) together in ui.perfetto.dev
-(Perfetto merges multiple opened files into one timeline) to see host
-dispatch/wait spans next to device execution. The two traces use different
-clock origins, so align on a recognizable boundary (e.g. the first
-``runner.run.dispatch`` span vs the first device program) rather than
-absolute timestamps; for a CLOCK-ALIGNED multi-worker host timeline use
-``telemetry.collect_cluster_trace`` / ``tools/tracedump.py`` instead; see
+``trace(..., with_host_spans=True)`` additionally enables the host-side
+telemetry spans (:mod:`autodist_tpu.telemetry`) for the traced window. An
+enabled span is a ``jax.profiler.TraceAnnotation`` of its name, so the
+profiler's own trace holds the program's spans in its host plane, on one clock
+with the device planes: open the one ``*.xplane.pb`` (TensorBoard, or
+``jax.profiler.ProfileData.from_file``) and host dispatch/wait spans sit next
+to device execution. For a CLOCK-ALIGNED multi-worker host timeline use
+``telemetry.collect_cluster_trace`` / ``tools/tracedump.py``; see
 docs/usage/observability.md.
 """
 
@@ -52,12 +48,9 @@ def trace(name: str = "trace", trace_dir: Optional[str] = None,
 
     Produces a Perfetto-compatible trace viewable in TensorBoard or ui.perfetto.dev
     (the chrome-trace timeline counterpart). With ``with_host_spans=True``,
-    telemetry span recording is enabled for the window and the host timeline
-    is written to ``<trace_dir>/host_spans_w<process-id>.json`` on exit
-    (telemetry returns to its prior enabled/disabled state afterwards; the
-    per-process name keeps workers sharing a trace dir from colliding) —
-    load both files in Perfetto for a host+device overlay (see module
-    docstring)."""
+    telemetry span recording is enabled for the window, so the trace's host
+    plane carries the program's spans beside the device planes (telemetry
+    returns to its prior enabled/disabled state afterwards)."""
     import jax
     trace_dir = trace_dir or _unique_trace_dir(name)
     os.makedirs(trace_dir, exist_ok=True)
@@ -65,23 +58,13 @@ def trace(name: str = "trace", trace_dir: Optional[str] = None,
     if with_host_spans:
         from autodist_tpu import telemetry
         was_enabled = telemetry.enabled()
-        # Window stamp BEFORE enabling: host_spans.json carries only spans
-        # started inside this trace window, not whatever an earlier window
-        # (or an always-enabled process) left in the ring.
-        window_start_ns = time.perf_counter_ns()
         telemetry.enable()
     try:
         with jax.profiler.trace(trace_dir):
             yield trace_dir
     finally:
-        if with_host_spans:
-            if not was_enabled:
-                telemetry.disable()
-            telemetry.export_chrome_trace(
-                os.path.join(
-                    trace_dir,
-                    f"host_spans_w{const.ENV.AUTODIST_PROCESS_ID.val}.json"),
-                since_ns=window_start_ns)
+        if with_host_spans and not was_enabled:
+            telemetry.disable()
 
 
 def dump_stage(tag: str, stage: str, fn, *example_args,

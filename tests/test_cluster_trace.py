@@ -7,8 +7,8 @@ runner, NTP-offset math and the deterministic known-skew rebase (merged
 ordering flips when the offsets say so), the PSServer watchdog flagging a
 stalled and a straggling stub worker, `tools/tracedump.py` merging two JSONL
 ring dumps, and the satellite pins: `export_chrome_trace(pid=,
-clock_offset_ns=)`, `stats_snapshot()` uptime/last-seen, and the per-worker
-`host_spans_w<id>.json` trace filename.
+clock_offset_ns=)`, `stats_snapshot()` uptime/last-seen, and host spans in
+the profiler's own trace (`tracing.trace(with_host_spans=True)`).
 
 Pure in-process host tests — no subprocess spawns (GL008-clean), named to
 sort inside the tier-1 window (before test_image_data).
@@ -362,19 +362,37 @@ def test_export_chrome_trace_pid_and_offset_params(tmp_path):
     assert ev1["dur"] == ev0["dur"]
 
 
-def test_trace_writes_per_worker_host_span_file(tmp_path):
-    from autodist_tpu import const
+def test_trace_holds_host_spans_in_the_profilers_own_trace(tmp_path):
+    """One clock: an enabled span is a ``TraceAnnotation``, so the profiler's
+    own trace carries it in its host plane (with its nesting), and no second
+    ``host_spans_w<id>.json`` file is written beside it."""
+    import glob
+
+    from jax.profiler import ProfileData
+
     from autodist_tpu.utils import tracing
     with tracing.trace("cluster_t", trace_dir=str(tmp_path),
                        with_host_spans=True):
         with telemetry.span("in.window"):
-            pass
-    wid = const.ENV.AUTODIST_PROCESS_ID.val
-    path = tmp_path / f"host_spans_w{wid}.json"
-    assert path.exists()
-    names = [e["name"] for e in json.load(open(path))["traceEvents"]
-             if e["ph"] == "X"]
-    assert "in.window" in names
+            with telemetry.span("in.window.child"):
+                time.sleep(0.002)
+    assert not telemetry.enabled()             # back to its prior state
+    assert not glob.glob(str(tmp_path / "host_spans*"))
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    events = {e.name: e
+              for plane in ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for e in line.events
+              if e.name.startswith("in.window")}
+    assert set(events) == {"in.window", "in.window.child"}
+    outer, inner = events["in.window"], events["in.window.child"]
+    assert inner.duration_ns >= 2e6
+    assert outer.start_ns <= inner.start_ns
+    assert (inner.start_ns + inner.duration_ns
+            <= outer.start_ns + outer.duration_ns)
+    # The ring holds the same spans (the exporters' source is unchanged).
+    assert {s[0] for s in telemetry.snapshot_spans()} == set(events)
 
 
 # ------------------------------------------------------ compile/memory gauges
@@ -383,7 +401,8 @@ def test_compile_signature_and_probe_counters():
     """The runner-side compile telemetry, without compiling anything: a new
     dispatch signature routes through _CompileProbe (bumping jit.cache_miss
     and jit.compile_s), a repeated one returns a plain span."""
-    from autodist_tpu.runner import DistributedRunner, _CompileProbe
+    from autodist_tpu.runner import (DistributedRunner, _CompileProbe,
+                                     _StepAnnotated)
 
     import weakref
 
@@ -397,20 +416,24 @@ def test_compile_signature_and_probe_counters():
     secs = telemetry.counter("jit.compile_s")
     before, before_s = misses.value, secs.value
 
+    # Enabled mode: the probe (or the plain span) sits inside the step's
+    # StepTraceAnnotation, whose number counts the annotated steps.
     cm = r._dispatch_span("runner.run.dispatch", "step", None, batch)
-    assert isinstance(cm, _CompileProbe)
+    assert isinstance(cm, _StepAnnotated)
+    assert isinstance(cm._inner, _CompileProbe)
     with cm:
         time.sleep(0.002)
     assert misses.value == before + 1
     assert secs.value > before_s
 
     again = r._dispatch_span("runner.run.dispatch", "step", None, batch)
-    assert not isinstance(again, _CompileProbe)        # cached signature
+    assert not isinstance(again._inner, _CompileProbe)  # cached signature
     assert misses.value == before + 1
     # A different shape is a new signature -> a new probe.
     other = r._dispatch_span("runner.run.dispatch", "step", None,
                              {"x": np.zeros((8, 2), np.float32)})
-    assert isinstance(other, _CompileProbe)
+    assert isinstance(other._inner, _CompileProbe)
+    assert r._annotated_steps == 3
     # jit.compile spans carry the signature digest.
     jc = [s for s in telemetry.snapshot_spans() if s[0] == "jit.compile"]
     assert jc and "sig" in jc[-1][4]

@@ -181,8 +181,12 @@ def test_producer_close_with_blocked_producer_is_prompt():
 
 
 def test_producer_wait_telemetry_books_loader_seconds():
-    wait0 = telemetry.counter("data.producer_wait").value
-    batches0 = telemetry.counter("data.producer_batches").value
+    # A prefix of this test's own: the process-wide ``data.*`` counters are
+    # shared with every other producer of the process, and under load one
+    # that an earlier test closed can still book a batch while this runs.
+    prefix = "data.wait_test"
+    wait0 = telemetry.counter(f"{prefix}.producer_wait").value
+    batches0 = telemetry.counter(f"{prefix}.producer_batches").value
 
     def slow_pull():
         if not hasattr(slow_pull, "n"):
@@ -193,12 +197,13 @@ def test_producer_wait_telemetry_books_loader_seconds():
         time.sleep(0.02)
         return slow_pull.n
 
-    prod = pf.PrefetchProducer(slow_pull, depth=2)
+    prod = pf.PrefetchProducer(slow_pull, depth=2, metric_prefix=prefix)
     assert len(list(prod)) == 4
     prod.close()
-    waited = telemetry.counter("data.producer_wait").value - wait0
+    waited = telemetry.counter(f"{prefix}.producer_wait").value - wait0
     assert waited >= 4 * 0.02 * 0.5   # the loader seconds are BOOKED
-    assert telemetry.counter("data.producer_batches").value - batches0 == 4
+    assert telemetry.counter(f"{prefix}.producer_batches").value \
+        - batches0 == 4
 
 
 # ------------------------------------------------- device feed parity

@@ -1,0 +1,296 @@
+"""Names a device trace can read, spans over the log boundary, and set-up
+seconds booked by the program itself.
+
+- every Pallas kernel carries its name (``ops/named_call.py``) and the four
+  phases of a step sit under ``jax.named_scope``s; names are trace-time
+  metadata, so the lowered StableHLO without locations equals the unnamed
+  step's;
+- ``train.boundary`` appears once per log boundary in both loops and nests
+  ``train.boundary.planes`` and ``train.boundary.on_metrics``;
+- ``setup.*`` and ``jit.*`` counters are booked with telemetry off, from
+  ``jax.monitoring`` listeners registered once however often ``configure()``
+  runs, nested traces counted once.
+
+Pure in-process tests on the CPU mesh (kernels in interpret mode).
+"""
+
+import contextlib
+import importlib
+import re
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from autodist_tpu import AutoDist, telemetry, train
+from autodist_tpu.models import transformer_lm
+from autodist_tpu.ops import named_call
+from autodist_tpu.strategy import AllReduce
+from autodist_tpu.utils import compile_cache
+
+PHASES = ("step.grad", "step.accumulate", "step.grad_sync", "step.optimizer")
+
+
+@pytest.fixture(autouse=True)
+def _telemetry_reset():
+    telemetry.disable()
+    telemetry.clear()
+    yield
+    telemetry.disable()
+    telemetry.clear()
+
+
+# ------------------------------------------------------------ lowered steps
+
+def _lm_step_lowered(zero=0, accum=2):
+    """The tiny flagship step (flash attention, fused head, accumulation)
+    through ``AutoDist`` on the 8-device mesh, lowered and not compiled."""
+    cfg = transformer_lm.TransformerLMConfig(
+        vocab_size=203, d_model=32, n_heads=2, n_layers=1, d_ff=64,
+        max_len=16, dtype=jnp.float32, attention_impl="flash",
+        fused_head=True)
+    model, params = transformer_lm.init_params(cfg, rng=jax.random.PRNGKey(0))
+    rng = np.random.default_rng(0)
+    batch = {"tokens": rng.integers(0, 203, (16, 17)).astype(np.int32)}
+    runner = AutoDist(strategy_builder=AllReduce()).create_distributed_session(
+        transformer_lm.make_loss_fn(model), params, optax.adam(1e-3),
+        example_batch=batch, accumulation_steps=accum, zero=zero)
+    state = runner.init(params)
+    with runner.mesh:
+        return runner._build_step(None).lower(state,
+                                              runner.shard_batch(batch))
+
+
+def _scopes(text: str, names) -> set:
+    """The names that are a scope of some location: a whole component of a
+    name stack with something below it (``_flash_fwd`` the function is not
+    ``flash_fwd`` the scope)."""
+    return {n for n in names if re.search(r'[/"]' + re.escape(n) + "/", text)}
+
+
+def test_lowered_step_names_kernels_and_phases():
+    text = _lm_step_lowered(zero=1).as_text(debug_info=True)
+    step_kernels = [k for k in named_call.KERNEL_NAMES if k != "flash_carry"]
+    assert _scopes(text, step_kernels) == set(step_kernels)
+    # ZeRO's constrain_update is the reduction under AllReduce (the implicit
+    # lowering leaves the all-reduce to XLA, so it has no scope of its own).
+    assert _scopes(text, PHASES) == set(PHASES)
+
+
+def test_explicit_gradient_sync_sits_under_its_scope_inside_step_grad():
+    """A compressor takes ``make_grad_fn``'s explicit lowering: the
+    reduction is under ``step.grad_sync``, nested in ``step.grad``."""
+    params = {"w": np.ones((8, 4), np.float32), "b": np.zeros((4,), np.float32)}
+    batch = {"x": np.ones((16, 8), np.float32), "y": np.ones((16, 4), np.float32)}
+    loss = lambda p, b: jnp.mean((b["x"] @ p["w"] + p["b"] - b["y"]) ** 2)  # noqa: E731
+    runner = AutoDist(strategy_builder=AllReduce(compressor="HorovodCompressor")) \
+        .create_distributed_session(loss, params, optax.sgd(0.1),
+                                    example_batch=batch)
+    state = runner.init(params)
+    with runner.mesh:
+        text = runner._build_step(None).lower(
+            state, runner.shard_batch(batch)).as_text(debug_info=True)
+    # The shard_map's body is a function of its own in the lowered module,
+    # with its own name stack: the call sits under ``step.grad`` and the
+    # collectives inside the body under ``step.grad_sync``.
+    assert "step.grad/shard_map" in text and "step.optimizer" in text
+    assert "step.grad_sync/psum" in text
+    backward = [line for line in text.splitlines() if "transpose(" in line]
+    assert backward and not any("step.grad_sync" in l for l in backward)
+
+
+def test_flash_carry_is_named():
+    fa = importlib.import_module("autodist_tpu.ops.flash_attention")
+    q = jnp.ones((1, 16, 2, 8), jnp.float32)
+    text = jax.jit(lambda q: fa.flash_attention_with_carry(q, q, q)) \
+        .lower(q).as_text(debug_info=True)
+    assert _scopes(text, ["flash_carry"])
+
+
+def test_an_unlisted_kernel_name_is_refused():
+    with pytest.raises(ValueError, match="KERNEL_NAMES"):
+        named_call.named_pallas_call("attn", lambda *refs: None)
+
+
+def test_names_are_metadata_only(monkeypatch):
+    """The compiled arithmetic is identical: with locations stripped, the
+    named step and the step with every name taken away lower to the same
+    StableHLO."""
+    named = _lm_step_lowered()
+    with_locations = named.as_text(debug_info=True)
+    assert _scopes(with_locations, ("step.optimizer", "flash_fwd"))
+    assert "step.optimizer" not in named.as_text()
+
+    # The same program with every name taken away: ``jax.named_scope`` a
+    # no-op and ``pallas_call`` without its ``name=``.
+    real_call = named_call.pl.pallas_call
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    monkeypatch.setattr(
+        named_call.pl, "pallas_call",
+        lambda kernel, name=None, **kwargs: real_call(kernel, **kwargs))
+    bare = _lm_step_lowered()
+    assert not _scopes(bare.as_text(debug_info=True),
+                       PHASES + named_call.KERNEL_NAMES)
+    assert bare.as_text() == named.as_text()
+
+
+# ----------------------------------------------------------- log boundaries
+
+def _linear_session():
+    params = {"w": np.ones((4, 1), np.float32), "b": np.zeros((1,), np.float32)}
+    loss = lambda p, b: jnp.mean((b["y"] - (b["x"] @ p["w"] + p["b"])) ** 2)  # noqa: E731
+    batch = {"x": np.ones((32, 4), np.float32), "y": np.ones((32, 1), np.float32)}
+    runner = AutoDist(strategy_builder=AllReduce()).create_distributed_session(
+        loss, params, optax.sgd(0.01), example_batch=batch)
+    return runner, params, batch
+
+
+def _contained(child, parent) -> bool:
+    return (parent[2] <= child[2]
+            and child[2] + child[3] <= parent[2] + parent[3])
+
+
+@pytest.mark.parametrize("unroll", [1, 2], ids=["per_step", "unrolled"])
+def test_boundary_span_once_per_log_boundary_with_two_children(unroll):
+    runner, params, batch = _linear_session()
+    seen = []
+    telemetry.enable()
+    train(runner, params, lambda i: batch, steps=9, log_every=2,
+          unroll=unroll, prefetch_depth=0,
+          on_metrics=lambda step, loss, rate: seen.append(step))
+    spans = telemetry.snapshot_spans()
+    by_name = {}
+    for s in spans:
+        by_name.setdefault(s[0], []).append(s)
+    boundaries = by_name["train.boundary"]
+    assert len(seen) >= 3                            # the run had boundaries
+    assert len(boundaries) == len(seen)              # one span each, no more
+    for child in ("train.boundary.planes", "train.boundary.on_metrics"):
+        assert len(by_name[child]) == len(boundaries)
+        for c, b in zip(by_name[child], boundaries):
+            assert _contained(c, b)
+    # The boundary span opens where the meter returned: after the read-back.
+    for rb, b in zip(by_name["train.readback_wait"][-len(boundaries):],
+                     boundaries):
+        assert rb[2] + rb[3] <= b[2]
+
+
+def test_boundary_without_telemetry_records_nothing_and_pays_no_planes():
+    runner, params, batch = _linear_session()
+    seen = []
+    train(runner, params, lambda i: batch, steps=5, log_every=2,
+          prefetch_depth=0, on_metrics=lambda *a: seen.append(a[0]))
+    assert seen and telemetry.snapshot_spans() == []
+
+
+# ------------------------------------------------- set-up seconds, counters
+
+def _counter(name):
+    instrument = telemetry.registry().get(name)
+    return 0 if instrument is None else instrument.value
+
+
+def test_setup_seconds_are_booked_with_telemetry_off():
+    assert not telemetry.enabled()
+    names = ("setup.strategy_build_s", "setup.plan_build_s",
+             "setup.state_place_s", "setup.state_place_calls")
+    before = {n: _counter(n) for n in names}
+    runner, params, batch = _linear_session()
+    runner.init(params)
+    runner.init(params)                        # every init counts
+    after = {n: _counter(n) for n in names}
+    assert after["setup.state_place_calls"] \
+        == before["setup.state_place_calls"] + 2
+    for n in names[:3]:
+        assert after[n] > before[n], n
+    assert telemetry.snapshot_spans() == []    # counters only: no span
+
+
+def test_setup_work_is_a_span_of_the_counters_name_when_enabled():
+    telemetry.enable()
+    runner, params, batch = _linear_session()
+    runner.init(params)
+    names = {s[0] for s in telemetry.snapshot_spans()}
+    assert {"setup.strategy_build_s", "setup.plan_build_s",
+            "setup.state_place_s"} <= names
+
+
+def test_jit_stage_listener_registers_once_and_books_with_telemetry_off():
+    from jax._src import monitoring
+    for _ in range(3):
+        compile_cache.configure()
+    assert monitoring.get_event_duration_listeners().count(
+        compile_cache._on_jit_stage) == 1
+    assert monitoring.get_event_time_span_listeners().count(
+        compile_cache._on_trace_span) == 1
+    names = ("jit.trace_s", "jit.lower_s", "jit.backend_s", "jit.programs")
+    x = jnp.arange(7.0)                        # a program of its own
+    before = {n: _counter(n) for n in names}
+    assert not telemetry.enabled()
+    jax.jit(lambda x: x * 3 + 1)(x).block_until_ready()
+    after = {n: _counter(n) for n in names}
+    assert after["jit.programs"] == before["jit.programs"] + 1
+    for n in names[:3]:
+        assert after[n] > before[n], n
+    # Events of other kinds pass through without a counter of their own.
+    compile_cache._on_jit_stage("/jax/compilation_cache/cache_hits", 1.0)
+    compile_cache._on_trace_span("/jax/core/compile/other", 0.0, 1.0)
+    assert {n: _counter(n) for n in names} == after
+
+
+def test_configure_still_places_the_cache_on_an_accelerator(monkeypatch, tmp_path):
+    """The branch the CPU suite never takes: with an accelerator backend and
+    the directory given from outside, ``configure()`` sets no directory of
+    its own, keeps every compile, and returns the directory in use."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setenv(compile_cache.CACHE_DIR_ENV, str(tmp_path))
+    was_dir = jax.config.jax_compilation_cache_dir
+    was_min = jax.config.jax_persistent_cache_min_compile_time_secs
+    try:
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        assert compile_cache.configure() == str(tmp_path)
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was_dir)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          was_min)
+
+
+def test_nested_traces_count_once():
+    """A trace inside another reports its own span first; ``jit.trace_s``
+    grows by the union, not by the sum."""
+    event = compile_cache.TRACE_EVENT
+    base = time.time() + 1e6                    # later than any real span
+    before = _counter("jit.trace_s")
+    compile_cache._on_trace_span(event, base + 1.0, base + 2.0)    # inner
+    compile_cache._on_trace_span(event, base + 3.0, base + 3.5)    # inner
+    assert _counter("jit.trace_s") == pytest.approx(before + 1.5)
+    compile_cache._on_trace_span(event, base + 0.5, base + 4.0)    # encloses
+    assert _counter("jit.trace_s") == pytest.approx(before + 3.5)
+    compile_cache._on_trace_span(event, base + 5.0, base + 6.0)    # the next
+    assert _counter("jit.trace_s") == pytest.approx(before + 4.5)
+    # and on real programs: an outer jit that traces an inner one
+    inner = jax.jit(lambda x: x * 2 + 1)
+    t0 = time.perf_counter()
+    before = _counter("jit.trace_s")
+    jax.jit(lambda x: inner(x) + inner(x * 3))(jnp.arange(5.0))
+    assert 0 < _counter("jit.trace_s") - before <= time.perf_counter() - t0
+
+
+def test_dispatch_sits_in_a_step_annotation_only_when_enabled():
+    from autodist_tpu.runner import _StepAnnotated
+    from autodist_tpu.telemetry.spans import _NULL_SPAN
+    runner, params, batch = _linear_session()
+    state = runner.init(params)
+    assert runner._dispatch_span("runner.run.dispatch", "step", None,
+                                 batch) is _NULL_SPAN
+    telemetry.enable()
+    state, _ = runner.run(state, batch)
+    state, _ = runner.run_many(state, [batch, batch, batch])
+    assert runner._annotated_steps == 4        # 1 + a block of 3
+    cm = runner._dispatch_span("runner.run.dispatch", "step", None, batch)
+    assert isinstance(cm, _StepAnnotated)

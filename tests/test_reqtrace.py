@@ -370,7 +370,23 @@ def test_histogram_exemplar_slowest_in_window():
     assert h.exemplar()["rid"] == "d"
 
 
-def test_p99_burn_books_exemplar_and_adtrace_names_guilty_replica(tmp_path):
+class _WindowClock:
+    """``time`` for the metrics history with ``monotonic`` in the test's
+    hands: the burn-rate windows are then counted in the seconds the test
+    says a storm took, not in what a loaded machine made of it."""
+
+    def __init__(self):
+        self.now = time.monotonic()
+
+    def monotonic(self):
+        return self.now
+
+    def __getattr__(self, name):
+        return getattr(time, name)
+
+
+def test_p99_burn_books_exemplar_and_adtrace_names_guilty_replica(
+        tmp_path, monkeypatch):
     """The PR's e2e acceptance pin: one SLOW replica in a 2-replica fleet
     drives serve.latency_s.total's p99 over a tight SLO; the firing
     serve_p99_burn books the slowest request's exemplar (rid + phase
@@ -378,12 +394,21 @@ def test_p99_burn_books_exemplar_and_adtrace_names_guilty_replica(tmp_path):
     manifest; adtrace's waterfall for that rid names decode on the guilty
     replica."""
     reqtrace.enable()
+    # The windows' clock is injected: each storm books 0.5 s on it (what it
+    # takes unloaded), so both windows hold two samples however long the
+    # machine took. The latencies the rule reads stay real.
+    clock = _WindowClock()
+    monkeypatch.setattr(history, "time", clock)
     rule = alerts.AlertRule(name="serve_p99_burn", kind="burn_rate",
                             metric="serve.latency_s.total", q=0.99,
                             objective_s=0.05, long_s=1.2, short_s=0.6)
     eng = alerts.AlertEngine(rules=[rule], action="warn")
     alerts.set_engine(eng)
     h = history.MetricsHistory(out_dir="", min_interval_s=0.0)
+    # The baseline must hold the histogram (a delta needs both ends): it
+    # exists already when an earlier test of this file served a request,
+    # and this test does not lean on that.
+    telemetry.histogram("serve.latency_s.total")
     h.sample()                                     # window-opening baseline
 
     fleet = []
@@ -405,6 +430,7 @@ def test_p99_burn_books_exemplar_and_adtrace_names_guilty_replica(tmp_path):
                 t.start()
             for t in threads:
                 t.join()
+            clock.now += 0.5
 
         storm()                                    # burns the long window...
         h.sample()
