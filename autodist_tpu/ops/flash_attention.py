@@ -1,12 +1,19 @@
 """Flash attention — pallas TPU kernels, forward AND backward.
 
-Forward: grid (batch*heads, q-blocks, k-blocks); each K/V block streams through
-VMEM via its own BlockSpec while VMEM scratch carries the online-softmax state
-(running max, denominator, unnormalized accumulator) across the k dimension of the
-grid — the [L, L] score matrix never exists, and resident VMEM is O(q_block +
-k_block), independent of sequence length. Causal upper-triangular blocks are
-skipped entirely (~2x fewer FLOPs). The per-row logsumexp is emitted as a residual
-for the backward pass.
+Forward: grid (batch*heads, q-blocks, k-blocks); VMEM scratch carries the
+online-softmax state (running max, denominator, unnormalized accumulator) across
+the k dimension of the grid — the [L, L] score matrix never exists. While K and V
+of one (batch, head) are small they are ONE resident block (one grid step a q
+block, nothing copied for a block above the diagonal); longer ones stream in
+blocks, and a block above the diagonal is neither copied nor computed. Inside a
+grid step the resident block is walked in key tiles whose score tile is held
+TRANSPOSED, [keys, queries]: the softmax statistics reduce along sublanes and
+are lane-dense [1, q_block] vectors. Each tile runs the body of its class —
+plain (below the diagonal, no padded key: no iota, no compare, no select),
+masked (crossed by the diagonal or holding the ragged tail) or skipped — decided
+from grid indices and the SMEM offsets, so ring attention's traced offsets
+classify at run time. The per-row logsumexp is emitted as a residual for the
+backward pass.
 
 Backward (FlashAttention-2 style): scores are recomputed blockwise from the saved
 logsumexp, so nothing quadratic is ever materialized. Two kernels:
@@ -23,69 +30,172 @@ the same code path on the CPU-sim mesh.
 """
 
 import functools
+import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from autodist_tpu import telemetry
 from autodist_tpu.ops.blockwise_attention import NEG_INF
 from autodist_tpu.ops.named_call import named_pallas_call
 
-# 512-blocks amortize grid/DMA overhead into MXU-sized matmuls: measured on a TPU
-# v5e chip (B=8 H=8 D=64, causal, fwd+bwd) flash@512 beats XLA's fused dot-product
-# attention at L>=2048 (10.1 vs 10.9 ms) and 1.5x at L=4096 (21.7 vs 32.5 ms),
-# while 128-blocks were 2.5x SLOWER than XLA. 1024 is faster still (16 ms at
-# L=4096) at higher VMEM pressure — worth passing explicitly for long context.
+# The forward's blocks, from stand-alone timings of `_flash_forward` on a TPU v5e
+# (jax 0.9.0 / libtpu 0.0.34, bf16, causal, the kernel's own device time;
+# tools/flash_forward_timing.py, PERF.md §6 "PR 24"). At B·H 128, L 1,024, D 64
+# (GPT-2-medium's call) the row-major kernel this replaced took 1.133 ms: its time
+# was not the masking but the softmax statistics of a [queries, keys] tile — two
+# cross-lane reductions per 8 queries per tile and [q, 1] column vectors that fill
+# a vreg per 8 queries (3.5 ns a query row a tile; narrower key tiles made it
+# SLOWER, 3.44 ms at 128 keys). With the tile transposed: 0.518 ms at the old
+# 512 x 512 blocks, 0.521 with per-class bodies, 0.455 with K/V resident
+# (1,024 rows, one grid step a q block), of which the exact scale on q is 0.016 and
+# the class bodies 0.009. q block 512 beats 256 (0.699) and 128 (1.006) although it
+# computes 75% of the square for 62.5% / 56%; key tile 512 beats 256 (0.554) and
+# 1,024 (0.539). Long context, B·H 64: L 4,096 2.33 ms (was 6.49), L 8,192 8.44 ms
+# (was 24.05) resident; streamed in 2,048-row blocks 10.16 ms at L 8,192 (11.56
+# before skipped blocks stopped being copied). D 128 at L 2,048: 0.90 ms (1.78).
+# Non-causal L 1,024: 0.554 ms (1.406). The backward still runs 512 x 512 row-major.
 DEFAULT_Q_BLOCK = 512
 DEFAULT_K_BLOCK = 512
-_LANES = 128  # scratch minor dim (TPU lane count)
+_KEY_TILE = 512             # keys a score tile of the forward: [512, bq] f32
+_RESIDENT_KV_BYTES = 1 << 20     # K (or V) of one (batch, head) kept in VMEM
+_STREAM_K_BLOCK = 2048           # K/V rows a grid step beyond that
 
 
-def _online_softmax_step(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref,
-                         q_start, k_start, q_off, k_off, lk, causal, scale):
-    """One k-block online-softmax update against the VMEM-resident (acc, m, l)
-    state — the single definition shared by the plain forward kernel and the
-    carry variant. Matmul operands stay in the input dtype (bf16 runs the MXU at
-    full rate); accumulation and softmax arithmetic are f32."""
-    q = q_ref[0]                                      # [bq, d]
-    k_blk = k_ref[0]                                  # [bk, d]
-    v_blk = v_ref[0]
-    bq, bk = q.shape[0], k_blk.shape[0]
-    scores = scale * jax.lax.dot_general(
-        q, k_blk, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)           # [bq, bk]
-    k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    invalid = k_pos >= lk                             # tail padding (local)
+def _sub_tile(bk: int) -> int:
+    """Keys a tile: the widest of 512/256/128 that divides the K/V block, the
+    block itself where none does (a ragged or tiny block is one tile)."""
+    for sub in (_KEY_TILE, 256, 128):
+        if bk % sub == 0:
+            return sub
+    return bk
+
+
+def _is_static(n) -> bool:
+    return isinstance(n, (int, np.integer))
+
+
+def _tile_counts(q_lo, k_lo, valid, bq: int, bk: int, sub: int, causal: bool):
+    """``(n_plain, n_need)`` of the ``bk // sub`` key tiles of one (q block,
+    K/V block) pair: tiles ``[0, n_plain)`` hold no masked score (the plain
+    body), ``[n_plain, n_need)`` are crossed by the diagonal or hold padded
+    keys (the masked body), the rest hold nothing the mask keeps (skipped).
+    ``q_lo`` / ``k_lo`` are the global positions of the block's first query
+    and key, ``valid`` the real keys from the block's first on (None: all of
+    them). One definition for the trace-time count (ints in, ints out) and
+    the kernel (SMEM scalars and grid indices)."""
+    operands = (valid, q_lo, k_lo) if causal else (valid,)
+    xp = np if all(x is None or _is_static(x) for x in operands) else jnp
+    valid = bk if valid is None else xp.clip(valid, 0, bk)
+    n_plain = valid // sub
+    n_need = (valid + sub - 1) // sub
     if causal:
-        q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        invalid = invalid | (k_off + k_pos > q_off + q_pos)
-    scores = jnp.where(invalid, NEG_INF, scores)
-
-    m_prev = m_ref[:, :1]                             # [bq, 1]
-    l_prev = l_ref[:, :1]
-    m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
-    correction = jnp.exp(m_prev - m_new)
-    p = jnp.where(scores <= NEG_INF * 0.5, 0.0, jnp.exp(scores - m_new))
-    l_ref[:] = jnp.broadcast_to(l_prev * correction + p.sum(axis=-1, keepdims=True),
-                                l_ref.shape)
-    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-    acc_ref[:] = acc_ref[:] * correction + jax.lax.dot_general(
-        p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        # Keys at or before the first query row are visible to every row; keys
+        # after the last row to none.
+        n_plain = xp.minimum(n_plain, xp.clip(q_lo - k_lo + 1, 0, bk) // sub)
+        n_need = xp.minimum(
+            n_need, (xp.clip(q_lo + bq - k_lo, 0, bk) + sub - 1) // sub)
+    return n_plain, n_need
 
 
-def _flash_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
-                  l_ref, *,
-                  lk: int, q_block: int, k_block: int, causal: bool, scale: float):
+def _attend_block(q_ref, k_ref, v_ref, state, *, q_lo, k_lo, valid, sub: int,
+                  causal: bool, scale: float, guard_empty_rows: bool):
+    """Online-softmax update of ``state = (m [1, bq], l [1, bq], acc [d, bq])``
+    against the VMEM-resident K/V block — the single definition shared by the
+    plain forward kernel and the carry variant.
+
+    The score tile is held TRANSPOSED, ``[sub keys, bq queries]``: queries run
+    along the lanes, so the row maximum and the row sum reduce along sublanes
+    (elementwise across vregs, one short reduce at the end) instead of across
+    the 128 lanes of every vreg row, and ``m``, ``l`` and the correction are
+    lane-dense ``[1, bq]`` vectors instead of ``[bq, 1]`` columns that fill a
+    vreg per 8 queries (module header: that, not the masking, was the
+    forward's time). The block is walked in tiles of ``sub`` keys with the
+    state carried as values, and each tile runs the body of its class
+    (:func:`_tile_counts`). Matmul operands stay in the input dtype (bf16 runs
+    the MXU at full rate); accumulation and softmax arithmetic are f32.
+
+    ``q_lo`` / ``k_lo``: global positions of the block's first query and key;
+    ``valid``: real keys from the block's first on, None where the K/V rows
+    hold no padding. ``guard_empty_rows``: a query may have met no valid key yet (ring offsets,
+    a carry that starts at NEG_INF), so a masked score must not read as
+    ``exp(NEG_INF - NEG_INF) = 1``. With zero offsets every query sees key 0
+    in its first tile and the guard is dead."""
+    q = q_ref[0]                                      # [bq, d]
+    bq, bk = q.shape[0], k_ref.shape[1]
+    # scale once per q block where that is exact (a power of two, as at
+    # d = 16, 64, 256), else on the score tile as before.
+    prescale = math.frexp(scale)[0] == 0.5
+    if prescale:
+        q = q * jnp.asarray(scale, q.dtype)
+    n_plain, n_need = _tile_counts(q_lo, k_lo, valid, bq, bk, sub, causal)
+
+    def tile(j, state, masked: bool):
+        m_prev, l_prev, acc = state
+        if sub == bk:
+            start = 0                                 # the one tile, whatever j
+        else:
+            start = j * sub
+            if not _is_static(start):
+                start = pl.multiple_of(start, sub)
+        k_t = k_ref[0, pl.ds(start, sub), :]          # [sub, d]
+        v_t = v_ref[0, pl.ds(start, sub), :]
+        scores = jax.lax.dot_general(
+            k_t, q, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)       # [sub, bq]
+        if not prescale:
+            scores = scale * scores
+        if masked:
+            key = jax.lax.broadcasted_iota(jnp.int32, (sub, bq), 0)
+            invalid = None
+            if valid is not None:
+                invalid = key >= valid - start
+            if causal:
+                query = jax.lax.broadcasted_iota(jnp.int32, (sub, bq), 1)
+                above = key - query > q_lo - k_lo - start
+                invalid = above if invalid is None else invalid | above
+            scores = jnp.where(invalid, NEG_INF, scores)
+        m_new = jnp.maximum(m_prev, scores.max(axis=0, keepdims=True))
+        correction = jnp.exp(m_prev - m_new)
+        p = jnp.exp(scores - m_new)
+        if masked and guard_empty_rows:
+            p = jnp.where(scores <= NEG_INF * 0.5, 0.0, p)
+        l_new = l_prev * correction + p.sum(axis=0, keepdims=True)
+        acc = acc * correction + jax.lax.dot_general(
+            v_t, p.astype(v_t.dtype), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)       # [d, bq]
+        return m_new, l_new, acc
+
+    state = _loop(0, n_plain, lambda j, s: tile(j, s, False), state)
+    return _loop(n_plain, n_need, lambda j, s: tile(j, s, True), state)
+
+
+def _valid_keys(lk: int, k_start, bk: int):
+    """Real keys from a K/V block's first on, None where no block is padded."""
+    return lk - k_start if lk % bk else None
+
+
+def _loop(lo, hi, body, state):
+    """``fori_loop`` that emits nothing for a range known to be empty and no
+    loop for a single known tile."""
+    if _is_static(lo) and _is_static(hi):
+        if hi <= lo:
+            return state
+        if hi - lo == 1:
+            return body(int(lo), state)
+    return jax.lax.fori_loop(lo, hi, body, state)
+
+
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
+                  lk: int, sub: int, causal: bool, scale: float):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     n_k = pl.num_programs(2)
-    # Global offsets of the first local query/key (SMEM scalars): ring attention
-    # passes the ring-shifted key offset so causal masking stays globally correct;
-    # the plain path passes zeros.
-    q_off = off_ref[0]
-    k_off = off_ref[1]
+    bq, bk = q_ref.shape[1], k_ref.shape[1]
 
     @pl.when(ki == 0)
     def _init():
@@ -93,32 +203,64 @@ def _flash_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    q_start = qi * q_block
-    k_start = ki * k_block
-    # Causal: skip blocks strictly above the (global) diagonal.
-    needed = (k_off + k_start <= q_off + q_start + q_block - 1) if causal else True
+    q_start = qi * bq
+    k_start = ki * bk
+    # Causal: skip K/V blocks strictly above the diagonal.
+    needed = (k_start <= q_start + bq - 1) if causal else True
 
     @pl.when(needed)
     def _step():
-        _online_softmax_step(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref,
-                             q_start, k_start, q_off, k_off, lk, causal, scale)
+        m_ref[:], l_ref[:], acc_ref[:] = _attend_block(
+            q_ref, k_ref, v_ref, (m_ref[:], l_ref[:], acc_ref[:]),
+            q_lo=q_start, k_lo=k_start, valid=_valid_keys(lk, k_start, bk),
+            sub=sub, causal=causal, scale=scale, guard_empty_rows=False)
 
     @pl.when(ki == n_k - 1)
     def _finish():
-        l_fin = l_ref[:, :1]
-        o_ref[0] = (acc_ref[:] / jnp.maximum(l_fin, 1e-30)).astype(o_ref.dtype)
+        l_fin = jnp.maximum(l_ref[:], 1e-30)                      # [1, bq]
+        o_ref[0] = (acc_ref[:] / l_fin).T.astype(o_ref.dtype)     # [bq, d]
         # Per-row logsumexp residual for the backward pass. Padding query rows get
         # a finite lse too (zero-padded q still attends real keys); the backward is
         # safe for them ONLY because dO is zero-padded there — do not rely on lse
         # being NEG_INF for masked rows. Layout: [bh, n_q, bq] with the whole
         # (n_q, bq) plane as one resident block (TPU tiling forbids a [1, bq]
         # block); each q-block writes its row.
-        lse = m_ref[:, 0] + jnp.log(jnp.maximum(l_ref[:, 0], 1e-30))
-        lse_ref[0, qi, :] = lse
+        lse_ref[0, pl.ds(qi, 1), :] = m_ref[:] + jnp.log(l_fin)
 
 
-def _flash_forward(q, k, v, causal: bool, q_block: int, k_block: int,
-                   interpret: bool):
+def _forward_blocks(lq: int, lk: int, d: int, itemsize: int, q_block, k_block):
+    """``(bq, bk, sub)`` of the forward: q rows and K/V rows a grid step, keys
+    a score tile. An explicit ``q_block`` / ``k_block`` is the grid's block as
+    before. Left to the shape (None), the choice is the one the chip timings
+    in the module header justify: the backward's q block (so the lse plane is
+    its layout already), and K/V resident for a whole (batch, head) while one
+    of them is at most ``_RESIDENT_KV_BYTES`` — one grid step a q block, no
+    step and no copy for a block above the diagonal — rounded up to whole key
+    tiles (the tail is padding, masked like any ragged tail)."""
+    bq = min(q_block or DEFAULT_Q_BLOCK, lq)
+    if k_block is None:
+        bk = lk if lk <= _KEY_TILE else pl.cdiv(lk, _KEY_TILE) * _KEY_TILE
+        if bk * d * itemsize > _RESIDENT_KV_BYTES:
+            bk = _STREAM_K_BLOCK
+    else:
+        bk = min(k_block, lk)
+    return bq, bk, _sub_tile(bk)
+
+
+def _count_tiles(lq: int, lk: int, bq: int, bk: int, sub: int, causal: bool):
+    """(plain, masked, skipped) score tiles of one (batch, head) under zero
+    offsets, at the granularity the body runs them: [bq, sub]."""
+    n_q, n_k = pl.cdiv(lq, bq), pl.cdiv(lk, bk)
+    plain = need = 0
+    for qi in range(n_q):
+        for ki in range(n_k):
+            a, b = _tile_counts(qi * bq, ki * bk, _valid_keys(lk, ki * bk, bk),
+                                bq, bk, sub, causal)
+            plain, need = plain + int(a), need + int(b)
+    return plain, need - plain, n_q * n_k * (bk // sub) - need
+
+
+def _flash_forward(q, k, v, causal: bool, q_block, k_block, interpret: bool):
     """Returns (out [B, Lq, H, D], lse [B*H, n_q, bq] f32)."""
     b, lq, h, d = q.shape
     lk = k.shape[1]
@@ -129,27 +271,37 @@ def _flash_forward(q, k, v, causal: bool, q_block: int, k_block: int,
     kf = k.transpose(0, 2, 1, 3).reshape(b * h, lk, d)
     vf = v.transpose(0, 2, 1, 3).reshape(b * h, lk, d)
 
-    bq = min(q_block, lq)
+    bq, bk, sub = _forward_blocks(lq, lk, d, q.dtype.itemsize, q_block, k_block)
     n_q = pl.cdiv(lq, bq)
     if n_q * bq - lq:
         qf = jnp.pad(qf, ((0, 0), (0, n_q * bq - lq), (0, 0)))
-    bk = min(k_block, lk)
     n_k = pl.cdiv(lk, bk)
     if n_k * bk - lk:
         kf = jnp.pad(kf, ((0, 0), (0, n_k * bk - lk), (0, 0)))
         vf = jnp.pad(vf, ((0, 0), (0, n_k * bk - lk), (0, 0)))
 
-    kernel = functools.partial(_flash_kernel, lk=lk, q_block=bq, k_block=bk,
-                               causal=causal, scale=scale)
-    offs = jnp.zeros((2,), jnp.int32)
+    plain, masked, skipped = _count_tiles(lq, lk, bq, bk, sub, causal)
+    telemetry.gauge("flash.fwd.tiles_plain").set(plain)
+    telemetry.gauge("flash.fwd.tiles_masked").set(masked)
+    telemetry.gauge("flash.fwd.tiles_skipped").set(skipped)
+
+    kernel = functools.partial(_flash_kernel, lk=lk, sub=sub, causal=causal,
+                               scale=scale)
+    if causal and n_k > 1:
+        # A K/V block above the diagonal names the last one below it again, so
+        # its (skipped) grid step copies nothing in.
+        def kv_index(bh, i, j):
+            return bh, jnp.minimum(j, ((i + 1) * bq - 1) // bk), 0
+    else:
+        def kv_index(bh, i, j):
+            return bh, j, 0
     out, lse = named_pallas_call(
         "flash_fwd", kernel,
         grid=(b * h, n_q, n_k),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, j, 0)),
+            pl.BlockSpec((1, bk, d), kv_index),
+            pl.BlockSpec((1, bk, d), kv_index),
         ],
         out_specs=(
             pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
@@ -167,12 +319,14 @@ def _flash_forward(q, k, v, causal: bool, q_block: int, k_block: int,
             jax.ShapeDtypeStruct((b * h, n_q, bq), jnp.float32),
         ),
         scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),       # acc
-            pltpu.VMEM((bq, _LANES), jnp.float32),  # running max
-            pltpu.VMEM((bq, _LANES), jnp.float32),  # running denominator
+            pltpu.VMEM((d, bq), jnp.float32),   # acc, transposed
+            pltpu.VMEM((1, bq), jnp.float32),   # running max
+            pltpu.VMEM((1, bq), jnp.float32),   # running denominator
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(offs, qf, kf, vf)
+    )(qf, kf, vf)
 
     out = out[:, :lq, :].reshape(b, h, lq, d).transpose(0, 2, 1, 3)
     return out, lse
@@ -388,8 +542,8 @@ def _flash_backward(q, k, v, o, lse, g, causal, q_block, k_block, interpret,
 def _flash_carry_kernel(off_ref, q_ref, k_ref, v_ref, acc_in_ref, m_in_ref,
                         l_in_ref, acc_out_ref, m_out_ref, l_out_ref,
                         acc_sc, m_sc, l_sc, *,
-                        lk: int, q_block: int, k_block: int, causal: bool,
-                        scale: float):
+                        lk: int, q_block: int, k_block: int, sub: int,
+                        causal: bool, scale: float):
     """Forward kernel with online-softmax carry in/out (ring attention's local
     step): identical block math to :func:`_flash_kernel`, but the (acc, m, l)
     state initializes from the carry inputs and is emitted UNNORMALIZED so
@@ -403,9 +557,9 @@ def _flash_carry_kernel(off_ref, q_ref, k_ref, v_ref, acc_in_ref, m_in_ref,
 
     @pl.when(ki == 0)
     def _init():
-        acc_sc[:] = acc_in_ref[0]
-        m_sc[:] = jnp.broadcast_to(m_in_ref[0, qi, :][:, None], m_sc.shape)
-        l_sc[:] = jnp.broadcast_to(l_in_ref[0, qi, :][:, None], l_sc.shape)
+        acc_sc[:] = acc_in_ref[0].T
+        m_sc[:] = m_in_ref[0, pl.ds(qi, 1), :]
+        l_sc[:] = l_in_ref[0, pl.ds(qi, 1), :]
 
     q_start = qi * q_block
     k_start = ki * k_block
@@ -413,14 +567,17 @@ def _flash_carry_kernel(off_ref, q_ref, k_ref, v_ref, acc_in_ref, m_in_ref,
 
     @pl.when(needed)
     def _step():
-        _online_softmax_step(q_ref, k_ref, v_ref, acc_sc, m_sc, l_sc,
-                             q_start, k_start, q_off, k_off, lk, causal, scale)
+        m_sc[:], l_sc[:], acc_sc[:] = _attend_block(
+            q_ref, k_ref, v_ref, (m_sc[:], l_sc[:], acc_sc[:]),
+            q_lo=q_off + q_start, k_lo=k_off + k_start,
+            valid=_valid_keys(lk, k_start, k_block), sub=sub, causal=causal,
+            scale=scale, guard_empty_rows=True)
 
     @pl.when(ki == n_k - 1)
     def _finish():
-        acc_out_ref[0] = acc_sc[:]
-        m_out_ref[0, qi, :] = m_sc[:, 0]
-        l_out_ref[0, qi, :] = l_sc[:, 0]
+        acc_out_ref[0] = acc_sc[:].T
+        m_out_ref[0, pl.ds(qi, 1), :] = m_sc[:]
+        l_out_ref[0, pl.ds(qi, 1), :] = l_sc[:]
 
 
 def flash_attention_with_carry(q, k, v, carry=None, *, causal: bool = True,
@@ -476,7 +633,7 @@ def flash_attention_with_carry(q, k, v, carry=None, *, causal: bool = True,
     offs = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                       jnp.asarray(k_offset, jnp.int32)])
     kernel = functools.partial(_flash_carry_kernel, lk=lk, q_block=bq, k_block=bk,
-                               causal=causal, scale=scale)
+                               sub=_sub_tile(bk), causal=causal, scale=scale)
     row_plane = pl.BlockSpec((1, n_q, bq), lambda bh, i, j: (bh, 0, 0))
     acc, m, l = named_pallas_call(
         "flash_carry", kernel,
@@ -501,9 +658,9 @@ def flash_attention_with_carry(q, k, v, carry=None, *, causal: bool = True,
             jax.ShapeDtypeStruct((b * h, n_q, bq), jnp.float32),
         ),
         scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),
-            pltpu.VMEM((bq, _LANES), jnp.float32),
-            pltpu.VMEM((bq, _LANES), jnp.float32),
+            pltpu.VMEM((d, bq), jnp.float32),
+            pltpu.VMEM((1, bq), jnp.float32),
+            pltpu.VMEM((1, bq), jnp.float32),
         ],
         interpret=interpret,
     )(offs, qf, kf, vf, acc0, m0, l0)
@@ -541,7 +698,8 @@ def _flash_fwd(q, k, v, causal, q_block, k_block):
 
 def _flash_bwd(causal, q_block, k_block, residuals, g):
     q, k, v, o, lse = residuals
-    return _flash_backward(q, k, v, o, lse, g, causal, q_block, k_block,
+    return _flash_backward(q, k, v, o, lse, g, causal,
+                           q_block or DEFAULT_Q_BLOCK, k_block or DEFAULT_K_BLOCK,
                            _use_interpret())
 
 
@@ -549,9 +707,13 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                    causal: bool = True, q_block: int = DEFAULT_Q_BLOCK,
-                    k_block: int = DEFAULT_K_BLOCK) -> jax.Array:
+                    causal: bool = True, q_block: Optional[int] = None,
+                    k_block: Optional[int] = None) -> jax.Array:
     """Flash attention over [B, L, H, D] tensors (pallas forward and backward).
+
+    ``q_block`` / ``k_block`` left at None: the forward picks its blocks from
+    the shape (:func:`_forward_blocks`), the backward runs at
+    ``DEFAULT_Q_BLOCK`` x ``DEFAULT_K_BLOCK``.
 
     Under a mesh of several devices the kernels run per device on its share
     of the batch (:func:`autodist_tpu.parallel.mesh.per_device`)."""

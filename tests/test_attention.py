@@ -106,6 +106,78 @@ def test_flash_gradients_flow():
         _close(a, b, atol=3e-4, mxu=0.05)
 
 
+# (length, causal, q_block, k_block): which classes of score tile the forward
+# meets — plain (below the diagonal, no padded key), masked (crossed by the
+# diagonal or holding the ragged tail), skipped (above the diagonal or all
+# padding). A K/V block is walked in key tiles of 512/256/128 where one divides it.
+_BLOCK_CLASS_CASES = {
+    "causal-aligned": (64, True, 32, 32),            # masked diagonal, plain below, skipped above
+    "noncausal-aligned": (64, False, 32, 32),        # plain only
+    "causal-ragged": (60, True, 32, 32),             # the tail and the diagonal in one tile
+    "noncausal-ragged": (60, False, 32, 32),         # masked by the tail alone
+    "q-block-below-k-block": (128, True, 32, 64),
+    "q-block-above-k-block": (128, True, 64, 32),
+    "tiles-inside-a-resident-block": (384, True, 128, 384),   # 3 plain, 3 masked, 3 skipped tiles of 128 keys
+    "ragged-inside-a-resident-block": (300, False, 128, 256),  # second block: 44 real keys, then a tile of padding
+    "blocks-from-the-shape": (640, True, None, None),          # 512-row q blocks, K/V resident, padded to 1,024
+}
+
+
+@pytest.mark.parametrize("case", list(_BLOCK_CLASS_CASES))
+def test_flash_block_classes_match_reference(case):
+    """Forward and gradients against plain softmax attention, one case per
+    mix of tile classes."""
+    length, causal, q_block, k_block = _BLOCK_CLASS_CASES[case]
+    rng = np.random.RandomState(7)
+    q, k, v = (jnp.asarray(rng.randn(1, length, 2, 16), jnp.float32)
+               for _ in range(3))
+    weights = jnp.asarray(rng.randn(1, length, 2, 16), jnp.float32)
+
+    def loss(attend):
+        return lambda q, k, v: jnp.sum(attend(q, k, v) * weights)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, q_block=q_block,
+                               k_block=k_block)
+
+    def plain(q, k, v):
+        return _reference(q, k, v, causal)
+
+    _close(flash(q, k, v), plain(q, k, v), atol=2e-5)
+    got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(plain), argnums=(0, 1, 2))(q, k, v)
+    for a, b, name in zip(got, want, "qkv"):
+        _close(a, b, atol=3e-4, mxu=0.05, err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("length,causal,want", [
+    (1024, True, (1, 2, 1)),    # 512-row q blocks x 512-key tiles: the diagonal crosses two
+    (300, True, (0, 1, 0)),     # ragged, one block, one tile
+    (1024, False, (4, 0, 0)),
+    (600, False, (2, 2, 0)),    # K/V padded to 1,024: each q block's second tile holds the tail
+    (2048, True, (6, 4, 6)),
+], ids=["L1024-causal", "L300-ragged", "L1024-noncausal", "L600-noncausal-ragged",
+        "L2048-causal"])
+def test_flash_forward_tile_gauges_equal_hand_count(length, causal, want):
+    """``flash.fwd.tiles_*``: score tiles of one (batch, head) by class, set
+    when the forward is traced."""
+    from autodist_tpu import telemetry
+
+    qkv = jax.ShapeDtypeStruct((1, length, 2, 64), jnp.bfloat16)
+    jax.eval_shape(lambda q, k, v: flash_attention(q, k, v, causal=causal),
+                   qkv, qkv, qkv)
+    got = tuple(telemetry.gauge(f"flash.fwd.tiles_{name}").value
+                for name in ("plain", "masked", "skipped"))
+    assert got == want
+
+
+def test_kernel_names_unchanged():
+    from autodist_tpu.ops import named_call
+    assert named_call.KERNEL_NAMES == (
+        "flash_fwd", "flash_bwd_dkv", "flash_bwd_dq", "flash_carry",
+        "xent_fwd", "xent_bwd_dh", "xent_bwd_dw")
+
+
 @_NEEDS_MESH
 @pytest.mark.parametrize("causal", [True, False])
 def test_ring_attention_matches_single_device(causal):
@@ -204,6 +276,45 @@ def test_flash_carry_matches_blockwise_carry():
                                     q_block=16, k_block=16)
     for a, b_, name in zip(fl, bw, ("acc", "m", "l")):
         _close(a, b_, atol=1e-5, rtol=1e-5, mxu=0.05, err_msg=name)
+
+
+@pytest.mark.parametrize("q_offset,k_offset,blocks", [
+    (256, 0, 128),      # the shard lies wholly before the queries: plain tiles only
+    (0, 256, 128),      # wholly after: every tile skipped, the carry passes through
+    (256, 256, 128),    # on the diagonal: plain, masked and skipped tiles
+    (192, 64, 64),      # crossed, the offsets off the block grid
+    (128, 0, 256),      # one 256-key block a step, the diagonal never reached
+], ids=["visible", "masked", "diagonal", "crossed-unaligned", "one-block"])
+def test_flash_carry_block_classes_match_blockwise(q_offset, k_offset, blocks):
+    """The ring step under traced offsets: the tile classes are decided at run
+    time from the SMEM scalars. Two chained steps (an own-shard step first, so
+    the carry is not at its NEG_INF start) against the pure-JAX carry."""
+    from autodist_tpu.ops.blockwise_attention import blockwise_attention_with_carry
+    from autodist_tpu.ops.flash_attention import flash_attention_with_carry
+
+    rng = np.random.RandomState(11)
+    length = 256
+    q, k1, v1, k2, v2 = (jnp.asarray(rng.randn(1, length, 2, 16), jnp.float32)
+                         for _ in range(5))
+
+    @jax.jit
+    def flash(q_off, k_off):
+        carry = flash_attention_with_carry(
+            q, k1, v1, None, causal=True, q_offset=q_off, k_offset=q_off,
+            q_block=blocks, k_block=blocks)
+        return flash_attention_with_carry(
+            q, k2, v2, carry, causal=True, q_offset=q_off, k_offset=k_off,
+            q_block=blocks, k_block=blocks)
+
+    want = blockwise_attention_with_carry(
+        q, k1, v1, None, causal=True, block_size=64, q_offset=q_offset,
+        k_offset=q_offset)
+    want = blockwise_attention_with_carry(
+        q, k2, v2, want, causal=True, block_size=64, q_offset=q_offset,
+        k_offset=k_offset)
+    got = flash(jnp.int32(q_offset), jnp.int32(k_offset))
+    for a, b_, name in zip(got, want, ("acc", "m", "l")):
+        _close(a, b_, atol=2e-5, rtol=1e-5, mxu=0.05, err_msg=name)
 
 
 @_NEEDS_MESH

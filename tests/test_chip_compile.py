@@ -63,10 +63,15 @@ def _compiled_text(fn, chip, *shapes) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("length,depth", [(2048, 64), (300, 64), (2048, 128)],
-                         ids=["L2048-D64", "ragged-L300", "D128"])
-def test_flash_attention_fwd_bwd_compiles(chip, length, depth):
-    qkv = ((4, length, 8, depth), jnp.bfloat16)
+@pytest.mark.parametrize("batch,length,heads,depth", [
+    (4, 2048, 8, 64), (4, 300, 8, 64), (4, 2048, 8, 128),
+    (8, 1024, 16, 64),      # gpt2m-pretrain-1k / gpt2m-dp4-sync, a chip and call
+    (2, 1100, 8, 64),       # K/V resident and padded to whole 512-key tiles
+    (1, 16384, 8, 64),      # past the resident limit: K/V streamed in blocks
+], ids=["L2048-D64", "ragged-L300", "D128", "cell-8x1024x16x64", "ragged-L1100",
+        "streamed-L16384"])
+def test_flash_attention_fwd_bwd_compiles(chip, batch, length, heads, depth):
+    qkv = ((batch, length, heads, depth), jnp.bfloat16)
 
     def loss(q, k, v):
         return fa.flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
