@@ -1,9 +1,45 @@
-"""Shared loss helpers for the model zoo."""
+"""Shared layers and loss helpers for the model zoo."""
 
-from typing import Callable, Optional
+from typing import Any, Callable, Optional
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
+
+
+class RMSNorm(nn.Module):
+    """``x / sqrt(mean(x^2) + eps) * scale`` over the last axis, no bias and
+    no mean subtraction; the arithmetic is float32 whatever comes in, the
+    result is cast to ``dtype`` (float32 for a reader that must not see the
+    activation dtype's rounding, an MoE router for one)."""
+    eps: float = 1e-5
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           jnp.float32)
+        x = x.astype(jnp.float32)
+        rms = jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+                            + self.eps)
+        return (x * rms * scale).astype(self.dtype)
+
+
+def rope(x, positions, theta: float = 10000.0):
+    """Rotary position embedding, rotate-half form over the whole head dim.
+
+    x: ``[..., L, H, D]`` (D even); positions: ``[L]``. The pair
+    ``(x[i], x[i + D/2])`` is rotated by ``position * theta^(-2i/D)``; the
+    rotation is computed in float32 and cast back to ``x.dtype``."""
+    d = x.shape[-1]
+    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angles = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    cos = jnp.concatenate([jnp.cos(angles)] * 2, axis=-1)[:, None, :]
+    sin = jnp.concatenate([jnp.sin(angles)] * 2, axis=-1)[:, None, :]
+    x32 = x.astype(jnp.float32)
+    x1, x2 = x32[..., : d // 2], x32[..., d // 2:]
+    rotated = jnp.concatenate([-x2, x1], axis=-1)
+    return (x32 * cos + rotated * sin).astype(x.dtype)
 
 
 def jit_init(model, *args, rng: Optional[jax.Array] = None):

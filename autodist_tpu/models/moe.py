@@ -13,10 +13,18 @@ GEMMs.
 Top-1 (Switch) routing keeps shapes static: tokens beyond an expert's capacity are
 dropped (their combine weight is zero, so they pass through the residual only), the
 standard TPU-friendly trade.
+
+Beside it, the dropless top-k path (:func:`topk_route`, :func:`routed_experts`,
+:class:`RoutedFFN`; ``models/olmoe.py`` stacks it): every token x slot row is
+sorted by expert into ragged groups and the experts run as grouped matmuls
+(``ops/grouped_matmul.py``) over exactly those rows. Shapes stay static because the
+row count is (tokens x k); only the group boundaries are run-time data. No
+``[tokens, experts, capacity]`` tensor exists and no token is dropped or padded.
 """
 
 import dataclasses
-from typing import Any, Callable, Optional, Tuple
+import functools
+from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -201,3 +209,153 @@ def synthetic_batch(config: MoETransformerLMConfig, batch_size: int, seq_len: in
     rng = np.random.RandomState(seed)
     return {"tokens": rng.randint(0, config.vocab_size,
                                   size=(batch_size, seq_len + 1)).astype(np.int32)}
+
+
+# ----------------------------------------------------- dropless top-k routing
+
+class Route(NamedTuple):
+    """Where every (token, slot) row goes. ``T`` tokens, ``k`` slots each."""
+    indices: jax.Array       # [T, k] int32 expert of each slot, best first
+    weights: jax.Array       # [T, k] the router's probabilities as they are
+    group_sizes: jax.Array   # [E] int32 rows each expert receives; sums to T*k
+    perm: jax.Array          # [T*k] sorted row i holds flat slot perm[i]
+    inv_perm: jax.Array      # [T*k] flat slot j sits at sorted row inv_perm[j]
+
+
+def topk_route(probs: jax.Array, k: int) -> Route:
+    """The ``k`` largest router probabilities of each token and the sort of the
+    ``T*k`` (token, slot) rows by expert. Dropless: every row lands in its
+    expert's group (``group_sizes`` sums to ``T*k``), the weights are the
+    softmax values themselves (not renormalised over the chosen), and the sort
+    is stable, so a group holds its rows in token order."""
+    n_tokens, n_experts = probs.shape
+    weights, indices = jax.lax.top_k(probs, k)
+    flat = indices.reshape(-1).astype(jnp.int32)
+    perm = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    rows = jnp.arange(n_tokens * k, dtype=jnp.int32)
+    inv_perm = jnp.zeros_like(perm).at[perm].set(rows, unique_indices=True)
+    ends = jnp.searchsorted(flat[perm], jnp.arange(n_experts, dtype=jnp.int32),
+                            side="right").astype(jnp.int32)
+    group_sizes = jnp.diff(ends, prepend=0)
+    return Route(indices.astype(jnp.int32), weights, group_sizes, perm,
+                 inv_perm)
+
+
+@jax.custom_vjp
+def _permute_rows(x, perm, inv_perm):
+    """``out[i] = x[perm[i]]`` for a permutation: its transpose is the gather
+    by the inverse, not the scatter autodiff would write."""
+    return jnp.take(x, perm, axis=0)
+
+
+def _permute_rows_fwd(x, perm, inv_perm):
+    return jnp.take(x, perm, axis=0), (inv_perm,)
+
+
+def _permute_rows_bwd(residuals, g):
+    (inv_perm,) = residuals
+    return jnp.take(g, inv_perm, axis=0), None, None
+
+
+_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch_rows(x, perm, inv_perm, k):
+    """``[T, d] -> [T*k, d]``: sorted row ``i`` is token ``perm[i] // k``. The
+    transpose un-sorts by the inverse and sums a token's ``k`` rows: two
+    gathers and a reduction where autodiff would scatter-add."""
+    return jnp.take(x, perm // k, axis=0)
+
+
+def _dispatch_rows_fwd(x, perm, inv_perm, k):
+    return jnp.take(x, perm // k, axis=0), (inv_perm,)
+
+
+def _dispatch_rows_bwd(k, residuals, g):
+    (inv_perm,) = residuals
+    unsorted = jnp.take(g, inv_perm, axis=0)
+    dx = unsorted.reshape(-1, k, g.shape[-1]).astype(jnp.float32).sum(axis=1)
+    return dx.astype(g.dtype), None, None
+
+
+_dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
+
+
+def routed_experts(x, probs, gate, up, down, *, top_k: int):
+    """The local computation of a routed gated-SiLU FFN, one function of the
+    tokens and the expert bank it is given: top-k and sort, the grouped
+    products over the experts held, the weighted un-sort.
+
+    x: ``[T, d]``; probs: ``[T, E]`` float32 router probabilities; gate, up:
+    ``[E, d, w]``; down: ``[E, w, d]``. Returns ``(y [T, d] float32,
+    group_sizes [E])``: ``y[t] = sum_slots p * down_e(silu(gate_e x[t]) * up_e
+    x[t])``, the weighted sum accumulated in float32 and left so."""
+    from autodist_tpu import telemetry
+    from autodist_tpu.ops.grouped_matmul import gmm
+    n_tokens, d = x.shape
+    telemetry.gauge("moe.experts").set(int(gate.shape[0]))
+    telemetry.gauge("moe.top_k").set(int(top_k))
+    telemetry.gauge("moe.rows_per_call").set(int(n_tokens * top_k))
+    with jax.named_scope("moe.route"):
+        route = topk_route(probs, top_k)
+    with jax.named_scope("moe.dispatch"):
+        rows = _dispatch_rows(x, route.perm, route.inv_perm, top_k)
+    with jax.named_scope("moe.experts"):
+        hidden = (nn.silu(gmm(rows, gate, route.group_sizes))
+                  * gmm(rows, up, route.group_sizes))
+        out = gmm(hidden, down, route.group_sizes)
+    with jax.named_scope("moe.combine"):
+        out = _permute_rows(out, route.inv_perm, route.perm)
+        y = jnp.einsum("tk,tkd->td", route.weights,
+                       out.reshape(n_tokens, top_k, d),
+                       preferred_element_type=jnp.float32)
+    return y, route.group_sizes
+
+
+class RoutedFFN(nn.Module):
+    """Dropless top-k mixture of gated-SiLU experts.
+
+    ``__call__(h)`` takes the block's normalised input in float32 ``[B, S, d]``:
+    the router product and softmax read it as it is (at ``HIGHEST`` precision,
+    so the choice of experts does not hang on the activation dtype's rounding
+    of ``h``), the experts read it cast to ``dtype``. Returns ``(y, aux)`` with
+    ``y`` float32 (the weighted sum's accumulator, for a float32 residual) and
+    ``aux`` the layer's load-balancing loss ``E * sum_e f_e P_e`` (``f_e`` the
+    rows expert ``e`` received over the tokens, ``P_e`` its mean probability)
+    and router z-loss ``mean(logsumexp(logits)^2)``."""
+    n_experts: int
+    top_k: int
+    d_expert: int
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, h):
+        from autodist_tpu.parallel.mesh import per_device
+        b, s, d = h.shape
+        init = nn.initializers.normal(0.02)
+        router = self.param("router", init, (d, self.n_experts), jnp.float32)
+        bank = [self.param(name, init, shape, jnp.float32) for name, shape in (
+            ("gate", (self.n_experts, d, self.d_expert)),
+            ("up", (self.n_experts, d, self.d_expert)),
+            ("down", (self.n_experts, self.d_expert, d)))]
+        if self.is_initializing():
+            # Shapes are all that init needs: no kernel is compiled for the
+            # handful of positions it runs on.
+            zero = jnp.zeros((), jnp.float32)
+            return (jnp.zeros((b, s, d), jnp.float32),
+                    {"load_balance": zero, "router_z": zero})
+        tokens = h.reshape(b * s, d)
+        logits = jnp.dot(tokens.astype(jnp.float32), router,
+                         precision=jax.lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(logits, axis=-1)
+        y, sizes = per_device(
+            functools.partial(routed_experts, top_k=self.top_k),
+            (tokens.astype(self.dtype), probs, *bank),
+            batched=(True, True, False, False, False))
+        # Per device the sizes are of its own tokens: [devices * E] here.
+        sizes = sizes.reshape(-1, self.n_experts).sum(axis=0)
+        load = jax.lax.stop_gradient(sizes.astype(jnp.float32)) / (b * s)
+        aux = {"load_balance": self.n_experts * jnp.sum(load * probs.mean(axis=0)),
+               "router_z": jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))}
+        return y.reshape(b, s, d), aux

@@ -175,7 +175,8 @@ def test_kernel_names_unchanged():
     from autodist_tpu.ops import named_call
     assert named_call.KERNEL_NAMES == (
         "flash_fwd", "flash_bwd_dkv", "flash_bwd_dq", "flash_carry",
-        "xent_fwd", "xent_bwd_dh", "xent_bwd_dw")
+        "xent_fwd", "xent_bwd_dh", "xent_bwd_dw",
+        "moe_gmm_fwd", "moe_gmm_bwd_dx", "moe_gmm_bwd_dw")
 
 
 @_NEEDS_MESH

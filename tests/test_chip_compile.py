@@ -68,8 +68,9 @@ def _compiled_text(fn, chip, *shapes) -> str:
     (8, 1024, 16, 64),      # gpt2m-pretrain-1k / gpt2m-dp4-sync, a chip and call
     (2, 1100, 8, 64),       # K/V resident and padded to whole 512-key tiles
     (1, 16384, 8, 64),      # past the resident limit: K/V streamed in blocks
+    (4, 4096, 16, 128),     # olmoe-pretrain-4k: K/V of a head exactly the resident 1 MB
 ], ids=["L2048-D64", "ragged-L300", "D128", "cell-8x1024x16x64", "ragged-L1100",
-        "streamed-L16384"])
+        "streamed-L16384", "olmoe-4x4096x16x128"])
 def test_flash_attention_fwd_bwd_compiles(chip, batch, length, heads, depth):
     qkv = ((batch, length, heads, depth), jnp.bfloat16)
 
@@ -110,8 +111,9 @@ _XENT_ROWS = 2048
     # in-kernel temporaries.
     (1024, 32_000, "dv", jnp.bfloat16, True),
     (1024, 50_257, "vd", jnp.bfloat16, True),
+    (2048, 50_304, "dv", jnp.float32, True),     # olmoe-pretrain-4k's untied head
 ], ids=["flagship-d512-dv-f32", "d1024-vd-f32", "d1024-dv-bf16",
-        "d1024-vd-bf16-V50257"])
+        "d1024-vd-bf16-V50257", "olmoe-d2048-dv-f32"])
 def test_fused_xent_fwd_bwd_compiles(chip, d, vocab, layout, table_dtype,
                                      shrinks):
     table = (vocab, d) if layout == "vd" else (d, vocab)
@@ -126,6 +128,24 @@ def test_fused_xent_fwd_bwd_compiles(chip, d, vocab, layout, table_dtype,
                           ((_XENT_ROWS, d), jnp.bfloat16), (table, table_dtype),
                           ((_XENT_ROWS,), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1024), (1024, 2048)],
+                         ids=["gate-up-2048x1024", "down-1024x2048"])
+def test_grouped_matmul_fwd_bwd_compiles_at_the_olmoe_cell_shapes(chip, k, n):
+    """131,072 routed rows (16,384 tokens x top-8) in 64 groups: the forward,
+    dX and dW kernels, each a Mosaic call under its own name."""
+    from autodist_tpu.ops.grouped_matmul import gmm
+
+    def loss(x, w, group_sizes):
+        return gmm(x, w, group_sizes).astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1)), chip,
+                          ((131_072, k), jnp.bfloat16),
+                          ((64, k, n), jnp.float32), ((64,), jnp.int32))
+    assert "tpu_custom_call" in text
+    for name in ("moe_gmm_fwd", "moe_gmm_bwd_dx", "moe_gmm_bwd_dw"):
+        assert name in text
 
 
 def test_fused_xent_forward_compiles_at_lm1b_vocab(chip):

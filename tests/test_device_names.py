@@ -15,6 +15,7 @@ Pure in-process tests on the CPU mesh (kernels in interpret mode).
 """
 
 import contextlib
+import functools
 import importlib
 import re
 import time
@@ -73,7 +74,8 @@ def _scopes(text: str, names) -> set:
 
 def test_lowered_step_names_kernels_and_phases():
     text = _lm_step_lowered(zero=1).as_text(debug_info=True)
-    step_kernels = [k for k in named_call.KERNEL_NAMES if k != "flash_carry"]
+    step_kernels = [k for k in named_call.KERNEL_NAMES
+                    if k != "flash_carry" and not k.startswith("moe_")]
     assert _scopes(text, step_kernels) == set(step_kernels)
     # ZeRO's constrain_update is the reduction under AllReduce (the implicit
     # lowering leaves the all-reduce to XLA, so it has no scope of its own).
@@ -100,6 +102,50 @@ def test_explicit_gradient_sync_sits_under_its_scope_inside_step_grad():
     assert "step.grad_sync/psum" in text
     backward = [line for line in text.splitlines() if "transpose(" in line]
     assert backward and not any("step.grad_sync" in l for l in backward)
+
+
+MOE_SCOPES = ("moe.route", "moe.dispatch", "moe.experts", "moe.combine")
+
+
+def _olmoe_step_lowered():
+    """The tiny OLMoE step (QK-norm + RoPE attention, dropless top-2 of 8
+    experts, fused head) through ``AutoDist`` on the 8-device mesh."""
+    from autodist_tpu.models import olmoe
+    cfg = olmoe.OlmoeConfig(
+        vocab_size=203, d_model=32, n_heads=2, n_layers=1, d_expert=16,
+        n_experts=8, top_k=2, max_len=16, dtype=jnp.float32,
+        attention_impl="flash", fused_head=True)
+    model, params = olmoe.init_params(cfg, rng=jax.random.PRNGKey(0))
+    batch = olmoe.synthetic_batch(cfg, batch_size=16, seq_len=16)
+    runner = AutoDist(strategy_builder=AllReduce()).create_distributed_session(
+        olmoe.make_loss_fn(model), params, optax.adam(1e-3),
+        example_batch=batch)
+    state = runner.init(params)
+    with runner.mesh:
+        return runner._build_step(None).lower(state,
+                                              runner.shard_batch(batch))
+
+
+@functools.lru_cache(maxsize=1)
+def _olmoe_step_text() -> str:       # one lowering for the seven cases
+    return _olmoe_step_lowered().as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("name", [k for k in named_call.KERNEL_NAMES
+                                  if k.startswith("moe_")] + list(MOE_SCOPES))
+def test_olmoe_step_names_its_kernels_and_routing_scopes(name):
+    """The grouped-matmul kernels by their device names, and the four scopes
+    of the routed FFN innermost around their operations."""
+    assert _scopes(_olmoe_step_text(), [name]) == {name}
+
+
+def test_moe_gauges_are_set_when_the_layer_is_traced():
+    _olmoe_step_lowered()
+    assert telemetry.gauge("moe.experts").value == 8
+    assert telemetry.gauge("moe.top_k").value == 2
+    # per device: 16 sequences of 16 over 8 devices, two slots a token
+    assert telemetry.gauge("moe.rows_per_call").value == 2 * 16 * 2
+    assert telemetry.gauge("moe.gmm.row_tiles").value == 1 + 8
 
 
 def test_flash_carry_is_named():

@@ -1,0 +1,278 @@
+#!/usr/bin/env python3
+"""Time the OLMoE cell's kernels alone, on the chip, at the cell's shapes.
+
+The instrument behind the choice of the grouped matmul (``ops/grouped_matmul.py``
+against ``jax.lax.ragged_dot``) and of its tiles, and the stand-alone readings of
+the two kernels the cell shares with the GPT-2 cells at shapes those never run.
+Every time is device time from a profiler trace of ``--calls`` calls (the busy
+union of all device operations a call, and the self time under each kernel's
+name); one JSON line a measurement on standard output.
+
+    python tools/moe_timing.py                         # every phase
+    python tools/moe_timing.py --phases gmm --tiles 256,512 --tiles 512,512
+    python tools/moe_timing.py --phases flash,xent,flips
+
+Phases: ``gmm`` (forward, and dX + dW, at the gate/up and the down
+shape: 131,072 rows in 64 groups as a top-8 of random logits sorts them, against
+``ragged_dot`` on the same arguments), ``flash`` (4 x 4,096 x 16 x 128 causal,
+forward + backward), ``xent`` (the fused head at 16,384 x 2,048 x 50,304),
+``flips`` (the share of top-8 choices that differ between bfloat16 and float32
+activations, one sequence through the published widths at depth 1),
+``gmmcheck`` (``gmm`` and its gradients against ``ragged_dot`` in float32 at the
+highest precision, relative L2), ``gradcheck`` (the benchmark's check of the cell by parameter: each one's share
+of the squared difference from the reference's gradient and of its norm).
+Needs the TPU: a time from the CPU's interpreter says nothing.
+"""
+
+import argparse
+import glob
+import json
+import os
+import sys
+import tempfile
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROWS, EXPERTS, TOP_K, D_MODEL, D_EXPERT, VOCAB = 131072, 64, 8, 2048, 1024, 50304
+
+
+def device_ms(fn, args, calls: int):
+    """(busy ms a call, {group: self ms a call}) of ``fn(*args)``."""
+    import jax
+
+    from benchmark import trace_reduce
+    jax.block_until_ready(fn(*args))
+    jax.block_until_ready(fn(*args))
+    with tempfile.TemporaryDirectory() as trace_dir:
+        with jax.profiler.trace(trace_dir):
+            jax.block_until_ready([fn(*args) for _ in range(calls)])
+        files = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                       "*.xplane.pb"))
+        summary = trace_reduce.summarize(trace_reduce.read_xplane(files[0]))
+    device = summary.devices[min(summary.devices)]
+    groups = sorted(device.by_group.items(), key=lambda kv: -kv[1])[:8]
+    return (device.busy_s / calls * 1e3,
+            {g: s / calls * 1e3 for g, s in groups})
+
+
+def group_sizes(seed: int = 0):
+    """Rows an expert receives when the top 8 of 64 random logits route
+    16,384 tokens: ragged, none empty, mean 2,048."""
+    import jax
+    import jax.numpy as jnp
+    logits = jax.random.normal(jax.random.PRNGKey(seed), (ROWS // TOP_K, EXPERTS))
+    _, chosen = jax.lax.top_k(logits, TOP_K)
+    return jnp.bincount(chosen.reshape(-1), length=EXPERTS).astype(jnp.int32)
+
+
+def phase_gmm(calls, tiles):
+    import jax
+    import jax.numpy as jnp
+
+    from autodist_tpu.ops import grouped_matmul
+    sizes = group_sizes()
+    yield {"phase": "gmm", "group_sizes_min_max": [int(sizes.min()),
+                                                    int(sizes.max())]}
+    implementations = {
+        "ragged_dot": lambda x, w, s: jax.lax.ragged_dot(
+            x, w.astype(x.dtype), s, preferred_element_type=jnp.float32
+        ).astype(x.dtype)}
+    for tile in tiles or [None]:
+        def kernel(x, w, s, tile=tile):
+            if tile is not None:
+                grouped_matmul.ROW_TILE, grouped_matmul.DW_ROW_TILE = tile
+            return grouped_matmul.gmm(x, w, s)
+        implementations[f"gmm{'' if tile is None else tile}"] = kernel
+    for shape_name, (k, n) in (("gate_up", (D_MODEL, D_EXPERT)),
+                               ("down", (D_EXPERT, D_MODEL))):
+        keys = jax.random.split(jax.random.PRNGKey(1), 3)
+        x = jax.random.normal(keys[0], (ROWS, k), jnp.bfloat16)
+        w = jax.random.normal(keys[1], (EXPERTS, k, n), jnp.float32) * 0.02
+        ct = jax.random.normal(keys[2], (ROWS, n), jnp.bfloat16)
+        flop = 2.0 * ROWS * k * n
+        for name, fn in implementations.items():
+            fwd = jax.jit(fn)
+            both = jax.jit(jax.grad(
+                lambda x, w, s, ct, fn=fn: jnp.sum(
+                    fn(x, w, s).astype(jnp.float32) * ct.astype(jnp.float32)),
+                argnums=(0, 1)))
+            # the gradient of a linear function needs no forward product:
+            # the compiler drops it, and "bwd" is dX + dW alone
+            for what, f, args, products in (("fwd", fwd, (x, w, sizes), 1),
+                                            ("bwd", both, (x, w, sizes, ct), 2)):
+                busy, groups = device_ms(f, args, calls)
+                yield {"phase": "gmm", "shape": shape_name, "impl": name,
+                       "what": what, "busy_ms": busy,
+                       "tflops_of_busy": products * flop / busy / 1e9,
+                       "groups_ms": groups}
+
+
+def phase_gmmcheck(_calls, _tiles):
+    """``gmm`` and its two gradients on the chip against ``ragged_dot`` in
+    float32 at the highest precision, at the cell's shapes: relative L2."""
+    import jax
+    import jax.numpy as jnp
+
+    from autodist_tpu.ops.grouped_matmul import gmm
+    sizes = group_sizes()
+    for shape_name, (k, n) in (("gate_up", (D_MODEL, D_EXPERT)),
+                               ("down", (D_EXPERT, D_MODEL))):
+        keys = jax.random.split(jax.random.PRNGKey(1), 3)
+        x = jax.random.normal(keys[0], (ROWS, k), jnp.bfloat16)
+        w = (jax.random.normal(keys[1], (EXPERTS, k, n), jnp.float32) * 0.02
+             ).astype(jnp.bfloat16).astype(jnp.float32)
+        ct = jax.random.normal(keys[2], (ROWS, n), jnp.bfloat16)
+
+        def exact(x, w):
+            return jax.lax.ragged_dot(x.astype(jnp.float32), w, sizes,
+                                      precision=jax.lax.Precision.HIGHEST)
+
+        def both(fn):
+            return jax.jit(lambda x, w: (fn(x, w),) + jax.grad(
+                lambda x, w: jnp.sum(fn(x, w).astype(jnp.float32)
+                                     * ct.astype(jnp.float32)),
+                argnums=(0, 1))(x, w))
+        got = both(lambda x, w: gmm(x, w, sizes))(x, w)
+        want = both(exact)(x, w)
+        rel = [float(jnp.linalg.norm((g.astype(jnp.float32)
+                                      - r.astype(jnp.float32)).ravel())
+                     / jnp.linalg.norm(r.astype(jnp.float32).ravel()))
+               for g, r in zip(got, want)]
+        yield {"phase": "gmmcheck", "shape": shape_name,
+               "rel_l2_y_dx_dw": rel}
+
+
+def phase_flash(calls, _):
+    import jax
+    import jax.numpy as jnp
+
+    from autodist_tpu.ops.flash_attention import flash_attention
+    q, k, v = (jax.random.normal(key, (4, 4096, 16, 128), jnp.bfloat16)
+               for key in jax.random.split(jax.random.PRNGKey(0), 3))
+    grad = jax.jit(jax.grad(lambda q, k, v: flash_attention(
+        q, k, v, causal=True).astype(jnp.float32).sum(), argnums=(0, 1, 2)))
+    busy, groups = device_ms(grad, (q, k, v), calls)
+    yield {"phase": "flash", "shape": [4, 4096, 16, 128], "busy_ms": busy,
+           "groups_ms": groups}
+
+
+def phase_xent(calls, _):
+    import jax
+    import jax.numpy as jnp
+
+    from autodist_tpu.ops.fused_xent import fused_softmax_xent
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    h = jax.random.normal(keys[0], (ROWS // TOP_K, D_MODEL), jnp.bfloat16)
+    w = jax.random.normal(keys[1], (D_MODEL, VOCAB), jnp.float32) * 0.02
+    targets = jax.random.randint(keys[2], (ROWS // TOP_K,), 0, VOCAB)
+    grad = jax.jit(jax.grad(lambda h, w: fused_softmax_xent(
+        h, w, targets).mean(), argnums=(0, 1)))
+    busy, groups = device_ms(grad, (h, w), calls)
+    yield {"phase": "xent", "shape": [ROWS // TOP_K, D_MODEL, VOCAB],
+           "busy_ms": busy, "groups_ms": groups}
+
+
+def phase_flips(_calls, _tiles):
+    """The router's input under bfloat16 activations against float32 ones,
+    same weights and tokens: how many top-8 choices change."""
+    import jax
+    import jax.numpy as jnp
+
+    from autodist_tpu.models import olmoe
+
+    def choices(dtype, params=None):
+        cfg = olmoe.OlmoeConfig(n_layers=1, dtype=dtype, attention_impl="flash")
+        model = olmoe.Olmoe(cfg)
+        if params is None:
+            params = olmoe.init_params(cfg, jax.random.PRNGKey(0))[1]
+        tokens = jax.random.randint(jax.random.PRNGKey(1), (1, 4096), 0,
+                                    cfg.vocab_size)
+        _, state = jax.jit(lambda p, t: model.apply(
+            {"params": p}, t, return_hidden=True, capture_intermediates=(
+                lambda module, _: module.name == "ln_moe")))(params, tokens)
+        h = state["intermediates"]["block_0"]["ln_moe"]["__call__"][0]
+        logits = jnp.dot(h.reshape(-1, cfg.d_model).astype(jnp.float32),
+                         params["block_0"]["moe"]["router"],
+                         precision=jax.lax.Precision.HIGHEST)
+        return jax.lax.top_k(jax.nn.softmax(logits), cfg.top_k)[1], params
+
+    low, params = choices(jnp.bfloat16)
+    high, _ = choices(jnp.float32, params)
+    same = (low[:, :, None] == high[:, None, :]).any(axis=-1)    # [T, k]
+    yield {"phase": "flips", "tokens": int(low.shape[0]),
+           "slots_flipped_share": float(1.0 - same.mean()),
+           "tokens_with_a_flip_share": float(1.0 - same.all(axis=-1).mean())}
+
+
+def phase_gradcheck(_calls, _tiles):
+    """The benchmark's check of ``olmoe-pretrain-4k`` taken apart: the
+    system's gradient against the plain reference's, by parameter, for the
+    cell's configuration and for variants of it that take one source of
+    rounding away at a time."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from autodist_tpu.models import olmoe
+    from benchmark import harness
+    cell = harness.load_cell("olmoe-pretrain-4k", ROOT)
+    family = cell.load_module("families", "olmoe")
+    reference = cell.load_module("reference", "olmoe")
+    built = family.build(cell.config, cell.traffic, 11, 4)
+    sample = {k: jnp.asarray(v) for k, v in built.sample.items()}
+    with jax.default_matmul_precision("highest"):
+        ref = jax.jit(jax.grad(lambda p, b: reference.loss(
+            p, b, **built.reference_config)))(built.params, sample)
+    cfg = family.model_config(cell.config)
+    variants = (
+        ("the cell", {}, None),
+        ("dot attention", {"attention_impl": "dot"}, None),
+        ("XLA head", {"fused_head": False}, None),
+        ("float32 activations", {"dtype": jnp.float32}, None),
+        # the fused head's tiles are not fitted for float32 rows at d 2,048
+        ("float32 activations, XLA head and attention, highest precision",
+         {"dtype": jnp.float32, "fused_head": False, "attention_impl": "dot"},
+         "highest"),
+    )
+    for name, changes, precision in variants:
+        loss_fn = olmoe.make_loss_fn(olmoe.Olmoe(dataclasses.replace(cfg, **changes)))
+        with jax.default_matmul_precision(precision or "default"):
+            grads = jax.jit(jax.grad(loss_fn))(built.params, sample)
+        rows = {jax.tree_util.keystr(path): (float(jnp.sum(jnp.square(g - r))),
+                                             float(jnp.sum(jnp.square(r))))
+                for (path, g), r in zip(
+                    jax.tree_util.tree_leaves_with_path(grads),
+                    jax.tree_util.tree_leaves(ref))}
+        diff, norm = (sum(x) for x in zip(*rows.values()))
+        yield {"phase": "gradcheck", "variant": name,
+               "grad_rel_l2": (diff / norm) ** 0.5,
+               "rel_l2_by_parameter": {
+                   k: round((d / n) ** 0.5, 4) for k, (d, n) in rows.items()
+                   if n > 1e-4 * norm}}
+        del grads
+
+
+PHASES = {"gmmcheck": phase_gmmcheck, "gradcheck": phase_gradcheck,
+          "gmm": phase_gmm, "flash": phase_flash, "xent": phase_xent,
+          "flips": phase_flips}
+
+
+def main(argv=None):
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument("--phases", default=",".join(PHASES))
+    parser.add_argument("--tiles", action="append", default=[],
+                        help="ROW_TILE,DW_ROW_TILE override; may repeat")
+    parser.add_argument("--calls", type=int, default=5)
+    args = parser.parse_args(argv)
+    sys.path.insert(0, ROOT)
+    import jax
+    if jax.default_backend() != "tpu":
+        raise SystemExit(f"needs the TPU, the backend is {jax.default_backend()!r}")
+    tiles = [tuple(int(x) for x in t.split(",")) for t in args.tiles]
+    for name in args.phases.split(","):
+        for record in PHASES[name](args.calls, tiles):
+            print(json.dumps(record), flush=True)
+
+
+if __name__ == "__main__":
+    main()
