@@ -16,14 +16,25 @@ classify at run time. The per-row logsumexp is emitted as a residual for the
 backward pass.
 
 Backward (FlashAttention-2 style): scores are recomputed blockwise from the saved
-logsumexp, so nothing quadratic is ever materialized. Two kernels:
+logsumexp, so nothing quadratic is ever materialized. ONE kernel (device name
+``flash_bwd_dkv``) produces dQ, dK and dV: grid (batch*heads, k-blocks); q, dO
+and a float32 dQ accumulator of the whole (batch, head) stay in VMEM across the
+k blocks, and each grid step walks the q tiles from the first the diagonal lets
+its keys see — one recomputed score tile (scores, p, dP, ds) feeds all three
+gradients, five products where two kernels ran seven. The tile is held
+transposed as in the forward, so every product is a form the forward runs and
+lse / D enter as the lane-dense rows they are stored as. Past
+``_RESIDENT_DQ_BYTES`` of dQ a (batch, head) (L 16,384 at head_dim 64) the same
+block body runs as two kernels, each recomputing the tile:
 
-- dK/dV: grid (batch*heads, k-blocks, q-blocks) — each k block accumulates
-  p^T dO and ds^T q across all its query blocks in VMEM scratch.
-- dQ:    grid (batch*heads, q-blocks, k-blocks) — each q block accumulates
-  ds k across its key blocks.
+- dK/dV (``flash_bwd_dkv``): grid (batch*heads, k-blocks, q-blocks) — a k block
+  accumulates p^T dO and ds^T q across its query blocks in VMEM scratch.
+- dQ (``flash_bwd_dq``): grid (batch*heads, q-blocks, k-blocks) — a q block
+  accumulates ds k across its key blocks.
 
-The row term D_i = rowsum(dO * O) is precomputed in XLA (elementwise, fused).
+A block the diagonal hides is neither computed nor (under static offsets)
+copied. The row term D_i = rowsum(dO * O) is precomputed in XLA (elementwise,
+fused).
 
 On non-TPU backends the kernels run in pallas interpret mode, so tests exercise
 the same code path on the CPU-sim mesh.
@@ -58,12 +69,30 @@ from autodist_tpu.ops.named_call import named_pallas_call
 # 1,024 (0.539). Long context, B·H 64: L 4,096 2.33 ms (was 6.49), L 8,192 8.44 ms
 # (was 24.05) resident; streamed in 2,048-row blocks 10.16 ms at L 8,192 (11.56
 # before skipped blocks stopped being copied). D 128 at L 2,048: 0.90 ms (1.78).
-# Non-causal L 1,024: 0.554 ms (1.406). The backward still runs 512 x 512 row-major.
+# Non-causal L 1,024: 0.554 ms (1.406).
+#
+# The backward's schedule, from stand-alone timings of `_flash_backward` on the same
+# chip (same tool, PERF.md §6 "PR 26"; ms a call, the kernels' own device time).
+# GPT-2-medium's call, two row-major kernels of 512 x 512 blocks: 0.722 + 0.619 =
+# 1.341. One pass on the row-major tile 0.923; the tile transposed 0.834; class
+# bodies on top 0.910 and the skipped block uncopied 0.918 (SLOWER: the second body
+# costs more than the iota / compare / select it saves); q and dO resident with the
+# q tiles walked in the kernel 0.812, with one body for every needed tile 0.793 —
+# what runs, 0.796 here. dQ accumulated untransposed 0.845. Tiles (q x k) 256 x 512
+# 0.895, 512 x 256 0.876, 1,024 x 512 and 512 x 1,024 0.958; a q tile of 512 with
+# 1,024-row K/V blocks 0.982. OLMoE's call (B·H 64, L 4,096, D 128): 4.911 + 4.178 =
+# 9.089 -> 4.574 (1,024 x 512: 4.828; 256 x 512: 4.967). L 4,096 at D 64: 8.988 ->
+# 4.205; D 128 at L 2,048: 2.381 -> 1.332; non-causal L 1,024: 1.529 -> 0.998; one
+# ring step at L 4,096 (traced offsets, f32 out): 9.269 -> 4.237 on the diagonal,
+# 12.373 -> 7.265 wholly visible. A needed [512, 512] tile takes 2.07 us for five
+# products where the MXU at head_dim 64 (half filled) needs about 1.7.
 DEFAULT_Q_BLOCK = 512
 DEFAULT_K_BLOCK = 512
 _KEY_TILE = 512             # keys a score tile of the forward: [512, bq] f32
 _RESIDENT_KV_BYTES = 1 << 20     # K (or V) of one (batch, head) kept in VMEM
 _STREAM_K_BLOCK = 2048           # K/V rows a grid step beyond that
+_RESIDENT_DQ_BYTES = 2 << 20     # the one-pass backward's f32 dQ of one (batch, head)
+_BACKWARD_VMEM_LIMIT = 48 << 20  # scoped VMEM the one-pass backward asks for
 
 
 def _sub_tile(bk: int) -> int:
@@ -77,6 +106,20 @@ def _sub_tile(bk: int) -> int:
 
 def _is_static(n) -> bool:
     return isinstance(n, (int, np.integer))
+
+
+def _scale_is_exact(scale: float) -> bool:
+    """A power of two (d = 16, 64, 256): scaling q once is exact in any dtype,
+    else the scale goes on the float32 tile or accumulator."""
+    return math.frexp(scale)[0] == 0.5
+
+
+def _tile_start(t, sub: int, n_tiles: int):
+    """First row of tile ``t`` of ``n_tiles`` tiles of ``sub`` rows."""
+    if n_tiles == 1:
+        return 0                                          # the one tile, whatever t
+    start = t * sub
+    return start if _is_static(start) else pl.multiple_of(start, sub)
 
 
 def _tile_counts(q_lo, k_lo, valid, bq: int, bk: int, sub: int, causal: bool):
@@ -127,21 +170,15 @@ def _attend_block(q_ref, k_ref, v_ref, state, *, q_lo, k_lo, valid, sub: int,
     in its first tile and the guard is dead."""
     q = q_ref[0]                                      # [bq, d]
     bq, bk = q.shape[0], k_ref.shape[1]
-    # scale once per q block where that is exact (a power of two, as at
-    # d = 16, 64, 256), else on the score tile as before.
-    prescale = math.frexp(scale)[0] == 0.5
+    # scale once per q block where that is exact, else on the score tile.
+    prescale = _scale_is_exact(scale)
     if prescale:
         q = q * jnp.asarray(scale, q.dtype)
     n_plain, n_need = _tile_counts(q_lo, k_lo, valid, bq, bk, sub, causal)
 
     def tile(j, state, masked: bool):
         m_prev, l_prev, acc = state
-        if sub == bk:
-            start = 0                                 # the one tile, whatever j
-        else:
-            start = j * sub
-            if not _is_static(start):
-                start = pl.multiple_of(start, sub)
+        start = _tile_start(j, sub, bk // sub)
         k_t = k_ref[0, pl.ds(start, sub), :]          # [sub, d]
         v_t = v_ref[0, pl.ds(start, sub), :]
         scores = jax.lax.dot_general(
@@ -332,102 +369,221 @@ def _flash_forward(q, k, v, causal: bool, q_block, k_block, interpret: bool):
     return out, lse
 
 
-def _recompute_p_ds(q, do, k_blk, v_blk, lse, dd, q_start, k_start, lk, causal,
-                    scale, q_off=0, k_off=0):
-    """Shared backward block math: p [bq, bk] and ds (pre-scale) from a recomputed
-    score block. Matmul operands keep the input dtype (MXU rate); p/ds are f32."""
-    bq, bk = q.shape[0], k_blk.shape[0]
-    scores = scale * jax.lax.dot_general(
-        q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    invalid = k_pos >= lk
-    if causal:
-        q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        invalid = invalid | (k_off + k_pos > q_off + q_pos)
-    p = jnp.where(invalid, 0.0, jnp.exp(scores - lse))            # [bq, bk]
-    dp = jax.lax.dot_general(do, v_blk, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)  # [bq, bk]
-    ds = p * (dp - dd)
-    return p, ds
+def _query_tile_counts(q_lo, k_lo, valid, bq: int, bk: int, sub: int,
+                       causal: bool):
+    """``(t_need, t_plain)`` of the ``bq // sub`` query tiles of one (q block,
+    K/V block) pair of the backward, whose block is walked along the QUERIES:
+    tiles ``[0, t_need)`` hold nothing the mask keeps (their queries come
+    before every key: skipped, neither computed nor, where they are a grid
+    step, copied), ``[t_need, t_plain)`` are crossed by the diagonal or meet
+    padded keys, the rest hold no masked score. :func:`_tile_counts` on the
+    mirrored pair: with positions negated the queries are the keys of a
+    causal mask and the last query tile is the first key tile, so there is
+    one definition of the classes."""
+    n_t = bq // sub
+    if not causal:
+        t_need = t_plain = 0
+    else:
+        n_plain, n_need = _tile_counts(-(k_lo + bk - 1), -(q_lo + bq - 1), None,
+                                       bk, bq, sub, True)
+        t_need, t_plain = n_t - n_need, n_t - n_plain
+    if valid is not None:           # a ragged tail of keys: every query meets it
+        xp = np if _is_static(valid) and _is_static(t_plain) else jnp
+        t_plain = xp.where(valid < bk, n_t, t_plain)
+    return t_need, t_plain
+
+
+def _backward_block(q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
+                    dq_acc, dk_acc, dv_acc, *, row0, q_lo, k_lo, valid,
+                    sub: int, causal: bool, scale: float):
+    """The backward's block math against one VMEM-resident K/V block — the
+    single definition shared by the one-pass kernel and the two kernels of the
+    split path. The q rows of the grid step are walked in tiles of ``sub``
+    queries from the first the mask keeps anything of
+    (:func:`_query_tile_counts`; one body for crossed and plain tiles alike:
+    a second, unmasked body made the kernel 0.3–2.4% SLOWER on the chip, the
+    iota / compare / select costing less than a second loop), and one
+    recomputed score tile feeds every accumulator that is not None:
+    ``dv_acc`` / ``dk_acc`` ``[bk, d]`` (dK unscaled unless the scale went
+    onto q exactly) and ``dq_acc[t]`` ``[d, sub]``, dQ of tile ``t``
+    transposed and unscaled.
+
+    The tile is held TRANSPOSED, ``[bk keys, sub queries]``, as the forward
+    holds it: ``s^T = k q^T`` and ``dP^T = v dO^T`` contract the head dim of
+    both operands, ``dV += p^T dO`` and ``dK += ds^T q`` are plain products
+    (row-major they transposed a score-sized tile each), ``dQ^T += k^T ds^T``
+    contracts the first axis of the small operand as the forward's ``acc``
+    does, and lse and D enter as the lane-dense ``[1, sub]`` rows they are
+    stored as. Matmul operands keep the input dtype (MXU rate); p / ds and
+    every accumulator are f32, p and ds cast where they enter a product.
+
+    ``row0``: the row of the q rows' first tile in the lse / D planes;
+    ``q_lo`` / ``k_lo``: global positions of the first query and key;
+    ``valid``: real keys from the block's first on, None where no K/V row is
+    padding. Padded QUERY rows need no mask: dO is zero there (so dP, D and
+    with them ds are zero, and p meets a zero row of dO)."""
+    bq, bk = q_ref.shape[1], k_ref.shape[1]
+    n_t = bq // sub
+    prescale = _scale_is_exact(scale)
+    k = k_ref[0]                                          # [bk, d]
+    v = v_ref[0]
+    t_need, _ = _query_tile_counts(q_lo, k_lo, valid, bq, bk, sub, causal)
+
+    def tile(t, carry):
+        start = _tile_start(t, sub, n_t)
+        q = q_ref[0, pl.ds(start, sub), :]                # [sub, d]
+        do = do_ref[0, pl.ds(start, sub), :]
+        if prescale:
+            q = q * jnp.asarray(scale, q.dtype)
+        lse = lse_ref[0, pl.ds(row0 + t, 1), :]           # [1, sub]
+        dd = dd_ref[0, pl.ds(row0 + t, 1), :]
+        scores = jax.lax.dot_general(
+            k, q, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)           # [bk, sub]
+        if not prescale:
+            scores = scale * scores
+        p = jnp.exp(scores - lse)
+        invalid = None
+        if valid is not None or causal:
+            key = jax.lax.broadcasted_iota(jnp.int32, (bk, sub), 0)
+        if valid is not None:
+            invalid = key >= valid
+        if causal:
+            query = jax.lax.broadcasted_iota(jnp.int32, (bk, sub), 1)
+            above = key - query > q_lo + start - k_lo
+            invalid = above if invalid is None else invalid | above
+        if invalid is not None:
+            p = jnp.where(invalid, 0.0, p)
+        dp = jax.lax.dot_general(
+            v, do, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)           # [bk, sub]
+        ds = (p * (dp - dd)).astype(q.dtype)
+        if dv_acc is not None:
+            dv_acc[:] += jnp.dot(p.astype(do.dtype), do,
+                                 preferred_element_type=jnp.float32)
+            dk_acc[:] += jnp.dot(ds, q, preferred_element_type=jnp.float32)
+        if dq_acc is not None:
+            dq_acc[t] += jax.lax.dot_general(
+                k, ds, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)       # [d, sub]
+        return carry
+
+    if n_t == 1 and not _is_static(t_need):
+        pl.when(t_need == 0)(lambda: tile(0, None))       # a branch, not a loop
+    else:
+        _loop(t_need, n_t, tile, None)
+
+
+def _finish_dkdv(dk_ref, dv_ref, dk_acc, dv_acc, scale: float):
+    # dK's q carried the scale where that is exact
+    dk = dk_acc[:] if _scale_is_exact(scale) else scale * dk_acc[:]
+    dk_ref[0] = dk.astype(dk_ref.dtype)
+    dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+def _flash_bwd_kernel(off_ref, q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
+                      dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
+                      lk: int, sub: int, causal: bool, scale: float):
+    """The one-pass backward: q, dO and the float32 dQ accumulator of one
+    (batch, head) stay in VMEM across its K/V blocks (the grid's second axis);
+    a grid step finishes dK and dV of its block, the last writes dQ."""
+    ki = pl.program_id(1)
+    bk = k_ref.shape[1]
+
+    @pl.when(ki == 0)
+    def _init():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    dk_acc[:] = jnp.zeros_like(dk_acc)
+    dv_acc[:] = jnp.zeros_like(dv_acc)
+    k_start = ki * bk
+    _backward_block(q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
+                    dq_acc, dk_acc, dv_acc, row0=0, q_lo=off_ref[0],
+                    k_lo=off_ref[1] + k_start,
+                    valid=_valid_keys(lk, k_start, bk), sub=sub, causal=causal,
+                    scale=scale)
+    _finish_dkdv(dk_ref, dv_ref, dk_acc, dv_acc, scale)
+
+    @pl.when(ki == pl.num_programs(1) - 1)
+    def _finish():
+        n_t = q_ref.shape[1] // sub
+
+        def turn(t, carry):
+            dq_ref[0, pl.ds(_tile_start(t, sub, n_t), sub), :] = (
+                scale * dq_acc[t]).T.astype(dq_ref.dtype)
+            return carry
+
+        _loop(0, n_t, turn, None)
 
 
 def _flash_bwd_dkdv_kernel(off_ref, q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
                            dk_ref, dv_ref, dk_acc, dv_acc, *,
-                           lk: int, q_block: int, k_block: int, causal: bool,
-                           scale: float):
+                           lk: int, causal: bool, scale: float):
+    """The split path's dK/dV: a K/V block accumulates over the q blocks."""
     ki = pl.program_id(1)
     qi = pl.program_id(2)
-    n_q = pl.num_programs(2)
-    q_off = off_ref[0]
-    k_off = off_ref[1]
+    bq, bk = q_ref.shape[1], k_ref.shape[1]
 
     @pl.when(qi == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    q_start = qi * q_block
-    k_start = ki * k_block
-    needed = (k_off + k_start <= q_off + q_start + q_block - 1) if causal else True
+    k_start = ki * bk
+    _backward_block(q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
+                    None, dk_acc, dv_acc, row0=qi, q_lo=off_ref[0] + qi * bq,
+                    k_lo=off_ref[1] + k_start,
+                    valid=_valid_keys(lk, k_start, bk), sub=bq, causal=causal,
+                    scale=scale)
 
-    @pl.when(needed)
-    def _step():
-        q = q_ref[0]
-        do = do_ref[0]
-        k_blk = k_ref[0]
-        v_blk = v_ref[0]
-        lse = lse_ref[0, qi, :][:, None]                  # [bq, 1]
-        dd = dd_ref[0, qi, :][:, None]
-        p, ds = _recompute_p_ds(q, do, k_blk, v_blk, lse, dd, q_start, k_start,
-                                lk, causal, scale, q_off, k_off)
-        dv_acc[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dk_acc[:] += scale * jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    @pl.when(qi == n_q - 1)
+    @pl.when(qi == pl.num_programs(2) - 1)
     def _finish():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        _finish_dkdv(dk_ref, dv_ref, dk_acc, dv_acc, scale)
 
 
 def _flash_bwd_dq_kernel(off_ref, q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
-                         dq_ref, dq_acc, *,
-                         lk: int, q_block: int, k_block: int, causal: bool,
-                         scale: float):
+                         dq_ref, dq_acc, *, lk: int, causal: bool, scale: float):
+    """The split path's dQ: a q block accumulates over the K/V blocks."""
     qi = pl.program_id(1)
     ki = pl.program_id(2)
-    n_k = pl.num_programs(2)
-    q_off = off_ref[0]
-    k_off = off_ref[1]
+    bq, bk = q_ref.shape[1], k_ref.shape[1]
 
     @pl.when(ki == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    q_start = qi * q_block
-    k_start = ki * k_block
-    needed = (k_off + k_start <= q_off + q_start + q_block - 1) if causal else True
+    k_start = ki * bk
+    _backward_block(q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
+                    dq_acc, None, None, row0=qi, q_lo=off_ref[0] + qi * bq,
+                    k_lo=off_ref[1] + k_start,
+                    valid=_valid_keys(lk, k_start, bk), sub=bq, causal=causal,
+                    scale=scale)
 
-    @pl.when(needed)
-    def _step():
-        q = q_ref[0]
-        do = do_ref[0]
-        k_blk = k_ref[0]
-        v_blk = v_ref[0]
-        lse = lse_ref[0, qi, :][:, None]
-        dd = dd_ref[0, qi, :][:, None]
-        _, ds = _recompute_p_ds(q, do, k_blk, v_blk, lse, dd, q_start, k_start,
-                                lk, causal, scale, q_off, k_off)
-        dq_acc[:] += scale * jax.lax.dot_general(
-            ds.astype(k_blk.dtype), k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    @pl.when(ki == n_k - 1)
+    @pl.when(ki == pl.num_programs(2) - 1)
     def _finish():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+        dq_ref[0] = (scale * dq_acc[0]).T.astype(dq_ref.dtype)
+
+
+def _backward_blocks(lq: int, lk: int, q_block, k_block):
+    """``(bq, bk)`` of the backward: queries and keys a score tile (bk is also
+    the K/V rows a grid step). An explicit ``q_block`` / ``k_block`` is what
+    the caller asked. Left to the shape (None): 512 x 512, which the chip
+    timings in the module header chose at head_dim 64 and 128 alike, so D and
+    the dtype do not enter; bq is also the forward's q block, whose lse plane
+    is then the backward's layout."""
+    return min(q_block or DEFAULT_Q_BLOCK, lq), min(k_block or DEFAULT_K_BLOCK, lk)
+
+
+def _count_backward_tiles(n_q: int, lk: int, bq: int, bk: int, causal: bool):
+    """(plain, masked, skipped) ``[bk, bq]`` score tiles of one (batch, head)
+    under zero offsets."""
+    n_k = pl.cdiv(lk, bk)
+    plain = need = 0
+    for ki in range(n_k):
+        t_need, t_plain = _query_tile_counts(
+            0, ki * bk, _valid_keys(lk, ki * bk, bk), n_q * bq, bk, bq, causal)
+        plain, need = plain + n_q - int(t_plain), need + n_q - int(t_need)
+    return plain, need - plain, n_q * n_k - need
 
 
 def prepare_backward_q_side(q, o, g, q_block):
@@ -458,10 +614,17 @@ def _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, k_block,
                        out_dtype=None):
     """Backward against one K/V shard from prepared query-side layout. Returns
     (dq, dk, dv) in [B, L, H, D]; ``out_dtype`` overrides the kernels' output
-    dtype (ring passes f32 so per-step contributions accumulate unquantized)."""
+    dtype (ring passes f32 so per-step contributions accumulate unquantized).
+
+    One pass (a single kernel, named ``flash_bwd_dkv``) while the float32 dQ
+    accumulator of one (batch, head) is within ``_RESIDENT_DQ_BYTES``; past
+    it the same block body in two kernels, dK/dV and ``flash_bwd_dq``, each
+    recomputing the score tiles. ``bq`` is the q tile and the lse / D planes'
+    row, ``k_block`` the K/V rows a grid step."""
     b, lq, h, d = q_shape
     lk = k.shape[1]
     scale = 1.0 / (d ** 0.5)
+    static_offsets = _is_static(q_offset) and _is_static(k_offset)
     offs = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                       jnp.asarray(k_offset, jnp.int32)])
 
@@ -476,54 +639,79 @@ def _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, k_block,
     dq_dtype = out_dtype or qf.dtype
     dk_dtype = out_dtype or k.dtype
     dv_dtype = out_dtype or v.dtype
+    lq_p = n_q * bq
+    one_pass = lq_p * d * 4 <= _RESIDENT_DQ_BYTES
 
-    q_spec = pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, j, 0))
-    row_spec = pl.BlockSpec((1, n_q, bq), lambda bh, i, j: (bh, 0, 0))
-    kv_spec = pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, i, 0))
+    plain, masked, skipped = _count_backward_tiles(n_q, lk, bq, bk, causal)
+    telemetry.gauge("flash.bwd.passes").set(1 if one_pass else 2)
+    telemetry.gauge("flash.bwd.tiles_plain").set(plain)
+    telemetry.gauge("flash.bwd.tiles_masked").set(masked)
+    telemetry.gauge("flash.bwd.tiles_skipped").set(skipped)
 
-    dkdv_kernel = functools.partial(
-        _flash_bwd_dkdv_kernel, lk=lk, q_block=bq, k_block=bk, causal=causal,
-        scale=scale)
-    dk, dv = named_pallas_call(
-        "flash_bwd_dkv", dkdv_kernel,
-        grid=(b * h, n_k, n_q),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                  q_spec, q_spec, row_spec, row_spec, kv_spec, kv_spec],
-        out_specs=(
-            pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, i, 0)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((b * h, n_k * bk, d), dk_dtype),
-            jax.ShapeDtypeStruct((b * h, n_k * bk, d), dv_dtype),
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
-        ],
-        interpret=interpret,
-    )(offs, qf, dof, lse, dd, kf, vf)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    kernel_args = dict(lk=lk, causal=causal, scale=scale)
+    dq_shape = jax.ShapeDtypeStruct((b * h, lq_p, d), dq_dtype)
+    dkv_shape = (jax.ShapeDtypeStruct((b * h, n_k * bk, d), dk_dtype),
+                 jax.ShapeDtypeStruct((b * h, n_k * bk, d), dv_dtype))
+    dkv_scratch = [pltpu.VMEM((bk, d), jnp.float32),
+                   pltpu.VMEM((bk, d), jnp.float32)]
+    if one_pass:
+        q_all = pl.BlockSpec((1, lq_p, d), lambda bh, i: (bh, 0, 0))
+        rows = pl.BlockSpec((1, n_q, bq), lambda bh, i: (bh, 0, 0))
+        kv_spec = pl.BlockSpec((1, bk, d), lambda bh, i: (bh, i, 0))
+        dq, dk, dv = named_pallas_call(
+            "flash_bwd_dkv",
+            functools.partial(_flash_bwd_kernel, sub=bq, **kernel_args),
+            grid=(b * h, n_k),
+            in_specs=[smem, q_all, q_all, rows, rows, kv_spec, kv_spec],
+            out_specs=(q_all, kv_spec, kv_spec),
+            out_shape=(dq_shape,) + dkv_shape,
+            scratch_shapes=[pltpu.VMEM((n_q, d, bq), jnp.float32)] + dkv_scratch,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"),
+                vmem_limit_bytes=_BACKWARD_VMEM_LIMIT),
+            interpret=interpret,
+        )(offs, qf, dof, lse, dd, kf, vf)
+    else:
+        # A block the diagonal hides names the nearest one it does not, so its
+        # (skipped) grid step copies nothing in; traced offsets (the ring)
+        # cannot enter an index map and copy every block.
+        skip = causal and static_offsets
 
-    dq_kernel = functools.partial(
-        _flash_bwd_dq_kernel, lk=lk, q_block=bq, k_block=bk, causal=causal,
-        scale=scale)
-    dq = named_pallas_call(
-        "flash_bwd_dq", dq_kernel,
-        grid=(b * h, n_q, n_k),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, n_q, bq), lambda bh, i, j: (bh, 0, 0)),
-            pl.BlockSpec((1, n_q, bq), lambda bh, i, j: (bh, 0, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, n_q * bq, d), dq_dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        interpret=interpret,
-    )(offs, qf, dof, lse, dd, kf, vf)
+        def q_of(i, j):      # dK/dV's grid: K/V block i, q block j
+            return jnp.clip((k_offset + i * bk - q_offset) // bq, j, n_q - 1) \
+                if skip else j
+
+        def k_of(i, j):      # dQ's grid: q block i, K/V block j
+            return jnp.clip((q_offset + (i + 1) * bq - 1 - k_offset) // bk, 0, j) \
+                if skip else j
+
+        rows = pl.BlockSpec((1, n_q, bq), lambda bh, i, j: (bh, 0, 0))
+        q_spec = pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, q_of(i, j), 0))
+        kv_spec = pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, i, 0))
+        dk, dv = named_pallas_call(
+            "flash_bwd_dkv",
+            functools.partial(_flash_bwd_dkdv_kernel, **kernel_args),
+            grid=(b * h, n_k, n_q),
+            in_specs=[smem, q_spec, q_spec, rows, rows, kv_spec, kv_spec],
+            out_specs=(kv_spec, kv_spec),
+            out_shape=dkv_shape,
+            scratch_shapes=dkv_scratch,
+            interpret=interpret,
+        )(offs, qf, dof, lse, dd, kf, vf)
+
+        q_spec = pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0))
+        kv_spec = pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, k_of(i, j), 0))
+        dq = named_pallas_call(
+            "flash_bwd_dq",
+            functools.partial(_flash_bwd_dq_kernel, **kernel_args),
+            grid=(b * h, n_q, n_k),
+            in_specs=[smem, q_spec, q_spec, rows, rows, kv_spec, kv_spec],
+            out_specs=q_spec,
+            out_shape=dq_shape,
+            scratch_shapes=[pltpu.VMEM((1, d, bq), jnp.float32)],
+            interpret=interpret,
+        )(offs, qf, dof, lse, dd, kf, vf)
 
     dq = dq[:, :lq, :].reshape(b, h, lq, d).transpose(0, 2, 1, 3)
     dk = dk[:, :lk, :].reshape(b, h, lk, d).transpose(0, 2, 1, 3)
@@ -533,8 +721,9 @@ def _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, k_block,
 
 def _flash_backward(q, k, v, o, lse, g, causal, q_block, k_block, interpret,
                     q_offset=0, k_offset=0, out_dtype=None):
-    qf, dof, dd, bq, n_q = prepare_backward_q_side(q, o, g, q_block)
-    return _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, k_block,
+    bq, bk = _backward_blocks(q.shape[1], k.shape[1], q_block, k_block)
+    qf, dof, dd, bq, n_q = prepare_backward_q_side(q, o, g, bq)
+    return _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, bk,
                               interpret, q.shape, q_offset=q_offset,
                               k_offset=k_offset, out_dtype=out_dtype)
 
@@ -698,8 +887,7 @@ def _flash_fwd(q, k, v, causal, q_block, k_block):
 
 def _flash_bwd(causal, q_block, k_block, residuals, g):
     q, k, v, o, lse = residuals
-    return _flash_backward(q, k, v, o, lse, g, causal,
-                           q_block or DEFAULT_Q_BLOCK, k_block or DEFAULT_K_BLOCK,
+    return _flash_backward(q, k, v, o, lse, g, causal, q_block, k_block,
                            _use_interpret())
 
 
@@ -711,9 +899,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     k_block: Optional[int] = None) -> jax.Array:
     """Flash attention over [B, L, H, D] tensors (pallas forward and backward).
 
-    ``q_block`` / ``k_block`` left at None: the forward picks its blocks from
-    the shape (:func:`_forward_blocks`), the backward runs at
-    ``DEFAULT_Q_BLOCK`` x ``DEFAULT_K_BLOCK``.
+    ``q_block`` / ``k_block`` left at None: the forward and the backward pick
+    their blocks from the shape (:func:`_forward_blocks`,
+    :func:`_backward_blocks`), and the backward its schedule: one pass while
+    the float32 dQ of a (batch, head) fits ``_RESIDENT_DQ_BYTES``.
 
     Under a mesh of several devices the kernels run per device on its share
     of the batch (:func:`autodist_tpu.parallel.mesh.per_device`)."""

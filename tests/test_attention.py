@@ -171,6 +171,131 @@ def test_flash_forward_tile_gauges_equal_hand_count(length, causal, want):
     assert got == want
 
 
+def _backward_both_paths(monkeypatch, q, k, v, causal, q_block, k_block,
+                          q_offset=0, k_offset=0, out_dtype=None):
+    """``_flash_backward`` on a forward's residuals, in one pass and with the
+    byte limit at 0 (the split path), and ``flash.bwd.passes`` of each.
+    Offsets other than 0 enter traced, as the ring's do."""
+    import importlib
+    from autodist_tpu import telemetry
+    fa = importlib.import_module("autodist_tpu.ops.flash_attention")
+
+    g = jnp.asarray(np.random.RandomState(13).randn(*q.shape), q.dtype)
+    interpret = fa._use_interpret()
+    out, lse = fa._flash_forward(q, k, v, causal and q.shape[1] == k.shape[1],
+                                 q_block, k_block, interpret)
+
+    def backward(**offsets):
+        return fa._flash_backward(q, k, v, out, lse, g, causal, q_block, k_block,
+                                  interpret, out_dtype=out_dtype, **offsets)
+
+    results, passes = [], []
+    for limit in (fa._RESIDENT_DQ_BYTES, 0):
+        monkeypatch.setattr(fa, "_RESIDENT_DQ_BYTES", limit)
+        if q_offset or k_offset:
+            results.append(jax.jit(
+                lambda q_off, k_off: backward(q_offset=q_off, k_offset=k_off))(
+                    jnp.int32(q_offset), jnp.int32(k_offset)))
+        else:
+            results.append(backward())
+        passes.append(telemetry.gauge("flash.bwd.passes").value)
+    return results, passes
+
+
+def _randn(rng, *shape):
+    return jnp.asarray(rng.randn(*shape), jnp.float32)
+
+
+# name -> (Lq, Lk, D, causal, q_block, k_block, out_dtype)
+_ONE_PASS_CASES = {
+    **{name: (length, length, 16, causal, q_block, k_block, None)
+       for name, (length, causal, q_block, k_block) in _BLOCK_CLASS_CASES.items()},
+    "head-dim-128": (128, 128, 128, True, 64, 64, None),    # the scale is not a power of two
+    "ragged-lq-ne-lk": (100, 150, 16, False, 32, 64, None),
+    "float32-out": (128, 128, 16, True, 32, 32, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("case", list(_ONE_PASS_CASES))
+def test_flash_backward_one_pass_matches_split(case, monkeypatch):
+    """dQ, dK and dV from one recomputed score tile against the two-kernel
+    schedule of the same block body: equal within float32 accumulation order."""
+    lq, lk, d, causal, q_block, k_block, out_dtype = _ONE_PASS_CASES[case]
+    rng = np.random.RandomState(17)
+    q, k, v = _randn(rng, 1, lq, 2, d), _randn(rng, 1, lk, 2, d), _randn(rng, 1, lk, 2, d)
+    (one, split), passes = _backward_both_paths(
+        monkeypatch, q, k, v, causal, q_block, k_block, out_dtype=out_dtype)
+    assert passes == [1, 2]
+    for a, b, name in zip(one, split, "qkv"):
+        assert a.dtype == b.dtype == (out_dtype or jnp.float32)
+        _close(a, b, atol=2e-5, rtol=1e-5, mxu=0.05, err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("q_offset,k_offset", [(128, 128), (128, 0), (0, 128)],
+                         ids=["diagonal", "visible", "hidden"])
+def test_flash_backward_one_pass_matches_split_under_traced_offsets(
+        q_offset, k_offset, monkeypatch):
+    """The ring's backward step: traced offsets, float32 outputs, the classes
+    decided at run time. A shard wholly after the queries gives zeros."""
+    rng = np.random.RandomState(19)
+    q, k, v = (_randn(rng, 1, 128, 2, 16) for _ in range(3))
+    (one, split), passes = _backward_both_paths(
+        monkeypatch, q, k, v, True, 32, 32, q_offset=q_offset, k_offset=k_offset,
+        out_dtype=jnp.float32)
+    assert passes == [1, 2]
+    for a, b, name in zip(one, split, "qkv"):
+        assert a.dtype == jnp.float32
+        _close(a, b, atol=2e-5, rtol=1e-5, mxu=0.05, err_msg=f"d{name}")
+        assert bool(jnp.any(a != 0)) == (k_offset <= q_offset)
+
+
+@pytest.mark.parametrize("length,causal,want", [
+    (1024, True, (1, 2, 1)),    # 512 x 512 tiles: two on the diagonal, one below, one above
+    (300, True, (0, 1, 0)),     # ragged, one tile
+    (2048, True, (6, 4, 6)),
+], ids=["L1024-causal", "L300-ragged", "L2048-causal"])
+def test_flash_backward_gauges_equal_hand_count(length, causal, want):
+    """``flash.bwd.tiles_*`` (score tiles of one (batch, head) by class) and
+    ``flash.bwd.passes``, set when the backward is traced."""
+    from autodist_tpu import telemetry
+
+    qkv = jax.ShapeDtypeStruct((1, length, 2, 64), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=causal).astype(jnp.float32).sum()
+
+    jax.eval_shape(jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv)
+    got = tuple(telemetry.gauge(f"flash.bwd.tiles_{name}").value
+                for name in ("plain", "masked", "skipped"))
+    assert got == want
+    assert telemetry.gauge("flash.bwd.passes").value == 1
+
+
+def test_flash_backward_byte_limit_switches_the_path(monkeypatch):
+    """Past ``_RESIDENT_DQ_BYTES`` of float32 dQ a (batch, head) the backward
+    is the two-kernel schedule: the gradients of ``flash_attention`` are the
+    same on either side of the limit."""
+    import importlib
+    from autodist_tpu import telemetry
+    fa = importlib.import_module("autodist_tpu.ops.flash_attention")
+
+    rng = np.random.RandomState(23)
+    q, k, v = (_randn(rng, 1, 96, 2, 16) for _ in range(3))
+
+    def grads():
+        return jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, q_block=32, k_block=32) ** 2), argnums=(0, 1, 2))(q, k, v)
+
+    resident = 96 * 16 * 4          # dQ of one (batch, head), float32
+    got = []
+    for limit, passes in ((resident, 1), (resident - 1, 2)):
+        monkeypatch.setattr(fa, "_RESIDENT_DQ_BYTES", limit)
+        got.append(grads())
+        assert telemetry.gauge("flash.bwd.passes").value == passes
+    for a, b, name in zip(*got, "qkv"):
+        _close(a, b, atol=2e-5, rtol=1e-5, mxu=0.05, err_msg=f"d{name}")
+
+
 def test_kernel_names_unchanged():
     from autodist_tpu.ops import named_call
     assert named_call.KERNEL_NAMES == (

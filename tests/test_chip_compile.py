@@ -80,6 +80,9 @@ def test_flash_attention_fwd_bwd_compiles(chip, batch, length, heads, depth):
     text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1, 2)), chip,
                           qkv, qkv, qkv)
     assert "tpu_custom_call" in text
+    # one pass while float32 dQ of a (batch, head) stays in VMEM, two kernels past it
+    one_pass = -(-length // 512) * 512 * depth * 4 <= fa._RESIDENT_DQ_BYTES
+    assert ("flash_bwd_dq" in text) != one_pass
 
 
 def test_flash_carry_variant_compiles(chip):
@@ -96,6 +99,25 @@ def test_flash_carry_variant_compiles(chip):
                           ((b, h, length), jnp.float32),
                           ((b, h, length), jnp.float32))
     assert "tpu_custom_call" in text
+
+
+def test_flash_ring_backward_step_compiles(chip):
+    """One step of ring attention's backward as ``_ring_flash_bwd`` runs it:
+    traced offsets (the classes decided from SMEM scalars at run time),
+    float32 dQ / dK / dV, 512-row blocks asked for."""
+    b, length, h, d = 1, 4096, 8, 64
+    qkv = ((b, length, h, d), jnp.bfloat16)
+
+    def step(q, k, v, o, lse, g, q_offset, k_offset):
+        qf, dof, dd, bq, n_q = fa.prepare_backward_q_side(q, o, g, 512)
+        return fa._flash_backward_kv(
+            qf, dof, lse, dd, k, v, True, bq, n_q, 512, False, q.shape,
+            q_offset=q_offset, k_offset=k_offset, out_dtype=jnp.float32)
+
+    text = _compiled_text(step, chip, qkv, qkv, qkv, qkv,
+                          ((b * h, length // 512, 512), jnp.float32), qkv,
+                          ((), jnp.int32), ((), jnp.int32))
+    assert "flash_bwd_dkv" in text and "flash_bwd_dq" not in text
 
 
 # Row count 2,048: the tiles, not the rows, decide what the compiler accepts,

@@ -72,11 +72,19 @@ def _scopes(text: str, names) -> set:
     return {n for n in names if re.search(r'[/"]' + re.escape(n) + "/", text)}
 
 
-def test_lowered_step_names_kernels_and_phases():
+@pytest.mark.parametrize("backward", ["one-pass", "split"])
+def test_lowered_step_names_kernels_and_phases(backward, monkeypatch):
+    """The flash backward is one kernel, ``flash_bwd_dkv``, while dQ of a
+    (batch, head) stays in VMEM (this shape); ``flash_bwd_dq`` is the second
+    kernel of the split path, forced here through the byte limit."""
+    if backward == "split":
+        fa = importlib.import_module("autodist_tpu.ops.flash_attention")
+        monkeypatch.setattr(fa, "_RESIDENT_DQ_BYTES", 0)
     text = _lm_step_lowered(zero=1).as_text(debug_info=True)
     step_kernels = [k for k in named_call.KERNEL_NAMES
-                    if k != "flash_carry" and not k.startswith("moe_")]
-    assert _scopes(text, step_kernels) == set(step_kernels)
+                    if k != "flash_carry" and not k.startswith("moe_")
+                    and (k != "flash_bwd_dq" or backward == "split")]
+    assert _scopes(text, named_call.KERNEL_NAMES) == set(step_kernels)
     # ZeRO's constrain_update is the reduction under AllReduce (the implicit
     # lowering leaves the all-reduce to XLA, so it has no scope of its own).
     assert _scopes(text, PHASES) == set(PHASES)

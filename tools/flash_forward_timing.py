@@ -1,21 +1,24 @@
 #!/usr/bin/env python3
-"""Time the flash forward kernel alone, on the chip, at named shapes.
+"""Time the flash kernels alone, on the chip, at named shapes.
 
-The instrument behind the block choice in ``ops/flash_attention.py``
-(``_forward_blocks``): one jitted ``_flash_forward`` (or one carry step) per
-shape, run under the profiler, and the kernel's own device time read from the
-trace's ``XLA Ops`` events named ``flash_fwd`` / ``flash_carry`` (the host
-clock around the whole call, transposes included, is printed beside it). One
-JSON line a measurement on standard output.
+The instrument behind the block choices in ``ops/flash_attention.py``
+(``_forward_blocks``, ``_backward_blocks``): one jitted ``_flash_forward``,
+carry step or ``_flash_backward`` (on residuals prepared outside the trace)
+per shape, run under the profiler, and the kernels' own device time read from
+the trace's ``XLA Ops`` events named ``flash_fwd`` / ``flash_carry`` /
+``flash_bwd_dkv`` + ``flash_bwd_dq`` (summed, and each under ``parts``; the
+host clock around the whole call, transposes included, is printed beside it).
+One JSON line a measurement on standard output.
 
     python tools/flash_forward_timing.py                     # every shape
-    python tools/flash_forward_timing.py --shapes cell,l4096
+    python tools/flash_forward_timing.py --shapes cell,l4096,bwd-cell,bwd-olmoe
     python tools/flash_forward_timing.py --blocks 512,512,128 --blocks 256,1024,256
+    python tools/flash_forward_timing.py --bwd-blocks 512,1024
     python tools/flash_forward_timing.py --root .archive_check/parent   # another checkout
 
-``--blocks bq,bk,sub`` overrides the forward's choice (a checkout whose forward
-has no ``_forward_blocks`` runs its fixed default and takes no override).
-Needs the TPU: a time from the CPU's interpreter says nothing.
+``--blocks bq,bk,sub`` overrides the forward's choice, ``--bwd-blocks bq,bk``
+the backward's (a checkout whose kernels run a fixed default takes no
+override). Needs the TPU: a time from the CPU's interpreter says nothing.
 """
 
 import argparse
@@ -37,8 +40,21 @@ SHAPES = {
     # shard wholly before the queries
     "carry-diag": (4, 4096, 16, 64, True, "carry"),
     "carry-visible": (4, 4096, 16, 64, True, "carry"),
+    # the backward on a forward's residuals
+    "bwd-cell": (8, 1024, 16, 64, True, "bwd"),
+    "bwd-l4096": (4, 4096, 16, 64, True, "bwd"),
+    "bwd-d128": (4, 2048, 16, 128, True, "bwd"),
+    "bwd-noncausal": (8, 1024, 16, 64, False, "bwd"),
+    "bwd-olmoe": (4, 4096, 16, 128, True, "bwd"),     # olmoe-pretrain-4k's call
+    "bwd-l16384": (1, 16384, 8, 64, True, "bwd"),     # past the one-pass limit: split
+    # one ring step of the backward as ``_ring_flash_bwd`` runs it: traced
+    # offsets, float32 outputs, 512-row blocks asked for
+    "bwd-ring-diag": (4, 4096, 16, 64, True, "ring-bwd"),
+    "bwd-ring-visible": (4, 4096, 16, 64, True, "ring-bwd"),
 }
-KERNELS = {"fwd": "flash_fwd", "carry": "flash_carry"}
+KERNELS = {"fwd": ("flash_fwd",), "carry": ("flash_carry",),
+           "bwd": ("flash_bwd_dkv", "flash_bwd_dq"),
+           "ring-bwd": ("flash_bwd_dkv", "flash_bwd_dq")}
 
 
 def kernel_ms(trace_dir: str, kernel: str):
@@ -60,7 +76,7 @@ def kernel_ms(trace_dir: str, kernel: str):
     return out
 
 
-def build(fa, name):
+def build(fa, name, blocks=None):
     import jax
     import jax.numpy as jnp
 
@@ -74,6 +90,26 @@ def build(fa, name):
         fn = jax.jit(lambda q, k, v: fa._flash_forward(
             q, k, v, causal, default, default, False))
         args = (q, k, v)
+    elif kind in ("bwd", "ring-bwd"):
+        ring = kind == "ring-bwd"
+        chooses = hasattr(fa, "_backward_blocks") and not ring
+        block = None if chooses else fa.DEFAULT_Q_BLOCK
+        # the lse plane's rows are the forward's q block: the backward's q tile
+        out, lse = jax.jit(lambda q, k, v: fa._flash_forward(
+            q, k, v, causal, blocks[0] if blocks else block, block, False))(q, k, v)
+        g = jax.random.normal(jax.random.PRNGKey(1), q.shape, q.dtype)
+        if ring:
+            k_offset = length if name == "bwd-ring-diag" else 0
+            fn = jax.jit(lambda q, k, v, o, lse, g, q_off, k_off:
+                         fa._flash_backward(
+                             q, k, v, o, lse, g, causal, block, block, False,
+                             q_offset=q_off, k_offset=k_off,
+                             out_dtype=jnp.float32))
+            args = (q, k, v, out, lse, g, jnp.int32(length), jnp.int32(k_offset))
+        else:
+            fn = jax.jit(lambda q, k, v, o, lse, g: fa._flash_backward(
+                q, k, v, o, lse, g, causal, block, block, False))
+            args = (q, k, v, out, lse, g)
     else:
         carry = (jnp.zeros((b, h, length, d), jnp.float32),
                  jnp.full((b, h, length), -1e30, jnp.float32),
@@ -90,11 +126,13 @@ def build(fa, name):
 def measure(fa, name, blocks, calls):
     import jax
 
+    kind = SHAPES[name][5]
     if blocks is not None:
-        if not hasattr(fa, "_forward_blocks"):
-            raise SystemExit("--blocks: this checkout's forward has a fixed default")
-        fa._forward_blocks = lambda *a, **kw: blocks
-    fn, args = build(fa, name)
+        chooser = "_backward_blocks" if kind == "bwd" else "_forward_blocks"
+        if not hasattr(fa, chooser):
+            raise SystemExit(f"this checkout has no {chooser}: a fixed default")
+        setattr(fa, chooser, lambda *a, **kw: blocks)
+    fn, args = build(fa, name, blocks if kind == "bwd" else None)
     jax.block_until_ready(fn(*args))
     jax.block_until_ready(fn(*args))
     with tempfile.TemporaryDirectory() as trace_dir:
@@ -103,18 +141,24 @@ def measure(fa, name, blocks, calls):
             outs = [fn(*args) for _ in range(calls)]
             jax.block_until_ready(outs)
             call = (time.perf_counter() - t0) / calls * 1e3
-        kernel = sorted(kernel_ms(trace_dir, KERNELS[SHAPES[name][5]]))
-    if not kernel:
-        raise SystemExit(f"{name}: the trace holds no {KERNELS[SHAPES[name][5]]} event")
-    record = {"shape": name, "blocks": blocks, "events": len(kernel),
-              "kernel_ms_median": kernel[len(kernel) // 2],
-              "kernel_ms_min": kernel[0], "call_ms_host": call}
-    if SHAPES[name][5] == "fwd":
-        from autodist_tpu import telemetry
-        gauges = {k: v for k, v in telemetry.snapshot().items()
-                  if k.startswith("flash.fwd.tiles_")}
-        if gauges:      # a checkout older than the gauges has none
-            record["tiles"] = gauges
+        parts = {kernel: sorted(kernel_ms(trace_dir, kernel))
+                 for kernel in KERNELS[kind]}
+    parts = {kernel: ms for kernel, ms in parts.items() if ms}
+    if not parts:
+        raise SystemExit(f"{name}: the trace holds no {KERNELS[kind]} event")
+    record = {"shape": name, "blocks": blocks,
+              "events": sum(len(ms) for ms in parts.values()),
+              "kernel_ms_median": sum(ms[len(ms) // 2] for ms in parts.values()),
+              "kernel_ms_min": sum(ms[0] for ms in parts.values()),
+              "call_ms_host": call}
+    if len(KERNELS[kind]) > 1:      # the one-pass backward holds no flash_bwd_dq
+        record["parts"] = {kernel: ms[len(ms) // 2] for kernel, ms in parts.items()}
+    from autodist_tpu import telemetry
+    prefix = {"fwd": "flash.fwd.", "bwd": "flash.bwd."}.get(kind)
+    gauges = {k: v for k, v in telemetry.snapshot().items()
+              if prefix and k.startswith(prefix)}
+    if gauges:      # a checkout older than the gauges has none
+        record["gauges"] = gauges
     return record
 
 
@@ -124,7 +168,9 @@ def main(argv=None):
         os.path.abspath(__file__))), help="checkout to import autodist_tpu from")
     parser.add_argument("--shapes", default=",".join(SHAPES))
     parser.add_argument("--blocks", action="append", default=[],
-                        help="bq,bk,sub override; may repeat")
+                        help="the forward's bq,bk,sub override; may repeat")
+    parser.add_argument("--bwd-blocks", action="append", default=[],
+                        help="the backward's bq,bk override; may repeat")
     parser.add_argument("--calls", type=int, default=20)
     args = parser.parse_args(argv)
 
@@ -133,11 +179,13 @@ def main(argv=None):
     if jax.default_backend() != "tpu":
         raise SystemExit(f"needs the TPU, the backend is {jax.default_backend()!r}")
     fa = importlib.import_module("autodist_tpu.ops.flash_attention")
-    plans = [tuple(int(x) for x in b.split(",")) for b in args.blocks] or [None]
+
+    def plans(flags):
+        return [tuple(int(x) for x in b.split(",")) for b in flags] or [None]
+
+    overrides = {"fwd": plans(args.blocks), "bwd": plans(args.bwd_blocks)}
     for name in args.shapes.split(","):
-        for blocks in plans:
-            if blocks is not None and SHAPES[name][5] != "fwd":
-                continue
+        for blocks in overrides.get(SHAPES[name][5], [None]):
             print(json.dumps({"root": args.root, **measure(fa, name, blocks,
                                                            args.calls)}),
                   flush=True)
