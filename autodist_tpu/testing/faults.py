@@ -17,7 +17,7 @@ kind               instrumented site                           effect
                                                                :class:`WorkerCrashed`
 ``worker_hang``    same sites                                  bounded
                                                                ``time.sleep(for_s)``
-``nan_grads``      ``train()``'s per-step loop                 batch floats
+``nan_grads``      ``train()``'s batch source                  batch floats
                                                                NaN-filled (real
                                                                NaN gradients
                                                                through the real

@@ -53,11 +53,9 @@ def _observe_health(monitor, runner, step: int, losses,
 def _make_meter(first_batch: PyTree, batch_size: Optional[int],
                 log_every: int) -> ThroughputMeter:
     """Meter sized lazily from the first batch: the largest leading dim fixes
-    the example count per step (shared by the per-step and unrolled loops so
-    their examples/s can never diverge for identical configs). A batch that
-    already went through ``shard_batch`` under gradient accumulation carries
-    ``MicroBatched`` leaves laid out ``[k, B/k, ...]`` — fold those back to
-    ``B`` (the prefetched per-step loop meters the transformed batch)."""
+    the example count per step. A batch that already went through
+    ``shard_batch`` under gradient accumulation carries ``MicroBatched``
+    leaves laid out ``[k, B/k, ...]`` — fold those back to ``B``."""
     n = batch_size
     if n is None:
         dims = []
@@ -117,18 +115,18 @@ def train(runner, params: PyTree,
     winner) and otherwise behaves as ``unroll=1``; pass an explicit value
     to override the tuned knob.
 
-    ``unroll=K`` (K > 1) switches the loop to the fused dispatch-ahead
-    pipeline: K consecutive batches are stacked into one pre-sharded block and
-    run as ONE compiled K-step program (:meth:`DistributedRunner.run_many` —
-    bit-identical to K per-step calls), while the host gathers and pre-shards
-    the next block behind the running one. Checkpoint and eval cadence points
-    force block boundaries, so saves/evals fire at exactly the per-step
-    loop's steps and resume semantics are unchanged (step i still consumes
+    ``unroll=K`` (K > 1) makes one dispatch a block of steps: K consecutive
+    batches are stacked into one pre-sharded block and run as ONE compiled
+    K-step program (:meth:`DistributedRunner.run_many` — bit-identical to K
+    per-step calls), while the host gathers and pre-shards the next block
+    behind the running one. Checkpoint and eval cadence points force block
+    boundaries, so saves/evals fire at exactly the steps ``unroll=1`` fires
+    them at and resume semantics are unchanged (step i still consumes
     batch i); only logging moves to block granularity (the first block is the
     meter's warmup, periods close at the first block end with ``log_every``
     post-warmup steps, and ``on_metrics`` receives the block's last loss).
-    Runners without fused support (async-PS, remote workers) fall back to the
-    per-step loop with a warning.
+    Runners without fused support (async-PS, remote workers) fall back to
+    one step a dispatch with a warning.
 
     ``prefetch_depth`` arms the async input pipeline
     (:mod:`autodist_tpu.data.prefetch`): a background producer pulls up to
@@ -280,8 +278,8 @@ def train(runner, params: PyTree,
         return final_state
 
     # Recover-and-resume policy (parallel/recovery.py): under
-    # AUTODIST_HEALTH_ACTION=recover (or the alert-engine twin) the loops
-    # push the state into a bounded last-known-good ring at every HEALTHY
+    # AUTODIST_HEALTH_ACTION=recover (or the alert-engine twin) the loop
+    # pushes the state into a bounded last-known-good ring at every HEALTHY
     # log boundary, and an anomaly rolls back to the newest good snapshot
     # and re-enters the loop — bounded by AUTODIST_RECOVER_MAX attempts
     # before escalating to the existing halt.
@@ -307,50 +305,43 @@ def train(runner, params: PyTree,
             "batches(step) source for exact replay)")
 
     def _run_attempt(attempt_state: TrainState) -> TrainState:
-        """One pass of the chosen loop from ``attempt_state``'s own step —
-        feeds are (re)built per attempt so a rollback's replay pulls the
-        rolled-back step range, not the crashed attempt's readahead."""
-        start_i = int(attempt_state.step)
+        """One pass of the loop from ``attempt_state``'s own step — the
+        source and its feed are (re)built per attempt so a rollback's replay
+        pulls the rolled-back step range, not the crashed attempt's
+        readahead."""
+        source = _BatchSource(next_batch, batch_iter, int(attempt_state.step),
+                              steps)
         if use_blocks:
-            # Async input pipeline for the fused loop: the producer gathers
-            # the NEXT blocks (clipped at the same cadence boundaries the
-            # sync path uses) and pre-shards them (shard_block = stacking +
-            # async device_put) up to prefetch_depth blocks ahead, so the
-            # BatchBlock queue feeds without blocking at block assembly.
-            feed = None
-            if prefetch_depth > 0:
-                feed = _BlockFeed(
-                    runner, next_batch, batch_iter, start_i, steps, unroll,
-                    _boundary_fn(steps,
-                                 save_every if saver is not None else 0,
-                                 eval_every), prefetch_depth)
-            try:
-                return _unrolled_loop(
-                    runner, attempt_state, next_batch, batch_iter, start_i,
-                    steps, unroll, saver, prefix_base, save_participant,
-                    save_every, async_save, log_every, batch_size,
-                    on_metrics, eval_every, eval_batch, eval_fn, on_eval,
-                    monitor, feed, ring)
-            finally:
-                if feed is not None:
-                    feed.close()
-        # Async input pipeline: with prefetch_depth > 0 a background
-        # producer pulls host batches AND applies the feed remapping
-        # (shard_batch = async device_put) up to `depth` ahead, so the
-        # loop's train.data_wait span measures only the residual queue
-        # wait. The producer books data.producer_wait/queue_depth, keeping
-        # a slow loader visible.
-        feed = _step_feed(runner, next_batch, batch_iter, start_i, steps,
-                          prefetch_depth) if prefetch_depth > 0 else None
+            periods = (save_every if saver is not None else 0, eval_every)
+            pull = lambda: source.pull_block(unroll, periods)  # noqa: E731
+            shard = runner.shard_block
+        else:
+            pull = source.pull
+            # Async/remote regimes prefetch host batches only.
+            shard = getattr(runner, "shard_batch", None)
+            if not callable(shard) or getattr(runner, "_is_remote_worker",
+                                              False):
+                shard = None
+        # Async input pipeline: with prefetch_depth > 0 a background producer
+        # pulls host batches (or cadence-clipped blocks of them) AND applies
+        # the feed remapping (shard_batch, or shard_block = stacking + async
+        # device_put) up to `depth` items ahead, so the loop's
+        # train.data_wait span measures only the residual queue wait. The
+        # producer books data.producer_wait/queue_depth, keeping a slow
+        # loader visible.
+        producer = _prefetch.PrefetchProducer(
+            pull, shard, depth=prefetch_depth,
+            workers=_prefetch.default_prefetch_workers(),
+            name="train-feed") if prefetch_depth > 0 else None
         try:
-            return _per_step_loop(
-                runner, attempt_state, feed, next_batch, batch_iter,
-                start_i, steps, saver, prefix_base, save_participant,
-                save_every, async_save, log_every, batch_size, on_metrics,
-                eval_every, eval_batch, eval_fn, on_eval, monitor, ring)
+            return _loop(
+                runner, attempt_state, source, producer, pull, use_blocks,
+                steps, saver, prefix_base, save_participant, save_every,
+                async_save, log_every, batch_size, on_metrics, eval_every,
+                eval_batch, eval_fn, on_eval, monitor, ring)
         finally:
-            if feed is not None:
-                feed.close()
+            if producer is not None:
+                producer.close()
 
     attempt = 0
     last_fail_step = None
@@ -382,423 +373,158 @@ def train(runner, params: PyTree,
     return _finish(state)
 
 
-def _step_feed(runner, next_batch, batch_iter, start: int, steps: int,
-               depth: int, workers: Optional[int] = None):
-    """The per-step loop's async feed: a :class:`PrefetchProducer` pulling
-    the batch source in step order and applying ``runner.shard_batch``
-    (when the runner has one — async/remote regimes prefetch host batches
-    only) on the producer side. Pulls stop at ``steps``: a callable
+class _BatchSource:
+    """Host batches in step order from ``fn(step) -> batch`` or an iterator:
+    the ONE place ``train()`` pulls a batch, called inline by the loop or by
+    the prefetch producer's thread. Pulls stop at ``steps`` — a callable
     source is never invoked past the last step it could train (readahead
-    must not call user code out of the run's contract)."""
-    if next_batch is not None:
-        counter = iter(range(start, steps))
-        pull = lambda: next_batch(next(counter))  # noqa: E731
-    else:
-        pull = lambda: next(batch_iter)           # noqa: E731
-    shard = getattr(runner, "shard_batch", None)
-    transform = shard if (callable(shard)
-                          and not getattr(runner, "_is_remote_worker",
-                                          False)) else None
-    return _prefetch.PrefetchProducer(pull, transform, depth=depth,
-                                      workers=workers
-                                      or _prefetch.default_prefetch_workers(),
-                                      name="train-feed")
+    must not call user code out of the run's contract) — and at the
+    iterator's exhaustion, which is reported once."""
 
+    def __init__(self, next_batch, batch_iter, start: int, steps: int):
+        self.first = None   # the first batch pulled: sizes the meter
+        self._next_batch = next_batch
+        self._batch_iter = batch_iter
+        self._cursor = start
+        self._steps = steps
+        self._exhausted = False
 
-def _per_step_loop(runner, state: TrainState, feed, next_batch, batch_iter,
-                   start: int, steps: int, saver, prefix_base,
-                   save_participant, save_every: int, async_save: bool,
-                   log_every: int, batch_size: Optional[int], on_metrics,
-                   eval_every: int, eval_batch, eval_fn, on_eval,
-                   monitor, ring=None) -> TrainState:
-    """The classic one-dispatch-per-step loop (``unroll=1``), fed either
-    synchronously or from the async prefetch producer (``feed``).
-    ``ring`` (a :class:`recovery.SnapshotRing`) receives the state at every
-    boundary that closes healthy — the recover action's rollback targets."""
-    meter = None
-    loss = None
-    # Health monitoring: per-step device losses accumulate here (tiny device
-    # scalars, no sync) and are read back together at the log boundary — so
-    # the spike detector sees EVERY step's loss while the loop still syncs
-    # only once per period.
-    pending_losses = []
-    for step_i in range(start, steps):
-        if feed is not None:
-            try:
-                with telemetry.span("train.data_wait"):
-                    batch = next(feed)
-            except StopIteration:
-                logging.info("train: batch iterator exhausted at step %d",
-                             step_i)
-                break
-        elif next_batch is not None:
-            with telemetry.span("train.data_wait"):
-                batch = next_batch(step_i)
+    def pull(self):
+        """The batch of the next step; ``StopIteration`` when the run is
+        over."""
+        i = self._cursor
+        if self._exhausted or i >= self._steps:
+            raise StopIteration
+        if self._next_batch is not None:
+            batch = self._next_batch(i)
         else:
             try:
-                with telemetry.span("train.data_wait"):
-                    batch = next(batch_iter)
+                batch = next(self._batch_iter)
             except StopIteration:
-                logging.info("train: batch iterator exhausted at step %d", step_i)
-                break
-        if _faults.armed() and _faults.should_fire("nan_grads", step=step_i):
+                self._exhausted = True
+                logging.info("train: batch iterator exhausted at step %d", i)
+                raise
+        if _faults.armed() and _faults.should_fire("nan_grads", step=i):
             # Chaos harness (testing/faults.py): NaN-fill the batch's float
             # leaves so the REAL compiled step produces real NaN gradients —
             # the recover-action tests and bench drive genuine anomalies,
-            # not mocks. Un-armed cost: one module-global read per step.
-            logging.warning("faults: injecting NaN batch at step %d", step_i)
+            # not mocks. Keyed by the step the batch is FOR, so it fires in
+            # a block as it does alone; a producer's readahead can consume a
+            # firing for a step a rollback then never reaches. Un-armed
+            # cost: one module-global read per step.
+            logging.warning("faults: injecting NaN batch at step %d", i)
             batch = _faults.corrupt_batch(batch)
-        with telemetry.span("train.dispatch"):
-            state, fetched = runner.run(state, batch)
-        loss = fetched[0] if isinstance(fetched, tuple) else fetched
+        if self.first is None:
+            self.first = batch
+        self._cursor = i + 1
+        return batch
+
+    def pull_block(self, unroll: int, periods) -> list:
+        """Up to ``unroll`` batches from the next step on, clipped so that a
+        block ENDS at every multiple of a nonzero ``periods`` entry (the
+        save and eval cadences; ``pull`` stops it at ``steps``) — which keeps
+        checkpoint/eval/resume semantics those of one step a dispatch. A
+        source that exhausts mid-block still emits the partial block: those
+        steps were consumed and must train."""
+        i = self._cursor
+        end = min([i + unroll] + [(i // p + 1) * p for p in periods if p])
+        blk = []
+        for _ in range(end - i):
+            try:
+                blk.append(self.pull())
+            except StopIteration:
+                break
+        if not blk:
+            raise StopIteration
+        return blk
+
+
+def _flat_losses(pending) -> np.ndarray:
+    """One host array of per-step losses from a period's dispatches (device
+    scalars, or ``[K]`` stacks of a block), read back together."""
+    return np.concatenate([np.asarray(l).reshape(-1)
+                           for l in jax.device_get(pending)])
+
+
+def _loop(runner, state: TrainState, source: _BatchSource, producer, pull,
+          use_blocks: bool, steps: int, saver, prefix_base, save_participant,
+          save_every: int, async_save: bool, log_every: int,
+          batch_size: Optional[int], on_metrics, eval_every: int, eval_batch,
+          eval_fn, on_eval, monitor, ring) -> TrainState:
+    """The training loop: pull, dispatch, meter, and at a closed log period
+    the boundary; then the eval / save cadence. What ONE dispatch is, is the
+    only thing ``unroll`` decides.
+
+    ``unroll == 1``: ``runner.run(state, batch)`` advances one step and
+    fetches a scalar loss. ``unroll > 1`` (``use_blocks``): up to ``unroll``
+    consecutive batches, clipped at cadence points, run as one compiled
+    K-step scan (:meth:`DistributedRunner.run_many`) fetching a ``[K]`` loss
+    stack; dispatch is asynchronous, so the host gathers and pre-shards the
+    next block while the device executes this one, and losses are read back
+    only when a ``log_every`` period closes at a block end.
+
+    With ``producer`` (``train(prefetch_depth>0)``) the items arrive
+    pre-sharded from its thread instead of from ``pull`` here:
+    ``train.data_wait`` then measures only the residual queue wait, and the
+    producer's ``data.*`` telemetry carries the loader cost. ``ring`` (a
+    :class:`recovery.SnapshotRing`) receives the state at every boundary
+    that closes healthy — the recover action's rollback targets."""
+    next_item = pull if producer is None else producer.__next__
+    meter = None
+    step_i = int(state.step)
+    # Health monitoring: every dispatch's device losses accumulate here (tiny
+    # device arrays, no sync) and are read back together at the log boundary
+    # — so the spike detector sees EVERY step's loss while the loop still
+    # syncs only once per period.
+    pending_losses = []
+    while True:
+        try:
+            with telemetry.span("train.data_wait"):
+                item = next_item()
+        except StopIteration:
+            break
+        if use_blocks:
+            if producer is None:
+                # A train.dispatch SIBLING, not a child: the attribution
+                # plane adds these spans to the host phase on that footing.
+                with telemetry.span("runner.shard_block"):
+                    item = runner.shard_block(item)
+            n = item.length
+            with telemetry.span("train.dispatch", steps=n):
+                state, fetched = runner.run_many(state, item)
+        else:
+            n = 1
+            with telemetry.span("train.dispatch"):
+                state, fetched = runner.run(state, item)
+        losses = fetched[0] if isinstance(fetched, tuple) else fetched
+        step_i += n
         if monitor is not None:
-            pending_losses.append(loss)
+            pending_losses.append(losses)
+        # The producer's fill (0 without one: items are pulled exactly at
+        # their dispatch). 0 under prefetch means the loader is not keeping
+        # up — the host failed to stay ahead of the device.
+        queue_depth = producer.queue_depth() if producer is not None else 0
+        if telemetry.enabled():
+            telemetry.gauge("train.dispatch_queue_depth").set(queue_depth)
         if meter is None and log_every:
-            meter = _make_meter(batch, batch_size, log_every)
+            meter = _make_meter(source.first, batch_size, log_every)
         if meter is not None:
-            # The meter syncs (device->host read of the loss) only at its period
-            # boundaries — one boundary per log_every steps, not per step — and
-            # excludes its warmup step, so boundaries land at 1 + k*log_every
-            # local steps.
-            rate = meter.step(sync=loss)
+            # The meter syncs (device->host read of the losses) only where a
+            # period closes — not per dispatch — and its first dispatch is
+            # warmup (it carries the compile), so boundaries land at
+            # 1 + k*log_every local steps for single steps, and at the first
+            # block end with >= log_every post-warmup steps for blocks.
+            rate = meter.step_many(n, sync=losses)
             if rate is not None:
-                # From the meter's return to the end of the boundary block:
-                # what the device waits for before the next feed. Its two
-                # children are what only a traced run pays (planes) and the
-                # caller's callback; the rest is what every run pays.
-                with telemetry.span("train.boundary"):
-                    # The period's attribution closes HERE — after the meter's
-                    # boundary sync recorded its readback span, before the
-                    # snapshot below is emitted — so the train.attr.*/mfu
-                    # gauges it books describe exactly this period.
-                    attr = _profiling.observe_period(step_i + 1) \
-                        if _profiling.active() else None
-                    # Async-PS runs append their transport accounting (zero-copy
-                    # wire counters) so per-period logs show parameter/gradient
-                    # traffic next to throughput. `q` is the input queue depth
-                    # (the prefetch producer's fill with prefetch_depth > 0,
-                    # else 0 — 0 under prefetch means the loader is not keeping
-                    # up), `rb` the seconds this period spent blocked on
-                    # device->host readback — together they say whether a slow
-                    # period was compute, readback, or host-side stall, from
-                    # the log line alone.
-                    stats = getattr(runner, "wire_stats", None)
-                    stats = stats() if callable(stats) else None
-                    logging.info("train: step %d loss %.4f %.1f examples/s "
-                                 "| q %d rb %.3fs%s%s",
-                                 step_i + 1, float(loss), rate,
-                                 feed.queue_depth() if feed is not None else 0,
-                                 meter.last_readback_s,
-                                 f" | {stats.format_line()}" if stats else "",
-                                 _profiling.format_attr_line(attr))
-                    # The period's throughput as a gauge: the fleet console
-                    # (tools/adfleet.py) compares steps/s across processes off
-                    # the status opcode, so the rate must live in the registry,
-                    # not just the log line. One gauge set per log boundary.
-                    telemetry.gauge("train.steps_per_s").set(
-                        round(rate / meter.batch_size, 4))
-                    if telemetry.enabled():
-                        with telemetry.span("train.boundary.planes"):
-                            # Memory gauges first so the snapshot emitted below
-                            # carries this boundary's live-buffer/HBM readings (and
-                            # the opt-state footprint ZeRO sharding divides). The
-                            # census tags re-point at THIS boundary's state — the
-                            # step donates its inputs, so last boundary's claims
-                            # are dead weakrefs by now.
-                            _memplane.tag("params", state.params)
-                            _memplane.tag("opt_state", state.opt_state)
-                            telemetry.sample_device_memory(
-                                opt_state=state.opt_state)
-                            telemetry.emit_metrics(global_step=step_i + 1)
-                    if monitor is not None:
-                        _observe_health(monitor, runner, step_i + 1,
-                                        jax.device_get(pending_losses), state)
-                        pending_losses = []
-                    # Metric-history sample LAST at the boundary, so the sample
-                    # (and the alert rules it evaluates) sees this period's
-                    # attr/mfu/health/throughput gauges. An AlertHalt under
-                    # AUTODIST_ALERT_ACTION=halt propagates from here — the
-                    # train loop is the sampler a halt can actually stop — with
-                    # the LIVE TrainState attached (the HealthHalt contract:
-                    # a halt leaves the state checkpointable, not discarded).
-                    try:
-                        _history.maybe_sample(step_i + 1)
-                    except telemetry.AlertHalt as e:
-                        e.state = state
-                        raise
-                    # The boundary closed HEALTHY (no health anomaly raised, no
-                    # alert fired past this point): this state is a valid
-                    # rollback target. push() DEEP-COPIES on device via the
-                    # ring's copy_fn — the step donates its input buffers, so a
-                    # bare reference would be deleted by the next dispatch.
-                    if ring is not None:
-                        ring.push(step_i + 1, state)
-                        if telemetry.enabled():
-                            # Ring census: the deep-copied snapshot states are
-                            # pinned device memory nothing else accounts for.
-                            _memplane.tag("snapshots", ring.states())
-                    if on_metrics is not None:
-                        with telemetry.span("train.boundary.on_metrics"):
-                            on_metrics(step_i + 1, float(loss), rate)
-        if (eval_every and (step_i + 1) % eval_every == 0
+                _log_boundary(runner, state, step_i, losses, rate, meter,
+                              queue_depth, monitor, pending_losses, ring,
+                              on_metrics)
+        if (eval_every and step_i % eval_every == 0
                 and not getattr(runner, "_is_remote_worker", False)):
             # Async remote workers skip: their local state is a compile-shapes
             # template and AsyncPSRunner.evaluate raises there by design. Sync
             # SPMD processes all evaluate together (the compiled eval is a
             # collective program).
-            with telemetry.span("train.eval"):
-                val = runner.evaluate(state, eval_batch, eval_fn)
-            try:
-                logging.info("train: step %d eval %.6f", step_i + 1, float(val))
-            except (TypeError, ValueError):
-                logging.info("train: step %d eval (pytree)", step_i + 1)
-            if on_eval is not None:
-                on_eval(step_i + 1, val)
-        if (saver is not None and save_participant and save_every
-                and (step_i + 1) % save_every == 0 and step_i + 1 < steps):
-            with telemetry.span("train.checkpoint"):
-                saver.save(state, prefix_base, runner=runner,
-                           async_write=async_save)
-
-    if monitor is not None and pending_losses:
-        # End-of-run flush: a NaN in the final partial period (steps not a
-        # multiple of log_every) must still anomaly/snapshot/halt — the
-        # monitor's contract is EVERY step observed, not every full period.
-        _observe_health(monitor, runner, steps,
-                        jax.device_get(pending_losses), state)
-    if meter is not None:
-        meter.finish()   # freeze the run clock: average stays the TRAIN rate
-    return state
-
-
-def _boundary_fn(steps: int, save_every: int, eval_every: int):
-    """``next_boundary(i)``: the first step index after ``i`` where a block
-    must END (a ``save_every``/``eval_every`` multiple, or ``steps``) — ONE
-    clipping rule, shared by the sync gather and the async block feed so
-    their block shapes can never diverge."""
-    boundaries = [p for p in (save_every, eval_every) if p]
-
-    def next_boundary(i: int) -> int:
-        nxt = steps
-        for p in boundaries:
-            nxt = min(nxt, (i // p + 1) * p)
-        return nxt
-
-    return next_boundary
-
-
-class _BlockFeed:
-    """The unrolled loop's async block source: a :class:`PrefetchProducer`
-    whose pulls gather cadence-clipped host blocks (the sync ``gather``'s
-    exact clipping, via the shared boundary fn) and whose transform is
-    ``runner.shard_block`` — so block assembly AND host->HBM transfer run
-    ``depth`` blocks ahead of the device. A source that exhausts mid-block
-    still emits the partial block (the sync path's contract: those steps
-    were consumed and must train)."""
-
-    def __init__(self, runner, next_batch, batch_iter, start: int,
-                 steps: int, unroll: int, next_boundary, depth: int,
-                 workers: Optional[int] = None):
-        self.first_batch = None   # meter sizing; set before the first emit
-        self._next_batch = next_batch
-        self._batch_iter = batch_iter
-        self._cursor = start
-        self._steps = steps
-        self._unroll = unroll
-        self._next_boundary = next_boundary
-        self._exhausted = False
-        self._producer = _prefetch.PrefetchProducer(
-            self._pull, runner.shard_block, depth=depth,
-            workers=workers or _prefetch.default_prefetch_workers(),
-            name="train-feed")
-
-    def _pull(self):
-        i = self._cursor
-        if self._exhausted or i >= self._steps:
-            raise StopIteration
-        blk = []
-        for j in range(min(self._unroll, self._next_boundary(i) - i)):
-            if self._next_batch is not None:
-                blk.append(self._next_batch(i + j))
-            else:
-                try:
-                    blk.append(next(self._batch_iter))
-                except StopIteration:
-                    self._exhausted = True
-                    logging.info("train: batch iterator exhausted at "
-                                 "step %d", i + len(blk))
-                    break
-        if not blk:
-            raise StopIteration
-        if self.first_batch is None:
-            self.first_batch = blk[0]
-        self._cursor = i + len(blk)
-        return blk
-
-    def next_block(self):
-        """The next pre-sharded BatchBlock, or None at the end of the run
-        (exhaustion / ``steps`` reached) — the sync ``gather``'s return
-        contract."""
-        try:
-            return next(self._producer)
-        except StopIteration:
-            return None
-
-    def queue_depth(self) -> int:
-        return self._producer.queue_depth()
-
-    def close(self):
-        self._producer.close()
-
-
-def _unrolled_loop(runner, state: TrainState, next_batch, batch_iter,
-                   start: int, steps: int, unroll: int,
-                   saver, prefix_base, save_participant, save_every: int,
-                   async_save: bool, log_every: int, batch_size: Optional[int],
-                   on_metrics, eval_every: int, eval_batch, eval_fn,
-                   on_eval, monitor=None, feed: Optional[_BlockFeed] = None,
-                   ring=None) -> TrainState:
-    """The fused dispatch-ahead pipeline behind ``train(..., unroll=K)``.
-
-    Consecutive batches are gathered into blocks of up to ``unroll`` steps and
-    run as one compiled K-step scan (:meth:`DistributedRunner.run_many`);
-    while the device executes a block, the host gathers and pre-shards the
-    next one (a one-block dispatch-ahead queue — dispatch is asynchronous, so
-    the prep overlaps device compute). Blocks are clipped so they END exactly
-    at every ``save_every``/``eval_every`` multiple and at ``steps``, which
-    keeps checkpoint/eval/resume semantics identical to the per-step loop;
-    losses are read back (``jax.device_get``) only when a ``log_every``
-    period closes at a block boundary.
-
-    With ``feed`` (a :class:`_BlockFeed`, ``train(prefetch_depth>0)``) the
-    blocks arrive pre-sharded from the async producer instead of being
-    gathered here: ``train.data_wait`` then measures only the residual
-    queue wait, and the producer's ``data.*`` telemetry carries the loader
-    cost."""
-    next_boundary = _boundary_fn(steps,
-                                 save_every if saver is not None else 0,
-                                 eval_every)
-    exhausted = False
-    first_batch = None
-
-    def gather(i: int):
-        """Up to min(unroll, steps-to-next-cadence-point) host batches
-        starting at step index ``i``, pre-sharded; None when the run is
-        over."""
-        nonlocal exhausted, first_batch
-        if feed is not None:
-            with telemetry.span("train.data_wait"):
-                block = feed.next_block()
-            if first_batch is None:
-                first_batch = feed.first_batch
-            return block
-        if exhausted or i >= steps:
-            return None
-        blk = []
-        with telemetry.span("train.data_wait"):
-            for j in range(min(unroll, next_boundary(i) - i)):
-                if next_batch is not None:
-                    blk.append(next_batch(i + j))
-                else:
-                    try:
-                        blk.append(next(batch_iter))
-                    except StopIteration:
-                        exhausted = True
-                        logging.info("train: batch iterator exhausted at "
-                                     "step %d", i + len(blk))
-                        break
-        if not blk:
-            return None
-        if first_batch is None:
-            first_batch = blk[0]
-        with telemetry.span("runner.shard_block"):
-            return runner.shard_block(blk)
-
-    meter = None
-    step_i = start
-    # Health: the period's per-block loss stacks (device [K] arrays), read
-    # back together at the boundary the meter already syncs.
-    pending_losses = []
-    block = gather(step_i)
-    while block is not None:
-        with telemetry.span("train.dispatch", steps=block.length):
-            state, fetched = runner.run_many(state, block)
-        losses = fetched[0] if isinstance(fetched, tuple) else fetched
-        if monitor is not None:
-            pending_losses.append(losses)
-        step_i += block.length
-        # Dispatch-ahead: run_many returns as soon as the K-step program is
-        # enqueued; gather + pre-shard the next block NOW, before any sync
-        # below, so host batch assembly and h->d transfer overlap the device.
-        next_block = gather(step_i)
-        queue_depth = (1 if next_block is not None else 0) \
-            + (feed.queue_depth() if feed is not None else 0)
-        if telemetry.enabled():
-            telemetry.gauge("train.dispatch_queue_depth").set(queue_depth)
-        if meter is None and log_every:
-            meter = _make_meter(first_batch, batch_size, log_every)
-        if meter is not None:
-            rate = meter.step_many(block.length, sync=losses)
-            if rate is not None:
-                # From the meter's return to the end of the boundary block:
-                # what the device waits for before the next feed. Its two
-                # children are what only a traced run pays (planes) and the
-                # caller's callback; the rest is what every run pays.
-                with telemetry.span("train.boundary"):
-                    # Attribution closes at the same boundary the meter synced
-                    # (readback span recorded), before emit_metrics ships the
-                    # snapshot carrying the freshly-booked attr/mfu gauges.
-                    attr = _profiling.observe_period(step_i) \
-                        if _profiling.active() else None
-                    last = float(jax.device_get(losses)[-1])
-                    # `q`: dispatch-ahead queue depth (0 means the host failed to
-                    # stay ahead of the device — data-starved); `rb`: period
-                    # seconds blocked on loss readback.
-                    logging.info("train: step %d loss %.4f %.1f examples/s "
-                                 "| q %d rb %.3fs%s",
-                                 step_i, last, rate, queue_depth,
-                                 meter.last_readback_s,
-                                 _profiling.format_attr_line(attr))
-                    # Steps/s gauge for the fleet console (same contract as the
-                    # per-step loop: the registry carries the rate, not just
-                    # the log line).
-                    telemetry.gauge("train.steps_per_s").set(
-                        round(rate / meter.batch_size, 4))
-                    if telemetry.enabled():
-                        with telemetry.span("train.boundary.planes"):
-                            # Memory gauges first so the emitted snapshot carries
-                            # this boundary's live-buffer/HBM readings (and the
-                            # opt-state footprint ZeRO sharding divides); census
-                            # tags re-pointed first, as in the per-step loop.
-                            _memplane.tag("params", state.params)
-                            _memplane.tag("opt_state", state.opt_state)
-                            telemetry.sample_device_memory(
-                                opt_state=state.opt_state)
-                            telemetry.emit_metrics(global_step=step_i)
-                    if monitor is not None:
-                        flat = np.concatenate([np.asarray(l).reshape(-1) for l
-                                               in jax.device_get(pending_losses)])
-                        _observe_health(monitor, runner, step_i, flat, state)
-                        pending_losses = []
-                    # History sample last: the alert tick sees this boundary's
-                    # freshly-booked gauges (AlertHalt propagates with the live
-                    # state attached, like the per-step loop).
-                    try:
-                        _history.maybe_sample(step_i)
-                    except telemetry.AlertHalt as e:
-                        e.state = state
-                        raise
-                    # Healthy-boundary snapshot for the recover action (the
-                    # per-step loop's contract: push() deep-copies on device to
-                    # survive the step's buffer donation).
-                    if ring is not None:
-                        ring.push(step_i, state)
-                        if telemetry.enabled():
-                            _memplane.tag("snapshots", ring.states())
-                    if on_metrics is not None:
-                        with telemetry.span("train.boundary.on_metrics"):
-                            on_metrics(step_i, last, rate)
-        if eval_every and step_i % eval_every == 0:
             with telemetry.span("train.eval"):
                 val = runner.evaluate(state, eval_batch, eval_fn)
             try:
@@ -812,13 +538,91 @@ def _unrolled_loop(runner, state: TrainState, next_batch, batch_iter,
             with telemetry.span("train.checkpoint"):
                 saver.save(state, prefix_base, runner=runner,
                            async_write=async_save)
-        block = next_block
+
     if monitor is not None and pending_losses:
-        # End-of-run flush (same contract as the per-step loop): the final
-        # partial period's losses/bundle still reach the monitor.
-        flat = np.concatenate([np.asarray(l).reshape(-1) for l
-                               in jax.device_get(pending_losses)])
-        _observe_health(monitor, runner, step_i, flat, state)
+        # End-of-run flush: a NaN in the final partial period (steps not a
+        # multiple of log_every) must still anomaly/snapshot/halt — the
+        # monitor's contract is EVERY step observed, not every full period.
+        _observe_health(monitor, runner, step_i, _flat_losses(pending_losses),
+                        state)
     if meter is not None:
         meter.finish()   # freeze the run clock: average stays the TRAIN rate
     return state
+
+
+def _log_boundary(runner, state: TrainState, step: int, losses, rate: float,
+                  meter: ThroughputMeter, queue_depth: int, monitor,
+                  pending_losses: list, ring, on_metrics):
+    """What a closed log period pays, from the meter's return to the next
+    feed: what the device waits for. The span's two children are what only a
+    traced run pays (planes) and the caller's callback; the rest is what
+    every run pays. The ORDER is the contract — each stage reads what the
+    one before it booked."""
+    with telemetry.span("train.boundary"):
+        # The period's attribution closes HERE — after the meter's boundary
+        # sync recorded its readback span, before the snapshot below is
+        # emitted — so the train.attr.*/mfu gauges it books describe exactly
+        # this period.
+        attr = _profiling.observe_period(step) \
+            if _profiling.active() else None
+        # The meter's sync already read `losses` back: this copies nothing.
+        last = float(np.asarray(losses).reshape(-1)[-1])
+        # Async-PS runs append their transport accounting (zero-copy wire
+        # counters) so per-period logs show parameter/gradient traffic next
+        # to throughput. `q` is the input queue depth, `rb` the seconds this
+        # period spent blocked on device->host readback — together they say
+        # whether a slow period was compute, readback, or host-side stall,
+        # from the log line alone.
+        stats = getattr(runner, "wire_stats", None)
+        stats = stats() if callable(stats) else None
+        logging.info("train: step %d loss %.4f %.1f examples/s "
+                     "| q %d rb %.3fs%s%s",
+                     step, last, rate, queue_depth, meter.last_readback_s,
+                     f" | {stats.format_line()}" if stats else "",
+                     _profiling.format_attr_line(attr))
+        # The period's throughput as a gauge: the fleet console
+        # (tools/adfleet.py) compares steps/s across processes off the
+        # status opcode, so the rate must live in the registry, not just the
+        # log line. One gauge set per log boundary.
+        telemetry.gauge("train.steps_per_s").set(
+            round(rate / meter.batch_size, 4))
+        if telemetry.enabled():
+            with telemetry.span("train.boundary.planes"):
+                # Memory gauges first so the snapshot emitted below carries
+                # this boundary's live-buffer/HBM readings (and the opt-state
+                # footprint ZeRO sharding divides). The census tags re-point
+                # at THIS boundary's state — the step donates its inputs, so
+                # last boundary's claims are dead weakrefs by now.
+                _memplane.tag("params", state.params)
+                _memplane.tag("opt_state", state.opt_state)
+                telemetry.sample_device_memory(opt_state=state.opt_state)
+                telemetry.emit_metrics(global_step=step)
+        if monitor is not None:
+            _observe_health(monitor, runner, step,
+                            _flat_losses(pending_losses), state)
+            pending_losses.clear()
+        # Metric-history sample LAST of the planes, so the sample (and the
+        # alert rules it evaluates) sees this period's attr/mfu/health/
+        # throughput gauges. An AlertHalt under AUTODIST_ALERT_ACTION=halt
+        # propagates from here — the train loop is the sampler a halt can
+        # actually stop — with the LIVE TrainState attached (the HealthHalt
+        # contract: a halt leaves the state checkpointable, not discarded).
+        try:
+            _history.maybe_sample(step)
+        except telemetry.AlertHalt as e:
+            e.state = state
+            raise
+        # The boundary closed HEALTHY (no health anomaly raised, no alert
+        # fired past this point): this state is a valid rollback target.
+        # push() DEEP-COPIES on device via the ring's copy_fn — the step
+        # donates its input buffers, so a bare reference would be deleted by
+        # the next dispatch.
+        if ring is not None:
+            ring.push(step, state)
+            if telemetry.enabled():
+                # Ring census: the deep-copied snapshot states are pinned
+                # device memory nothing else accounts for.
+                _memplane.tag("snapshots", ring.states())
+        if on_metrics is not None:
+            with telemetry.span("train.boundary.on_metrics"):
+                on_metrics(step, last, rate)

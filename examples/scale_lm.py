@@ -1,4 +1,5 @@
-"""Reproduce the README's decoder scaling table on one chip.
+"""Train a bigger decoder of the flagship's kind on one chip (the round-5
+scaling rows; README, "Round-5 figures", keeps the command and no table).
 
     PYTHONPATH=. python examples/scale_lm.py --d_model 768 --n_layers 12 --batch_size 192
     PYTHONPATH=. python examples/scale_lm.py --d_model 1024 --n_layers 12 --batch_size 128

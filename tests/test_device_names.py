@@ -5,8 +5,9 @@ seconds booked by the program itself.
   phases of a step sit under ``jax.named_scope``s; names are trace-time
   metadata, so the lowered StableHLO without locations equals the unnamed
   step's;
-- ``train.boundary`` appears once per log boundary in both loops and nests
-  ``train.boundary.planes`` and ``train.boundary.on_metrics``;
+- ``train.boundary`` appears once per log boundary, whether a dispatch is a
+  step or a block, and nests ``train.boundary.planes`` and
+  ``train.boundary.on_metrics``; its stages keep their order;
 - ``setup.*`` and ``jit.*`` counters are booked with telemetry off, from
   ``jax.monitoring`` listeners registered once however often ``configure()``
   runs, nested traces counted once.
@@ -231,6 +232,85 @@ def test_boundary_span_once_per_log_boundary_with_two_children(unroll):
     for rb, b in zip(by_name["train.readback_wait"][-len(boundaries):],
                      boundaries):
         assert rb[2] + rb[3] <= b[2]
+
+
+def _recording(calls, name, real):
+    """``real`` wrapped to note ``(name, step)`` first; the step is the first
+    positional argument or ``global_step``."""
+    def wrapper(*args, **kwargs):
+        calls.append((name, args[0] if args else kwargs["global_step"]))
+        return real(*args, **kwargs)
+    return wrapper
+
+
+@pytest.mark.parametrize("unroll", [1, 2], ids=["per_step", "unrolled"])
+def test_boundary_order_planes_then_health_then_history_then_callback(
+        unroll, monkeypatch):
+    """The order inside one log boundary, which each stage's reader stands
+    on: the snapshot is emitted (``emit_metrics``) before the health
+    monitor observes, the history sample (and its alert tick) comes after
+    both, and the caller's ``on_metrics`` is last."""
+    from autodist_tpu.telemetry import health, history
+    runner, params, batch = _linear_session()
+    calls = []
+    monitor = health.HealthMonitor(health.HealthConfig(action="warn"))
+    monkeypatch.setattr(telemetry, "emit_metrics", _recording(
+        calls, "emit_metrics", telemetry.emit_metrics))
+    monkeypatch.setattr(monitor, "observe", _recording(
+        calls, "health", monitor.observe))
+    monkeypatch.setattr(history, "maybe_sample", _recording(
+        calls, "history", history.maybe_sample))
+    telemetry.enable()
+    train(runner, params, lambda i: batch, steps=9, log_every=2,
+          unroll=unroll, prefetch_depth=0, health_monitor=monitor,
+          on_metrics=_recording(calls, "on_metrics", lambda *a: None))
+    steps = [step for name, step in calls if name == "on_metrics"]
+    assert len(steps) >= 3
+    order = ["emit_metrics", "health", "history", "on_metrics"]
+    for k, step in enumerate(steps):
+        assert calls[4 * k:4 * k + 4] == [(name, step) for name in order]
+    # After the last boundary only the end-of-run flushes are left: the
+    # monitor's tail period (a block that closed no period) and the forced
+    # history sample.
+    assert [name for name, _ in calls[4 * len(steps):]] in (
+        ["history"], ["health", "history"])
+
+
+class _FakeWireStats:
+    def format_line(self):
+        return "wire FAKE"
+
+
+@pytest.mark.parametrize("prefetch_depth", [0, 2], ids=["inline", "prefetch"])
+@pytest.mark.parametrize("unroll", [1, 2], ids=["per_step", "unrolled"])
+def test_log_line_and_queue_depth_are_the_same_for_a_step_and_a_block(
+        unroll, prefetch_depth, monkeypatch):
+    """What only one of the two old loops did, both dispatch kinds do now:
+    the log line ends with the runner's ``wire_stats`` when it has any, and
+    ``train.dispatch_queue_depth`` is booked after every dispatch (the
+    producer's fill; 0 without one)."""
+    from autodist_tpu.utils import logging as adlog
+    runner, params, batch = _linear_session()
+    runner.wire_stats = _FakeWireStats
+    lines = []
+    monkeypatch.setattr(adlog, "info",
+                        lambda msg, *args: lines.append(msg % args))
+    telemetry.enable()
+    seen = []
+    train(runner, params, lambda i: batch, steps=9, log_every=2,
+          unroll=unroll, prefetch_depth=prefetch_depth,
+          on_metrics=lambda step, loss, rate: seen.append(step))
+    period_lines = [l for l in lines if "examples/s" in l]
+    assert len(period_lines) == len(seen) >= 3
+    for line, step in zip(period_lines, seen):
+        assert line.startswith(f"train: step {step} loss ")
+        assert re.search(r"\| q \d+ rb \d+\.\d{3}s \| wire FAKE", line)
+        if not prefetch_depth:
+            assert "| q 0 rb" in line
+    gauge = telemetry.registry().get("train.dispatch_queue_depth")
+    assert gauge is not None
+    assert gauge.value == 0 if not prefetch_depth \
+        else 0 <= gauge.value <= prefetch_depth
 
 
 def test_boundary_without_telemetry_records_nothing_and_pays_no_planes():

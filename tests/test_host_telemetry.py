@@ -305,6 +305,32 @@ def test_throughput_meter_finish_freezes_average():
     assert meter.average != frozen
 
 
+def test_step_many_of_one_is_step(monkeypatch):
+    """``train()`` meters a single step as ``step_many(1, ...)``: over
+    3 x log_every + 2 steps it must close the same periods and read the
+    device back at the same steps as ``step()``: the warm-up dispatch and
+    steps 1 + k x log_every, nowhere else."""
+    from autodist_tpu.utils import metrics as umetrics
+    log_every = 4
+
+    def walk(tick):
+        meter = umetrics.ThroughputMeter(batch_size=8, log_every=log_every,
+                                         log=False)
+        synced, closed = [], []
+        monkeypatch.setattr(
+            umetrics, "_sync", lambda value: synced.append(value) or 0.0)
+        for i in range(1, 3 * log_every + 3):
+            if tick(meter, i) is not None:
+                closed.append(i)
+        return synced, closed, len(meter.history), meter._run_steps
+
+    by_step = walk(lambda m, i: m.step(sync=i))
+    by_many = walk(lambda m, i: m.step_many(1, sync=i))
+    assert by_many == by_step
+    boundaries = [1 + k * log_every for k in (1, 2, 3)]
+    assert by_step == ([1] + boundaries, boundaries, 3, 3 * log_every + 1)
+
+
 def test_sync_failure_is_narrow_and_silent():
     import jax
 

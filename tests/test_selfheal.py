@@ -391,12 +391,17 @@ def test_crash_respawn_readmin_catchup_bit_identical():
 
 # ------------------------------------------------------------ recover action
 
-def test_nan_recover_rolls_back_finishes_finite_and_bit_identical(ar_runner):
+@pytest.mark.parametrize("unroll", [1, 2], ids=["per_step", "unrolled"])
+def test_nan_recover_rolls_back_finishes_finite_and_bit_identical(ar_runner,
+                                                                  unroll):
+    """The fault fires where the batch of step 5 is pulled, so a block that
+    holds that step is poisoned like the step alone."""
     rollbacks0 = telemetry.counter("recover.rollback").value
     monitor = health.HealthMonitor(health.HealthConfig(action="recover"))
     faults.install("nan_grads@step=5")
     final = train(ar_runner, _params(), _batch, steps=12, log_every=2,
-                  health_monitor=monitor)
+                  health_monitor=monitor, unroll=unroll)
+    assert faults.points()[0].fired == 1
     faults.clear()
     # (a) The run FINISHED (did not halt) with finite params.
     assert int(final.step) == 12
@@ -407,7 +412,8 @@ def test_nan_recover_rolls_back_finishes_finite_and_bit_identical(ar_runner):
     assert recovery.recovery_snapshot()["counts"]["rollbacks"] >= 1
     # (c) A callable source replays the rolled-back steps exactly: the
     # recovered run is BIT-IDENTICAL to a never-faulted one.
-    clean = train(ar_runner, _params(), _batch, steps=12, log_every=2)
+    clean = train(ar_runner, _params(), _batch, steps=12, log_every=2,
+                  unroll=unroll)
     a = jax.device_get(jax.tree_util.tree_leaves(final.params))
     b = jax.device_get(jax.tree_util.tree_leaves(clean.params))
     assert all(np.array_equal(x, y) for x, y in zip(a, b))

@@ -36,6 +36,14 @@ def _batch(cfg, seed=0):
                                           seed=seed)
 
 
+def _value_and_grad(loss_fn):
+    """Loss and gradients as ONE jitted program. Called eagerly, a
+    ``shard_map`` over eight devices runs primitive by primitive, and the
+    interpreted kernels inside it are thousands of them: the same values
+    took 20 times as long (217 s against 13 s for the tied fused head)."""
+    return jax.jit(jax.value_and_grad(loss_fn))
+
+
 def test_sp_loss_and_grads_match_single_device():
     """SP loss/grads over a (data=2, seq=4) mesh == the plain single-shard model
     with identical parameters."""
@@ -51,7 +59,7 @@ def test_sp_loss_and_grads_match_single_device():
                                               optax.sgd(0.1))
     assert runner.mesh.shape["seq"] == 4
     sp_loss_fn = make_sequence_parallel_loss_fn(model_ring, runner.mesh)
-    sp_loss, sp_grads = jax.value_and_grad(sp_loss_fn)(params, batch)
+    sp_loss, sp_grads = _value_and_grad(sp_loss_fn)(params, batch)
 
     np.testing.assert_allclose(float(sp_loss), float(ref_loss), rtol=1e-5)
     flat_ref = jax.tree_util.tree_leaves(ref_grads)
@@ -82,8 +90,8 @@ def test_sp_fused_head_matches_plain_sp(tied):
     state = runner.init(params)
     p = runner.logical_params(state)
     with runner.mesh:
-        lp, gp = jax.value_and_grad(loss_plain)(p, batch)
-        lf, gf = jax.value_and_grad(loss_fused)(p, batch)
+        lp, gp = _value_and_grad(loss_plain)(p, batch)
+        lf, gf = _value_and_grad(loss_fused)(p, batch)
     np.testing.assert_allclose(float(lf), float(lp), rtol=1e-5)
     for a, e in zip(jax.tree_util.tree_leaves(gf), jax.tree_util.tree_leaves(gp)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(e),
@@ -116,7 +124,8 @@ def test_sp_composes_with_data_parallelism():
                                               optax.sgd(0.1))
     assert runner.mesh.shape["data"] == 4 and runner.mesh.shape["seq"] == 2
     loss_fn = make_sequence_parallel_loss_fn(model_ring, runner.mesh)
-    np.testing.assert_allclose(float(loss_fn(params, batch)), ref, rtol=1e-5)
+    np.testing.assert_allclose(float(jax.jit(loss_fn)(params, batch)), ref,
+                               rtol=1e-5)
 
 
 def test_sp_rejects_indivisible_sequence():
@@ -187,7 +196,7 @@ def test_ulysses_sp_loss_and_grads_match_single_device():
     ad = AutoDist(strategy_builder=SequenceParallel(seq_axis_size=2))
     runner = create_sequence_parallel_session(ad, model_ul, params, optax.sgd(0.1))
     sp_loss_fn = make_sequence_parallel_loss_fn(model_ul, runner.mesh)
-    sp_loss, sp_grads = jax.value_and_grad(sp_loss_fn)(params, batch)
+    sp_loss, sp_grads = _value_and_grad(sp_loss_fn)(params, batch)
 
     np.testing.assert_allclose(float(sp_loss), float(ref_loss), rtol=1e-5)
     for a, b in zip(jax.tree_util.tree_leaves(ref_grads),

@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+import pytest
 
 from autodist_tpu import AutoDist, train
 from autodist_tpu.checkpoint.saver import Saver
@@ -169,9 +170,22 @@ def test_eval_hook_fires_on_current_params(tmp_path):
     assert evals[-1][1] < evals[0][1]
 
 
+@pytest.mark.parametrize("unroll", [1, 2], ids=["per_step", "unrolled"])
+def test_remote_worker_skips_eval(unroll):
+    """A runner that says it is a remote async worker (its local state is a
+    compile-shapes template) is never asked to evaluate, and is fed one step
+    a dispatch whatever ``unroll`` asks."""
+    runner = _runner()
+    runner._is_remote_worker = True
+    evals = []
+    state = train(runner, _params(), _batch_fn, steps=4, log_every=0,
+                  unroll=unroll, eval_every=2, eval_batch=_batch_fn(999),
+                  on_eval=lambda step, val: evals.append(step))
+    assert int(state.step) == 4 and evals == []
+
+
 def test_eval_every_without_batch_raises():
-    import pytest as _pytest
-    with _pytest.raises(ValueError, match="eval_batch"):
+    with pytest.raises(ValueError, match="eval_batch"):
         train(_runner(), _params(), _batch_fn, steps=2, eval_every=1)
 
 
