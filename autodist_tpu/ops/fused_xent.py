@@ -27,24 +27,34 @@ Three kernels:
 - d(h):    grid (n-blocks, v-blocks); accumulates g*p @ w^T tiles in VMEM.
 - d(w,b):  grid (v-blocks, n-blocks); accumulates h^T @ g*p and column-sums.
 
-Measured on a v5e chip: in the full flagship training step the fused head is
-faster than the XLA head at equal batch (410k vs 398k tokens/s at bs 256) and
-— because nothing here scales with N*V — unlocks batch sizes whose logits
-cannot exist: bs 384 (~428k tokens/s, the flagship bench config) OOMs with a
-materialized head. Larger still: V=262k (32 GiB of logits) and N=262k
-(16 GiB) both train where XLA OOMs, and the lm1b example trains its exact
-793,471-word vocabulary with the TRUE softmax objective (48 GiB of logits if
-materialized; the reference needed sampled softmax) at ~17k words/s/chip end
-to end (bs 96, Adafactor — Adam's unfactored moments on the 4.9 GiB of
-tables exceed one chip's HBM).
-(An isolated loss+grads microbench is near-parity — 73 vs 69 ms —
-because the two backward logit recomputes cost roughly what the avoided HBM
-traffic saves; inside the full step, overlap with the rest of the model tips
-it to a win.)
+What a tile size decides is the kernel's arithmetic intensity as well as its
+per-tile fixed cost, because each kernel holds a block of one operand and
+streams the other past it from HBM once per block. Per byte streamed:
+- forward: the table once per row block, one product a tile:
+  2*bn*bv*d FLOP over w_size*d*bv bytes = ``2 * bn / w_size`` FLOP/byte;
+- d(h): the table once per row block, two products: ``4 * bn / w_size``;
+- d(w,b): all rows once per vocab block, two products: ``4 * bv / h_size``.
+A v5e's ridge is 197 TFLOP/s over 819 GB/s = 240 FLOP/byte. By that count
+the forward and d(h) want rows and d(w,b) wants vocabulary, so each kernel
+gets its own (bn, bv) from ``_fit_blocks`` (``_ROWS``, ``_COLS``, with what
+the timings added to the count), clamped to the shape and shrunk to the
+scoped VMEM there is: Mosaic's default 16 MiB where tiles at the ridge fit it
+(d = 1,024), else a budget of 40 MiB under the 48 MiB the call then asks for
+(the default holds no tile of a d = 2,048 head that leaves the ridge). The
+backward recomputes the logits tile in both its kernels (five products for
+the four the algorithm needs); the two share nothing but ``lse`` and ``g``,
+each padded to the kernel's own row blocks.
+
+Nothing here scales with N*V, so the fused head trains batches and
+vocabularies whose logits cannot exist: V=262k (32 GiB of logits) and N=262k
+(16 GiB) both train where the XLA head runs out of memory, and the lm1b
+example trains its exact 793,471-word vocabulary with the TRUE softmax
+objective (48 GiB of logits if materialized; the reference needed sampled
+softmax).
 
 On the CPU backend the kernels run in pallas interpret mode, so the test mesh
 exercises the same code path; ``tests/test_chip_compile.py`` compiles them
-for a described v5e. The chip figures above are from round 5.
+for a described v5e at the tiles the rule picks.
 """
 
 import functools
@@ -54,13 +64,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from autodist_tpu import telemetry
 from autodist_tpu.ops.blockwise_attention import NEG_INF
 from autodist_tpu.ops.flash_attention import _use_interpret
 from autodist_tpu.ops.named_call import named_pallas_call
 
 _LANES = 128
-DEFAULT_N_BLOCK = 512
-DEFAULT_V_BLOCK = 1024
 # Padding rows' lse: large POSITIVE so exp(logits - lse) underflows to exactly 0
 # whatever the bias — padding with 0 would overflow exp for bias values > ~88
 # and poison dw/db with NaN through inf * 0.
@@ -120,28 +129,37 @@ def _shapes(h, w, bn, bv, w_vd: bool):
     return n, d, v, pl.cdiv(n, bn), pl.cdiv(v, bv)
 
 
-# Mosaic refuses a kernel whose scoped VMEM allocation exceeds 16 MiB on v5e
-# (RESOURCE_EXHAUSTED at compile time). The budget is that limit less 256 KiB
-# for the spread of the temporaries model below against the compiler's own
-# count (within 0.2 MiB over the grid it was fitted on).
-_VMEM_BUDGET = (16 << 20) - (256 << 10)
+# Scoped VMEM: Mosaic's default limit on v5e is 16 MiB of the chip's 128, and
+# under it no tile of a d = 2,048 head is large enough to leave the ridge. A
+# kernel whose tiles need more asks for _VMEM_LIMIT (as ops/grouped_matmul.py
+# does) and is fitted to _VMEM_BUDGET, the limit less 8 MiB for the spread of
+# the temporaries model below against the compiler's own count. One whose
+# tiles fit the default asks for nothing: with a raised limit on the head's
+# calls (23 to 48 MiB alike) XLA assigns less of the surrounding step to VMEM,
+# and the GPT-2 cell's own fusions ran 8 ms a step slower for 6 ms the larger
+# tiles saved (PERF.md, PR 28).
+_DEFAULT_VMEM_BUDGET = (16 << 20) - (256 << 10)
+_VMEM_BUDGET = 40 << 20
+_VMEM_LIMIT = _VMEM_BUDGET + (8 << 20)
 
 # What Mosaic allocates beyond the pipeline buffers and scratch: values the
 # kernel body materializes in VMEM (the f32 [bn, bv] logits/probability plane
 # and its cast for the second matmul, masked and transposed copies of the h
 # tile, the masked copy of the w tile). Bytes per element of ([bn, bv] plane,
 # [bn, d] h tile, [d, bv] w tile), keyed by kernel and activation itemsize.
-# Upper bounds over both table layouts and both table dtypes, fitted to the
-# scoped allocations libtpu 0.0.34 reports for v5e at d in {512, 768, 1024},
-# bn in {256, 512}, bv in {256, 512, 1024}; tests/test_chip_compile.py asks
-# the compiler itself.
+# Least upper bounds over both table layouts and both table dtypes of the
+# scoped allocations libtpu 0.0.34 reports for v5e at d in {512, 768, 1024,
+# 2048}, bn in {256, 512, 1024}, bv in {256, 512, 1024, 2048} (396 compiles,
+# PR 28): never under the compiler's count there, over it by 0.6 to 1.5 MiB
+# in the mean and 5.4 at most; tests/test_chip_compile.py asks the compiler
+# itself.
 _TEMP_BYTES = {
-    ("fwd", 2): (4.0, 3.0, 1.0),
-    ("dh", 2): (2.5, 3.5, 2.0),
-    ("dw", 2): (4.5, 4.0, 0.5),
-    ("fwd", 4): (4.0, 0.5, 0.0),
-    ("dh", 4): (6.5, 1.0, 0.0),
-    ("dw", 4): (4.5, 1.0, 0.5),
+    ("fwd", 2): (3.5, 2.75, 0.75),
+    ("dh", 2): (2.0, 3.25, 2.25),
+    ("dw", 2): (4.0, 4.75, 0.0),
+    ("fwd", 4): (4.25, 0.5, 0.0),
+    ("dh", 4): (5.75, 0.25, 0.25),
+    ("dw", 4): (4.25, 2.5, 0.0),
 }
 
 
@@ -168,42 +186,68 @@ def _vmem_need(kernel: str, d: int, bn: int, bv: int, h_size: int,
     return buffers + plane * bn * bv + h_tile * bn * d + w_tile * d * bv
 
 
-def _fit_blocks(d: int, bn: int, bv: int, h_size: int, w_size: int,
-                backward: bool):
-    """Shrink (bn, bv) until every kernel launched with them fits the budget.
+# The tiles a kernel is given where VMEM allows, found by timing each kernel
+# alone on a v5e at 16,384 x 2,048 x 50,304 and 8,192 x 1,024 x 50,257 (bf16
+# rows, f32 table; tools/flash_forward_timing.py ``xent-*``):
+# - rows: 1,024, or 512 where that is what fits. The table tile is cast to
+#   the activation dtype and masked once a grid step in all three kernels
+#   (dw casts its resident tile again every row block), a share of the
+#   tile's products that falls as 1 / bn, and bn is the FLOP a streamed byte
+#   pays for (module docstring): 512 rows are at the chip's ridge in the
+#   forward and twice it in dh. From 512 to 1,024 a kernel gains 0 to 2%,
+#   at 256 it loses 3%, and at (256, 128), where the default limit left the
+#   backward at d = 2,048, 30%.
+# - vocabulary: the forward pays 3.2 ns a row and vocab tile for the running
+#   max and sum (cross-lane reductions, [bn, 1] columns), so its time is the
+#   product's x (1 + 150 / bv): 12% more at 512 than at 1,024. dh and dw are
+#   flat from 512 up (four times the ridge in dw) and 1.5% slower at 256.
+_ROWS, _FLOOR_ROWS = 1024, 512
+_COLS = {"fwd": 1024, "dh": 512, "dw": 512}
 
-    The footprint scales with the model dim, the table dtype and the tile
-    plane: a [d, bv] table tile is double-buffered on input AND (for the dw
-    kernel) on output, plus an f32 accumulator, and each kernel spills a few
-    bytes per [bn, bv] logit to VMEM — so the defaults that fit d=512 overflow
-    at d=768 with an f32 table and at d=1024 with a bf16 one. The backward
-    pass launches TWO kernels (dh and dw/db) with the same blocks, so it
-    budgets against the larger. Halving clamps at one lane tile; block size
-    only changes tiling, not results (beyond fp summation order).
 
-    Vocab blocks shrink first: halving bv keeps the total table traffic and
-    the row-block count (hence table passes) unchanged, while halving bn
-    doubles the fwd/dh kernels' full-table re-streams — measured 15% slower
-    on the 793k-vocab full-softmax when bn gives way first."""
-    kernels = ("dh", "dw") if backward else ("fwd",)
+def _fit_blocks(kernel: str, n: int, d: int, v: int, h_size: int, w_size: int,
+                bn: int = None, bv: int = None):
+    """(bn, bv) of one kernel ("fwd", "dh" or "dw") at this shape: (``_ROWS``,
+    ``_COLS``), or the caller's ``bn`` / ``bv``, no larger than the rows and
+    the vocabulary there are, shrunk until ``_vmem_need`` fits: rows as far
+    as ``_FLOOR_ROWS`` under Mosaic's default limit if that is enough, else
+    under the raised one; below that vocabulary first (halving bv leaves the
+    table traffic as it is, halving bn doubles the forward's and dh's passes
+    over the table), to one lane tile each.
 
-    def need(bn_, bv_):
-        return max(_vmem_need(k, d, bn_, bv_, h_size, w_size)
-                   for k in kernels)
-    while bv > _LANES and need(bn, bv) > _VMEM_BUDGET:
-        bv = max(_LANES, bv // 2)
-    while bn > _LANES and need(bn, bv) > _VMEM_BUDGET:
-        bn = max(_LANES, bn // 2)
-    if need(bn, bv) > _VMEM_BUDGET:
+    The footprint scales with the model dim, the two dtypes and the tile
+    plane, differently in each kernel (dw double-buffers a [d, bv] table tile
+    on input AND output beside an f32 accumulator; dh holds three [bn, d] row
+    tiles), so each is fitted alone. Block size only changes tiling, not
+    results (beyond fp summation order)."""
+    start = (min(bn or _ROWS, -(-n // _LANES) * _LANES),
+             min(bv or _COLS[kernel], -(-v // _LANES) * _LANES))
+
+    def need(blocks):
+        return _vmem_need(kernel, d, *blocks, h_size, w_size)
+
+    def shrunk(blocks, budget, floor):
+        blocks = list(blocks)
+        for axis in (1, 0):
+            while blocks[axis] > floor[axis] and need(blocks) > budget:
+                blocks[axis] = max(_LANES, blocks[axis] // 2)
+        return tuple(blocks)
+
+    floor = (_FLOOR_ROWS, start[1])
+    blocks = shrunk(start, _DEFAULT_VMEM_BUDGET, floor)
+    if need(blocks) > _DEFAULT_VMEM_BUDGET:
+        blocks = shrunk(shrunk(start, _VMEM_BUDGET, floor), _VMEM_BUDGET,
+                        (_LANES, _LANES))
+    if need(blocks) > _VMEM_BUDGET:
         # Refusing here names the cause; the compiler's RESOURCE_EXHAUSTED
         # names an allocation size and nothing the caller can change.
         raise ValueError(
-            f"fused_softmax_xent: even the minimum ({bn}, {bv}) tiling "
-            f"needs {need(bn, bv) / 2**20:.1f} MiB of VMEM (budget "
-            f"{_VMEM_BUDGET / 2**20:.2f} MiB) at d={d} with {h_size}-byte "
-            f"activations and a {w_size}-byte table; use a smaller model "
-            f"dim or the XLA head (fused_head=False)")
-    return bn, bv
+            f"fused_softmax_xent: even the minimum {blocks} tiling of the "
+            f"{kernel} kernel needs {need(blocks) / 2**20:.1f} MiB of VMEM "
+            f"(budget {_VMEM_BUDGET / 2**20:.0f} MiB) at d={d} with "
+            f"{h_size}-byte activations and a {w_size}-byte table; use a "
+            f"smaller model dim or the XLA head (fused_head=False)")
+    return blocks
 
 
 def _w_spec(d, bv, w_vd, index2):
@@ -214,9 +258,23 @@ def _w_spec(d, bv, w_vd, index2):
     return pl.BlockSpec((d, bv), lambda *a: (0, index2(*a)))
 
 
+def _blocks(kernel, h, w, bn, bv, w_vd):
+    """``_fit_blocks`` for ``kernel`` at the shapes of these arguments, and
+    the compiler parameters its call takes: the raised scoped-VMEM limit
+    where the tiles need it."""
+    n, d = h.shape
+    v = w.shape[0] if w_vd else w.shape[1]
+    sizes = (h.dtype.itemsize, w.dtype.itemsize)
+    bn, bv = _fit_blocks(kernel, n, d, v, *sizes, bn, bv)
+    if _vmem_need(kernel, d, bn, bv, *sizes) <= _DEFAULT_VMEM_BUDGET:
+        return bn, bv, None
+    return bn, bv, pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
+
+
 def _forward(h, w, b, bn, bv, interpret, w_vd):
-    bn, bv = _fit_blocks(h.shape[1], bn, bv, h.dtype.itemsize,
-                         w.dtype.itemsize, backward=False)
+    bn, bv, params = _blocks("fwd", h, w, bn, bv, w_vd)
+    telemetry.gauge("xent.fwd.block_rows").set(bn)
+    telemetry.gauge("xent.fwd.block_cols").set(bv)
     n, d, v, n_n, n_v = _shapes(h, w, bn, bv, w_vd)
     lse = named_pallas_call(
         "xent_fwd",
@@ -235,6 +293,7 @@ def _forward(h, w, b, bn, bv, interpret, w_vd):
             pltpu.VMEM((bn, _LANES), jnp.float32),   # running max
             pltpu.VMEM((bn, _LANES), jnp.float32),   # running denominator
         ],
+        compiler_params=params,
         interpret=interpret,
     )(h, w, b.reshape(1, -1))
     return lse.reshape(n_n * bn)[:n]
@@ -302,52 +361,67 @@ def _dwdb_kernel(h_ref, w_ref, b_ref, lse_ref, g_ref, dw_ref, db_ref,
         db_ref[...] = db_acc[:1, :].astype(db_ref.dtype)
 
 
-def _backward(h, w, b, lse, g, bn, bv, interpret, w_vd):
-    bn, bv = _fit_blocks(h.shape[1], bn, bv, h.dtype.itemsize,
-                         w.dtype.itemsize, backward=True)
-    n, d, v, n_n, n_v = _shapes(h, w, bn, bv, w_vd)
-    bvec = b.reshape(1, -1)
-    # The lse/g planes are tiny [N] vectors; padding THEM is cheap (unlike the
-    # table). Padding rows must contribute nothing: gradient pads as zero AND
-    # lse pads large-positive so exp underflows (see _PAD_LSE).
-    lse_p = jnp.pad(lse, (0, n_n * bn - n),
-                    constant_values=_PAD_LSE).reshape(1, n_n, bn)
-    g_p = jnp.pad(g.astype(jnp.float32), (0, n_n * bn - n)).reshape(1, n_n, bn)
+def _row_planes(lse, g, n_n: int, bn: int):
+    """``lse`` and ``g`` as the ``[1, n_n, bn]`` planes one backward kernel
+    reads whole. They are tiny [N] vectors; padding THEM is cheap (unlike the
+    table). Padding rows must contribute nothing: the gradient pads as zero
+    AND lse pads large-positive so exp underflows (see _PAD_LSE)."""
+    pad = n_n * bn - lse.shape[0]
+    lse_p = jnp.pad(lse, (0, pad), constant_values=_PAD_LSE)
+    g_p = jnp.pad(g.astype(jnp.float32), (0, pad))
+    return lse_p.reshape(1, n_n, bn), g_p.reshape(1, n_n, bn)
 
+
+def _backward(h, w, b, lse, g, bn, bv, interpret, w_vd):
+    bvec = b.reshape(1, -1)
+    fwd_bn, _, _ = _blocks("fwd", h, w, bn, bv, w_vd)
+
+    # d(h): rows decide how often the table is streamed.
+    bn_h, bv_h, params = _blocks("dh", h, w, bn, bv, w_vd)
+    n, d, v, n_n, n_v = _shapes(h, w, bn_h, bv_h, w_vd)
+    telemetry.gauge("xent.bwd.dh.block_rows").set(bn_h)
+    telemetry.gauge("xent.bwd.dh.block_cols").set(bv_h)
+    telemetry.gauge("xent.table_passes").set(pl.cdiv(n, fwd_bn) + n_n)
     dh = named_pallas_call(
         "xent_bwd_dh",
-        functools.partial(_dh_kernel, n_v=n_v, w_vd=w_vd, bv=bv, v=v),
+        functools.partial(_dh_kernel, n_v=n_v, w_vd=w_vd, bv=bv_h, v=v),
         grid=(n_n, n_v),
         in_specs=[
-            pl.BlockSpec((bn, d), lambda i, j: (i, 0)),
-            _w_spec(d, bv, w_vd, lambda i, j: j),
-            pl.BlockSpec((1, bv), lambda i, j: (0, j)),
-            pl.BlockSpec((1, n_n, bn), lambda i, j: (0, 0, 0)),
-            pl.BlockSpec((1, n_n, bn), lambda i, j: (0, 0, 0)),
+            pl.BlockSpec((bn_h, d), lambda i, j: (i, 0)),
+            _w_spec(d, bv_h, w_vd, lambda i, j: j),
+            pl.BlockSpec((1, bv_h), lambda i, j: (0, j)),
+            pl.BlockSpec((1, n_n, bn_h), lambda i, j: (0, 0, 0)),
+            pl.BlockSpec((1, n_n, bn_h), lambda i, j: (0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((bn, d), lambda i, j: (i, 0)),
+        out_specs=pl.BlockSpec((bn_h, d), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), h.dtype),
-        scratch_shapes=[pltpu.VMEM((bn, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bn_h, d), jnp.float32)],
+        compiler_params=params,
         interpret=interpret,
-    )(h, w, bvec, lse_p, g_p)
+    )(h, w, bvec, *_row_planes(lse, g, n_n, bn_h))
 
+    # d(w, b): vocabulary decides how often the rows are streamed.
+    bn_w, bv_w, params = _blocks("dw", h, w, bn, bv, w_vd)
+    n, d, v, n_n, n_v = _shapes(h, w, bn_w, bv_w, w_vd)
+    telemetry.gauge("xent.bwd.dw.block_rows").set(bn_w)
+    telemetry.gauge("xent.bwd.dw.block_cols").set(bv_w)
     dw_shape = (v, d) if w_vd else (d, v)
-    dw_scratch = pltpu.VMEM((bv, d) if w_vd else (d, bv), jnp.float32)
+    dw_scratch = pltpu.VMEM((bv_w, d) if w_vd else (d, bv_w), jnp.float32)
     dw, db = named_pallas_call(
         "xent_bwd_dw",
-        functools.partial(_dwdb_kernel, n_n=n_n, w_vd=w_vd, bn=bn, bv=bv,
+        functools.partial(_dwdb_kernel, n_n=n_n, w_vd=w_vd, bn=bn_w, bv=bv_w,
                           n=n, v=v),
         grid=(n_v, n_n),
         in_specs=[
-            pl.BlockSpec((bn, d), lambda j, i: (i, 0)),
-            _w_spec(d, bv, w_vd, lambda j, i: j),
-            pl.BlockSpec((1, bv), lambda j, i: (0, j)),
-            pl.BlockSpec((1, n_n, bn), lambda j, i: (0, 0, 0)),
-            pl.BlockSpec((1, n_n, bn), lambda j, i: (0, 0, 0)),
+            pl.BlockSpec((bn_w, d), lambda j, i: (i, 0)),
+            _w_spec(d, bv_w, w_vd, lambda j, i: j),
+            pl.BlockSpec((1, bv_w), lambda j, i: (0, j)),
+            pl.BlockSpec((1, n_n, bn_w), lambda j, i: (0, 0, 0)),
+            pl.BlockSpec((1, n_n, bn_w), lambda j, i: (0, 0, 0)),
         ],
         out_specs=(
-            _w_spec(d, bv, w_vd, lambda j, i: j),
-            pl.BlockSpec((1, bv), lambda j, i: (0, j)),
+            _w_spec(d, bv_w, w_vd, lambda j, i: j),
+            pl.BlockSpec((1, bv_w), lambda j, i: (0, j)),
         ),
         out_shape=(
             jax.ShapeDtypeStruct(dw_shape, w.dtype),
@@ -355,10 +429,11 @@ def _backward(h, w, b, lse, g, bn, bv, interpret, w_vd):
         ),
         scratch_shapes=[
             dw_scratch,
-            pltpu.VMEM((_LANES, bv), jnp.float32),
+            pltpu.VMEM((_LANES, bv_w), jnp.float32),
         ],
+        compiler_params=params,
         interpret=interpret,
-    )(h, w, bvec, lse_p, g_p)
+    )(h, w, bvec, *_row_planes(lse, g, n_n, bn_w))
     return dh, dw, db[0]
 
 
@@ -370,8 +445,8 @@ def _mls(h, w, b, n_block, v_block, interpret, w_layout):
     return lse
 
 
-def matmul_logsumexp(h, w, b, n_block: int = DEFAULT_N_BLOCK,
-                     v_block: int = DEFAULT_V_BLOCK,
+def matmul_logsumexp(h, w, b, n_block: int = None,
+                     v_block: int = None,
                      interpret: bool = None, w_layout: str = "dv"):
     """``logsumexp(h @ w + b, axis=-1)`` without materializing the logits.
 
@@ -379,6 +454,8 @@ def matmul_logsumexp(h, w, b, n_block: int = DEFAULT_N_BLOCK,
     [V, D] (``w_layout="vd"``, reference softmax_w layout); b: [V] or None.
     Returns f32 [N]. Differentiable in h, w, b (custom VJP recomputes logits
     tiles from the saved lse); dw returns in w's stored layout and dtype.
+    ``n_block`` / ``v_block``: the tiles every kernel starts from in place of
+    its own (``_fit_blocks``); left out, each kernel's follow from the shape.
 
     Under a mesh of several devices the kernels run per device
     (:func:`autodist_tpu.parallel.mesh.per_device`): each on its rows of
@@ -421,8 +498,8 @@ def _mls_bwd(n_block, v_block, interpret, w_layout, res, g):
 _mls.defvjp(_mls_fwd, _mls_bwd)
 
 
-def fused_softmax_xent(h, w, targets, b=None, n_block: int = DEFAULT_N_BLOCK,
-                       v_block: int = DEFAULT_V_BLOCK,
+def fused_softmax_xent(h, w, targets, b=None, n_block: int = None,
+                       v_block: int = None,
                        w_layout: str = "dv") -> jax.Array:
     """Per-row NLL of ``targets`` under ``softmax(h @ w + b)`` — the fused-head
     loss. h: [N, D], w per ``w_layout``, targets: int [N]. Returns f32 [N].

@@ -1,7 +1,8 @@
 """Ask the chip's compiler, without the chip.
 
 Interpret mode runs the Pallas kernels' arithmetic but none of Mosaic's
-checks: tiling alignment, the 16 MiB scoped-VMEM limit, lowering of each op.
+checks: tiling alignment, the scoped-VMEM limit (16 MiB unless the call asks
+for more), lowering of each op.
 libtpu compiles for a TPU that is described and not attached, so these cases
 compile the main path's kernels at real widths for one chip of a ``v5e:2x2``
 and assert that each lowered to a Mosaic custom call. Nothing executes: a
@@ -125,31 +126,45 @@ def test_flash_ring_backward_step_compiles(chip):
 _XENT_ROWS = 2048
 
 
-@pytest.mark.parametrize("d,vocab,layout,table_dtype,shrinks", [
-    (512, 32_000, "dv", jnp.float32, False),     # the flagship head
-    (1024, 32_000, "vd", jnp.float32, True),
-    # The two that libtpu 0.0.34 refused at the default tiles (18.39M and
-    # 16.73M scoped against a 16M limit) until _fit_blocks counted the
-    # in-kernel temporaries.
-    (1024, 32_000, "dv", jnp.bfloat16, True),
-    (1024, 50_257, "vd", jnp.bfloat16, True),
-    (2048, 50_304, "dv", jnp.float32, True),     # olmoe-pretrain-4k's untied head
+@pytest.mark.parametrize("d,vocab,layout,rows_dtype,table_dtype,tiles", [
+    # (bn, bv) of forward, dh, dw: what ``_fit_blocks`` picks for each, under
+    # Mosaic's default 16 MiB where tiles this large fit it and under the
+    # 48 MiB the call asks for where they do not
+    (512, 32_000, "dv", jnp.bfloat16, jnp.float32,   # the flagship head
+     ((1024, 1024), (1024, 512), (1024, 512))),
+    (1024, 32_000, "vd", jnp.bfloat16, jnp.float32,
+     ((512, 1024), (512, 512), (512, 512))),
+    # The two that libtpu 0.0.34 refused at (512, 1024) tiles (18.39M and
+    # 16.73M scoped against 16M) until _fit_blocks counted the in-kernel
+    # temporaries.
+    (1024, 32_000, "dv", jnp.bfloat16, jnp.bfloat16,
+     ((512, 1024), (512, 512), (512, 512))),
+    (1024, 50_257, "vd", jnp.bfloat16, jnp.bfloat16,
+     ((512, 1024), (512, 512), (512, 512))),
+    (2048, 50_304, "dv", jnp.bfloat16, jnp.float32,  # olmoe-pretrain-4k's untied head
+     ((1024, 1024), (512, 512), (1024, 512))),
+    # float32 rows at d 2,048: the forward alone was refused under 16 MiB
+    (2048, 50_304, "dv", jnp.float32, jnp.float32,
+     ((1024, 1024), (512, 512), (512, 512))),
 ], ids=["flagship-d512-dv-f32", "d1024-vd-f32", "d1024-dv-bf16",
-        "d1024-vd-bf16-V50257", "olmoe-d2048-dv-f32"])
-def test_fused_xent_fwd_bwd_compiles(chip, d, vocab, layout, table_dtype,
-                                     shrinks):
+        "d1024-vd-bf16-V50257", "olmoe-d2048-dv-f32", "d2048-f32-rows"])
+def test_fused_xent_fwd_bwd_compiles(chip, d, vocab, layout, rows_dtype,
+                                     table_dtype, tiles):
     table = (vocab, d) if layout == "vd" else (d, vocab)
-    tiles = fx._fit_blocks(d, fx.DEFAULT_N_BLOCK, fx.DEFAULT_V_BLOCK, 2,
-                           jnp.dtype(table_dtype).itemsize, backward=True)
-    assert (tiles != (fx.DEFAULT_N_BLOCK, fx.DEFAULT_V_BLOCK)) == shrinks
+    assert tuple(fx._fit_blocks(kernel, _XENT_ROWS, d, vocab,
+                                jnp.dtype(rows_dtype).itemsize,
+                                jnp.dtype(table_dtype).itemsize)
+                 for kernel in ("fwd", "dh", "dw")) == tiles
 
     def loss(h, w, targets):
         return fx.fused_softmax_xent(h, w, targets, w_layout=layout).mean()
 
     text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1)), chip,
-                          ((_XENT_ROWS, d), jnp.bfloat16), (table, table_dtype),
+                          ((_XENT_ROWS, d), rows_dtype), (table, table_dtype),
                           ((_XENT_ROWS,), jnp.int32))
     assert "tpu_custom_call" in text
+    for name in ("xent_fwd", "xent_bwd_dh", "xent_bwd_dw"):
+        assert name in text
 
 
 @pytest.mark.parametrize("k,n", [(2048, 1024), (1024, 2048)],

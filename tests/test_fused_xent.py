@@ -65,53 +65,125 @@ def test_grads_match_reference_f32():
         np.testing.assert_allclose(a, e, rtol=2e-4, atol=2e-5)
 
 
-def test_fit_blocks_shrinks_for_large_d_f32_table():
-    """The default (bn=512, bv=1024) tiles fit d=512 but overflow VMEM at
-    d=768 with an f32 table — the dw kernel double-buffers both the table
-    tile and the dw output tile, plus an f32 accumulator. The fitter must
-    shrink bv at d=768 (the compiler refuses the kernel on overflow) and
-    leave the d=512 flagship tiling alone."""
-    from autodist_tpu.ops.fused_xent import (_VMEM_BUDGET, _fit_blocks,
-                                             _vmem_need)
+_RIDGE = 240.0   # FLOP / byte of a v5e: 197 TFLOP/s over 819 GB/s
 
-    # bf16 h (2 bytes), f32 table (4 bytes) — the model zoo's param_dtype.
-    assert _fit_blocks(512, 512, 1024, 2, 4, backward=True) == (512, 1024)
-    bn, bv = _fit_blocks(768, 512, 1024, 2, 4, backward=True)
-    assert bv < 1024
-    assert _vmem_need("dw", 768, bn, bv, 2, 4) <= _VMEM_BUDGET
-    # d=1024 shrinks further but never below one lane tile.
-    bn2, bv2 = _fit_blocks(1024, 512, 1024, 2, 4, backward=True)
-    assert 128 <= bv2 <= bv
-    # The backward budget covers BOTH its kernels: the dh footprint at large d
-    # with f32 activations must also bound the result.
-    bn3, bv3 = _fit_blocks(2048, 512, 1024, 4, 4, backward=True)
-    assert _vmem_need("dh", 2048, bn3, bv3, 4, 4) <= _VMEM_BUDGET
-    # Odd lane multiples clamp at one lane tile, never below (192 -> 128,
-    # not 96).
-    bn4, bv4 = _fit_blocks(2048, 512, 192, 4, 4, backward=True)
-    assert bv4 == 128 and bn4 >= 128
-    # A dim no tiling can fit refuses with an actionable error instead of
-    # leaving the caller with the compiler's allocation-size message.
+# (rows, d, vocab, activation bytes, table bytes): the two cells' calls, and
+# the shapes the fitter was first written against.
+_FIT_SHAPES = {
+    "olmoe-cell": (16_384, 2048, 50_304, 2, 4),
+    "gpt2-cell": (8_192, 1024, 50_257, 2, 4),
+    "flagship-d512": (98_304, 512, 32_000, 2, 4),
+    "d768-f32-table": (2_048, 768, 32_000, 2, 4),
+    "d1024-bf16-table": (2_048, 1024, 32_000, 2, 2),
+    "d2048-f32-rows": (2_048, 2048, 32_000, 4, 4),
+    "lm1b-vocab": (1_920, 1024, 793_471, 2, 4),
+    "ragged-few-rows": (1_000, 1024, 50_257, 2, 4),
+}
+
+
+def _intensity(kernel, bn, bv, h_size, w_size):
+    """FLOP per byte streamed from HBM (module docstring of ops/fused_xent)."""
+    return {"fwd": 2 * bn / w_size, "dh": 4 * bn / w_size,
+            "dw": 4 * bv / h_size}[kernel]
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "dh", "dw"])
+@pytest.mark.parametrize("shape", list(_FIT_SHAPES))
+def test_fit_blocks_rule(shape, kernel):
+    """Each kernel its own tiles from the shape: whole lane tiles, no larger
+    than the rows and the vocabulary there are, that fit Mosaic's default
+    limit (then no more is asked for) or the raised budget; at least at the
+    chip's ridge in FLOP a streamed byte, and at twice it wherever the next
+    larger block of the streamed axis would fit the raised budget too."""
+    from autodist_tpu.ops.fused_xent import (_DEFAULT_VMEM_BUDGET, _VMEM_BUDGET,
+                                             _fit_blocks, _vmem_need)
+
+    n, d, v, h_size, w_size = _FIT_SHAPES[shape]
+    bn, bv = _fit_blocks(kernel, n, d, v, h_size, w_size)
+    need = _vmem_need(kernel, d, bn, bv, h_size, w_size)
+    assert bn % 128 == 0 and bv % 128 == 0
+    assert bn < n + 128 and bv < v + 128
+    assert need <= _VMEM_BUDGET
+    assert _intensity(kernel, bn, bv, h_size, w_size) >= _RIDGE
+    if (need > _DEFAULT_VMEM_BUDGET
+            and _intensity(kernel, bn, bv, h_size, w_size) < 2 * _RIDGE):
+        grown = (bn, 2 * bv) if kernel == "dw" else (2 * bn, bv)
+        assert (grown[0] > n or grown[1] > v
+                or _vmem_need(kernel, d, *grown, h_size, w_size) > _VMEM_BUDGET)
+    # the kernels of one call no longer share tiles where their needs differ
+    if shape == "olmoe-cell":
+        assert need > _DEFAULT_VMEM_BUDGET
+        assert (bn, bv) == {"fwd": (1024, 1024), "dh": (512, 512),
+                            "dw": (1024, 512)}[kernel]
+    if shape == "gpt2-cell":   # the tiles PR 27 ran there, under the default limit
+        assert need <= _DEFAULT_VMEM_BUDGET
+        assert (bn, bv) == ((512, 1024) if kernel == "fwd" else (512, 512))
+    if shape == "ragged-few-rows":
+        assert bn == 512   # 1,000 rows, two blocks: the last one ragged
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "dh", "dw"])
+def test_fit_blocks_starts_from_given_blocks_and_refuses_what_nothing_fits(kernel):
+    from autodist_tpu.ops.fused_xent import _fit_blocks
+
+    # the caller's blocks are where the fit starts: kept where they fit,
+    assert _fit_blocks(kernel, 4096, 512, 32_000, 2, 4, 256, 384) == (256, 384)
+    # halved (never below one lane tile: 192 -> 128, not 96) where they do not,
+    bn, bv = _fit_blocks(kernel, 4096, 8192, 32_000, 4, 4, 512, 192)
+    assert bv == 128 and bn >= 128
+    # fewer rows than a block: one block of whole lane tiles,
+    assert _fit_blocks(kernel, 200, 512, 32_000, 2, 4)[0] == 256
+    # and a dim no tiling fits is refused by name, not by the compiler's
+    # allocation size.
     with pytest.raises(ValueError, match="VMEM"):
-        _fit_blocks(32768, 512, 1024, 4, 4, backward=True)
+        _fit_blocks(kernel, 4096, 65_536, 32_000, 4, 4)
 
 
-def test_fit_blocks_counts_in_kernel_temporaries_for_bf16_table():
-    """A 2-byte table halves the table tiles but not what the kernel body
-    spills to VMEM: at d=1024 the default tiles' buffers alone come to
-    14.5 MiB, the compiler counted 18.4 MiB (dv) and 16.7 MiB (vd) against
-    its 16 MiB limit, and the fitter has to shrink them
-    (tests/test_chip_compile.py compiles the result)."""
-    from autodist_tpu.ops.fused_xent import (_VMEM_BUDGET, _fit_blocks,
-                                             _vmem_need)
+def test_kernels_on_different_blocks_stay_value_exact(monkeypatch):
+    """dh and dw on different (bn, bv), neither the forward's: a ragged last
+    row block and vocab block in each, and a large bias entry against the
+    padding rows. Values and gradients against the f32 reference."""
+    from autodist_tpu.ops import fused_xent as fx
 
-    assert _vmem_need("dw", 1024, 512, 1024, 2, 2) > 16 << 20
-    bn, bv = _fit_blocks(1024, 512, 1024, 2, 2, backward=True)
-    assert (bn, bv) == (512, 512)
-    assert max(_vmem_need(k, 1024, bn, bv, 2, 2)
-               for k in ("dh", "dw")) <= _VMEM_BUDGET
-    # The forward kernel alone keeps the default tiles.
-    assert _fit_blocks(1024, 512, 1024, 2, 2, backward=False) == (512, 1024)
+    tiles = {"fwd": (128, 256), "dh": (256, 128), "dw": (128, 384)}
+    monkeypatch.setattr(fx, "_fit_blocks", lambda kernel, *a, **kw: tiles[kernel])
+    h, w, b = _data(300, 64, 500, jnp.float32, seed=12)
+    b = b.at[7].set(95.0)
+    np.testing.assert_allclose(fx.matmul_logsumexp(h, w, b), _ref_lse(h, w, b),
+                               **_f32_tol())
+    gf = jax.grad(lambda h, w, b: jnp.sum(fx.matmul_logsumexp(h, w, b) * 0.01),
+                  argnums=(0, 1, 2))(h, w, b)
+    gr = jax.grad(lambda h, w, b: jnp.sum(_ref_lse(h, w, b) * 0.01),
+                  argnums=(0, 1, 2))(h, w, b)
+    for a, e in zip(gf, gr):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_allclose(a, e, **_f32_tol(rtol=2e-4, atol=2e-5))
+    # the stored [V, D] layout through the same three tilings
+    g_vd = jax.grad(lambda h, w, b: jnp.sum(fx.matmul_logsumexp(
+        h, w, b, w_layout="vd") * 0.01), argnums=(0, 1, 2))(h, w.T, b)
+    np.testing.assert_allclose(g_vd[1], gr[1].T, **_f32_tol(rtol=2e-4, atol=2e-5))
+
+
+def test_tracing_the_head_sets_its_block_gauges():
+    """``xent.*``: each kernel's tiles and the table's passes a call (row
+    blocks of forward + dh), set when the op is traced; nothing executes."""
+    from autodist_tpu import telemetry
+    from autodist_tpu.ops.fused_xent import fused_softmax_xent
+
+    struct = jax.ShapeDtypeStruct
+    jax.eval_shape(
+        jax.grad(lambda h, w, t: fused_softmax_xent(h, w, t).mean(),
+                 argnums=(0, 1)),
+        struct((16_384, 2048), jnp.bfloat16), struct((2048, 50_304), jnp.float32),
+        struct((16_384,), jnp.int32))        # olmoe-pretrain-4k's call
+    got = {name: telemetry.gauge(f"xent.{name}").value for name in (
+        "fwd.block_rows", "fwd.block_cols", "bwd.dh.block_rows",
+        "bwd.dh.block_cols", "bwd.dw.block_rows", "bwd.dw.block_cols",
+        "table_passes")}
+    assert got == {"fwd.block_rows": 1024, "fwd.block_cols": 1024,
+                   "bwd.dh.block_rows": 512, "bwd.dh.block_cols": 512,
+                   "bwd.dw.block_rows": 1024, "bwd.dw.block_cols": 512,
+                   "table_passes": 16 + 32}
 
 
 def test_shrunken_blocks_stay_value_exact(monkeypatch):
@@ -121,9 +193,11 @@ def test_shrunken_blocks_stay_value_exact(monkeypatch):
     from autodist_tpu.ops import fused_xent as fx
 
     # 384 KiB: big enough for the minimum tiling (whose accounted footprint
-    # now includes the dw kernel's db_acc scratch + db output tile), small
+    # includes the dw kernel's db_acc scratch + db output tile), small
     # enough that the requested (64, 256) blocks must shrink to (64, 128).
     monkeypatch.setattr(fx, "_VMEM_BUDGET", 384 << 10)
+    monkeypatch.setattr(fx, "_DEFAULT_VMEM_BUDGET", 384 << 10)
+    assert fx._fit_blocks("dw", 128, 64, 320, 4, 4, 64, 256) == (64, 128)
     h, w, b = _data(128, 64, 320, jnp.float32, seed=6)
     got = fx.matmul_logsumexp(h, w, b, 64, 256)
     np.testing.assert_allclose(got, _ref_lse(h, w, b), **_f32_tol())

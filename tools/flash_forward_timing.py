@@ -1,24 +1,29 @@
 #!/usr/bin/env python3
-"""Time the flash kernels alone, on the chip, at named shapes.
+"""Time the flash and fused-head kernels alone, on the chip, at named shapes.
 
 The instrument behind the block choices in ``ops/flash_attention.py``
-(``_forward_blocks``, ``_backward_blocks``): one jitted ``_flash_forward``,
-carry step or ``_flash_backward`` (on residuals prepared outside the trace)
-per shape, run under the profiler, and the kernels' own device time read from
-the trace's ``XLA Ops`` events named ``flash_fwd`` / ``flash_carry`` /
-``flash_bwd_dkv`` + ``flash_bwd_dq`` (summed, and each under ``parts``; the
-host clock around the whole call, transposes included, is printed beside it).
-One JSON line a measurement on standard output.
+(``_forward_blocks``, ``_backward_blocks``) and ``ops/fused_xent.py``
+(``_fit_blocks``): one jitted ``_flash_forward``, carry step or
+``_flash_backward`` (on residuals prepared outside the trace), or the head's
+``_forward`` / ``_backward``, per shape, run under the profiler, and the
+kernels' own device time read from the trace's ``XLA Ops`` events named
+``flash_fwd`` / ``flash_carry`` / ``flash_bwd_dkv`` + ``flash_bwd_dq``
+(summed, and each under ``parts``) / ``xent_fwd`` / ``xent_bwd_dh`` /
+``xent_bwd_dw``; the host clock around the whole call, transposes included,
+is printed beside it. One JSON line a measurement on standard output.
 
     python tools/flash_forward_timing.py                     # every shape
     python tools/flash_forward_timing.py --shapes cell,l4096,bwd-cell,bwd-olmoe
     python tools/flash_forward_timing.py --blocks 512,512,128 --blocks 256,1024,256
     python tools/flash_forward_timing.py --bwd-blocks 512,1024
+    python tools/flash_forward_timing.py --shapes xent-fwd,xent-dh,xent-dw --xent-blocks 1024,512
     python tools/flash_forward_timing.py --root .archive_check/parent   # another checkout
 
 ``--blocks bq,bk,sub`` overrides the forward's choice, ``--bwd-blocks bq,bk``
-the backward's (a checkout whose kernels run a fixed default takes no
-override). Needs the TPU: a time from the CPU's interpreter says nothing.
+the backward's, ``--xent-blocks bn,bv`` the named head kernel's (a checkout
+whose kernels run a fixed default takes no override; tiles the compiler
+refuses are reported and passed over). Needs the TPU: a time from the CPU's
+interpreter says nothing.
 """
 
 import argparse
@@ -52,9 +57,26 @@ SHAPES = {
     "bwd-ring-diag": (4, 4096, 16, 64, True, "ring-bwd"),
     "bwd-ring-visible": (4, 4096, 16, 64, True, "ring-bwd"),
 }
+# the fused head, bfloat16 rows against a float32 table: name -> (N, D, V,
+# table layout, kind), at olmoe-pretrain-4k's call and at gpt2m-*'s, a chip
+# and call. ``xent-dh`` and ``xent-dw`` both run the whole backward and read
+# their own kernel's events.
+HEAD_SHAPES = {
+    f"xent-{kernel}{cell}": (*shape, f"xent-{kernel}")
+    for cell, shape in (("", (16384, 2048, 50304, "dv")),
+                        ("-gpt2", (8192, 1024, 50257, "vd")))
+    for kernel in ("fwd", "dh", "dw")}
 KERNELS = {"fwd": ("flash_fwd",), "carry": ("flash_carry",),
            "bwd": ("flash_bwd_dkv", "flash_bwd_dq"),
-           "ring-bwd": ("flash_bwd_dkv", "flash_bwd_dq")}
+           "ring-bwd": ("flash_bwd_dkv", "flash_bwd_dq"),
+           "xent-fwd": ("xent_fwd",), "xent-dh": ("xent_bwd_dh",),
+           "xent-dw": ("xent_bwd_dw",)}
+GAUGES = {"fwd": "flash.fwd.", "bwd": "flash.bwd.",
+          **{kind: "xent." for kind in ("xent-fwd", "xent-dh", "xent-dw")}}
+
+
+def kind_of(name: str) -> str:
+    return (SHAPES.get(name) or HEAD_SHAPES[name])[-1]
 
 
 def kernel_ms(trace_dir: str, kernel: str):
@@ -123,17 +145,62 @@ def build(fa, name, blocks=None):
     return fn, args
 
 
-def measure(fa, name, blocks, calls):
+def build_head(fx, name, blocks=None):
+    """The head's forward, or forward and backward (each kernel is read
+    from the trace by its own name)."""
+    import inspect
+
+    import jax
+    import jax.numpy as jnp
+
+    n, d, v, layout, kind = HEAD_SHAPES[name]
+    rule = vars(fx).setdefault("_fit_blocks_rule", fx._fit_blocks)
+    chooses = "kernel" in inspect.signature(rule).parameters
+    if blocks is not None and not chooses:
+        raise SystemExit("this checkout's _fit_blocks fits no kernel alone: "
+                         "a fixed default")
+    which = kind.split("-")[1]
+    fx._fit_blocks = rule if blocks is None else (
+        lambda kernel, *a, **kw: blocks if kernel == which
+        else rule(kernel, *a, **kw))
+    start = (None, None) if chooses else (fx.DEFAULT_N_BLOCK, fx.DEFAULT_V_BLOCK)
+    w_vd = layout == "vd"
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    h = jax.random.normal(keys[0], (n, d), jnp.bfloat16)
+    w = jax.random.normal(keys[1], (v, d) if w_vd else (d, v), jnp.float32) * 0.02
+    b = jnp.zeros((v,), jnp.float32)
+
+    def forward(h, w, b):
+        return fx._forward(h, w, b, *start, False, w_vd)
+
+    if kind == "xent-fwd":
+        return jax.jit(forward), (h, w, b)
+    g = jax.random.normal(keys[2], (n,), jnp.float32) / n
+    return (jax.jit(lambda h, w, b, g: fx._backward(
+        h, w, b, forward(h, w, b), g, *start, False, w_vd)), (h, w, b, g))
+
+
+def measure(modules, name, blocks, calls):
     import jax
 
-    kind = SHAPES[name][5]
-    if blocks is not None:
-        chooser = "_backward_blocks" if kind == "bwd" else "_forward_blocks"
-        if not hasattr(fa, chooser):
-            raise SystemExit(f"this checkout has no {chooser}: a fixed default")
-        setattr(fa, chooser, lambda *a, **kw: blocks)
-    fn, args = build(fa, name, blocks if kind == "bwd" else None)
-    jax.block_until_ready(fn(*args))
+    kind = kind_of(name)
+    if name in HEAD_SHAPES:
+        fn, args = build_head(modules["fused_xent"], name, blocks)
+    else:
+        fa = modules["flash_attention"]
+        if blocks is not None:
+            chooser = "_backward_blocks" if kind == "bwd" else "_forward_blocks"
+            if not hasattr(fa, chooser):
+                raise SystemExit(f"this checkout has no {chooser}: a fixed default")
+            setattr(fa, chooser, lambda *a, **kw: blocks)
+        fn, args = build(fa, name, blocks if kind == "bwd" else None)
+    try:
+        jax.block_until_ready(fn(*args))
+    except Exception as e:  # noqa: BLE001 — a sweep goes on past refused tiles
+        if blocks is None:
+            raise
+        return {"shape": name, "blocks": blocks,
+                "refused": str(e).splitlines()[0][:300]}
     jax.block_until_ready(fn(*args))
     with tempfile.TemporaryDirectory() as trace_dir:
         with jax.profiler.trace(trace_dir):
@@ -154,7 +221,7 @@ def measure(fa, name, blocks, calls):
     if len(KERNELS[kind]) > 1:      # the one-pass backward holds no flash_bwd_dq
         record["parts"] = {kernel: ms[len(ms) // 2] for kernel, ms in parts.items()}
     from autodist_tpu import telemetry
-    prefix = {"fwd": "flash.fwd.", "bwd": "flash.bwd."}.get(kind)
+    prefix = GAUGES.get(kind)
     gauges = {k: v for k, v in telemetry.snapshot().items()
               if prefix and k.startswith(prefix)}
     if gauges:      # a checkout older than the gauges has none
@@ -166,11 +233,13 @@ def main(argv=None):
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument("--root", default=os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), help="checkout to import autodist_tpu from")
-    parser.add_argument("--shapes", default=",".join(SHAPES))
+    parser.add_argument("--shapes", default=",".join((*SHAPES, *HEAD_SHAPES)))
     parser.add_argument("--blocks", action="append", default=[],
                         help="the forward's bq,bk,sub override; may repeat")
     parser.add_argument("--bwd-blocks", action="append", default=[],
                         help="the backward's bq,bk override; may repeat")
+    parser.add_argument("--xent-blocks", action="append", default=[],
+                        help="a head kernel's bn,bv override; may repeat")
     parser.add_argument("--calls", type=int, default=20)
     args = parser.parse_args(argv)
 
@@ -178,15 +247,18 @@ def main(argv=None):
     import jax
     if jax.default_backend() != "tpu":
         raise SystemExit(f"needs the TPU, the backend is {jax.default_backend()!r}")
-    fa = importlib.import_module("autodist_tpu.ops.flash_attention")
+    modules = {m: importlib.import_module(f"autodist_tpu.ops.{m}")
+               for m in ("flash_attention", "fused_xent")}
 
     def plans(flags):
         return [tuple(int(x) for x in b.split(",")) for b in flags] or [None]
 
-    overrides = {"fwd": plans(args.blocks), "bwd": plans(args.bwd_blocks)}
+    overrides = {"fwd": plans(args.blocks), "bwd": plans(args.bwd_blocks),
+                 **dict.fromkeys(("xent-fwd", "xent-dh", "xent-dw"),
+                                 plans(args.xent_blocks))}
     for name in args.shapes.split(","):
-        for blocks in overrides.get(SHAPES[name][5], [None]):
-            print(json.dumps({"root": args.root, **measure(fa, name, blocks,
+        for blocks in overrides.get(kind_of(name), [None]):
+            print(json.dumps({"root": args.root, **measure(modules, name, blocks,
                                                            args.calls)}),
                   flush=True)
 

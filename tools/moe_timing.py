@@ -229,7 +229,6 @@ def phase_gradcheck(_calls, _tiles):
         ("dot attention", {"attention_impl": "dot"}, None),
         ("XLA head", {"fused_head": False}, None),
         ("float32 activations", {"dtype": jnp.float32}, None),
-        # the fused head's tiles are not fitted for float32 rows at d 2,048
         ("float32 activations, XLA head and attention, highest precision",
          {"dtype": jnp.float32, "fused_head": False, "attention_impl": "dot"},
          "highest"),
