@@ -222,23 +222,62 @@ class Route(NamedTuple):
     inv_perm: jax.Array      # [T*k] flat slot j sits at sorted row inv_perm[j]
 
 
-def topk_route(probs: jax.Array, k: int) -> Route:
-    """The ``k`` largest router probabilities of each token and the sort of the
-    ``T*k`` (token, slot) rows by expert. Dropless: every row lands in its
-    expert's group (``group_sizes`` sums to ``T*k``), the weights are the
-    softmax values themselves (not renormalised over the chosen), and the sort
-    is stable, so a group holds its rows in token order."""
-    n_tokens, n_experts = probs.shape
-    weights, indices = jax.lax.top_k(probs, k)
+def _sorted_route(indices, weights, router_width: int, first_expert: int = 0,
+                  n_held: Optional[int] = None) -> Route:
+    """The stable sort of the ``T*k`` (token, slot) rows by expert, which
+    every router shares. Where the layer holds only the experts
+    ``[first_expert, first_expert + n_held)`` of the ``router_width`` the
+    router chooses among (one chip's share under expert parallelism), rows of
+    the held experts sort first, by local expert, and every row of an absent
+    expert after them: ``group_sizes`` is ``[n_held]`` and sums to the held
+    rows, which are the first of ``perm``."""
+    n_slots = indices.size
+    n_held = router_width if n_held is None else n_held
     flat = indices.reshape(-1).astype(jnp.int32)
+    if n_held != router_width:
+        local = flat - first_expert
+        flat = jnp.where((local >= 0) & (local < n_held), local, n_held)
     perm = jnp.argsort(flat, stable=True).astype(jnp.int32)
-    rows = jnp.arange(n_tokens * k, dtype=jnp.int32)
+    rows = jnp.arange(n_slots, dtype=jnp.int32)
     inv_perm = jnp.zeros_like(perm).at[perm].set(rows, unique_indices=True)
-    ends = jnp.searchsorted(flat[perm], jnp.arange(n_experts, dtype=jnp.int32),
+    ends = jnp.searchsorted(flat[perm], jnp.arange(n_held, dtype=jnp.int32),
                             side="right").astype(jnp.int32)
     group_sizes = jnp.diff(ends, prepend=0)
     return Route(indices.astype(jnp.int32), weights, group_sizes, perm,
                  inv_perm)
+
+
+def topk_route(probs: jax.Array, k: int, bias=None, *, first_expert: int = 0,
+               n_held: Optional[int] = None) -> Route:
+    """The ``k`` largest router probabilities of each token and the sort of the
+    ``T*k`` (token, slot) rows by expert. Dropless: every row lands in its
+    expert's group (``group_sizes`` sums to ``T*k`` where every expert is
+    held), the weights are the softmax values themselves (not renormalised over
+    the chosen), and the sort is stable, so a group holds its rows in token
+    order. This router has no ``bias``."""
+    if bias is not None:
+        raise ValueError("topk_route takes no bias")
+    weights, indices = jax.lax.top_k(probs, k)
+    return _sorted_route(indices, weights, probs.shape[1], first_expert, n_held)
+
+
+def sigmoid_topk_route(scores: jax.Array, k: int, bias=None, *,
+                       route_norm: bool = True, route_scale: float = 1.0,
+                       first_expert: int = 0,
+                       n_held: Optional[int] = None) -> Route:
+    """The router of the sigmoid-scored mixtures: ``scores = sigmoid(h.Wr)``
+    in float32, the ``k`` experts with the largest ``scores + bias`` are
+    chosen, and their weights are the scores themselves, without the bias
+    (which steers the load and takes no gradient), divided by their sum over
+    the chosen (+ 1e-20) under ``route_norm`` and multiplied by
+    ``route_scale``. The sort is :func:`topk_route`'s."""
+    choice = scores if bias is None else scores + jax.lax.stop_gradient(bias)
+    _, indices = jax.lax.top_k(choice, k)
+    weights = jnp.take_along_axis(scores, indices, axis=-1)
+    if route_norm:
+        weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-20)
+    return _sorted_route(indices, weights * route_scale, scores.shape[1],
+                         first_expert, n_held)
 
 
 @jax.custom_vjp
@@ -282,35 +321,163 @@ def _dispatch_rows_bwd(k, residuals, g):
 _dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
 
 
-def routed_experts(x, probs, gate, up, down, *, top_k: int):
-    """The local computation of a routed gated-SiLU FFN, one function of the
-    tokens and the expert bank it is given: top-k and sort, the grouped
-    products over the experts held, the weighted un-sort.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _take_rows(x, token, n_tokens):
+    """``[T, d] -> [R, d]``: compacted row ``i`` is token ``token[i]``. The
+    transpose adds a token's rows up in float32 (autodiff would add them in
+    the rows' dtype)."""
+    return jnp.take(x, token, axis=0)
 
-    x: ``[T, d]``; probs: ``[T, E]`` float32 router probabilities; gate, up:
-    ``[E, d, w]``; down: ``[E, w, d]``. Returns ``(y [T, d] float32,
-    group_sizes [E])``: ``y[t] = sum_slots p * down_e(silu(gate_e x[t]) * up_e
-    x[t])``, the weighted sum accumulated in float32 and left so."""
-    from autodist_tpu import telemetry
+
+def _take_rows_fwd(x, token, n_tokens):
+    return jnp.take(x, token, axis=0), (token,)
+
+
+def _take_rows_bwd(n_tokens, residuals, g):
+    (token,) = residuals
+    dx = jnp.zeros((n_tokens, g.shape[-1]), jnp.float32).at[token].add(
+        g.astype(jnp.float32))
+    return dx.astype(g.dtype), None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+def _gated_experts(rows, gate, up, down, group_sizes):
     from autodist_tpu.ops.grouped_matmul import gmm
-    n_tokens, d = x.shape
-    telemetry.gauge("moe.experts").set(int(gate.shape[0]))
-    telemetry.gauge("moe.top_k").set(int(top_k))
-    telemetry.gauge("moe.rows_per_call").set(int(n_tokens * top_k))
-    with jax.named_scope("moe.route"):
-        route = topk_route(probs, top_k)
-    with jax.named_scope("moe.dispatch"):
-        rows = _dispatch_rows(x, route.perm, route.inv_perm, top_k)
     with jax.named_scope("moe.experts"):
-        hidden = (nn.silu(gmm(rows, gate, route.group_sizes))
-                  * gmm(rows, up, route.group_sizes))
-        out = gmm(hidden, down, route.group_sizes)
+        hidden = (nn.silu(gmm(rows, gate, group_sizes))
+                  * gmm(rows, up, group_sizes))
+        return gmm(hidden, down, group_sizes)
+
+
+def _held_pass(c, x, weights, gate, up, down, perm, offsets, top_k: int,
+               bound: int):
+    """Pass ``c`` over the held rows: the part of the result that the sorted
+    rows ``[c * bound, (c + 1) * bound)`` give, ``[T, d]`` float32. ``perm``
+    holds the held rows' flat slots first, by expert; ``offsets [H + 1]`` the
+    first sorted row of each held expert and the end of the last."""
+    n_tokens, d = x.shape
+    first = c * bound
+    kept = jax.lax.dynamic_slice(perm, (first,), (bound,))
+    token = kept // top_k
+    sizes = jnp.diff(jnp.clip(offsets, first, first + bound))
+    weight = jnp.where(first + jnp.arange(bound) < offsets[-1],
+                       jnp.take(weights, kept), 0.0)
+    with jax.named_scope("moe.dispatch"):
+        rows = _take_rows(x, token, n_tokens)
+    out = _gated_experts(rows, gate, up, down, sizes)
     with jax.named_scope("moe.combine"):
-        out = _permute_rows(out, route.inv_perm, route.perm)
-        y = jnp.einsum("tk,tkd->td", route.weights,
-                       out.reshape(n_tokens, top_k, d),
-                       preferred_element_type=jnp.float32)
-    return y, route.group_sizes
+        return jnp.zeros((n_tokens, d), jnp.float32).at[token].add(
+            weight[:, None] * out.astype(jnp.float32))
+
+
+def _passes(offsets, bound: int):
+    return (offsets[-1] + bound - 1) // bound
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _held_passes(x, weights, gate, up, down, perm, offsets, top_k, bound):
+    """Every pass the held rows need, ``ceil(held rows / bound)`` of them, a
+    number known only at run time: a loop over :func:`_held_pass` whose
+    buffers are one pass's. The transpose runs the same number of passes
+    again, each recomputed and transposed in turn (a loop of unknown length
+    has no transpose of its own), so no pass keeps anything for it."""
+    return jax.lax.fori_loop(
+        0, _passes(offsets, bound),
+        lambda c, y: y + _held_pass(c, x, weights, gate, up, down, perm,
+                                    offsets, top_k, bound),
+        jnp.zeros(x.shape, jnp.float32))
+
+
+def _held_passes_fwd(x, weights, gate, up, down, perm, offsets, top_k, bound):
+    y = _held_passes(x, weights, gate, up, down, perm, offsets, top_k, bound)
+    return y, (x, weights, gate, up, down, perm, offsets)
+
+
+def _held_passes_bwd(top_k, bound, residuals, g):
+    *operands, perm, offsets = residuals
+
+    def one(c, total):
+        _, transpose = jax.vjp(
+            lambda *a: _held_pass(c, *a, perm, offsets, top_k, bound), *operands)
+        return jax.tree_util.tree_map(
+            lambda t, part: t + part.astype(t.dtype), total, transpose(g))
+
+    # a token's rows add up over the passes in float32, as within one
+    zeros = [jnp.zeros(a.shape, jnp.float32) for a in operands]
+    total = jax.lax.fori_loop(0, _passes(offsets, bound), one, tuple(zeros))
+    return (*(t.astype(a.dtype) for t, a in zip(total, operands)), None, None)
+
+
+_held_passes.defvjp(_held_passes_fwd, _held_passes_bwd)
+
+
+def routed_experts(x, scores, gate, up, down, bias=None, *, top_k: int,
+                   route: Callable[..., Route] = topk_route,
+                   first_expert: int = 0, rows_bound: Optional[int] = None):
+    """The local computation of a routed gated-SiLU FFN, one function of the
+    tokens, the router's scores over its full width and the bank of the
+    experts held here: top-k and sort, the grouped products over the experts
+    held, the weighted un-sort.
+
+    x: ``[T, d]``; scores: ``[T, E]`` float32 (``route`` says what they are:
+    :func:`topk_route` softmax probabilities, :func:`sigmoid_topk_route`
+    sigmoid scores chosen under ``bias [E]``); gate, up: ``[H, d, w]``; down:
+    ``[H, w, d]``, the experts ``[first_expert, first_expert + H)``. Returns
+    ``(y [T, d] float32, group_sizes [H])``: ``y[t] = sum over the slots of t
+    whose expert is held of weight * down_e(silu(gate_e x[t]) * up_e x[t])``,
+    accumulated in float32 and left so.
+
+    Every expert held (``H == E``, ``rows_bound`` None): the ``T*k`` rows are
+    sorted and every one computed. One chip's share (``H < E``): what the
+    absent experts would add is left out, and their rows are kept out of the
+    buffers too, not only out of the products: held rows sort first, and a
+    pass gathers, multiplies and adds back ``rows_bound`` of them in buffers of
+    that many rows (``T*k`` where not given: one pass). Dropless for the
+    experts held, whatever the router does: a step that routes more than
+    ``rows_bound`` rows to them takes as many passes over the same buffers as
+    they need (:func:`_held_passes`), so the bound sets the memory and the
+    grain of the work, never which rows are computed."""
+    from autodist_tpu import telemetry
+    n_tokens, d = x.shape
+    n_held, width = int(gate.shape[0]), int(scores.shape[1])
+    n_slots = n_tokens * top_k
+    whole = n_held == width and rows_bound is None
+    rows_bound = n_slots if rows_bound is None else min(int(rows_bound), n_slots)
+    telemetry.gauge("moe.experts").set(n_held)
+    telemetry.gauge("moe.top_k").set(int(top_k))
+    telemetry.gauge("moe.rows_per_call").set(rows_bound)
+    telemetry.gauge("moe.router_width").set(width)
+    telemetry.gauge("moe.experts_held").set(n_held)
+    telemetry.gauge("moe.rows_bound").set(rows_bound)
+    passes = -(-n_slots // rows_bound)       # at most; a step takes what it needs
+    telemetry.gauge("moe.passes_max").set(passes)
+    if whole:
+        with jax.named_scope("moe.route"):
+            r = route(scores, top_k, bias)
+        with jax.named_scope("moe.dispatch"):
+            rows = _dispatch_rows(x, r.perm, r.inv_perm, top_k)
+        out = _gated_experts(rows, gate, up, down, r.group_sizes)
+        with jax.named_scope("moe.combine"):
+            out = _permute_rows(out, r.inv_perm, r.perm)
+            y = jnp.einsum("tk,tkd->td", r.weights,
+                           out.reshape(n_tokens, top_k, d),
+                           preferred_element_type=jnp.float32)
+        return y, r.group_sizes
+    with jax.named_scope("moe.route"):
+        r = route(scores, top_k, bias, first_expert=first_expert, n_held=n_held)
+        offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                   jnp.cumsum(r.group_sizes, dtype=jnp.int32)])
+        weights = r.weights.reshape(-1)
+    if passes == 1:
+        y = _held_pass(0, x, weights, gate, up, down, r.perm, offsets, top_k,
+                       rows_bound)
+    else:
+        perm = jnp.pad(r.perm, (0, passes * rows_bound - n_slots))
+        y = _held_passes(x, weights, gate, up, down, perm, offsets, top_k,
+                         rows_bound)
+    return y, r.group_sizes
 
 
 class RoutedFFN(nn.Module):

@@ -24,7 +24,7 @@ its keys see — one recomputed score tile (scores, p, dP, ds) feeds all three
 gradients, five products where two kernels ran seven. The tile is held
 transposed as in the forward, so every product is a form the forward runs and
 lse / D enter as the lane-dense rows they are stored as. Past
-``_RESIDENT_DQ_BYTES`` of dQ a (batch, head) (L 16,384 at head_dim 64) the same
+``_RESIDENT_DQ_BYTES`` of dQ a (batch, head) (L 16,384 at head_dim 128) the same
 block body runs as two kernels, each recomputing the tile:
 
 - dK/dV (``flash_bwd_dkv``): grid (batch*heads, k-blocks, q-blocks) — a k block
@@ -35,6 +35,14 @@ block body runs as two kernels, each recomputing the tile:
 A block the diagonal hides is neither computed nor (under static offsets)
 copied. The row term D_i = rowsum(dO * O) is precomputed in XLA (elementwise,
 fused).
+
+A sliding window (``window=W``: a query sees itself and the ``W - 1`` keys
+before it) is a second edge of the same classes: tiles wholly below the band
+are skipped as those above the diagonal are (not walked inside a block, not
+copied where they are a grid step), tiles the lower edge crosses run the masked
+body. Grouped KV heads: K and V keep their ``H_kv`` heads in memory and query
+head ``n`` reads head ``n // (H / H_kv)`` through the index maps; a group's
+float32 dK / dV are summed outside the kernels.
 
 On non-TPU backends the kernels run in pallas interpret mode, so tests exercise
 the same code path on the CPU-sim mesh.
@@ -91,7 +99,11 @@ DEFAULT_K_BLOCK = 512
 _KEY_TILE = 512             # keys a score tile of the forward: [512, bq] f32
 _RESIDENT_KV_BYTES = 1 << 20     # K (or V) of one (batch, head) kept in VMEM
 _STREAM_K_BLOCK = 2048           # K/V rows a grid step beyond that
-_RESIDENT_DQ_BYTES = 2 << 20     # the one-pass backward's f32 dQ of one (batch, head)
+# The one-pass backward's f32 dQ of one (batch, head). 4 MiB since PR 29 (L 8,192
+# at head_dim 128, trinity-pretrain-8k's call: one pass 4.99 ms under a window of
+# 2,048 and 8.46 without, where the two kernels took 10.60 and 15.55; L 16,384
+# at head_dim 64: 7.30 against 14.37; PERF.md §6 "PR 29").
+_RESIDENT_DQ_BYTES = 4 << 20
 _BACKWARD_VMEM_LIMIT = 48 << 20  # scoped VMEM the one-pass backward asks for
 
 
@@ -105,7 +117,7 @@ def _sub_tile(bk: int) -> int:
 
 
 def _is_static(n) -> bool:
-    return isinstance(n, (int, np.integer))
+    return isinstance(n, (int, np.integer, np.ndarray))
 
 
 def _scale_is_exact(scale: float) -> bool:
@@ -145,8 +157,29 @@ def _tile_counts(q_lo, k_lo, valid, bq: int, bk: int, sub: int, causal: bool):
     return n_plain, n_need
 
 
+def _band_tile_counts(q_lo, k_lo, valid, bq: int, bk: int, sub: int,
+                      causal: bool, window):
+    """``(n_lo, n_ps, n_pe, n_need)`` of the key tiles of one (q block, K/V
+    block) pair under a window of ``window`` keys (a query at ``i`` sees keys
+    ``i - window < j <= i``): tiles ``[0, n_lo)`` lie wholly below the band
+    (skipped), ``[n_lo, n_ps)`` are crossed by its lower edge (masked),
+    ``[n_ps, n_pe)`` hold no masked score (plain), ``[n_pe, n_need)`` are
+    crossed by the diagonal or hold padded keys (masked), the rest hold
+    nothing the mask keeps. ``window=None`` is :func:`_tile_counts` with an
+    empty lower part, in Python ints, so that nothing is emitted for it."""
+    n_plain, n_need = _tile_counts(q_lo, k_lo, valid, bq, bk, sub, causal)
+    if window is None:
+        return 0, 0, n_plain, n_need
+    xp = np if all(_is_static(x) for x in (q_lo, k_lo, n_plain, n_need)) else jnp
+    gap = q_lo - k_lo - window        # the last key the block's first query cannot see
+    n_lo = xp.minimum(xp.clip(gap + 1, 0, bk) // sub, n_need)
+    n_ps = xp.clip((xp.clip(gap + bq, 0, bk) + sub - 1) // sub, n_lo, n_need)
+    return n_lo, n_ps, xp.clip(n_plain, n_ps, n_need), n_need
+
+
 def _attend_block(q_ref, k_ref, v_ref, state, *, q_lo, k_lo, valid, sub: int,
-                  causal: bool, scale: float, guard_empty_rows: bool):
+                  causal: bool, scale: float, guard_empty_rows: bool,
+                  window=None):
     """Online-softmax update of ``state = (m [1, bq], l [1, bq], acc [d, bq])``
     against the VMEM-resident K/V block — the single definition shared by the
     plain forward kernel and the carry variant.
@@ -165,16 +198,20 @@ def _attend_block(q_ref, k_ref, v_ref, state, *, q_lo, k_lo, valid, sub: int,
     ``q_lo`` / ``k_lo``: global positions of the block's first query and key;
     ``valid``: real keys from the block's first on, None where the K/V rows
     hold no padding. ``guard_empty_rows``: a query may have met no valid key yet (ring offsets,
-    a carry that starts at NEG_INF), so a masked score must not read as
-    ``exp(NEG_INF - NEG_INF) = 1``. With zero offsets every query sees key 0
-    in its first tile and the guard is dead."""
+    a carry that starts at NEG_INF, a window whose first tile the block's
+    later queries see nothing of), so a masked score must not read as
+    ``exp(NEG_INF - NEG_INF) = 1``. With zero offsets and no window every
+    query sees key 0 in its first tile and the guard is dead. ``window``:
+    keys a query sees, itself included (None: all before it); the tiles below
+    the band are not walked (:func:`_band_tile_counts`)."""
     q = q_ref[0]                                      # [bq, d]
     bq, bk = q.shape[0], k_ref.shape[1]
     # scale once per q block where that is exact, else on the score tile.
     prescale = _scale_is_exact(scale)
     if prescale:
         q = q * jnp.asarray(scale, q.dtype)
-    n_plain, n_need = _tile_counts(q_lo, k_lo, valid, bq, bk, sub, causal)
+    n_lo, n_ps, n_pe, n_need = _band_tile_counts(q_lo, k_lo, valid, bq, bk, sub,
+                                                 causal, window)
 
     def tile(j, state, masked: bool):
         m_prev, l_prev, acc = state
@@ -195,6 +232,8 @@ def _attend_block(q_ref, k_ref, v_ref, state, *, q_lo, k_lo, valid, sub: int,
                 query = jax.lax.broadcasted_iota(jnp.int32, (sub, bq), 1)
                 above = key - query > q_lo - k_lo - start
                 invalid = above if invalid is None else invalid | above
+                if window is not None:
+                    invalid |= key - query <= q_lo - k_lo - start - window
             scores = jnp.where(invalid, NEG_INF, scores)
         m_new = jnp.maximum(m_prev, scores.max(axis=0, keepdims=True))
         correction = jnp.exp(m_prev - m_new)
@@ -207,8 +246,9 @@ def _attend_block(q_ref, k_ref, v_ref, state, *, q_lo, k_lo, valid, sub: int,
             preferred_element_type=jnp.float32)       # [d, bq]
         return m_new, l_new, acc
 
-    state = _loop(0, n_plain, lambda j, s: tile(j, s, False), state)
-    return _loop(n_plain, n_need, lambda j, s: tile(j, s, True), state)
+    state = _loop(n_lo, n_ps, lambda j, s: tile(j, s, True), state)
+    state = _loop(n_ps, n_pe, lambda j, s: tile(j, s, False), state)
+    return _loop(n_pe, n_need, lambda j, s: tile(j, s, True), state)
 
 
 def _valid_keys(lk: int, k_start, bk: int):
@@ -228,7 +268,7 @@ def _loop(lo, hi, body, state):
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
-                  lk: int, sub: int, causal: bool, scale: float):
+                  lk: int, sub: int, causal: bool, scale: float, window=None):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     n_k = pl.num_programs(2)
@@ -244,13 +284,16 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
     k_start = ki * bk
     # Causal: skip K/V blocks strictly above the diagonal.
     needed = (k_start <= q_start + bq - 1) if causal else True
+    if window is not None:          # and K/V blocks wholly below the band
+        needed &= k_start + bk - 1 > q_start - window
 
     @pl.when(needed)
     def _step():
         m_ref[:], l_ref[:], acc_ref[:] = _attend_block(
             q_ref, k_ref, v_ref, (m_ref[:], l_ref[:], acc_ref[:]),
             q_lo=q_start, k_lo=k_start, valid=_valid_keys(lk, k_start, bk),
-            sub=sub, causal=causal, scale=scale, guard_empty_rows=False)
+            sub=sub, causal=causal, scale=scale,
+            guard_empty_rows=window is not None, window=window)
 
     @pl.when(ki == n_k - 1)
     def _finish():
@@ -284,29 +327,49 @@ def _forward_blocks(lq: int, lk: int, d: int, itemsize: int, q_block, k_block):
     return bq, bk, _sub_tile(bk)
 
 
-def _count_tiles(lq: int, lk: int, bq: int, bk: int, sub: int, causal: bool):
+def _count_tiles(lq: int, lk: int, bq: int, bk: int, sub: int, causal: bool,
+                 window=None):
     """(plain, masked, skipped) score tiles of one (batch, head) under zero
     offsets, at the granularity the body runs them: [bq, sub]."""
     n_q, n_k = pl.cdiv(lq, bq), pl.cdiv(lk, bk)
     plain = need = 0
     for qi in range(n_q):
         for ki in range(n_k):
-            a, b = _tile_counts(qi * bq, ki * bk, _valid_keys(lk, ki * bk, bk),
-                                bq, bk, sub, causal)
-            plain, need = plain + int(a), need + int(b)
+            n_lo, n_ps, n_pe, n_need = _band_tile_counts(
+                qi * bq, ki * bk, _valid_keys(lk, ki * bk, bk), bq, bk, sub,
+                causal, window)
+            plain, need = plain + int(n_pe - n_ps), need + int(n_need - n_lo)
     return plain, need - plain, n_q * n_k * (bk // sub) - need
 
 
-def _flash_forward(q, k, v, causal: bool, q_block, k_block, interpret: bool):
-    """Returns (out [B, Lq, H, D], lse [B*H, n_q, bq] f32)."""
+def _kv_group(q, k) -> int:
+    """Query heads a KV head serves (1: multi-head attention)."""
+    h, h_kv = q.shape[2], k.shape[2]
+    if h % h_kv:
+        raise ValueError(f"{h} query heads do not divide over {h_kv} KV heads")
+    return h // h_kv
+
+
+def _kv_row(group: int):
+    """Grid row of a (batch, query head) -> row of its (batch, KV head) in the
+    collapsed K/V: query head ``n`` reads KV head ``n // group``."""
+    return (lambda bh: bh) if group == 1 else (lambda bh: bh // group)
+
+
+def _flash_forward(q, k, v, causal: bool, q_block, k_block, interpret: bool,
+                   window=None):
+    """Returns (out [B, Lq, H, D], lse [B*H, n_q, bq] f32). ``k`` / ``v``
+    may hold fewer heads than ``q`` (grouped KV heads)."""
     b, lq, h, d = q.shape
-    lk = k.shape[1]
+    lk, h_kv = k.shape[1], k.shape[2]
+    group = _kv_group(q, k)
+    kv_row = _kv_row(group)
     scale = 1.0 / (d ** 0.5)
 
     # Collapse (batch, head) into the grid's first axis: [B*H, L, D].
     qf = q.transpose(0, 2, 1, 3).reshape(b * h, lq, d)
-    kf = k.transpose(0, 2, 1, 3).reshape(b * h, lk, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * h, lk, d)
+    kf = k.transpose(0, 2, 1, 3).reshape(b * h_kv, lk, d)
+    vf = v.transpose(0, 2, 1, 3).reshape(b * h_kv, lk, d)
 
     bq, bk, sub = _forward_blocks(lq, lk, d, q.dtype.itemsize, q_block, k_block)
     n_q = pl.cdiv(lq, bq)
@@ -317,21 +380,27 @@ def _flash_forward(q, k, v, causal: bool, q_block, k_block, interpret: bool):
         kf = jnp.pad(kf, ((0, 0), (0, n_k * bk - lk), (0, 0)))
         vf = jnp.pad(vf, ((0, 0), (0, n_k * bk - lk), (0, 0)))
 
-    plain, masked, skipped = _count_tiles(lq, lk, bq, bk, sub, causal)
+    plain, masked, skipped = _count_tiles(lq, lk, bq, bk, sub, causal, window)
     telemetry.gauge("flash.fwd.tiles_plain").set(plain)
     telemetry.gauge("flash.fwd.tiles_masked").set(masked)
     telemetry.gauge("flash.fwd.tiles_skipped").set(skipped)
+    telemetry.gauge("flash.window").set(window or 0)
+    telemetry.gauge("flash.kv_group").set(group)
 
     kernel = functools.partial(_flash_kernel, lk=lk, sub=sub, causal=causal,
-                               scale=scale)
+                               scale=scale, window=window)
     if causal and n_k > 1:
-        # A K/V block above the diagonal names the last one below it again, so
-        # its (skipped) grid step copies nothing in.
+        # A K/V block above the diagonal names the last one below it again
+        # (and one below the band the first one inside it), so its (skipped)
+        # grid step copies nothing in.
         def kv_index(bh, i, j):
-            return bh, jnp.minimum(j, ((i + 1) * bq - 1) // bk), 0
+            j = jnp.minimum(j, ((i + 1) * bq - 1) // bk)
+            if window is not None:
+                j = jnp.maximum(j, jnp.maximum(i * bq - window + 1, 0) // bk)
+            return kv_row(bh), j, 0
     else:
         def kv_index(bh, i, j):
-            return bh, j, 0
+            return kv_row(bh), j, 0
     out, lse = named_pallas_call(
         "flash_fwd", kernel,
         grid=(b * h, n_q, n_k),
@@ -370,16 +439,20 @@ def _flash_forward(q, k, v, causal: bool, q_block, k_block, interpret: bool):
 
 
 def _query_tile_counts(q_lo, k_lo, valid, bq: int, bk: int, sub: int,
-                       causal: bool):
-    """``(t_need, t_plain)`` of the ``bq // sub`` query tiles of one (q block,
-    K/V block) pair of the backward, whose block is walked along the QUERIES:
-    tiles ``[0, t_need)`` hold nothing the mask keeps (their queries come
-    before every key: skipped, neither computed nor, where they are a grid
-    step, copied), ``[t_need, t_plain)`` are crossed by the diagonal or meet
-    padded keys, the rest hold no masked score. :func:`_tile_counts` on the
-    mirrored pair: with positions negated the queries are the keys of a
-    causal mask and the last query tile is the first key tile, so there is
-    one definition of the classes."""
+                       causal: bool, window=None):
+    """``(t_need, t_plain, t_band, t_end)`` of the ``bq // sub`` query tiles
+    of one (q block, K/V block) pair of the backward, whose block is walked
+    along the QUERIES: tiles ``[0, t_need)`` hold nothing the mask keeps
+    (their queries come before every key: skipped, neither computed nor,
+    where they are a grid step, copied), ``[t_need, t_plain)`` are crossed by
+    the diagonal or meet padded keys, ``[t_plain, t_band)`` hold no masked
+    score, ``[t_band, t_end)`` are crossed by the lower edge of a window of
+    ``window`` keys, and from ``t_end`` on the queries come more than a window
+    after every key (skipped as the first are). Without a window ``t_band =
+    t_end = bq // sub`` as Python ints. :func:`_tile_counts` on the mirrored
+    pair: with positions negated the queries are the keys of a causal mask
+    and the last query tile is the first key tile, so there is one definition
+    of the classes."""
     n_t = bq // sub
     if not causal:
         t_need = t_plain = 0
@@ -390,12 +463,18 @@ def _query_tile_counts(q_lo, k_lo, valid, bq: int, bk: int, sub: int,
     if valid is not None:           # a ragged tail of keys: every query meets it
         xp = np if _is_static(valid) and _is_static(t_plain) else jnp
         t_plain = xp.where(valid < bk, n_t, t_plain)
-    return t_need, t_plain
+    if window is None:
+        return t_need, t_plain, n_t, n_t
+    xp = np if all(_is_static(x) for x in (q_lo, k_lo, t_need, t_plain)) else jnp
+    reach = k_lo + window - q_lo    # queries from the block's first on that see key k_lo
+    t_end = xp.maximum(xp.clip(reach + bk + sub - 2, 0, bq) // sub, t_need)
+    t_band = xp.clip(xp.clip(reach, 0, bq) // sub, t_plain, t_end)
+    return t_need, xp.minimum(t_plain, t_end), t_band, t_end
 
 
 def _backward_block(q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
                     dq_acc, dk_acc, dv_acc, *, row0, q_lo, k_lo, valid,
-                    sub: int, causal: bool, scale: float):
+                    sub: int, causal: bool, scale: float, window=None):
     """The backward's block math against one VMEM-resident K/V block — the
     single definition shared by the one-pass kernel and the two kernels of the
     split path. The q rows of the grid step are walked in tiles of ``sub``
@@ -421,13 +500,16 @@ def _backward_block(q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
     ``q_lo`` / ``k_lo``: global positions of the first query and key;
     ``valid``: real keys from the block's first on, None where no K/V row is
     padding. Padded QUERY rows need no mask: dO is zero there (so dP, D and
-    with them ds are zero, and p meets a zero row of dO)."""
+    with them ds are zero, and p meets a zero row of dO). ``window``: keys
+    a query sees, itself included; the walk ends with the last tile whose
+    queries still see a key of the block."""
     bq, bk = q_ref.shape[1], k_ref.shape[1]
     n_t = bq // sub
     prescale = _scale_is_exact(scale)
     k = k_ref[0]                                          # [bk, d]
     v = v_ref[0]
-    t_need, _ = _query_tile_counts(q_lo, k_lo, valid, bq, bk, sub, causal)
+    t_need, _, _, t_end = _query_tile_counts(q_lo, k_lo, valid, bq, bk, sub,
+                                             causal, window)
 
     def tile(t, carry):
         start = _tile_start(t, sub, n_t)
@@ -452,6 +534,8 @@ def _backward_block(q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
             query = jax.lax.broadcasted_iota(jnp.int32, (bk, sub), 1)
             above = key - query > q_lo + start - k_lo
             invalid = above if invalid is None else invalid | above
+            if window is not None:
+                invalid |= key - query <= q_lo + start - k_lo - window
         if invalid is not None:
             p = jnp.where(invalid, 0.0, p)
         dp = jax.lax.dot_general(
@@ -469,9 +553,10 @@ def _backward_block(q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
         return carry
 
     if n_t == 1 and not _is_static(t_need):
-        pl.when(t_need == 0)(lambda: tile(0, None))       # a branch, not a loop
+        run = t_need == 0 if _is_static(t_end) else (t_need == 0) & (t_end == 1)
+        pl.when(run)(lambda: tile(0, None))               # a branch, not a loop
     else:
-        _loop(t_need, n_t, tile, None)
+        _loop(t_need, t_end, tile, None)
 
 
 def _finish_dkdv(dk_ref, dv_ref, dk_acc, dv_acc, scale: float):
@@ -483,7 +568,8 @@ def _finish_dkdv(dk_ref, dv_ref, dk_acc, dv_acc, scale: float):
 
 def _flash_bwd_kernel(off_ref, q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
                       dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
-                      lk: int, sub: int, causal: bool, scale: float):
+                      lk: int, sub: int, causal: bool, scale: float,
+                      window=None):
     """The one-pass backward: q, dO and the float32 dQ accumulator of one
     (batch, head) stay in VMEM across its K/V blocks (the grid's second axis);
     a grid step finishes dK and dV of its block, the last writes dQ."""
@@ -501,7 +587,7 @@ def _flash_bwd_kernel(off_ref, q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
                     dq_acc, dk_acc, dv_acc, row0=0, q_lo=off_ref[0],
                     k_lo=off_ref[1] + k_start,
                     valid=_valid_keys(lk, k_start, bk), sub=sub, causal=causal,
-                    scale=scale)
+                    scale=scale, window=window)
     _finish_dkdv(dk_ref, dv_ref, dk_acc, dv_acc, scale)
 
     @pl.when(ki == pl.num_programs(1) - 1)
@@ -518,7 +604,7 @@ def _flash_bwd_kernel(off_ref, q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
 
 def _flash_bwd_dkdv_kernel(off_ref, q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
                            dk_ref, dv_ref, dk_acc, dv_acc, *,
-                           lk: int, causal: bool, scale: float):
+                           lk: int, causal: bool, scale: float, window=None):
     """The split path's dK/dV: a K/V block accumulates over the q blocks."""
     ki = pl.program_id(1)
     qi = pl.program_id(2)
@@ -534,7 +620,7 @@ def _flash_bwd_dkdv_kernel(off_ref, q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref
                     None, dk_acc, dv_acc, row0=qi, q_lo=off_ref[0] + qi * bq,
                     k_lo=off_ref[1] + k_start,
                     valid=_valid_keys(lk, k_start, bk), sub=bq, causal=causal,
-                    scale=scale)
+                    scale=scale, window=window)
 
     @pl.when(qi == pl.num_programs(2) - 1)
     def _finish():
@@ -542,7 +628,8 @@ def _flash_bwd_dkdv_kernel(off_ref, q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref
 
 
 def _flash_bwd_dq_kernel(off_ref, q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
-                         dq_ref, dq_acc, *, lk: int, causal: bool, scale: float):
+                         dq_ref, dq_acc, *, lk: int, causal: bool, scale: float,
+                         window=None):
     """The split path's dQ: a q block accumulates over the K/V blocks."""
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -557,7 +644,7 @@ def _flash_bwd_dq_kernel(off_ref, q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
                     dq_acc, None, None, row0=qi, q_lo=off_ref[0] + qi * bq,
                     k_lo=off_ref[1] + k_start,
                     valid=_valid_keys(lk, k_start, bk), sub=bq, causal=causal,
-                    scale=scale)
+                    scale=scale, window=window)
 
     @pl.when(ki == pl.num_programs(2) - 1)
     def _finish():
@@ -574,15 +661,17 @@ def _backward_blocks(lq: int, lk: int, q_block, k_block):
     return min(q_block or DEFAULT_Q_BLOCK, lq), min(k_block or DEFAULT_K_BLOCK, lk)
 
 
-def _count_backward_tiles(n_q: int, lk: int, bq: int, bk: int, causal: bool):
+def _count_backward_tiles(n_q: int, lk: int, bq: int, bk: int, causal: bool,
+                          window=None):
     """(plain, masked, skipped) ``[bk, bq]`` score tiles of one (batch, head)
     under zero offsets."""
     n_k = pl.cdiv(lk, bk)
     plain = need = 0
     for ki in range(n_k):
-        t_need, t_plain = _query_tile_counts(
-            0, ki * bk, _valid_keys(lk, ki * bk, bk), n_q * bq, bk, bq, causal)
-        plain, need = plain + n_q - int(t_plain), need + n_q - int(t_need)
+        t_need, t_plain, t_band, t_end = _query_tile_counts(
+            0, ki * bk, _valid_keys(lk, ki * bk, bk), n_q * bq, bk, bq, causal,
+            window)
+        plain, need = plain + int(t_band - t_plain), need + int(t_end - t_need)
     return plain, need - plain, n_q * n_k - need
 
 
@@ -611,7 +700,7 @@ def prepare_backward_q_side(q, o, g, q_block):
 
 def _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, k_block,
                        interpret, q_shape, q_offset=0, k_offset=0,
-                       out_dtype=None):
+                       out_dtype=None, window=None):
     """Backward against one K/V shard from prepared query-side layout. Returns
     (dq, dk, dv) in [B, L, H, D]; ``out_dtype`` overrides the kernels' output
     dtype (ring passes f32 so per-step contributions accumulate unquantized).
@@ -620,16 +709,22 @@ def _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, k_block,
     accumulator of one (batch, head) is within ``_RESIDENT_DQ_BYTES``; past
     it the same block body in two kernels, dK/dV and ``flash_bwd_dq``, each
     recomputing the score tiles. ``bq`` is the q tile and the lse / D planes'
-    row, ``k_block`` the K/V rows a grid step."""
+    row, ``k_block`` the K/V rows a grid step.
+
+    Grouped KV heads (``k`` / ``v`` with fewer heads than q): every query
+    head's kernels read the K/V rows of its KV head through the index map and
+    write their own float32 dK / dV, which are summed over the group here."""
     b, lq, h, d = q_shape
-    lk = k.shape[1]
+    lk, h_kv = k.shape[1], k.shape[2]
+    group = h // h_kv
+    kv_row = _kv_row(group)
     scale = 1.0 / (d ** 0.5)
     static_offsets = _is_static(q_offset) and _is_static(k_offset)
     offs = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                       jnp.asarray(k_offset, jnp.int32)])
 
-    kf = k.transpose(0, 2, 1, 3).reshape(b * h, lk, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * h, lk, d)
+    kf = k.transpose(0, 2, 1, 3).reshape(b * h_kv, lk, d)
+    vf = v.transpose(0, 2, 1, 3).reshape(b * h_kv, lk, d)
     bk = min(k_block, lk)
     n_k = pl.cdiv(lk, bk)
     k_pad = n_k * bk - lk
@@ -639,32 +734,37 @@ def _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, k_block,
     dq_dtype = out_dtype or qf.dtype
     dk_dtype = out_dtype or k.dtype
     dv_dtype = out_dtype or v.dtype
+    # a group's dK / dV leave the kernels unrounded and are summed below
+    head_dk, head_dv = ((dk_dtype, dv_dtype) if group == 1
+                        else (jnp.float32, jnp.float32))
     lq_p = n_q * bq
     one_pass = lq_p * d * 4 <= _RESIDENT_DQ_BYTES
 
-    plain, masked, skipped = _count_backward_tiles(n_q, lk, bq, bk, causal)
+    plain, masked, skipped = _count_backward_tiles(n_q, lk, bq, bk, causal,
+                                                   window)
     telemetry.gauge("flash.bwd.passes").set(1 if one_pass else 2)
     telemetry.gauge("flash.bwd.tiles_plain").set(plain)
     telemetry.gauge("flash.bwd.tiles_masked").set(masked)
     telemetry.gauge("flash.bwd.tiles_skipped").set(skipped)
 
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    kernel_args = dict(lk=lk, causal=causal, scale=scale)
+    kernel_args = dict(lk=lk, causal=causal, scale=scale, window=window)
     dq_shape = jax.ShapeDtypeStruct((b * h, lq_p, d), dq_dtype)
-    dkv_shape = (jax.ShapeDtypeStruct((b * h, n_k * bk, d), dk_dtype),
-                 jax.ShapeDtypeStruct((b * h, n_k * bk, d), dv_dtype))
+    dkv_shape = (jax.ShapeDtypeStruct((b * h, n_k * bk, d), head_dk),
+                 jax.ShapeDtypeStruct((b * h, n_k * bk, d), head_dv))
     dkv_scratch = [pltpu.VMEM((bk, d), jnp.float32),
                    pltpu.VMEM((bk, d), jnp.float32)]
     if one_pass:
         q_all = pl.BlockSpec((1, lq_p, d), lambda bh, i: (bh, 0, 0))
         rows = pl.BlockSpec((1, n_q, bq), lambda bh, i: (bh, 0, 0))
-        kv_spec = pl.BlockSpec((1, bk, d), lambda bh, i: (bh, i, 0))
+        kv_spec = pl.BlockSpec((1, bk, d), lambda bh, i: (kv_row(bh), i, 0))
+        dkv_spec = pl.BlockSpec((1, bk, d), lambda bh, i: (bh, i, 0))
         dq, dk, dv = named_pallas_call(
             "flash_bwd_dkv",
             functools.partial(_flash_bwd_kernel, sub=bq, **kernel_args),
             grid=(b * h, n_k),
             in_specs=[smem, q_all, q_all, rows, rows, kv_spec, kv_spec],
-            out_specs=(q_all, kv_spec, kv_spec),
+            out_specs=(q_all, dkv_spec, dkv_spec),
             out_shape=(dq_shape,) + dkv_shape,
             scratch_shapes=[pltpu.VMEM((n_q, d, bq), jnp.float32)] + dkv_scratch,
             compiler_params=pltpu.CompilerParams(
@@ -673,35 +773,48 @@ def _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, k_block,
             interpret=interpret,
         )(offs, qf, dof, lse, dd, kf, vf)
     else:
-        # A block the diagonal hides names the nearest one it does not, so its
-        # (skipped) grid step copies nothing in; traced offsets (the ring)
-        # cannot enter an index map and copy every block.
+        # A block the diagonal hides (or the band's lower edge) names the
+        # nearest one it does not, so its (skipped) grid step copies nothing
+        # in; traced offsets (the ring) cannot enter an index map and copy
+        # every block.
         skip = causal and static_offsets
 
         def q_of(i, j):      # dK/dV's grid: K/V block i, q block j
-            return jnp.clip((k_offset + i * bk - q_offset) // bq, j, n_q - 1) \
-                if skip else j
+            if not skip:
+                return j
+            first = (k_offset + i * bk - q_offset) // bq
+            if window is None:
+                return jnp.clip(first, j, n_q - 1)
+            last = (k_offset + (i + 1) * bk - 2 + window - q_offset) // bq
+            return jnp.clip(j, first, jnp.minimum(last, n_q - 1))
 
         def k_of(i, j):      # dQ's grid: q block i, K/V block j
-            return jnp.clip((q_offset + (i + 1) * bq - 1 - k_offset) // bk, 0, j) \
-                if skip else j
+            if not skip:
+                return j
+            last = (q_offset + (i + 1) * bq - 1 - k_offset) // bk
+            if window is None:
+                return jnp.clip(last, 0, j)
+            first = jnp.maximum(q_offset + i * bq - window + 1 - k_offset, 0) // bk
+            return jnp.clip(j, jnp.minimum(first, n_k - 1), jnp.maximum(last, 0))
 
         rows = pl.BlockSpec((1, n_q, bq), lambda bh, i, j: (bh, 0, 0))
         q_spec = pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, q_of(i, j), 0))
-        kv_spec = pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, i, 0))
+        kv_spec = pl.BlockSpec((1, bk, d), lambda bh, i, j: (kv_row(bh), i, 0))
+        dkv_spec = pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, i, 0))
         dk, dv = named_pallas_call(
             "flash_bwd_dkv",
             functools.partial(_flash_bwd_dkdv_kernel, **kernel_args),
             grid=(b * h, n_k, n_q),
             in_specs=[smem, q_spec, q_spec, rows, rows, kv_spec, kv_spec],
-            out_specs=(kv_spec, kv_spec),
+            out_specs=(dkv_spec, dkv_spec),
             out_shape=dkv_shape,
             scratch_shapes=dkv_scratch,
             interpret=interpret,
         )(offs, qf, dof, lse, dd, kf, vf)
 
         q_spec = pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0))
-        kv_spec = pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, k_of(i, j), 0))
+        kv_spec = pl.BlockSpec((1, bk, d),
+                               lambda bh, i, j: (kv_row(bh), k_of(i, j), 0))
         dq = named_pallas_call(
             "flash_bwd_dq",
             functools.partial(_flash_bwd_dq_kernel, **kernel_args),
@@ -714,18 +827,24 @@ def _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, k_block,
         )(offs, qf, dof, lse, dd, kf, vf)
 
     dq = dq[:, :lq, :].reshape(b, h, lq, d).transpose(0, 2, 1, 3)
-    dk = dk[:, :lk, :].reshape(b, h, lk, d).transpose(0, 2, 1, 3)
-    dv = dv[:, :lk, :].reshape(b, h, lk, d).transpose(0, 2, 1, 3)
-    return dq, dk, dv
+
+    def kv_heads(x, dtype):      # [B*H, Lk, D] -> [B, Lk, H_kv, D]
+        if group == 1:
+            return x[:, :lk, :].reshape(b, h, lk, d).transpose(0, 2, 1, 3)
+        x = x[:, :lk, :].reshape(b, h_kv, group, lk, d).sum(axis=2)
+        return x.astype(dtype).transpose(0, 2, 1, 3)
+
+    return dq, kv_heads(dk, dk_dtype), kv_heads(dv, dv_dtype)
 
 
 def _flash_backward(q, k, v, o, lse, g, causal, q_block, k_block, interpret,
-                    q_offset=0, k_offset=0, out_dtype=None):
+                    q_offset=0, k_offset=0, out_dtype=None, window=None):
     bq, bk = _backward_blocks(q.shape[1], k.shape[1], q_block, k_block)
     qf, dof, dd, bq, n_q = prepare_backward_q_side(q, o, g, bq)
     return _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, bk,
                               interpret, q.shape, q_offset=q_offset,
-                              k_offset=k_offset, out_dtype=out_dtype)
+                              k_offset=k_offset, out_dtype=out_dtype,
+                              window=window)
 
 
 def _flash_carry_kernel(off_ref, q_ref, k_ref, v_ref, acc_in_ref, m_in_ref,
@@ -874,30 +993,41 @@ def _use_interpret() -> bool:
         f"the default backend is {backend!r}")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash(q, k, v, causal, q_block, k_block):
-    out, _ = _flash_forward(q, k, v, causal, q_block, k_block, _use_interpret())
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, causal, q_block, k_block, window):
+    out, _ = _flash_forward(q, k, v, causal, q_block, k_block, _use_interpret(),
+                            window)
     return out
 
 
-def _flash_fwd(q, k, v, causal, q_block, k_block):
-    out, lse = _flash_forward(q, k, v, causal, q_block, k_block, _use_interpret())
+def _flash_fwd(q, k, v, causal, q_block, k_block, window):
+    out, lse = _flash_forward(q, k, v, causal, q_block, k_block, _use_interpret(),
+                              window)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, q_block, k_block, residuals, g):
+def _flash_bwd(causal, q_block, k_block, window, residuals, g):
     q, k, v, o, lse = residuals
     return _flash_backward(q, k, v, o, lse, g, causal, q_block, k_block,
-                           _use_interpret())
+                           _use_interpret(), window=window)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                    causal: bool = True, q_block: Optional[int] = None,
+                    causal: bool = True, window: Optional[int] = None,
+                    q_block: Optional[int] = None,
                     k_block: Optional[int] = None) -> jax.Array:
     """Flash attention over [B, L, H, D] tensors (pallas forward and backward).
+
+    ``window=W`` (causal only): the query at ``i`` sees the keys ``i - W < j
+    <= i``, itself and the ``W - 1`` before it. Tiles wholly below the band
+    are neither computed nor, where they are a grid step, copied; tiles either
+    edge crosses run the masked body. ``k`` / ``v`` may hold fewer heads than
+    ``q``, ``H_kv`` dividing ``H`` (grouped KV heads): query head ``n`` reads
+    KV head ``n // (H / H_kv)`` through the kernels' index maps, nothing is
+    repeated in memory, and dK / dV are the sum over a group's query heads.
 
     ``q_block`` / ``k_block`` left at None: the forward and the backward pick
     their blocks from the shape (:func:`_forward_blocks`,
@@ -907,6 +1037,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     Under a mesh of several devices the kernels run per device on its share
     of the batch (:func:`autodist_tpu.parallel.mesh.per_device`)."""
     from autodist_tpu.parallel.mesh import per_device
+    if window is not None and (not causal or window < 1):
+        raise ValueError(f"window={window!r} needs causal=True and window >= 1")
+    _kv_group(q, k)
     return per_device(
-        lambda q, k, v: _flash(q, k, v, causal, q_block, k_block),
+        lambda q, k, v: _flash(q, k, v, causal, q_block, k_block, window),
         (q, k, v), batched=(True, True, True))

@@ -86,6 +86,26 @@ def test_flash_attention_fwd_bwd_compiles(chip, batch, length, heads, depth):
     assert ("flash_bwd_dq" in text) != one_pass
 
 
+@pytest.mark.parametrize("window", [2048, None], ids=["window-2048", "full"])
+def test_flash_window_and_grouped_heads_compile_at_the_trinity_cell_shapes(
+        chip, window):
+    """trinity-pretrain-8k's calls: 1 x 8,192 x 32 query heads over 4 KV
+    heads of 128, a sliding layer (window 2,048) and the full one; K/V of a
+    head is 2 MB, so the forward streams it in 2,048-row blocks; float32 dQ of
+    a head is 4 MB, the most the backward takes in one pass."""
+    q = ((1, 8192, 32, 128), jnp.bfloat16)
+    kv = ((1, 8192, 4, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True,
+                                  window=window).astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1, 2)), chip,
+                          q, kv, kv)
+    assert "flash_fwd" in text and "flash_bwd_dkv" in text
+    assert 8192 * 128 * 4 == fa._RESIDENT_DQ_BYTES and "flash_bwd_dq" not in text
+
+
 def test_flash_carry_variant_compiles(chip):
     """The ring-attention local step: (acc, m, l) carry in and out."""
     b, length, h, d = 1, 4096, 8, 64
@@ -181,6 +201,23 @@ def test_grouped_matmul_fwd_bwd_compiles_at_the_olmoe_cell_shapes(chip, k, n):
                           ((131_072, k), jnp.bfloat16),
                           ((64, k, n), jnp.float32), ((64,), jnp.int32))
     assert "tpu_custom_call" in text
+    for name in ("moe_gmm_fwd", "moe_gmm_bwd_dx", "moe_gmm_bwd_dw"):
+        assert name in text
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1024), (1024, 2048)],
+                         ids=["gate-up-2048x1024", "down-1024x2048"])
+def test_grouped_matmul_fwd_bwd_compiles_at_the_trinity_cell_shapes(chip, k, n):
+    """The 8,192-row bound of one chip's share (8 of 128 experts held, 4,096
+    rows on average, the tail past the last group empty) in 8 groups."""
+    from autodist_tpu.ops.grouped_matmul import gmm
+
+    def loss(x, w, group_sizes):
+        return gmm(x, w, group_sizes).astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1)), chip,
+                          ((8192, k), jnp.bfloat16),
+                          ((8, k, n), jnp.float32), ((8,), jnp.int32))
     for name in ("moe_gmm_fwd", "moe_gmm_bwd_dx", "moe_gmm_bwd_dw"):
         assert name in text
 

@@ -1,0 +1,158 @@
+"""Flash attention under a sliding window and grouped KV heads
+(``ops/flash_attention.py`` ``flash_attention(..., window=W)``, K/V with
+fewer heads than q): forward and all three gradients against dot attention
+with the band mask, in every schedule the kernels have (K/V resident and
+walked in tiles, K/V streamed in blocks; the backward in one pass and
+split), and the tile counts and gauges against counts made by hand. Tiny
+sizes on the CPU; kernels in interpret mode."""
+
+import importlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from autodist_tpu import telemetry
+
+fa = importlib.import_module("autodist_tpu.ops.flash_attention")
+
+DEPTH = 32
+
+
+def band_attention(q, k, v, window):
+    """Dot attention over [B, L, H, D] with K/V repeated over their group and
+    the band ``i - window < j <= i`` as a mask."""
+    group = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+    length = q.shape[1]
+    i, j = jnp.arange(length)[:, None], jnp.arange(length)[None, :]
+    visible = j <= i
+    if window is not None:
+        visible &= i - j < window
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    probs = jax.nn.softmax(jnp.where(visible, scores, -1e30), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+def _inputs(length, heads, kv_heads):
+    keys = jax.random.split(jax.random.PRNGKey(length + heads), 4)
+    shape = lambda h: (1, length, h, DEPTH)  # noqa: E731
+    return (jax.random.normal(keys[0], shape(heads)),
+            jax.random.normal(keys[1], shape(kv_heads)),
+            jax.random.normal(keys[2], shape(kv_heads)),
+            jax.random.normal(keys[3], shape(heads)))
+
+
+# resident: K/V of a head is one block of 1,536 rows (1,100 padded to whole
+# 512-key tiles) walked in three tiles, the backward one pass over 512 x 512
+# tiles; streamed: 64-row blocks against a length of 200 (ragged), the
+# backward one pass; split: the same with the dQ byte limit at 0, so the
+# backward is its two kernels and a skipped block is a grid step.
+SCHEDULES = {"resident": (1100, None, None, None),
+             "streamed": (200, 64, 64, None),
+             "split": (200, 64, 64, 0)}
+
+
+@pytest.mark.parametrize("schedule", sorted(SCHEDULES))
+@pytest.mark.parametrize("group", [1, 8], ids=["mha", "kv-group-8"])
+@pytest.mark.parametrize("window", [None, 128, 2048],
+                         ids=["causal", "window-128", "window-past-L"])
+def test_window_and_grouped_heads_match_dot_attention(monkeypatch, window, group,
+                                                      schedule):
+    length, q_block, k_block, dq_bytes = SCHEDULES[schedule]
+    if dq_bytes is not None:
+        monkeypatch.setattr(fa, "_RESIDENT_DQ_BYTES", dq_bytes)
+    kv_heads = 1 if group == 8 else 2
+    q, k, v, w = _inputs(length, kv_heads * group, kv_heads)
+
+    def run(attend):
+        loss = lambda q, k, v: jnp.sum(attend(q, k, v) * w)  # noqa: E731
+        return attend(q, k, v), jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    out, grads = run(lambda q, k, v: fa.flash_attention(
+        q, k, v, causal=True, window=window, q_block=q_block, k_block=k_block))
+    ref_out, ref_grads = run(lambda q, k, v: band_attention(q, k, v, window))
+    assert telemetry.gauge("flash.window").value == (window or 0)
+    assert telemetry.gauge("flash.kv_group").value == group
+    assert telemetry.gauge("flash.bwd.passes").value == \
+        (2 if schedule == "split" else 1)
+    np.testing.assert_allclose(out, ref_out, atol=2e-5)
+    for name, got, want in zip("qkv", grads, ref_grads):
+        assert got.shape == want.shape, name
+        np.testing.assert_allclose(got, want, atol=2e-4, err_msg=f"d{name}")
+
+
+def test_a_window_of_one_key_returns_v():
+    q, k, v, _ = _inputs(256, 2, 2)
+    out = fa.flash_attention(q, k, v, window=1, q_block=64, k_block=64)
+    np.testing.assert_allclose(out, v, atol=1e-6)
+
+
+def test_window_needs_a_causal_mask_and_heads_that_divide():
+    q, k, v, _ = _inputs(64, 4, 2)
+    with pytest.raises(ValueError, match="causal"):
+        fa.flash_attention(q, k, v, causal=False, window=16)
+    with pytest.raises(ValueError, match="window"):
+        fa.flash_attention(q, k, v, window=0)
+    with pytest.raises(ValueError, match="KV heads"):
+        fa.flash_attention(q[:, :, :3], k, v)
+
+
+# ------------------------------------------------------------- tile counts
+
+def _by_hand(n_q, tile, window):
+    """(plain, masked, skipped) [tile x tile] score tiles of an n_q x n_q
+    square under the band, counted pair by pair from the positions."""
+    plain = masked = 0
+    for qi in range(n_q):
+        for ki in range(n_q):
+            rows = np.arange(qi * tile, (qi + 1) * tile)[:, None]
+            keys = np.arange(ki * tile, (ki + 1) * tile)[None, :]
+            visible = (keys <= rows) & (rows - keys < window)
+            plain += visible.all()
+            masked += visible.any() and not visible.all()
+    return int(plain), int(masked), n_q * n_q - int(plain) - int(masked)
+
+
+@pytest.mark.parametrize("length,tile,window,want", [
+    # the trinity cell's call: per q block the tile the lower edge crosses,
+    # three plain ones, the diagonal's; the first four blocks have no lower edge
+    (8192, 512, 2048, (42, 28, 186)),
+    (256, 64, 128, (3, 6, 7)),
+    (256, 64, 64, (0, 7, 9)),          # a window of one tile: both edges cross
+    (256, 64, 4096, (6, 4, 6)),        # wider than the sequence: the triangle
+], ids=["trinity-8k", "two-tiles", "one-tile", "past-L"])
+def test_tile_counts_under_the_band_equal_a_hand_count(length, tile, window, want):
+    n_q = length // tile
+    assert _by_hand(n_q, tile, window) == want
+    # the forward's walk: streamed K/V blocks of four tiles, and one resident block
+    assert fa._count_tiles(length, length, tile, 4 * tile, tile, True, window) == want
+    assert fa._count_tiles(length, length, tile, length, tile, True, window) == want
+    # the backward's walk over q tiles, a K/V block a tile
+    assert fa._count_backward_tiles(n_q, length, tile, tile, True, window) == want
+    if window >= length:
+        assert fa._count_tiles(length, length, tile, length, tile, True) == want
+        assert fa._count_backward_tiles(n_q, length, tile, tile, True) == want
+
+
+def test_the_gauges_count_the_band():
+    q, k, v, w = _inputs(256, 2, 1)
+    jax.grad(lambda q: jnp.sum(fa.flash_attention(
+        q, k, v, window=128, q_block=64, k_block=64) * w))(q)
+    for pass_ in ("fwd", "bwd"):
+        got = tuple(telemetry.gauge(f"flash.{pass_}.tiles_{name}").value
+                    for name in ("plain", "masked", "skipped"))
+        assert got == (3, 6, 7), pass_
+
+
+def test_ragged_keys_under_a_window_are_counted_masked_not_plain():
+    """200 keys in 64-row blocks: the last block holds 8 real keys, so every
+    tile that meets it is masked whatever the band says."""
+    plain, masked, skipped = fa._count_tiles(200, 200, 64, 64, 64, True, 100)
+    # q block 0: diagonal. 1: [0,64) crossed below (key 0 is 64 back of query
+    # 64.. and out of reach of query 100+), diagonal. 2: tile 0 crossed, tile 1
+    # crossed (query 191 sees from key 92), diagonal. 3: tile 0 below every
+    # query (192 - 100 = 92 > 63), tile 1 and 2 crossed, the ragged diagonal.
+    assert (plain, masked, skipped) == (0, 9, 7)
+    assert fa._count_backward_tiles(4, 200, 64, 64, True, 100) == (0, 9, 7)

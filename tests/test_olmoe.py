@@ -65,6 +65,27 @@ def test_loss_and_gradients_match_the_plain_reference(dtype, attention, fused,
     assert {str(g.dtype) for g in jax.tree_util.tree_leaves(grads)} == {"float32"}
 
 
+# PR 29 put a shared sort under ``topk_route`` and a second, compacting path
+# beside ``routed_experts`` (one chip's share of the experts, for
+# ``models/afmoe.py``), and a window and grouped KV heads into the flash
+# kernels. OLMoE holds every expert, attends without a window and has as many
+# KV heads as query heads: its program is what it was, and its loss at the
+# tiny size is the parent's (commit e714195) bit for bit, on this backend.
+@pytest.mark.parametrize("dtype,attention,fused,parent_loss", [
+    (jnp.float32, "dot", False, "0x1.6651600000000p+2"),
+    (jnp.float32, "flash", True, "0x1.66515e0000000p+2"),
+    (jnp.bfloat16, "flash", True, "0x1.6651900000000p+2"),
+], ids=["f32-xla", "f32-kernels", "bf16-kernels"])
+def test_the_loss_is_bit_for_bit_the_parents(dtype, attention, fused, parent_loss):
+    cfg = olmoe.OlmoeConfig(dtype=dtype, attention_impl=attention,
+                            fused_head=fused, **TINY)
+    model, params = olmoe.init_params(cfg, jax.random.PRNGKey(1))
+    batch = {"tokens": jnp.asarray(
+        olmoe.synthetic_batch(cfg, 4, 32, seed=3)["tokens"])}
+    loss = jax.jit(olmoe.make_loss_fn(model))(params, batch)
+    assert float(loss).hex() == parent_loss
+
+
 def test_topk_route_is_dropless():
     tokens, experts, k = 48, 8, 3
     probs = jax.nn.softmax(jax.random.normal(jax.random.PRNGKey(0),

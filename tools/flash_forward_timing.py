@@ -56,7 +56,21 @@ SHAPES = {
     # offsets, float32 outputs, 512-row blocks asked for
     "bwd-ring-diag": (4, 4096, 16, 64, True, "ring-bwd"),
     "bwd-ring-visible": (4, 4096, 16, 64, True, "ring-bwd"),
+    # trinity-pretrain-8k's calls (B·H 32, D 128, 4 KV heads): a sliding layer
+    # (window 2,048), the full layer, and each with K/V repeated in memory to
+    # the 32 query heads instead of read through the index map
+    "trinity-win": (1, 8192, 32, 128, True, "fwd"),
+    "trinity-full": (1, 8192, 32, 128, True, "fwd"),
+    "trinity-win-rep": (1, 8192, 32, 128, True, "fwd"),
+    "trinity-full-rep": (1, 8192, 32, 128, True, "fwd"),
+    "bwd-trinity-win": (1, 8192, 32, 128, True, "bwd"),
+    "bwd-trinity-full": (1, 8192, 32, 128, True, "bwd"),
+    "bwd-trinity-win-rep": (1, 8192, 32, 128, True, "bwd"),
+    "bwd-trinity-full-rep": (1, 8192, 32, 128, True, "bwd"),
 }
+# name -> (window, KV heads) where they are not (None, H)
+BANDS = {name: (2048 if "-win" in name else None, 32 if name.endswith("-rep") else 4)
+         for name in SHAPES if "trinity" in name}
 # the fused head, bfloat16 rows against a float32 table: name -> (N, D, V,
 # table layout, kind), at olmoe-pretrain-4k's call and at gpt2m-*'s, a chip
 # and call. ``xent-dh`` and ``xent-dw`` both run the whole backward and read
@@ -103,14 +117,17 @@ def build(fa, name, blocks=None):
     import jax.numpy as jnp
 
     b, length, h, d, causal, kind = SHAPES[name]
+    window, kv_heads = BANDS.get(name, (None, h))
+    # a checkout older than the window takes no such argument
+    band = {} if window is None else {"window": window}
     keys = jax.random.split(jax.random.PRNGKey(0), 3)
-    q, k, v = (jax.random.normal(key, (b, length, h, d), jnp.bfloat16)
-               for key in keys)
+    q, k, v = (jax.random.normal(key, (b, length, heads, d), jnp.bfloat16)
+               for key, heads in zip(keys, (h, kv_heads, kv_heads)))
     if kind == "fwd":
         chooses = hasattr(fa, "_forward_blocks")
         default = None if chooses else fa.DEFAULT_Q_BLOCK
         fn = jax.jit(lambda q, k, v: fa._flash_forward(
-            q, k, v, causal, default, default, False))
+            q, k, v, causal, default, default, False, **band))
         args = (q, k, v)
     elif kind in ("bwd", "ring-bwd"):
         ring = kind == "ring-bwd"
@@ -118,7 +135,8 @@ def build(fa, name, blocks=None):
         block = None if chooses else fa.DEFAULT_Q_BLOCK
         # the lse plane's rows are the forward's q block: the backward's q tile
         out, lse = jax.jit(lambda q, k, v: fa._flash_forward(
-            q, k, v, causal, blocks[0] if blocks else block, block, False))(q, k, v)
+            q, k, v, causal, blocks[0] if blocks else block, block, False,
+            **band))(q, k, v)
         g = jax.random.normal(jax.random.PRNGKey(1), q.shape, q.dtype)
         if ring:
             k_offset = length if name == "bwd-ring-diag" else 0
@@ -130,7 +148,7 @@ def build(fa, name, blocks=None):
             args = (q, k, v, out, lse, g, jnp.int32(length), jnp.int32(k_offset))
         else:
             fn = jax.jit(lambda q, k, v, o, lse, g: fa._flash_backward(
-                q, k, v, o, lse, g, causal, block, block, False))
+                q, k, v, o, lse, g, causal, block, block, False, **band))
             args = (q, k, v, out, lse, g)
     else:
         carry = (jnp.zeros((b, h, length, d), jnp.float32),
@@ -240,6 +258,9 @@ def main(argv=None):
                         help="the backward's bq,bk override; may repeat")
     parser.add_argument("--xent-blocks", action="append", default=[],
                         help="a head kernel's bn,bv override; may repeat")
+    parser.add_argument("--resident-dq-bytes", type=int, default=None,
+                        help="override _RESIDENT_DQ_BYTES: the float32 dQ of a "
+                             "(batch, head) up to which the backward is one pass")
     parser.add_argument("--calls", type=int, default=20)
     args = parser.parse_args(argv)
 
@@ -249,6 +270,9 @@ def main(argv=None):
         raise SystemExit(f"needs the TPU, the backend is {jax.default_backend()!r}")
     modules = {m: importlib.import_module(f"autodist_tpu.ops.{m}")
                for m in ("flash_attention", "fused_xent")}
+
+    if args.resident_dq_bytes is not None:
+        modules["flash_attention"]._RESIDENT_DQ_BYTES = args.resident_dq_bytes
 
     def plans(flags):
         return [tuple(int(x) for x in b.split(",")) for b in flags] or [None]
