@@ -21,17 +21,33 @@ activation dtype **per tile inside the kernel**, so no transposed or downcast
 copy of a multi-GiB table is ever materialized, and its gradient comes back in
 the stored layout/dtype directly.
 
-Three kernels:
+The kernels:
 - forward: grid (n-blocks, v-blocks); VMEM scratch carries (m, l) across the v
   dimension; last v-block writes ``lse = m + log l``.
-- d(h):    grid (n-blocks, v-blocks); accumulates g*p @ w^T tiles in VMEM.
-- d(w,b):  grid (v-blocks, n-blocks); accumulates h^T @ g*p and column-sums.
+- backward, one pass (device name ``xent_bwd_dw``): grid (n-blocks,
+  v-blocks); each step recomputes the logits tile and ``gp = exp(logits -
+  lse) * g`` ONCE and makes from it dh's term ``gp @ w^T`` (accumulated in
+  VMEM over a sweep of the vocabulary), the dw tile ``gp^T @ h`` and db's
+  column sums. A float32 dw cannot stay resident across row blocks (412 MB
+  at 2,048 x 50,304), so its tile accumulates through HBM: the dw output is
+  aliased to an input, each step reads the tile the earlier row blocks left,
+  adds and writes it back (the first row block writes without reading).
+- backward, two kernels, for the shapes the one pass does not take
+  (``_fit_blocks``): d(h), grid (n-blocks, v-blocks), accumulates
+  ``gp @ w^T`` tiles in VMEM; d(w,b), grid (v-blocks, n-blocks), accumulates
+  ``h^T @ gp`` and column sums. Both recompute the logits tile: five
+  logits-sized products a call where the one pass runs the four the
+  algorithm needs. All three share one block body (``_gp_tile``,
+  ``_dh_product``, ``_dw_product``).
 
 What a tile size decides is the kernel's arithmetic intensity as well as its
 per-tile fixed cost, because each kernel holds a block of one operand and
 streams the other past it from HBM once per block. Per byte streamed:
 - forward: the table once per row block, one product a tile:
   2*bn*bv*d FLOP over w_size*d*bv bytes = ``2 * bn / w_size`` FLOP/byte;
+- one-pass backward: per step the float32 w tile in, the dw tile in and the
+  dw tile out, three products: 6*bn*bv*d FLOP over 12*d*bv bytes =
+  ``bn / 2`` FLOP/byte whatever bv;
 - d(h): the table once per row block, two products: ``4 * bn / w_size``;
 - d(w,b): all rows once per vocab block, two products: ``4 * bv / h_size``.
 A v5e's ridge is 197 TFLOP/s over 819 GB/s = 240 FLOP/byte. By that count
@@ -39,11 +55,16 @@ the forward and d(h) want rows and d(w,b) wants vocabulary, so each kernel
 gets its own (bn, bv) from ``_fit_blocks`` (``_ROWS``, ``_COLS``, with what
 the timings added to the count), clamped to the shape and shrunk to the
 scoped VMEM there is: Mosaic's default 16 MiB where tiles at the ridge fit it
-(d = 1,024), else a budget of 40 MiB under the 48 MiB the call then asks for
-(the default holds no tile of a d = 2,048 head that leaves the ridge). The
-backward recomputes the logits tile in both its kernels (five products for
-the four the algorithm needs); the two share nothing but ``lse`` and ``g``,
-each padded to the kernel's own row blocks.
+(d = 1,024 in the forward and the two kernels), else a budget of 40 MiB
+under the 48 MiB the call then asks for (the default holds no tile of a
+d = 2,048 head that leaves the ridge). The one pass needs 1,024 rows a block
+to hide dw's way through HBM (512 FLOP/byte; at 512 rows it sits at the ridge
+and runs 12% slower: PERF.md, PR 30) and the VMEM for them, up to
+``_BWD_VMEM_BUDGET``; where they do not fit, where the table is not float32
+(dw accumulates in the table's own dtype) or where the vocabulary gives
+fewer than three blocks (``_MIN_COL_BLOCKS``), the two kernels run, which
+share nothing but ``lse`` and ``g``, each padded to the kernel's own row
+blocks.
 
 Nothing here scales with N*V, so the fused head trains batches and
 vocabularies whose logits cannot exist: V=262k (32 GiB of logits) and N=262k
@@ -141,6 +162,8 @@ def _shapes(h, w, bn, bv, w_vd: bool):
 _DEFAULT_VMEM_BUDGET = (16 << 20) - (256 << 10)
 _VMEM_BUDGET = 40 << 20
 _VMEM_LIMIT = _VMEM_BUDGET + (8 << 20)
+_BWD_VMEM_BUDGET = 64 << 20
+_BWD_VMEM_LIMIT = _BWD_VMEM_BUDGET + (8 << 20)
 
 # What Mosaic allocates beyond the pipeline buffers and scratch: values the
 # kernel body materializes in VMEM (the f32 [bn, bv] logits/probability plane
@@ -152,23 +175,30 @@ _VMEM_LIMIT = _VMEM_BUDGET + (8 << 20)
 # 2048}, bn in {256, 512, 1024}, bv in {256, 512, 1024, 2048} (396 compiles,
 # PR 28): never under the compiler's count there, over it by 0.6 to 1.5 MiB
 # in the mean and 5.4 at most; tests/test_chip_compile.py asks the compiler
-# itself.
+# itself. "bwd" (PR 30): over the least limit the one pass compiles under
+# (bisected to 0.25 MiB) at d in {512, 1024, 2048}, 1,024 rows whole and
+# ragged, bv in {256, 512}, a float32 table in both layouts, 52 shapes:
+# never under it, over it by 5.4 MiB in the mean with bfloat16 rows (a
+# [V, d] table takes 4 to 7 MiB more than a [d, V] one for the same tiles).
 _TEMP_BYTES = {
     ("fwd", 2): (3.5, 2.75, 0.75),
     ("dh", 2): (2.0, 3.25, 2.25),
     ("dw", 2): (4.0, 4.75, 0.0),
+    ("bwd", 2): (7.75, 5.0, 0.0),
     ("fwd", 4): (4.25, 0.5, 0.0),
     ("dh", 4): (5.75, 0.25, 0.25),
     ("dw", 4): (4.25, 2.5, 0.0),
+    ("bwd", 4): (10.25, 4.25, 3.5),
 }
 
 
 def _vmem_need(kernel: str, d: int, bn: int, bv: int, h_size: int,
                w_size: int) -> float:
-    """Scoped VMEM bytes one kernel ("fwd", "dh" or "dw") takes at these
-    tiles: double-buffered input/output tiles, scratch accumulators, and the
-    in-kernel temporaries of ``_TEMP_BYTES``. The whole-array lse/g planes
-    are not in it: the compiler's count does not move with the row count."""
+    """Scoped VMEM bytes one kernel ("fwd", "dh", "dw" or the one-pass
+    "bwd") takes at these tiles: double-buffered input/output tiles, scratch
+    accumulators, and the in-kernel temporaries of ``_TEMP_BYTES``. The
+    whole-array lse/g planes are not in it: the compiler's count does not
+    move with the row count."""
     h_tiles = 2 * bn * d * h_size
     w_tiles = 2 * d * bv * w_size
     if kernel == "fwd":
@@ -177,6 +207,13 @@ def _vmem_need(kernel: str, d: int, bn: int, bv: int, h_size: int,
     elif kernel == "dh":
         # output [bn, d] tile + f32 [bn, d] accumulator
         buffers = h_tiles + w_tiles + 2 * bn * d * h_size + 4 * bn * d
+    elif kernel == "bwd":
+        # dh's output tile (one buffer in what the fit counts: its block
+        # changes once a sweep) and f32 accumulator, the masked h tile of a
+        # ragged last row block (counted whether or not there is one), the
+        # dw tile in and out, the (8, bv) f32 db output tile
+        buffers = (h_tiles + w_tiles + 2 * bn * d * h_size + 4 * bn * d
+                   + 2 * 2 * d * bv * w_size + 2 * 8 * 4 * bv)
     else:
         # dw output tile + f32 dw accumulator + the [_LANES, bv] f32 db
         # accumulator + the (1, bv) db output tile
@@ -202,26 +239,40 @@ def _vmem_need(kernel: str, d: int, bn: int, bv: int, h_size: int,
 #   product's x (1 + 150 / bv): 12% more at 512 than at 1,024. dh and dw are
 #   flat from 512 up (four times the ridge in dw) and 1.5% slower at 256.
 _ROWS, _FLOOR_ROWS = 1024, 512
-_COLS = {"fwd": 1024, "dh": 512, "dw": 512}
+_COLS = {"fwd": 1024, "dh": 512, "dw": 512, "bwd": 512}
+# The one-pass backward reads the dw tile a row block after it was written,
+# n_v grid steps later, through Pallas's own pipeline: the fetch runs a step
+# ahead and the write-back a step behind, so with fewer than three vocab
+# blocks a fetch would overtake the write-back it depends on.
+_MIN_COL_BLOCKS = 3
 
 
 def _fit_blocks(kernel: str, n: int, d: int, v: int, h_size: int, w_size: int,
                 bn: int = None, bv: int = None):
-    """(bn, bv) of one kernel ("fwd", "dh" or "dw") at this shape: (``_ROWS``,
-    ``_COLS``), or the caller's ``bn`` / ``bv``, no larger than the rows and
-    the vocabulary there are, shrunk until ``_vmem_need`` fits: rows as far
-    as ``_FLOOR_ROWS`` under Mosaic's default limit if that is enough, else
-    under the raised one; below that vocabulary first (halving bv leaves the
-    table traffic as it is, halving bn doubles the forward's and dh's passes
-    over the table), to one lane tile each.
+    """(bn, bv) of one kernel ("fwd", "dh", "dw", or the one-pass backward
+    "bwd") at this shape: (``_ROWS``, ``_COLS``), or the caller's ``bn`` /
+    ``bv``, no larger than the rows and the vocabulary there are, shrunk
+    until ``_vmem_need`` fits: rows as far as ``_FLOOR_ROWS`` under Mosaic's
+    default limit if that is enough, else under the raised one; below that
+    vocabulary first (halving bv leaves the table traffic as it is, halving
+    bn doubles the forward's and dh's passes over the table), to one lane
+    tile each.
+
+    "bwd" keeps its rows (they are what hides dw's way through HBM: module
+    docstring) and shrinks vocabulary alone, and is ``None`` where one pass
+    is not to be had and "dh" and "dw" run instead: 1,024-row tiles fit no
+    limit, the table is not float32 (dw accumulates in the table's own
+    array), or the vocabulary gives fewer than ``_MIN_COL_BLOCKS`` blocks.
 
     The footprint scales with the model dim, the two dtypes and the tile
     plane, differently in each kernel (dw double-buffers a [d, bv] table tile
     on input AND output beside an f32 accumulator; dh holds three [bn, d] row
-    tiles), so each is fitted alone. Block size only changes tiling, not
-    results (beyond fp summation order)."""
+    tiles; bwd holds both), so each is fitted alone. Block size only changes
+    tiling, not results (beyond fp summation order)."""
     start = (min(bn or _ROWS, -(-n // _LANES) * _LANES),
              min(bv or _COLS[kernel], -(-v // _LANES) * _LANES))
+    one_pass = kernel == "bwd"
+    budget = _BWD_VMEM_BUDGET if one_pass else _VMEM_BUDGET
 
     def need(blocks):
         return _vmem_need(kernel, d, *blocks, h_size, w_size)
@@ -233,18 +284,23 @@ def _fit_blocks(kernel: str, n: int, d: int, v: int, h_size: int, w_size: int,
                 blocks[axis] = max(_LANES, blocks[axis] // 2)
         return tuple(blocks)
 
-    floor = (_FLOOR_ROWS, start[1])
+    row_floors = (start[0], start[0]) if one_pass else (_FLOOR_ROWS, _LANES)
+    floor = (row_floors[0], start[1])
     blocks = shrunk(start, _DEFAULT_VMEM_BUDGET, floor)
     if need(blocks) > _DEFAULT_VMEM_BUDGET:
-        blocks = shrunk(shrunk(start, _VMEM_BUDGET, floor), _VMEM_BUDGET,
-                        (_LANES, _LANES))
-    if need(blocks) > _VMEM_BUDGET:
+        blocks = shrunk(shrunk(start, budget, floor), budget,
+                        (row_floors[1], _LANES))
+    if one_pass:
+        fits = (need(blocks) <= budget and w_size == 4
+                and -(-v // blocks[1]) >= _MIN_COL_BLOCKS)
+        return blocks if fits else None
+    if need(blocks) > budget:
         # Refusing here names the cause; the compiler's RESOURCE_EXHAUSTED
         # names an allocation size and nothing the caller can change.
         raise ValueError(
             f"fused_softmax_xent: even the minimum {blocks} tiling of the "
             f"{kernel} kernel needs {need(blocks) / 2**20:.1f} MiB of VMEM "
-            f"(budget {_VMEM_BUDGET / 2**20:.0f} MiB) at d={d} with "
+            f"(budget {budget / 2**20:.0f} MiB) at d={d} with "
             f"{h_size}-byte activations and a {w_size}-byte table; use a "
             f"smaller model dim or the XLA head (fused_head=False)")
     return blocks
@@ -261,14 +317,18 @@ def _w_spec(d, bv, w_vd, index2):
 def _blocks(kernel, h, w, bn, bv, w_vd):
     """``_fit_blocks`` for ``kernel`` at the shapes of these arguments, and
     the compiler parameters its call takes: the raised scoped-VMEM limit
-    where the tiles need it."""
+    where the tiles need it. ``None`` where "bwd" has no one pass."""
     n, d = h.shape
     v = w.shape[0] if w_vd else w.shape[1]
     sizes = (h.dtype.itemsize, w.dtype.itemsize)
-    bn, bv = _fit_blocks(kernel, n, d, v, *sizes, bn, bv)
-    if _vmem_need(kernel, d, bn, bv, *sizes) <= _DEFAULT_VMEM_BUDGET:
-        return bn, bv, None
-    return bn, bv, pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
+    blocks = _fit_blocks(kernel, n, d, v, *sizes, bn, bv)
+    if blocks is None:
+        return None
+    need = _vmem_need(kernel, d, *blocks, *sizes)
+    if need <= _DEFAULT_VMEM_BUDGET:
+        return (*blocks, None)
+    limit = _VMEM_LIMIT if need <= _VMEM_BUDGET else _BWD_VMEM_LIMIT
+    return (*blocks, pltpu.CompilerParams(vmem_limit_bytes=limit))
 
 
 def _forward(h, w, b, bn, bv, interpret, w_vd):
@@ -301,8 +361,92 @@ def _forward(h, w, b, bn, bv, interpret, w_vd):
 
 # ------------------------------------------------------------------ backward
 
+def _gp_tile(h_ref, w_ref, b_ref, lse_ref, g_ref, w_vd: bool, ni, vi, bn: int,
+             bv: int, n: int, v: int, mask_rows: bool):
+    """(d(logits) of one [bn, bv] tile in float32, ``exp(logits - lse) * g``
+    on the recomputed logits, and the cast and masked w tile): what every
+    product of the backward takes. ``mask_rows``: the tile is contracted over
+    its rows (dw, db), so the ragged last row block's undefined rows must be
+    hard zeros (g pads to 0, but 0 * garbage-inf logits would be NaN)."""
+    logits, wt = _logits_tile(h_ref, w_ref, b_ref, w_vd, vi, bv, v)
+    gp = jnp.exp(logits - lse_ref[0, ni, :][:, None]) * g_ref[0, ni, :][:, None]
+    if mask_rows:
+        gp = _valid_rows(gp, ni, bn, n)
+    return gp, wt
+
+
+def _valid_rows(x, ni, bn: int, n: int):
+    """``x`` ([bn, ...], row block ``ni``) with the rows past ``n`` zeroed."""
+    row = ni * bn + jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    return jnp.where(row < n, x, jnp.zeros((), x.dtype))
+
+
+def _dh_product(gp, wt, w_vd: bool):
+    """[bn, d] f32: ``gp @ w_tile^T`` against the tile's stored layout."""
+    dims = (((1,), (0,)), ((), ())) if w_vd else (((1,), (1,)), ((), ()))
+    return jax.lax.dot_general(gp.astype(wt.dtype), wt, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _dw_product(rows, gp, w_vd: bool):
+    """The dw tile in float32 from the (masked) h tile ``rows`` [bn, d]:
+    ``gp^T @ rows`` as [bv, d] for a [V, d] table, ``rows^T @ gp`` as
+    [d, bv] for a [d, V] one."""
+    gph = gp.astype(rows.dtype)
+    operands = (gph, rows) if w_vd else (rows, gph)
+    return jax.lax.dot_general(*operands, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _bwd_kernel(h_ref, w_ref, b_ref, lse_ref, g_ref, dw_in_ref, dh_ref, dw_ref,
+                db_ref, dh_acc, *rows_ref, n_v: int, w_vd: bool, bn: int,
+                bv: int, n: int, v: int, interpret: bool):
+    """One pass: grid (row blocks, vocab blocks). The h tile and the f32 dh
+    accumulator stay for a sweep over the vocabulary; each step makes the
+    logits tile and d(logits) once and from it dh's term, the dw tile and
+    db's column sums. The dw tile is [bv, d] whatever the table's layout
+    (``_backward_one_pass``). dw accumulates through HBM: ``dw_in_ref`` is
+    the dw output itself (aliased), holding what the earlier row blocks left
+    in this tile. ``rows_ref``: where the last row block is ragged, the h
+    tile with its undefined rows zeroed, made once a row block."""
+    ni = pl.program_id(0)
+    vi = pl.program_id(1)
+    ragged = n % bn != 0
+    # Pallas's interpreter hands an aliased input over as a copy made before
+    # the first step and loads every output block with the array's current
+    # content, so there the output block is what holds the earlier row
+    # blocks' sum; on the chip an output block is only ever written.
+    left_ref = dw_ref if interpret else dw_in_ref
+    rows_ref = rows_ref[0] if ragged else h_ref
+
+    @pl.when(vi == 0)
+    def _init():
+        dh_acc[:] = jnp.zeros_like(dh_acc)
+        if ragged:
+            rows_ref[...] = _valid_rows(h_ref[...], ni, bn, n)
+
+    gp, wt = _gp_tile(h_ref, w_ref, b_ref, lse_ref, g_ref, w_vd, ni, vi, bn,
+                      bv, n, v, mask_rows=ragged)
+    dh_acc[:] += _dh_product(gp, wt, w_vd)
+    dw = _dw_product(rows_ref[...], gp, True)
+
+    @pl.when(ni == 0)
+    def _first():           # nothing to add to: what the buffer holds is not read
+        dw_ref[...] = dw
+
+    @pl.when(ni > 0)
+    def _add():
+        dw_ref[...] = left_ref[...] + dw
+
+    db_ref[0] = gp.sum(axis=0, keepdims=True)
+
+    @pl.when(vi == n_v - 1)
+    def _finish():
+        dh_ref[...] = dh_acc[:].astype(dh_ref.dtype)
+
+
 def _dh_kernel(h_ref, w_ref, b_ref, lse_ref, g_ref, dh_ref, acc_ref, *, n_v: int,
-               w_vd: bool, bv: int, v: int):
+               w_vd: bool, bn: int, bv: int, n: int, v: int):
     ni = pl.program_id(0)
     vi = pl.program_id(1)
 
@@ -310,13 +454,9 @@ def _dh_kernel(h_ref, w_ref, b_ref, lse_ref, g_ref, dh_ref, acc_ref, *, n_v: int
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    logits, wt = _logits_tile(h_ref, w_ref, b_ref, w_vd, vi, bv, v)
-    lse = lse_ref[0, ni, :]                                   # [bn]
-    gp = jnp.exp(logits - lse[:, None]) * g_ref[0, ni, :][:, None]  # [bn, bv]
-    dims = (((1,), (0,)), ((), ())) if w_vd else (((1,), (1,)), ((), ()))
-    acc_ref[:] += jax.lax.dot_general(
-        gp.astype(wt.dtype), wt, dims,
-        preferred_element_type=jnp.float32)                   # [bn, d]
+    gp, wt = _gp_tile(h_ref, w_ref, b_ref, lse_ref, g_ref, w_vd, ni, vi, bn,
+                      bv, n, v, mask_rows=False)
+    acc_ref[:] += _dh_product(gp, wt, w_vd)                   # [bn, d]
 
     @pl.when(vi == n_v - 1)
     def _finish():
@@ -334,25 +474,11 @@ def _dwdb_kernel(h_ref, w_ref, b_ref, lse_ref, g_ref, dw_ref, db_ref,
         dw_acc[:] = jnp.zeros_like(dw_acc)
         db_acc[:] = jnp.zeros_like(db_acc)
 
-    logits, _ = _logits_tile(h_ref, w_ref, b_ref, w_vd, vi, bv, v)  # [bn, bv]
-    lse = lse_ref[0, ni, :]
-    gp = jnp.exp(logits - lse[:, None]) * g_ref[0, ni, :][:, None]
     # The dw/db contraction runs over the row (token) axis, so the ragged last
-    # row block's undefined lanes must be hard zeros on BOTH operands: gp rows
-    # (g pads to 0, but 0 * garbage-inf logits would be NaN) and h rows.
-    row = ni * bn + jax.lax.broadcasted_iota(jnp.int32, gp.shape, 0)
-    gp = jnp.where(row < n, gp, 0.0)
-    hrow = ni * bn + jax.lax.broadcasted_iota(jnp.int32, h_ref.shape, 0)
-    ht = jnp.where(hrow < n, h_ref[...], jnp.zeros((), h_ref.dtype))
-    gph = gp.astype(ht.dtype)
-    if w_vd:
-        dw_acc[:] += jax.lax.dot_general(                     # [bv, d]
-            gph, ht, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-    else:
-        dw_acc[:] += jax.lax.dot_general(                     # [d, bv]
-            ht, gph, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    # row block's undefined rows must be hard zeros on BOTH operands.
+    gp, _ = _gp_tile(h_ref, w_ref, b_ref, lse_ref, g_ref, w_vd, ni, vi, bn,
+                     bv, n, v, mask_rows=True)
+    dw_acc[:] += _dw_product(_valid_rows(h_ref[...], ni, bn, n), gp, w_vd)
     db_acc[:, :] += jnp.broadcast_to(gp.sum(axis=0)[None, :], db_acc.shape)
 
     @pl.when(ni == n_n - 1)
@@ -373,18 +499,80 @@ def _row_planes(lse, g, n_n: int, bn: int):
 
 
 def _backward(h, w, b, lse, g, bn, bv, interpret, w_vd):
-    bvec = b.reshape(1, -1)
     fwd_bn, _, _ = _blocks("fwd", h, w, bn, bv, w_vd)
+    one_pass = _blocks("bwd", h, w, bn, bv, w_vd)
+    telemetry.gauge("xent.bwd.passes").set(1 if one_pass else 2)
+    if one_pass:
+        rows = one_pass[0]
+        grads = _backward_one_pass(h, w, b, lse, g, *one_pass, interpret, w_vd)
+    else:
+        rows = _blocks("dh", h, w, bn, bv, w_vd)[0]
+        grads = _backward_two_kernels(h, w, b, lse, g, bn, bv, interpret, w_vd)
+    n = h.shape[0]
+    telemetry.gauge("xent.table_passes").set(pl.cdiv(n, fwd_bn) + pl.cdiv(n, rows))
+    return grads
+
+
+def _backward_one_pass(h, w, b, lse, g, bn, bv, params, interpret, w_vd):
+    n, d, v, n_n, n_v = _shapes(h, w, bn, bv, w_vd)
+    telemetry.gauge("xent.bwd.block_rows").set(bn)
+    telemetry.gauge("xent.bwd.block_cols").set(bv)
+    row_plane = pl.BlockSpec((1, n_n, bn), lambda i, j: (0, 0, 0))
+    # dw is made as [V, d] whatever the table's layout and handed back
+    # transposed for a [d, V] table: the layout XLA keeps such a table's
+    # gradient in (the true-logit term scatters into its columns), so the
+    # transpose is a relabelling where the [d, V] tile was a copy.
+    dw_tile = pl.BlockSpec((bv, d), lambda i, j: (j, 0))
+    ragged_rows = [pltpu.VMEM((bn, d), h.dtype)] if n % bn else []
+    # Under its old name: the benchmark's reader sums xent_bwd_dh + xent_bwd_dw
+    # (benchmark/kernel_parts.py), as flash's one pass stayed flash_bwd_dkv.
+    dh, dw, db = named_pallas_call(
+        "xent_bwd_dw",
+        functools.partial(_bwd_kernel, n_v=n_v, w_vd=w_vd, bn=bn, bv=bv, n=n,
+                          v=v, interpret=interpret),
+        grid=(n_n, n_v),
+        in_specs=[
+            pl.BlockSpec((bn, d), lambda i, j: (i, 0)),
+            _w_spec(d, bv, w_vd, lambda i, j: j),
+            pl.BlockSpec((1, bv), lambda i, j: (0, j)),
+            row_plane, row_plane,
+            dw_tile,
+        ],
+        out_specs=(
+            pl.BlockSpec((bn, d), lambda i, j: (i, 0)),
+            dw_tile,
+            # a row block's own column sums, summed below: n_n * V floats
+            pl.BlockSpec((1, 1, bv), lambda i, j: (i, 0, j)),
+        ),
+        out_shape=(
+            jax.ShapeDtypeStruct((n, d), h.dtype),
+            jax.ShapeDtypeStruct((v, d), w.dtype),
+            jax.ShapeDtypeStruct((n_n, 1, v), jnp.float32),
+        ),
+        scratch_shapes=[pltpu.VMEM((bn, d), jnp.float32), *ragged_rows],
+        # dw is its own accumulator: the tile a step reads is the one the
+        # row block before wrote, n_v steps earlier (hence _MIN_COL_BLOCKS);
+        # what the array holds before the first row block is never read.
+        input_output_aliases={5: 1},
+        compiler_params=params,
+        interpret=interpret,
+    )(h, w, b.reshape(1, -1), *_row_planes(lse, g, n_n, bn),
+      jax.lax.empty((v, d), w.dtype))
+    return dh, dw if w_vd else dw.T, db.sum(axis=(0, 1))
+
+
+def _backward_two_kernels(h, w, b, lse, g, bn, bv, interpret, w_vd):
+    bvec = b.reshape(1, -1)
 
     # d(h): rows decide how often the table is streamed.
     bn_h, bv_h, params = _blocks("dh", h, w, bn, bv, w_vd)
     n, d, v, n_n, n_v = _shapes(h, w, bn_h, bv_h, w_vd)
     telemetry.gauge("xent.bwd.dh.block_rows").set(bn_h)
     telemetry.gauge("xent.bwd.dh.block_cols").set(bv_h)
-    telemetry.gauge("xent.table_passes").set(pl.cdiv(n, fwd_bn) + n_n)
     dh = named_pallas_call(
         "xent_bwd_dh",
-        functools.partial(_dh_kernel, n_v=n_v, w_vd=w_vd, bv=bv_h, v=v),
+        functools.partial(_dh_kernel, n_v=n_v, w_vd=w_vd, bn=bn_h, bv=bv_h,
+                          n=n, v=v),
         grid=(n_n, n_v),
         in_specs=[
             pl.BlockSpec((bn_h, d), lambda i, j: (i, 0)),

@@ -146,35 +146,40 @@ def test_flash_ring_backward_step_compiles(chip):
 _XENT_ROWS = 2048
 
 
-@pytest.mark.parametrize("d,vocab,layout,rows_dtype,table_dtype,tiles", [
+@pytest.mark.parametrize("d,vocab,layout,rows_dtype,table_dtype,tiles,one_pass", [
     # (bn, bv) of forward, dh, dw: what ``_fit_blocks`` picks for each, under
     # Mosaic's default 16 MiB where tiles this large fit it and under the
-    # 48 MiB the call asks for where they do not
+    # 48 MiB the call asks for where they do not; and of the one-pass
+    # backward, which runs in their place wherever the rule gives it (None:
+    # the two kernels run)
     (512, 32_000, "dv", jnp.bfloat16, jnp.float32,   # the flagship head
-     ((1024, 1024), (1024, 512), (1024, 512))),
+     ((1024, 1024), (1024, 512), (1024, 512)), (1024, 512)),
     (1024, 32_000, "vd", jnp.bfloat16, jnp.float32,
-     ((512, 1024), (512, 512), (512, 512))),
+     ((512, 1024), (512, 512), (512, 512)), (1024, 512)),
     # The two that libtpu 0.0.34 refused at (512, 1024) tiles (18.39M and
     # 16.73M scoped against 16M) until _fit_blocks counted the in-kernel
-    # temporaries.
+    # temporaries. A bfloat16 table: dw cannot accumulate in it.
     (1024, 32_000, "dv", jnp.bfloat16, jnp.bfloat16,
-     ((512, 1024), (512, 512), (512, 512))),
+     ((512, 1024), (512, 512), (512, 512)), None),
     (1024, 50_257, "vd", jnp.bfloat16, jnp.bfloat16,
-     ((512, 1024), (512, 512), (512, 512))),
+     ((512, 1024), (512, 512), (512, 512)), None),
     (2048, 50_304, "dv", jnp.bfloat16, jnp.float32,  # olmoe-pretrain-4k's untied head
-     ((1024, 1024), (512, 512), (1024, 512))),
+     ((1024, 1024), (512, 512), (1024, 512)), (1024, 512)),
     # float32 rows at d 2,048: the forward alone was refused under 16 MiB
     (2048, 50_304, "dv", jnp.float32, jnp.float32,
-     ((1024, 1024), (512, 512), (512, 512))),
+     ((1024, 1024), (512, 512), (512, 512)), (1024, 128)),
+    (2048, 25_024, "dv", jnp.bfloat16, jnp.float32,  # trinity-pretrain-8k's sliced head
+     ((1024, 1024), (512, 512), (1024, 512)), (1024, 512)),
 ], ids=["flagship-d512-dv-f32", "d1024-vd-f32", "d1024-dv-bf16",
-        "d1024-vd-bf16-V50257", "olmoe-d2048-dv-f32", "d2048-f32-rows"])
+        "d1024-vd-bf16-V50257", "olmoe-d2048-dv-f32", "d2048-f32-rows",
+        "trinity-d2048-dv-f32"])
 def test_fused_xent_fwd_bwd_compiles(chip, d, vocab, layout, rows_dtype,
-                                     table_dtype, tiles):
+                                     table_dtype, tiles, one_pass):
     table = (vocab, d) if layout == "vd" else (d, vocab)
-    assert tuple(fx._fit_blocks(kernel, _XENT_ROWS, d, vocab,
-                                jnp.dtype(rows_dtype).itemsize,
-                                jnp.dtype(table_dtype).itemsize)
+    sizes = (jnp.dtype(rows_dtype).itemsize, jnp.dtype(table_dtype).itemsize)
+    assert tuple(fx._fit_blocks(kernel, _XENT_ROWS, d, vocab, *sizes)
                  for kernel in ("fwd", "dh", "dw")) == tiles
+    assert fx._fit_blocks("bwd", _XENT_ROWS, d, vocab, *sizes) == one_pass
 
     def loss(h, w, targets):
         return fx.fused_softmax_xent(h, w, targets, w_layout=layout).mean()
@@ -183,8 +188,39 @@ def test_fused_xent_fwd_bwd_compiles(chip, d, vocab, layout, rows_dtype,
                           ((_XENT_ROWS, d), rows_dtype), (table, table_dtype),
                           ((_XENT_ROWS,), jnp.int32))
     assert "tpu_custom_call" in text
-    for name in ("xent_fwd", "xent_bwd_dh", "xent_bwd_dw"):
-        assert name in text
+    # the one pass runs under dw's name; dh's is the two-kernel path's alone
+    assert "xent_fwd" in text and "xent_bwd_dw" in text
+    assert ("xent_bwd_dh" in text) == (one_pass is None)
+
+
+@pytest.mark.parametrize("rows,d,vocab,layout", [
+    (16_384, 2048, 50_304, "dv"),        # olmoe-pretrain-4k
+    (8_192, 1024, 50_257, "vd"),         # gpt2m-pretrain-1k, gpt2m-dp4-sync
+    (8_192, 2048, 25_024, "dv"),         # trinity-pretrain-8k
+    (1_000, 1024, 50_257, "vd"),         # one ragged row block: the masked h tile
+], ids=["olmoe", "gpt2", "trinity", "ragged-rows"])
+def test_one_pass_backward_fits_the_vmem_its_model_counts(chip, rows, d, vocab,
+                                                          layout):
+    """``_vmem_need("bwd", ...)`` is never under the compiler's count: at the
+    cells' calls the one pass compiles under a scoped limit of exactly what
+    the model says its tiles take (bfloat16 rows, a float32 table)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    w_vd = layout == "vd"
+    bn, bv = fx._fit_blocks("bwd", rows, d, vocab, 2, 4)
+    need = int(fx._vmem_need("bwd", d, bn, bv, 2, 4))
+    assert need <= fx._BWD_VMEM_BUDGET
+
+    def backward(h, w, b, lse, g):
+        return fx._backward_one_pass(
+            h, w, b, lse, g, bn, bv,
+            pltpu.CompilerParams(vmem_limit_bytes=need), False, w_vd)[:3]
+
+    text = _compiled_text(
+        backward, chip, ((rows, d), jnp.bfloat16),
+        ((vocab, d) if w_vd else (d, vocab), jnp.float32),
+        ((vocab,), jnp.float32), ((rows,), jnp.float32), ((rows,), jnp.float32))
+    assert "xent_bwd_dw" in text and "xent_bwd_dh" not in text
 
 
 @pytest.mark.parametrize("k,n", [(2048, 1024), (1024, 2048)],

@@ -47,16 +47,16 @@ def _telemetry_reset():
 
 # ------------------------------------------------------------ lowered steps
 
-def _lm_step_lowered(zero=0, accum=2):
+def _lm_step_lowered(zero=0, accum=2, vocab=203):
     """The tiny flagship step (flash attention, fused head, accumulation)
     through ``AutoDist`` on the 8-device mesh, lowered and not compiled."""
     cfg = transformer_lm.TransformerLMConfig(
-        vocab_size=203, d_model=32, n_heads=2, n_layers=1, d_ff=64,
+        vocab_size=vocab, d_model=32, n_heads=2, n_layers=1, d_ff=64,
         max_len=16, dtype=jnp.float32, attention_impl="flash",
         fused_head=True)
     model, params = transformer_lm.init_params(cfg, rng=jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)
-    batch = {"tokens": rng.integers(0, 203, (16, 17)).astype(np.int32)}
+    batch = {"tokens": rng.integers(0, vocab, (16, 17)).astype(np.int32)}
     runner = AutoDist(strategy_builder=AllReduce()).create_distributed_session(
         transformer_lm.make_loss_fn(model), params, optax.adam(1e-3),
         example_batch=batch, accumulation_steps=accum, zero=zero)
@@ -89,6 +89,18 @@ def test_lowered_step_names_kernels_and_phases(backward, monkeypatch):
     # ZeRO's constrain_update is the reduction under AllReduce (the implicit
     # lowering leaves the all-reduce to XLA, so it has no scope of its own).
     assert _scopes(text, PHASES) == set(PHASES)
+
+
+@pytest.mark.parametrize("vocab,head", [(1100, "one-pass"), (203, "two-kernels")])
+def test_lowered_step_names_the_head_backward_in_both_paths(vocab, head):
+    """The fused head's backward is one kernel, ``xent_bwd_dw`` (dh, dw and
+    db from one logits tile), from three vocab blocks up (1,100 words in
+    blocks of 512); ``xent_bwd_dh`` is the first of the two kernels a
+    smaller vocabulary keeps (203 words: one block)."""
+    text = _lm_step_lowered(vocab=vocab).as_text(debug_info=True)
+    head_kernels = {k for k in named_call.KERNEL_NAMES if k.startswith("xent_")}
+    assert _scopes(text, head_kernels) == head_kernels - (
+        {"xent_bwd_dh"} if head == "one-pass" else set())
 
 
 def test_explicit_gradient_sync_sits_under_its_scope_inside_step_grad():

@@ -67,11 +67,13 @@ def test_grads_match_reference_f32():
 
 _RIDGE = 240.0   # FLOP / byte of a v5e: 197 TFLOP/s over 819 GB/s
 
-# (rows, d, vocab, activation bytes, table bytes): the two cells' calls, and
-# the shapes the fitter was first written against.
+# (rows, d, vocab, activation bytes, table bytes): the four cells' calls (the
+# two GPT-2 cells make the same one), and the shapes the fitter was first
+# written against.
 _FIT_SHAPES = {
     "olmoe-cell": (16_384, 2048, 50_304, 2, 4),
     "gpt2-cell": (8_192, 1024, 50_257, 2, 4),
+    "trinity-cell": (8_192, 2048, 25_024, 2, 4),
     "flagship-d512": (98_304, 512, 32_000, 2, 4),
     "d768-f32-table": (2_048, 768, 32_000, 2, 4),
     "d1024-bf16-table": (2_048, 1024, 32_000, 2, 2),
@@ -83,22 +85,50 @@ _FIT_SHAPES = {
 
 def _intensity(kernel, bn, bv, h_size, w_size):
     """FLOP per byte streamed from HBM (module docstring of ops/fused_xent)."""
+    # the one pass streams the w tile in and the dw tile in and out for
+    # three products
     return {"fwd": 2 * bn / w_size, "dh": 4 * bn / w_size,
-            "dw": 4 * bv / h_size}[kernel]
+            "dw": 4 * bv / h_size, "bwd": 2 * bn / w_size}[kernel]
 
 
-@pytest.mark.parametrize("kernel", ["fwd", "dh", "dw"])
+# the one-pass backward's tiles, or None where the two kernels run
+_ONE_PASS = {
+    "olmoe-cell": (1024, 512),
+    "gpt2-cell": (1024, 512),
+    "trinity-cell": (1024, 512),
+    "flagship-d512": (1024, 512),
+    "d768-f32-table": (1024, 512),
+    "d1024-bf16-table": None,      # dw accumulates in the table's own array
+    "d2048-f32-rows": (1024, 128),
+    "lm1b-vocab": (1024, 512),
+    "ragged-few-rows": (1024, 512),
+}
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "dh", "dw", "bwd"])
 @pytest.mark.parametrize("shape", list(_FIT_SHAPES))
 def test_fit_blocks_rule(shape, kernel):
     """Each kernel its own tiles from the shape: whole lane tiles, no larger
     than the rows and the vocabulary there are, that fit Mosaic's default
     limit (then no more is asked for) or the raised budget; at least at the
     chip's ridge in FLOP a streamed byte, and at twice it wherever the next
-    larger block of the streamed axis would fit the raised budget too."""
-    from autodist_tpu.ops.fused_xent import (_DEFAULT_VMEM_BUDGET, _VMEM_BUDGET,
+    larger block of the streamed axis would fit the raised budget too. The
+    one-pass backward ("bwd"): whether the shape gets it, and at twice the
+    ridge wherever it does (dw's way through HBM has to hide)."""
+    from autodist_tpu.ops.fused_xent import (_BWD_VMEM_BUDGET,
+                                             _DEFAULT_VMEM_BUDGET, _VMEM_BUDGET,
                                              _fit_blocks, _vmem_need)
 
     n, d, v, h_size, w_size = _FIT_SHAPES[shape]
+    if kernel == "bwd":
+        blocks = _fit_blocks("bwd", n, d, v, h_size, w_size)
+        assert blocks == _ONE_PASS[shape]
+        if blocks is not None:
+            bn, bv = blocks
+            assert bn % 128 == 0 and bv % 128 == 0 and -(-v // bv) >= 3
+            assert _vmem_need("bwd", d, bn, bv, h_size, w_size) <= _BWD_VMEM_BUDGET
+            assert _intensity("bwd", bn, bv, h_size, w_size) >= 2 * _RIDGE
+        return
     bn, bv = _fit_blocks(kernel, n, d, v, h_size, w_size)
     need = _vmem_need(kernel, d, bn, bv, h_size, w_size)
     assert bn % 128 == 0 and bv % 128 == 0
@@ -111,7 +141,7 @@ def test_fit_blocks_rule(shape, kernel):
         assert (grown[0] > n or grown[1] > v
                 or _vmem_need(kernel, d, *grown, h_size, w_size) > _VMEM_BUDGET)
     # the kernels of one call no longer share tiles where their needs differ
-    if shape == "olmoe-cell":
+    if shape in ("olmoe-cell", "trinity-cell"):
         assert need > _DEFAULT_VMEM_BUDGET
         assert (bn, bv) == {"fwd": (1024, 1024), "dh": (512, 512),
                             "dw": (1024, 512)}[kernel]
@@ -145,7 +175,7 @@ def test_kernels_on_different_blocks_stay_value_exact(monkeypatch):
     padding rows. Values and gradients against the f32 reference."""
     from autodist_tpu.ops import fused_xent as fx
 
-    tiles = {"fwd": (128, 256), "dh": (256, 128), "dw": (128, 384)}
+    tiles = {"fwd": (128, 256), "dh": (256, 128), "dw": (128, 384), "bwd": None}
     monkeypatch.setattr(fx, "_fit_blocks", lambda kernel, *a, **kw: tiles[kernel])
     h, w, b = _data(300, 64, 500, jnp.float32, seed=12)
     b = b.at[7].set(95.0)
@@ -164,9 +194,20 @@ def test_kernels_on_different_blocks_stay_value_exact(monkeypatch):
     np.testing.assert_allclose(g_vd[1], gr[1].T, **_f32_tol(rtol=2e-4, atol=2e-5))
 
 
-def test_tracing_the_head_sets_its_block_gauges():
-    """``xent.*``: each kernel's tiles and the table's passes a call (row
-    blocks of forward + dh), set when the op is traced; nothing executes."""
+@pytest.mark.parametrize("table_dtype,expected", [
+    (jnp.float32, {"fwd.block_rows": 1024, "fwd.block_cols": 1024,
+                   "bwd.passes": 1, "bwd.block_rows": 1024,
+                   "bwd.block_cols": 512, "table_passes": 16 + 16}),
+    (jnp.bfloat16, {"fwd.block_rows": 1024, "fwd.block_cols": 1024,
+                    "bwd.passes": 2, "bwd.dh.block_rows": 1024,
+                    "bwd.dh.block_cols": 512, "bwd.dw.block_rows": 1024,
+                    "bwd.dw.block_cols": 512, "table_passes": 16 + 16}),
+], ids=["one-pass", "two-kernels"])
+def test_tracing_the_head_sets_its_block_gauges(table_dtype, expected):
+    """``xent.*``: which backward runs (1 = one pass, 2 = dh and dw), its
+    tiles (the one pass's, or each of the two kernels' own), the forward's,
+    and the table's passes a call (row blocks of forward + backward), set
+    when the op is traced; nothing executes."""
     from autodist_tpu import telemetry
     from autodist_tpu.ops.fused_xent import fused_softmax_xent
 
@@ -174,16 +215,11 @@ def test_tracing_the_head_sets_its_block_gauges():
     jax.eval_shape(
         jax.grad(lambda h, w, t: fused_softmax_xent(h, w, t).mean(),
                  argnums=(0, 1)),
-        struct((16_384, 2048), jnp.bfloat16), struct((2048, 50_304), jnp.float32),
-        struct((16_384,), jnp.int32))        # olmoe-pretrain-4k's call
-    got = {name: telemetry.gauge(f"xent.{name}").value for name in (
-        "fwd.block_rows", "fwd.block_cols", "bwd.dh.block_rows",
-        "bwd.dh.block_cols", "bwd.dw.block_rows", "bwd.dw.block_cols",
-        "table_passes")}
-    assert got == {"fwd.block_rows": 1024, "fwd.block_cols": 1024,
-                   "bwd.dh.block_rows": 512, "bwd.dh.block_cols": 512,
-                   "bwd.dw.block_rows": 1024, "bwd.dw.block_cols": 512,
-                   "table_passes": 16 + 32}
+        struct((16_384, 2048), jnp.bfloat16), struct((2048, 50_304), table_dtype),
+        struct((16_384,), jnp.int32))        # olmoe-pretrain-4k's call, and
+    # the same under a bfloat16 table, which dw cannot accumulate in
+    got = {name: telemetry.gauge(f"xent.{name}").value for name in expected}
+    assert got == expected
 
 
 def test_shrunken_blocks_stay_value_exact(monkeypatch):
@@ -301,3 +337,49 @@ def test_jit_and_value_under_jit():
     h, w, b = _data(128, 64, 256, jnp.float32, seed=7)
     f = jax.jit(lambda h, w, b: matmul_logsumexp(h, w, b, 64, 128))
     np.testing.assert_allclose(f(h, w, b), _ref_lse(h, w, b), rtol=1e-5, atol=1e-5)
+
+
+def _grads(h, w, b, coef, layout, n_block, v_block):
+    return jax.grad(lambda h, w, b: jnp.sum(matmul_logsumexp(
+        h, w, b, n_block, v_block, None, layout) * coef), argnums=(0, 1, 2))(h, w, b)
+
+
+@pytest.mark.parametrize("rows", ["bf16-ragged", "f32-whole"])
+@pytest.mark.parametrize("n_v", [1, 2, 3, 5])
+@pytest.mark.parametrize("n_n", [1, 2, 3])
+@pytest.mark.parametrize("layout", ["dv", "vd"])
+def test_one_pass_backward_matches_the_two_kernels(monkeypatch, layout, n_n, n_v,
+                                                   rows):
+    """dh, dw, db of the one pass against the two kernels, value for value:
+    both table layouts, bias on with an entry past exp's range against the
+    padding rows, one to three row blocks (dw summed across them through the
+    aliased buffer) and one to five vocab blocks, the last of each ragged or
+    whole. Below three vocab blocks the rule itself keeps the two kernels
+    (``xent.bwd.passes``): there a tile would be read back before the
+    pipeline had written it."""
+    from autodist_tpu import telemetry
+    from autodist_tpu.ops import fused_xent as fx
+
+    ragged = rows == "bf16-ragged"
+    n, v = n_n * 64 - 14 * ragged, n_v * 128 - 33 * ragged
+    h, w, b = _data(n, 64, v, jnp.float32, seed=13)
+    h = h.astype(jnp.bfloat16 if ragged else jnp.float32)
+    b = b.at[5].set(95.0)
+    coef = jnp.asarray(np.random.RandomState(14).randn(n), jnp.float32)
+    w = w.T if layout == "vd" else w
+
+    got = _grads(h, w, b, coef, layout, 64, 128)
+    assert telemetry.gauge("xent.bwd.passes").value == (1 if n_v >= 3 else 2)
+    rule = fx._fit_blocks
+    monkeypatch.setattr(fx, "_fit_blocks", lambda kernel, *a, **kw: (
+        None if kernel == "bwd" else rule(kernel, *a, **kw)))
+    want = _grads(h, w, b, coef, layout, 64, 128)
+    assert telemetry.gauge("xent.bwd.passes").value == 2
+    for a, e in zip(got, want):
+        assert a.dtype == e.dtype and np.isfinite(np.asarray(a, np.float32)).all()
+        # the same float32 tile products summed in the same order; dh's
+        # bfloat16 rounding may fall the other way on a last-bit difference
+        tol = dict(rtol=1e-2, atol=1e-6) if a.dtype == jnp.bfloat16 else \
+            _f32_tol(rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(e, np.float32), **tol)

@@ -11,12 +11,20 @@ kernels' own device time read from the trace's ``XLA Ops`` events named
 (summed, and each under ``parts``) / ``xent_fwd`` / ``xent_bwd_dh`` /
 ``xent_bwd_dw``; the host clock around the whole call, transposes included,
 is printed beside it. One JSON line a measurement on standard output.
+``xent-bwd*`` shapes time the head's whole backward twice, as the rule runs
+it (one pass under the name ``xent_bwd_dw`` where the rule gives one) and as
+the two kernels, side by side; ``--check`` compares dh, dw and db of the two
+on the chip instead of timing them (the one pass reads dw back through an
+aliased buffer, in an order only the chip's own pipeline shows), and exits
+1 where they differ.
 
     python tools/flash_forward_timing.py                     # every shape
     python tools/flash_forward_timing.py --shapes cell,l4096,bwd-cell,bwd-olmoe
     python tools/flash_forward_timing.py --blocks 512,512,128 --blocks 256,1024,256
     python tools/flash_forward_timing.py --bwd-blocks 512,1024
     python tools/flash_forward_timing.py --shapes xent-fwd,xent-dh,xent-dw --xent-blocks 1024,512
+    python tools/flash_forward_timing.py --shapes xent-bwd,xent-bwd-gpt2,xent-bwd-trinity --xent-blocks 1024,256
+    python tools/flash_forward_timing.py --shapes xent-bwd,xent-bwd-gpt2,xent-bwd-trinity,xent-bwd-v3 --check
     python tools/flash_forward_timing.py --root .archive_check/parent   # another checkout
 
 ``--blocks bq,bk,sub`` overrides the forward's choice, ``--bwd-blocks bq,bk``
@@ -72,21 +80,33 @@ SHAPES = {
 BANDS = {name: (2048 if "-win" in name else None, 32 if name.endswith("-rep") else 4)
          for name in SHAPES if "trinity" in name}
 # the fused head, bfloat16 rows against a float32 table: name -> (N, D, V,
-# table layout, kind), at olmoe-pretrain-4k's call and at gpt2m-*'s, a chip
-# and call. ``xent-dh`` and ``xent-dw`` both run the whole backward and read
-# their own kernel's events.
+# table layout, kind), at olmoe-pretrain-4k's call, at gpt2m-*'s, a chip and
+# call, and at trinity-pretrain-8k's. ``xent-dh`` and ``xent-dw`` both run
+# the whole backward and read their own kernel's events; ``xent-bwd`` reads
+# both names, as the rule runs the backward and as the two kernels.
+# ``xent-bwd-v3``: three vocab blocks of 512, the fewest the one pass takes
+# (the block a row block reads back was written three grid steps before).
 HEAD_SHAPES = {
     f"xent-{kernel}{cell}": (*shape, f"xent-{kernel}")
     for cell, shape in (("", (16384, 2048, 50304, "dv")),
-                        ("-gpt2", (8192, 1024, 50257, "vd")))
-    for kernel in ("fwd", "dh", "dw")}
+                        ("-gpt2", (8192, 1024, 50257, "vd")),
+                        ("-trinity", (8192, 2048, 25024, "dv")),
+                        ("-v3", (4096, 1024, 1536, "dv")))
+    for kernel in ("fwd", "dh", "dw", "bwd")
+    if kernel == "bwd" or cell in ("", "-gpt2")}
 KERNELS = {"fwd": ("flash_fwd",), "carry": ("flash_carry",),
            "bwd": ("flash_bwd_dkv", "flash_bwd_dq"),
            "ring-bwd": ("flash_bwd_dkv", "flash_bwd_dq"),
            "xent-fwd": ("xent_fwd",), "xent-dh": ("xent_bwd_dh",),
-           "xent-dw": ("xent_bwd_dw",)}
+           "xent-dw": ("xent_bwd_dw",),
+           "xent-bwd": ("xent_bwd_dh", "xent_bwd_dw")}
 GAUGES = {"fwd": "flash.fwd.", "bwd": "flash.bwd.",
-          **{kind: "xent." for kind in ("xent-fwd", "xent-dh", "xent-dw")}}
+          **{kind: "xent." for kind in ("xent-fwd", "xent-dh", "xent-dw",
+                                        "xent-bwd")}}
+# relative L2 distance up to which the one pass agrees with the two kernels:
+# dh is rounded to bfloat16 from sums taken in another order over the
+# vocabulary, dw and db are float32 sums of the same tile products
+CHECK_TOLERANCE = {"dh": 4e-3, "dw": 1e-4, "db": 1e-4}
 
 
 def kind_of(name: str) -> str:
@@ -163,9 +183,11 @@ def build(fa, name, blocks=None):
     return fn, args
 
 
-def build_head(fx, name, blocks=None):
+def build_head(fx, name, blocks=None, two_kernels=False):
     """The head's forward, or forward and backward (each kernel is read
-    from the trace by its own name)."""
+    from the trace by its own name). ``two_kernels``: the backward as dh and
+    dw whatever the rule says of one pass. The rule is read when the
+    function is traced, so it stays overridden until the next build."""
     import inspect
 
     import jax
@@ -178,9 +200,15 @@ def build_head(fx, name, blocks=None):
         raise SystemExit("this checkout's _fit_blocks fits no kernel alone: "
                          "a fixed default")
     which = kind.split("-")[1]
-    fx._fit_blocks = rule if blocks is None else (
-        lambda kernel, *a, **kw: blocks if kernel == which
-        else rule(kernel, *a, **kw))
+
+    def fit(kernel, *a, **kw):
+        if kernel == "bwd" and (two_kernels or which in ("dh", "dw")):
+            return None     # xent-dh / xent-dw time the two kernels' own
+        if kernel == which and blocks is not None:
+            return blocks
+        return rule(kernel, *a, **kw)
+
+    fx._fit_blocks = fit if chooses else rule
     start = (None, None) if chooses else (fx.DEFAULT_N_BLOCK, fx.DEFAULT_V_BLOCK)
     w_vd = layout == "vd"
     keys = jax.random.split(jax.random.PRNGKey(0), 3)
@@ -198,12 +226,39 @@ def build_head(fx, name, blocks=None):
         h, w, b, forward(h, w, b), g, *start, False, w_vd)), (h, w, b, g))
 
 
-def measure(modules, name, blocks, calls):
+def check_head(fx, name, blocks):
+    """dh, dw, db of the backward as the rule runs it against the two
+    kernels, on the chip: relative L2 distance of each, and whether all are
+    within ``CHECK_TOLERANCE``."""
+    import jax
+    import jax.numpy as jnp
+
+    from autodist_tpu import telemetry
+
+    fn, args = build_head(fx, name, blocks)
+    got = jax.block_until_ready(fn(*args))
+    passes = telemetry.snapshot().get("xent.bwd.passes")
+    fn, args = build_head(fx, name, two_kernels=True)
+    want = jax.block_until_ready(fn(*args))
+
+    def distance(a, b):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+
+    record = {"shape": name, "blocks": blocks, "check": True, "passes": passes,
+              **{part: distance(a, b)
+                 for part, a, b in zip(CHECK_TOLERANCE, got, want)}}
+    record["agree"] = all(record[part] <= limit     # a NaN agrees with nothing
+                          for part, limit in CHECK_TOLERANCE.items())
+    return record
+
+
+def measure(modules, name, blocks, calls, two_kernels=False):
     import jax
 
     kind = kind_of(name)
     if name in HEAD_SHAPES:
-        fn, args = build_head(modules["fused_xent"], name, blocks)
+        fn, args = build_head(modules["fused_xent"], name, blocks, two_kernels)
     else:
         fa = modules["flash_attention"]
         if blocks is not None:
@@ -232,6 +287,7 @@ def measure(modules, name, blocks, calls):
     if not parts:
         raise SystemExit(f"{name}: the trace holds no {KERNELS[kind]} event")
     record = {"shape": name, "blocks": blocks,
+              **({"two_kernels": two_kernels} if kind == "xent-bwd" else {}),
               "events": sum(len(ms) for ms in parts.values()),
               "kernel_ms_median": sum(ms[len(ms) // 2] for ms in parts.values()),
               "kernel_ms_min": sum(ms[0] for ms in parts.values()),
@@ -261,6 +317,9 @@ def main(argv=None):
     parser.add_argument("--resident-dq-bytes", type=int, default=None,
                         help="override _RESIDENT_DQ_BYTES: the float32 dQ of a "
                              "(batch, head) up to which the backward is one pass")
+    parser.add_argument("--check", action="store_true",
+                        help="xent-bwd* shapes: compare the one pass's dh, dw, "
+                             "db with the two kernels' instead of timing them")
     parser.add_argument("--calls", type=int, default=20)
     args = parser.parse_args(argv)
 
@@ -278,13 +337,24 @@ def main(argv=None):
         return [tuple(int(x) for x in b.split(",")) for b in flags] or [None]
 
     overrides = {"fwd": plans(args.blocks), "bwd": plans(args.bwd_blocks),
-                 **dict.fromkeys(("xent-fwd", "xent-dh", "xent-dw"),
+                 **dict.fromkeys(("xent-fwd", "xent-dh", "xent-dw", "xent-bwd"),
                                  plans(args.xent_blocks))}
+    def emit(record):
+        print(json.dumps({"root": args.root, **record}), flush=True)
+        return record.get("agree", True)
+
+    agree = True
     for name in args.shapes.split(","):
-        for blocks in overrides.get(kind_of(name), [None]):
-            print(json.dumps({"root": args.root, **measure(modules, name, blocks,
-                                                           args.calls)}),
-                  flush=True)
+        kind = kind_of(name)
+        for blocks in overrides.get(kind, [None]):
+            if args.check and kind == "xent-bwd":
+                agree &= emit(check_head(modules["fused_xent"], name, blocks))
+            else:
+                emit(measure(modules, name, blocks, args.calls))
+        if kind == "xent-bwd" and not args.check:   # beside it, the two kernels
+            emit(measure(modules, name, None, args.calls, two_kernels=True))
+    if not agree:
+        raise SystemExit(1)
 
 
 if __name__ == "__main__":
