@@ -44,7 +44,8 @@ computes alike.
 the loss carries the term ``sum_e (b_e - stop_gradient(b_e)) .
 stop_gradient(c_e - mean c) / T`` a layer: its value is exactly zero (the
 loss is the cross-entropy and nothing else) and ``d loss / d b_e`` is the
-layer's load error; :func:`make_optimizer` gives those leaves sign-SGD at
+layer's load error; :func:`make_optimizer` (``models/moe.py``
+``balanced_optimizer``) gives those leaves sign-SGD at
 ``load_balance_coeff`` with the mean removed (``optax.multi_transform``; every
 other leaf AdamW), which is the rule above, through ``AutoDist(...).function``
 and ``training.train`` unchanged. Departure from the published rule: under
@@ -68,12 +69,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from autodist_tpu.models.common import RMSNorm, rope
-from autodist_tpu.models.moe import routed_experts, sigmoid_topk_route
+from autodist_tpu.models.moe import (  # noqa: F401 — the mixture's, under this family's names
+    GatedMLP, _dense, _INIT, balance_expert_bias,
+    balanced_optimizer as make_optimizer, expert_loads, sigmoid_routed_share,
+    sigmoid_topk_route, sown_loads)
 from autodist_tpu.models.transformer_lm import (  # noqa: F401 — synthetic_batch re-exported
     dot_product_attention, synthetic_batch)
 
 SLIDING, FULL = "sliding_attention", "full_attention"
-_INIT = nn.initializers.normal(0.02)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -141,11 +144,6 @@ def band_mask(length: int, window: Optional[int], dtype) -> jax.Array:
     return jnp.where(visible, jnp.zeros((), dtype), jnp.full((), -1e9, dtype))
 
 
-def _dense(features: int, dtype, name: str) -> nn.Dense:
-    return nn.Dense(features, use_bias=False, dtype=dtype,
-                    param_dtype=jnp.float32, kernel_init=_INIT, name=name)
-
-
 class GatedAttention(nn.Module):
     """Causal attention of one layer kind: RMSNorm on q and k per head, RoPE
     on a sliding layer only, ``H`` query heads over ``H_kv`` KV heads, the
@@ -184,67 +182,30 @@ class GatedAttention(nn.Module):
         return _dense(cfg.d_model, cfg.dtype, "out")(ctx)
 
 
-class GatedMLP(nn.Module):
-    """``W_down(silu(W_gate h) * W_up h)``: the dense layers' MLP and the
-    shared expert."""
-    width: int
-    dtype: Any
-
-    @nn.compact
-    def __call__(self, h):
-        hidden = (nn.silu(_dense(self.width, self.dtype, "gate")(h))
-                  * _dense(self.width, self.dtype, "up")(h))
-        return _dense(h.shape[-1], self.dtype, "down")(hidden)
-
-
 class SharedAndRoutedExperts(nn.Module):
     """The expert layer's MLP: a shared expert every token passes, beside this
-    chip's share of the sigmoid top-k routed experts. ``__call__(h)`` takes the
-    float32 normalised input ``[B, S, d]`` and returns ``(m float32, the bias
-    term of the loss)``."""
+    chip's share of the sigmoid top-k routed experts
+    (``models/moe.py`` :func:`sigmoid_routed_share`, whose parameters live in
+    this module's scope). ``__call__(h)`` takes the float32 normalised input
+    ``[B, S, d]`` and returns ``(m float32, the bias term of the loss)``."""
     config: AfmoeConfig
 
     @nn.compact
     def __call__(self, h):
-        from autodist_tpu.parallel.mesh import per_device
         cfg = self.config
-        b, s, d = h.shape
-        width, held = cfg.n_experts_routed, cfg.experts_held
-        router = self.param("router", _INIT, (d, width), jnp.float32)
-        bias = self.param("expert_bias", nn.initializers.zeros, (width,),
-                          jnp.float32)
-        bank = [self.param(name, _INIT, shape, jnp.float32) for name, shape in (
-            ("gate", (held, d, cfg.d_expert)), ("up", (held, d, cfg.d_expert)),
-            ("down", (held, cfg.d_expert, d)))]
         with jax.named_scope("moe.shared"):
             shared = GatedMLP(cfg.d_expert * cfg.n_shared_experts, cfg.dtype,
                               name="shared")(h.astype(cfg.dtype))
-        if self.is_initializing():
-            # Shapes are all that init needs: no kernel is compiled for the
-            # handful of positions it runs on.
-            return shared.astype(jnp.float32), jnp.zeros((), jnp.float32)
-        tokens = h.reshape(b * s, d)
-        scores = jax.nn.sigmoid(jnp.dot(tokens.astype(jnp.float32), router,
-                                        precision=jax.lax.Precision.HIGHEST))
-        route = functools.partial(sigmoid_topk_route, route_norm=cfg.route_norm,
-                                  route_scale=cfg.route_scale)
-        y, _ = per_device(
-            functools.partial(routed_experts, top_k=cfg.top_k, route=route,
-                              first_expert=cfg.first_expert_held,
-                              rows_bound=cfg.rows_bound),
-            (tokens.astype(cfg.dtype), scores, *bank, bias),
-            batched=(True, True, False, False, False, False))
-        # The load every expert of the router's width received, absent ones
-        # too: the choice is made here for all of them. (The same top_k as the
-        # route's; the compiler keeps one.)
-        _, chosen = jax.lax.top_k(jax.lax.stop_gradient(scores + bias), cfg.top_k)
-        load = jnp.sum(chosen[..., None] == jnp.arange(width), axis=(0, 1),
-                       dtype=jnp.float32)
-        bias_term = jnp.sum((bias - jax.lax.stop_gradient(bias))
-                            * jax.lax.stop_gradient(load - load.mean())) / (b * s)
-        # for whoever applies with mutable=["intermediates"] (tools/afmoe_load.py)
-        self.sow("intermediates", "load", load)
-        return shared.astype(jnp.float32) + y.reshape(b, s, d), bias_term
+        y, bias_term = sigmoid_routed_share(
+            self, h, router_width=cfg.n_experts_routed,
+            experts_held=cfg.experts_held,
+            first_expert_held=cfg.first_expert_held, top_k=cfg.top_k,
+            d_expert=cfg.d_expert, rows_bound=cfg.rows_bound,
+            route=functools.partial(sigmoid_topk_route,
+                                    route_norm=cfg.route_norm,
+                                    route_scale=cfg.route_scale),
+            dtype=cfg.dtype)
+        return shared.astype(jnp.float32) + y, bias_term
 
 
 class AfmoeBlock(nn.Module):
@@ -316,85 +277,6 @@ def make_loss_fn(model: Afmoe) -> Callable:
         return nll.mean() + bias_term
 
     return loss_fn
-
-
-def make_optimizer(learning_rate: float, load_balance_coeff: float,
-                   weights: Optional[Callable] = None):
-    """AdamW (or ``weights(learning_rate)``) for every leaf but the
-    ``expert_bias`` ones, which take ``b += delta - mean(delta)``, ``delta =
-    -load_balance_coeff * sign(d loss / d b)``: with this file's loss term the
-    published aux-loss-free balancing rule, as an optax transformation."""
-    import optax
-
-    def balance(grads, state, params=None):
-        del params
-        signs = jax.tree_util.tree_map(jnp.sign, grads)
-        return jax.tree_util.tree_map(
-            lambda s: -load_balance_coeff * (s - s.mean()), signs), state
-
-    def labels(params):
-        return jax.tree_util.tree_map_with_path(
-            lambda path, _: "bias" if getattr(path[-1], "key", None)
-            == "expert_bias" else "weights", params)
-
-    return optax.multi_transform(
-        {"weights": (weights or optax.adamw)(learning_rate),
-         "bias": optax.GradientTransformation(lambda params: optax.EmptyState(),
-                                              balance)},
-        labels)
-
-
-def _expert_blocks(tree) -> list:
-    """Names of the blocks of ``tree`` that hold an expert layer, in layer
-    order."""
-    return sorted((name for name in tree if "moe" in tree[name]),
-                  key=lambda name: int(name.rsplit("_", 1)[1]))
-
-
-def sown_loads(intermediates) -> jax.Array:
-    """``[expert layers, router width]`` from the ``intermediates`` an
-    ``apply(..., mutable=["intermediates"])`` returns: the rows every expert
-    of every expert layer received, absent experts too, in layer order."""
-    return jnp.stack([intermediates[name]["moe"]["load"][0]
-                      for name in _expert_blocks(intermediates)])
-
-
-def expert_loads(model: Afmoe, params, tokens) -> jax.Array:
-    """:func:`sown_loads` of one forward pass over ``tokens [B, L]``."""
-    _, sown = model.apply({"params": params}, tokens, return_hidden=True,
-                          mutable=["intermediates"])
-    return sown_loads(sown["intermediates"])
-
-
-def balance_expert_bias(model: Afmoe, params, batches, coeffs):
-    """The parameters with every ``expert_bias`` moved by the balancing rule
-    alone, no weight touched: for each coefficient in ``coeffs``, in turn on
-    the next of ``batches`` (``[B, L]`` token arrays, cycled), ``b += delta -
-    mean(delta)`` with ``delta = coeff * sign(mean(c) - c_e)`` in every expert
-    layer at once. A randomly initialised router loads its experts very
-    unevenly (the normalised residual stream has a large component common to
-    all tokens, which every token's scores share); a trained one is held
-    level by this rule. A falling ``coeffs`` brings the first to the second's
-    loads in tens of forward passes."""
-    names = _expert_blocks(params)
-
-    @jax.jit
-    def moved(params, tokens, coeff):      # the biases alone: nothing else is copied
-        loads = expert_loads(model, params, tokens)
-        delta = coeff * jnp.sign(loads.mean(axis=1, keepdims=True) - loads)
-        return [params[name]["moe"]["expert_bias"] + d - d.mean()
-                for name, d in zip(names, delta)]
-
-    for i, coeff in enumerate(coeffs):
-        # fenced: the host must not run passes ahead of the device (each holds
-        # a forward's activations)
-        biases = jax.block_until_ready(
-            moved(params, batches[i % len(batches)], jnp.float32(coeff)))
-        params = dict(params)
-        for name, bias in zip(names, biases):
-            params[name] = dict(params[name], moe=dict(params[name]["moe"],
-                                                       expert_bias=bias))
-    return params
 
 
 def init_params(config: AfmoeConfig, rng: Optional[jax.Array] = None,

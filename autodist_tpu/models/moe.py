@@ -20,6 +20,13 @@ sorted by expert into ragged groups and the experts run as grouped matmuls
 (``ops/grouped_matmul.py``) over exactly those rows. Shapes stay static because the
 row count is (tokens x k); only the group boundaries are run-time data. No
 ``[tokens, experts, capacity]`` tensor exists and no token is dropped or padded.
+
+Last, what the sigmoid-routed families (``models/afmoe.py``,
+``models/lfm2_moe.py``) share and neither copies: :class:`GatedMLP`,
+:func:`sigmoid_routed_share` (one chip's share of the routed experts with the
+``expert_bias`` leaf and its zero-valued loss term), :func:`balanced_optimizer`
+(the aux-loss-free balancing rule as an optax transformation) and
+:func:`balance_expert_bias` (the same rule alone, before training).
 """
 
 import dataclasses
@@ -263,19 +270,20 @@ def topk_route(probs: jax.Array, k: int, bias=None, *, first_expert: int = 0,
 
 def sigmoid_topk_route(scores: jax.Array, k: int, bias=None, *,
                        route_norm: bool = True, route_scale: float = 1.0,
-                       first_expert: int = 0,
+                       route_eps: float = 1e-20, first_expert: int = 0,
                        n_held: Optional[int] = None) -> Route:
     """The router of the sigmoid-scored mixtures: ``scores = sigmoid(h.Wr)``
     in float32, the ``k`` experts with the largest ``scores + bias`` are
     chosen, and their weights are the scores themselves, without the bias
     (which steers the load and takes no gradient), divided by their sum over
-    the chosen (+ 1e-20) under ``route_norm`` and multiplied by
-    ``route_scale``. The sort is :func:`topk_route`'s."""
+    the chosen (+ ``route_eps``: AFMoE's 1e-20, LFM2's 1e-6) under
+    ``route_norm`` and multiplied by ``route_scale``. The sort is
+    :func:`topk_route`'s."""
     choice = scores if bias is None else scores + jax.lax.stop_gradient(bias)
     _, indices = jax.lax.top_k(choice, k)
     weights = jnp.take_along_axis(scores, indices, axis=-1)
     if route_norm:
-        weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-20)
+        weights = weights / (weights.sum(axis=-1, keepdims=True) + route_eps)
     return _sorted_route(indices, weights * route_scale, scores.shape[1],
                          first_expert, n_held)
 
@@ -526,3 +534,161 @@ class RoutedFFN(nn.Module):
         aux = {"load_balance": self.n_experts * jnp.sum(load * probs.mean(axis=0)),
                "router_z": jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))}
         return y.reshape(b, s, d), aux
+
+
+# ------------------------------------------- sigmoid-routed shares and their bias
+
+_INIT = nn.initializers.normal(0.02)
+
+
+def _dense(features: int, dtype, name: str) -> nn.Dense:
+    return nn.Dense(features, use_bias=False, dtype=dtype,
+                    param_dtype=jnp.float32, kernel_init=_INIT, name=name)
+
+
+class GatedMLP(nn.Module):
+    """``W_down(silu(W_gate h) * W_up h)``: a dense layer's MLP, a shared
+    expert."""
+    width: int
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, h):
+        hidden = (nn.silu(_dense(self.width, self.dtype, "gate")(h))
+                  * _dense(self.width, self.dtype, "up")(h))
+        return _dense(h.shape[-1], self.dtype, "down")(hidden)
+
+
+def sigmoid_routed_share(module: nn.Module, h, *, router_width: int,
+                         experts_held: int, first_expert_held: int, top_k: int,
+                         d_expert: int, rows_bound: Optional[int],
+                         route: Callable[..., Route], dtype):
+    """This chip's share of a layer's sigmoid top-k routed experts, with its
+    parameters made in ``module``'s own scope (call it from the compact
+    method of the expert layer): ``router [d, router_width]``, ``expert_bias
+    [router_width]`` (float32, zeros) and the banks ``gate``, ``up`` ``[held,
+    d, d_expert]``, ``down [held, d_expert, d]`` of the experts
+    ``[first_expert_held, first_expert_held + experts_held)``.
+
+    ``h`` is the float32 normalised input ``[B, S, d]``: the router's product
+    and sigmoid read it as it is at ``HIGHEST`` precision, the experts its
+    cast to ``dtype``. ``route`` is :func:`sigmoid_topk_route` with the
+    family's normaliser (``functools.partial``). Returns ``(y [B, S, d]
+    float32, the bias term)``: the held experts' weighted sum (what the absent
+    ones would add is left out), and ``sum_e (b_e - stop_gradient(b_e)) .
+    stop_gradient(c_e - mean c) / T``, zero in value, whose gradient with
+    respect to ``expert_bias`` is the layer's load error (``c_e``: the rows
+    expert ``e`` of the router's whole width received). The loads are sown
+    under ``intermediates`` / ``load``."""
+    from autodist_tpu.parallel.mesh import per_device
+    b, s, d = h.shape
+    router = module.param("router", _INIT, (d, router_width), jnp.float32)
+    bias = module.param("expert_bias", nn.initializers.zeros, (router_width,),
+                        jnp.float32)
+    bank = [module.param(name, _INIT, shape, jnp.float32) for name, shape in (
+        ("gate", (experts_held, d, d_expert)), ("up", (experts_held, d, d_expert)),
+        ("down", (experts_held, d_expert, d)))]
+    if module.is_initializing():
+        # Shapes are all that init needs: no kernel is compiled for the
+        # handful of positions it runs on.
+        return jnp.zeros((b, s, d), jnp.float32), jnp.zeros((), jnp.float32)
+    tokens = h.reshape(b * s, d)
+    scores = jax.nn.sigmoid(jnp.dot(tokens.astype(jnp.float32), router,
+                                    precision=jax.lax.Precision.HIGHEST))
+    y, _ = per_device(
+        functools.partial(routed_experts, top_k=top_k, route=route,
+                          first_expert=first_expert_held,
+                          rows_bound=rows_bound),
+        (tokens.astype(dtype), scores, *bank, bias),
+        batched=(True, True, False, False, False, False))
+    # The load every expert of the router's width received, absent ones
+    # too: the choice is made here for all of them. (The same top_k as the
+    # route's; the compiler keeps one.)
+    _, chosen = jax.lax.top_k(jax.lax.stop_gradient(scores + bias), top_k)
+    load = jnp.sum(chosen[..., None] == jnp.arange(router_width), axis=(0, 1),
+                   dtype=jnp.float32)
+    bias_term = jnp.sum((bias - jax.lax.stop_gradient(bias))
+                        * jax.lax.stop_gradient(load - load.mean())) / (b * s)
+    # for whoever applies with mutable=["intermediates"] (tools/afmoe_load.py)
+    module.sow("intermediates", "load", load)
+    return y.reshape(b, s, d), bias_term
+
+
+def balanced_optimizer(learning_rate, load_balance_coeff: float,
+                       weights: Optional[Callable] = None):
+    """AdamW (or ``weights(learning_rate)``) for every leaf but the
+    ``expert_bias`` ones, which take ``b += delta - mean(delta)``, ``delta =
+    -load_balance_coeff * sign(d loss / d b)``: with
+    :func:`sigmoid_routed_share`'s loss term the published aux-loss-free
+    balancing rule, as an optax transformation."""
+    import optax
+
+    def balance(grads, state, params=None):
+        del params
+        signs = jax.tree_util.tree_map(jnp.sign, grads)
+        return jax.tree_util.tree_map(
+            lambda s: -load_balance_coeff * (s - s.mean()), signs), state
+
+    def labels(params):
+        return jax.tree_util.tree_map_with_path(
+            lambda path, _: "bias" if getattr(path[-1], "key", None)
+            == "expert_bias" else "weights", params)
+
+    return optax.multi_transform(
+        {"weights": (weights or optax.adamw)(learning_rate),
+         "bias": optax.GradientTransformation(lambda params: optax.EmptyState(),
+                                              balance)},
+        labels)
+
+
+def _expert_blocks(tree) -> list:
+    """Names of the blocks of ``tree`` that hold an expert layer (``block_<i>``
+    with a ``moe`` entry), in layer order."""
+    return sorted((name for name in tree if "moe" in tree[name]),
+                  key=lambda name: int(name.rsplit("_", 1)[1]))
+
+
+def sown_loads(intermediates) -> jax.Array:
+    """``[expert layers, router width]`` from the ``intermediates`` an
+    ``apply(..., mutable=["intermediates"])`` returns: the rows every expert
+    of every expert layer received, absent experts too, in layer order."""
+    return jnp.stack([intermediates[name]["moe"]["load"][0]
+                      for name in _expert_blocks(intermediates)])
+
+
+def expert_loads(model: nn.Module, params, tokens) -> jax.Array:
+    """:func:`sown_loads` of one forward pass over ``tokens [B, L]``."""
+    _, sown = model.apply({"params": params}, tokens, return_hidden=True,
+                          mutable=["intermediates"])
+    return sown_loads(sown["intermediates"])
+
+
+def balance_expert_bias(model: nn.Module, params, batches, coeffs):
+    """The parameters with every ``expert_bias`` moved by the balancing rule
+    alone, no weight touched: for each coefficient in ``coeffs``, in turn on
+    the next of ``batches`` (``[B, L]`` token arrays, cycled), ``b += delta -
+    mean(delta)`` with ``delta = coeff * sign(mean(c) - c_e)`` in every expert
+    layer at once. A randomly initialised router loads its experts very
+    unevenly (the normalised residual stream has a large component common to
+    all tokens, which every token's scores share); a trained one is held
+    level by this rule. A falling ``coeffs`` brings the first to the second's
+    loads in tens of forward passes."""
+    names = _expert_blocks(params)
+
+    @jax.jit
+    def moved(params, tokens, coeff):      # the biases alone: nothing else is copied
+        loads = expert_loads(model, params, tokens)
+        delta = coeff * jnp.sign(loads.mean(axis=1, keepdims=True) - loads)
+        return [params[name]["moe"]["expert_bias"] + d - d.mean()
+                for name, d in zip(names, delta)]
+
+    for i, coeff in enumerate(coeffs):
+        # fenced: the host must not run passes ahead of the device (each holds
+        # a forward's activations)
+        biases = jax.block_until_ready(
+            moved(params, batches[i % len(batches)], jnp.float32(coeff)))
+        params = dict(params)
+        for name, bias in zip(names, biases):
+            params[name] = dict(params[name], moe=dict(params[name]["moe"],
+                                                       expert_bias=bias))
+    return params

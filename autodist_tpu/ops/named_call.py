@@ -18,7 +18,8 @@ from jax.experimental import pallas as pl
 # (the benchmark's kernel readers and tests/test_device_names.py lean on these).
 KERNEL_NAMES = ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq", "flash_carry",
                 "xent_fwd", "xent_bwd_dh", "xent_bwd_dw",
-                "moe_gmm_fwd", "moe_gmm_bwd_dx", "moe_gmm_bwd_dw")
+                "moe_gmm_fwd", "moe_gmm_bwd_dx", "moe_gmm_bwd_dw",
+                "short_conv_fwd", "short_conv_bwd")
 
 
 def named_pallas_call(name: str, kernel, **kwargs):

@@ -258,6 +258,65 @@ def test_grouped_matmul_fwd_bwd_compiles_at_the_trinity_cell_shapes(chip, k, n):
         assert name in text
 
 
+@pytest.mark.parametrize("k,n", [(2048, 1536), (1536, 2048)],
+                         ids=["gate-up-2048x1536", "down-1536x2048"])
+def test_grouped_matmul_fwd_bwd_compiles_at_the_lfm2_cell_shapes(chip, k, n):
+    """A 16,384-row pass of one chip's share (8 of 64 experts held, 8,192 rows
+    on average) in 8 groups of experts 1,536 wide: ``_col_tile(1536)`` is 512
+    where the other cells' 1,024 takes 1,024-column blocks."""
+    from autodist_tpu.ops import grouped_matmul
+
+    assert grouped_matmul._col_tile(1536) == 512
+
+    def loss(x, w, group_sizes):
+        return grouped_matmul.gmm(x, w, group_sizes).astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1)), chip,
+                          ((16_384, k), jnp.bfloat16),
+                          ((8, k, n), jnp.float32), ((8,), jnp.int32))
+    for name in ("moe_gmm_fwd", "moe_gmm_bwd_dx", "moe_gmm_bwd_dw"):
+        assert name in text
+
+
+def test_flash_grouped_heads_of_64_compile_at_the_lfm2_cell_shape(chip):
+    """lfm2-pretrain-8k's call: 2 x 8,192 x 32 query heads over 8 KV heads of
+    64, causal, no window. K of a head is exactly ``_RESIDENT_KV_BYTES`` (the
+    largest the resident walk holds) and float32 dQ of a head 2 MiB of the
+    4 MiB the one-pass backward takes: both limits' last resident shape."""
+    q = ((2, 8192, 32, 64), jnp.bfloat16)
+    kv = ((2, 8192, 8, 64), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1, 2)), chip,
+                          q, kv, kv)
+    assert 8192 * 64 * 2 == fa._RESIDENT_KV_BYTES
+    assert 8192 * 64 * 4 <= fa._RESIDENT_DQ_BYTES
+    assert "flash_fwd" in text and "flash_bwd_dkv" in text
+    assert "flash_bwd_dq" not in text
+
+
+@pytest.mark.parametrize("batch,length,d,taps", [
+    (2, 8192, 2048, 3),         # lfm2-pretrain-8k: bcu [2, 8192, 6144]
+    (2, 1000, 2048, 4),         # a ragged last block, four taps
+    (1, 8, 128, 3),             # shorter than a 16-row tile
+], ids=["cell-2x8192x6144", "ragged-L1000-K4", "L8"])
+def test_short_conv_fwd_bwd_compiles(chip, batch, length, d, taps):
+    """The gated short convolution's two kernels, each a Mosaic call under
+    its own name, through the operator's custom VJP."""
+    from autodist_tpu.ops.short_conv import gated_short_conv
+
+    def loss(bcu, w):
+        return gated_short_conv(bcu, w, "pallas").astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1)), chip,
+                          ((batch, length, 3 * d), jnp.bfloat16),
+                          ((d, taps), jnp.float32))
+    assert "tpu_custom_call" in text
+    assert "short_conv_fwd" in text and "short_conv_bwd" in text
+
+
 def test_fused_xent_forward_compiles_at_lm1b_vocab(chip):
     """lm1b's exact 793,471-word vocabulary, softmax_w layout, forward."""
     def nll(h, w, targets):

@@ -83,7 +83,8 @@ def test_lowered_step_names_kernels_and_phases(backward, monkeypatch):
         monkeypatch.setattr(fa, "_RESIDENT_DQ_BYTES", 0)
     text = _lm_step_lowered(zero=1).as_text(debug_info=True)
     step_kernels = [k for k in named_call.KERNEL_NAMES
-                    if k != "flash_carry" and not k.startswith("moe_")
+                    if k != "flash_carry"
+                    and not k.startswith(("moe_", "short_conv_"))
                     and (k != "flash_bwd_dq" or backward == "split")]
     assert _scopes(text, named_call.KERNEL_NAMES) == set(step_kernels)
     # ZeRO's constrain_update is the reduction under AllReduce (the implicit
@@ -167,6 +168,56 @@ def test_moe_gauges_are_set_when_the_layer_is_traced():
     # per device: 16 sequences of 16 over 8 devices, two slots a token
     assert telemetry.gauge("moe.rows_per_call").value == 2 * 16 * 2
     assert telemetry.gauge("moe.gmm.row_tiles").value == 1 + 8
+
+
+CONV_SCOPES = ("conv.in_proj", "conv.gate_conv", "conv.out_proj")
+
+
+@functools.lru_cache(maxsize=1)
+def _lfm2_step_text() -> str:       # one lowering for the cases below
+    """The tiny LFM2-MoE step (a conv layer behind a dense MLP, an attention
+    layer and a conv layer with their share of the experts, the tied fused
+    head) through ``AutoDist`` on the 8-device mesh."""
+    from autodist_tpu.models import lfm2_moe
+    cfg = lfm2_moe.Lfm2MoeConfig(
+        vocab_size=203, d_model=128, n_heads=2, n_kv_heads=1, head_dim=16,
+        layer_types=("conv", "full_attention", "conv"), n_dense_layers=1,
+        d_ff=64, d_expert=16, n_experts_routed=8, experts_held=2,
+        first_expert_held=2, top_k=2, max_len=16, dtype=jnp.float32,
+        attention_impl="flash", conv_impl="pallas", fused_head=True)
+    model, params = lfm2_moe.init_params(cfg, rng=jax.random.PRNGKey(0))
+    batch = lfm2_moe.synthetic_batch(cfg, batch_size=16, seq_len=16)
+    runner = AutoDist(strategy_builder=AllReduce()).create_distributed_session(
+        lfm2_moe.make_loss_fn(model), params,
+        lfm2_moe.make_optimizer(1e-3, cfg.load_balance_coeff),
+        example_batch=batch)
+    state = runner.init(params)
+    with runner.mesh:
+        return runner._build_step(None).lower(
+            state, runner.shard_batch(batch)).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("name", [k for k in named_call.KERNEL_NAMES
+                                  if k.startswith("short_conv_")]
+                         + list(CONV_SCOPES) + list(MOE_SCOPES))
+def test_lfm2_step_names_its_conv_kernels_and_scopes(name):
+    """The gated short convolution's two kernels by their device names
+    (``pallas:short_conv_fwd`` / ``pallas:short_conv_bwd`` in a trace), the
+    three scopes of the conv operator, and the routed share's beside them."""
+    assert _scopes(_lfm2_step_text(), [name]) == {name}
+
+
+def test_short_conv_gauges_are_set_when_the_operator_is_traced():
+    calls = telemetry.counter("short_conv.calls").value
+    _lfm2_step_text.cache_clear()
+    _lfm2_step_text()
+    # per device: 16 sequences of 16 over 8 devices, one 16-row block each
+    assert telemetry.gauge("short_conv.fwd.block_rows").value == 16
+    assert telemetry.gauge("short_conv.bwd.block_rows").value == 16
+    tensor = 2 * 16 * 128 * 4          # [2 x 16, 128] float32 activations
+    assert telemetry.gauge("short_conv.fwd.bytes").value == 4 * tensor + 3 * 128 * 4
+    assert telemetry.gauge("short_conv.bwd.bytes").value > 7 * tensor
+    assert telemetry.counter("short_conv.calls").value >= calls + 2   # two conv layers
 
 
 def test_flash_carry_is_named():
