@@ -35,9 +35,13 @@ here. The router scores and chooses over all of them, this layer adds its own
 experts' part and leaves the rest out (``models/moe.py`` ``routed_experts``:
 held rows sorted first and computed ``rows_bound`` at a time, in as many
 passes as a step's routing needs): what expert parallelism asks of a rank,
-without the exchange. The shared
-expert, attention, the router and the dense layer are what every rank
-computes alike.
+without the exchange. The first pass keeps the gathered rows and the three
+products for its backward (the bound is twice the mean held rows, so it is
+nearly always the only one, and is computed once a step); a pass past it
+keeps nothing and is computed again for the backward, since their number is
+known only at run time. The layer sows that number (``passes``) beside the
+loads. The shared expert, attention, the router and the dense layer are what
+every rank computes alike.
 
 **The expert bias on the normal path.** ``expert_bias`` is a parameter leaf
 (float32 ``[E]``, zeros). It enters the choice under ``stop_gradient``, and

@@ -42,8 +42,11 @@ the code is the same code: ``models/moe.py`` ``sigmoid_routed_share``,
 ``balanced_optimizer``, ``balance_expert_bias``. ``n_experts_routed`` is the
 router's width; ``experts_held`` of them, from ``first_expert_held`` on, have
 their banks here; the router chooses over all of them and this layer adds its
-own experts' part. The conv and attention operators, the router and the dense
-layer are what every rank computes alike.
+own experts' part, ``rows_bound`` held rows a pass: the first pass keeps what
+its backward reads and is computed once, a pass past it (rare: the bound is
+twice the mean held rows) is computed again for the backward, and the layer
+sows how many a step took (``passes``). The conv and attention operators, the
+router and the dense layer are what every rank computes alike.
 
 The gated convolution between the two projections is one operator,
 ``ops/short_conv.py`` ``gated_short_conv``, plain (``conv_impl="xla"``) or as

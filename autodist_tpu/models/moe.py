@@ -380,41 +380,65 @@ def _held_pass(c, x, weights, gate, up, down, perm, offsets, top_k: int,
             weight[:, None] * out.astype(jnp.float32))
 
 
-def _passes(offsets, bound: int):
-    return (offsets[-1] + bound - 1) // bound
+# One trace of the pass for every layer and loop of a program that share its
+# shapes (the pass number an argument, not a constant): each call of the plain
+# function traces its three kernels again, and a share calls it three times a
+# layer (pass 0, the forward's loop, the transpose's loop).
+_traced_once_pass = jax.jit(_held_pass, static_argnames=("top_k", "bound"))
+
+
+def _pass_of(perm, offsets, top_k: int, bound: int):
+    """``(c, x, weights, gate, up, down) -> pass c``, of one routing."""
+    return functools.partial(_traced_once_pass, perm=perm, offsets=offsets,
+                             top_k=top_k, bound=bound)
+
+
+def _passes(held_rows, bound: int):
+    return (held_rows + bound - 1) // bound
+
+
+def _later_passes(run, y, operands, passes):
+    return jax.lax.fori_loop(1, passes, lambda c, y: y + run(c, *operands), y)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
 def _held_passes(x, weights, gate, up, down, perm, offsets, top_k, bound):
     """Every pass the held rows need, ``ceil(held rows / bound)`` of them, a
-    number known only at run time: a loop over :func:`_held_pass` whose
-    buffers are one pass's. The transpose runs the same number of passes
-    again, each recomputed and transposed in turn (a loop of unknown length
-    has no transpose of its own), so no pass keeps anything for it."""
-    return jax.lax.fori_loop(
-        0, _passes(offsets, bound),
-        lambda c, y: y + _held_pass(c, x, weights, gate, up, down, perm,
-                                    offsets, top_k, bound),
-        jnp.zeros(x.shape, jnp.float32))
+    number known only at run time: pass 0, then a loop over :func:`_held_pass`
+    from 1 whose buffers are one pass's.
+
+    Pass 0 runs once and keeps what its transpose reads (the gathered rows,
+    the three products, the weights and indices); the transpose starts its
+    float32 totals from pass 0's. The bound is twice the mean held rows, so
+    pass 0 is nearly always all there is, and a forward run again for the
+    transpose would be paid by every step. The passes past it keep nothing (a
+    loop of unknown length has no transpose of its own, and no buffer of
+    unknown size to keep things in): the transpose runs as many again, each
+    recomputed and transposed in turn."""
+    run, operands = _pass_of(perm, offsets, top_k, bound), (x, weights, gate, up, down)
+    return _later_passes(run, run(0, *operands), operands,
+                         _passes(offsets[-1], bound))
 
 
 def _held_passes_fwd(x, weights, gate, up, down, perm, offsets, top_k, bound):
-    y = _held_passes(x, weights, gate, up, down, perm, offsets, top_k, bound)
-    return y, (x, weights, gate, up, down, perm, offsets)
+    run, operands = _pass_of(perm, offsets, top_k, bound), (x, weights, gate, up, down)
+    y, transpose_first = jax.vjp(functools.partial(run, 0), *operands)
+    y = _later_passes(run, y, operands, _passes(offsets[-1], bound))
+    return y, (transpose_first, operands, perm, offsets)
 
 
 def _held_passes_bwd(top_k, bound, residuals, g):
-    *operands, perm, offsets = residuals
+    transpose_first, operands, perm, offsets = residuals
+    run = _pass_of(perm, offsets, top_k, bound)
 
     def one(c, total):
-        _, transpose = jax.vjp(
-            lambda *a: _held_pass(c, *a, perm, offsets, top_k, bound), *operands)
+        _, transpose = jax.vjp(functools.partial(run, c), *operands)
         return jax.tree_util.tree_map(
             lambda t, part: t + part.astype(t.dtype), total, transpose(g))
 
     # a token's rows add up over the passes in float32, as within one
-    zeros = [jnp.zeros(a.shape, jnp.float32) for a in operands]
-    total = jax.lax.fori_loop(0, _passes(offsets, bound), one, tuple(zeros))
+    first = tuple(part.astype(jnp.float32) for part in transpose_first(g))
+    total = jax.lax.fori_loop(1, _passes(offsets[-1], bound), one, first)
     return (*(t.astype(a.dtype) for t, a in zip(total, operands)), None, None)
 
 
@@ -446,7 +470,10 @@ def routed_experts(x, scores, gate, up, down, bias=None, *, top_k: int,
     experts held, whatever the router does: a step that routes more than
     ``rows_bound`` rows to them takes as many passes over the same buffers as
     they need (:func:`_held_passes`), so the bound sets the memory and the
-    grain of the work, never which rows are computed."""
+    grain of the work, never which rows are computed. The first pass keeps
+    what its backward reads, as the two one-pass branches do through plain
+    autodiff; only the passes past it, which a step rarely takes, are computed
+    again for the backward."""
     from autodist_tpu import telemetry
     n_tokens, d = x.shape
     n_held, width = int(gate.shape[0]), int(scores.shape[1])
@@ -461,6 +488,7 @@ def routed_experts(x, scores, gate, up, down, bias=None, *, top_k: int,
     telemetry.gauge("moe.rows_bound").set(rows_bound)
     passes = -(-n_slots // rows_bound)       # at most; a step takes what it needs
     telemetry.gauge("moe.passes_max").set(passes)
+    telemetry.gauge("moe.passes_kept").set(1)   # pass 0; the others recompute
     if whole:
         with jax.named_scope("moe.route"):
             r = route(scores, top_k, bias)
@@ -579,7 +607,8 @@ def sigmoid_routed_share(module: nn.Module, h, *, router_width: int,
     stop_gradient(c_e - mean c) / T``, zero in value, whose gradient with
     respect to ``expert_bias`` is the layer's load error (``c_e``: the rows
     expert ``e`` of the router's whole width received). The loads are sown
-    under ``intermediates`` / ``load``."""
+    under ``intermediates`` / ``load``, the passes the held rows took under
+    ``passes``."""
     from autodist_tpu.parallel.mesh import per_device
     b, s, d = h.shape
     router = module.param("router", _INIT, (d, router_width), jnp.float32)
@@ -595,7 +624,7 @@ def sigmoid_routed_share(module: nn.Module, h, *, router_width: int,
     tokens = h.reshape(b * s, d)
     scores = jax.nn.sigmoid(jnp.dot(tokens.astype(jnp.float32), router,
                                     precision=jax.lax.Precision.HIGHEST))
-    y, _ = per_device(
+    y, sizes = per_device(
         functools.partial(routed_experts, top_k=top_k, route=route,
                           first_expert=first_expert_held,
                           rows_bound=rows_bound),
@@ -609,8 +638,15 @@ def sigmoid_routed_share(module: nn.Module, h, *, router_width: int,
                    dtype=jnp.float32)
     bias_term = jnp.sum((bias - jax.lax.stop_gradient(bias))
                         * jax.lax.stop_gradient(load - load.mean())) / (b * s)
+    # Per device the sizes are of its own tokens, [devices * held]: the
+    # passes are those of the device that took most.
+    held_rows = sizes.reshape(-1, experts_held).sum(axis=1)
+    slots = b * s * top_k // held_rows.size
+    bound = slots if rows_bound is None else min(int(rows_bound), slots)
+    passes = _passes(held_rows, bound).max()
     # for whoever applies with mutable=["intermediates"] (tools/afmoe_load.py)
     module.sow("intermediates", "load", load)
+    module.sow("intermediates", "passes", passes.astype(jnp.int32))
     return y.reshape(b, s, d), bias_term
 
 
@@ -648,12 +684,24 @@ def _expert_blocks(tree) -> list:
                   key=lambda name: int(name.rsplit("_", 1)[1]))
 
 
+def _sown(intermediates, what: str) -> jax.Array:
+    return jnp.stack([intermediates[name]["moe"][what][0]
+                      for name in _expert_blocks(intermediates)])
+
+
 def sown_loads(intermediates) -> jax.Array:
     """``[expert layers, router width]`` from the ``intermediates`` an
     ``apply(..., mutable=["intermediates"])`` returns: the rows every expert
     of every expert layer received, absent experts too, in layer order."""
-    return jnp.stack([intermediates[name]["moe"]["load"][0]
-                      for name in _expert_blocks(intermediates)])
+    return _sown(intermediates, "load")
+
+
+def sown_passes(intermediates) -> jax.Array:
+    """``[expert layers]`` int32 from the same ``intermediates``: the passes
+    each expert layer's held rows took (``ceil(held rows / rows_bound)`` on
+    the device that took most; every one past the first is recomputed for the
+    backward)."""
+    return _sown(intermediates, "passes")
 
 
 def expert_loads(model: nn.Module, params, tokens) -> jax.Array:

@@ -292,6 +292,129 @@ def test_more_held_rows_than_the_bound_take_more_passes_and_nothing_is_dropped()
     assert all(np.isfinite(g).all() for g in jax.tree_util.tree_leaves(grads))
 
 
+def _kernels_and_loops(jaxpr, inside=False):
+    """``(kernel name, inside a loop?)`` of every Pallas call and ``("while",
+    first index)`` of every loop, sub-programs included (a ``fori_loop`` over
+    a traced bound carries its index first)."""
+    for eqn in jaxpr.eqns:
+        loop = eqn.primitive.name == "while"
+        if eqn.primitive.name == "pallas_call":
+            yield str(eqn.params["name"]), inside
+        elif loop:
+            first = eqn.invars[eqn.params["cond_nconsts"]
+                               + eqn.params["body_nconsts"]]
+            yield "while", int(first.val)
+        for param in eqn.params.values():
+            for sub in (param if isinstance(param, (tuple, list)) else [param]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _kernels_and_loops(sub, inside or loop)
+
+
+@pytest.mark.parametrize("what,outside,inside", [
+    ("moe_gmm_fwd", 3, 6), ("moe_gmm_bwd_dx", 3, 3), ("moe_gmm_bwd_dw", 3, 3),
+    ("while", None, [1, 1])])
+def test_the_first_pass_runs_once_and_only_the_passes_past_it_recompute(
+        what, outside, inside):
+    """The gradient program of a share whose bound is below ``T*k``: pass 0's
+    three products and their six transposes sit outside every loop, once (its
+    forward is not run again for the backward); the two loops, the forward's
+    and the transpose's, start at pass 1, and the transpose's holds the three
+    products a second time beside their transposes."""
+    x, scores, bias, bank = _layer_inputs()
+    share = lambda x, gate, up, down: moe.routed_experts(  # noqa: E731
+        x, scores, gate, up, down, bias, top_k=3,
+        route=moe.sigmoid_topk_route, first_expert=2, rows_bound=16)[0]
+    jaxpr = jax.make_jaxpr(jax.grad(lambda *a: share(*a).sum(),
+                                    argnums=(0, 1, 2, 3)))(
+        x, *(b[2:5] for b in bank))
+    found = list(_kernels_and_loops(jaxpr.jaxpr))
+    if what == "while":
+        assert [first for name, first in found if name == "while"] == inside
+        return
+    calls = [looped for name, looped in found if name == what]
+    assert calls.count(False) == outside and calls.count(True) == inside
+
+
+def test_two_layers_and_their_loops_trace_the_pass_at_most_twice(monkeypatch):
+    """What a warm start pays for the share is tracing: pass 0, the forward's
+    loop and the transpose's loop of every expert layer with the same shapes
+    run the pass's Python body, and so its three kernels' tracing, twice
+    between them (JAX keeps one trace for the calls of the forward and one
+    for those made while it transposes), not three times a layer. Shapes no
+    other test of this file uses."""
+    x, scores, bias, bank = _layer_inputs(tokens=20, d=16, w=40)
+    calls = []
+    inner = moe._gated_experts
+    monkeypatch.setattr(moe, "_gated_experts",
+                        lambda *a: calls.append(1) or inner(*a))
+    share = lambda x, gate, up, down: moe.routed_experts(  # noqa: E731
+        x, scores, gate, up, down, bias, top_k=3,
+        route=moe.sigmoid_topk_route, first_expert=2, rows_bound=16)[0]
+    two_layers = lambda x, *bank: share(share(x, *bank), *bank).sum()  # noqa: E731
+    jax.make_jaxpr(jax.grad(two_layers, argnums=(0, 1, 2, 3)))(
+        x, *(b[2:5] for b in bank))
+    assert 1 <= len(calls) <= 2
+
+
+# Held experts 4-5 of 8, top-2, 24 tokens: (the two experts every token
+# chooses, bound, passes): 4 and 5 (48 held rows), 4 and 0 (24), 0 and 1 (none).
+_ROUTINGS = {"1-pass": ((4, 0), 36, 1), "2-passes": ((4, 5), 24, 2),
+             "4-passes": ((4, 5), 12, 4), "no-held-rows": ((0, 1), 12, 0)}
+
+
+def _bias_on(experts):
+    return jnp.zeros(8).at[jnp.asarray(experts)].set(jnp.asarray([10.0, 9.0]))
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5), (jnp.bfloat16, 2e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("routing", ["1-pass", "2-passes", "4-passes"])
+def test_kept_and_recomputed_passes_give_the_dense_shares_gradients(
+        routing, dtype, tol):
+    """y and the gradients of x, the router's scores and the three banks
+    through :func:`moe._held_passes` (every bound is below ``T*k``), pass 0
+    transposed from what it kept and the others recomputed, against the dense
+    share in float32 on the same (rounded) rows."""
+    x, scores, _, bank = _layer_inputs()
+    chosen, bound, _ = _ROUTINGS[routing]
+    bias, mine = _bias_on(chosen), [b[4:6] for b in bank]
+    x = x.astype(dtype)
+    share = lambda x, scores, *bank: moe.routed_experts(  # noqa: E731
+        x, scores, *bank, bias, top_k=2, route=moe.sigmoid_topk_route,
+        first_expert=4, rows_bound=bound)[0]
+    dense = lambda x, scores, *bank: _dense_share(  # noqa: E731
+        x.astype(jnp.float32), scores, *bank, bias, k=2, first=4, scale=1.0)
+    assert bound < x.shape[0] * 2
+    target = jax.random.normal(jax.random.PRNGKey(7), x.shape)
+    loss = lambda fn: lambda *a: (fn(*a) * target).sum()  # noqa: E731
+    np.testing.assert_allclose(share(x, scores, *mine), dense(x, scores, *mine),
+                               rtol=tol, atol=tol)
+    got = jax.grad(loss(share), argnums=(0, 1, 2, 3, 4))(x, scores, *mine)
+    want = jax.grad(loss(dense), argnums=(0, 1, 2, 3, 4))(x, scores, *mine)
+    assert got[0].dtype == dtype and got[2].dtype == jnp.float32
+    for g, r in zip(got, want):
+        assert _rel_l2(g.astype(jnp.float32), r.astype(jnp.float32)) <= tol
+
+
+@pytest.mark.parametrize("routing", list(_ROUTINGS))
+def test_the_layer_sows_the_passes_its_held_rows_took(routing):
+    """Beside ``load``: ``ceil(held rows / rows_bound)`` of the step's
+    routing, 0 where no row is held (pass 0 runs then too, over nothing)."""
+    chosen, bound, passes = _ROUTINGS[routing]
+    bias = _bias_on(chosen)
+    cfg = afmoe.AfmoeConfig(dtype=jnp.float32, **dict(
+        TINY, first_expert_held=4, rows_bound=bound))
+    layer = afmoe.SharedAndRoutedExperts(cfg)
+    params = layer.init(jax.random.PRNGKey(2), jnp.zeros((1, 4, 64)))["params"]
+    h = jax.random.normal(jax.random.PRNGKey(3), (1, 24, 64))
+    _, sown = layer.apply({"params": dict(params, expert_bias=bias)}, h,
+                          mutable=["intermediates"])
+    (took,), (load,) = (sown["intermediates"][k] for k in ("passes", "load"))
+    assert took.dtype == jnp.int32 and int(took) == passes
+    assert -(-int(load[4:6].sum()) // bound) == passes
+
+
 def test_the_balancing_rule_alone_levels_a_random_routers_loads():
     """``balance_expert_bias``: no weight moves, every expert layer's bias
     does, and the loads' spread over the router's width falls."""

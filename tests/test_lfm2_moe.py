@@ -198,6 +198,43 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
                                rtol=1e-4, atol=1e-5)
 
 
+def test_a_layer_whose_held_rows_take_three_passes_equals_the_one_pass_layer():
+    """``lfm2_moe``'s expert layer, experts 8-15 of 64 held, top-4 with the
+    1e-6: under a bound the held rows fill three times (pass 0 kept, two
+    recomputed) the output and the gradients of the input and of every
+    parameter are those of the same layer in one pass over all ``T*k`` rows,
+    and the layer sows the three."""
+    wide = dict(TINY, d_model=32, d_expert=16, n_experts_routed=64, top_k=4,
+                experts_held=8, first_expert_held=8)
+    layers = {bound: lfm2_moe.RoutedExperts(lfm2_moe.Lfm2MoeConfig(
+        dtype=jnp.float32, rows_bound=bound, **wide)) for bound in (None, 48)}
+    params = layers[None].init(jax.random.PRNGKey(2),
+                               jnp.zeros((1, 4, 32)))["params"]
+    # three held experts preferred: most tokens choose them
+    params = dict(params, expert_bias=jnp.zeros(64).at[8:11].set(0.5))
+    h = jax.random.normal(jax.random.PRNGKey(3), (1, 40, 32))
+    target = jax.random.normal(jax.random.PRNGKey(4), h.shape)
+
+    def run(bound):
+        def loss(params, h):
+            (y, bias_term), sown = layers[bound].apply(
+                {"params": params}, h, mutable=["intermediates"])
+            return (y * target).sum() + bias_term, (y, sown["intermediates"])
+        (_, (y, sown)), grads = jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True)(params, h)
+        return y, grads, sown
+
+    y, grads, sown = run(48)
+    want_y, want_grads, want_sown = run(None)
+    held = int(sown["load"][0][8:16].sum())
+    assert 96 < held <= 144 < 160 and int(sown["passes"][0]) == 3
+    assert int(want_sown["passes"][0]) == 1
+    np.testing.assert_allclose(y, want_y, rtol=1e-5, atol=1e-6)
+    for g, r in zip(jax.tree_util.tree_leaves(grads),
+                    jax.tree_util.tree_leaves(want_grads)):
+        np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-6)
+
+
 def test_the_two_families_share_the_mixtures_code_and_neither_copies_it():
     for name in ("GatedMLP", "sigmoid_routed_share", "balance_expert_bias",
                  "expert_loads", "sown_loads", "sigmoid_topk_route"):

@@ -9,11 +9,13 @@ Trains the cell's own model from the cell's own seeded weights and batch pool
 with the cell's optimizer (``benchmark/families/afmoe.py``), one jitted step,
 and reads every expert layer's load over the router's full width from the
 ``load`` the layer sows. One JSON line a step: the loss, and per expert layer
-the rows of the experts held here, of the fullest of the ``width / held``
-ranks a deployment would have (any of them could be this chip), and of the
-fullest expert. ``--rows-bound`` overrides the rows a pass computes (default
-tokens x top_k / 2: one pass nearly always, so that the step's time does not
-follow the loads it reports). Needs the TPU at the cell's size.
+the rows of the experts held here, the passes they took (``passes``: the
+first keeps what its backward reads, each one more is computed twice), the
+rows of the fullest of the ``width / held`` ranks a deployment would have
+(any of them could be this chip), and of the fullest expert. ``--rows-bound``
+overrides the rows a pass computes (default tokens x top_k / 2: one pass
+nearly always, so that the step's time does not follow the loads it reports).
+Needs the TPU at the cell's size.
 """
 
 import argparse
@@ -47,7 +49,7 @@ def main(argv=None):
     import jax.numpy as jnp
     import optax
 
-    from autodist_tpu.models import afmoe
+    from autodist_tpu.models import afmoe, moe
     from autodist_tpu.models.common import fused_lm_head_nll
     from benchmark import harness
 
@@ -68,7 +70,8 @@ def main(argv=None):
                                            return_hidden=True,
                                            mutable=["intermediates"])
         return (fused_lm_head_nll(h, params, targets).mean() + bias_term,
-                afmoe.sown_loads(sown["intermediates"]))
+                (afmoe.sown_loads(sown["intermediates"]),
+                 moe.sown_passes(sown["intermediates"])))
 
     assumed = config["assumed"]
     if args.balance is not None:
@@ -87,10 +90,10 @@ def main(argv=None):
         optimizer = built.optimizer
 
         def step(params, opt_state, batch):
-            (loss, loads), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            (loss, sown), grads = jax.value_and_grad(loss_fn, has_aux=True)(
                 params, batch)
             updates, opt_state = optimizer.update(grads, opt_state, params)
-            return optax.apply_updates(params, updates), opt_state, loss, loads
+            return optax.apply_updates(params, updates), opt_state, loss, sown
 
         step = jax.jit(step, donate_argnums=(0, 1))
         params, opt_state = built.params, optimizer.init(built.params)
@@ -98,8 +101,8 @@ def main(argv=None):
         for i in range(args.steps):
             batch = {k: jnp.asarray(v)
                      for k, v in built.pool[i % len(built.pool)].items()}
-            params, opt_state, loss, loads = step(params, opt_state, batch)
-            loads = jax.device_get(loads)
+            params, opt_state, loss, sown = step(params, opt_state, batch)
+            loads, passes = jax.device_get(sown)
             ranks = loads.reshape(loads.shape[0], width // held, held).sum(-1)
             worst = max(worst, int(ranks.max()))
             if i % args.every == 0 or i == args.steps - 1:
@@ -108,6 +111,7 @@ def main(argv=None):
                     "loss": float(loss), "rows_bound": bound,
                     "held_rows": [int(x) for x in
                                   ranks[:, cfg.first_expert_held // held]],
+                    "passes": [int(x) for x in passes],
                     "fullest_rank_rows": [int(x) for x in ranks.max(-1)],
                     "fullest_expert_rows": [int(x) for x in loads.max(-1)],
                     "fullest_rank_so_far": worst}), flush=True)
