@@ -32,14 +32,23 @@ __all__ = ["AutoDist", "get_default_autodist", "ResourceSpec", "train",
            "__version__"]
 
 
+_LAZY = {"AutoDist": "autodist", "get_default_autodist": "autodist",
+         "ResourceSpec": "resource_spec", "train": "training"}
+
+
 def __getattr__(name):  # PEP 562 lazy imports to keep `import autodist_tpu` light
-    if name in ("AutoDist", "get_default_autodist"):
-        from autodist_tpu import autodist
-        return getattr(autodist, name)
-    if name == "ResourceSpec":
-        from autodist_tpu.resource_spec import ResourceSpec
-        return ResourceSpec
-    if name == "train":
-        from autodist_tpu.training import train
-        return train
-    raise AttributeError(f"module 'autodist_tpu' has no attribute {name!r}")
+    if name not in _LAZY:
+        raise AttributeError(f"module 'autodist_tpu' has no attribute {name!r}")
+    import importlib
+    import sys
+    import time
+    module_name = f"autodist_tpu.{_LAZY[name]}"
+    t0 = None if module_name in sys.modules else time.perf_counter()
+    module = importlib.import_module(module_name)
+    if t0 is not None:
+        # The heavy imports (jax, flax, optax behind `autodist`) happen here:
+        # their seconds go to the set-up ledger.
+        from autodist_tpu import telemetry
+        with telemetry.phase("setup.import_s", since=t0):
+            pass
+    return getattr(module, name)

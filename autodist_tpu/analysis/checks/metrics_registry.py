@@ -13,7 +13,8 @@ rules, so a typo'd selector silently disables online retuning.
 
 GL009 makes the name vocabulary itself a checked registry (the GL007 move,
 applied to metrics): it harvests every ``counter("…")`` / ``gauge("…")`` /
-``histogram("…")`` / ``span("…")`` call across the WHOLE program into a
+``histogram("…")`` / ``span("…")`` / ``phase("…")`` (a set-up phase books
+the counter of its name) call across the WHOLE program into a
 producer registry — f-string names contribute prefix patterns
 (``f"train.attr.{phase}"`` books ``train.attr.*``), string parameter
 defaults are substituted (``metric_prefix="data"`` books
@@ -41,7 +42,7 @@ from typing import Dict, List, Optional, Set, Tuple
 from autodist_tpu.analysis import callgraph
 from autodist_tpu.analysis.core import Context, Finding, register_program
 
-_PRODUCER_FNS = {"counter", "gauge", "histogram", "span"}
+_PRODUCER_FNS = {"counter", "gauge", "histogram", "span", "phase"}
 _REG_TOKENS = {"reg", "registry", "metrics"}
 _DOC_PATH = "docs/usage/observability.md"
 _NAME_RE = re.compile(r"^[a-z][a-z0-9_]*(\.[a-z0-9_*]+)+$")
@@ -262,7 +263,7 @@ def check_metric_registry(program, ctx: Context) -> List[Finding]:
     """GL009 — metric/event-name registry (see the module docstring).
 
     The producer registry is generated from the program itself — every
-    ``counter``/``gauge``/``histogram``/``span`` first-argument literal,
+    ``counter``/``gauge``/``histogram``/``span``/``phase`` first-argument literal,
     with f-string sites contributing ``prefix.*`` patterns — so a metric is
     "registered" by being booked, never by being listed twice. Consumers
     (alert-rule ``metric`` selectors, registry ``.get("…")`` lookups in the

@@ -17,7 +17,6 @@ Surface parity with reference ``autodist/autodist.py``:
 """
 
 import contextlib
-import time
 from typing import Any, Callable, Optional, Sequence, Union
 
 from autodist_tpu import const, telemetry
@@ -103,13 +102,14 @@ class AutoDist:
             return self._strategy
         # Once a build: its seconds go to the registry whether or not
         # telemetry is on (set-up is paid on every fresh machine and restart).
-        t0 = time.perf_counter()
-        with telemetry.span("setup.strategy_build_s"):
+        with telemetry.phase("setup.strategy_build_s"):
             if self.is_chief:
                 self._strategy = self._strategy_builder.build(
                     model_spec, self._resource_spec)
-                path = self._strategy.serialize()
-                logging.info("Built strategy %s -> %s", self._strategy.id, path)
+                with telemetry.phase("setup.strategy_write_s"):
+                    path = self._strategy.serialize()
+                    logging.info("Built strategy %s -> %s",
+                                 self._strategy.id, path)
             else:
                 strategy_id = const.ENV.AUTODIST_STRATEGY_ID.val
                 if not strategy_id:
@@ -118,8 +118,6 @@ class AutoDist:
                         "coordinator must ship the chief's strategy id")
                 self._strategy = Strategy.deserialize(strategy_id)
                 logging.info("Loaded strategy %s (worker)", strategy_id)
-        telemetry.counter("setup.strategy_build_s").inc(
-            time.perf_counter() - t0)
         return self._strategy
 
     def _compile(self, model_spec: ModelSpec) -> Strategy:
@@ -382,12 +380,16 @@ class AutoDist:
         Async strategies: the ``step`` closure is one worker's loop (the reference
         ran one such loop per process); the worker pool is sized by the cluster —
         one slot per launched process, or a single slot for single-node runs (an
-        in-process phantom worker that never steps would deadlock the gate)."""
-        runner = self.create_distributed_session(
-            loss_fn, params, optimizer, example_batch, sparse_names, has_aux,
-            accumulation_steps=accumulation_steps, batch_size=batch_size,
-            zero=zero, health=health, tune=tune)
-        state = runner.init(params)
+        in-process phantom worker that never steps would deadlock the gate).
+
+        All of the call is the set-up phase ``setup.function_s`` (model spec,
+        strategy, plan, mesh, the first state placed)."""
+        with telemetry.phase("setup.function_s"):
+            runner = self.create_distributed_session(
+                loss_fn, params, optimizer, example_batch, sparse_names,
+                has_aux, accumulation_steps=accumulation_steps,
+                batch_size=batch_size, zero=zero, health=health, tune=tune)
+            state = runner.init(params)
 
         def step(batch, fetches=None):
             nonlocal state

@@ -721,6 +721,7 @@ def balance_expert_bias(model: nn.Module, params, batches, coeffs):
     all tokens, which every token's scores share); a trained one is held
     level by this rule. A falling ``coeffs`` brings the first to the second's
     loads in tens of forward passes."""
+    from autodist_tpu import telemetry
     names = _expert_blocks(params)
 
     @jax.jit
@@ -730,13 +731,16 @@ def balance_expert_bias(model: nn.Module, params, batches, coeffs):
         return [params[name]["moe"]["expert_bias"] + d - d.mean()
                 for name, d in zip(names, delta)]
 
-    for i, coeff in enumerate(coeffs):
-        # fenced: the host must not run passes ahead of the device (each holds
-        # a forward's activations)
-        biases = jax.block_until_ready(
-            moved(params, batches[i % len(batches)], jnp.float32(coeff)))
-        params = dict(params)
-        for name, bias in zip(names, biases):
-            params[name] = dict(params[name], moe=dict(params[name]["moe"],
-                                                       expert_bias=bias))
+    # Once a build, and tens of fenced forward passes: a set-up phase.
+    with telemetry.phase("setup.expert_bias_balance_s"):
+        for i, coeff in enumerate(coeffs):
+            # fenced: the host must not run passes ahead of the device (each
+            # holds a forward's activations)
+            biases = jax.block_until_ready(
+                moved(params, batches[i % len(batches)], jnp.float32(coeff)))
+            params = dict(params)
+            for name, bias in zip(names, biases):
+                params[name] = dict(params[name], moe=dict(
+                    params[name]["moe"], expert_bias=bias))
+        telemetry.counter("setup.expert_bias_passes").inc(len(coeffs))
     return params

@@ -14,6 +14,8 @@ trace-time metadata: the compiled arithmetic is unchanged.
 import jax
 from jax.experimental import pallas as pl
 
+from autodist_tpu import telemetry
+
 # Every kernel this package lowers, by the name its device events carry
 # (the benchmark's kernel readers and tests/test_device_names.py lean on these).
 KERNEL_NAMES = ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq", "flash_carry",
@@ -28,6 +30,11 @@ def named_pallas_call(name: str, kernel, **kwargs):
     if name not in KERNEL_NAMES:
         raise ValueError(f"kernel name {name!r} is not in KERNEL_NAMES")
     call = pl.pallas_call(kernel, name=name, **kwargs)
+    # Runs when the call site is traced (every site is traced and lowered
+    # again, which set-up pays for), never in a step: the set-up ledger's
+    # count of traced kernel call sites, all and by kernel.
+    telemetry.counter("jit.kernel_call_sites").inc()
+    telemetry.counter(f"jit.kernel_call_sites.{name}").inc()
 
     def run(*args):
         with jax.named_scope(name):

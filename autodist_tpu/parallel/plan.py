@@ -18,7 +18,6 @@ SPMD partitioner insert the collectives:
 
 import collections
 import dataclasses
-import time
 from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
@@ -95,11 +94,8 @@ class ShardingPlan:
         """The plan of a compiled strategy. Once a build: its seconds are
         booked as ``setup.plan_build_s`` whether or not telemetry is on."""
         from autodist_tpu import telemetry
-        t0 = time.perf_counter()
-        with telemetry.span("setup.plan_build_s"):
-            plan = cls._from_strategy(strategy, model_spec)
-        telemetry.counter("setup.plan_build_s").inc(time.perf_counter() - t0)
-        return plan
+        with telemetry.phase("setup.plan_build_s"):
+            return cls._from_strategy(strategy, model_spec)
 
     @classmethod
     def _from_strategy(cls, strategy, model_spec: ModelSpec) -> "ShardingPlan":

@@ -316,10 +316,8 @@ class DistributedRunner:
         partitioned ones are zero-padded to their physical storage shape here.
         Every call books its seconds as ``setup.state_place_s`` and counts in
         ``setup.state_place_calls``, whether or not telemetry is on."""
-        t0 = time.perf_counter()
-        with telemetry.span("setup.state_place_s"):
+        with telemetry.phase("setup.state_place_s"):
             state = self._place_state(params)
-        telemetry.counter("setup.state_place_s").inc(time.perf_counter() - t0)
         telemetry.counter("setup.state_place_calls").inc()
         return state
 
@@ -475,8 +473,12 @@ class DistributedRunner:
 
     def _build_step(self, fetch_fn: Optional[Callable] = None):
         donate = (0,) if self._donate else ()
+        step_fn = self._make_step_body(fetch_fn)
+        # run, compiled_step and the cost probe all trace and lower this one
+        # function: its stages are the set-up ledger's jit.step.*.
+        compile_cache.register_step_programs(step_fn.__name__)
         jitted = jax.jit(
-            self._make_step_body(fetch_fn),
+            step_fn,
             in_shardings=(self._state_shardings, None),
             out_shardings=(self._state_shardings, None),
             donate_argnums=donate,
@@ -505,6 +507,7 @@ class DistributedRunner:
             return state, (losses, auxes, fetched, bundles)
 
         donate = (0,) if self._donate else ()
+        compile_cache.register_step_programs(many_fn.__name__)
         jitted = jax.jit(
             many_fn,
             in_shardings=(self._state_shardings, None),
@@ -799,9 +802,13 @@ class DistributedRunner:
         the once-per-step optimizer update, accepted because the gradient
         pass dominates any program accumulation is worth using on."""
         try:
-            with self.mesh:
-                compiled = jitted.lower(*args).compile()
-            cost = compiled.cost_analysis()
+            # Once a program (the first dispatch of a signature with the
+            # profiling plane armed, or the autotuner's probe): what the
+            # re-lowering and the analysis cost goes to the set-up ledger.
+            with telemetry.phase("setup.cost_probe_s"):
+                with self.mesh:
+                    compiled = jitted.lower(*args).compile()
+                cost = compiled.cost_analysis()
             if isinstance(cost, (list, tuple)):
                 cost = cost[0] if cost else None
             if not cost:

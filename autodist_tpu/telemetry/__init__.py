@@ -68,6 +68,12 @@ costs one attribute check per span (gated in ``bench.py
 per train step (``bench.py --health-overhead`` gates the enabled side).
 """
 
+import time as _time
+
+# Before the package's own imports (numpy and the planes below are most of a
+# second): where ``setup.import_s`` starts, booked at the end of this file.
+_IMPORT_T0 = _time.perf_counter()
+
 from autodist_tpu.telemetry import (alerts, history, memplane, openmetrics,
                                     reqtrace)
 from autodist_tpu.telemetry.alerts import (AlertEngine, AlertHalt,
@@ -101,12 +107,16 @@ from autodist_tpu.telemetry.profiling import (peak_spec, profile_document,
 from autodist_tpu.telemetry.recorder import (FlightRecorder, build_manifest,
                                              get_recorder, maybe_record,
                                              set_recorder)
+from autodist_tpu.telemetry import phases
+from autodist_tpu.telemetry.phases import (format_setup_report, phase,
+                                           setup_report)
 from autodist_tpu.telemetry.spans import (clear, disable, enable, enabled,
                                           snapshot_spans, span, traced)
 
 __all__ = [
     "span", "traced", "enable", "disable", "enabled", "clear",
     "snapshot_spans",
+    "phase", "phases", "setup_report", "format_setup_report",
     "Counter", "Gauge", "Histogram", "Registry",
     "counter", "gauge", "histogram", "registry", "snapshot",
     "event", "events",
@@ -127,3 +137,7 @@ __all__ = [
     "MetricsHistory",
     "MetricsExporter", "quantile", "merge_histograms",
 ]
+
+
+# The set-up ledger's first entry: this package's own import.
+phases.package_imported(_IMPORT_T0)

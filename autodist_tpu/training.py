@@ -164,185 +164,192 @@ def train(runner, params: PyTree,
     escalates to the existing :class:`telemetry.HealthHalt` /
     :class:`telemetry.AlertHalt`. See ``docs/usage/resilience.md``.
     """
-    if unroll is None:
-        tuned = getattr(runner, "tuned_plan", None)
-        unroll = int(getattr(tuned, "unroll", 1) or 1)
-        if unroll > 1:
-            logging.info("train: adopting tuned plan unroll=%d (%s; pass "
-                         "unroll= explicitly to override)", unroll,
-                         getattr(tuned, "name", "tuned plan"))
-    if unroll < 1:
-        raise ValueError("unroll must be >= 1")
-    if prefetch_depth is None:
-        tuned = getattr(runner, "tuned_plan", None)
-        tuned_depth = int(getattr(tuned, "prefetch_depth", 0) or 0)
-        if tuned_depth > 0:
-            logging.info("train: adopting tuned plan prefetch_depth=%d "
-                         "(pass prefetch_depth= explicitly to override)",
-                         tuned_depth)
-            prefetch_depth = tuned_depth
-        else:
-            prefetch_depth = _prefetch.default_prefetch_depth()
-    prefetch_depth = max(0, int(prefetch_depth))
-    if eval_every and eval_batch is None:
-        raise ValueError("eval_every needs an eval_batch")
-    if is_chief is None:
-        is_chief = const.is_chief_process()
-    # Scrape endpoint: AUTODIST_METRICS_PORT attaches /metrics + /healthz to
-    # the trainer process too (PSServer/InferenceServer processes attach in
-    # their constructors; the process-global exporter binds once either way).
-    _openmetrics.maybe_serve()
-    # Sharded (multi-process SPMD) saves are collective: every process must
-    # participate — each writes the shards it owns; the Saver itself gates
-    # manifest/rotation to process 0. Chief-only gating remains for
-    # single-process programs (incl. async-PS roles, where each process is
-    # its own jax program).
-    save_participant = is_chief or jax.process_count() > 1
-    saver = Saver(max_to_keep=max_to_keep) if checkpoint_dir else None
-    prefix_base = f"{checkpoint_dir}/{checkpoint_name}" if checkpoint_dir else None
+    # Set-up's last phase: from here to the loop, whose first act is its first
+    # pull from the batch source (the second runner.init, the saver, the
+    # monitors).
+    with telemetry.phase("setup.train_enter_s"):
+        if unroll is None:
+            tuned = getattr(runner, "tuned_plan", None)
+            unroll = int(getattr(tuned, "unroll", 1) or 1)
+            if unroll > 1:
+                logging.info("train: adopting tuned plan unroll=%d (%s; pass "
+                             "unroll= explicitly to override)", unroll,
+                             getattr(tuned, "name", "tuned plan"))
+        if unroll < 1:
+            raise ValueError("unroll must be >= 1")
+        if prefetch_depth is None:
+            tuned = getattr(runner, "tuned_plan", None)
+            tuned_depth = int(getattr(tuned, "prefetch_depth", 0) or 0)
+            if tuned_depth > 0:
+                logging.info("train: adopting tuned plan prefetch_depth=%d "
+                             "(pass prefetch_depth= explicitly to override)",
+                             tuned_depth)
+                prefetch_depth = tuned_depth
+            else:
+                prefetch_depth = _prefetch.default_prefetch_depth()
+        prefetch_depth = max(0, int(prefetch_depth))
+        if eval_every and eval_batch is None:
+            raise ValueError("eval_every needs an eval_batch")
+        if is_chief is None:
+            is_chief = const.is_chief_process()
+        # Scrape endpoint: AUTODIST_METRICS_PORT attaches /metrics + /healthz to
+        # the trainer process too (PSServer/InferenceServer processes attach in
+        # their constructors; the process-global exporter binds once either way).
+        _openmetrics.maybe_serve()
+        # Sharded (multi-process SPMD) saves are collective: every process must
+        # participate — each writes the shards it owns; the Saver itself gates
+        # manifest/rotation to process 0. Chief-only gating remains for
+        # single-process programs (incl. async-PS roles, where each process is
+        # its own jax program).
+        save_participant = is_chief or jax.process_count() > 1
+        saver = Saver(max_to_keep=max_to_keep) if checkpoint_dir else None
+        prefix_base = f"{checkpoint_dir}/{checkpoint_name}" if checkpoint_dir else None
 
-    state = None
-    if saver is not None and resume:
-        latest = Saver.latest_checkpoint(checkpoint_dir, name=checkpoint_name)
-        if latest is not None:
-            state = saver.restore(latest, runner=runner)
-            logging.info("train: resumed from %s at step %d", latest,
-                         int(state.step))
-    if state is None:
-        state = runner.init(params)
+        state = None
+        if saver is not None and resume:
+            latest = Saver.latest_checkpoint(checkpoint_dir, name=checkpoint_name)
+            if latest is not None:
+                state = saver.restore(latest, runner=runner)
+                logging.info("train: resumed from %s at step %d", latest,
+                             int(state.step))
+        if state is None:
+            state = runner.init(params)
 
-    next_batch = batches if callable(batches) else None
-    batch_iter = iter(batches) if next_batch is None else None
+        next_batch = batches if callable(batches) else None
+        batch_iter = iter(batches) if next_batch is None else None
 
-    start = int(state.step)
-    if batch_iter is not None and start > 0:
-        # Resume with an iterable: fast-forward so step i still consumes batch i —
-        # replaying from item 0 would retrain on already-seen data and break the
-        # identical-resume contract.
-        logging.info("train: fast-forwarding batch iterator by %d consumed steps",
-                     start)
-        for _ in range(start):
+        start = int(state.step)
+        if batch_iter is not None and start > 0:
+            # Resume with an iterable: fast-forward so step i still consumes batch i —
+            # replaying from item 0 would retrain on already-seen data and break the
+            # identical-resume contract.
+            logging.info("train: fast-forwarding batch iterator by %d consumed steps",
+                         start)
+            for _ in range(start):
+                try:
+                    next(batch_iter)
+                except StopIteration:
+                    return state
+        monitor = health_monitor if health_monitor is not None \
+            else _health.HealthMonitor.from_env()
+        if monitor is not None and not log_every:
+            logging.warning("train: health monitors need log_every > 0 (the "
+                            "bundle readback rides log boundaries); disabling "
+                            "them for this run")
+            monitor = None
+        use_blocks = (unroll > 1 and getattr(runner, "supports_run_many", False)
+                      and not getattr(runner, "_is_remote_worker", False))
+        if unroll > 1 and not use_blocks:
+            logging.warning(
+                "train: unroll=%d requested but %s has no fused multi-step path "
+                "(async/remote regime); falling back to per-step dispatch",
+                unroll, type(runner).__name__)
+
+        def _finish(final_state: TrainState) -> TrainState:
+            # End-of-run attribution flush (the health monitors' PR 8 contract,
+            # re-established here): a final partial period — steps not a
+            # multiple of log_every, or a run shorter than one period — still
+            # reaches the series; require_steps drops a dispatch-less tail.
+            # BEFORE the final save: a multi-second synchronous checkpoint
+            # would otherwise land in the tail period's compute residual and
+            # inflate the profile's period-weighted step_s.
+            if _profiling.active():
+                _profiling.observe_period(int(final_state.step),
+                                          require_steps=True)
+            # End-of-run history flush (forced past the throttle): a run shorter
+            # than one min_interval_s window still leaves at least one sample —
+            # and its final alert tick — in the ring/shards. AFTER the closing
+            # observe_period so the sample carries the tail period's gauges;
+            # BEFORE the final save so a halt-action alert stops us with the
+            # state unsaved-but-LIVE on the exception, exactly like HealthHalt.
             try:
-                next(batch_iter)
-            except StopIteration:
-                return state
-    monitor = health_monitor if health_monitor is not None \
-        else _health.HealthMonitor.from_env()
-    if monitor is not None and not log_every:
-        logging.warning("train: health monitors need log_every > 0 (the "
-                        "bundle readback rides log boundaries); disabling "
-                        "them for this run")
-        monitor = None
-    use_blocks = (unroll > 1 and getattr(runner, "supports_run_many", False)
-                  and not getattr(runner, "_is_remote_worker", False))
-    if unroll > 1 and not use_blocks:
-        logging.warning(
-            "train: unroll=%d requested but %s has no fused multi-step path "
-            "(async/remote regime); falling back to per-step dispatch",
-            unroll, type(runner).__name__)
+                _history.maybe_sample(int(final_state.step), reason="final",
+                                      force=True)
+            except telemetry.AlertHalt as e:
+                e.state = final_state
+                raise
+            # Final save stays synchronous: train() returning means the state is
+            # durably on disk (save() joins any in-flight periodic write first).
+            if saver is not None and save_participant and int(final_state.step) > start:
+                with telemetry.span("train.checkpoint", final=True):
+                    saver.save(final_state, prefix_base, runner=runner)
+            if saver is not None:
+                saver.wait()
+            # Per-run profile store: with the attribution plane armed and
+            # AUTODIST_PROFILE_DIR set, the run's profile JSON (program costs +
+            # attribution series) lands on disk for adprof/costmodel.
+            _profiling.maybe_write_profile()
+            return final_state
 
-    def _finish(final_state: TrainState) -> TrainState:
-        # End-of-run attribution flush (the health monitors' PR 8 contract,
-        # re-established here): a final partial period — steps not a
-        # multiple of log_every, or a run shorter than one period — still
-        # reaches the series; require_steps drops a dispatch-less tail.
-        # BEFORE the final save: a multi-second synchronous checkpoint
-        # would otherwise land in the tail period's compute residual and
-        # inflate the profile's period-weighted step_s.
-        if _profiling.active():
-            _profiling.observe_period(int(final_state.step),
-                                      require_steps=True)
-        # End-of-run history flush (forced past the throttle): a run shorter
-        # than one min_interval_s window still leaves at least one sample —
-        # and its final alert tick — in the ring/shards. AFTER the closing
-        # observe_period so the sample carries the tail period's gauges;
-        # BEFORE the final save so a halt-action alert stops us with the
-        # state unsaved-but-LIVE on the exception, exactly like HealthHalt.
-        try:
-            _history.maybe_sample(int(final_state.step), reason="final",
-                                  force=True)
-        except telemetry.AlertHalt as e:
-            e.state = final_state
-            raise
-        # Final save stays synchronous: train() returning means the state is
-        # durably on disk (save() joins any in-flight periodic write first).
-        if saver is not None and save_participant and int(final_state.step) > start:
-            with telemetry.span("train.checkpoint", final=True):
-                saver.save(final_state, prefix_base, runner=runner)
-        if saver is not None:
-            saver.wait()
-        # Per-run profile store: with the attribution plane armed and
-        # AUTODIST_PROFILE_DIR set, the run's profile JSON (program costs +
-        # attribution series) lands on disk for adprof/costmodel.
-        _profiling.maybe_write_profile()
-        return final_state
+        # Recover-and-resume policy (parallel/recovery.py): under
+        # AUTODIST_HEALTH_ACTION=recover (or the alert-engine twin) the loop
+        # pushes the state into a bounded last-known-good ring at every HEALTHY
+        # log boundary, and an anomaly rolls back to the newest good snapshot
+        # and re-enters the loop — bounded by AUTODIST_RECOVER_MAX attempts
+        # before escalating to the existing halt.
+        recover_armed = (
+            (monitor is not None and monitor.config.action == "recover")
+            or str(const.ENV.AUTODIST_ALERT_ACTION.val) == "recover")
+        ring = None
+        if recover_armed:
+            # Ring entries must OWN their buffers: the sync runner's step
+            # DONATES its input state, so a bare reference would be deleted by
+            # the dispatch right after the push. One fused on-device copy per
+            # healthy boundary (sharding-preserving; recover is opt-in and log
+            # boundaries are sparse — the copy is the price of a rollback
+            # target that survives donation).
+            import jax.numpy as jnp
+            ring = _recovery.SnapshotRing(copy_fn=jax.jit(
+                lambda s: jax.tree_util.tree_map(jnp.copy, s)))
+        if ring is not None and batch_iter is not None:
+            logging.warning(
+                "train: recover action with an ITERABLE batch source — a "
+                "rollback cannot replay consumed batches, so the resumed loop "
+                "continues on the next unconsumed ones (pass a callable "
+                "batches(step) source for exact replay)")
 
-    # Recover-and-resume policy (parallel/recovery.py): under
-    # AUTODIST_HEALTH_ACTION=recover (or the alert-engine twin) the loop
-    # pushes the state into a bounded last-known-good ring at every HEALTHY
-    # log boundary, and an anomaly rolls back to the newest good snapshot
-    # and re-enters the loop — bounded by AUTODIST_RECOVER_MAX attempts
-    # before escalating to the existing halt.
-    recover_armed = (
-        (monitor is not None and monitor.config.action == "recover")
-        or str(const.ENV.AUTODIST_ALERT_ACTION.val) == "recover")
-    ring = None
-    if recover_armed:
-        # Ring entries must OWN their buffers: the sync runner's step
-        # DONATES its input state, so a bare reference would be deleted by
-        # the dispatch right after the push. One fused on-device copy per
-        # healthy boundary (sharding-preserving; recover is opt-in and log
-        # boundaries are sparse — the copy is the price of a rollback
-        # target that survives donation).
-        import jax.numpy as jnp
-        ring = _recovery.SnapshotRing(copy_fn=jax.jit(
-            lambda s: jax.tree_util.tree_map(jnp.copy, s)))
-    if ring is not None and batch_iter is not None:
-        logging.warning(
-            "train: recover action with an ITERABLE batch source — a "
-            "rollback cannot replay consumed batches, so the resumed loop "
-            "continues on the next unconsumed ones (pass a callable "
-            "batches(step) source for exact replay)")
+        def _run_attempt(attempt_state: TrainState) -> TrainState:
+            """One pass of the loop from ``attempt_state``'s own step — the
+            source and its feed are (re)built per attempt so a rollback's replay
+            pulls the rolled-back step range, not the crashed attempt's
+            readahead."""
+            source = _BatchSource(next_batch, batch_iter, int(attempt_state.step),
+                                  steps)
+            if use_blocks:
+                periods = (save_every if saver is not None else 0, eval_every)
+                pull = lambda: source.pull_block(unroll, periods)  # noqa: E731
+                shard = runner.shard_block
+            else:
+                pull = source.pull
+                # Async/remote regimes prefetch host batches only.
+                shard = getattr(runner, "shard_batch", None)
+                if not callable(shard) or getattr(runner, "_is_remote_worker",
+                                                  False):
+                    shard = None
+            # Async input pipeline: with prefetch_depth > 0 a background producer
+            # pulls host batches (or cadence-clipped blocks of them) AND applies
+            # the feed remapping (shard_batch, or shard_block = stacking + async
+            # device_put) up to `depth` items ahead, so the loop's
+            # train.data_wait span measures only the residual queue wait. The
+            # producer books data.producer_wait/queue_depth, keeping a slow
+            # loader visible.
+            producer = _prefetch.PrefetchProducer(
+                pull, shard, depth=prefetch_depth,
+                workers=_prefetch.default_prefetch_workers(),
+                name="train-feed") if prefetch_depth > 0 else None
+            try:
+                return _loop(
+                    runner, attempt_state, source, producer, pull, use_blocks,
+                    steps, saver, prefix_base, save_participant, save_every,
+                    async_save, log_every, batch_size, on_metrics, eval_every,
+                    eval_batch, eval_fn, on_eval, monitor, ring)
+            finally:
+                if producer is not None:
+                    producer.close()
 
-    def _run_attempt(attempt_state: TrainState) -> TrainState:
-        """One pass of the loop from ``attempt_state``'s own step — the
-        source and its feed are (re)built per attempt so a rollback's replay
-        pulls the rolled-back step range, not the crashed attempt's
-        readahead."""
-        source = _BatchSource(next_batch, batch_iter, int(attempt_state.step),
-                              steps)
-        if use_blocks:
-            periods = (save_every if saver is not None else 0, eval_every)
-            pull = lambda: source.pull_block(unroll, periods)  # noqa: E731
-            shard = runner.shard_block
-        else:
-            pull = source.pull
-            # Async/remote regimes prefetch host batches only.
-            shard = getattr(runner, "shard_batch", None)
-            if not callable(shard) or getattr(runner, "_is_remote_worker",
-                                              False):
-                shard = None
-        # Async input pipeline: with prefetch_depth > 0 a background producer
-        # pulls host batches (or cadence-clipped blocks of them) AND applies
-        # the feed remapping (shard_batch, or shard_block = stacking + async
-        # device_put) up to `depth` items ahead, so the loop's
-        # train.data_wait span measures only the residual queue wait. The
-        # producer books data.producer_wait/queue_depth, keeping a slow
-        # loader visible.
-        producer = _prefetch.PrefetchProducer(
-            pull, shard, depth=prefetch_depth,
-            workers=_prefetch.default_prefetch_workers(),
-            name="train-feed") if prefetch_depth > 0 else None
-        try:
-            return _loop(
-                runner, attempt_state, source, producer, pull, use_blocks,
-                steps, saver, prefix_base, save_participant, save_every,
-                async_save, log_every, batch_size, on_metrics, eval_every,
-                eval_batch, eval_fn, on_eval, monitor, ring)
-        finally:
-            if producer is not None:
-                producer.close()
-
+    # Set-up ends here: the loop's first act is its first pull from the batch
+    # source. The ledger freezes its sum and logs its one line.
+    telemetry.phases.mark_setup_end()
     attempt = 0
     last_fail_step = None
     while True:
