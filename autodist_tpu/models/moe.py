@@ -329,26 +329,64 @@ def _dispatch_rows_bwd(k, residuals, g):
 _dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _take_rows(x, token, n_tokens):
-    """``[T, d] -> [R, d]``: compacted row ``i`` is token ``token[i]``. The
-    transpose adds a token's rows up in float32 (autodiff would add them in
-    the rows' dtype)."""
-    return jnp.take(x, token, axis=0)
+def _rows_at(x, token):
+    """``x[token]`` for tokens that are in bounds by construction (a slot
+    index over ``top_k``): ``jnp.take`` would pass over the result once more
+    to fill what an index out of bounds names."""
+    return x.at[token].get(mode="promise_in_bounds")
 
 
-def _take_rows_fwd(x, token, n_tokens):
-    return jnp.take(x, token, axis=0), (token,)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _take_rows(x, token, count, plan, n_tokens):
+    """``[T, d] -> [R, d]``: compacted row ``i`` is token ``token[i]``,
+    XLA's gather (at this chip's bandwidth, rows not held included: PERF.md
+    §6, PR 34). The transpose adds a token's held rows ``i < count`` up in
+    float32 (autodiff would add them in the rows' dtype) in the kernel of
+    ``ops/moe_rows.py``, where XLA would scatter-add every row under repeated
+    indices; ``plan`` is the walk it shares with the combine."""
+    return _rows_at(x, token)
+
+
+def _take_rows_fwd(x, token, count, plan, n_tokens):
+    return _rows_at(x, token), (token, count, plan)
 
 
 def _take_rows_bwd(n_tokens, residuals, g):
-    (token,) = residuals
-    dx = jnp.zeros((n_tokens, g.shape[-1]), jnp.float32).at[token].add(
-        g.astype(jnp.float32))
-    return dx.astype(g.dtype), None
+    from autodist_tpu.ops.moe_rows import moe_rows_combine
+    token, count, plan = residuals
+    dx = moe_rows_combine(g, None, token, count, n_tokens, plan, dtype=g.dtype)
+    return dx, None, None, None
 
 
 _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _add_rows(out, weight, token, count, plan, n_tokens):
+    """``[R, d] -> [T, d]`` float32: token ``t`` is the sum of ``weight[r] *
+    out[r]`` over its held rows ``r < count``, added in float32 in row order
+    by a kernel that fetches only those rows (``ops/moe_rows.py``) where XLA
+    would scatter-add all ``R`` under repeated indices. The transpose is
+    XLA's gather of the cotangent by ``token``, scaled for ``out`` and dotted
+    with ``out`` for ``weight`` (zero past ``count``, where ``weight`` is)."""
+    from autodist_tpu.ops.moe_rows import moe_rows_combine
+    return moe_rows_combine(out, weight, token, count, n_tokens, plan)
+
+
+def _add_rows_fwd(out, weight, token, count, plan, n_tokens):
+    return (_add_rows(out, weight, token, count, plan, n_tokens),
+            (out, weight, token))
+
+
+def _add_rows_bwd(n_tokens, residuals, g):
+    out, weight, token = residuals
+    taken = _rows_at(g, token)
+    d_weight = jnp.sum(taken * out.astype(jnp.float32), axis=-1)
+    return ((weight[:, None] * taken).astype(out.dtype),
+            d_weight.astype(weight.dtype), None, None, None)
+
+
+_add_rows.defvjp(_add_rows_fwd, _add_rows_bwd)
 
 
 def _gated_experts(rows, gate, up, down, group_sizes):
@@ -364,20 +402,24 @@ def _held_pass(c, x, weights, gate, up, down, perm, offsets, top_k: int,
     """Pass ``c`` over the held rows: the part of the result that the sorted
     rows ``[c * bound, (c + 1) * bound)`` give, ``[T, d]`` float32. ``perm``
     holds the held rows' flat slots first, by expert; ``offsets [H + 1]`` the
-    first sorted row of each held expert and the end of the last."""
-    n_tokens, d = x.shape
+    first sorted row of each held expert and the end of the last. Of the
+    ``bound`` rows the first ``count`` are held: the combine and the
+    dispatch's transpose (``ops/moe_rows.py``) move those and no other."""
+    from autodist_tpu.ops.moe_rows import combine_plan
+    n_tokens = x.shape[0]
     first = c * bound
     kept = jax.lax.dynamic_slice(perm, (first,), (bound,))
     token = kept // top_k
     sizes = jnp.diff(jnp.clip(offsets, first, first + bound))
-    weight = jnp.where(first + jnp.arange(bound) < offsets[-1],
-                       jnp.take(weights, kept), 0.0)
+    count = jnp.clip(offsets[-1] - first, 0, bound)
+    weight = jnp.where(jnp.arange(bound) < count, jnp.take(weights, kept), 0.0)
+    with jax.named_scope("moe.route"):
+        plan = combine_plan(token, count, n_tokens)
     with jax.named_scope("moe.dispatch"):
-        rows = _take_rows(x, token, n_tokens)
+        rows = _take_rows(x, token, count, plan, n_tokens)
     out = _gated_experts(rows, gate, up, down, sizes)
     with jax.named_scope("moe.combine"):
-        return jnp.zeros((n_tokens, d), jnp.float32).at[token].add(
-            weight[:, None] * out.astype(jnp.float32))
+        return _add_rows(out, weight, token, count, plan, n_tokens)
 
 
 # One trace of the pass for every layer and loop of a program that share its
@@ -489,6 +531,11 @@ def routed_experts(x, scores, gate, up, down, bias=None, *, top_k: int,
     passes = -(-n_slots // rows_bound)       # at most; a step takes what it needs
     telemetry.gauge("moe.passes_max").set(passes)
     telemetry.gauge("moe.passes_kept").set(1)   # pass 0; the others recompute
+    # of a pass's four row operations (dispatch, combine, their transposes),
+    # how many a kernel of ops/moe_rows.py carries: the share's two sums of a
+    # token's rows; its two gathers are XLA's, as all four of the whole
+    # bank's are (a permutation has no rows to add up)
+    telemetry.gauge("moe.rows.by_kernel").set(0 if whole else 2)
     if whole:
         with jax.named_scope("moe.route"):
             r = route(scores, top_k, bias)

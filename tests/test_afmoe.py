@@ -300,6 +300,7 @@ def _kernels_and_loops(jaxpr, inside=False):
         loop = eqn.primitive.name == "while"
         if eqn.primitive.name == "pallas_call":
             yield str(eqn.params["name"]), inside
+            continue        # a kernel's own loops are not passes
         elif loop:
             first = eqn.invars[eqn.params["cond_nconsts"]
                                + eqn.params["body_nconsts"]]
@@ -313,6 +314,7 @@ def _kernels_and_loops(jaxpr, inside=False):
 
 @pytest.mark.parametrize("what,outside,inside", [
     ("moe_gmm_fwd", 3, 6), ("moe_gmm_bwd_dx", 3, 3), ("moe_gmm_bwd_dw", 3, 3),
+    ("moe_rows_combine", 2, 3), ("moe_rows_gather", 0, 0),
     ("while", None, [1, 1])])
 def test_the_first_pass_runs_once_and_only_the_passes_past_it_recompute(
         what, outside, inside):
@@ -320,7 +322,10 @@ def test_the_first_pass_runs_once_and_only_the_passes_past_it_recompute(
     three products and their six transposes sit outside every loop, once (its
     forward is not run again for the backward); the two loops, the forward's
     and the transpose's, start at pass 1, and the transpose's holds the three
-    products a second time beside their transposes."""
+    products a second time beside their transposes. A pass adds a token's
+    rows up in a kernel twice, in the combine and in the dispatch's transpose
+    (so twice outside, once in the forward's loop, twice in the transpose's);
+    its two gathers are XLA's."""
     x, scores, bias, bank = _layer_inputs()
     share = lambda x, gate, up, down: moe.routed_experts(  # noqa: E731
         x, scores, gate, up, down, bias, top_k=3,

@@ -302,7 +302,8 @@ def test_kernel_names_unchanged():
         "flash_fwd", "flash_bwd_dkv", "flash_bwd_dq", "flash_carry",
         "xent_fwd", "xent_bwd_dh", "xent_bwd_dw",
         "moe_gmm_fwd", "moe_gmm_bwd_dx", "moe_gmm_bwd_dw",
-        "short_conv_fwd", "short_conv_bwd")
+        "short_conv_fwd", "short_conv_bwd",
+        "moe_rows_gather", "moe_rows_combine")
 
 
 @_NEEDS_MESH

@@ -278,6 +278,32 @@ def test_grouped_matmul_fwd_bwd_compiles_at_the_lfm2_cell_shapes(chip, k, n):
         assert name in text
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("tokens,rows", [(8192, 8192), (16_384, 16_384)],
+                         ids=["trinity-8192x8192", "lfm2-16384x16384"])
+def test_row_kernels_compile_at_the_two_share_cells_shapes(chip, tokens, rows,
+                                                           dtype):
+    """One pass of one chip's share at d 2,048 (the rows of the two cases
+    above): the gather by token (one DMA a held row from a source left in
+    HBM), and the combine with weights into float32 and without them into the
+    rows' dtype (the dispatch's transpose), each a Mosaic call under its own
+    name; float32 rows (8 KB slabs) as well as bfloat16 ones."""
+    from autodist_tpu.ops import moe_rows
+
+    def both(src, out, weight, token, count):
+        plan = moe_rows.combine_plan(token, count, tokens)
+        return (moe_rows.moe_rows_gather(src, token, count),
+                moe_rows.moe_rows_combine(out, weight, token, count, tokens, plan),
+                moe_rows.moe_rows_combine(out, None, token, count, tokens, plan,
+                                          dtype=dtype))
+
+    text = _compiled_text(both, chip, ((tokens, 2048), dtype),
+                          ((rows, 2048), dtype), ((rows,), jnp.float32),
+                          ((rows,), jnp.int32), ((), jnp.int32))
+    assert "tpu_custom_call" in text
+    assert "moe_rows_gather" in text and "moe_rows_combine" in text
+
+
 def test_flash_grouped_heads_of_64_compile_at_the_lfm2_cell_shape(chip):
     """lfm2-pretrain-8k's call: 2 x 8,192 x 32 query heads over 8 KV heads of
     64, causal, no window. K of a head is exactly ``_RESIDENT_KV_BYTES`` (the

@@ -154,7 +154,7 @@ def _olmoe_step_text() -> str:       # one lowering for the seven cases
 
 
 @pytest.mark.parametrize("name", [k for k in named_call.KERNEL_NAMES
-                                  if k.startswith("moe_")] + list(MOE_SCOPES))
+                                  if k.startswith("moe_gmm_")] + list(MOE_SCOPES))
 def test_olmoe_step_names_its_kernels_and_routing_scopes(name):
     """The grouped-matmul kernels by their device names, and the four scopes
     of the routed FFN innermost around their operations."""
@@ -199,12 +199,24 @@ def _lfm2_step_text() -> str:       # one lowering for the cases below
 
 @pytest.mark.parametrize("name", [k for k in named_call.KERNEL_NAMES
                                   if k.startswith("short_conv_")]
+                         + ["moe_rows_combine"]
                          + list(CONV_SCOPES) + list(MOE_SCOPES))
 def test_lfm2_step_names_its_conv_kernels_and_scopes(name):
     """The gated short convolution's two kernels by their device names
     (``pallas:short_conv_fwd`` / ``pallas:short_conv_bwd`` in a trace), the
-    three scopes of the conv operator, and the routed share's beside them."""
+    three scopes of the conv operator, and the routed share's beside them
+    with the kernel that adds a token's rows up (``pallas:moe_rows_combine``:
+    the combine and the dispatch's transpose)."""
     assert _scopes(_lfm2_step_text(), [name]) == {name}
+
+
+def test_a_shares_gathers_stay_xlas_and_the_whole_bank_names_no_row_kernel():
+    """``moe_rows_gather`` is in no step: the share's two gathers run at the
+    chip's bandwidth as XLA's (PERF.md §6, PR 34), and OLMoE's whole bank
+    moves permutations, which have no rows to add up."""
+    rows = [k for k in named_call.KERNEL_NAMES if k.startswith("moe_rows_")]
+    assert _scopes(_lfm2_step_text(), rows) == {"moe_rows_combine"}
+    assert _scopes(_olmoe_step_text(), rows) == set()
 
 
 def test_short_conv_gauges_are_set_when_the_operator_is_traced():

@@ -9,7 +9,9 @@ Trains the cell's own model from the cell's own seeded weights and batch pool
 with the cell's optimizer (``benchmark/families/afmoe.py``), one jitted step,
 and reads every expert layer's load over the router's full width from the
 ``load`` the layer sows. One JSON line a step: the loss, and per expert layer
-the rows of the experts held here, the passes they took (``passes``: the
+the rows of the experts held here and their share of ``rows_bound`` (what
+of a pass's buffer the row kernel of ``ops/moe_rows.py`` moves: it fetches no
+row past the held ones), the passes they took (``passes``: the
 first keeps what its backward reads, each one more is computed twice), the
 rows of the fullest of the ``width / held`` ranks a deployment would have
 (any of them could be this chip), and of the fullest expert. ``--rows-bound``
@@ -105,12 +107,16 @@ def main(argv=None):
             loads, passes = jax.device_get(sown)
             ranks = loads.reshape(loads.shape[0], width // held, held).sum(-1)
             worst = max(worst, int(ranks.max()))
+            held_rows = ranks[:, cfg.first_expert_held // held]
             if i % args.every == 0 or i == args.steps - 1:
                 print(json.dumps({
                     "seed": seed, "rate": rate, "warmup": warmup, "step": i + 1,
                     "loss": float(loss), "rows_bound": bound,
-                    "held_rows": [int(x) for x in
-                                  ranks[:, cfg.first_expert_held // held]],
+                    "held_rows": [int(x) for x in held_rows],
+                    # the share of a pass's buffer the row kernel moves
+                    # (ops/moe_rows.py fetches no row past the held ones)
+                    "held_rows_over_bound": [round(float(x) / bound, 4)
+                                             for x in held_rows],
                     "passes": [int(x) for x in passes],
                     "fullest_rank_rows": [int(x) for x in ranks.max(-1)],
                     "fullest_expert_rows": [int(x) for x in loads.max(-1)],

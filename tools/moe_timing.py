@@ -11,6 +11,7 @@ name); one JSON line a measurement on standard output.
     python tools/moe_timing.py                         # every phase
     python tools/moe_timing.py --phases gmm --tiles 256,512 --tiles 512,512
     python tools/moe_timing.py --phases flash,xent,flips
+    python tools/moe_timing.py --phases rows            # ~2 min
 
 Phases: ``gmm`` (forward, and dX + dW, at the gate/up and the down
 shape: 131,072 rows in 64 groups as a top-8 of random logits sorts them, against
@@ -20,7 +21,16 @@ forward + backward), ``xent`` (the fused head at 16,384 x 2,048 x 50,304),
 activations, one sequence through the published widths at depth 1),
 ``gmmcheck`` (``gmm`` and its gradients against ``ragged_dot`` in float32 at the
 highest precision, relative L2), ``gradcheck`` (the benchmark's check of the cell by parameter: each one's share
-of the squared difference from the reference's gradient and of its norm).
+of the squared difference from the reference's gradient and of its norm),
+``rows`` (one chip's share, not OLMoE's whole bank: the four row operations of
+``models/moe.py`` ``_held_pass`` (dispatch, combine and their transposes) as
+XLA's gather and scatter-add and as the two kernels of ``ops/moe_rows.py``,
+``[T, d]`` in and ``[R, d]`` out with every reshape and layout copy they
+bring, at both share cells' shapes: T 16,384 / R 16,384 / top-4 of 64 and T
+8,192 / R 8,192 / top-8 of 128, 8 experts held, d 2,048, the held rows those
+of a real top-k of random scores; ``plan`` is the sort by token the two
+combines of a pass share; ``ns_a_held_row`` is the busy time over the held
+rows).
 Needs the TPU: a time from the CPU's interpreter says nothing.
 """
 
@@ -251,9 +261,109 @@ def phase_gradcheck(_calls, _tiles):
         del grads
 
 
+# (cell, tokens, rows a pass, router width, top_k); 8 experts held, d 2,048
+ROW_SHAPES = (("lfm2-pretrain-8k", 16384, 16384, 64, 4),
+              ("trinity-pretrain-8k", 8192, 8192, 128, 8))
+
+
+def phase_rows(calls, _tiles):
+    """XLA's four row operations of one pass of a share against the two
+    kernels, operation by operation, the same arguments to both."""
+    import jax
+    import jax.numpy as jnp
+
+    from autodist_tpu.models import moe
+    from autodist_tpu.ops import moe_rows
+    f32, bf16 = jnp.float32, jnp.bfloat16
+
+    def scatter_add(values, token, n_tokens):
+        return jnp.zeros((n_tokens, values.shape[-1]), f32).at[token].add(values)
+
+    for cell, n_tokens, bound, width, top_k in ROW_SHAPES:
+        keys = jax.random.split(jax.random.PRNGKey(0), 6)
+        scores = jax.nn.sigmoid(jax.random.normal(keys[0], (n_tokens, width)))
+        r = moe.sigmoid_topk_route(scores, top_k, first_expert=0, n_held=8)
+        count = jnp.minimum(r.group_sizes.sum(), bound).astype(jnp.int32)
+        kept = r.perm[:bound]
+        token = kept // top_k
+        # as ``_held_pass`` had it before the kernels: zero past the held rows
+        weight = jnp.where(jnp.arange(bound) < count,
+                           jnp.take(r.weights.reshape(-1), kept), 0.0)
+        held = int(count)
+        x = jax.random.normal(keys[1], (n_tokens, D_MODEL), bf16)
+        out = jax.random.normal(keys[2], (bound, D_MODEL), bf16)
+        # the grouped matmul's dX: zero past the held rows
+        g_rows = jnp.where(jnp.arange(bound)[:, None] < count,
+                           jax.random.normal(keys[3], (bound, D_MODEL), bf16), 0)
+        g = jax.random.normal(keys[4], (n_tokens, D_MODEL), f32)
+        plan = jax.jit(lambda t, c: moe_rows.combine_plan(t, c, n_tokens))(
+            token, count)
+        yield {"phase": "rows", "cell": cell, "tokens": n_tokens,
+               "rows_bound": bound, "held_rows": held}
+
+        def scaled(taken, out, weight):
+            return ((weight[:, None] * taken).astype(out.dtype),
+                    jnp.sum(taken * out.astype(f32), axis=-1))
+
+        measurements = (
+            ("plan", "sort", lambda t, c: moe_rows.combine_plan(t, c, n_tokens),
+             (token, count)),
+            ("1 dispatch (bf16 gather)", "xla",
+             lambda x, t: jnp.take(x, t, axis=0), (x, token)),
+            ("1 dispatch (bf16 gather)", "kernel", moe_rows.moe_rows_gather,
+             (x, token, count)),
+            ("1 dispatch (bf16 gather)", "xla, indices promised in bounds",
+             lambda x, t: x.at[t].get(mode="promise_in_bounds"),
+             (x, token)),
+            ("2 combine (f32 scatter-add of weighted bf16 rows)", "xla",
+             lambda o, w, t: scatter_add(w[:, None] * o.astype(f32), t, n_tokens),
+             (out, weight, token)),
+            ("2 combine (f32 scatter-add of weighted bf16 rows)", "kernel",
+             lambda o, w, t, c, p: moe_rows.moe_rows_combine(
+                 o, w, t, c, n_tokens, p), (out, weight, token, count, plan)),
+            ("3 combine's transpose (f32 gather, scaled)", "xla",
+             lambda g, o, w, t: scaled(jnp.take(g, t, axis=0), o, w),
+             (g, out, weight, token)),
+            ("3 combine's transpose (f32 gather, scaled)", "kernel",
+             lambda g, o, w, t, c: scaled(moe_rows.moe_rows_gather(g, t, c), o, w),
+             (g, out, weight, token, count)),
+            ("3 combine's transpose (f32 gather, scaled)",
+             "xla, indices promised in bounds",
+             lambda g, o, w, t: scaled(
+                 g.at[t].get(mode="promise_in_bounds"), o, w),
+             (g, out, weight, token)),
+            ("4 dispatch's transpose (f32 scatter-add of bf16 rows)", "xla",
+             lambda gr, t: scatter_add(gr.astype(f32), t, n_tokens).astype(bf16),
+             (g_rows, token)),
+            ("4 dispatch's transpose (f32 scatter-add of bf16 rows)", "kernel",
+             lambda gr, t, c, p: moe_rows.moe_rows_combine(
+                 gr, None, t, c, n_tokens, p, dtype=bf16),
+             (g_rows, token, count, plan)),
+        )
+        results = {}
+        for what, impl, fn, args in measurements:
+            fn = jax.jit(fn)
+            results[what, impl] = jax.device_get(fn(*args))
+            busy, groups = device_ms(fn, args, calls)
+            yield {"phase": "rows", "cell": cell, "what": what, "impl": impl,
+                   "busy_ms": busy, "ns_a_held_row": busy * 1e6 / max(held, 1),
+                   "groups_ms": groups}
+        for what in sorted({w for w, impl in results if impl == "kernel"}):
+            got, want = results[what, "kernel"], results[what, "xla"]
+            # a gather's rows past the held ones differ by design: XLA
+            # fetches what they name, the kernel writes zeros
+            n = held if what[0] in "13" else None
+            worst = max(float(jnp.max(jnp.abs(
+                jnp.asarray(a[:n], f32) - jnp.asarray(b[:n], f32)), initial=0.0))
+                for a, b in zip(jax.tree_util.tree_leaves(got),
+                                jax.tree_util.tree_leaves(want)))
+            yield {"phase": "rows", "cell": cell, "what": what,
+                   "max_abs_kernel_minus_xla": worst}
+
+
 PHASES = {"gmmcheck": phase_gmmcheck, "gradcheck": phase_gradcheck,
           "gmm": phase_gmm, "flash": phase_flash, "xent": phase_xent,
-          "flips": phase_flips}
+          "flips": phase_flips, "rows": phase_rows}
 
 
 def main(argv=None):
