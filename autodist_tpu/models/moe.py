@@ -22,7 +22,9 @@ row count is (tokens x k); only the group boundaries are run-time data. No
 ``[tokens, experts, capacity]`` tensor exists and no token is dropped or padded.
 
 Last, what the sigmoid-routed families (``models/afmoe.py``,
-``models/lfm2_moe.py``) share and neither copies: :class:`GatedMLP`,
+``models/lfm2_moe.py``, ``models/nemotron_h.py``) share and none copies:
+:class:`GatedMLP` and :class:`PlainMLP`, the expert's form as an argument
+(:data:`EXPERT_FORMS`: gated-SiLU over three banks, ``relu2`` over two),
 :func:`sigmoid_routed_share` (one chip's share of the routed experts with the
 ``expert_bias`` leaf and its zero-valued loss term), :func:`balanced_optimizer`
 (the aux-loss-free balancing rule as an optax transformation) and
@@ -389,16 +391,31 @@ def _add_rows_bwd(n_tokens, residuals, g):
 _add_rows.defvjp(_add_rows_fwd, _add_rows_bwd)
 
 
-def _gated_experts(rows, gate, up, down, group_sizes):
+# What one expert computes, by name: ``W_down(silu(W_gate h) * W_up h)`` over
+# three banks, or ``W_down relu(W_up h)^2`` over two (no ``gate``: None).
+EXPERT_FORMS = ("gated_silu", "relu2")
+
+
+def relu2(x):
+    return jnp.square(nn.relu(x))
+
+
+def _expert_mlps(rows, gate, up, down, group_sizes, form="gated_silu"):
     from autodist_tpu.ops.grouped_matmul import gmm
+    if form not in EXPERT_FORMS or (gate is None) != (form == "relu2"):
+        raise ValueError(f"expert form {form!r} (valid: {EXPERT_FORMS}) with "
+                         f"{'no' if gate is None else 'a'} gate bank")
     with jax.named_scope("moe.experts"):
-        hidden = (nn.silu(gmm(rows, gate, group_sizes))
-                  * gmm(rows, up, group_sizes))
+        hidden = gmm(rows, up, group_sizes)
+        if form == "relu2":
+            hidden = relu2(hidden)
+        else:
+            hidden = nn.silu(gmm(rows, gate, group_sizes)) * hidden
         return gmm(hidden, down, group_sizes)
 
 
 def _held_pass(c, x, weights, gate, up, down, perm, offsets, top_k: int,
-               bound: int):
+               bound: int, form: str = "gated_silu"):
     """Pass ``c`` over the held rows: the part of the result that the sorted
     rows ``[c * bound, (c + 1) * bound)`` give, ``[T, d]`` float32. ``perm``
     holds the held rows' flat slots first, by expert; ``offsets [H + 1]`` the
@@ -417,7 +434,7 @@ def _held_pass(c, x, weights, gate, up, down, perm, offsets, top_k: int,
         plan = combine_plan(token, count, n_tokens)
     with jax.named_scope("moe.dispatch"):
         rows = _take_rows(x, token, count, plan, n_tokens)
-    out = _gated_experts(rows, gate, up, down, sizes)
+    out = _expert_mlps(rows, gate, up, down, sizes, form)
     with jax.named_scope("moe.combine"):
         return _add_rows(out, weight, token, count, plan, n_tokens)
 
@@ -426,13 +443,14 @@ def _held_pass(c, x, weights, gate, up, down, perm, offsets, top_k: int,
 # shapes (the pass number an argument, not a constant): each call of the plain
 # function traces its three kernels again, and a share calls it three times a
 # layer (pass 0, the forward's loop, the transpose's loop).
-_traced_once_pass = jax.jit(_held_pass, static_argnames=("top_k", "bound"))
+_traced_once_pass = jax.jit(_held_pass,
+                            static_argnames=("top_k", "bound", "form"))
 
 
-def _pass_of(perm, offsets, top_k: int, bound: int):
+def _pass_of(perm, offsets, top_k: int, bound: int, form: str):
     """``(c, x, weights, gate, up, down) -> pass c``, of one routing."""
     return functools.partial(_traced_once_pass, perm=perm, offsets=offsets,
-                             top_k=top_k, bound=bound)
+                             top_k=top_k, bound=bound, form=form)
 
 
 def _passes(held_rows, bound: int):
@@ -443,8 +461,9 @@ def _later_passes(run, y, operands, passes):
     return jax.lax.fori_loop(1, passes, lambda c, y: y + run(c, *operands), y)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
-def _held_passes(x, weights, gate, up, down, perm, offsets, top_k, bound):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def _held_passes(x, weights, gate, up, down, perm, offsets, top_k, bound,
+                 form="gated_silu"):
     """Every pass the held rows need, ``ceil(held rows / bound)`` of them, a
     number known only at run time: pass 0, then a loop over :func:`_held_pass`
     from 1 whose buffers are one pass's.
@@ -457,21 +476,24 @@ def _held_passes(x, weights, gate, up, down, perm, offsets, top_k, bound):
     loop of unknown length has no transpose of its own, and no buffer of
     unknown size to keep things in): the transpose runs as many again, each
     recomputed and transposed in turn."""
-    run, operands = _pass_of(perm, offsets, top_k, bound), (x, weights, gate, up, down)
+    run = _pass_of(perm, offsets, top_k, bound, form)
+    operands = (x, weights, gate, up, down)
     return _later_passes(run, run(0, *operands), operands,
                          _passes(offsets[-1], bound))
 
 
-def _held_passes_fwd(x, weights, gate, up, down, perm, offsets, top_k, bound):
-    run, operands = _pass_of(perm, offsets, top_k, bound), (x, weights, gate, up, down)
+def _held_passes_fwd(x, weights, gate, up, down, perm, offsets, top_k, bound,
+                     form):
+    run = _pass_of(perm, offsets, top_k, bound, form)
+    operands = (x, weights, gate, up, down)
     y, transpose_first = jax.vjp(functools.partial(run, 0), *operands)
     y = _later_passes(run, y, operands, _passes(offsets[-1], bound))
     return y, (transpose_first, operands, perm, offsets)
 
 
-def _held_passes_bwd(top_k, bound, residuals, g):
+def _held_passes_bwd(top_k, bound, form, residuals, g):
     transpose_first, operands, perm, offsets = residuals
-    run = _pass_of(perm, offsets, top_k, bound)
+    run = _pass_of(perm, offsets, top_k, bound, form)
 
     def one(c, total):
         _, transpose = jax.vjp(functools.partial(run, c), *operands)
@@ -479,9 +501,12 @@ def _held_passes_bwd(top_k, bound, residuals, g):
             lambda t, part: t + part.astype(t.dtype), total, transpose(g))
 
     # a token's rows add up over the passes in float32, as within one
-    first = tuple(part.astype(jnp.float32) for part in transpose_first(g))
+    # (a form without a gate bank has None in its place, here as there)
+    first = jax.tree_util.tree_map(lambda part: part.astype(jnp.float32),
+                                   transpose_first(g))
     total = jax.lax.fori_loop(1, _passes(offsets[-1], bound), one, first)
-    return (*(t.astype(a.dtype) for t, a in zip(total, operands)), None, None)
+    return (*jax.tree_util.tree_map(lambda t, a: t.astype(a.dtype), total,
+                                    operands), None, None)
 
 
 _held_passes.defvjp(_held_passes_fwd, _held_passes_bwd)
@@ -489,11 +514,14 @@ _held_passes.defvjp(_held_passes_fwd, _held_passes_bwd)
 
 def routed_experts(x, scores, gate, up, down, bias=None, *, top_k: int,
                    route: Callable[..., Route] = topk_route,
-                   first_expert: int = 0, rows_bound: Optional[int] = None):
-    """The local computation of a routed gated-SiLU FFN, one function of the
-    tokens, the router's scores over its full width and the bank of the
-    experts held here: top-k and sort, the grouped products over the experts
-    held, the weighted un-sort.
+                   first_expert: int = 0, rows_bound: Optional[int] = None,
+                   form: str = "gated_silu"):
+    """The local computation of a routed FFN, one function of the tokens, the
+    router's scores over its full width and the banks of the experts held
+    here: top-k and sort, the grouped products over the experts held, the
+    weighted un-sort. ``form`` names what an expert computes
+    (:data:`EXPERT_FORMS`): gated-SiLU over ``gate``, ``up``, ``down``, or
+    ``relu2``, ``down_e(relu(up_e x)^2)``, with ``gate`` None.
 
     x: ``[T, d]``; scores: ``[T, E]`` float32 (``route`` says what they are:
     :func:`topk_route` softmax probabilities, :func:`sigmoid_topk_route`
@@ -518,7 +546,7 @@ def routed_experts(x, scores, gate, up, down, bias=None, *, top_k: int,
     again for the backward."""
     from autodist_tpu import telemetry
     n_tokens, d = x.shape
-    n_held, width = int(gate.shape[0]), int(scores.shape[1])
+    n_held, width = int(up.shape[0]), int(scores.shape[1])
     n_slots = n_tokens * top_k
     whole = n_held == width and rows_bound is None
     rows_bound = n_slots if rows_bound is None else min(int(rows_bound), n_slots)
@@ -536,12 +564,14 @@ def routed_experts(x, scores, gate, up, down, bias=None, *, top_k: int,
     # token's rows; its two gathers are XLA's, as all four of the whole
     # bank's are (a permutation has no rows to add up)
     telemetry.gauge("moe.rows.by_kernel").set(0 if whole else 2)
+    # banks an expert has: 3 gated-SiLU (gate, up, down), 2 relu2 (up, down)
+    telemetry.gauge("moe.expert_form").set(3 if gate is not None else 2)
     if whole:
         with jax.named_scope("moe.route"):
             r = route(scores, top_k, bias)
         with jax.named_scope("moe.dispatch"):
             rows = _dispatch_rows(x, r.perm, r.inv_perm, top_k)
-        out = _gated_experts(rows, gate, up, down, r.group_sizes)
+        out = _expert_mlps(rows, gate, up, down, r.group_sizes, form)
         with jax.named_scope("moe.combine"):
             out = _permute_rows(out, r.inv_perm, r.perm)
             y = jnp.einsum("tk,tkd->td", r.weights,
@@ -555,11 +585,11 @@ def routed_experts(x, scores, gate, up, down, bias=None, *, top_k: int,
         weights = r.weights.reshape(-1)
     if passes == 1:
         y = _held_pass(0, x, weights, gate, up, down, r.perm, offsets, top_k,
-                       rows_bound)
+                       rows_bound, form)
     else:
         perm = jnp.pad(r.perm, (0, passes * rows_bound - n_slots))
         y = _held_passes(x, weights, gate, up, down, perm, offsets, top_k,
-                         rows_bound)
+                         rows_bound, form)
     return y, r.group_sizes
 
 
@@ -634,16 +664,31 @@ class GatedMLP(nn.Module):
         return _dense(h.shape[-1], self.dtype, "down")(hidden)
 
 
+class PlainMLP(nn.Module):
+    """``W_down act(W_up h)``, two matrices and no gate (``act``: ``relu2``,
+    Nemotron-H's): :class:`GatedMLP`'s sibling, a shared expert."""
+    width: int
+    dtype: Any
+    act: Callable = relu2
+
+    @nn.compact
+    def __call__(self, h):
+        hidden = self.act(_dense(self.width, self.dtype, "up")(h))
+        return _dense(h.shape[-1], self.dtype, "down")(hidden)
+
+
 def sigmoid_routed_share(module: nn.Module, h, *, router_width: int,
                          experts_held: int, first_expert_held: int, top_k: int,
                          d_expert: int, rows_bound: Optional[int],
-                         route: Callable[..., Route], dtype):
+                         route: Callable[..., Route], dtype,
+                         form: str = "gated_silu"):
     """This chip's share of a layer's sigmoid top-k routed experts, with its
     parameters made in ``module``'s own scope (call it from the compact
     method of the expert layer): ``router [d, router_width]``, ``expert_bias
     [router_width]`` (float32, zeros) and the banks ``gate``, ``up`` ``[held,
     d, d_expert]``, ``down [held, d_expert, d]`` of the experts
-    ``[first_expert_held, first_expert_held + experts_held)``.
+    ``[first_expert_held, first_expert_held + experts_held)`` (no ``gate``
+    under ``form="relu2"``: :data:`EXPERT_FORMS`).
 
     ``h`` is the float32 normalised input ``[B, S, d]``: the router's product
     and sigmoid read it as it is at ``HIGHEST`` precision, the experts its
@@ -661,9 +706,12 @@ def sigmoid_routed_share(module: nn.Module, h, *, router_width: int,
     router = module.param("router", _INIT, (d, router_width), jnp.float32)
     bias = module.param("expert_bias", nn.initializers.zeros, (router_width,),
                         jnp.float32)
+    if form not in EXPERT_FORMS:
+        raise ValueError(f"Unknown expert form {form!r}; valid: {EXPERT_FORMS}")
+    gated = form != "relu2"
     bank = [module.param(name, _INIT, shape, jnp.float32) for name, shape in (
         ("gate", (experts_held, d, d_expert)), ("up", (experts_held, d, d_expert)),
-        ("down", (experts_held, d_expert, d)))]
+        ("down", (experts_held, d_expert, d)))[0 if gated else 1:]]
     if module.is_initializing():
         # Shapes are all that init needs: no kernel is compiled for the
         # handful of positions it runs on.
@@ -671,12 +719,13 @@ def sigmoid_routed_share(module: nn.Module, h, *, router_width: int,
     tokens = h.reshape(b * s, d)
     scores = jax.nn.sigmoid(jnp.dot(tokens.astype(jnp.float32), router,
                                     precision=jax.lax.Precision.HIGHEST))
+    share = functools.partial(routed_experts, top_k=top_k, route=route,
+                              first_expert=first_expert_held,
+                              rows_bound=rows_bound, form=form)
     y, sizes = per_device(
-        functools.partial(routed_experts, top_k=top_k, route=route,
-                          first_expert=first_expert_held,
-                          rows_bound=rows_bound),
+        share if gated else lambda x, s, *rest: share(x, s, None, *rest),
         (tokens.astype(dtype), scores, *bank, bias),
-        batched=(True, True, False, False, False, False))
+        batched=(True, True) + (False,) * (len(bank) + 1))
     # The load every expert of the router's width received, absent ones
     # too: the choice is made here for all of them. (The same top_k as the
     # route's; the compiler keeps one.)

@@ -1,0 +1,396 @@
+"""Nemotron-H (``model_type`` ``nemotron_h``: NVIDIA's Nemotron-3-Nano-30B-A3B)
+— a decoder-only LM whose every layer is ONE mixer under a pre-norm and a
+residual: a Mamba-2 state-space layer (``M``), a sigmoid top-k mixture of
+``relu2`` experts beside a shared expert (``E``), of which this layer may
+hold one chip's share, or grouped-query attention (``*``), in the order
+``hybrid_override_pattern`` spells.
+
+The equations, from the published ``config.json`` and the Mamba-2 paper (Dao
+& Gu 2024; what the config does not carry is marked *assumed*, and listed
+with its source in ``benchmark/configs/nemotron-3-nano-30b-a3b.json``). ``T``
+tokens, width ``d``; Mamba-2: ``H`` heads of ``P`` (``d_inner = H P``), ``G``
+groups of state ``N``, ``K`` taps; attention: ``H_a`` query heads over
+``H_kv`` KV heads of ``head_dim``::
+
+    x0 = E[tokens]                                       (no scale)
+    per layer l, kind = pattern[l] in {M, E, *}:  x = x + mixer_kind(RMSNorm(x))
+      M:  [z | xBC | dt] = h.W_in [d, 2 d_inner + 2 G N + H]           (no bias; assumed: this order)
+          xBC_t = silu(sum_{j<K} w[:, j] * xBC_{t-(K-1)+j} + b)       depthwise, causal, w [d_inner + 2GN, K]
+          [x | B | C] = xBC   (d_inner | G N | G N);  x: H heads of P; B, C: G groups of N
+          dt = softplus(dt + dt_bias)  float32;  A = -exp(A_log)  a head;  a_t = dt_t A
+          per head h of group g = h // (H / G):
+            S_t = exp(a_t) S_{t-1} + dt_t x_t B_t^T      S in R^{P x N}, S_0 = 0 at every sequence's start
+            y_t = S_t C_t + D_h x_t
+          y = RMSNorm_grouped(y * silu(z))               groups of d_inner / G, one weight [d_inner]
+          out = y.W_out [d_inner, d]
+      E:  s = sigmoid(h.Wr [d, E]) in float32
+          chosen = top_k(s + b)         b = expert_bias [E], in the choice only, no gradient
+          w = s[chosen] / (sum over chosen of s + 1e-20) * route_scale          (norm_topk_prob; assumed eps)
+          out = W_down,s relu(W_up,s h)^2  (the shared expert, width d_shared)
+                + sum over chosen e of w_e . W_down,e relu(W_up,e h)^2          (mlp_hidden_act relu2: no gate, no bias)
+      *:  q, k, v = h.Wq [d, H_a*hd], h.Wk [d, H_kv*hd], h.Wv        (no bias, no q/k norm)
+          s_ij = q_i.k_j / sqrt(hd) for j <= i;  query head n reads KV head n // (H_a / H_kv)
+          out = softmax_j(s).v . Wo [H_a*hd, d]
+          no positional embedding: the family's published description (Nemotron-H) gives its
+          attention layers none, the state-space layers carry the order (assumed; ``config.json``
+          has a ``rope_theta`` this file does not read)
+    logits = RMSNorm_f(x).W_head  (untied);  loss = mean next-token cross-entropy
+    after each optimizer step, per expert layer, c_e = rows expert e received in the step:
+      delta = load_balance_coeff * sign(mean(c) - c_e);  b += delta - mean(delta)        (assumed)
+
+Initialisation (assumed, the Mamba-2 reference's): ``A_log = log(1..H)``, ``D``
+ones, ``dt_bias`` the inverse softplus of ``dt`` drawn log-uniformly in
+``[time_step_min, time_step_max]`` and floored at ``time_step_floor``, the
+convolution uniform in ``+-1/sqrt(K)``, every matrix normal(0.02), and under
+``rescale_prenorm_residual`` each mixer's output matrix divided by
+``sqrt(n_layers)``.
+
+**One chip's share**, **the expert bias on the normal path** and its start
+from the balancing rule alone are ``models/afmoe.py``'s, word for word, and
+the code is the same code: ``models/moe.py`` ``sigmoid_routed_share`` (told
+the expert's form, ``relu2``: two banks and no gate), ``balanced_optimizer``,
+``balance_expert_bias``. ``n_experts_routed`` is the router's width;
+``experts_held`` of them, from ``first_expert_held`` on, have their banks
+here; the router chooses over all of them and this layer adds its own
+experts' part, ``rows_bound`` held rows a pass. The shared expert, the
+Mamba-2 and attention layers and the router are what every rank computes
+alike.
+
+The scan is one operator, ``ops/ssd_scan.py`` ``ssd_scan``, chunked
+(``chunk_size`` positions a chunk, one ``[P, N]`` state a head carried between
+chunks), and the convolution before it ``ops/short_conv.py`` ``conv_silu``:
+both plain (``ssm_impl="xla"``) or each as two Pallas kernels (``"pallas"``). Under
+``remat`` every layer is a ``jax.checkpoint``: its backward recomputes its
+forward from the residual stream.
+
+Parameters and the residual stream are float32; the mixers compute in
+``dtype`` (under ``exact_first_layer`` layer 0's in float32 around a scan on
+``dtype`` operands: :class:`Mamba2` says why); ``dt``, ``a`` and every
+``exp`` of the scan are float32; the router
+reads the float32 normalised input at ``HIGHEST`` precision, as OLMoE's and
+AFMoE's do.
+"""
+
+import dataclasses
+import functools
+import math
+from typing import Any, Callable, Optional
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from autodist_tpu.models.common import RMSNorm
+from autodist_tpu.models.moe import (  # noqa: F401 — the mixture's, under this family's names
+    PlainMLP, _INIT, balance_expert_bias, balanced_optimizer as make_optimizer,
+    expert_loads, sigmoid_routed_share, sigmoid_topk_route, sown_loads)
+from autodist_tpu.models.transformer_lm import (  # noqa: F401 — synthetic_batch re-exported
+    causal_mask, dot_product_attention, synthetic_batch)
+from autodist_tpu.ops.short_conv import conv_silu
+from autodist_tpu.ops.ssd_scan import IMPLS as SSM_IMPLS, ssd_scan
+
+MAMBA, EXPERTS, ATTENTION = "M", "E", "*"
+PUBLISHED_PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+
+
+@dataclasses.dataclass(frozen=True)
+class NemotronHConfig:
+    """Defaults are Nemotron-3-Nano-30B-A3B's published sizes, every expert
+    held."""
+    vocab_size: int = 131072
+    d_model: int = 2688
+    pattern: str = PUBLISHED_PATTERN  # hybrid_override_pattern: M, E, * a layer
+    mamba_heads: int = 64
+    mamba_head_dim: int = 64
+    n_groups: int = 8
+    d_state: int = 128                # ssm_state_size
+    conv_kernel: int = 4
+    chunk: int = 128                  # chunk_size of the scan
+    n_heads: int = 32
+    n_kv_heads: int = 2
+    head_dim: int = 128
+    d_expert: int = 1856              # one routed expert's width
+    d_shared: int = 3712              # the shared expert's
+    n_experts_routed: int = 128       # the router's width
+    experts_held: int = 128           # experts whose banks live here ...
+    first_expert_held: int = 0        # ... from this one on
+    top_k: int = 6
+    rows_bound: Optional[int] = None  # held rows a pass computes; None: tokens x top_k
+    route_norm: bool = True           # norm_topk_prob
+    route_scale: float = 2.5          # routed_scaling_factor
+    route_eps: float = 1e-20          # in the normaliser of the chosen scores
+    load_balance_coeff: float = 1e-3
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    rescale_prenorm_residual: bool = True
+    rms_eps: float = 1e-5
+    max_len: int = 262144
+    dtype: Any = jnp.bfloat16         # what the mixers compute in
+    attention_impl: str = "dot"       # "dot" | "flash"
+    ssm_impl: str = "xla"             # "xla" | "pallas": the scan and its convolution
+    fused_head: bool = False          # pallas head + loss (ops/fused_xent)
+    remat: bool = False               # jax.checkpoint around every layer
+    exact_first_layer: bool = False   # layer 0 (Mamba-2) in float32, see Mamba2
+
+    def __post_init__(self):
+        if self.attention_impl not in ("dot", "flash"):
+            raise ValueError(f"Unknown attention_impl {self.attention_impl!r}; "
+                             f"valid: 'dot', 'flash'")
+        if self.ssm_impl not in SSM_IMPLS:
+            raise ValueError(f"Unknown ssm_impl {self.ssm_impl!r}; "
+                             f"valid: {SSM_IMPLS}")
+        unknown = set(self.pattern) - {MAMBA, EXPERTS, ATTENTION}
+        if unknown or not self.pattern:
+            raise ValueError(f"pattern must be of {MAMBA!r}, {EXPERTS!r} and "
+                             f"{ATTENTION!r}; got {sorted(unknown)}")
+        if self.n_heads % self.n_kv_heads or self.mamba_heads % self.n_groups:
+            raise ValueError("n_heads must divide over n_kv_heads, "
+                             "mamba_heads over n_groups")
+        if not 1 <= self.top_k <= self.n_experts_routed:
+            raise ValueError("top_k must be in [1, n_experts_routed]")
+        if self.exact_first_layer and self.pattern[0] != MAMBA:
+            raise ValueError("exact_first_layer is the Mamba-2 layer's; the "
+                             f"pattern starts with {self.pattern[0]!r}")
+        if not (0 <= self.first_expert_held and self.experts_held >= 1
+                and self.first_expert_held + self.experts_held
+                <= self.n_experts_routed):
+            raise ValueError("the experts held must lie inside the router's width")
+
+    @property
+    def n_layers(self) -> int:
+        return len(self.pattern)
+
+    @property
+    def d_inner(self) -> int:
+        return self.mamba_heads * self.mamba_head_dim
+
+
+def _out_proj(config: NemotronHConfig, name: str, dtype=None,
+              precision=None) -> nn.Dense:
+    """A mixer's output matrix: normal(0.02), divided by ``sqrt(n_layers)``
+    under ``rescale_prenorm_residual``."""
+    std = 0.02 / (math.sqrt(config.n_layers)
+                  if config.rescale_prenorm_residual else 1.0)
+    return nn.Dense(config.d_model, use_bias=False, dtype=dtype or config.dtype,
+                    param_dtype=jnp.float32, precision=precision,
+                    kernel_init=nn.initializers.normal(std), name=name)
+
+
+def _in_proj(features: int, config: NemotronHConfig, name: str, dtype=None,
+             precision=None) -> nn.Dense:
+    return nn.Dense(features, use_bias=False, dtype=dtype or config.dtype,
+                    param_dtype=jnp.float32, precision=precision,
+                    kernel_init=_INIT, name=name)
+
+
+def _dt_bias_init(config: NemotronHConfig):
+    def init(key, shape, dtype=jnp.float32):
+        low, high = math.log(config.time_step_min), math.log(config.time_step_max)
+        dt = jnp.exp(jax.random.uniform(key, shape, dtype) * (high - low) + low)
+        dt = jnp.maximum(dt, config.time_step_floor)
+        return dt + jnp.log(-jnp.expm1(-dt))        # softplus^-1
+    return init
+
+
+def _uniform(bound: float):
+    return lambda key, shape, dtype=jnp.float32: jax.random.uniform(
+        key, shape, dtype, -bound, bound)
+
+
+def gated_group_norm(y, z, scale, groups: int, eps: float):
+    """``RMSNorm_grouped(y * silu(z)) * scale``: the mean square over each of
+    ``groups`` equal runs of the last axis, float32."""
+    gated = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    runs = gated.reshape(*gated.shape[:-1], groups, -1)
+    runs = runs * jax.lax.rsqrt(jnp.mean(jnp.square(runs), axis=-1,
+                                         keepdims=True) + eps)
+    return runs.reshape(gated.shape) * scale
+
+
+class Mamba2(nn.Module):
+    """The state-space mixer: input projection to ``[z | xBC | dt]``, the
+    depthwise causal convolution with bias and SiLU, the chunked scan, the
+    gated grouped RMSNorm, output projection.
+
+    ``exact``: everything but the scan's products in float32, the two
+    projections at ``Precision.HIGH`` (three bfloat16 passes). The first
+    layer's: the embedding is a twentieth of what that layer adds to it, so
+    its output *is* the residual stream every later router reads, and its
+    rounding (0.5% in bfloat16) moves one token in twenty across a top-k
+    boundary in every expert layer above (PERF.md section 6, "PR 35")."""
+    config: NemotronHConfig
+    exact: bool = False
+
+    @nn.compact
+    def __call__(self, h):
+        cfg = self.config
+        dtype, precision = ((jnp.float32, jax.lax.Precision.HIGH) if self.exact
+                            else (cfg.dtype, None))
+        b, length, _ = h.shape
+        heads, p, g, n = (cfg.mamba_heads, cfg.mamba_head_dim, cfg.n_groups,
+                          cfg.d_state)
+        d_inner, conv_dim = cfg.d_inner, cfg.d_inner + 2 * g * n
+        taps = self.param("conv", _uniform(cfg.conv_kernel ** -0.5),
+                          (conv_dim, cfg.conv_kernel), jnp.float32)
+        conv_bias = self.param("conv_bias", _uniform(cfg.conv_kernel ** -0.5),
+                               (conv_dim,), jnp.float32)
+        a_log = self.param("A_log", lambda *_: jnp.log(
+            jnp.arange(1, heads + 1, dtype=jnp.float32)))
+        d_skip = self.param("D", nn.initializers.ones, (heads,), jnp.float32)
+        dt_bias = self.param("dt_bias", _dt_bias_init(cfg), (heads,), jnp.float32)
+        scale = self.param("norm", nn.initializers.ones, (d_inner,), jnp.float32)
+        # init runs the plain paths: shapes are all it needs
+        impl = "xla" if self.is_initializing() else cfg.ssm_impl
+        with jax.named_scope("ssm.in_proj"):
+            zxbcdt = _in_proj(d_inner + conv_dim + heads, cfg, "in_proj", dtype,
+                              precision)(h)
+            z, xbc, dt = jnp.split(zxbcdt, [d_inner, d_inner + conv_dim], axis=-1)
+        with jax.named_scope("ssm.conv"):
+            xbc = conv_silu(xbc, taps, conv_bias, impl).astype(cfg.dtype)
+            x, bmat, cmat = jnp.split(xbc, [d_inner, d_inner + g * n], axis=-1)
+        with jax.named_scope("ssm.scan"):
+            dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
+            y = ssd_scan(x.reshape(b, length, heads, p), dt, -jnp.exp(a_log),
+                         bmat.reshape(b, length, g, n),
+                         cmat.reshape(b, length, g, n), d_skip, chunk=cfg.chunk,
+                         impl=impl)
+        with jax.named_scope("ssm.gate_norm"):
+            y = gated_group_norm(y.reshape(b, length, d_inner), z, scale, g,
+                                 cfg.rms_eps).astype(dtype)
+        with jax.named_scope("ssm.out_proj"):
+            return _out_proj(cfg, "out_proj", dtype, precision)(y)
+
+
+class GroupedAttention(nn.Module):
+    """Causal attention, ``H_a`` query heads over ``H_kv`` KV heads: no bias,
+    no norm on q or k, no positional embedding, no gate."""
+    config: NemotronHConfig
+
+    @nn.compact
+    def __call__(self, h):
+        cfg = self.config
+        b, length, _ = h.shape
+        heads = lambda t, n: t.reshape(b, length, n, cfg.head_dim)  # noqa: E731
+        wide, narrow = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
+        q = heads(_in_proj(wide, cfg, "query")(h), cfg.n_heads)
+        k = heads(_in_proj(narrow, cfg, "key")(h), cfg.n_kv_heads)
+        v = heads(_in_proj(narrow, cfg, "value")(h), cfg.n_kv_heads)
+        if cfg.attention_impl == "flash" and not self.is_initializing():
+            from autodist_tpu.ops.flash_attention import flash_attention
+            ctx = flash_attention(q, k, v, causal=True)
+        else:
+            group = cfg.n_heads // cfg.n_kv_heads
+            ctx = dot_product_attention(
+                q, jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2),
+                causal_mask(length, cfg.dtype), cfg.dtype)
+        return _out_proj(cfg, "out")(ctx.reshape(b, length, wide))
+
+
+class SharedAndRoutedExperts(nn.Module):
+    """The expert mixer: a shared ``relu2`` expert every token passes, beside
+    this chip's share of the sigmoid top-k routed ``relu2`` experts
+    (``models/moe.py`` :func:`sigmoid_routed_share`, whose parameters live in
+    this module's scope). ``__call__(h)`` takes the float32 normalised input
+    ``[B, S, d]`` and returns ``(m float32, the bias term of the loss)``."""
+    config: NemotronHConfig
+
+    @nn.compact
+    def __call__(self, h):
+        cfg = self.config
+        with jax.named_scope("moe.shared"):
+            shared = PlainMLP(cfg.d_shared, cfg.dtype, name="shared")(
+                h.astype(cfg.dtype))
+        y, bias_term = sigmoid_routed_share(
+            self, h, router_width=cfg.n_experts_routed,
+            experts_held=cfg.experts_held,
+            first_expert_held=cfg.first_expert_held, top_k=cfg.top_k,
+            d_expert=cfg.d_expert, rows_bound=cfg.rows_bound,
+            route=functools.partial(sigmoid_topk_route,
+                                    route_norm=cfg.route_norm,
+                                    route_scale=cfg.route_scale,
+                                    route_eps=cfg.route_eps),
+            dtype=cfg.dtype, form="relu2")
+        return shared.astype(jnp.float32) + y, bias_term
+
+
+class NemotronHBlock(nn.Module):
+    """``x + mixer(RMSNorm(x))`` for one mixer; ``(x, the layer's bias term)``."""
+    config: NemotronHConfig
+    kind: str
+    exact: bool = False               # a Mamba-2 layer's (Mamba2)
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        bias_term = jnp.zeros((), jnp.float32)
+        if self.kind == EXPERTS:
+            h = RMSNorm(cfg.rms_eps, jnp.float32, name="norm")(x)
+            m, bias_term = SharedAndRoutedExperts(cfg, name="moe")(h)
+        elif self.kind == MAMBA:
+            h = RMSNorm(cfg.rms_eps, jnp.float32 if self.exact else cfg.dtype,
+                        name="norm")(x)
+            m = Mamba2(cfg, self.exact, name="mamba")(h)
+        else:
+            h = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm")(x)
+            m = GroupedAttention(cfg, name="attn")(h)
+        return x + m, bias_term
+
+
+class NemotronH(nn.Module):
+    """``tokens [B, L] -> (logits or hidden, bias term)``; the bias term is
+    the sum over the expert layers of the zero-valued term whose gradient is
+    the load error (``models/afmoe.py``'s docstring)."""
+    config: NemotronHConfig
+
+    @nn.compact
+    def __call__(self, tokens, return_hidden: bool = False):
+        cfg = self.config
+        x = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=jnp.float32,
+                     param_dtype=jnp.float32, embedding_init=_INIT,
+                     name="embed")(tokens)
+        block = (nn.remat(NemotronHBlock)
+                 if cfg.remat and not self.is_initializing() else NemotronHBlock)
+        bias_term = jnp.zeros((), jnp.float32)
+        for i, kind in enumerate(cfg.pattern):
+            x, term = block(cfg, kind, cfg.exact_first_layer and i == 0,
+                            name=f"block_{i}")(x)
+            bias_term = bias_term + term
+        x = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm_f")(x)
+        if return_hidden:
+            # The fused-head loss owns the projection; the head's parameters
+            # exist from init, which runs the path below.
+            return x, bias_term
+        return _in_proj(cfg.vocab_size, cfg, "lm_head")(x), bias_term
+
+
+def make_loss_fn(model: NemotronH) -> Callable:
+    """Mean next-token cross-entropy (+ the expert layers' bias terms, zero in
+    value); batch = ``{"tokens": int32 [B, L+1]}``."""
+    cfg = model.config
+
+    def loss_fn(params, batch):
+        tokens = batch["tokens"]
+        inputs, targets = tokens[:, :-1], tokens[:, 1:]
+        if cfg.fused_head:
+            from autodist_tpu.models.common import fused_lm_head_nll
+            h, bias_term = model.apply({"params": params}, inputs,
+                                       return_hidden=True)
+            nll = fused_lm_head_nll(h, params, targets)
+        else:
+            logits, bias_term = model.apply({"params": params}, inputs)
+            logprobs = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+            nll = -jnp.take_along_axis(logprobs, targets[..., None],
+                                       axis=-1)[..., 0]
+        return nll.mean() + bias_term
+
+    return loss_fn
+
+
+def init_params(config: NemotronHConfig, rng: Optional[jax.Array] = None,
+                batch_size: int = 2):
+    from autodist_tpu.models.common import jit_init
+    rng = rng if rng is not None else jax.random.PRNGKey(0)
+    model = NemotronH(config)
+    tokens = jnp.zeros((batch_size, min(8, config.max_len)), jnp.int32)
+    return model, jit_init(model, tokens, rng=rng)

@@ -74,12 +74,16 @@ class _Visits(NamedTuple):
 
 
 def _col_tile(n: int) -> int:
-    """Output columns a block: the widest of 1,024/512/256/128 that divides
-    ``n``, or ``n`` itself (one block) where none does."""
-    for t in (1024, 512, 256, 128):
+    """Output columns a block: the widest of 1,024/512/256 that divides
+    ``n``; failing those the widest multiple of 128 up to 1,024 that does
+    (2,688 = 3 x 896, where 128 alone would make 21 blocks); or ``n`` itself,
+    one block, where ``n`` is no multiple of 128 (1,856 = 14.5 x 128)."""
+    for t in (1024, 512, 256):
         if n % t == 0:
             return t
-    return n
+    if n % 128:
+        return n
+    return max(t for t in range(128, 1025, 128) if n % t == 0)
 
 
 def _plan_visits(group_sizes, rows: int, tm: int, *, tail: bool,

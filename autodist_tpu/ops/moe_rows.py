@@ -28,7 +28,10 @@ by one DMA, and no DMA is issued for a row that is not held:
 A row is one DMA because the kernels see ``[n, d]`` in HBM as ``[n, d / 128,
 128]``: a row taken by its leading index is a whole slab of tiles, where
 Mosaic refuses a one-row slice of a 2-D HBM ref (the tiling holds 8 or 16
-rows). XLA pays for the view with a layout copy of the operand. On the CPU
+rows). XLA pays for the view with a layout copy of the operand; where ``d /
+128`` is not a multiple of 8 (2,688 = 21 x 128) that copy also pads the row
+to whole tiles of 8 slab rows, which a DMA's slice must be, and the kernels
+write the ``d`` real columns. On the CPU
 backend the kernels run in pallas interpret mode; ``tests/test_chip_compile.py``
 compiles them for a described v5e at the two cells' shapes.
 """
@@ -60,9 +63,22 @@ class RowPlan(NamedTuple):
     starts: jax.Array    # [token tiles + 1] first entry of each tile, then count
 
 
+_SLAB_ROWS = 8          # Mosaic's tile: a DMA takes whole tiles of a slab
+
+
 def _slabs(n: int, d: int):
-    """The 3-D shape a ``[n, d]`` operand takes: a row is one slab."""
-    return (n, d // _LANES, _LANES) if d % _LANES == 0 else (n, 1, d)
+    """The 3-D shape a ``[n, d]`` operand takes: a row is one slab, of whole
+    tiles."""
+    if d % _LANES:
+        return (n, 1, d)
+    return (n, -(-d // (_LANES * _SLAB_ROWS)) * _SLAB_ROWS, _LANES)
+
+
+def _as_slabs(x):
+    """``x [n, d]`` in its 3-D view, zero columns added up to whole tiles."""
+    view = _slabs(*x.shape)
+    pad = view[1] * view[2] - x.shape[1]
+    return (jnp.pad(x, ((0, 0), (0, pad))) if pad else x).reshape(view)
 
 
 def _token_tile(n_tokens: int) -> int:
@@ -136,8 +152,8 @@ def moe_rows_gather(src: jax.Array, token: jax.Array, count) -> jax.Array:
             dimension_semantics=("arbitrary",)),
         interpret=_flash._use_interpret(),
     )(token.astype(jnp.int32), jnp.asarray(count, jnp.int32).reshape(1),
-      src.reshape(view))
-    return out.reshape(n_rows, d)
+      _as_slabs(src))
+    return out.reshape(n_rows, -1)[:, :d]
 
 
 # ----------------------------------------------------------------- combine
@@ -180,7 +196,7 @@ def _combine_kernel(order, starts, token, *refs, tt: int, fetch: int,
     # token t's slab is rows [t * slab, (t + 1) * slab) of ``acc``; the
     # output block is [tokens, d]: column block j is every slab's row j
     lanes = acc.shape[1]
-    for j in range(slab):
+    for j in range(pl.cdiv(out.shape[1], lanes)):   # the real columns' slabs
         out[:, j * lanes:(j + 1) * lanes] = acc[
             pl.ds(j, out.shape[0], stride=slab), :].astype(out.dtype)
 
@@ -222,4 +238,4 @@ def moe_rows_combine(rows: jax.Array, weight: Optional[jax.Array],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=_flash._use_interpret(),
-    )(*scalars, rows.reshape(view))
+    )(*scalars, _as_slabs(rows))

@@ -40,6 +40,14 @@ The custom VJP saves ``bcu`` and ``w`` and nothing else, on both paths
 from the saved operands; init, the CPU and the comparison run it). On the CPU
 backend the kernels run in pallas interpret mode; ``tests/test_chip_compile.py``
 compiles them for a described v5e at the LFM2 cell's shape.
+
+Beside it the ungated form the state-space hybrids run before their scan
+(Mamba-2's): :func:`conv_silu`, ``silu(causal_conv_w(x) + b)``, plain
+``jax.numpy`` alone; its custom VJP keeps ``x``, ``w`` and ``b`` and nothing
+else, as the gated form's does. It has no kernel yet and is worth one:
+stand-alone at Nemotron's ``[1, 8192, 6144]`` XLA's lowering takes 1.74 ms
+forward and 5.76 ms backward where the bytes allow 0.25 and 0.37 (PERF.md §6,
+"PR 35"; §7 has the walk such a kernel would take).
 """
 
 import functools
@@ -84,6 +92,17 @@ def _plain(bcu, w):
     conv = sum(w[:, j].astype(jnp.float32) * v[:, j:j + length]
                for j in range(k))
     return (c * conv).astype(bcu.dtype)
+
+
+def _plain_silu(x, w, b):
+    """``silu(causal_conv_w(x) + b)``, float32, ``K`` shifted products on a
+    zero-padded array."""
+    _, k = w.shape
+    length = x.shape[1]
+    v = jnp.pad(x.astype(jnp.float32), ((0, 0), (k - 1, 0), (0, 0)))
+    conv = sum(w[:, j].astype(jnp.float32) * v[:, j:j + length]
+               for j in range(k))
+    return jax.nn.silu(conv + b.astype(jnp.float32)).astype(x.dtype)
 
 
 # ----------------------------------------------------------------- kernels
@@ -203,6 +222,124 @@ def _bwd_kernel(b_ref, c_ref, u_ref, dy_ref, c_after_ref, dy_after_ref, w_ref,
             dw_ref[j:j + 1, lanes] += jnp.sum(sums[j], axis=0, keepdims=True)
 
 
+def _silu_fwd_kernel(x_ref, w_ref, bias_ref, o_ref, carry_ref, *, k: int,
+                     channels: int):
+    """``silu(conv + b)`` of one (channel block, sequence, row block): the
+    gated forward's walk with ``v = x`` and the bias and SiLU where it has the
+    ``C`` gate."""
+    _, block_rows, d = o_ref.shape
+
+    @pl.when(pl.program_id(2) == 0)
+    def _first_block_of_a_sequence():
+        carry_ref[...] = jnp.zeros_like(carry_ref)
+
+    row = jax.lax.broadcasted_iota(jnp.int32, (_SUB, channels), 0)
+    for first in range(0, d, channels):
+        lanes = pl.ds(first, channels)
+        taps = [w_ref[j:j + 1, lanes] for j in range(k)]
+        bias = bias_ref[:, lanes]
+
+        def walk(r, before, lanes=lanes, taps=taps, bias=bias):
+            rows = pl.ds(pl.multiple_of(r * _SUB, _SUB), _SUB)
+            v = _f32(x_ref, rows, lanes)
+            conv = bias + taps[k - 1] * v
+            for s in range(1, k):
+                conv += taps[k - 1 - s] * _shifted(v, before, s, row)
+            o_ref[0, rows, lanes] = (conv * jax.nn.sigmoid(conv)).astype(o_ref.dtype)
+            return v
+
+        carry_ref[:, lanes] = jax.lax.fori_loop(
+            0, block_rows // _SUB, walk, carry_ref[:, lanes])
+
+
+def _silu_bwd_kernel(x_ref, dy_ref, x_after_ref, dy_after_ref, w_ref, bias_ref,
+                     dx_ref, dw_ref, db_ref, carry_ref, *, k: int, channels: int,
+                     length: int):
+    """``g = dy * silu'(conv + b)`` needs the convolution again, so the walk
+    is one step ahead of what it writes: step ``r`` computes ``g`` of its 16
+    rows (and their part of ``dw`` and ``db``) and then ``dx`` of the 16 rows
+    *before* them, which need the ``K - 1`` rows of ``g`` after; the block's
+    last 16 rows take theirs from the 16 rows after the block (two 16-row
+    block specs, zero past a sequence's end)."""
+    _, block_rows, d = dy_ref.shape
+    i = pl.program_id(2)
+    steps = block_rows // _SUB
+    ragged = length % block_rows != 0
+
+    @pl.when((pl.program_id(1) == 0) & (i == 0))
+    def _first_block_of_the_channels():
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+        db_ref[...] = jnp.zeros_like(db_ref)
+
+    @pl.when(i == 0)
+    def _first_block_of_a_sequence():
+        carry_ref[...] = jnp.zeros_like(carry_ref)
+
+    row = jax.lax.broadcasted_iota(jnp.int32, (_SUB, channels), 0)
+
+    def inside(first_row):
+        return i * block_rows + first_row + row < length
+
+    for first in range(0, d, channels):
+        lanes = pl.ds(first, channels)
+        taps = [w_ref[j:j + 1, lanes] for j in range(k)]
+        bias = bias_ref[:, lanes]
+
+        def g_of(v, before, dy, taps=taps, bias=bias):
+            """``(g, [v moved down by 1..K-1])`` of 16 rows."""
+            moved = [_shifted(v, before, s, row) for s in range(1, k)]
+            pre = bias + taps[k - 1] * v
+            for s in range(1, k):
+                pre += taps[k - 1 - s] * moved[s - 1]
+            sig = jax.nn.sigmoid(pre)
+            return dy * sig * (1.0 + pre * (1.0 - sig)), moved
+
+        def dx_of(g, after, taps=taps):
+            dv = taps[k - 1] * g
+            for s in range(1, k):
+                dv += taps[k - 1 - s] * _shifted_up(g, after, s, row)
+            return dv
+
+        def rows_of(r, lanes=lanes):
+            rows = pl.ds(pl.multiple_of(r * _SUB, _SUB), _SUB)
+            v, dy = _f32(x_ref, rows, lanes), _f32(dy_ref, rows, lanes)
+            if ragged:      # rows past the sequence hold anything
+                valid = inside(r * _SUB)
+                v, dy = jnp.where(valid, v, 0.0), jnp.where(valid, dy, 0.0)
+            return v, dy
+
+        def summed(sums, g, v, moved):
+            sums = list(sums)
+            sums[k - 1] += g * v
+            for s in range(1, k):
+                sums[k - 1 - s] += g * moved[s - 1]
+            sums[k] += g
+            return tuple(sums)
+
+        def walk(r, carry, lanes=lanes):
+            before, g_before, sums = carry
+            v, dy = rows_of(r)
+            g, moved = g_of(v, before, dy)
+            rows = pl.ds(pl.multiple_of((r - 1) * _SUB, _SUB), _SUB)
+            dx_ref[0, rows, lanes] = dx_of(g_before, g).astype(dx_ref.dtype)
+            return v, g, summed(sums, g, v, moved)
+
+        zeros = jnp.zeros((_SUB, channels), jnp.float32)
+        v, dy = rows_of(0)
+        g, moved = g_of(v, carry_ref[:, lanes], dy)
+        v, g, sums = jax.lax.fori_loop(
+            1, steps, walk, (v, g, summed((zeros,) * (k + 1), g, v, moved)))
+        g_after, _ = g_of(_f32(x_after_ref, slice(None), lanes), v,
+                          _f32(dy_after_ref, slice(None), lanes))
+        g_after = jnp.where(inside(block_rows), g_after, 0.0)
+        last = pl.ds((steps - 1) * _SUB, _SUB)
+        dx_ref[0, last, lanes] = dx_of(g, g_after).astype(dx_ref.dtype)
+        carry_ref[:, lanes] = v
+        for j in range(k):
+            dw_ref[j:j + 1, lanes] += jnp.sum(sums[j], axis=0, keepdims=True)
+        db_ref[:, lanes] += jnp.sum(sums[k], axis=0, keepdims=True)
+
+
 # ------------------------------------------------------------------- calls
 
 def _check(bcu, w):
@@ -301,6 +438,75 @@ def _backward_call(bcu, w, dy, interpret: bool, block_rows=None, channels=None):
     return dbcu, dw.T.astype(w.dtype)
 
 
+_SILU_BLOCK_D = 2048    # channels a grid step holds: 6,144 = 3 x 2,048
+
+
+def _silu_tiles(x, w, block_rows: int):
+    if x.ndim != 3 or w.ndim != 2 or x.shape[2] != w.shape[0]:
+        raise ValueError(f"conv_silu: x {x.shape} against taps {w.shape}; "
+                         f"want [B, L, d] and [d, K]")
+    d, k = w.shape
+    if d % 128 or not 1 <= k <= _SUB + 1:
+        raise ValueError(f"conv_silu kernels: d {d} must be a multiple of 128 "
+                         f"and K {k} at most {_SUB + 1}")
+    block_rows, block_d = _tiles(x.shape[1], d, block_rows, _SILU_BLOCK_D)
+    return block_rows, block_d, _tiles(x.shape[1], block_d, block_rows,
+                                       _CHANNELS)[1]
+
+
+def _silu_forward_call(x, w, b, interpret: bool):
+    batch, length, d = x.shape
+    k = w.shape[1]
+    block_rows, block_d, channels = _silu_tiles(x, w, FWD_BLOCK_ROWS)
+    block = pl.BlockSpec((1, block_rows, block_d), lambda c, s, i: (s, i, c))
+    taps = lambda rows: pl.BlockSpec((rows, block_d), lambda c, s, i: (0, c))  # noqa: E731
+    return named_pallas_call(
+        "conv_silu_fwd",
+        functools.partial(_silu_fwd_kernel, k=k, channels=channels),
+        grid=(d // block_d, batch, pl.cdiv(length, block_rows)),
+        in_specs=[block, taps(k), taps(1)],
+        out_specs=block,
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        scratch_shapes=[pltpu.VMEM((_SUB, block_d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+    )(x, w.astype(jnp.float32).T, b.astype(jnp.float32)[None])
+
+
+def _silu_backward_call(x, w, b, dy, interpret: bool):
+    batch, length, d = x.shape
+    k = w.shape[1]
+    block_rows, block_d, channels = _silu_tiles(x, w, BWD_BLOCK_ROWS)
+    last_halo = pl.cdiv(length, _SUB) - 1
+    per_block = block_rows // _SUB
+    block = pl.BlockSpec((1, block_rows, block_d), lambda c, s, i: (s, i, c))
+    after = pl.BlockSpec(       # the 16 rows after block i
+        (1, _SUB, block_d),
+        lambda c, s, i: (s, jnp.minimum((i + 1) * per_block, last_halo), c))
+    taps = lambda rows: pl.BlockSpec((rows, block_d), lambda c, s, i: (0, c))  # noqa: E731
+    dy = dy.astype(x.dtype)
+    dx, dw, db = named_pallas_call(
+        "conv_silu_bwd",
+        functools.partial(_silu_bwd_kernel, k=k, channels=channels,
+                          length=length),
+        grid=(d // block_d, batch, pl.cdiv(length, block_rows)),
+        in_specs=[block, block, after, after, taps(k), taps(1)],
+        out_specs=[block, taps(k), taps(1)],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+                   jax.ShapeDtypeStruct((k, d), jnp.float32),
+                   jax.ShapeDtypeStruct((1, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((_SUB, block_d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            # dw and db are one block a channel block, summed over its grid
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+    )(x, dy, x, dy, w.astype(jnp.float32).T, b.astype(jnp.float32)[None])
+    return dx, dw.T.astype(w.dtype), db[0].astype(b.dtype)
+
+
 # --------------------------------------------------------------- public op
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
@@ -343,3 +549,42 @@ def gated_short_conv(bcu: jax.Array, w: jax.Array, impl: str = "xla") -> jax.Arr
     from autodist_tpu.parallel.mesh import per_device
     return per_device(functools.partial(_conv, impl=impl), (bcu, w),
                       batched=(True, False))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _conv_silu(x, w, b, impl):
+    if impl == "xla":
+        return _plain_silu(x, w, b)
+    return _silu_forward_call(x, w, b, _flash._use_interpret())
+
+
+def _conv_silu_fwd(x, w, b, impl):
+    return _conv_silu(x, w, b, impl), (x, w, b)
+
+
+def _conv_silu_bwd(impl, residuals, dy):
+    x = residuals[0]
+    if impl == "xla":
+        return jax.vjp(_plain_silu, *residuals)[1](dy.astype(x.dtype))
+    return _silu_backward_call(*residuals, dy, _flash._use_interpret())
+
+
+_conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)
+
+
+def conv_silu(x: jax.Array, w: jax.Array, b: jax.Array,
+              impl: str = "xla") -> jax.Array:
+    """``y_t = silu(sum_j w[:, j] * x_{t-(K-1)+j} + b)``, depthwise over the
+    channels, causal, each sequence on its own (``x_s = 0`` for ``s < 0``).
+    x: ``[batch, L, d]``; w: ``[d, K]``; b: ``[d]``; ``impl``: ``"xla"`` or
+    ``"pallas"`` (``d`` a multiple of 128). Returns ``[batch, L, d]`` in
+    ``x.dtype``; the arithmetic is float32. Differentiable in all three; only
+    they are kept for the backward. Under a mesh of several devices the
+    kernels run per device, as :func:`gated_short_conv`'s do."""
+    if impl not in IMPLS:
+        raise ValueError(f"Unknown conv impl {impl!r}; valid: {IMPLS}")
+    if impl == "xla":
+        return _conv_silu(x, w, b, impl)
+    from autodist_tpu.parallel.mesh import per_device
+    return per_device(functools.partial(_conv_silu, impl=impl), (x, w, b),
+                      batched=(True, False, False))

@@ -186,15 +186,33 @@ def test_the_shares_add_up_to_the_uncut_layer():
 def _dense_share(x, scores, gate, up, down, bias, *, k, first, scale):
     """The held experts' part by a 0/1 choice mask: every held expert on every
     token, weighted where it is among the token's top k."""
-    held = gate.shape[0]
+    held = up.shape[0]
     choice = scores + bias
     kth = jnp.sort(choice, axis=-1)[:, scores.shape[1] - k]
     w = jnp.where(choice >= kth[:, None], scores, 0.0)
     w = scale * w / w.sum(axis=-1, keepdims=True)
-    every = jnp.einsum("tew,ewd->ted", jax.nn.silu(
-        jnp.einsum("td,edw->tew", x, gate)) * jnp.einsum("td,edw->tew", x, up),
-        down)
+    hidden = jnp.einsum("td,edw->tew", x, up)
+    if gate is None:                # the relu2 form: two banks, no gate
+        hidden = jnp.square(jax.nn.relu(hidden))
+    else:
+        hidden = jax.nn.silu(jnp.einsum("td,edw->tew", x, gate)) * hidden
+    every = jnp.einsum("tew,ewd->ted", hidden, down)
     return jnp.einsum("te,ted->td", w[:, first:first + held], every)
+
+
+FORMS = pytest.mark.parametrize("form", list(moe.EXPERT_FORMS))
+
+
+def _bank_of(form, bank):
+    """``(gate, up, down)`` as the form takes it: no gate bank under relu2."""
+    return bank if form == "gated_silu" else [None, *bank[1:]]
+
+
+def _grads(fn, args, argnums):
+    """Gradients of ``fn(*args).sum()`` for the ``argnums`` that hold an
+    array (a form without a gate has None in its place)."""
+    argnums = tuple(i for i in argnums if args[i] is not None)
+    return jax.grad(lambda *a: fn(*a).sum(), argnums=argnums)(*args)
 
 
 def _all_avals(jaxpr):
@@ -209,18 +227,21 @@ def _all_avals(jaxpr):
                     yield from _all_avals(sub)
 
 
+@FORMS
 @pytest.mark.parametrize("bound", [None, 56, 16], ids=["one-pass-T*k", "bound-56", "bound-16"])
-def test_a_share_equals_its_experts_under_a_mask_and_keeps_absent_rows_out(bound):
+def test_a_share_equals_its_experts_under_a_mask_and_keeps_absent_rows_out(bound,
+                                                                           form):
     """Experts 2-4 of 8 held, top-3: in one pass over T*k rows, in buffers of
-    56 rows (the held rows fit one pass), and of 16 (they need several)."""
+    56 rows (the held rows fit one pass), and of 16 (they need several); the
+    gated-SiLU expert over three banks and the relu2 one over two."""
     x, scores, bias, bank = _layer_inputs()
     tokens, k, first, held = x.shape[0], 3, 2, 3
     route = functools.partial(moe.sigmoid_topk_route, route_norm=True,
                               route_scale=2.0)
-    mine = [b[first:first + held] for b in bank]
+    mine = _bank_of(form, [b[first:first + held] for b in bank])
     share = lambda x, scores, gate, up, down, bias: moe.routed_experts(  # noqa: E731
         x, scores, gate, up, down, bias, top_k=k, route=route,
-        first_expert=first, rows_bound=bound)[0]
+        first_expert=first, rows_bound=bound, form=form)[0]
     dense = functools.partial(_dense_share, k=k, first=first, scale=2.0)
 
     args = (x, scores, *mine, bias)
@@ -228,8 +249,9 @@ def test_a_share_equals_its_experts_under_a_mask_and_keeps_absent_rows_out(bound
                           n_held=held).group_sizes.sum())
     assert 16 < held_rows <= 56 < tokens * k
     np.testing.assert_allclose(share(*args), dense(*args), rtol=1e-5, atol=1e-5)
-    got = jax.grad(lambda *a: share(*a).sum(), argnums=(0, 1, 2, 3, 4))(*args)
-    want = jax.grad(lambda *a: dense(*a).sum(), argnums=(0, 1, 2, 3, 4))(*args)
+    got = _grads(share, args, (0, 1, 2, 3, 4))
+    want = _grads(dense, args, (0, 1, 2, 3, 4))
+    assert len(got) == (5 if form == "gated_silu" else 4)
     for g, r in zip(got, want):
         np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-5)
     if bound is None:
@@ -237,7 +259,7 @@ def test_a_share_equals_its_experts_under_a_mask_and_keeps_absent_rows_out(bound
     # The buffers hold the bound's rows, not tokens x k: nothing two-
     # dimensional in the program, forward or backward, has tokens x k rows.
     jaxpr = jax.make_jaxpr(jax.grad(lambda *a: share(*a).sum(),
-                                    argnums=(0, 2, 3, 4)))(*args)
+                                    argnums=(0, 3, 4)))(*args)
     rows = {aval.shape[0] for aval in _all_avals(jaxpr.jaxpr)
             if len(aval.shape) == 2 and aval.shape[1] > 1}
     assert tokens * k not in rows and bound in rows
@@ -350,8 +372,8 @@ def test_two_layers_and_their_loops_trace_the_pass_at_most_twice(monkeypatch):
     other test of this file uses."""
     x, scores, bias, bank = _layer_inputs(tokens=20, d=16, w=40)
     calls = []
-    inner = moe._gated_experts
-    monkeypatch.setattr(moe, "_gated_experts",
+    inner = moe._expert_mlps
+    monkeypatch.setattr(moe, "_expert_mlps",
                         lambda *a: calls.append(1) or inner(*a))
     share = lambda x, gate, up, down: moe.routed_experts(  # noqa: E731
         x, scores, gate, up, down, bias, top_k=3,
@@ -372,22 +394,25 @@ def _bias_on(experts):
     return jnp.zeros(8).at[jnp.asarray(experts)].set(jnp.asarray([10.0, 9.0]))
 
 
+@FORMS
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5), (jnp.bfloat16, 2e-2)],
                          ids=["f32", "bf16"])
 @pytest.mark.parametrize("routing", ["1-pass", "2-passes", "4-passes"])
 def test_kept_and_recomputed_passes_give_the_dense_shares_gradients(
-        routing, dtype, tol):
-    """y and the gradients of x, the router's scores and the three banks
-    through :func:`moe._held_passes` (every bound is below ``T*k``), pass 0
-    transposed from what it kept and the others recomputed, against the dense
-    share in float32 on the same (rounded) rows."""
+        routing, dtype, tol, form):
+    """y and the gradients of x, the router's scores and the banks (three
+    gated, two relu2) through :func:`moe._held_passes` (every bound is below
+    ``T*k``), pass 0 transposed from what it kept and the others recomputed,
+    against the dense share in float32 on the same (rounded) rows."""
     x, scores, _, bank = _layer_inputs()
     chosen, bound, _ = _ROUTINGS[routing]
-    bias, mine = _bias_on(chosen), [b[4:6] for b in bank]
+    bias, mine = _bias_on(chosen), _bank_of(form, [b[4:6] for b in bank])
     x = x.astype(dtype)
+    if form == "relu2" and dtype == jnp.bfloat16:
+        tol = 2 * tol           # the square doubles the hidden row's rounding
     share = lambda x, scores, *bank: moe.routed_experts(  # noqa: E731
         x, scores, *bank, bias, top_k=2, route=moe.sigmoid_topk_route,
-        first_expert=4, rows_bound=bound)[0]
+        first_expert=4, rows_bound=bound, form=form)[0]
     dense = lambda x, scores, *bank: _dense_share(  # noqa: E731
         x.astype(jnp.float32), scores, *bank, bias, k=2, first=4, scale=1.0)
     assert bound < x.shape[0] * 2
@@ -395,8 +420,8 @@ def test_kept_and_recomputed_passes_give_the_dense_shares_gradients(
     loss = lambda fn: lambda *a: (fn(*a) * target).sum()  # noqa: E731
     np.testing.assert_allclose(share(x, scores, *mine), dense(x, scores, *mine),
                                rtol=tol, atol=tol)
-    got = jax.grad(loss(share), argnums=(0, 1, 2, 3, 4))(x, scores, *mine)
-    want = jax.grad(loss(dense), argnums=(0, 1, 2, 3, 4))(x, scores, *mine)
+    got = _grads(loss(share), (x, scores, *mine), range(5))
+    want = _grads(loss(dense), (x, scores, *mine), range(5))
     assert got[0].dtype == dtype and got[2].dtype == jnp.float32
     for g, r in zip(got, want):
         assert _rel_l2(g.astype(jnp.float32), r.astype(jnp.float32)) <= tol

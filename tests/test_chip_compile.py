@@ -170,9 +170,13 @@ _XENT_ROWS = 2048
      ((1024, 1024), (512, 512), (512, 512)), (1024, 128)),
     (2048, 25_024, "dv", jnp.bfloat16, jnp.float32,  # trinity-pretrain-8k's sliced head
      ((1024, 1024), (512, 512), (1024, 512)), (1024, 512)),
+    # nemotron-pretrain-8k's sliced head: d 2,688 is past the 512-2,048 the
+    # temporaries were fitted for, and the fit holds (PR 35)
+    (2688, 16_384, "dv", jnp.bfloat16, jnp.float32,
+     ((512, 1024), (512, 512), (512, 512)), (1024, 256)),
 ], ids=["flagship-d512-dv-f32", "d1024-vd-f32", "d1024-dv-bf16",
         "d1024-vd-bf16-V50257", "olmoe-d2048-dv-f32", "d2048-f32-rows",
-        "trinity-d2048-dv-f32"])
+        "trinity-d2048-dv-f32", "nemotron-d2688-dv-f32"])
 def test_fused_xent_fwd_bwd_compiles(chip, d, vocab, layout, rows_dtype,
                                      table_dtype, tiles, one_pass):
     table = (vocab, d) if layout == "vd" else (d, vocab)
@@ -278,16 +282,84 @@ def test_grouped_matmul_fwd_bwd_compiles_at_the_lfm2_cell_shapes(chip, k, n):
         assert name in text
 
 
+@pytest.mark.parametrize("k,n", [(2688, 1856), (1856, 2688)],
+                         ids=["up-2688x1856", "down-1856x2688"])
+def test_grouped_matmul_fwd_bwd_compiles_at_the_nemotron_cell_shapes(chip, k, n):
+    """A 6,144-row pass of one chip's share (8 of 128 experts held, 3,072 rows
+    on average) in 8 groups of ``relu2`` experts 1,856 wide under a hidden
+    size of 2,688: a width of 21 x 128 in 896-column blocks, one of 14.5 x 128
+    as one block and as a whole contraction."""
+    from autodist_tpu.ops import grouped_matmul
+
+    assert (grouped_matmul._col_tile(2688), grouped_matmul._col_tile(1856)) \
+        == (896, 1856)
+
+    def loss(x, w, group_sizes):
+        return grouped_matmul.gmm(x, w, group_sizes).astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1)), chip,
+                          ((6144, k), jnp.bfloat16),
+                          ((8, k, n), jnp.float32), ((8,), jnp.int32))
+    for name in ("moe_gmm_fwd", "moe_gmm_bwd_dx", "moe_gmm_bwd_dw"):
+        assert name in text
+
+
+@pytest.mark.parametrize("length,chunks", [(8192, 64), (1000, 8)],
+                         ids=["cell-1x8192", "ragged-L1000"])
+def test_ssd_scan_fwd_bwd_compiles_at_the_nemotron_cell_shape(chip, length,
+                                                              chunks):
+    """nemotron-pretrain-8k's call: 64 heads of 64 in 8 groups, state 128,
+    chunks of 128, bfloat16 ``x``, ``B``, ``C`` and float32 ``dt``: the
+    forward and the backward kernel, each a Mosaic call under its own name,
+    through the operator's custom VJP; a length the chunk does not divide is
+    padded."""
+    from autodist_tpu.ops.ssd_scan import ssd_scan
+
+    def loss(x, dt, a, b, c, d):
+        return ssd_scan(x, dt, a, b, c, d, impl="pallas").astype(jnp.float32).sum()
+
+    wide = ((1, length, 64, 64), jnp.bfloat16)
+    narrow = ((1, length, 8, 128), jnp.bfloat16)
+    text = _compiled_text(jax.value_and_grad(loss, argnums=tuple(range(6))), chip,
+                          wide, ((1, length, 64), jnp.float32),
+                          ((64,), jnp.float32), narrow, narrow,
+                          ((64,), jnp.float32))
+    assert "tpu_custom_call" in text
+    assert "ssd_fwd" in text and "ssd_bwd" in text
+    assert f"f32[1,{chunks},8,512,128]" in text      # one state a chunk and head
+
+
+def test_flash_sixteen_query_heads_a_kv_head_compile_at_the_nemotron_cell_shape(chip):
+    """nemotron-pretrain-8k's call: 1 x 8,192 x 32 query heads over 2 KV
+    heads of 128, causal, no window: Trinity's full layer's schedule at 16
+    query heads a KV head where Trinity has 8 and LFM2 4. No new code; this
+    guards it."""
+    q = ((1, 8192, 32, 128), jnp.bfloat16)
+    kv = ((1, 8192, 2, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1, 2)), chip,
+                          q, kv, kv)
+    assert "flash_fwd" in text and "flash_bwd_dkv" in text
+
+
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
-@pytest.mark.parametrize("tokens,rows", [(8192, 8192), (16_384, 16_384)],
-                         ids=["trinity-8192x8192", "lfm2-16384x16384"])
-def test_row_kernels_compile_at_the_two_share_cells_shapes(chip, tokens, rows,
-                                                           dtype):
+@pytest.mark.parametrize("tokens,rows,d", [(8192, 8192, 2048),
+                                           (16_384, 16_384, 2048),
+                                           (8192, 6144, 2688)],
+                         ids=["trinity-8192x8192", "lfm2-16384x16384",
+                              "nemotron-8192x6144-d2688"])
+def test_row_kernels_compile_at_the_share_cells_shapes(chip, tokens, rows, d,
+                                                       dtype):
     """One pass of one chip's share at d 2,048 (the rows of the two cases
     above): the gather by token (one DMA a held row from a source left in
     HBM), and the combine with weights into float32 and without them into the
     rows' dtype (the dispatch's transpose), each a Mosaic call under its own
-    name; float32 rows (8 KB slabs) as well as bfloat16 ones."""
+    name; float32 rows (8 KB slabs) as well as bfloat16 ones. At d 2,688 a
+    row is 21 slab rows of 128 lanes, padded to 24: a DMA takes whole tiles
+    of 8 (Mosaic refused the 21: PR 35)."""
     from autodist_tpu.ops import moe_rows
 
     def both(src, out, weight, token, count):
@@ -297,8 +369,8 @@ def test_row_kernels_compile_at_the_two_share_cells_shapes(chip, tokens, rows,
                 moe_rows.moe_rows_combine(out, None, token, count, tokens, plan,
                                           dtype=dtype))
 
-    text = _compiled_text(both, chip, ((tokens, 2048), dtype),
-                          ((rows, 2048), dtype), ((rows,), jnp.float32),
+    text = _compiled_text(both, chip, ((tokens, d), dtype),
+                          ((rows, d), dtype), ((rows,), jnp.float32),
                           ((rows,), jnp.int32), ((), jnp.int32))
     assert "tpu_custom_call" in text
     assert "moe_rows_gather" in text and "moe_rows_combine" in text
@@ -341,6 +413,29 @@ def test_short_conv_fwd_bwd_compiles(chip, batch, length, d, taps):
                           ((d, taps), jnp.float32))
     assert "tpu_custom_call" in text
     assert "short_conv_fwd" in text and "short_conv_bwd" in text
+
+
+@pytest.mark.parametrize("length,dtype", [(8192, jnp.bfloat16),
+                                          (8192, jnp.float32),
+                                          (1000, jnp.bfloat16)],
+                         ids=["cell-1x8192x6144", "cell-layer-0-f32",
+                              "ragged-L1000"])
+def test_conv_silu_fwd_bwd_compiles_at_the_nemotron_cell_shape(chip, length,
+                                                               dtype):
+    """nemotron-pretrain-8k's call: the ungated convolution with bias and SiLU
+    over 6,144 channels (3 channel blocks of 2,048), four taps, in bfloat16
+    and, for layer 0, float32: two Mosaic calls under their own names through
+    the operator's custom VJP."""
+    from autodist_tpu.ops.short_conv import conv_silu
+
+    def loss(x, w, b):
+        return conv_silu(x, w, b, "pallas").astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1, 2)), chip,
+                          ((1, length, 6144), dtype), ((6144, 4), jnp.float32),
+                          ((6144,), jnp.float32))
+    assert "tpu_custom_call" in text
+    assert "conv_silu_fwd" in text and "conv_silu_bwd" in text
 
 
 def test_fused_xent_forward_compiles_at_lm1b_vocab(chip):

@@ -84,7 +84,8 @@ def test_lowered_step_names_kernels_and_phases(backward, monkeypatch):
     text = _lm_step_lowered(zero=1).as_text(debug_info=True)
     step_kernels = [k for k in named_call.KERNEL_NAMES
                     if k != "flash_carry"
-                    and not k.startswith(("moe_", "short_conv_"))
+                    and not k.startswith(("moe_", "short_conv_", "ssd_",
+                                          "conv_silu_"))
                     and (k != "flash_bwd_dq" or backward == "split")]
     assert _scopes(text, named_call.KERNEL_NAMES) == set(step_kernels)
     # ZeRO's constrain_update is the reduction under AllReduce (the implicit
@@ -217,6 +218,68 @@ def test_a_shares_gathers_stay_xlas_and_the_whole_bank_names_no_row_kernel():
     rows = [k for k in named_call.KERNEL_NAMES if k.startswith("moe_rows_")]
     assert _scopes(_lfm2_step_text(), rows) == {"moe_rows_combine"}
     assert _scopes(_olmoe_step_text(), rows) == set()
+
+
+SSM_SCOPES = ("ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.gate_norm",
+              "ssm.out_proj", "moe.shared")
+
+
+@functools.lru_cache(maxsize=1)
+def _nemotron_step_text() -> str:       # one lowering for the cases below
+    """The tiny Nemotron-H step (a Mamba-2 layer, an expert layer with its
+    share of the relu2 experts, an attention layer, a Mamba-2 layer, every
+    layer recomputed, the fused untied head) through ``AutoDist`` on the
+    8-device mesh."""
+    from autodist_tpu.models import nemotron_h
+    cfg = nemotron_h.NemotronHConfig(
+        vocab_size=203, d_model=128, pattern="ME*M", mamba_heads=2,
+        mamba_head_dim=64, n_groups=1, d_state=128, n_heads=2, n_kv_heads=1,
+        head_dim=16, d_expert=16, d_shared=32, n_experts_routed=8,
+        experts_held=2, first_expert_held=2, top_k=2, max_len=16,
+        dtype=jnp.float32, attention_impl="flash", ssm_impl="pallas",
+        fused_head=True, remat=True)
+    model, params = nemotron_h.init_params(cfg, rng=jax.random.PRNGKey(0))
+    batch = nemotron_h.synthetic_batch(cfg, batch_size=16, seq_len=16)
+    runner = AutoDist(strategy_builder=AllReduce()).create_distributed_session(
+        nemotron_h.make_loss_fn(model), params,
+        nemotron_h.make_optimizer(1e-3, cfg.load_balance_coeff),
+        example_batch=batch)
+    state = runner.init(params)
+    with runner.mesh:
+        return runner._build_step(None).lower(
+            state, runner.shard_batch(batch)).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("name", [k for k in named_call.KERNEL_NAMES
+                                  if k.startswith(("ssd_", "conv_silu_"))]
+                         + ["moe_rows_combine", "moe_gmm_fwd", "flash_fwd",
+                            "xent_fwd"] + list(SSM_SCOPES) + list(MOE_SCOPES))
+def test_nemotron_step_names_its_scan_kernels_and_scopes(name):
+    """The chunked scan's two kernels and the two of the convolution before it
+    by their device names (``pallas:ssd_fwd`` / ``pallas:ssd_bwd``,
+    ``pallas:conv_silu_fwd`` / ``pallas:conv_silu_bwd`` in a trace), the five
+    scopes of the Mamba-2 layer and
+    the shared expert's, beside the routed share's four and the kernels the
+    step shares with the other families, under per-layer recomputation."""
+    assert _scopes(_nemotron_step_text(), [name]) == {name}
+
+
+def test_ssd_and_expert_form_gauges_are_set_when_the_step_is_traced():
+    calls = telemetry.counter("ssd.calls").value
+    _nemotron_step_text.cache_clear()
+    _nemotron_step_text()
+    # the call as the model makes it, all devices: 16 sequences of 16
+    # positions, each padded to one chunk of 128; float32 operands here
+    assert [telemetry.gauge(f"ssd.{k}").value for k in
+            ("chunk", "chunks", "heads", "groups", "state")] == [128, 16, 2, 1, 128]
+    wide, narrow = 16 * 16 * 2 * 64 * 4, 16 * 16 * 128 * 4
+    states = 16 * 2 * 64 * 128 * 4
+    assert telemetry.gauge("ssd.fwd.bytes").value == 2 * wide + 2 * narrow + states
+    assert telemetry.gauge("ssd.bwd.bytes").value == 3 * wide + 4 * narrow + states
+    assert telemetry.counter("ssd.calls").value >= calls + 2    # two Mamba-2 layers
+    assert telemetry.gauge("moe.expert_form").value == 2        # relu2: up, down
+    _olmoe_step_lowered()
+    assert telemetry.gauge("moe.expert_form").value == 3        # gate, up, down
 
 
 def test_short_conv_gauges_are_set_when_the_operator_is_traced():

@@ -94,3 +94,69 @@ def test_row_tile_gauge_counts_the_grid_visits(small_tiles):
     x, w = jnp.zeros((64, K)), jnp.zeros((5, K, N))
     jax.eval_shape(gmm, x, w, jnp.zeros((5,), jnp.int32))
     assert telemetry.gauge("moe.gmm.row_tiles").value == 64 // 16 + 5
+
+
+@pytest.mark.parametrize("k,n", [(24, 200), (200, 24), (232, 336), (336, 232)],
+                         ids=["n-200", "k-200", "up-232x336", "down-336x232"])
+def test_gmm_at_widths_that_are_no_multiple_of_128(small_tiles, k, n):
+    """Nemotron's expert is 1,856 = 14.5 x 128 wide under a hidden size of
+    2,688 = 21 x 128: an output width that is no multiple of 128 is one block,
+    a contraction that is none is whole; 232 and 336 are the same widths an
+    eighth the size."""
+    rng = np.random.default_rng(2)
+    sizes = [9, 0, 21, 6]
+    x = jnp.asarray(rng.normal(size=(40, k)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(4, k, n)), jnp.float32)
+    ct = jnp.asarray(rng.normal(size=(40, n)), jnp.float32)
+    group_sizes = jnp.asarray(sizes, jnp.int32)
+    np.testing.assert_allclose(gmm(x, w, group_sizes), _loop(x, w, sizes),
+                               rtol=1e-4, atol=1e-4)
+    got = jax.grad(lambda x, w: jnp.sum(gmm(x, w, group_sizes) * ct),
+                   argnums=(0, 1))(x, w)
+    want = jax.grad(lambda x, w: jnp.sum(_loop(x, w, sizes) * ct),
+                    argnums=(0, 1))(x, w)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("width,tile", [
+    (2048, 1024), (1024, 1024), (1536, 512),      # cells 6-8: the parent's tiles
+    (512, 512), (256, 256), (128, 128),
+    (2688, 896), (1856, 1856), (384, 384), (200, 200)])
+def test_the_column_tile_of_a_width(width, tile):
+    """The widths the older cells run keep the tiles they had; 2,688 = 3 x
+    896 takes the widest multiple of 128 that divides it (the parent took 128:
+    21 blocks), 1,856 = 14.5 x 128 is one block."""
+    assert grouped_matmul._col_tile(width) == tile
+    assert width % tile == 0
+
+
+def test_gmm_at_the_nemotron_widths_in_real_tiles():
+    """2,688 x 1,856 and back at the kernels' own tiles (256 and 512 rows, 896
+    or 1,856 columns) in the cell's dtypes, a ragged share of a pass's rows;
+    float32 rows at these widths do not fit the kernels' VMEM budget and are
+    refused by name."""
+    rng = np.random.default_rng(3)
+    sizes = [300, 0, 130, 70]
+    group_sizes = jnp.asarray(sizes, jnp.int32)
+    rounded = lambda t: t.astype(jnp.bfloat16).astype(jnp.float32)  # noqa: E731
+    for k, n in ((2688, 1856), (1856, 2688)):
+        x = jnp.asarray(rng.normal(size=(640, k)) / np.sqrt(k), jnp.bfloat16)
+        w = jnp.asarray(rng.normal(size=(4, k, n)), jnp.float32)
+        ct = jnp.asarray(rng.normal(size=(640, n)), jnp.bfloat16)
+        dense = lambda x, w: _loop(rounded(x), rounded(w), sizes)  # noqa: E731
+        np.testing.assert_allclose(gmm(x, w, group_sizes).astype(jnp.float32),
+                                   dense(x, w), rtol=2e-2, atol=2e-2)
+        loss = lambda fn: lambda x, w: jnp.sum(  # noqa: E731
+            fn(x, w).astype(jnp.float32) * ct.astype(jnp.float32))
+        got = jax.grad(loss(lambda x, w: gmm(x, w, group_sizes)),
+                       argnums=(0, 1))(x, w)
+        want = jax.grad(loss(dense), argnums=(0, 1))(x, w)
+        for g, r in zip(got, want):
+            scale = float(jnp.abs(r.astype(jnp.float32)).max())
+            np.testing.assert_allclose(g.astype(jnp.float32),
+                                       r.astype(jnp.float32), rtol=2e-2,
+                                       atol=2e-2 * scale)
+    with pytest.raises(ValueError, match="MiB of VMEM"):
+        gmm(jnp.zeros((640, 2688), jnp.float32),
+            jnp.zeros((4, 2688, 1856), jnp.float32), group_sizes)

@@ -82,6 +82,32 @@ def test_combine_is_the_float32_scatter_add_of_the_held_rows(count, dtype,
         assert not np.asarray(got[:4]).any() and not np.asarray(got[9:]).any()
 
 
+@pytest.mark.parametrize("d,slab", [(2688, 24), (1280, 16), (1024, 8), (200, 1)],
+                         ids=["2688-21-slabs", "1280-10-slabs", "1024", "200"])
+@DTYPES
+def test_a_row_that_is_no_whole_number_of_tiles_moves_padded(d, slab, dtype):
+    """2,688 = 21 x 128: a row's slab is padded to 24 rows of 128 lanes (a
+    DMA takes whole tiles of 8) and the kernels write the 2,688 real columns;
+    a width that is no multiple of 128 stays one row of its own."""
+    assert moe_rows._slabs(R, d)[1] == slab
+    rows = jax.random.normal(jax.random.PRNGKey(2), (R, d), dtype)
+    weight = jax.random.uniform(jax.random.PRNGKey(3), (R,))
+    token = _tokens("spread")
+    got = moe_rows.moe_rows_combine(rows, weight, token, 11, T)
+    scale = jnp.where(_held(11), weight, 0.0)
+    want = jnp.zeros((T, d), jnp.float32).at[token].add(
+        scale[:, None] * rows.astype(jnp.float32))
+    assert got.shape == (T, d)
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    src = jax.random.normal(jax.random.PRNGKey(0), (T, d), dtype)
+    taken = moe_rows.moe_rows_gather(src, token, 11)
+    assert taken.shape == (R, d) and taken.dtype == dtype
+    np.testing.assert_array_equal(
+        np.asarray(taken, np.float32),
+        np.asarray(jnp.where(_held(11)[:, None], jnp.take(src, token, axis=0), 0),
+                   np.float32))
+
+
 def test_a_tokens_rows_are_added_in_row_order():
     """Deterministic where the scatter-add was not: ((a + b) + c) + d in
     float32 for the four rows of token 5, whatever else the tile holds."""
@@ -131,7 +157,7 @@ def _xla_held_pass(c, x, weights, gate, up, down, perm, offsets, top_k, bound):
     weight = jnp.where(first + jnp.arange(bound) < offsets[-1],
                        jnp.take(weights, kept), 0.0)
     rows = _xla_take_rows(x, token, n_tokens)
-    out = moe._gated_experts(rows, gate, up, down, sizes)
+    out = moe._expert_mlps(rows, gate, up, down, sizes)
     return jnp.zeros((n_tokens, d), jnp.float32).at[token].add(
         weight[:, None] * out.astype(jnp.float32))
 

@@ -1,0 +1,232 @@
+"""The chunked state-space scan (``ops/ssd_scan.py``): both paths against the
+literal step-by-step recurrence written out here and against the exact
+quadratic form of the benchmark's reference, value and the gradient of every
+input; lengths that are and are not whole chunks; nothing crosses from one
+sequence of a batch to the next; chunk 128 and a smaller one; the custom VJP
+keeps the inputs and one state a chunk and head; the gauges say what a call
+moves; ``conv_silu`` (``ops/short_conv.py``) against shifted products. The
+kernels run in interpret mode on the CPU."""
+
+import functools
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from autodist_tpu import telemetry
+from autodist_tpu.ops import short_conv, ssd_scan as ssd
+from autodist_tpu.ops.ssd_scan import ssd_scan
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+
+def recurrence(x, dt, A, B, C, D):
+    """``S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T``, ``y_t = S_t C_t + D
+    x_t``, a position at a time, one ``[P, N]`` state a head."""
+    b, _, h, p = x.shape
+    g, n = B.shape[2:]
+    Bh, Ch = jnp.repeat(B, h // g, axis=2), jnp.repeat(C, h // g, axis=2)
+
+    def step(state, at):
+        xt, dtt, bt, ct = at
+        state = (jnp.exp(dtt * A)[..., None, None] * state
+                 + (dtt[..., None] * xt)[..., None] * bt[..., None, :])
+        return state, jnp.einsum("bhpn,bhn->bhp", state, ct) + D[:, None] * xt
+
+    _, ys = jax.lax.scan(step, jnp.zeros((b, h, p, n)),
+                         tuple(jnp.moveaxis(t, 1, 0) for t in (x, dt, Bh, Ch)))
+    return jnp.moveaxis(ys, 0, 1)
+
+
+def quadratic(x, dt, A, B, C, D):
+    from benchmark.reference import nemotron_h as reference
+    h, g = x.shape[2], B.shape[2]
+    return reference.quadratic_ssm(x, dt, dt * A, jnp.repeat(B, h // g, axis=2),
+                                   jnp.repeat(C, h // g, axis=2), D)
+
+
+def _operands(b, length, h, p, g, n, seed=0, dtype=jnp.float32):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 7)
+    inputs = (jax.random.normal(keys[0], (b, length, h, p), dtype),
+              jax.nn.softplus(jax.random.normal(keys[1], (b, length, h)) - 1.0),
+              -jnp.exp(0.5 * jax.random.normal(keys[2], (h,))),
+              (0.3 * jax.random.normal(keys[3], (b, length, g, n))).astype(dtype),
+              (0.3 * jax.random.normal(keys[4], (b, length, g, n))).astype(dtype),
+              jax.random.normal(keys[5], (h,)))
+    return inputs, jax.random.normal(keys[6], (b, length, h, p))
+
+
+def _value_and_grads(fn, inputs, weight):
+    return jax.value_and_grad(
+        lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * weight),
+        argnums=tuple(range(6)), has_aux=False)(*inputs), fn(*inputs)
+
+
+# (b, L, H, P, G, N, chunk): the kernels want N, a group's heads x P and the
+# chunk in multiples of 128
+CASES = {
+    "xla-whole-chunks": ("xla", (2, 32, 4, 8, 2, 16, 16)),
+    "xla-ragged": ("xla", (2, 40, 4, 8, 2, 16, 16)),
+    "xla-shorter-than-a-chunk": ("xla", (1, 9, 2, 8, 1, 16, 16)),
+    "xla-chunk-128": ("xla", (1, 200, 2, 8, 2, 16, 128)),
+    "xla-one-head-a-group": ("xla", (2, 48, 3, 8, 3, 16, 16)),
+    "pallas-two-chunks-two-sequences": ("pallas", (2, 256, 4, 64, 2, 128, 128)),
+    "pallas-ragged": ("pallas", (1, 200, 2, 64, 1, 128, 128)),
+    "pallas-eight-heads-a-group": ("pallas", (1, 128, 8, 16, 1, 128, 128)),
+}
+
+
+@pytest.mark.parametrize("against", ["recurrence", "quadratic"])
+@pytest.mark.parametrize("case", list(CASES))
+def test_values_and_every_gradient_match(case, against):
+    impl, (*shape, chunk) = CASES[case]
+    inputs, weight = _operands(*shape)
+    plain = {"recurrence": recurrence, "quadratic": quadratic}[against]
+    fn = lambda *a: ssd_scan(*a, chunk=chunk, impl=impl)  # noqa: E731
+    (_, got), y = _value_and_grads(fn, inputs, weight)
+    (_, want), want_y = _value_and_grads(plain, inputs, weight)
+    np.testing.assert_allclose(y, want_y, rtol=2e-4, atol=2e-4)
+    for name, g, r in zip(("dx", "ddt", "dA", "dB", "dC", "dD"), got, want):
+        assert g.shape == r.shape and g.dtype == r.dtype, name
+        scale = float(jnp.abs(r).max())
+        np.testing.assert_allclose(g, r, rtol=1e-3, atol=2e-4 * scale,
+                                   err_msg=name)
+
+
+@pytest.mark.parametrize("impl,shape", [("xla", (2, 40, 4, 8, 2, 16, 16)),
+                                        ("pallas", (2, 200, 2, 64, 1, 128, 128))])
+def test_no_state_crosses_from_one_sequence_to_the_next(impl, shape):
+    """The second sequence's result is what it gives alone, whatever the
+    first holds; and the first sequence's inputs take no gradient from the
+    second's outputs."""
+    *dims, chunk = shape
+    inputs, weight = _operands(*dims)
+    fn = lambda *a: ssd_scan(*a, chunk=chunk, impl=impl)  # noqa: E731
+    alone = fn(*(t[1:] if t.ndim > 1 else t for t in inputs))
+    np.testing.assert_allclose(fn(*inputs)[1:], alone, rtol=1e-5, atol=1e-5)
+    loud = tuple(t.at[0].multiply(50.0) if t.ndim == 4 else t for t in inputs)
+    np.testing.assert_allclose(fn(*loud)[1:], alone, rtol=1e-5, atol=1e-5)
+    second_only = weight.at[0].set(0.0)
+    (_, grads), _ = _value_and_grads(fn, inputs, second_only)
+    for g in (grads[0], grads[1], grads[3], grads[4]):
+        assert float(jnp.abs(g[0]).max()) == 0.0 < float(jnp.abs(g[1]).max())
+
+
+def test_bfloat16_operands_accumulate_in_float32_and_agree_across_paths():
+    """The models' dtypes: bfloat16 ``x``, ``B``, ``C``, float32 ``dt``; the
+    result and dx, dB, dC come back bfloat16, ddt, dA, dD float32; the two
+    paths round the same operands and agree to a rounding or two."""
+    inputs, weight = _operands(1, 256, 4, 64, 2, 128, dtype=jnp.bfloat16)
+    out = {}
+    for impl in ssd.IMPLS:
+        fn = lambda *a, impl=impl: ssd_scan(*a, impl=impl)  # noqa: E731
+        (_, grads), y = _value_and_grads(fn, inputs, weight)
+        assert y.dtype == jnp.bfloat16
+        assert [g.dtype for g in grads] == [jnp.bfloat16, jnp.float32,
+                                            jnp.float32, jnp.bfloat16,
+                                            jnp.bfloat16, jnp.float32]
+        out[impl] = (y, *grads)
+    f32 = tuple(t.astype(jnp.float32) for t in inputs)
+    (_, exact), exact_y = _value_and_grads(recurrence, f32, weight)
+    for a, b, r in zip(out["pallas"], out["xla"], (exact_y, *exact)):
+        norm = float(jnp.linalg.norm(r))
+        assert float(jnp.linalg.norm(a.astype(jnp.float32)
+                                     - b.astype(jnp.float32))) <= 1e-2 * norm
+        assert float(jnp.linalg.norm(a.astype(jnp.float32) - r)) <= 2e-2 * norm
+
+
+@pytest.mark.parametrize("impl", ssd.IMPLS)
+def test_the_backward_keeps_the_inputs_and_one_state_a_chunk_and_head(impl):
+    """Residuals of the custom VJP: the six inputs and ``[b, chunks, H, P,
+    N]`` float32, nothing with a ``[Q, Q]`` plane in it."""
+    inputs, _ = _operands(1, 256, 4, 64, 2, 128)
+    _, residuals = ssd._scan_fwd(*inputs, 128, impl)
+    *kept, states = residuals
+    for a, b in zip(kept, inputs):
+        assert a is b
+    assert states.dtype == jnp.float32 and states.size == 2 * 4 * 64 * 128
+
+
+def test_gauges_and_the_counter_are_set_when_the_operator_is_traced():
+    calls = telemetry.counter("ssd.calls").value
+    inputs, _ = _operands(2, 200, 4, 64, 2, 128, dtype=jnp.bfloat16)
+    jax.eval_shape(lambda *a: ssd_scan(*a, impl="pallas"), *inputs)
+    assert telemetry.counter("ssd.calls").value == calls + 1
+    assert [telemetry.gauge(f"ssd.{k}").value for k in
+            ("chunk", "chunks", "heads", "groups", "state")] == [128, 4, 4, 2, 128]
+    wide, narrow = 2 * 200 * 4 * 64 * 2, 2 * 200 * 2 * 128 * 2
+    states = 4 * 4 * 64 * 128 * 4
+    assert telemetry.gauge("ssd.fwd.bytes").value == 2 * wide + 2 * narrow + states
+    assert telemetry.gauge("ssd.bwd.bytes").value == 3 * wide + 4 * narrow + states
+
+
+def test_mismatched_arguments_and_unknown_impls_are_refused():
+    (x, dt, A, B, C, D), _ = _operands(1, 16, 4, 8, 2, 16)
+    with pytest.raises(ValueError, match="Unknown ssd impl"):
+        ssd_scan(x, dt, A, B, C, D, impl="mosaic")
+    with pytest.raises(ValueError, match="G dividing H"):
+        ssd_scan(x, dt, A, B[:, :, :1].repeat(3, axis=2), C, D)
+    with pytest.raises(ValueError, match="want"):
+        ssd_scan(x, dt[:, :8], A, B, C, D)
+    with pytest.raises(ValueError, match="multiples of 128"):
+        ssd_scan(x, dt, A, B, C, D, chunk=16, impl="pallas")
+
+
+# ------------------------------------------- the convolution before the scan
+
+def _shifted_silu(x, w, b):
+    k = w.shape[1]
+    total = jnp.zeros(x.shape, jnp.float32)
+    for j in range(k):
+        s = k - 1 - j
+        moved = x if s == 0 else jnp.concatenate(
+            [jnp.zeros_like(x[:, :s]), x[:, :x.shape[1] - s]], axis=1)
+        total = total + w[:, j] * moved
+    return jax.nn.silu(total + b)
+
+
+@pytest.mark.parametrize("impl,batch,length,d,k", [
+    ("xla", 2, 24, 48, 4), ("xla", 1, 3, 16, 4), ("xla", 2, 17, 32, 3),
+    # the kernels: a length of whole row blocks, a ragged one of three row
+    # blocks whose last holds 8 rows, one shorter than a walk step, and two
+    # channel blocks of a grid (2,560 = 2 x 1,280)
+    ("pallas", 2, 512, 256, 4), ("pallas", 2, 520, 128, 4),
+    ("pallas", 1, 3, 128, 4), ("pallas", 2, 40, 2560, 3),
+], ids=["four-taps", "shorter-than-the-taps", "three-taps", "kernels-whole-blocks",
+        "kernels-ragged", "kernels-shorter-than-a-step", "kernels-two-channel-blocks"])
+def test_conv_silu_and_its_three_gradients_match_shifted_products(impl, batch,
+                                                                  length, d, k):
+    conv = functools.partial(short_conv.conv_silu, impl=impl)
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    x = jax.random.normal(keys[0], (batch, length, d))
+    w = jax.random.normal(keys[1], (d, k))
+    b = jax.random.normal(keys[2], (d,))
+    weight = jax.random.normal(keys[3], x.shape)
+    loss = lambda fn: lambda *a: jnp.sum(fn(*a) * weight)  # noqa: E731
+    np.testing.assert_allclose(conv(x, w, b), _shifted_silu(x, w, b),
+                               rtol=1e-5, atol=1e-5)
+    got = jax.grad(loss(conv), argnums=(0, 1, 2))(x, w, b)
+    want = jax.grad(loss(_shifted_silu), argnums=(0, 1, 2))(x, w, b)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-4 * float(jnp.abs(r).max()))
+    # each sequence on its own, and only x, w and b kept for the backward
+    np.testing.assert_allclose(conv(x, w, b)[-1:], conv(x[-1:], w, b), rtol=1e-6)
+    _, residuals = short_conv._conv_silu_fwd(x, w, b, impl)
+    assert [r is a for r, a in zip(residuals, (x, w, b))] == [True] * 3
+    out = conv(x.astype(jnp.bfloat16), w, b)
+    assert out.dtype == jnp.bfloat16
+
+
+def test_conv_silu_refuses_what_its_kernels_cannot_take():
+    x, w, b = jnp.zeros((1, 8, 48)), jnp.zeros((48, 4)), jnp.zeros((48,))
+    with pytest.raises(ValueError, match="multiple of 128"):
+        short_conv.conv_silu(x, w, b, impl="pallas")
+    with pytest.raises(ValueError, match="Unknown conv impl"):
+        short_conv.conv_silu(x, w, b, impl="mosaic")
+    with pytest.raises(ValueError, match=r"want \[B, L, d\]"):
+        short_conv.conv_silu(x, jnp.zeros((32, 4)), b, impl="pallas")

@@ -19,6 +19,11 @@ shape: 131,072 rows in 64 groups as a top-8 of random logits sorts them, against
 forward + backward), ``xent`` (the fused head at 16,384 x 2,048 x 50,304),
 ``flips`` (the share of top-8 choices that differ between bfloat16 and float32
 activations, one sequence through the published widths at depth 1),
+``gmmshare`` (not in a whole run; the grouped matmul at one chip's share of
+Nemotron's ``relu2`` experts: 6,144 rows a pass of which a top-6 of 128 random
+scores sends ~3,072 to the 8 experts held, 2,688 x 1,856 and back, widths
+that are 21 x 128 and 14.5 x 128; ``--col-tiles 2688:128`` (may repeat) sets
+the output columns a block of that width takes, against the rule's own),
 ``gmmcheck`` (``gmm`` and its gradients against ``ragged_dot`` in float32 at the
 highest precision, relative L2), ``gradcheck`` (the benchmark's check of the cell by parameter: each one's share
 of the squared difference from the reference's gradient and of its norm),
@@ -114,6 +119,71 @@ def phase_gmm(calls, tiles):
                        "what": what, "busy_ms": busy,
                        "tflops_of_busy": products * flop / busy / 1e9,
                        "groups_ms": groups}
+
+
+SHARE_ROWS, SHARE_HELD, SHARE_WIDTH, SHARE_TOP_K = 6144, 8, 128, 6
+SHARE_D_MODEL, SHARE_D_EXPERT = 2688, 1856
+COL_TILES = []          # --col-tiles: {width: columns a block}, one a sweep
+
+
+def share_group_sizes(seed: int = 0):
+    """Rows the 8 experts held receive when the top 6 of 128 random scores
+    route 8,192 tokens: ragged, ~384 each, the pass's tail empty."""
+    import jax
+    import jax.numpy as jnp
+    scores = jax.random.normal(jax.random.PRNGKey(seed), (8192, SHARE_WIDTH))
+    _, chosen = jax.lax.top_k(scores, SHARE_TOP_K)
+    return jnp.bincount(chosen.reshape(-1), length=SHARE_WIDTH
+                        )[:SHARE_HELD].astype(jnp.int32)
+
+
+def phase_gmmshare(calls, _tiles):
+    import jax
+    import jax.numpy as jnp
+
+    from autodist_tpu.ops import grouped_matmul
+    sizes = share_group_sizes()
+    yield {"phase": "gmmshare", "held_rows": int(sizes.sum()),
+           "rows": SHARE_ROWS}
+    rule = grouped_matmul._col_tile
+    implementations = {
+        "ragged_dot": (None, lambda x, w, s: jax.lax.ragged_dot(
+            x, w.astype(x.dtype), s, preferred_element_type=jnp.float32
+        ).astype(x.dtype))}
+    for override in [{}] + COL_TILES:
+        name = "gmm" + "".join(f"[{k}:{v}]" for k, v in override.items())
+        implementations[name] = (override, grouped_matmul.gmm)
+    for shape_name, (k, n) in (("up", (SHARE_D_MODEL, SHARE_D_EXPERT)),
+                               ("down", (SHARE_D_EXPERT, SHARE_D_MODEL))):
+        keys = jax.random.split(jax.random.PRNGKey(1), 3)
+        x = jax.random.normal(keys[0], (SHARE_ROWS, k), jnp.bfloat16)
+        w = jax.random.normal(keys[1], (SHARE_HELD, k, n), jnp.float32) * 0.02
+        ct = jax.random.normal(keys[2], (SHARE_ROWS, n), jnp.bfloat16)
+        flop = 2.0 * int(sizes.sum()) * k * n
+        for name, (override, fn) in implementations.items():
+            if override is not None:
+                grouped_matmul._col_tile = (
+                    lambda width, override=override:
+                    override.get(width) or rule(width))
+            fwd = jax.jit(fn)
+            both = jax.jit(jax.grad(
+                lambda x, w, s, ct, fn=fn: jnp.sum(
+                    fn(x, w, s).astype(jnp.float32) * ct.astype(jnp.float32)),
+                argnums=(0, 1)))
+            for what, f, args, products in (("fwd", fwd, (x, w, sizes), 1),
+                                            ("bwd", both, (x, w, sizes, ct), 2)):
+                try:
+                    busy, groups = device_ms(f, args, calls)
+                except Exception as e:  # noqa: BLE001 — a sweep goes on past refused tiles
+                    yield {"phase": "gmmshare", "shape": shape_name,
+                           "impl": name, "what": what,
+                           "refused": str(e).splitlines()[0][:300]}
+                    continue
+                yield {"phase": "gmmshare", "shape": shape_name, "impl": name,
+                       "what": what, "busy_ms": busy,
+                       "tflops_of_busy_held_rows": products * flop / busy / 1e9,
+                       "groups_ms": groups}
+    grouped_matmul._col_tile = rule
 
 
 def phase_gmmcheck(_calls, _tiles):
@@ -364,6 +434,8 @@ def phase_rows(calls, _tiles):
 PHASES = {"gmmcheck": phase_gmmcheck, "gradcheck": phase_gradcheck,
           "gmm": phase_gmm, "flash": phase_flash, "xent": phase_xent,
           "flips": phase_flips, "rows": phase_rows}
+# not in a whole run (PERF.md's 12 minutes are the phases above)
+EXTRA_PHASES = {"gmmshare": phase_gmmshare}
 
 
 def main(argv=None):
@@ -371,15 +443,20 @@ def main(argv=None):
     parser.add_argument("--phases", default=",".join(PHASES))
     parser.add_argument("--tiles", action="append", default=[],
                         help="ROW_TILE,DW_ROW_TILE override; may repeat")
+    parser.add_argument("--col-tiles", action="append", default=[],
+                        help="width:columns[,width:columns] a block of gmm "
+                             "takes in gmmshare; may repeat")
     parser.add_argument("--calls", type=int, default=5)
     args = parser.parse_args(argv)
+    COL_TILES.extend(dict(tuple(int(x) for x in pair.split(":"))
+                          for pair in t.split(",")) for t in args.col_tiles)
     sys.path.insert(0, ROOT)
     import jax
     if jax.default_backend() != "tpu":
         raise SystemExit(f"needs the TPU, the backend is {jax.default_backend()!r}")
     tiles = [tuple(int(x) for x in t.split(",")) for t in args.tiles]
     for name in args.phases.split(","):
-        for record in PHASES[name](args.calls, tiles):
+        for record in {**PHASES, **EXTRA_PHASES}[name](args.calls, tiles):
             print(json.dumps(record), flush=True)
 
 
