@@ -39,9 +39,19 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from autodist_tpu.models.transformer_lm import (MultiHeadAttention,
                                                 TransformerLMConfig, causal_mask)
+
+# ``checkpoint_name``s a caller's ``jax.checkpoint`` may list in its policy
+# (``models/nemotron_h.py`` ``KEPT``); the identity, lowered to nothing,
+# outside one: :class:`PlainMLP`'s ``up`` product, the router's logits in
+# :func:`sigmoid_routed_share` (six bfloat16 passes to make again), and what
+# pass 0 of :func:`_held_passes` makes for its transpose.
+KEPT_UP = "mlp_up"
+KEPT_ROUTER_LOGITS = "router_logits"
+KEPT_PASS = "held_pass_0"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -487,6 +497,11 @@ def _held_passes_fwd(x, weights, gate, up, down, perm, offsets, top_k, bound,
     run = _pass_of(perm, offsets, top_k, bound, form)
     operands = (x, weights, gate, up, down)
     y, transpose_first = jax.vjp(functools.partial(run, 0), *operands)
+    # what pass 0 made for its transpose (not what it was given), by name
+    given = {id(t) for t in operands}
+    transpose_first = jax.tree_util.tree_map(
+        lambda t: t if id(t) in given else checkpoint_name(t, KEPT_PASS),
+        transpose_first)
     y = _later_passes(run, y, operands, _passes(offsets[-1], bound))
     return y, (transpose_first, operands, perm, offsets)
 
@@ -673,8 +688,8 @@ class PlainMLP(nn.Module):
 
     @nn.compact
     def __call__(self, h):
-        hidden = self.act(_dense(self.width, self.dtype, "up")(h))
-        return _dense(h.shape[-1], self.dtype, "down")(hidden)
+        up = checkpoint_name(_dense(self.width, self.dtype, "up")(h), KEPT_UP)
+        return _dense(h.shape[-1], self.dtype, "down")(self.act(up))
 
 
 def sigmoid_routed_share(module: nn.Module, h, *, router_width: int,
@@ -717,8 +732,9 @@ def sigmoid_routed_share(module: nn.Module, h, *, router_width: int,
         # handful of positions it runs on.
         return jnp.zeros((b, s, d), jnp.float32), jnp.zeros((), jnp.float32)
     tokens = h.reshape(b * s, d)
-    scores = jax.nn.sigmoid(jnp.dot(tokens.astype(jnp.float32), router,
-                                    precision=jax.lax.Precision.HIGHEST))
+    scores = jax.nn.sigmoid(checkpoint_name(
+        jnp.dot(tokens.astype(jnp.float32), router,
+                precision=jax.lax.Precision.HIGHEST), KEPT_ROUTER_LOGITS))
     share = functools.partial(routed_experts, top_k=top_k, route=route,
                               first_expert=first_expert_held,
                               rows_bound=rows_bound, form=form)

@@ -59,9 +59,32 @@ alike.
 The scan is one operator, ``ops/ssd_scan.py`` ``ssd_scan``, chunked
 (``chunk_size`` positions a chunk, one ``[P, N]`` state a head carried between
 chunks), and the convolution before it ``ops/short_conv.py`` ``conv_silu``:
-both plain (``ssm_impl="xla"``) or each as two Pallas kernels (``"pallas"``). Under
-``remat`` every layer is a ``jax.checkpoint``: its backward recomputes its
-forward from the residual stream.
+both plain (``ssm_impl="xla"``) or each as two Pallas kernels (``"pallas"``).
+
+Under ``remat`` every layer is a ``jax.checkpoint`` whose policy keeps a
+short list of named values (:data:`KEPT`) and recomputes everything else
+from the residual stream. Without any checkpoint the benchmark's cell needs
+17.1 GiB of a 15.75 GiB chip; with a bare checkpoint it has 3 GiB free and
+its backward runs every forward kernel and matmul a second time. The list is
+what is dearest to make again for the bytes it takes to keep (PERF.md
+section 6, "PR 36", has the milliseconds a MiB of each): attention's q / k /
+v and what flash's forward rule hands its backward (``o``, the log-sum-exp),
+the router's logits (six bfloat16 passes), a Mamba-2 layer's ``in_proj``
+product (layer 0's float32 one at three passes first), the shared expert's
+``up`` product, what pass 0 of one chip's share of the routed experts makes
+for its transpose (the gathered rows and the ``up`` product) and what the
+scan's forward rule makes (``y`` and one state a chunk and head). The ceiling
+that ends the list is the cell's compiled step at 12.4 GiB
+(``benchmark/rehearse.py`` says it before any chip time): the convolution's
+output beside the rest passes it (12.50) and in the scan's place it buys the
+same time, so it is made again. A mixer's *last* product (``out_proj``,
+attention's ``out``, the shared and the routed ``down``) feeds only the
+layer's output, which the next layer keeps as its own input, so no backward
+makes it again; norms, softplus, the gated norm, ``relu2`` and casts are made
+again, cheap for their bytes. The names inside the kernels' ``custom_vjp``
+forward rules (``ops/flash_attention.py``, ``ops/ssd_scan.py``) and in
+``models/moe.py`` are the identity wherever no checkpoint lists them: the
+policy is this model's, so no other caller's program changes.
 
 Parameters and the residual stream are float32; the mixers compute in
 ``dtype`` (under ``exact_first_layer`` layer 0's in float32 around a scan on
@@ -79,18 +102,30 @@ from typing import Any, Callable, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
+from autodist_tpu import telemetry
 from autodist_tpu.models.common import RMSNorm
 from autodist_tpu.models.moe import (  # noqa: F401 — the mixture's, under this family's names
-    PlainMLP, _INIT, balance_expert_bias, balanced_optimizer as make_optimizer,
-    expert_loads, sigmoid_routed_share, sigmoid_topk_route, sown_loads)
+    KEPT_PASS, KEPT_ROUTER_LOGITS, KEPT_UP, PlainMLP, _INIT, balance_expert_bias,
+    balanced_optimizer as make_optimizer, expert_loads, sigmoid_routed_share,
+    sigmoid_topk_route, sown_loads)
 from autodist_tpu.models.transformer_lm import (  # noqa: F401 — synthetic_batch re-exported
     causal_mask, dot_product_attention, synthetic_batch)
+from autodist_tpu.ops.flash_attention import KEPT_NAME as KEPT_FLASH
 from autodist_tpu.ops.short_conv import conv_silu
-from autodist_tpu.ops.ssd_scan import IMPLS as SSM_IMPLS, ssd_scan
+from autodist_tpu.ops.ssd_scan import (IMPLS as SSM_IMPLS, KEPT_NAME as KEPT_SCAN,
+                                       ssd_scan)
 
 MAMBA, EXPERTS, ATTENTION = "M", "E", "*"
 PUBLISHED_PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+
+KEPT_IN_PROJ = "ssm_in_proj"          # a Mamba-2 layer's [z | xBC | dt]
+KEPT_QKV = "attn_qkv"                 # attention's three projections
+# What a checkpointed layer keeps for its backward (module docstring), the
+# dearest to make again for its bytes first.
+KEPT = (KEPT_QKV, KEPT_FLASH, KEPT_ROUTER_LOGITS, KEPT_IN_PROJ, KEPT_UP,
+        KEPT_PASS, KEPT_SCAN)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -243,8 +278,9 @@ class Mamba2(nn.Module):
         # init runs the plain paths: shapes are all it needs
         impl = "xla" if self.is_initializing() else cfg.ssm_impl
         with jax.named_scope("ssm.in_proj"):
-            zxbcdt = _in_proj(d_inner + conv_dim + heads, cfg, "in_proj", dtype,
-                              precision)(h)
+            zxbcdt = checkpoint_name(
+                _in_proj(d_inner + conv_dim + heads, cfg, "in_proj", dtype,
+                         precision)(h), KEPT_IN_PROJ)
             z, xbc, dt = jnp.split(zxbcdt, [d_inner, d_inner + conv_dim], axis=-1)
         with jax.named_scope("ssm.conv"):
             xbc = conv_silu(xbc, taps, conv_bias, impl).astype(cfg.dtype)
@@ -271,7 +307,8 @@ class GroupedAttention(nn.Module):
     def __call__(self, h):
         cfg = self.config
         b, length, _ = h.shape
-        heads = lambda t, n: t.reshape(b, length, n, cfg.head_dim)  # noqa: E731
+        heads = lambda t, n: checkpoint_name(t, KEPT_QKV).reshape(  # noqa: E731
+            b, length, n, cfg.head_dim)
         wide, narrow = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
         q = heads(_in_proj(wide, cfg, "query")(h), cfg.n_heads)
         k = heads(_in_proj(narrow, cfg, "key")(h), cfg.n_kv_heads)
@@ -337,6 +374,27 @@ class NemotronHBlock(nn.Module):
         return x + m, bias_term
 
 
+def _keeping(names):
+    """The ``jax.checkpoint`` policy that keeps the values named in ``names``
+    and nothing else, and books what it keeps as it decides (when a
+    checkpointed layer is differentiated, at trace time): gauges
+    ``remat.kept_values`` and ``remat.kept_bytes``, of this call's layers
+    together."""
+    keep = jax.checkpoint_policies.save_only_these_names(*names)
+    kept = [0, 0]
+
+    def policy(prim, *avals, **params):
+        if not keep(prim, *avals, **params):
+            return False
+        kept[0] += 1
+        kept[1] += sum(a.size * a.dtype.itemsize for a in avals)
+        telemetry.gauge("remat.kept_values").set(kept[0])
+        telemetry.gauge("remat.kept_bytes").set(kept[1])
+        return True
+
+    return policy
+
+
 class NemotronH(nn.Module):
     """``tokens [B, L] -> (logits or hidden, bias term)``; the bias term is
     the sum over the expert layers of the zero-valued term whose gradient is
@@ -349,8 +407,10 @@ class NemotronH(nn.Module):
         x = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=jnp.float32,
                      param_dtype=jnp.float32, embedding_init=_INIT,
                      name="embed")(tokens)
-        block = (nn.remat(NemotronHBlock)
-                 if cfg.remat and not self.is_initializing() else NemotronHBlock)
+        block = NemotronHBlock
+        if cfg.remat and not self.is_initializing():
+            block = nn.remat(NemotronHBlock, policy=_keeping(KEPT))
+            telemetry.gauge("remat.layers").set(cfg.n_layers)
         bias_term = jnp.zeros((), jnp.float32)
         for i, kind in enumerate(cfg.pattern):
             x, term = block(cfg, kind, cfg.exact_first_layer and i == 0,

@@ -55,6 +55,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -105,6 +106,9 @@ _STREAM_K_BLOCK = 2048           # K/V rows a grid step beyond that
 # at head_dim 64: 7.30 against 14.37; PERF.md §6 "PR 29").
 _RESIDENT_DQ_BYTES = 4 << 20
 _BACKWARD_VMEM_LIMIT = 48 << 20  # scoped VMEM the one-pass backward asks for
+# ``jax.ad_checkpoint.checkpoint_name`` of what the forward rule hands the
+# backward beside its inputs: the output and the log-sum-exp
+KEPT_NAME = "flash_residuals"
 
 
 def _sub_tile(bk: int) -> int:
@@ -1001,8 +1005,12 @@ def _flash(q, k, v, causal, q_block, k_block, window):
 
 
 def _flash_fwd(q, k, v, causal, q_block, k_block, window):
-    out, lse = _flash_forward(q, k, v, causal, q_block, k_block, _use_interpret(),
-                              window)
+    # Named so that a caller's ``jax.checkpoint`` whose policy lists
+    # ``KEPT_NAME`` keeps them and does not launch the forward kernel again
+    # for its backward; the identity, lowered to nothing, anywhere else.
+    out, lse = checkpoint_name(
+        _flash_forward(q, k, v, causal, q_block, k_block, _use_interpret(),
+                       window), KEPT_NAME)
     return out, (q, k, v, out, lse)
 
 

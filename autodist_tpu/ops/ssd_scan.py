@@ -57,6 +57,7 @@ import importlib
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -69,6 +70,9 @@ _flash = importlib.import_module("autodist_tpu.ops.flash_attention")
 
 IMPLS = ("xla", "pallas")
 _VMEM_LIMIT = 48 << 20
+# ``jax.ad_checkpoint.checkpoint_name`` of what the forward rule makes: the
+# output and the chunks' states
+KEPT_NAME = "ssd_residuals"
 
 
 # ------------------------------------------------------------ the equations
@@ -415,6 +419,9 @@ def _scan_fwd(x, dt, A, B, C, D, chunk, impl):
     else:
         y, states = _forward_call(x, dt, A, B, C, D, chunk,
                                   _flash._use_interpret())
+    # what a caller's ``jax.checkpoint`` may keep by name, so that its
+    # backward does not run the forward again (``_flash_fwd``'s comment)
+    y, states = checkpoint_name((y, states), KEPT_NAME)
     return y, (x, dt, A, B, C, D, states)
 
 

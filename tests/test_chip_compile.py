@@ -345,6 +345,60 @@ def test_flash_sixteen_query_heads_a_kv_head_compile_at_the_nemotron_cell_shape(
     assert "flash_fwd" in text and "flash_bwd_dkv" in text
 
 
+def _kernel_launches(text: str, kernel: str) -> int:
+    return sum("custom-call(" in line and kernel in line
+               for line in text.splitlines())
+
+
+@pytest.mark.parametrize("kind,exact,kept,bare", [
+    ("M", True, dict(ssd_fwd=1, conv_silu_fwd=2, ssd_bwd=1, conv_silu_bwd=1),
+     dict(ssd_fwd=2)),
+    ("*", False, dict(flash_fwd=1, flash_bwd_dkv=1), dict(flash_fwd=2)),
+], ids=["mamba-exact", "attention"])
+def test_a_checkpointed_nemotron_layer_launches_each_kept_forward_kernel_once(
+        chip, kind, exact, kept, bare):
+    """One layer of nemotron-pretrain-8k at its widths and 8,192 positions
+    under ``jax.checkpoint`` with the model's policy (``KEPT``): what the
+    kernels' forward rules hand their backward is kept by name, so the
+    compiled gradient launches those forward kernels once (the convolution's,
+    whose output is not on the list, twice); under a bare ``jax.checkpoint``
+    (the parent's) it launches each twice."""
+    from autodist_tpu.models import nemotron_h
+    cfg = nemotron_h.NemotronHConfig(attention_impl="flash", ssm_impl="pallas")
+    block = nemotron_h.NemotronHBlock(cfg, kind, exact)
+    x = ((1, 8192, cfg.d_model), jnp.float32)
+    params = jax.eval_shape(
+        lambda: block.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, cfg.d_model)))
+    )["params"]
+    params = jax.tree_util.tree_map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=chip), params)
+
+    def layer(params, x):
+        y, term = block.apply({"params": params}, x)
+        return jnp.sum(jnp.square(y)) + term
+
+    def launches(policy):
+        fn = jax.value_and_grad(jax.checkpoint(layer, policy=policy), argnums=(0, 1))
+        text = jax.jit(fn).lower(
+            params, jax.ShapeDtypeStruct(*x, sharding=chip)).compile().as_text()
+        return {k: _kernel_launches(text, k) for k in kept}
+
+    assert launches(nemotron_h._keeping(nemotron_h.KEPT)) == kept
+    assert launches(None) == dict(kept, **bare)
+
+
+@pytest.mark.slow    # a whole step: about 100 s
+def test_the_nemotron_cells_step_fits_its_ceiling_with_the_kept_values(topology):
+    """``benchmark/rehearse.py nemotron-pretrain-8k`` as a test: the cell's
+    whole step compiles for the described chip and needs at most 12.4 GiB
+    (10.238 with nothing kept; 2.48 GiB of parameters handed to ``train()``
+    sit beside it on the chip's 15.75)."""
+    from benchmark import harness, rehearse
+    facts = rehearse.compile_cell(harness.load_cell("nemotron-pretrain-8k"),
+                                  topology.devices)
+    assert facts["tpu_custom_call"] and 10.3 < facts["step_gib"] <= 12.4
+
+
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
 @pytest.mark.parametrize("tokens,rows,d", [(8192, 8192, 2048),
                                            (16_384, 16_384, 2048),

@@ -15,6 +15,7 @@ seconds booked by the program itself.
 Pure in-process tests on the CPU mesh (kernels in interpret mode).
 """
 
+import collections
 import contextlib
 import functools
 import importlib
@@ -329,6 +330,60 @@ def test_names_are_metadata_only(monkeypatch):
     assert not _scopes(bare.as_text(debug_info=True),
                        PHASES + named_call.KERNEL_NAMES)
     assert bare.as_text() == named.as_text()
+
+
+def _gradient_through(what):
+    """``(module that names a value, the lowered gradient)`` of one operator
+    outside any ``jax.checkpoint``."""
+    key = jax.random.PRNGKey(0)
+    draw = lambda *shape: jax.random.normal(key, shape, jnp.float32)  # noqa: E731
+    if what == "flash":
+        from autodist_tpu.ops import flash_attention
+        fn = lambda q, k: flash_attention(q, k, k).sum()  # noqa: E731
+        args = (draw(1, 16, 4, 8), draw(1, 16, 2, 8))
+    elif what == "ssd":
+        from autodist_tpu.ops.ssd_scan import ssd_scan
+        fn = lambda x, b: ssd_scan(  # noqa: E731
+            x, jnp.ones((1, 128, 2)), -jnp.ones(2), b, b, jnp.ones(2),
+            impl="pallas").sum()
+        args = (draw(1, 128, 2, 64), draw(1, 128, 1, 128))
+    elif what == "held_pass":
+        from autodist_tpu.models import moe
+        share = functools.partial(
+            moe.routed_experts, top_k=3, first_expert=2, rows_bound=40,
+            form="relu2", route=functools.partial(
+                moe.sigmoid_topk_route, route_norm=True, route_scale=2.5,
+                route_eps=1e-20))
+        scores = jax.nn.sigmoid(draw(64, 8))
+        fn = lambda x, up: share(  # noqa: E731
+            x, scores, None, up, jnp.swapaxes(up, 1, 2), jnp.zeros(8))[0].sum()
+        args = (draw(64, 64), draw(3, 64, 24))
+    else:
+        from autodist_tpu.models.moe import PlainMLP
+        mlp = PlainMLP(16, jnp.float32)
+        fn = lambda p, h: mlp.apply({"params": p}, h).sum()  # noqa: E731
+        args = (mlp.init(key, draw(2, 8))["params"], draw(2, 8))
+    module = {"flash": "ops.flash_attention", "ssd": "ops.ssd_scan",
+              "held_pass": "models.moe", "plain_mlp": "models.moe"}[what]
+    return (importlib.import_module(f"autodist_tpu.{module}"),
+            lambda: jax.jit(jax.grad(fn, argnums=(0, 1))).lower(*args).as_text())
+
+
+@pytest.mark.parametrize("what", ["flash", "ssd", "held_pass", "plain_mlp"])
+def test_a_kept_name_outside_a_checkpoint_lowers_to_nothing(what, monkeypatch):
+    """``checkpoint_name`` marks what a caller's ``jax.checkpoint`` may keep
+    (``models/nemotron_h.py`` ``KEPT``); where no checkpoint surrounds the
+    operator the lowered gradient holds no trace of the name and the
+    operations, one by one, of a build without it."""
+    from autodist_tpu.models import nemotron_h
+    module, lowered = _gradient_through(what)
+    named = lowered()
+    assert not [name for name in nemotron_h.KEPT if name in named]
+    operations = lambda text: collections.Counter(  # noqa: E731
+        re.findall(r"= \"?([a-z_]+\.[a-z_.]+)", text))
+    assert sum(operations(named).values()) > 10
+    monkeypatch.setattr(module, "checkpoint_name", lambda value, name: value)
+    assert operations(lowered()) == operations(named)
 
 
 # ----------------------------------------------------------- log boundaries
