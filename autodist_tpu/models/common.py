@@ -6,6 +6,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from autodist_tpu import telemetry
+
 
 class RMSNorm(nn.Module):
     """``x / sqrt(mean(x^2) + eps) * scale`` over the last axis, no bias and
@@ -40,6 +42,56 @@ def rope(x, positions, theta: float = 10000.0):
     x1, x2 = x32[..., : d // 2], x32[..., d // 2:]
     rotated = jnp.concatenate([-x2, x1], axis=-1)
     return (x32 * cos + rotated * sin).astype(x.dtype)
+
+
+def rope_pairs(x, positions, theta: float = 10000.0,
+               rotary_dim: Optional[int] = None):
+    """Rotary position embedding in the interleaved-pair form, over the last
+    ``rotary_dim`` columns of the head dim (None: all of it).
+
+    x: ``[..., L, H, D]``; positions: ``[L]``. With ``first = D -
+    rotary_dim``, the pair ``(x[first + 2i], x[first + 2i + 1])`` is rotated
+    by ``position * theta^(-2i/rotary_dim)`` and the columns before ``first``
+    pass as they are; the columns keep their order. It is :func:`rope` under
+    the permutation that puts the even columns before the odd ones (what
+    DeepSeek-V3's ``rope_interleave`` does to activations before it rotates
+    halves); a pair's partner is the lane beside it, so nothing is sliced or
+    relaid: one elementwise pass. float32 inside, cast back to ``x.dtype``."""
+    d = x.shape[-1]
+    r = d if rotary_dim is None else rotary_dim
+    if r % 2 or (d - r) % 2:
+        raise ValueError(f"rotary_dim {r} of {d} columns: both parts must be even")
+    inv_freq = theta ** (-jnp.arange(0, r, 2, dtype=jnp.float32) / r)
+    angles = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    still = ((0, 0), (d - r, 0))                  # cos 1, sin 0: not rotated
+    cos = jnp.pad(jnp.repeat(jnp.cos(angles), 2, axis=-1), still,
+                  constant_values=1.0)[:, None, :]
+    sin = jnp.pad(jnp.repeat(jnp.sin(angles), 2, axis=-1), still)[:, None, :]
+    x32 = x.astype(jnp.float32)
+    partner = jnp.where(jnp.arange(d) % 2 == 0, -jnp.roll(x32, -1, axis=-1),
+                        jnp.roll(x32, 1, axis=-1))
+    return (x32 * cos + partner * sin).astype(x.dtype)
+
+
+def keeping(names):
+    """The ``jax.checkpoint`` policy that keeps the values named in ``names``
+    and nothing else, and books what it keeps as it decides (when a
+    checkpointed layer is differentiated, at trace time): gauges
+    ``remat.kept_values`` and ``remat.kept_bytes``, of this call's layers
+    together."""
+    keep = jax.checkpoint_policies.save_only_these_names(*names)
+    kept = [0, 0]
+
+    def policy(prim, *avals, **params):
+        if not keep(prim, *avals, **params):
+            return False
+        kept[0] += 1
+        kept[1] += sum(a.size * a.dtype.itemsize for a in avals)
+        telemetry.gauge("remat.kept_values").set(kept[0])
+        telemetry.gauge("remat.kept_bytes").set(kept[1])
+        return True
+
+    return policy
 
 
 def jit_init(model, *args, rng: Optional[jax.Array] = None):

@@ -1,0 +1,312 @@
+"""DeepSeek-V3 family (``model_type`` ``deepseek_v3``: Kakao's Kanana-2-30B-A3B
+among others) — a decoder-only LM whose attention is *latent* (MLA): keys and
+values come through a normed low-rank latent, one rotary key head is shared
+by all query heads, and a key is wider than a value. The leading dense layers
+are followed by a sigmoid top-k mixture of gated-SiLU experts beside shared
+experts, of which this layer may hold one chip's share.
+
+The equations, from the published ``config.json`` (``q_lora_rank`` null: the
+query has no low-rank path; what the config does not carry is marked
+*assumed*, and listed with its source in
+``benchmark/configs/kanana-2-30b-a3b.json``). ``T`` tokens, width ``d``, ``H``
+heads, a key ``d_n + d_r`` wide (``qk_nope_head_dim`` + ``qk_rope_head_dim``),
+a value ``d_v``, the latent ``r`` (``kv_lora_rank``)::
+
+    x0 = E[tokens]                                          (no scale)
+    per layer l:
+      h  = RMSNorm_attn(x)
+      q  = h.W_q [d, H (d_n + d_r)]                a head: [q_nope d_n | q_rope d_r]     (no bias)
+      [c | k_rope] = h.W_kva [d, r + d_r];  c = RMSNorm_kv(c)   (its own weight, the layer's eps)
+      [k_nope d_n | v d_v] a head = c.W_kvb [r, H (d_n + d_v)]
+      q_rope, k_rope = rope(q_rope), rope(k_rope)  over the pairs (2i, 2i+1), theta, no scaling
+                       (rope_interleave; the columns stay in their order: assumed, immaterial,
+                       q and k share it)
+      k  = [k_nope | k_rope], k_rope the same for every head
+      s_ij = q_i.k_j / sqrt(d_n + d_r)  for j <= i;   a = softmax_j(s).v;   x = x + a.W_o [H d_v, d]
+      h  = RMSNorm_mlp(x)
+      l < n_dense_layers:  m = W_down(silu(W_gate h) * W_up h), width d_ff
+      else:  s = sigmoid(h.Wr [d, E]) in float32
+             chosen = top_k(s + b)          b = expert_bias [E], in the choice only, no gradient
+                                            (noaux_tc; n_group 1, topk_group 1: no group-limited choice)
+             w = s[chosen] / (sum over chosen of s + 1e-20) * routed_scaling_factor    (norm_topk_prob)
+             m = shared(h) + sum over chosen e of w_e . W_down,e(silu(W_gate,e h) * W_up,e h)
+             shared: the n_shared_experts as one gated-SiLU MLP n_shared x d_expert wide
+      x = x + m
+    logits = RMSNorm_f(x).W_head  (untied);  loss = mean next-token cross-entropy
+    after each optimizer step, per expert layer, c_e = rows expert e received in the step:
+      delta = load_balance_coeff * sign(mean(c) - c_e);  b += delta - mean(delta)        (assumed)
+
+No multi-token-prediction module. **One chip's share**, **the expert bias on
+the normal path** and its start from the balancing rule alone are
+``models/afmoe.py``'s, word for word, and the code is the same code:
+``models/moe.py`` ``sigmoid_routed_share`` (``form="gated_silu"``),
+``balanced_optimizer``, ``balance_expert_bias``.
+
+**The attention core** is one call: ``ops/flash_attention.py``
+``flash_attention`` with a value width of its own and the rotary key as
+``k_shared`` (read once a layer through the index maps, never repeated in
+memory; its gradient the sum over the heads), or under ``attention_impl="dot"``
+the quadratic form on an assembled key.
+
+Under ``remat`` every layer is a ``jax.checkpoint`` whose policy keeps the
+values named in :data:`KEPT` and recomputes everything else from the residual
+stream (``models/nemotron_h.py`` has the mechanism's story). Latent
+attention's own lever is in the list: **the normed latent and the rotary
+key, ``r + d_r`` numbers a token, stand in for K and V, ``H (d_n + d_r) + H
+d_v``**, and ``W_kvb``'s product is made again (8.4 MFLOP a token beside the
+core's 168 at 16,384 positions). Beside them the list holds q, what flash's
+forward rule hands its backward (``o``, the log-sum-exp: the core's forward
+is the dearest thing a layer could make again), the router's logits (six
+bfloat16 passes), the MLPs' ``gate`` and ``up`` products (the dense layer's
+and the shared experts') and what pass 0 of the routed share makes for its
+transpose. Every product out of the 2,048-wide stream costs the same to make
+again for the bytes it takes to keep (2,048 operations a byte), so what ends
+the list is memory: the benchmark's cell compiles to 14.39 GiB of a 15.75 GiB
+chip with the list and to 12.85 without its last three names, which cost 12
+ms of a 1,003 ms step to make again (``benchmark/rehearse.py``; PERF.md
+section 6, "PR 37"). Gauge ``mla.kept_bytes_per_token`` says what the
+attention's named values take.
+
+Parameters and the residual stream are float32; the sublayers compute in
+``dtype``; the router reads the float32 normalised input at ``HIGHEST``
+precision, as OLMoE's and AFMoE's do.
+"""
+
+import dataclasses
+import functools
+from typing import Any, Callable, Optional
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from autodist_tpu import telemetry
+from autodist_tpu.models.common import RMSNorm, keeping, rope_pairs
+from autodist_tpu.models.moe import (  # noqa: F401 — the mixture's, under this family's names
+    KEPT_GATE, KEPT_PASS, KEPT_ROUTER_LOGITS, KEPT_UP, GatedMLP, _dense, _INIT,
+    balance_expert_bias, balanced_optimizer as make_optimizer, expert_loads,
+    sigmoid_routed_share, sigmoid_topk_route, sown_loads)
+from autodist_tpu.models.transformer_lm import (  # noqa: F401 — synthetic_batch re-exported
+    causal_mask, dot_product_attention, synthetic_batch)
+from autodist_tpu.ops.flash_attention import KEPT_NAME as KEPT_FLASH
+
+KEPT_QUERY = "mla_query"              # q, before its rotary columns turn
+KEPT_LATENT = "mla_latent"            # c, normed
+KEPT_ROPE_KEY = "mla_rope_key"        # the one rotary key head, turned
+# What a checkpointed layer keeps for its backward (module docstring; PERF.md
+# section 6, "PR 37", has the lists that were tried)
+KEPT = (KEPT_QUERY, KEPT_LATENT, KEPT_ROPE_KEY, KEPT_FLASH, KEPT_ROUTER_LOGITS,
+        KEPT_GATE, KEPT_UP, KEPT_PASS)
+
+
+@dataclasses.dataclass(frozen=True)
+class DeepseekV3Config:
+    """Defaults are Kanana-2-30B-A3B's published sizes, every expert held."""
+    vocab_size: int = 128256
+    d_model: int = 2048
+    n_layers: int = 48
+    n_heads: int = 32
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    kv_lora_rank: int = 512
+    n_dense_layers: int = 1           # first_k_dense_replace
+    d_ff: int = 6144                  # the dense layers' width
+    d_expert: int = 768               # one expert's width
+    n_experts_routed: int = 128       # the router's width
+    experts_held: int = 128           # experts whose banks live here ...
+    first_expert_held: int = 0        # ... from this one on
+    top_k: int = 6
+    n_shared_experts: int = 2
+    rows_bound: Optional[int] = None  # held rows a pass computes; None: tokens x top_k
+    route_norm: bool = True
+    route_scale: float = 2.448
+    route_eps: float = 1e-20
+    load_balance_coeff: float = 1e-3
+    rope_theta: float = 1e6
+    rms_eps: float = 1e-6
+    max_len: int = 32768
+    dtype: Any = jnp.bfloat16         # what the sublayers compute in
+    attention_impl: str = "dot"       # "dot" | "flash"
+    fused_head: bool = False          # pallas head + loss (ops/fused_xent)
+    remat: bool = False               # jax.checkpoint around every layer, keeping KEPT
+
+    def __post_init__(self):
+        if self.attention_impl not in ("dot", "flash"):
+            raise ValueError(f"Unknown attention_impl {self.attention_impl!r}; "
+                             f"valid: 'dot', 'flash'")
+        if self.qk_rope_head_dim % 2 or self.qk_nope_head_dim % 2:
+            raise ValueError("the key's two parts must be even")
+        if not 0 <= self.n_dense_layers <= self.n_layers:
+            raise ValueError("n_dense_layers must be in [0, n_layers]")
+        if not 1 <= self.top_k <= self.n_experts_routed:
+            raise ValueError("top_k must be in [1, n_experts_routed]")
+        if not (0 <= self.first_expert_held and self.experts_held >= 1
+                and self.first_expert_held + self.experts_held
+                <= self.n_experts_routed):
+            raise ValueError("the experts held must lie inside the router's width")
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
+class LatentAttention(nn.Module):
+    """Causal multi-head latent attention: the keys' position-free part and
+    the values through a normed ``kv_lora_rank``-wide latent, one rotary key
+    head for all query heads, no bias, no gate."""
+    config: DeepseekV3Config
+
+    @nn.compact
+    def __call__(self, h):
+        cfg = self.config
+        b, length, _ = h.shape
+        heads, d_n, d_r, d_v = (cfg.n_heads, cfg.qk_nope_head_dim,
+                                cfg.qk_rope_head_dim, cfg.v_head_dim)
+        kept = []       # bytes of what the layer's checkpoint keeps of this
+
+        def name(x, what):
+            kept.append(x.size * x.dtype.itemsize)
+            return checkpoint_name(x, what)
+
+        with jax.named_scope("mla.q_proj"):
+            q = name(_dense(heads * cfg.qk_head_dim, cfg.dtype, "query")(h),
+                     KEPT_QUERY).reshape(b, length, heads, cfg.qk_head_dim)
+        with jax.named_scope("mla.kv_down"):
+            down = _dense(cfg.kv_lora_rank + d_r, cfg.dtype, "kv_down")(h)
+            c = name(RMSNorm(cfg.rms_eps, cfg.dtype, name="kv_norm")(
+                down[..., :cfg.kv_lora_rank]), KEPT_LATENT)
+        with jax.named_scope("mla.kv_up"):
+            kv = _dense(heads * (d_n + d_v), cfg.dtype, "kv_up")(c).reshape(
+                b, length, heads, d_n + d_v)
+            k_nope, v = kv[..., :d_n], kv[..., d_n:]
+        with jax.named_scope("mla.rope"):
+            positions = jnp.arange(length)
+            q = rope_pairs(q, positions, cfg.rope_theta, d_r)
+            k_rope = name(rope_pairs(down[..., None, cfg.kv_lora_rank:],
+                                     positions, cfg.rope_theta), KEPT_ROPE_KEY)
+        if cfg.attention_impl == "flash" and not self.is_initializing():
+            from autodist_tpu.ops.flash_attention import flash_attention
+            ctx = flash_attention(q, k_nope, v, causal=True,
+                                  k_shared=k_rope[:, :, 0, :])
+            # KEPT_FLASH: o and one float32 lse a head
+            kept.append(ctx.size * ctx.dtype.itemsize + b * length * heads * 4)
+        else:
+            k = jnp.concatenate([k_nope, jnp.broadcast_to(
+                k_rope, (b, length, heads, d_r))], axis=-1)
+            ctx = dot_product_attention(q, k, v, causal_mask(length, cfg.dtype),
+                                        cfg.dtype)
+        telemetry.gauge("mla.kept_bytes_per_token").set(
+            sum(kept) // (b * length) if cfg.remat else 0)
+        with jax.named_scope("mla.out_proj"):
+            return _dense(cfg.d_model, cfg.dtype, "out")(
+                ctx.reshape(b, length, heads * d_v))
+
+
+class SharedAndRoutedExperts(nn.Module):
+    """The expert layer's MLP: the shared experts as one gated-SiLU MLP every
+    token passes, beside this chip's share of the sigmoid top-k routed
+    experts (``models/moe.py`` :func:`sigmoid_routed_share`, whose parameters
+    live in this module's scope). ``__call__(h)`` takes the float32
+    normalised input ``[B, S, d]`` and returns ``(m float32, the bias term
+    of the loss)``."""
+    config: DeepseekV3Config
+
+    @nn.compact
+    def __call__(self, h):
+        cfg = self.config
+        with jax.named_scope("moe.shared"):
+            shared = GatedMLP(cfg.d_expert * cfg.n_shared_experts, cfg.dtype,
+                              name="shared")(h.astype(cfg.dtype))
+        y, bias_term = sigmoid_routed_share(
+            self, h, router_width=cfg.n_experts_routed,
+            experts_held=cfg.experts_held,
+            first_expert_held=cfg.first_expert_held, top_k=cfg.top_k,
+            d_expert=cfg.d_expert, rows_bound=cfg.rows_bound,
+            route=functools.partial(sigmoid_topk_route,
+                                    route_norm=cfg.route_norm,
+                                    route_scale=cfg.route_scale,
+                                    route_eps=cfg.route_eps),
+            dtype=cfg.dtype, form="gated_silu")
+        return shared.astype(jnp.float32) + y, bias_term
+
+
+class DeepseekV3Block(nn.Module):
+    """``x + Attn(RMSNorm(x))``, then ``+ FFN(RMSNorm(.))``; ``(x, the
+    layer's bias term)``."""
+    config: DeepseekV3Config
+    dense: bool
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        x = x + LatentAttention(cfg, name="attn")(
+            RMSNorm(cfg.rms_eps, cfg.dtype, name="ln_attn")(x))
+        h = RMSNorm(cfg.rms_eps, jnp.float32, name="ln_mlp")(x)
+        if self.dense:
+            m = GatedMLP(cfg.d_ff, cfg.dtype, name="mlp")(h.astype(cfg.dtype))
+            bias_term = jnp.zeros((), jnp.float32)
+        else:
+            m, bias_term = SharedAndRoutedExperts(cfg, name="moe")(h)
+        return x + m, bias_term
+
+
+class DeepseekV3(nn.Module):
+    """``tokens [B, L] -> (logits or hidden, bias term)``; the bias term is
+    the sum over the expert layers of the zero-valued term whose gradient is
+    the load error (``models/afmoe.py``'s docstring)."""
+    config: DeepseekV3Config
+
+    @nn.compact
+    def __call__(self, tokens, return_hidden: bool = False):
+        cfg = self.config
+        x = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=jnp.float32,
+                     param_dtype=jnp.float32, embedding_init=_INIT,
+                     name="embed")(tokens)
+        block = DeepseekV3Block
+        if cfg.remat and not self.is_initializing():
+            block = nn.remat(DeepseekV3Block, policy=keeping(KEPT))
+            telemetry.gauge("remat.layers").set(cfg.n_layers)
+        bias_term = jnp.zeros((), jnp.float32)
+        for i in range(cfg.n_layers):
+            x, term = block(cfg, i < cfg.n_dense_layers, name=f"block_{i}")(x)
+            bias_term = bias_term + term
+        x = RMSNorm(cfg.rms_eps, cfg.dtype, name="ln_f")(x)
+        if return_hidden:
+            # The fused-head loss owns the projection; the head's parameters
+            # exist from init, which runs the path below.
+            return x, bias_term
+        return _dense(cfg.vocab_size, cfg.dtype, "lm_head")(x), bias_term
+
+
+def make_loss_fn(model: DeepseekV3) -> Callable:
+    """Mean next-token cross-entropy (+ the expert layers' bias terms, zero in
+    value); batch = ``{"tokens": int32 [B, L+1]}``."""
+    cfg = model.config
+
+    def loss_fn(params, batch):
+        tokens = batch["tokens"]
+        inputs, targets = tokens[:, :-1], tokens[:, 1:]
+        if cfg.fused_head:
+            from autodist_tpu.models.common import fused_lm_head_nll
+            h, bias_term = model.apply({"params": params}, inputs,
+                                       return_hidden=True)
+            nll = fused_lm_head_nll(h, params, targets)
+        else:
+            logits, bias_term = model.apply({"params": params}, inputs)
+            logprobs = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+            nll = -jnp.take_along_axis(logprobs, targets[..., None],
+                                       axis=-1)[..., 0]
+        return nll.mean() + bias_term
+
+    return loss_fn
+
+
+def init_params(config: DeepseekV3Config, rng: Optional[jax.Array] = None,
+                batch_size: int = 2):
+    from autodist_tpu.models.common import jit_init
+    rng = rng if rng is not None else jax.random.PRNGKey(0)
+    model = DeepseekV3(config)
+    tokens = jnp.zeros((batch_size, min(8, config.max_len)), jnp.int32)
+    return model, jit_init(model, tokens, rng=rng)

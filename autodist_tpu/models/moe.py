@@ -45,11 +45,13 @@ from autodist_tpu.models.transformer_lm import (MultiHeadAttention,
                                                 TransformerLMConfig, causal_mask)
 
 # ``checkpoint_name``s a caller's ``jax.checkpoint`` may list in its policy
-# (``models/nemotron_h.py`` ``KEPT``); the identity, lowered to nothing,
-# outside one: :class:`PlainMLP`'s ``up`` product, the router's logits in
+# (``models/nemotron_h.py`` and ``models/deepseek_v3.py`` ``KEPT``); the
+# identity, lowered to nothing, outside one: :class:`PlainMLP`'s ``up``
+# product, :class:`GatedMLP`'s ``gate`` and ``up``, the router's logits in
 # :func:`sigmoid_routed_share` (six bfloat16 passes to make again), and what
 # pass 0 of :func:`_held_passes` makes for its transpose.
 KEPT_UP = "mlp_up"
+KEPT_GATE = "mlp_gate"
 KEPT_ROUTER_LOGITS = "router_logits"
 KEPT_PASS = "held_pass_0"
 
@@ -674,9 +676,10 @@ class GatedMLP(nn.Module):
 
     @nn.compact
     def __call__(self, h):
-        hidden = (nn.silu(_dense(self.width, self.dtype, "gate")(h))
-                  * _dense(self.width, self.dtype, "up")(h))
-        return _dense(h.shape[-1], self.dtype, "down")(hidden)
+        gate = checkpoint_name(_dense(self.width, self.dtype, "gate")(h),
+                               KEPT_GATE)
+        up = checkpoint_name(_dense(self.width, self.dtype, "up")(h), KEPT_UP)
+        return _dense(h.shape[-1], self.dtype, "down")(nn.silu(gate) * up)
 
 
 class PlainMLP(nn.Module):

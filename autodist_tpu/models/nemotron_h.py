@@ -105,7 +105,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from autodist_tpu import telemetry
-from autodist_tpu.models.common import RMSNorm
+from autodist_tpu.models.common import RMSNorm, keeping as _keeping
 from autodist_tpu.models.moe import (  # noqa: F401 — the mixture's, under this family's names
     KEPT_PASS, KEPT_ROUTER_LOGITS, KEPT_UP, PlainMLP, _INIT, balance_expert_bias,
     balanced_optimizer as make_optimizer, expert_loads, sigmoid_routed_share,
@@ -372,27 +372,6 @@ class NemotronHBlock(nn.Module):
             h = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm")(x)
             m = GroupedAttention(cfg, name="attn")(h)
         return x + m, bias_term
-
-
-def _keeping(names):
-    """The ``jax.checkpoint`` policy that keeps the values named in ``names``
-    and nothing else, and books what it keeps as it decides (when a
-    checkpointed layer is differentiated, at trace time): gauges
-    ``remat.kept_values`` and ``remat.kept_bytes``, of this call's layers
-    together."""
-    keep = jax.checkpoint_policies.save_only_these_names(*names)
-    kept = [0, 0]
-
-    def policy(prim, *avals, **params):
-        if not keep(prim, *avals, **params):
-            return False
-        kept[0] += 1
-        kept[1] += sum(a.size * a.dtype.itemsize for a in avals)
-        telemetry.gauge("remat.kept_values").set(kept[0])
-        telemetry.gauge("remat.kept_bytes").set(kept[1])
-        return True
-
-    return policy
 
 
 class NemotronH(nn.Module):

@@ -24,8 +24,9 @@ its keys see — one recomputed score tile (scores, p, dP, ds) feeds all three
 gradients, five products where two kernels ran seven. The tile is held
 transposed as in the forward, so every product is a form the forward runs and
 lse / D enter as the lane-dense rows they are stored as. Past
-``_RESIDENT_DQ_BYTES`` of dQ a (batch, head) (L 16,384 at head_dim 128) the same
-block body runs as two kernels, each recomputing the tile:
+``_RESIDENT_DQ_BYTES`` of dQ a (batch, head) (L 49,152 at a key width of 64,
+24,576 at 128, 16,384 at latent attention's 192) the same block body runs as
+two kernels, each recomputing the tile:
 
 - dK/dV (``flash_bwd_dkv``): grid (batch*heads, k-blocks, q-blocks) — a k block
   accumulates p^T dO and ds^T q across its query blocks in VMEM scratch.
@@ -43,6 +44,15 @@ copied where they are a grid step), tiles the lower edge crosses run the masked
 body. Grouped KV heads: K and V keep their ``H_kv`` heads in memory and query
 head ``n`` reads head ``n // (H / H_kv)`` through the index maps; a group's
 float32 dK / dV are summed outside the kernels.
+
+Two widths (latent attention): the values may be narrower than the keys (the
+accumulator, ``o``, ``dO`` and ``dV`` are the values' wide, ``q``, ``k``,
+``dQ`` and ``dK`` the keys', the scale ``1 / sqrt(key width)``), and the keys'
+trailing columns may be ONE operand all heads share (``k_shared``, the rotary
+key head): the score tile is then the sum of two products, ``q_nope k_nope^T
++ q_rope k_rope^T``, the shared block is read through an index map that
+ignores the head, and each head's float32 part of its gradient is summed
+outside the kernels as a group's is.
 
 On non-TPU backends the kernels run in pallas interpret mode, so tests exercise
 the same code path on the CPU-sim mesh.
@@ -100,12 +110,26 @@ DEFAULT_K_BLOCK = 512
 _KEY_TILE = 512             # keys a score tile of the forward: [512, bq] f32
 _RESIDENT_KV_BYTES = 1 << 20     # K (or V) of one (batch, head) kept in VMEM
 _STREAM_K_BLOCK = 2048           # K/V rows a grid step beyond that
-# The one-pass backward's f32 dQ of one (batch, head). 4 MiB since PR 29 (L 8,192
-# at head_dim 128, trinity-pretrain-8k's call: one pass 4.99 ms under a window of
-# 2,048 and 8.46 without, where the two kernels took 10.60 and 15.55; L 16,384
-# at head_dim 64: 7.30 against 14.37; PERF.md §6 "PR 29").
-_RESIDENT_DQ_BYTES = 4 << 20
-_BACKWARD_VMEM_LIMIT = 48 << 20  # scoped VMEM the one-pass backward asks for
+# The one-pass backward's f32 dQ of one (batch, head): Lq x the KEY width x 4
+# bytes, so the limit in positions falls with the width. 4 MiB from PR 29 (L
+# 16,384 at head_dim 64, 8,192 at 128: trinity-pretrain-8k's call, one pass 4.99
+# ms under a window of 2,048 and 8.46 without, where the two kernels took 10.60
+# and 15.55; L 16,384 at head_dim 64: 7.30 against 14.37; PERF.md §6 "PR 29");
+# 12 MiB since PR 37: L 16,384 at latent attention's 192 (kanana-pretrain-16k's
+# call: one pass 49.3 ms where the two kernels took 87.8; PERF.md §6 "PR 37"),
+# 24,576 at 128, 49,152 at 64.
+_RESIDENT_DQ_BYTES = 12 << 20
+_SMALL_DQ_BYTES = 4 << 20
+
+
+def _backward_vmem_limit(dq_bytes: int) -> int:
+    """Scoped VMEM the one-pass backward asks for: 48 MiB up to PR 29's 4 MiB
+    of dQ (every call older than PR 37 compiles what it compiled), 100 MiB
+    past it (12 MiB of dQ 192 wide, q, dO and dQ's output twice: the kernel
+    needs 57.4 MiB and the compiler refuses it 56)."""
+    return (48 << 20) if dq_bytes <= _SMALL_DQ_BYTES else (100 << 20)
+
+
 # ``jax.ad_checkpoint.checkpoint_name`` of what the forward rule hands the
 # backward beside its inputs: the output and the log-sum-exp
 KEPT_NAME = "flash_residuals"
@@ -183,8 +207,8 @@ def _band_tile_counts(q_lo, k_lo, valid, bq: int, bk: int, sub: int,
 
 def _attend_block(q_ref, k_ref, v_ref, state, *, q_lo, k_lo, valid, sub: int,
                   causal: bool, scale: float, guard_empty_rows: bool,
-                  window=None):
-    """Online-softmax update of ``state = (m [1, bq], l [1, bq], acc [d, bq])``
+                  window=None, ks_ref=None):
+    """Online-softmax update of ``state = (m [1, bq], l [1, bq], acc [dv, bq])``
     against the VMEM-resident K/V block — the single definition shared by the
     plain forward kernel and the carry variant.
 
@@ -207,13 +231,18 @@ def _attend_block(q_ref, k_ref, v_ref, state, *, q_lo, k_lo, valid, sub: int,
     ``exp(NEG_INF - NEG_INF) = 1``. With zero offsets and no window every
     query sees key 0 in its first tile and the guard is dead. ``window``:
     keys a query sees, itself included (None: all before it); the tiles below
-    the band are not walked (:func:`_band_tile_counts`)."""
+    the band are not walked (:func:`_band_tile_counts`). ``ks_ref``: the
+    keys' trailing columns where every query head shares them (``[1, bk,
+    d_s]``; ``k_ref`` then holds the leading ``d - d_s``): the score tile is
+    two products, the second against the shared operand."""
     q = q_ref[0]                                      # [bq, d]
     bq, bk = q.shape[0], k_ref.shape[1]
     # scale once per q block where that is exact, else on the score tile.
     prescale = _scale_is_exact(scale)
     if prescale:
         q = q * jnp.asarray(scale, q.dtype)
+    if ks_ref is not None:
+        q, q_s = q[:, :k_ref.shape[2]], q[:, k_ref.shape[2]:]
     n_lo, n_ps, n_pe, n_need = _band_tile_counts(q_lo, k_lo, valid, bq, bk, sub,
                                                  causal, window)
 
@@ -225,6 +254,10 @@ def _attend_block(q_ref, k_ref, v_ref, state, *, q_lo, k_lo, valid, sub: int,
         scores = jax.lax.dot_general(
             k_t, q, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)       # [sub, bq]
+        if ks_ref is not None:
+            scores += jax.lax.dot_general(
+                ks_ref[0, pl.ds(start, sub), :], q_s, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
         if not prescale:
             scores = scale * scores
         if masked:
@@ -271,8 +304,11 @@ def _loop(lo, hi, body, state):
     return jax.lax.fori_loop(lo, hi, body, state)
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
-                  lk: int, sub: int, causal: bool, scale: float, window=None):
+def _flash_kernel(q_ref, k_ref, v_ref, *refs, lk: int, sub: int, causal: bool,
+                  scale: float, window=None):
+    # refs: [the keys' shared columns,] o, lse | acc, m, l
+    ks_ref = refs[0] if len(refs) == 6 else None
+    o_ref, lse_ref, acc_ref, m_ref, l_ref = refs[-5:]
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     n_k = pl.num_programs(2)
@@ -297,12 +333,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
             q_ref, k_ref, v_ref, (m_ref[:], l_ref[:], acc_ref[:]),
             q_lo=q_start, k_lo=k_start, valid=_valid_keys(lk, k_start, bk),
             sub=sub, causal=causal, scale=scale,
-            guard_empty_rows=window is not None, window=window)
+            guard_empty_rows=window is not None, window=window, ks_ref=ks_ref)
 
     @pl.when(ki == n_k - 1)
     def _finish():
         l_fin = jnp.maximum(l_ref[:], 1e-30)                      # [1, bq]
-        o_ref[0] = (acc_ref[:] / l_fin).T.astype(o_ref.dtype)     # [bq, d]
+        o_ref[0] = (acc_ref[:] / l_fin).T.astype(o_ref.dtype)     # [bq, dv]
         # Per-row logsumexp residual for the backward pass. Padding query rows get
         # a finite lse too (zero-padded q still attends real keys); the backward is
         # safe for them ONLY because dO is zero-padded there — do not rely on lse
@@ -360,29 +396,48 @@ def _kv_row(group: int):
     return (lambda bh: bh) if group == 1 else (lambda bh: bh // group)
 
 
+def _shared_cols(q, k, k_shared) -> int:
+    """Trailing key columns every query head shares (0: none); the widths of
+    ``q``, ``k`` and ``k_shared`` must add up."""
+    d_s = 0 if k_shared is None else k_shared.shape[-1]
+    if k.shape[-1] + d_s != q.shape[-1]:
+        raise ValueError(
+            f"q is {q.shape[-1]} wide, k {k.shape[-1]}"
+            + (f" + {d_s} shared" if d_s else ""))
+    return d_s
+
+
 def _flash_forward(q, k, v, causal: bool, q_block, k_block, interpret: bool,
-                   window=None):
-    """Returns (out [B, Lq, H, D], lse [B*H, n_q, bq] f32). ``k`` / ``v``
-    may hold fewer heads than ``q`` (grouped KV heads)."""
+                   window=None, k_shared=None):
+    """Returns (out [B, Lq, H, Dv], lse [B*H, n_q, bq] f32). ``k`` / ``v``
+    may hold fewer heads than ``q`` (grouped KV heads), ``v`` another width
+    than ``q`` and ``k`` (the scale is the key width's), and ``k_shared``
+    ``[B, Lk, Ds]`` the keys' trailing columns where all heads share them
+    (``k`` then holds the leading ``D - Ds``)."""
     b, lq, h, d = q.shape
-    lk, h_kv = k.shape[1], k.shape[2]
+    lk, h_kv, dv = k.shape[1], k.shape[2], v.shape[3]
+    d_s = _shared_cols(q, k, k_shared)
+    d_k = d - d_s
     group = _kv_group(q, k)
     kv_row = _kv_row(group)
     scale = 1.0 / (d ** 0.5)
 
     # Collapse (batch, head) into the grid's first axis: [B*H, L, D].
     qf = q.transpose(0, 2, 1, 3).reshape(b * h, lq, d)
-    kf = k.transpose(0, 2, 1, 3).reshape(b * h_kv, lk, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * h_kv, lk, d)
+    kf = k.transpose(0, 2, 1, 3).reshape(b * h_kv, lk, d_k)
+    vf = v.transpose(0, 2, 1, 3).reshape(b * h_kv, lk, dv)
 
-    bq, bk, sub = _forward_blocks(lq, lk, d, q.dtype.itemsize, q_block, k_block)
+    bq, bk, sub = _forward_blocks(lq, lk, max(d_k, dv), q.dtype.itemsize,
+                                  q_block, k_block)
     n_q = pl.cdiv(lq, bq)
     if n_q * bq - lq:
         qf = jnp.pad(qf, ((0, 0), (0, n_q * bq - lq), (0, 0)))
     n_k = pl.cdiv(lk, bk)
     if n_k * bk - lk:
-        kf = jnp.pad(kf, ((0, 0), (0, n_k * bk - lk), (0, 0)))
-        vf = jnp.pad(vf, ((0, 0), (0, n_k * bk - lk), (0, 0)))
+        pad = ((0, 0), (0, n_k * bk - lk), (0, 0))
+        kf, vf = jnp.pad(kf, pad), jnp.pad(vf, pad)
+        if d_s:
+            k_shared = jnp.pad(k_shared, pad)
 
     plain, masked, skipped = _count_tiles(lq, lk, bq, bk, sub, causal, window)
     telemetry.gauge("flash.fwd.tiles_plain").set(plain)
@@ -390,6 +445,9 @@ def _flash_forward(q, k, v, causal: bool, q_block, k_block, interpret: bool,
     telemetry.gauge("flash.fwd.tiles_skipped").set(skipped)
     telemetry.gauge("flash.window").set(window or 0)
     telemetry.gauge("flash.kv_group").set(group)
+    telemetry.gauge("flash.d_qk").set(d)
+    telemetry.gauge("flash.d_v").set(dv)
+    telemetry.gauge("flash.shared_key_cols").set(d_s)
 
     kernel = functools.partial(_flash_kernel, lk=lk, sub=sub, causal=causal,
                                scale=scale, window=window)
@@ -397,24 +455,34 @@ def _flash_forward(q, k, v, causal: bool, q_block, k_block, interpret: bool,
         # A K/V block above the diagonal names the last one below it again
         # (and one below the band the first one inside it), so its (skipped)
         # grid step copies nothing in.
-        def kv_index(bh, i, j):
+        def kv_block(i, j):
             j = jnp.minimum(j, ((i + 1) * bq - 1) // bk)
             if window is not None:
                 j = jnp.maximum(j, jnp.maximum(i * bq - window + 1, 0) // bk)
-            return kv_row(bh), j, 0
+            return j
     else:
-        def kv_index(bh, i, j):
-            return kv_row(bh), j, 0
+        def kv_block(i, j):
+            return j
+
+    def kv_index(bh, i, j):
+        return kv_row(bh), kv_block(i, j), 0
+
+    in_specs = [
+        pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
+        pl.BlockSpec((1, bk, d_k), kv_index),
+        pl.BlockSpec((1, bk, dv), kv_index),
+    ]
+    operands = (qf, kf, vf)
+    if d_s:     # one row a batch entry, whatever the head
+        in_specs.append(pl.BlockSpec(
+            (1, bk, d_s), lambda bh, i, j: (bh // h, kv_block(i, j), 0)))
+        operands += (k_shared,)
     out, lse = named_pallas_call(
         "flash_fwd", kernel,
         grid=(b * h, n_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, bk, d), kv_index),
-            pl.BlockSpec((1, bk, d), kv_index),
-        ],
+        in_specs=in_specs,
         out_specs=(
-            pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
+            pl.BlockSpec((1, bq, dv), lambda bh, i, j: (bh, i, 0)),
             # VMEM bound: the whole [n_q, bq] lse plane (one f32 row per query,
             # ~4*Lq bytes) stays resident per grid row in this kernel and both
             # backward kernels, so max single-shard sequence length is capped at
@@ -425,20 +493,20 @@ def _flash_forward(q, k, v, causal: bool, q_block, k_block, interpret: bool,
             pl.BlockSpec((1, n_q, bq), lambda bh, i, j: (bh, 0, 0)),
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((b * h, n_q * bq, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, n_q * bq, dv), q.dtype),
             jax.ShapeDtypeStruct((b * h, n_q, bq), jnp.float32),
         ),
         scratch_shapes=[
-            pltpu.VMEM((d, bq), jnp.float32),   # acc, transposed
+            pltpu.VMEM((dv, bq), jnp.float32),  # acc, transposed
             pltpu.VMEM((1, bq), jnp.float32),   # running max
             pltpu.VMEM((1, bq), jnp.float32),   # running denominator
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(qf, kf, vf)
+    )(*operands)
 
-    out = out[:, :lq, :].reshape(b, h, lq, d).transpose(0, 2, 1, 3)
+    out = out[:, :lq, :].reshape(b, h, lq, dv).transpose(0, 2, 1, 3)
     return out, lse
 
 
@@ -478,7 +546,8 @@ def _query_tile_counts(q_lo, k_lo, valid, bq: int, bk: int, sub: int,
 
 def _backward_block(q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
                     dq_acc, dk_acc, dv_acc, *, row0, q_lo, k_lo, valid,
-                    sub: int, causal: bool, scale: float, window=None):
+                    sub: int, causal: bool, scale: float, window=None,
+                    ks_ref=None, dks_acc=None):
     """The backward's block math against one VMEM-resident K/V block — the
     single definition shared by the one-pass kernel and the two kernels of the
     split path. The q rows of the grid step are walked in tiles of ``sub``
@@ -487,9 +556,13 @@ def _backward_block(q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
     a second, unmasked body made the kernel 0.3–2.4% SLOWER on the chip, the
     iota / compare / select costing less than a second loop), and one
     recomputed score tile feeds every accumulator that is not None:
-    ``dv_acc`` / ``dk_acc`` ``[bk, d]`` (dK unscaled unless the scale went
-    onto q exactly) and ``dq_acc[t]`` ``[d, sub]``, dQ of tile ``t``
-    transposed and unscaled.
+    ``dv_acc`` ``[bk, dv]``, ``dk_acc`` ``[bk, d]`` (dK unscaled unless the
+    scale went onto q exactly) and ``dq_acc[t]`` ``[d, sub]``, dQ of tile
+    ``t`` transposed and unscaled. ``ks_ref`` ``[1, bk, d_s]``: the keys'
+    trailing columns where every query head shares them; ``k_ref`` and
+    ``dk_acc`` then hold the leading ``d - d_s``, the score tile is two
+    products and ``dks_acc`` ``[bk, d_s]`` takes this head's part of the
+    shared columns' gradient.
 
     The tile is held TRANSPOSED, ``[bk keys, sub queries]``, as the forward
     holds it: ``s^T = k q^T`` and ``dP^T = v dO^T`` contract the head dim of
@@ -512,6 +585,8 @@ def _backward_block(q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
     prescale = _scale_is_exact(scale)
     k = k_ref[0]                                          # [bk, d]
     v = v_ref[0]
+    d_k = k.shape[1]
+    ks = None if ks_ref is None else ks_ref[0]            # [bk, d_s]
     t_need, _, _, t_end = _query_tile_counts(q_lo, k_lo, valid, bq, bk, sub,
                                              causal, window)
 
@@ -521,11 +596,17 @@ def _backward_block(q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
         do = do_ref[0, pl.ds(start, sub), :]
         if prescale:
             q = q * jnp.asarray(scale, q.dtype)
+        if ks is not None:
+            q, q_s = q[:, :d_k], q[:, d_k:]
         lse = lse_ref[0, pl.ds(row0 + t, 1), :]           # [1, sub]
         dd = dd_ref[0, pl.ds(row0 + t, 1), :]
         scores = jax.lax.dot_general(
             k, q, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)           # [bk, sub]
+        if ks is not None:
+            scores += jax.lax.dot_general(
+                ks, q_s, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
         if not prescale:
             scores = scale * scores
         p = jnp.exp(scores - lse)
@@ -550,10 +631,20 @@ def _backward_block(q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
             dv_acc[:] += jnp.dot(p.astype(do.dtype), do,
                                  preferred_element_type=jnp.float32)
             dk_acc[:] += jnp.dot(ds, q, preferred_element_type=jnp.float32)
-        if dq_acc is not None:
+            if ks is not None:
+                dks_acc[:] += jnp.dot(ds, q_s,
+                                      preferred_element_type=jnp.float32)
+        if dq_acc is not None and ks is None:
             dq_acc[t] += jax.lax.dot_general(
                 k, ds, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)       # [d, sub]
+        elif dq_acc is not None:
+            dq_acc[t, :d_k, :] += jax.lax.dot_general(
+                k, ds, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dq_acc[t, d_k:, :] += jax.lax.dot_general(
+                ks, ds, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
         return carry
 
     if n_t == 1 and not _is_static(t_need):
@@ -563,20 +654,39 @@ def _backward_block(q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
         _loop(t_need, t_end, tile, None)
 
 
-def _finish_dkdv(dk_ref, dv_ref, dk_acc, dv_acc, scale: float):
+def _finish_dkdv(dk_ref, dv_ref, dk_acc, dv_acc, scale: float,
+                 dks_ref=None, dks_acc=None):
     # dK's q carried the scale where that is exact
-    dk = dk_acc[:] if _scale_is_exact(scale) else scale * dk_acc[:]
-    dk_ref[0] = dk.astype(dk_ref.dtype)
+    exact = _scale_is_exact(scale)
+    dk_ref[0] = (dk_acc[:] if exact else scale * dk_acc[:]).astype(dk_ref.dtype)
     dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+    if dks_ref is not None:
+        dks_ref[0] = (dks_acc[:] if exact else scale * dks_acc[:]).astype(
+            dks_ref.dtype)
+
+
+def _shared_refs(refs, shared: bool, n_out: int):
+    """A backward kernel's ``refs`` after q, dO, lse, D, k, v — ``[ks,] outs
+    [, dks] | scratch [, dks_acc]`` — as ``(ks_ref, dks_ref, dks_acc, the
+    rest)``; ``n_out`` outputs without dks (0: a kernel that writes no
+    dK)."""
+    if not shared:
+        return None, None, None, refs
+    ks_ref, refs = refs[0], refs[1:]
+    if not n_out:
+        return ks_ref, None, None, refs
+    return (ks_ref, refs[n_out], refs[-1],
+            refs[:n_out] + refs[n_out + 1:-1])
 
 
 def _flash_bwd_kernel(off_ref, q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
-                      dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
-                      lk: int, sub: int, causal: bool, scale: float,
-                      window=None):
+                      *refs, lk: int, sub: int, causal: bool, scale: float,
+                      window=None, shared: bool = False):
     """The one-pass backward: q, dO and the float32 dQ accumulator of one
     (batch, head) stay in VMEM across its K/V blocks (the grid's second axis);
     a grid step finishes dK and dV of its block, the last writes dQ."""
+    ks_ref, dks_ref, dks_acc, refs = _shared_refs(refs, shared, 3)
+    dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = refs
     ki = pl.program_id(1)
     bk = k_ref.shape[1]
 
@@ -586,13 +696,15 @@ def _flash_bwd_kernel(off_ref, q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
 
     dk_acc[:] = jnp.zeros_like(dk_acc)
     dv_acc[:] = jnp.zeros_like(dv_acc)
+    if shared:
+        dks_acc[:] = jnp.zeros_like(dks_acc)
     k_start = ki * bk
     _backward_block(q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
                     dq_acc, dk_acc, dv_acc, row0=0, q_lo=off_ref[0],
                     k_lo=off_ref[1] + k_start,
                     valid=_valid_keys(lk, k_start, bk), sub=sub, causal=causal,
-                    scale=scale, window=window)
-    _finish_dkdv(dk_ref, dv_ref, dk_acc, dv_acc, scale)
+                    scale=scale, window=window, ks_ref=ks_ref, dks_acc=dks_acc)
+    _finish_dkdv(dk_ref, dv_ref, dk_acc, dv_acc, scale, dks_ref, dks_acc)
 
     @pl.when(ki == pl.num_programs(1) - 1)
     def _finish():
@@ -607,9 +719,11 @@ def _flash_bwd_kernel(off_ref, q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
 
 
 def _flash_bwd_dkdv_kernel(off_ref, q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
-                           dk_ref, dv_ref, dk_acc, dv_acc, *,
-                           lk: int, causal: bool, scale: float, window=None):
+                           *refs, lk: int, causal: bool, scale: float,
+                           window=None, shared: bool = False):
     """The split path's dK/dV: a K/V block accumulates over the q blocks."""
+    ks_ref, dks_ref, dks_acc, refs = _shared_refs(refs, shared, 2)
+    dk_ref, dv_ref, dk_acc, dv_acc = refs
     ki = pl.program_id(1)
     qi = pl.program_id(2)
     bq, bk = q_ref.shape[1], k_ref.shape[1]
@@ -618,23 +732,26 @@ def _flash_bwd_dkdv_kernel(off_ref, q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
+        if shared:
+            dks_acc[:] = jnp.zeros_like(dks_acc)
 
     k_start = ki * bk
     _backward_block(q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
                     None, dk_acc, dv_acc, row0=qi, q_lo=off_ref[0] + qi * bq,
                     k_lo=off_ref[1] + k_start,
                     valid=_valid_keys(lk, k_start, bk), sub=bq, causal=causal,
-                    scale=scale, window=window)
+                    scale=scale, window=window, ks_ref=ks_ref, dks_acc=dks_acc)
 
     @pl.when(qi == pl.num_programs(2) - 1)
     def _finish():
-        _finish_dkdv(dk_ref, dv_ref, dk_acc, dv_acc, scale)
+        _finish_dkdv(dk_ref, dv_ref, dk_acc, dv_acc, scale, dks_ref, dks_acc)
 
 
 def _flash_bwd_dq_kernel(off_ref, q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
-                         dq_ref, dq_acc, *, lk: int, causal: bool, scale: float,
-                         window=None):
+                         *refs, lk: int, causal: bool, scale: float,
+                         window=None, shared: bool = False):
     """The split path's dQ: a q block accumulates over the K/V blocks."""
+    ks_ref, _, _, (dq_ref, dq_acc) = _shared_refs(refs, shared, 0)
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     bq, bk = q_ref.shape[1], k_ref.shape[1]
@@ -648,7 +765,7 @@ def _flash_bwd_dq_kernel(off_ref, q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
                     dq_acc, None, None, row0=qi, q_lo=off_ref[0] + qi * bq,
                     k_lo=off_ref[1] + k_start,
                     valid=_valid_keys(lk, k_start, bk), sub=bq, causal=causal,
-                    scale=scale, window=window)
+                    scale=scale, window=window, ks_ref=ks_ref)
 
     @pl.when(ki == pl.num_programs(2) - 1)
     def _finish():
@@ -686,8 +803,8 @@ def prepare_backward_q_side(q, o, g, q_block):
     every ring step."""
     b, lq, h, d = q.shape
     qf = q.transpose(0, 2, 1, 3).reshape(b * h, lq, d)
-    dof = g.transpose(0, 2, 1, 3).reshape(b * h, lq, d)
-    of = o.transpose(0, 2, 1, 3).reshape(b * h, lq, d)
+    dof = g.transpose(0, 2, 1, 3).reshape(b * h, lq, -1)    # the values' width
+    of = o.transpose(0, 2, 1, 3).reshape(b * h, lq, -1)
     # D_i = rowsum(dO * O) — elementwise, XLA fuses it.
     dd = jnp.sum(dof.astype(jnp.float32) * of.astype(jnp.float32), axis=-1)
 
@@ -704,10 +821,12 @@ def prepare_backward_q_side(q, o, g, q_block):
 
 def _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, k_block,
                        interpret, q_shape, q_offset=0, k_offset=0,
-                       out_dtype=None, window=None):
+                       out_dtype=None, window=None, k_shared=None):
     """Backward against one K/V shard from prepared query-side layout. Returns
-    (dq, dk, dv) in [B, L, H, D]; ``out_dtype`` overrides the kernels' output
-    dtype (ring passes f32 so per-step contributions accumulate unquantized).
+    (dq, dk, dv) in [B, L, H, D] (and the shared key columns' gradient ``[B,
+    Lk, Ds]`` after them where ``k_shared`` is given); ``out_dtype`` overrides
+    the kernels' output dtype (ring passes f32 so per-step contributions
+    accumulate unquantized).
 
     One pass (a single kernel, named ``flash_bwd_dkv``) while the float32 dQ
     accumulator of one (batch, head) is within ``_RESIDENT_DQ_BYTES``; past
@@ -717,9 +836,11 @@ def _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, k_block,
 
     Grouped KV heads (``k`` / ``v`` with fewer heads than q): every query
     head's kernels read the K/V rows of its KV head through the index map and
-    write their own float32 dK / dV, which are summed over the group here."""
+    write their own float32 dK / dV, which are summed over the group here;
+    the shared key columns' float32 parts likewise, over all the heads."""
     b, lq, h, d = q_shape
-    lk, h_kv = k.shape[1], k.shape[2]
+    lk, h_kv, d_k, dv = k.shape[1], k.shape[2], k.shape[3], v.shape[3]
+    d_s = d - d_k
     group = h // h_kv
     kv_row = _kv_row(group)
     scale = 1.0 / (d ** 0.5)
@@ -727,14 +848,16 @@ def _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, k_block,
     offs = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                       jnp.asarray(k_offset, jnp.int32)])
 
-    kf = k.transpose(0, 2, 1, 3).reshape(b * h_kv, lk, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * h_kv, lk, d)
+    kf = k.transpose(0, 2, 1, 3).reshape(b * h_kv, lk, d_k)
+    vf = v.transpose(0, 2, 1, 3).reshape(b * h_kv, lk, dv)
     bk = min(k_block, lk)
     n_k = pl.cdiv(lk, bk)
     k_pad = n_k * bk - lk
     if k_pad:
-        kf = jnp.pad(kf, ((0, 0), (0, k_pad), (0, 0)))
-        vf = jnp.pad(vf, ((0, 0), (0, k_pad), (0, 0)))
+        pad = ((0, 0), (0, k_pad), (0, 0))
+        kf, vf = jnp.pad(kf, pad), jnp.pad(vf, pad)
+        if d_s:
+            k_shared = jnp.pad(k_shared, pad)
     dq_dtype = out_dtype or qf.dtype
     dk_dtype = out_dtype or k.dtype
     dv_dtype = out_dtype or v.dtype
@@ -742,7 +865,8 @@ def _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, k_block,
     head_dk, head_dv = ((dk_dtype, dv_dtype) if group == 1
                         else (jnp.float32, jnp.float32))
     lq_p = n_q * bq
-    one_pass = lq_p * d * 4 <= _RESIDENT_DQ_BYTES
+    dq_bytes = lq_p * d * 4
+    one_pass = dq_bytes <= _RESIDENT_DQ_BYTES
 
     plain, masked, skipped = _count_backward_tiles(n_q, lk, bq, bk, causal,
                                                    window)
@@ -753,29 +877,45 @@ def _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, k_block,
 
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     kernel_args = dict(lk=lk, causal=causal, scale=scale, window=window)
+    if d_s:
+        kernel_args["shared"] = True
+    shared_in = (k_shared,) if d_s else ()
     dq_shape = jax.ShapeDtypeStruct((b * h, lq_p, d), dq_dtype)
-    dkv_shape = (jax.ShapeDtypeStruct((b * h, n_k * bk, d), head_dk),
-                 jax.ShapeDtypeStruct((b * h, n_k * bk, d), head_dv))
-    dkv_scratch = [pltpu.VMEM((bk, d), jnp.float32),
-                   pltpu.VMEM((bk, d), jnp.float32)]
+    dkv_shape = (jax.ShapeDtypeStruct((b * h, n_k * bk, d_k), head_dk),
+                 jax.ShapeDtypeStruct((b * h, n_k * bk, dv), head_dv))
+    dkv_scratch = [pltpu.VMEM((bk, d_k), jnp.float32),
+                   pltpu.VMEM((bk, dv), jnp.float32)]
+    # a head's part of the shared columns' gradient, summed over heads below
+    dks_shape = (jax.ShapeDtypeStruct((b * h, n_k * bk, d_s), jnp.float32),) \
+        if d_s else ()
+    dks_scratch = [pltpu.VMEM((bk, d_s), jnp.float32)] if d_s else []
+    dks = None
     if one_pass:
         q_all = pl.BlockSpec((1, lq_p, d), lambda bh, i: (bh, 0, 0))
+        do_all = pl.BlockSpec((1, lq_p, dv), lambda bh, i: (bh, 0, 0))
         rows = pl.BlockSpec((1, n_q, bq), lambda bh, i: (bh, 0, 0))
-        kv_spec = pl.BlockSpec((1, bk, d), lambda bh, i: (kv_row(bh), i, 0))
-        dkv_spec = pl.BlockSpec((1, bk, d), lambda bh, i: (bh, i, 0))
-        dq, dk, dv = named_pallas_call(
+        k_spec = pl.BlockSpec((1, bk, d_k), lambda bh, i: (kv_row(bh), i, 0))
+        v_spec = pl.BlockSpec((1, bk, dv), lambda bh, i: (kv_row(bh), i, 0))
+        dk_spec = pl.BlockSpec((1, bk, d_k), lambda bh, i: (bh, i, 0))
+        dv_spec = pl.BlockSpec((1, bk, dv), lambda bh, i: (bh, i, 0))
+        ks_in = [pl.BlockSpec((1, bk, d_s), lambda bh, i: (bh // h, i, 0))] \
+            if d_s else []
+        ks_out = (pl.BlockSpec((1, bk, d_s), lambda bh, i: (bh, i, 0)),) \
+            if d_s else ()
+        dq, dk, dv_, *dks = named_pallas_call(
             "flash_bwd_dkv",
             functools.partial(_flash_bwd_kernel, sub=bq, **kernel_args),
             grid=(b * h, n_k),
-            in_specs=[smem, q_all, q_all, rows, rows, kv_spec, kv_spec],
-            out_specs=(q_all, dkv_spec, dkv_spec),
-            out_shape=(dq_shape,) + dkv_shape,
-            scratch_shapes=[pltpu.VMEM((n_q, d, bq), jnp.float32)] + dkv_scratch,
+            in_specs=[smem, q_all, do_all, rows, rows, k_spec, v_spec] + ks_in,
+            out_specs=(q_all, dk_spec, dv_spec) + ks_out,
+            out_shape=(dq_shape,) + dkv_shape + dks_shape,
+            scratch_shapes=[pltpu.VMEM((n_q, d, bq), jnp.float32)]
+            + dkv_scratch + dks_scratch,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary"),
-                vmem_limit_bytes=_BACKWARD_VMEM_LIMIT),
+                vmem_limit_bytes=_backward_vmem_limit(dq_bytes)),
             interpret=interpret,
-        )(offs, qf, dof, lse, dd, kf, vf)
+        )(offs, qf, dof, lse, dd, kf, vf, *shared_in)
     else:
         # A block the diagonal hides (or the band's lower edge) names the
         # nearest one it does not, so its (skipped) grid step copies nothing
@@ -801,54 +941,77 @@ def _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, k_block,
             first = jnp.maximum(q_offset + i * bq - window + 1 - k_offset, 0) // bk
             return jnp.clip(j, jnp.minimum(first, n_k - 1), jnp.maximum(last, 0))
 
+        def spec(rows, width, index):
+            return pl.BlockSpec((1, rows, width), index)
+
+        def q_blk(bh, i, j):     # the q block a K/V block's grid step reads
+            return bh, q_of(i, j), 0
+
+        def q_own(bh, i, j):     # a grid row's own block, q's or dK/dV's
+            return bh, i, 0
+
+        def kv_own(bh, i, j):
+            return kv_row(bh), i, 0
+
+        def kv_blk(bh, i, j):    # the K/V block a q block's grid step reads
+            return kv_row(bh), k_of(i, j), 0
+
         rows = pl.BlockSpec((1, n_q, bq), lambda bh, i, j: (bh, 0, 0))
-        q_spec = pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, q_of(i, j), 0))
-        kv_spec = pl.BlockSpec((1, bk, d), lambda bh, i, j: (kv_row(bh), i, 0))
-        dkv_spec = pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, i, 0))
-        dk, dv = named_pallas_call(
+        ks_in = [pl.BlockSpec((1, bk, d_s), lambda bh, i, j: (bh // h, i, 0))] \
+            if d_s else []
+        dk, dv_, *dks = named_pallas_call(
             "flash_bwd_dkv",
             functools.partial(_flash_bwd_dkdv_kernel, **kernel_args),
             grid=(b * h, n_k, n_q),
-            in_specs=[smem, q_spec, q_spec, rows, rows, kv_spec, kv_spec],
-            out_specs=(dkv_spec, dkv_spec),
-            out_shape=dkv_shape,
-            scratch_shapes=dkv_scratch,
+            in_specs=[smem, spec(bq, d, q_blk), spec(bq, dv, q_blk), rows, rows,
+                      spec(bk, d_k, kv_own), spec(bk, dv, kv_own)] + ks_in,
+            out_specs=(spec(bk, d_k, q_own), spec(bk, dv, q_own))
+            + ((spec(bk, d_s, q_own),) if d_s else ()),
+            out_shape=dkv_shape + dks_shape,
+            scratch_shapes=dkv_scratch + dks_scratch,
             interpret=interpret,
-        )(offs, qf, dof, lse, dd, kf, vf)
+        )(offs, qf, dof, lse, dd, kf, vf, *shared_in)
 
-        q_spec = pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0))
-        kv_spec = pl.BlockSpec((1, bk, d),
-                               lambda bh, i, j: (kv_row(bh), k_of(i, j), 0))
+        ks_in = [pl.BlockSpec(
+            (1, bk, d_s), lambda bh, i, j: (bh // h, k_of(i, j), 0))] \
+            if d_s else []
         dq = named_pallas_call(
             "flash_bwd_dq",
             functools.partial(_flash_bwd_dq_kernel, **kernel_args),
             grid=(b * h, n_q, n_k),
-            in_specs=[smem, q_spec, q_spec, rows, rows, kv_spec, kv_spec],
-            out_specs=q_spec,
+            in_specs=[smem, spec(bq, d, q_own), spec(bq, dv, q_own), rows, rows,
+                      spec(bk, d_k, kv_blk), spec(bk, dv, kv_blk)] + ks_in,
+            out_specs=spec(bq, d, q_own),
             out_shape=dq_shape,
             scratch_shapes=[pltpu.VMEM((1, d, bq), jnp.float32)],
             interpret=interpret,
-        )(offs, qf, dof, lse, dd, kf, vf)
+        )(offs, qf, dof, lse, dd, kf, vf, *shared_in)
 
     dq = dq[:, :lq, :].reshape(b, h, lq, d).transpose(0, 2, 1, 3)
 
     def kv_heads(x, dtype):      # [B*H, Lk, D] -> [B, Lk, H_kv, D]
+        width = x.shape[-1]
         if group == 1:
-            return x[:, :lk, :].reshape(b, h, lk, d).transpose(0, 2, 1, 3)
-        x = x[:, :lk, :].reshape(b, h_kv, group, lk, d).sum(axis=2)
+            return x[:, :lk, :].reshape(b, h, lk, width).transpose(0, 2, 1, 3)
+        x = x[:, :lk, :].reshape(b, h_kv, group, lk, width).sum(axis=2)
         return x.astype(dtype).transpose(0, 2, 1, 3)
 
-    return dq, kv_heads(dk, dk_dtype), kv_heads(dv, dv_dtype)
+    grads = dq, kv_heads(dk, dk_dtype), kv_heads(dv_, dv_dtype)
+    if d_s:
+        grads += (dks[0][:, :lk, :].reshape(b, h, lk, d_s).sum(axis=1).astype(
+            out_dtype or k_shared.dtype),)
+    return grads
 
 
 def _flash_backward(q, k, v, o, lse, g, causal, q_block, k_block, interpret,
-                    q_offset=0, k_offset=0, out_dtype=None, window=None):
+                    q_offset=0, k_offset=0, out_dtype=None, window=None,
+                    k_shared=None):
     bq, bk = _backward_blocks(q.shape[1], k.shape[1], q_block, k_block)
     qf, dof, dd, bq, n_q = prepare_backward_q_side(q, o, g, bq)
     return _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, bk,
                               interpret, q.shape, q_offset=q_offset,
                               k_offset=k_offset, out_dtype=out_dtype,
-                              window=window)
+                              window=window, k_shared=k_shared)
 
 
 def _flash_carry_kernel(off_ref, q_ref, k_ref, v_ref, acc_in_ref, m_in_ref,
@@ -997,27 +1160,28 @@ def _use_interpret() -> bool:
         f"the default backend is {backend!r}")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal, q_block, k_block, window):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash(q, k, v, k_shared, causal, q_block, k_block, window):
     out, _ = _flash_forward(q, k, v, causal, q_block, k_block, _use_interpret(),
-                            window)
+                            window, k_shared)
     return out
 
 
-def _flash_fwd(q, k, v, causal, q_block, k_block, window):
+def _flash_fwd(q, k, v, k_shared, causal, q_block, k_block, window):
     # Named so that a caller's ``jax.checkpoint`` whose policy lists
     # ``KEPT_NAME`` keeps them and does not launch the forward kernel again
     # for its backward; the identity, lowered to nothing, anywhere else.
     out, lse = checkpoint_name(
         _flash_forward(q, k, v, causal, q_block, k_block, _use_interpret(),
-                       window), KEPT_NAME)
-    return out, (q, k, v, out, lse)
+                       window, k_shared), KEPT_NAME)
+    return out, (q, k, v, k_shared, out, lse)
 
 
 def _flash_bwd(causal, q_block, k_block, window, residuals, g):
-    q, k, v, o, lse = residuals
-    return _flash_backward(q, k, v, o, lse, g, causal, q_block, k_block,
-                           _use_interpret(), window=window)
+    q, k, v, k_shared, o, lse = residuals
+    grads = _flash_backward(q, k, v, o, lse, g, causal, q_block, k_block,
+                            _use_interpret(), window=window, k_shared=k_shared)
+    return grads if k_shared is not None else grads + (None,)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -1025,9 +1189,18 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: Optional[int] = None,
+                    k_shared: Optional[jax.Array] = None,
                     q_block: Optional[int] = None,
                     k_block: Optional[int] = None) -> jax.Array:
     """Flash attention over [B, L, H, D] tensors (pallas forward and backward).
+
+    ``v`` may be of another width than ``q`` and ``k`` (the result is ``v``'s
+    wide, the scale ``1 / sqrt(D)`` of the key width). ``k_shared`` ``[B, Lk,
+    Ds]``: the trailing ``Ds`` columns of every head's key where all heads
+    share them (latent attention's one rotary key head); ``k`` then holds the
+    leading ``D - Ds`` columns a head, nothing is repeated in memory, the
+    score tile is the sum of two products, and the gradient with respect to
+    ``k_shared`` is the sum over the heads.
 
     ``window=W`` (causal only): the query at ``i`` sees the keys ``i - W < j
     <= i``, itself and the ``W - 1`` before it. Tiles wholly below the band
@@ -1048,6 +1221,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if window is not None and (not causal or window < 1):
         raise ValueError(f"window={window!r} needs causal=True and window >= 1")
     _kv_group(q, k)
+    _shared_cols(q, k, k_shared)
+    operands = (q, k, v) if k_shared is None else (q, k, v, k_shared)
     return per_device(
-        lambda q, k, v: _flash(q, k, v, causal, q_block, k_block, window),
-        (q, k, v), batched=(True, True, True))
+        lambda q, k, v, ks=None: _flash(q, k, v, ks, causal, q_block, k_block,
+                                        window),
+        operands, batched=(True,) * len(operands))

@@ -70,8 +70,9 @@ def _compiled_text(fn, chip, *shapes) -> str:
     (2, 1100, 8, 64),       # K/V resident and padded to whole 512-key tiles
     (1, 16384, 8, 64),      # past the resident limit: K/V streamed in blocks
     (4, 4096, 16, 128),     # olmoe-pretrain-4k: K/V of a head exactly the resident 1 MB
+    (1, 32768, 2, 128),     # 16 MiB of float32 dQ a head: the split backward
 ], ids=["L2048-D64", "ragged-L300", "D128", "cell-8x1024x16x64", "ragged-L1100",
-        "streamed-L16384", "olmoe-4x4096x16x128"])
+        "streamed-L16384", "olmoe-4x4096x16x128", "split-L32768-D128"])
 def test_flash_attention_fwd_bwd_compiles(chip, batch, length, heads, depth):
     qkv = ((batch, length, heads, depth), jnp.bfloat16)
 
@@ -92,7 +93,8 @@ def test_flash_window_and_grouped_heads_compile_at_the_trinity_cell_shapes(
     """trinity-pretrain-8k's calls: 1 x 8,192 x 32 query heads over 4 KV
     heads of 128, a sliding layer (window 2,048) and the full one; K/V of a
     head is 2 MB, so the forward streams it in 2,048-row blocks; float32 dQ of
-    a head is 4 MB, the most the backward takes in one pass."""
+    a head is 4 MB, the most the backward takes in one pass under 48 MiB of
+    scoped VMEM."""
     q = ((1, 8192, 32, 128), jnp.bfloat16)
     kv = ((1, 8192, 4, 128), jnp.bfloat16)
 
@@ -103,7 +105,7 @@ def test_flash_window_and_grouped_heads_compile_at_the_trinity_cell_shapes(
     text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1, 2)), chip,
                           q, kv, kv)
     assert "flash_fwd" in text and "flash_bwd_dkv" in text
-    assert 8192 * 128 * 4 == fa._RESIDENT_DQ_BYTES and "flash_bwd_dq" not in text
+    assert 8192 * 128 * 4 == fa._SMALL_DQ_BYTES and "flash_bwd_dq" not in text
 
 
 def test_flash_carry_variant_compiles(chip):
@@ -343,6 +345,32 @@ def test_flash_sixteen_query_heads_a_kv_head_compile_at_the_nemotron_cell_shape(
     text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1, 2)), chip,
                           q, kv, kv)
     assert "flash_fwd" in text and "flash_bwd_dkv" in text
+
+
+@pytest.mark.parametrize("shared", [True, False], ids=["shared-key", "assembled"])
+def test_flash_at_two_widths_compiles_at_the_kanana_cell_shape(chip, shared):
+    """kanana-pretrain-16k's call: 1 x 16,384 x 32 heads, keys 192 wide (128
+    a head + the 64 rotary columns all heads share, as a second operand or
+    assembled by the caller), values 128: the streamed forward, 1.5 lane
+    tiles of key width, and the backward in one pass at 12 MiB of float32 dQ
+    a head, which takes more scoped VMEM than the 48 MiB the older calls ask
+    for."""
+    b, length, h = 1, 16384, 32
+    shapes = [((b, length, h, 192), jnp.bfloat16),
+              ((b, length, h, 128 if shared else 192), jnp.bfloat16),
+              ((b, length, h, 128), jnp.bfloat16)]
+    if shared:
+        shapes.append(((b, length, 64), jnp.bfloat16))
+
+    def loss(q, k, v, k_shared=None):
+        return fa.flash_attention(q, k, v, causal=True,
+                                  k_shared=k_shared).astype(jnp.float32).sum()
+
+    text = _compiled_text(
+        jax.value_and_grad(loss, argnums=tuple(range(len(shapes)))), chip, *shapes)
+    assert 16384 * 192 * 4 == fa._RESIDENT_DQ_BYTES
+    assert "flash_fwd" in text and "flash_bwd_dkv" in text
+    assert "flash_bwd_dq" not in text
 
 
 def _kernel_launches(text: str, kernel: str) -> int:

@@ -26,6 +26,19 @@ aliased buffer, in an order only the chip's own pipeline shows), and exits
     python tools/flash_forward_timing.py --shapes xent-bwd,xent-bwd-gpt2,xent-bwd-trinity --xent-blocks 1024,256
     python tools/flash_forward_timing.py --shapes xent-bwd,xent-bwd-gpt2,xent-bwd-trinity,xent-bwd-v3 --check
     python tools/flash_forward_timing.py --root .archive_check/parent   # another checkout
+    python tools/flash_forward_timing.py --shapes mla-padded,mla-assembled,mla-shared,bwd-mla-padded,bwd-mla-assembled,bwd-mla-shared [--resident-dq-bytes 4194304]
+
+``mla-*`` (PR 37): latent attention's call at kanana-pretrain-16k's shape, 1 x
+16,384 x 32 heads, keys 192 wide (128 a head + 64 rotary columns all heads
+share), values 128, in three forms: ``padded`` (v padded to 192 through the
+one-width kernels: runs on any checkout), ``assembled`` (k put together in
+memory, 32 copies of the rotary columns, dK's last 64 columns summed over
+the heads afterwards; the kernels take the two widths) and ``shared`` (the
+rotary columns a second operand, the score tile two products). Each times
+what the form adds around the kernels too (``device_ms_all``: every device
+operation of a call). The backward runs in one pass (12 MiB of dQ a head);
+``--resident-dq-bytes 4194304`` times the two kernels of the split path, which
+PR 29's limit gave this width.
 
 ``--blocks bq,bk,sub`` overrides the forward's choice, ``--bwd-blocks bq,bk``
 the backward's, ``--xent-blocks bn,bv`` the named head kernel's (a checkout
@@ -59,7 +72,8 @@ SHAPES = {
     "bwd-d128": (4, 2048, 16, 128, True, "bwd"),
     "bwd-noncausal": (8, 1024, 16, 64, False, "bwd"),
     "bwd-olmoe": (4, 4096, 16, 128, True, "bwd"),     # olmoe-pretrain-4k's call
-    "bwd-l16384": (1, 16384, 8, 64, True, "bwd"),     # past the one-pass limit: split
+    "bwd-l16384": (1, 16384, 8, 64, True, "bwd"),     # 4 MiB of dQ: PR 29's limit
+    "bwd-l16384-d128": (1, 16384, 8, 128, True, "bwd"),    # 8 MiB: one pass since PR 37
     # one ring step of the backward as ``_ring_flash_bwd`` runs it: traced
     # offsets, float32 outputs, 512-row blocks asked for
     "bwd-ring-diag": (4, 4096, 16, 64, True, "ring-bwd"),
@@ -76,6 +90,13 @@ SHAPES = {
     "bwd-trinity-win-rep": (1, 8192, 32, 128, True, "bwd"),
     "bwd-trinity-full-rep": (1, 8192, 32, 128, True, "bwd"),
 }
+# latent attention's call: name -> form
+MLA_V, MLA_SHARED = 128, 64
+MLA = {f"{kind}mla-{form}": form for kind in ("", "bwd-")
+       for form in ("padded", "assembled", "shared")}
+SHAPES.update({name: (1, 16384, 32, 192, True,
+                      "bwd" if name.startswith("bwd-") else "fwd")
+               for name in MLA})
 # name -> (window, KV heads) where they are not (None, H)
 BANDS = {name: (2048 if "-win" in name else None, 32 if name.endswith("-rep") else 4)
          for name in SHAPES if "trinity" in name}
@@ -113,29 +134,86 @@ def kind_of(name: str) -> str:
     return (SHAPES.get(name) or HEAD_SHAPES[name])[-1]
 
 
-def kernel_ms(trace_dir: str, kernel: str):
-    """Durations (ms) of the device events of ``kernel`` in the newest trace."""
+def device_events(trace_dir: str):
+    """The ``XLA Ops`` events of every device in the newest trace."""
     from jax.profiler import ProfileData
     found = []
     for root, _, files in os.walk(trace_dir):
         found += [os.path.join(root, f) for f in files if f.endswith(".xplane.pb")]
     data = ProfileData.from_file(max(found, key=os.path.getmtime))
-    out = []
-    for plane in data.planes:
-        if not plane.name.startswith("/device:TPU:"):
-            continue
-        for line in plane.lines:
-            if line.name != "XLA Ops":
-                continue
-            out += [e.duration_ns * 1e-6 for e in line.events
-                    if e.name.lstrip("%").startswith(kernel)]
-    return out
+    return [e for plane in data.planes if plane.name.startswith("/device:TPU:")
+            for line in plane.lines if line.name == "XLA Ops"
+            for e in line.events]
+
+
+def kernel_ms(trace_dir: str, kernel: str):
+    """Durations (ms) of the device events of ``kernel`` in the newest trace."""
+    return [e.duration_ns * 1e-6 for e in device_events(trace_dir)
+            if e.name.lstrip("%").startswith(kernel)]
+
+
+def all_ops_ms(trace_dir: str) -> float:
+    """Milliseconds of every device operation in the newest trace."""
+    return sum(e.duration_ns * 1e-6 for e in device_events(trace_dir))
+
+
+def build_mla(fa, name):
+    """Latent attention's call in one of its three forms, from the operands
+    the layer has (q, a head's 128 key columns, the 64 all heads share, v)
+    to what it needs back (the result; the four gradients)."""
+    import jax
+    import jax.numpy as jnp
+
+    b, length, h, d, causal, kind = SHAPES[name]
+    form = MLA[name]
+    keys = jax.random.split(jax.random.PRNGKey(0), 5)
+    q = jax.random.normal(keys[0], (b, length, h, d), jnp.bfloat16)
+    k_nope = jax.random.normal(keys[1], (b, length, h, d - MLA_SHARED), jnp.bfloat16)
+    k_rope = jax.random.normal(keys[2], (b, length, MLA_SHARED), jnp.bfloat16)
+    v = jax.random.normal(keys[3], (b, length, h, MLA_V), jnp.bfloat16)
+    g = jax.random.normal(keys[4], (b, length, h, MLA_V), jnp.bfloat16)
+
+    def operands(k_nope, k_rope, v):
+        if form == "shared":
+            return k_nope, v, {"k_shared": k_rope}
+        k = jnp.concatenate([k_nope, jnp.broadcast_to(
+            k_rope[:, :, None, :], (b, length, h, MLA_SHARED))], axis=-1)
+        if form == "padded":
+            v = jnp.pad(v, ((0, 0),) * 3 + ((0, d - MLA_V),))
+        return k, v, {}
+
+    def forward(q, k_nope, k_rope, v):
+        k, v, shared = operands(k_nope, k_rope, v)
+        out, lse = fa._flash_forward(q, k, v, causal, None, None, False, **shared)
+        return out, lse
+
+    if kind == "fwd":
+        return (jax.jit(lambda *a: forward(*a)[0][..., :MLA_V]),
+                (q, k_nope, k_rope, v))
+    out, lse = jax.jit(forward)(q, k_nope, k_rope, v)
+
+    def backward(q, k_nope, k_rope, v, out, lse, g):
+        k, v, shared = operands(k_nope, k_rope, v)
+        if form == "padded":
+            g = jnp.pad(g, ((0, 0),) * 3 + ((0, d - MLA_V),))
+        dq, dk, dv, *dks = fa._flash_backward(q, k, v, out, lse, g, causal,
+                                              None, None, False, **shared)
+        if form == "shared":
+            return dq, dk, dks[0], dv
+        split = d - MLA_SHARED
+        return (dq, dk[..., :split],
+                dk[..., split:].astype(jnp.float32).sum(axis=2).astype(dk.dtype),
+                dv[..., :MLA_V])
+
+    return jax.jit(backward), (q, k_nope, k_rope, v, out, lse, g)
 
 
 def build(fa, name, blocks=None):
     import jax
     import jax.numpy as jnp
 
+    if name in MLA:
+        return build_mla(fa, name)
     b, length, h, d, causal, kind = SHAPES[name]
     window, kv_heads = BANDS.get(name, (None, h))
     # a checkout older than the window takes no such argument
@@ -283,6 +361,7 @@ def measure(modules, name, blocks, calls, two_kernels=False):
             call = (time.perf_counter() - t0) / calls * 1e3
         parts = {kernel: sorted(kernel_ms(trace_dir, kernel))
                  for kernel in KERNELS[kind]}
+        device_all = all_ops_ms(trace_dir) / calls if name in MLA else None
     parts = {kernel: ms for kernel, ms in parts.items() if ms}
     if not parts:
         raise SystemExit(f"{name}: the trace holds no {KERNELS[kind]} event")
@@ -292,6 +371,8 @@ def measure(modules, name, blocks, calls, two_kernels=False):
               "kernel_ms_median": sum(ms[len(ms) // 2] for ms in parts.values()),
               "kernel_ms_min": sum(ms[0] for ms in parts.values()),
               "call_ms_host": call}
+    if device_all is not None:
+        record["device_ms_all"] = device_all
     if len(KERNELS[kind]) > 1:      # the one-pass backward holds no flash_bwd_dq
         record["parts"] = {kernel: ms[len(ms) // 2] for kernel, ms in parts.items()}
     from autodist_tpu import telemetry
