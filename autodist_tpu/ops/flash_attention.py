@@ -3,17 +3,25 @@
 Forward: grid (batch*heads, q-blocks, k-blocks); VMEM scratch carries the
 online-softmax state (running max, denominator, unnormalized accumulator) across
 the k dimension of the grid — the [L, L] score matrix never exists. While K and V
-of one (batch, head) are small they are ONE resident block (one grid step a q
-block, nothing copied for a block above the diagonal); longer ones stream in
-blocks, and a block above the diagonal is neither copied nor computed. Inside a
-grid step the resident block is walked in key tiles whose score tile is held
-TRANSPOSED, [keys, queries]: the softmax statistics reduce along sublanes and
-are lane-dense [1, q_block] vectors. Each tile runs the body of its class —
-plain (below the diagonal, no padded key: no iota, no compare, no select),
-masked (crossed by the diagonal or holding the ragged tail) or skipped — decided
-from grid indices and the SMEM offsets, so ring attention's traced offsets
-classify at run time. The per-row logsumexp is emitted as a residual for the
-backward pass.
+of one (batch, head) are within ``_RESIDENT_KV_BYTES`` they are ONE resident
+block (one grid step a q block, nothing copied for a block above the diagonal);
+longer ones stream in blocks, and a block above the diagonal is neither copied
+nor computed. Inside a grid step the block is walked in key tiles whose score
+tile is held TRANSPOSED, [keys, queries]: the softmax statistics reduce along
+sublanes and are lane-dense [1, q_block] vectors. Each tile runs the body of its
+class — plain (below the diagonal, no padded key: no iota, no compare, no
+select), masked (crossed by the diagonal or holding the ragged tail) or skipped —
+decided from grid indices and the SMEM offsets, so ring attention's traced
+offsets classify at run time. A tile alone is a chain (product, maximum,
+exponential, sum, product: the MXU and the VPU take turns), so the plain tiles,
+which are most of a long walk, are taken FOUR (then two) AT A TIME as one
+straight-line block with every score product issued first: the scheduler keeps
+the MXU's operations in program order, so a tile's score product runs under its
+predecessor's softmax and its value product under its successor's, and inside a
+block each tile is cut into two halves of the queries, whose softmaxes are
+independent, for the same reason. The updates happen in the walk's order, tile by
+tile, whatever is in flight. The per-row logsumexp is emitted as a residual for
+the backward pass.
 
 Backward (FlashAttention-2 style): scores are recomputed blockwise from the saved
 logsumexp, so nothing quadratic is ever materialized. ONE kernel (device name
@@ -90,6 +98,52 @@ from autodist_tpu.ops.named_call import named_pallas_call
 # before skipped blocks stopped being copied). D 128 at L 2,048: 0.90 ms (1.78).
 # Non-causal L 1,024: 0.554 ms (1.406).
 #
+# The forward's walk and its K/V blocks, from stand-alone timings on the same
+# chip (PR 39; same tool, PERF.md §6 "PR 39"; ms a call, parent -> change). A
+# needed tile cost its MXU time PLUS its VPU time (a chain: 1.79 us at keys 192 /
+# values 128, 1.36 at 128, where the products alone need 1.02 / 0.68), so the
+# plain tiles are taken four (then two) at a time as one straight-line block,
+# every score product issued first, each tile in two halves of the queries; and
+# K/V of a head stay resident up to 4 MiB (1 MiB until then), which ends the
+# grid's steps over hidden blocks at every shape a cell runs.
+#
+#   kanana's call (1 x 16,384 x 32, keys 128 + 64 shared, values 128)
+#                                            30.206 -> 21.447   (roofline 14.0)
+#   trinity's full layer / nemotron's call (1 x 8,192 x 32 over 4 / 2 of 128)
+#                                             5.907 ->  4.515
+#   trinity's sliding layers (window 2,048)   3.536 ->  3.004
+#   lfm2's call (2 x 8,192 x 32 over 8 of 64) 8.443 ->  7.039
+#   B.H 64, L 8,192, D 64                     8.435 ->  7.043
+#   B.H 64, L 4,096, D 64                     2.334 ->  2.058
+#   B.H 64, L 2,048, D 128                    0.902 ->  0.865
+#   non-causal, B.H 128, L 1,024, D 64        0.554 ->  0.439
+#   1 x 32,768 x 8 of 128 (streamed)         22.966 -> 17.658 (16.160 in the
+#                                             16,384-row blocks that now run)
+#   GPT-2-medium's call                       0.454 ->  0.454 (no walk under its
+#     diagonal holds two plain tiles: no block is built, the parent's kernel)
+#   one ring step at L 4,096 (512-row blocks, one tile a walk)
+#                                     3.995 / 4.855 -> 3.995 / 4.856
+#
+# Of kanana's 8.76 ms the blocks of tiles are 4.81 and resident K/V 3.95 (the
+# parent's walk on resident K/V 26.255; blocks of tiles on 2,048-row K/V 26.678;
+# K/V rows a grid step 1,024 / 2,048 / 4,096 / 8,192 / all 16,384: 29.704 /
+# 26.678 / 24.939 / 22.535 / 21.447). What lost or gave nothing: blocks of two
+# only (26.681 at kanana's call, the same; 7.311 against 7.039 at lfm2's);
+# blocks of eight before four (21.356 against 21.447; at D 64 6.955 against
+# 7.039 and 16.84 MiB of scoped VMEM at L 8,192, which the compiler refuses);
+# tiles whole inside a block (28.284 against 26.678; 5.368 against 5.150 at
+# trinity's full layer); four pieces of the queries (26.709); a tile that runs
+# alone cut in halves too (-1% at 128 wide, +1.8% at GPT-2-medium's call, +3.8%
+# on a wholly visible ring step). Not timed, refused by the compiler's static
+# schedule of the loop body (bundles a needed tile at kanana's call, parent
+# 1,944; PERF.md §6 says how it is read): the scores of tile j + 1 carried
+# across loop iterations as values (2,442: the loop's phi copies are 512 vector
+# moves a tile) or through a VMEM buffer with a run-time slot (2,140: taken for
+# aliased, the product and the softmax are serialized), a raw and a settled
+# buffer with static addresses (1,824), half a tile rotated across iterations
+# (1,764); two tiles an iteration with two static buffers reached 1,641, the
+# block of four without any carried state 1,480.
+#
 # The backward's schedule, from stand-alone timings of `_flash_backward` on the same
 # chip (same tool, PERF.md §6 "PR 26"; ms a call, the kernels' own device time).
 # GPT-2-medium's call, two row-major kernels of 512 x 512 blocks: 0.722 + 0.619 =
@@ -108,8 +162,11 @@ from autodist_tpu.ops.named_call import named_pallas_call
 DEFAULT_Q_BLOCK = 512
 DEFAULT_K_BLOCK = 512
 _KEY_TILE = 512             # keys a score tile of the forward: [512, bq] f32
-_RESIDENT_KV_BYTES = 1 << 20     # K (or V) of one (batch, head) kept in VMEM
-_STREAM_K_BLOCK = 2048           # K/V rows a grid step beyond that
+_RESIDENT_KV_BYTES = 4 << 20     # K (or V) of one (batch, head) kept in VMEM
+# Plain tiles the forward's walk takes as one straight-line block, largest
+# first, and the halves of the queries a tile is cut into inside one.
+_WALK_GROUPS = (4, 2)
+_GROUP_Q_CHUNKS = 2
 # The one-pass backward's f32 dQ of one (batch, head): Lq x the KEY width x 4
 # bytes, so the limit in positions falls with the width. 4 MiB from PR 29 (L
 # 16,384 at head_dim 64, 8,192 at 128: trinity-pretrain-8k's call, one pass 4.99
@@ -207,7 +264,7 @@ def _band_tile_counts(q_lo, k_lo, valid, bq: int, bk: int, sub: int,
 
 def _attend_block(q_ref, k_ref, v_ref, state, *, q_lo, k_lo, valid, sub: int,
                   causal: bool, scale: float, guard_empty_rows: bool,
-                  window=None, ks_ref=None):
+                  window=None, ks_ref=None, groups=()):
     """Online-softmax update of ``state = (m [1, bq], l [1, bq], acc [dv, bq])``
     against the VMEM-resident K/V block — the single definition shared by the
     plain forward kernel and the carry variant.
@@ -246,31 +303,46 @@ def _attend_block(q_ref, k_ref, v_ref, state, *, q_lo, k_lo, valid, sub: int,
     n_lo, n_ps, n_pe, n_need = _band_tile_counts(q_lo, k_lo, valid, bq, bk, sub,
                                                  causal, window)
 
-    def tile(j, state, masked: bool):
-        m_prev, l_prev, acc = state
-        start = _tile_start(j, sub, bk // sub)
-        k_t = k_ref[0, pl.ds(start, sub), :]          # [sub, d]
-        v_t = v_ref[0, pl.ds(start, sub), :]
-        scores = jax.lax.dot_general(
-            k_t, q, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [sub, bq]
+    n_tiles = bk // sub
+
+    def scores(j, cols=None):
+        """Tile ``j``'s raw score product(s), for the queries ``cols`` (a
+        slice of the block's; None: all of them)."""
+        start = _tile_start(j, sub, n_tiles)
+        q_c = q if cols is None else q[cols]
+        s = jax.lax.dot_general(
+            k_ref[0, pl.ds(start, sub), :], q_c, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)       # [sub, queries]
         if ks_ref is not None:
-            scores += jax.lax.dot_general(
-                ks_ref[0, pl.ds(start, sub), :], q_s, (((1,), (1,)), ((), ())),
+            s += jax.lax.dot_general(
+                ks_ref[0, pl.ds(start, sub), :],
+                q_s if cols is None else q_s[cols], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
+        return s
+
+    def update(j, scores, state, masked: bool, cols=None):
+        """The online-softmax update of ``state`` (its columns ``cols``)
+        with tile ``j``, given the tile's raw scores: the one definition of
+        what a tile does, whoever made its scores and when."""
+        m_prev, l_prev, acc = (state if cols is None
+                               else [x[:, cols] for x in state])
+        start = _tile_start(j, sub, n_tiles)
+        v_t = v_ref[0, pl.ds(start, sub), :]
         if not prescale:
             scores = scale * scores
         if masked:
-            key = jax.lax.broadcasted_iota(jnp.int32, (sub, bq), 0)
+            shape, first = scores.shape, 0 if cols is None else cols.start
+            key = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
             invalid = None
             if valid is not None:
                 invalid = key >= valid - start
             if causal:
-                query = jax.lax.broadcasted_iota(jnp.int32, (sub, bq), 1)
-                above = key - query > q_lo - k_lo - start
+                query = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+                above = key - query > q_lo + first - k_lo - start
                 invalid = above if invalid is None else invalid | above
                 if window is not None:
-                    invalid |= key - query <= q_lo - k_lo - start - window
+                    invalid |= (key - query
+                                <= q_lo + first - k_lo - start - window)
             scores = jnp.where(invalid, NEG_INF, scores)
         m_new = jnp.maximum(m_prev, scores.max(axis=0, keepdims=True))
         correction = jnp.exp(m_prev - m_new)
@@ -280,11 +352,58 @@ def _attend_block(q_ref, k_ref, v_ref, state, *, q_lo, k_lo, valid, sub: int,
         l_new = l_prev * correction + p.sum(axis=0, keepdims=True)
         acc = acc * correction + jax.lax.dot_general(
             v_t, p.astype(v_t.dtype), (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [d, bq]
+            preferred_element_type=jnp.float32)       # [d, queries]
         return m_new, l_new, acc
 
+    def tile(j, state, masked: bool):
+        """One tile alone, whole: product, softmax, product, a chain."""
+        return update(j, scores(j), state, masked)
+
+    def group(first, state, size: int):
+        """``size`` plain tiles from ``first`` on as ONE straight-line block,
+        the software pipeline written out: every score product is issued
+        first (the MXU takes its operations in program order), so tile
+        ``j + 1``'s product runs under tile ``j``'s softmax and a tile's
+        value product under its successor's; each tile in
+        ``_GROUP_Q_CHUNKS`` pieces of the queries (the softmax is a column's
+        own), so a piece's products run under another piece's softmax as
+        well. The updates themselves come in the walk's order, tile by
+        tile."""
+        n_c = _GROUP_Q_CHUNKS
+        if n_c == 1 or bq % (128 * n_c):    # pieces of whole lane tiles, else whole
+            chunks = [None]
+        else:
+            chunks = [slice(c * bq // n_c, (c + 1) * bq // n_c)
+                      for c in range(n_c)]
+        tiles = [first + u for u in range(size)]
+        raw = [[scores(j, cols) for cols in chunks] for j in tiles]
+        for j, row in zip(tiles, raw):
+            parts = [update(j, s, state, False, cols)
+                     for cols, s in zip(chunks, row)]
+            state = parts[0] if len(parts) == 1 else tuple(
+                jnp.concatenate(x, axis=1) for x in zip(*parts))
+        return state
+
+    return _walk((n_lo, n_ps, n_pe, n_need), groups, tile, group, state)
+
+
+def _walk(counts, groups, tile, group, state):
+    """The order of a walk over the key tiles ``[n_lo, n_need)`` of
+    ``counts`` (:func:`_band_tile_counts`): the tiles the band's lower edge
+    crosses one at a time (``tile(j, state, masked)``), then the plain
+    tiles, which are most of a long walk, in the largest blocks of
+    ``groups`` they fill (``group(first, state, size)``) and what is left of
+    them one at a time, then the tiles the diagonal crosses. Every needed
+    tile once, in ascending order, whatever is traced."""
+    n_lo, n_ps, n_pe, n_need = counts
     state = _loop(n_lo, n_ps, lambda j, s: tile(j, s, True), state)
-    state = _loop(n_ps, n_pe, lambda j, s: tile(j, s, False), state)
+    lo = n_ps
+    for size in groups:
+        n = (n_pe - lo) // size
+        state = _loop(0, n, lambda i, s, lo=lo, size=size: group(
+            lo + size * i, s, size), state)
+        lo = lo + size * n
+    state = _loop(lo, n_pe, lambda j, s: tile(j, s, False), state)
     return _loop(n_pe, n_need, lambda j, s: tile(j, s, True), state)
 
 
@@ -305,7 +424,7 @@ def _loop(lo, hi, body, state):
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, *refs, lk: int, sub: int, causal: bool,
-                  scale: float, window=None):
+                  scale: float, window=None, groups=()):
     # refs: [the keys' shared columns,] o, lse | acc, m, l
     ks_ref = refs[0] if len(refs) == 6 else None
     o_ref, lse_ref, acc_ref, m_ref, l_ref = refs[-5:]
@@ -333,7 +452,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, *refs, lk: int, sub: int, causal: bool,
             q_ref, k_ref, v_ref, (m_ref[:], l_ref[:], acc_ref[:]),
             q_lo=q_start, k_lo=k_start, valid=_valid_keys(lk, k_start, bk),
             sub=sub, causal=causal, scale=scale,
-            guard_empty_rows=window is not None, window=window, ks_ref=ks_ref)
+            guard_empty_rows=window is not None, window=window, ks_ref=ks_ref,
+            groups=groups)
 
     @pl.when(ki == n_k - 1)
     def _finish():
@@ -355,31 +475,72 @@ def _forward_blocks(lq: int, lk: int, d: int, itemsize: int, q_block, k_block):
     in the module header justify: the backward's q block (so the lse plane is
     its layout already), and K/V resident for a whole (batch, head) while one
     of them is at most ``_RESIDENT_KV_BYTES`` — one grid step a q block, no
-    step and no copy for a block above the diagonal — rounded up to whole key
-    tiles (the tail is padding, masked like any ragged tail)."""
+    step and no copy for a block above the diagonal, one walk a q block for
+    the blocks of tiles to run in — rounded up to whole key tiles (the tail
+    is padding, masked like any ragged tail); past it the most tiles, a power
+    of two of them, that the same bytes hold."""
     bq = min(q_block or DEFAULT_Q_BLOCK, lq)
     if k_block is None:
         bk = lk if lk <= _KEY_TILE else pl.cdiv(lk, _KEY_TILE) * _KEY_TILE
         if bk * d * itemsize > _RESIDENT_KV_BYTES:
-            bk = _STREAM_K_BLOCK
+            bk = _KEY_TILE          # a power of two of tiles, so lengths divide
+            while 2 * bk * d * itemsize <= _RESIDENT_KV_BYTES:
+                bk *= 2
     else:
         bk = min(k_block, lk)
     return bq, bk, _sub_tile(bk)
+
+
+def _walk_groups(run: int) -> tuple:
+    """Sizes of the straight-line blocks the forward's walk takes its plain
+    tiles in, where the longest run of plain tiles a walk can hold is
+    ``run``: of ``_WALK_GROUPS`` those a run can fill, so a kernel whose
+    walks hold fewer than two plain tiles (K/V of 1,024 rows under the
+    diagonal: GPT-2-medium's call) is the one-tile chain and nothing else."""
+    return tuple(size for size in _WALK_GROUPS if size <= run)
+
+
+def _grouped(run: int, groups) -> int:
+    """Of a run of ``run`` plain tiles, those whose score product is issued
+    under another tile's softmax: all but the first of every block."""
+    overlapped = 0
+    for size in groups:
+        overlapped += run // size * (size - 1)
+        run %= size
+    return overlapped
+
+
+def _forward_vmem_limit(kv_bytes: int):
+    """Scoped VMEM the forward asks for, from the bytes of one grid step's
+    K/V blocks (the shared key columns with them): the compiler's default
+    (16 MiB, None here) while two buffers of them are within 4 MiB, as every
+    call before PR 39 was; past it the two buffers and 12 MiB for the rest
+    (the score tiles of a block of four in flight are 4 MiB, q, o, the
+    accumulator and the statistics under 2)."""
+    return None if 2 * kv_bytes <= 4 << 20 else 2 * kv_bytes + (12 << 20)
+
+
+def _walks(lq: int, lk: int, bq: int, bk: int, sub: int, causal: bool,
+           window=None) -> tuple:
+    """``(needed, plain)`` tiles of every walk, one (q block, K/V block)
+    pair, of one (batch, head) under zero offsets."""
+    walks = []
+    for qi in range(pl.cdiv(lq, bq)):
+        for ki in range(pl.cdiv(lk, bk)):
+            n_lo, n_ps, n_pe, n_need = _band_tile_counts(
+                qi * bq, ki * bk, _valid_keys(lk, ki * bk, bk), bq, bk, sub,
+                causal, window)
+            walks.append((int(n_need - n_lo), int(n_pe - n_ps)))
+    return tuple(walks)
 
 
 def _count_tiles(lq: int, lk: int, bq: int, bk: int, sub: int, causal: bool,
                  window=None):
     """(plain, masked, skipped) score tiles of one (batch, head) under zero
     offsets, at the granularity the body runs them: [bq, sub]."""
-    n_q, n_k = pl.cdiv(lq, bq), pl.cdiv(lk, bk)
-    plain = need = 0
-    for qi in range(n_q):
-        for ki in range(n_k):
-            n_lo, n_ps, n_pe, n_need = _band_tile_counts(
-                qi * bq, ki * bk, _valid_keys(lk, ki * bk, bk), bq, bk, sub,
-                causal, window)
-            plain, need = plain + int(n_pe - n_ps), need + int(n_need - n_lo)
-    return plain, need - plain, n_q * n_k * (bk // sub) - need
+    walks = _walks(lq, lk, bq, bk, sub, causal, window)
+    need, plain = (sum(column) for column in zip(*walks))
+    return plain, need - plain, len(walks) * (bk // sub) - need
 
 
 def _kv_group(q, k) -> int:
@@ -440,9 +601,13 @@ def _flash_forward(q, k, v, causal: bool, q_block, k_block, interpret: bool,
             k_shared = jnp.pad(k_shared, pad)
 
     plain, masked, skipped = _count_tiles(lq, lk, bq, bk, sub, causal, window)
+    runs = [run for _, run in _walks(lq, lk, bq, bk, sub, causal, window)]
+    groups = _walk_groups(max(runs))
     telemetry.gauge("flash.fwd.tiles_plain").set(plain)
     telemetry.gauge("flash.fwd.tiles_masked").set(masked)
     telemetry.gauge("flash.fwd.tiles_skipped").set(skipped)
+    telemetry.gauge("flash.fwd.tiles_overlapped").set(
+        sum(_grouped(run, groups) for run in runs))
     telemetry.gauge("flash.window").set(window or 0)
     telemetry.gauge("flash.kv_group").set(group)
     telemetry.gauge("flash.d_qk").set(d)
@@ -450,7 +615,7 @@ def _flash_forward(q, k, v, causal: bool, q_block, k_block, interpret: bool,
     telemetry.gauge("flash.shared_key_cols").set(d_s)
 
     kernel = functools.partial(_flash_kernel, lk=lk, sub=sub, causal=causal,
-                               scale=scale, window=window)
+                               scale=scale, window=window, groups=groups)
     if causal and n_k > 1:
         # A K/V block above the diagonal names the last one below it again
         # (and one below the band the first one inside it), so its (skipped)
@@ -502,7 +667,9 @@ def _flash_forward(q, k, v, causal: bool, q_block, k_block, interpret: bool,
             pltpu.VMEM((1, bq), jnp.float32),   # running denominator
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_forward_vmem_limit(
+                bk * (d + dv) * q.dtype.itemsize)),
         interpret=interpret,
     )(*operands)
 
@@ -1046,7 +1213,8 @@ def _flash_carry_kernel(off_ref, q_ref, k_ref, v_ref, acc_in_ref, m_in_ref,
             q_ref, k_ref, v_ref, (m_sc[:], l_sc[:], acc_sc[:]),
             q_lo=q_off + q_start, k_lo=k_off + k_start,
             valid=_valid_keys(lk, k_start, k_block), sub=sub, causal=causal,
-            scale=scale, guard_empty_rows=True)
+            scale=scale, guard_empty_rows=True,
+            groups=_walk_groups(k_block // sub))
 
     @pl.when(ki == n_k - 1)
     def _finish():

@@ -351,10 +351,12 @@ def test_flash_sixteen_query_heads_a_kv_head_compile_at_the_nemotron_cell_shape(
 def test_flash_at_two_widths_compiles_at_the_kanana_cell_shape(chip, shared):
     """kanana-pretrain-16k's call: 1 x 16,384 x 32 heads, keys 192 wide (128
     a head + the 64 rotary columns all heads share, as a second operand or
-    assembled by the caller), values 128: the streamed forward, 1.5 lane
-    tiles of key width, and the backward in one pass at 12 MiB of float32 dQ
-    a head, which takes more scoped VMEM than the 48 MiB the older calls ask
-    for."""
+    assembled by the caller), values 128: the forward with K/V of a head
+    resident (4 MiB of keys 128 wide and the 2 MiB all heads share, under 32
+    MiB of scoped VMEM; assembled, 192 wide, they stream in 8,192-row
+    blocks), 1.5 lane tiles of key width, and the backward in one pass at 12
+    MiB of float32 dQ a head, which takes more scoped VMEM than the 48 MiB
+    the older calls ask for."""
     b, length, h = 1, 16384, 32
     shapes = [((b, length, h, 192), jnp.bfloat16),
               ((b, length, h, 128 if shared else 192), jnp.bfloat16),
@@ -369,6 +371,9 @@ def test_flash_at_two_widths_compiles_at_the_kanana_cell_shape(chip, shared):
     text = _compiled_text(
         jax.value_and_grad(loss, argnums=tuple(range(len(shapes)))), chip, *shapes)
     assert 16384 * 192 * 4 == fa._RESIDENT_DQ_BYTES
+    assert 16384 * 128 * 2 == fa._RESIDENT_KV_BYTES     # K of a head: resident
+    assert fa._forward_blocks(length, length, 128 if shared else 192, 2, None,
+                              None)[1] == (length if shared else 8192)
     assert "flash_fwd" in text and "flash_bwd_dkv" in text
     assert "flash_bwd_dq" not in text
 
@@ -460,9 +465,10 @@ def test_row_kernels_compile_at_the_share_cells_shapes(chip, tokens, rows, d,
 
 def test_flash_grouped_heads_of_64_compile_at_the_lfm2_cell_shape(chip):
     """lfm2-pretrain-8k's call: 2 x 8,192 x 32 query heads over 8 KV heads of
-    64, causal, no window. K of a head is exactly ``_RESIDENT_KV_BYTES`` (the
-    largest the resident walk holds) and float32 dQ of a head 2 MiB of the
-    4 MiB the one-pass backward takes: both limits' last resident shape."""
+    64, causal, no window. K of a head is 1 MiB, the most the forward kept
+    resident until PR 39 (4 MiB since: kanana's call, guarded above) and the
+    last whose blocks fit the compiler's default scoped VMEM, and float32 dQ
+    of a head 2 MiB of what the one-pass backward takes."""
     q = ((2, 8192, 32, 64), jnp.bfloat16)
     kv = ((2, 8192, 8, 64), jnp.bfloat16)
 
@@ -471,7 +477,8 @@ def test_flash_grouped_heads_of_64_compile_at_the_lfm2_cell_shape(chip):
 
     text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1, 2)), chip,
                           q, kv, kv)
-    assert 8192 * 64 * 2 == fa._RESIDENT_KV_BYTES
+    assert 8192 * 64 * 2 <= fa._RESIDENT_KV_BYTES
+    assert fa._forward_vmem_limit(8192 * (64 + 64) * 2) is None
     assert 8192 * 64 * 4 <= fa._RESIDENT_DQ_BYTES
     assert "flash_fwd" in text and "flash_bwd_dkv" in text
     assert "flash_bwd_dq" not in text

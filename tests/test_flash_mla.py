@@ -137,16 +137,20 @@ def _traced_gauges(batch, length, heads, kv_heads, d_qk, d_v=None, d_s=0,
 
 # (B, L, H, H_kv, D, window) of the calls the benchmark's older cells make ->
 # the forward's (bq, bk, sub), and (plain, masked, skipped) tiles a head: the
-# parent's, which these kernels' second width must not move
-@pytest.mark.parametrize("call,blocks,tiles,group", [
-    ((8, 1024, 16, 16, 64, None), (512, 1024, 512), (1, 2, 1), 1),      # gpt2m-*
-    ((4, 4096, 16, 16, 128, None), (512, 4096, 512), (28, 8, 28), 1),   # olmoe
-    ((1, 8192, 32, 4, 128, 2048), (512, 2048, 512), (42, 28, 186), 8),  # trinity, sliding
-    ((1, 8192, 32, 4, 128, None), (512, 2048, 512), (120, 16, 120), 8),  # trinity, full
-    ((2, 8192, 32, 8, 64, None), (512, 8192, 512), (120, 16, 120), 4),  # lfm2
-    ((1, 8192, 32, 2, 128, None), (512, 2048, 512), (120, 16, 120), 16),  # nemotron
+# parent's, which these kernels' second width must not move; and (PR 39) the
+# forward's plain tiles whose score product runs under another tile's softmax,
+# with K/V of a head resident up to 4 MiB (8,192 rows of 128: Trinity's and
+# Nemotron's calls, streamed in 2,048-row blocks until then)
+@pytest.mark.parametrize("call,blocks,tiles,group,overlapped", [
+    ((8, 1024, 16, 16, 64, None), (512, 1024, 512), (1, 2, 1), 1, 0),   # gpt2m-*
+    ((4, 4096, 16, 16, 128, None), (512, 4096, 512), (28, 8, 28), 1, 16),  # olmoe
+    ((1, 8192, 32, 4, 128, 2048), (512, 8192, 512), (42, 28, 186), 8, 14),  # trinity, sliding
+    ((1, 8192, 32, 4, 128, None), (512, 8192, 512), (120, 16, 120), 8, 80),  # trinity, full
+    ((2, 8192, 32, 8, 64, None), (512, 8192, 512), (120, 16, 120), 4, 80),  # lfm2
+    ((1, 8192, 32, 2, 128, None), (512, 8192, 512), (120, 16, 120), 16, 80),  # nemotron
 ], ids=["gpt2m", "olmoe", "trinity-sliding", "trinity-full", "lfm2", "nemotron"])
-def test_the_older_cells_calls_pick_what_they_picked(call, blocks, tiles, group):
+def test_the_older_cells_calls_pick_what_they_picked(call, blocks, tiles, group,
+                                                     overlapped):
     batch, length, heads, kv_heads, d, window = call
     assert fa._forward_blocks(length, length, d, 2, None, None) == blocks
     assert fa._backward_blocks(length, length, None, None) == (512, 512)
@@ -154,17 +158,31 @@ def test_the_older_cells_calls_pick_what_they_picked(call, blocks, tiles, group)
     assert gauges == {
         "bwd.passes": 1, "kv_group": group, "window": window or 0,
         "d_qk": d, "d_v": d, "shared_key_cols": 0,
+        "fwd.tiles_overlapped": overlapped,
         **{f"{side}.tiles_{kind}": n for side in ("fwd", "bwd")
            for kind, n in zip(("plain", "masked", "skipped"), tiles)}}
 
 
-def test_latent_attentions_call_streams_its_keys_and_its_schedule_follows_the_width():
+def test_latent_attentions_call_keeps_its_keys_and_its_schedule_follows_the_width():
     """The float32 dQ of a query is 768 bytes at 192 columns, so the cell's
     16,384 positions are the last the one pass takes (``_RESIDENT_DQ_BYTES``,
     12 MiB since PR 37) where head_dim 128 goes on to 24,576; past PR 29's 4
     MiB the kernel asks for more scoped VMEM, up to it for what the older
-    calls ask; keys past 1 MiB a head stream in 2,048-row blocks."""
-    assert fa._forward_blocks(16384, 16384, 128, 2, None, None) == (512, 2048, 512)
+    calls ask; the forward keeps the keys of a head in VMEM up to 4 MiB
+    (PR 39: the cell's 16,384 rows of 128 are the last; 2,048-row blocks
+    until then) and streams the most whole tiles, a power of two of them,
+    that 4 MiB hold past it, under the scoped VMEM two buffers of its
+    blocks need."""
+    assert fa._forward_blocks(16384, 16384, 128, 2, None, None) == (512, 16384, 512)
+    assert fa._forward_blocks(16896, 16896, 128, 2, None, None) == (512, 16384, 512)
+    assert fa._forward_blocks(32768, 32768, 64, 2, None, None) == (512, 32768, 512)
+    assert fa._forward_blocks(32768, 32768, 256, 4, None, None) == (512, 4096, 512)
+    assert fa._forward_blocks(16384, 16384, 192, 2, None, None) == (512, 8192, 512)
+    assert fa._forward_vmem_limit(1024 * 128 * 2) is None           # gpt2m-*
+    assert fa._forward_vmem_limit(4096 * 256 * 2) is None           # olmoe
+    assert fa._forward_vmem_limit(8192 * 128 * 2) is None           # lfm2
+    assert fa._forward_vmem_limit(8192 * 256 * 2) == 20 << 20       # trinity, nemotron
+    assert fa._forward_vmem_limit(16384 * 320 * 2) == 32 << 20      # kanana
     gauges = _traced_gauges(1, 16384, 32, 32, 192, 128, 64)
     assert (gauges["d_qk"], gauges["d_v"], gauges["shared_key_cols"]) == (192, 128, 64)
     assert gauges["bwd.passes"] == 1

@@ -27,6 +27,7 @@ aliased buffer, in an order only the chip's own pipeline shows), and exits
     python tools/flash_forward_timing.py --shapes xent-bwd,xent-bwd-gpt2,xent-bwd-trinity,xent-bwd-v3 --check
     python tools/flash_forward_timing.py --root .archive_check/parent   # another checkout
     python tools/flash_forward_timing.py --shapes mla-padded,mla-assembled,mla-shared,bwd-mla-padded,bwd-mla-assembled,bwd-mla-shared [--resident-dq-bytes 4194304]
+    python tools/flash_forward_timing.py --shapes mla-shared,trinity-full,trinity-win,nemotron,lfm2,cell,l8192 [--walk-groups 0] [--blocks 512,2048,512]
 
 ``mla-*`` (PR 37): latent attention's call at kanana-pretrain-16k's shape, 1 x
 16,384 x 32 heads, keys 192 wide (128 a head + 64 rotary columns all heads
@@ -39,6 +40,15 @@ what the form adds around the kernels too (``device_ms_all``: every device
 operation of a call). The backward runs in one pass (12 MiB of dQ a head);
 ``--resident-dq-bytes 4194304`` times the two kernels of the split path, which
 PR 29's limit gave this width.
+
+``nemotron`` / ``lfm2`` (PR 39): the two cells' forward calls no other name
+covered, so one command times every cell's forward; ``l32768-d128``: 8 MiB of
+K a head, past what the forward keeps resident. ``flash.fwd.tiles_overlapped``
+(the plain tiles whose score product is issued under another tile's softmax)
+is printed with the other gauges. ``--walk-groups 4,2`` overrides the sizes
+of the straight-line blocks the forward's walk takes its plain tiles in (0:
+none, every tile a chain of its own), ``--group-q-chunks N`` the pieces of
+the queries a tile is cut into inside one.
 
 ``--blocks bq,bk,sub`` overrides the forward's choice, ``--bwd-blocks bq,bk``
 the backward's, ``--xent-blocks bn,bv`` the named head kernel's (a checkout
@@ -89,6 +99,13 @@ SHAPES = {
     "bwd-trinity-full": (1, 8192, 32, 128, True, "bwd"),
     "bwd-trinity-win-rep": (1, 8192, 32, 128, True, "bwd"),
     "bwd-trinity-full-rep": (1, 8192, 32, 128, True, "bwd"),
+    # the two forwards no other name covers (PR 39): nemotron-pretrain-8k's
+    # call, 32 query heads over 2 KV heads of 128, and lfm2-pretrain-8k's, 2
+    # sequences of 32 query heads over 8 KV heads of 64 (walks to 16 tiles)
+    "nemotron": (1, 8192, 32, 128, True, "fwd"),
+    "lfm2": (2, 8192, 32, 64, True, "fwd"),
+    # past what stays resident (PR 39: 4 MiB of K a head): 8 MiB, streamed
+    "l32768-d128": (1, 32768, 8, 128, True, "fwd"),
 }
 # latent attention's call: name -> form
 MLA_V, MLA_SHARED = 128, 64
@@ -100,6 +117,7 @@ SHAPES.update({name: (1, 16384, 32, 192, True,
 # name -> (window, KV heads) where they are not (None, H)
 BANDS = {name: (2048 if "-win" in name else None, 32 if name.endswith("-rep") else 4)
          for name in SHAPES if "trinity" in name}
+BANDS.update({"nemotron": (None, 2), "lfm2": (None, 8)})
 # the fused head, bfloat16 rows against a float32 table: name -> (N, D, V,
 # table layout, kind), at olmoe-pretrain-4k's call, at gpt2m-*'s, a chip and
 # call, and at trinity-pretrain-8k's. ``xent-dh`` and ``xent-dw`` both run
@@ -395,6 +413,13 @@ def main(argv=None):
                         help="the backward's bq,bk override; may repeat")
     parser.add_argument("--xent-blocks", action="append", default=[],
                         help="a head kernel's bn,bv override; may repeat")
+    parser.add_argument("--walk-groups", default=None,
+                        help="override _WALK_GROUPS, the plain tiles the "
+                             "forward's walk takes as one block, largest "
+                             "first (e.g. 4,2; 0: none, the one-tile chain)")
+    parser.add_argument("--group-q-chunks", type=int, default=None,
+                        help="override _GROUP_Q_CHUNKS, the pieces of the "
+                             "queries a tile is cut into inside a block")
     parser.add_argument("--resident-dq-bytes", type=int, default=None,
                         help="override _RESIDENT_DQ_BYTES: the float32 dQ of a "
                              "(batch, head) up to which the backward is one pass")
@@ -413,6 +438,11 @@ def main(argv=None):
 
     if args.resident_dq_bytes is not None:
         modules["flash_attention"]._RESIDENT_DQ_BYTES = args.resident_dq_bytes
+    if args.walk_groups is not None:
+        modules["flash_attention"]._WALK_GROUPS = tuple(
+            int(x) for x in args.walk_groups.split(",") if int(x) > 1)
+    if args.group_q_chunks is not None:
+        modules["flash_attention"]._GROUP_Q_CHUNKS = args.group_q_chunks
 
     def plans(flags):
         return [tuple(int(x) for x in b.split(",")) for b in flags] or [None]
