@@ -164,7 +164,7 @@ class GatedAttention(nn.Module):
         wide, narrow = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
         q = heads(_dense(wide, cfg.dtype, "query")(x), cfg.n_heads)
         k = heads(_dense(narrow, cfg.dtype, "key")(x), cfg.n_kv_heads)
-        v = heads(_dense(narrow, cfg.dtype, "value")(x), cfg.n_kv_heads)
+        v = _dense(narrow, cfg.dtype, "value")(x)
         gate = _dense(wide, cfg.dtype, "gate")(x)
         q = RMSNorm(cfg.rms_eps, cfg.dtype, name="q_norm")(q)
         k = RMSNorm(cfg.rms_eps, cfg.dtype, name="k_norm")(k)
@@ -175,11 +175,16 @@ class GatedAttention(nn.Module):
         window = cfg.window if sliding else None
         if cfg.attention_impl == "flash" and not self.is_initializing():
             from autodist_tpu.ops.flash_attention import flash_attention
-            ctx = flash_attention(q, k, v, causal=True, window=window)
+            # v goes from its projection into the kernels and the result
+            # from them into the gate as rows, read and written where they
+            # lie; q and k have been normed a head (and turned) since theirs
+            ctx = flash_attention(q, k, v, causal=True, window=window,
+                                  heads=(cfg.n_heads, cfg.n_kv_heads))
         else:
             group = cfg.n_heads // cfg.n_kv_heads
             ctx = dot_product_attention(
-                q, jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2),
+                q, jnp.repeat(k, group, axis=2),
+                jnp.repeat(heads(v, cfg.n_kv_heads), group, axis=2),
                 band_mask(length, window, cfg.dtype), cfg.dtype)
         with jax.named_scope("attn.gate"):
             ctx = ctx.reshape(b, length, wide) * nn.sigmoid(gate)

@@ -55,18 +55,25 @@ def rope_pairs(x, positions, theta: float = 10000.0,
     pass as they are; the columns keep their order. It is :func:`rope` under
     the permutation that puts the even columns before the odd ones (what
     DeepSeek-V3's ``rope_interleave`` does to activations before it rotates
-    halves); a pair's partner is the lane beside it, so nothing is sliced or
-    relaid: one elementwise pass. float32 inside, cast back to ``x.dtype``."""
+    halves); a pair's partner is the lane beside it. float32 inside, cast
+    back to ``x.dtype``. Only the rotary columns are read into float32 and
+    turned; the others are passed on as they came (until PR 41 they went
+    through ``x * 1 + partner * 0`` in float32 with the rest, and the shift
+    by one lane, which XLA on TPU answers by laying the whole array out
+    anew and writing both shifted copies, was over all ``D`` columns: three
+    times the bytes at latent attention's 64 of 192)."""
     d = x.shape[-1]
     r = d if rotary_dim is None else rotary_dim
     if r % 2 or (d - r) % 2:
         raise ValueError(f"rotary_dim {r} of {d} columns: both parts must be even")
+    if r < d:
+        return jnp.concatenate(
+            [x[..., :d - r], rope_pairs(x[..., d - r:], positions, theta)],
+            axis=-1)
     inv_freq = theta ** (-jnp.arange(0, r, 2, dtype=jnp.float32) / r)
     angles = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
-    still = ((0, 0), (d - r, 0))                  # cos 1, sin 0: not rotated
-    cos = jnp.pad(jnp.repeat(jnp.cos(angles), 2, axis=-1), still,
-                  constant_values=1.0)[:, None, :]
-    sin = jnp.pad(jnp.repeat(jnp.sin(angles), 2, axis=-1), still)[:, None, :]
+    cos = jnp.repeat(jnp.cos(angles), 2, axis=-1)[:, None, :]
+    sin = jnp.repeat(jnp.sin(angles), 2, axis=-1)[:, None, :]
     x32 = x.astype(jnp.float32)
     partner = jnp.where(jnp.arange(d) % 2 == 0, -jnp.roll(x32, -1, axis=-1),
                         jnp.roll(x32, 1, axis=-1))

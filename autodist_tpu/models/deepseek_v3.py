@@ -46,7 +46,11 @@ the normal path** and its start from the balancing rule alone are
 ``flash_attention`` with a value width of its own and the rotary key as
 ``k_shared`` (read once a layer through the index maps, never repeated in
 memory; its gradient the sum over the heads), or under ``attention_impl="dot"``
-the quadratic form on an assembled key.
+the quadratic form on an assembled key. ``kv_up``'s output goes into that
+call whole, as the rows the product wrote (a head's 128 key columns then its
+128 values: the kernels read both where they lie and hand d(kv) back as one
+array), and the result comes out as rows for the output projection; q is
+turned in between and goes in ``[B, L, H, 192]`` (PR 41).
 
 Under ``remat`` every layer is a ``jax.checkpoint`` whose policy keeps the
 values named in :data:`KEPT` and recomputes everything else from the residual
@@ -178,9 +182,8 @@ class LatentAttention(nn.Module):
             c = name(RMSNorm(cfg.rms_eps, cfg.dtype, name="kv_norm")(
                 down[..., :cfg.kv_lora_rank]), KEPT_LATENT)
         with jax.named_scope("mla.kv_up"):
-            kv = _dense(heads * (d_n + d_v), cfg.dtype, "kv_up")(c).reshape(
-                b, length, heads, d_n + d_v)
-            k_nope, v = kv[..., :d_n], kv[..., d_n:]
+            # a head's position-free key columns, then its values
+            kv = _dense(heads * (d_n + d_v), cfg.dtype, "kv_up")(c)
         with jax.named_scope("mla.rope"):
             positions = jnp.arange(length)
             q = rope_pairs(q, positions, cfg.rope_theta, d_r)
@@ -188,15 +191,20 @@ class LatentAttention(nn.Module):
                                      positions, cfg.rope_theta), KEPT_ROPE_KEY)
         if cfg.attention_impl == "flash" and not self.is_initializing():
             from autodist_tpu.ops.flash_attention import flash_attention
-            ctx = flash_attention(q, k_nope, v, causal=True,
+            # kv as the product wrote it, rows of heads * 256 columns: the
+            # kernels find a head's keys and values where they lie and hand
+            # d(kv), and the result, back as such rows. q has been turned
+            # since its projection and goes in [B, L, H, 192].
+            ctx = flash_attention(q, kv, None, causal=True, heads=(heads, heads),
                                   k_shared=k_rope[:, :, 0, :])
             # KEPT_FLASH: o and one float32 lse a head
             kept.append(ctx.size * ctx.dtype.itemsize + b * length * heads * 4)
         else:
-            k = jnp.concatenate([k_nope, jnp.broadcast_to(
+            kv = kv.reshape(b, length, heads, d_n + d_v)
+            k = jnp.concatenate([kv[..., :d_n], jnp.broadcast_to(
                 k_rope, (b, length, heads, d_r))], axis=-1)
-            ctx = dot_product_attention(q, k, v, causal_mask(length, cfg.dtype),
-                                        cfg.dtype)
+            ctx = dot_product_attention(q, k, kv[..., d_n:],
+                                        causal_mask(length, cfg.dtype), cfg.dtype)
         telemetry.gauge("mla.kept_bytes_per_token").set(
             sum(kept) // (b * length) if cfg.remat else 0)
         with jax.named_scope("mla.out_proj"):

@@ -307,16 +307,21 @@ class GroupedAttention(nn.Module):
     def __call__(self, h):
         cfg = self.config
         b, length, _ = h.shape
-        heads = lambda t, n: checkpoint_name(t, KEPT_QKV).reshape(  # noqa: E731
-            b, length, n, cfg.head_dim)
         wide, narrow = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
-        q = heads(_in_proj(wide, cfg, "query")(h), cfg.n_heads)
-        k = heads(_in_proj(narrow, cfg, "key")(h), cfg.n_kv_heads)
-        v = heads(_in_proj(narrow, cfg, "value")(h), cfg.n_kv_heads)
+        q = checkpoint_name(_in_proj(wide, cfg, "query")(h), KEPT_QKV)
+        k = checkpoint_name(_in_proj(narrow, cfg, "key")(h), KEPT_QKV)
+        v = checkpoint_name(_in_proj(narrow, cfg, "value")(h), KEPT_QKV)
         if cfg.attention_impl == "flash" and not self.is_initializing():
             from autodist_tpu.ops.flash_attention import flash_attention
-            ctx = flash_attention(q, k, v, causal=True)
+            # no position is turned into q or k here: the projections' own
+            # rows go in and the result's rows come out, read and written by
+            # the kernels where they lie
+            ctx = flash_attention(q, k, v, causal=True,
+                                  heads=(cfg.n_heads, cfg.n_kv_heads))
         else:
+            heads = lambda t, n: t.reshape(b, length, n, cfg.head_dim)  # noqa: E731
+            q, k, v = heads(q, cfg.n_heads), heads(k, cfg.n_kv_heads), \
+                heads(v, cfg.n_kv_heads)
             group = cfg.n_heads // cfg.n_kv_heads
             ctx = dot_product_attention(
                 q, jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2),

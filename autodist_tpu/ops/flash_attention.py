@@ -62,6 +62,27 @@ key head): the score tile is then the sum of two products, ``q_nope k_nope^T
 ignores the head, and each head's float32 part of its gradient is summed
 outside the kernels as a group's is.
 
+Where the operands lie (PR 41). The grid's first axis is the (batch, head)
+pair and every operand is addressed through its BlockSpec's index map. An
+operand the caller hands as ``[B, L, heads, D]`` is transposed by XLA to ``[B
+* heads, L, D]`` before the call and its gradient back after it, as every
+operand was until PR 41. One handed as ``[B, L, heads * D]``, a projection's
+own rows, is read where it lies wherever ``D`` is whole 128-lane tiles and no
+row is padded to a block (:func:`_stays`): head ``n`` is column block ``n`` of
+the rows (a KV head's query heads name its block; keys and values that one
+projection wrote side by side, ``v`` None, are blocks ``2n`` and ``2n + 1`` of
+one array, and dK / dV go back as one), the result and the gradients are
+written the same way, and the row term D is a product of ``dO * O`` with the
+heads' 0 / 1 columns. The kernels' bodies do not know which: a block is ``[1,
+rows, D]`` either way, the tiles and their arithmetic are the same, and which
+it is is decided from the operands' ranks and widths alone, before anything
+is traced. What XLA does AROUND the call decides which form a caller should
+hand (measured in ``kanana-pretrain-16k``, PERF.md §6 "PR 41"): rows it never
+touches between a projection and the call cost nothing in place and two
+copies a layer transposed; a ``[B, L, heads, D]`` array it turns or norms in
+between is computed in the transposed layout for nothing, and asked for as
+rows it is copied across in float32 instead.
+
 On non-TPU backends the kernels run in pallas interpret mode, so tests exercise
 the same code path on the CPU-sim mesh.
 """
@@ -177,6 +198,7 @@ _GROUP_Q_CHUNKS = 2
 # 24,576 at 128, 49,152 at 64.
 _RESIDENT_DQ_BYTES = 12 << 20
 _SMALL_DQ_BYTES = 4 << 20
+_LANES = 128                # columns of a lane tile
 
 
 def _backward_vmem_limit(dq_bytes: int) -> int:
@@ -557,39 +579,154 @@ def _kv_row(group: int):
     return (lambda bh: bh) if group == 1 else (lambda bh: bh // group)
 
 
-def _shared_cols(q, k, k_shared) -> int:
+def _stays(width: int, rows: bool) -> bool:
+    """Whether an operand of ``n`` heads of ``width`` columns is read (or
+    written) by the kernels where it lies, ``[B, L, n * width]`` with column
+    block ``head`` the head's rows: ``rows`` — the caller handed it so, as a
+    projection's own rows (:func:`_given_as_rows`), and no row of the call
+    is padded to a block — and the lane rule: a head's columns are whole
+    128-lane tiles (a 64- or a 192-wide column block does not lower).
+    Static shapes alone. Anything else is transposed to ``[B * n, L, width]``
+    by XLA around the kernels, as every operand was before PR 41: a ``[B, L,
+    n, width]`` operand always (where the caller turns q and k between the
+    projection and this call, XLA does that in the transposed layout, and
+    asked for ``[B, L, n * width]`` rows it copies them across in float32
+    instead), so such a call traces and lowers what it always did."""
+    return rows and width % _LANES == 0
+
+
+def _given_as_rows(q, k, v) -> tuple:
+    """Which of q, k, v the caller handed as ``[B, L, heads * D]`` rows,
+    three dimensions, and not as ``[B, L, heads, D]``; ``v`` None (the
+    values packed behind the keys) goes with ``k``. The result and the
+    gradient of the result are handed as ``v`` is."""
+    rows = [x is not None and x.ndim == 3 for x in (q, k, v)]
+    return rows[0], rows[1], rows[1] if v is None else rows[2]
+
+
+def _as_heads(x, n: int):
+    """``x`` as ``[B, L, n, D]`` whichever way it was handed (None: None)."""
+    return x if x is None or x.ndim == 4 else x.reshape(*x.shape[:2], n, -1)
+
+
+def _as_given(x, rows: bool):
+    """``[B, L, n, D]`` back in the form its operand was handed in."""
+    return x.reshape(*x.shape[:2], -1) if rows and x is not None else x
+
+
+def _head_rows(x, stays: bool):
+    """``x`` ``[B, L, n, D]`` as the kernels address it: ``[B, L, n * D]``
+    where it stays (:func:`_stays`), else ``[B * n, L, D]``."""
+    b, length, n, d = x.shape
+    if stays:
+        return x.reshape(b, length, n * d)
+    return x.transpose(0, 2, 1, 3).reshape(b * n, length, d)
+
+
+def _head_spec(rows: int, width: int, h: int, stays: bool, block, group: int = 1,
+               part=(1, 0)):
+    """BlockSpec of ``rows`` x ``width`` of one head out of an operand laid
+    out by :func:`_head_rows`, under a grid whose first axis is the (batch,
+    query head) pair ``bh`` of ``h`` heads: ``block(*rest of the grid
+    indices)`` is the row block, and the head is query head ``bh % h``'s
+    own, or with ``group`` > 1 the KV head it reads (:func:`_kv_row`).
+    ``part = (n, i)``: a head's columns are ``n`` such blocks and this is
+    the ``i``-th (an operand that stays only: keys and values of one packed
+    array, :func:`_packed_kv`)."""
+    if stays:
+        n, i = part
+
+        def index(bh, *ij):
+            head = jax.lax.rem(bh, h)
+            if group > 1:
+                head = jax.lax.div(head, group)
+            return jax.lax.div(bh, h), block(*ij), head * n + i if n > 1 else head
+    else:
+        row = _kv_row(group)
+
+        def index(bh, *ij):
+            return row(bh), block(*ij), 0
+    return pl.BlockSpec((1, rows, width), index)
+
+
+def _heads_back(x, b: int, h: int, length: int, stays: bool):
+    """A kernel's per-head output back as ``[B, L, h, D]``."""
+    if stays:
+        return x.reshape(b, length, h, -1)
+    return x[:, :length, :].reshape(b, h, length, -1).transpose(0, 2, 1, 3)
+
+
+def _shared_cols(q, k, k_shared, packed: bool = False) -> int:
     """Trailing key columns every query head shares (0: none); the widths of
-    ``q``, ``k`` and ``k_shared`` must add up."""
+    ``q``, ``k`` and ``k_shared`` must add up (``packed``: ``k`` holds a
+    head's values after its keys, so it must be wider than they are)."""
     d_s = 0 if k_shared is None else k_shared.shape[-1]
-    if k.shape[-1] + d_s != q.shape[-1]:
+    d_k = q.shape[-1] - d_s
+    if k.shape[-1] <= d_k if packed else k.shape[-1] != d_k:
         raise ValueError(
             f"q is {q.shape[-1]} wide, k {k.shape[-1]}"
-            + (f" + {d_s} shared" if d_s else ""))
+            + (f" + {d_s} shared" if d_s else "")
+            + (" with the values packed behind" if packed else ""))
     return d_s
 
 
+def _kv_parts(packed: bool) -> tuple:
+    """``part`` of :func:`_head_spec` for the keys and for the values."""
+    return ((2, 0), (2, 1)) if packed else ((1, 0), (1, 0))
+
+
+def _packed_kv(k, v, d_k: int, k_in: bool):
+    """``(k, v, packed)``. ``v`` None: ``k`` is ``[B, L, H_kv, d_k + dv]``, a
+    head's keys then its values, as one projection wrote them. ``packed``
+    (``k_in``: keys of this width stay where they lie, :func:`_stays`; and
+    the values are as wide): the kernels read the two parts of a head where
+    they are, blocks ``2 * head`` and ``2 * head + 1`` of the ``[B, L, H_kv *
+    2 * d_k]`` rows, ``k`` comes back as it came and ``v`` as None; any
+    other packed array is cut in two here."""
+    if v is not None:
+        return k, v, False
+    if k_in and k.shape[-1] == 2 * d_k:
+        return k, None, True
+    return k[..., :d_k], k[..., d_k:], False
+
+
 def _flash_forward(q, k, v, causal: bool, q_block, k_block, interpret: bool,
-                   window=None, k_shared=None):
+                   window=None, k_shared=None, kept=lambda x: x, heads=None):
     """Returns (out [B, Lq, H, Dv], lse [B*H, n_q, bq] f32). ``k`` / ``v``
     may hold fewer heads than ``q`` (grouped KV heads), ``v`` another width
     than ``q`` and ``k`` (the scale is the key width's), and ``k_shared``
     ``[B, Lk, Ds]`` the keys' trailing columns where all heads share them
-    (``k`` then holds the leading ``D - Ds``)."""
+    (``k`` then holds the leading ``D - Ds``). ``v`` None: ``k`` holds a
+    head's values behind its keys (:func:`_packed_kv`). ``kept``: applied
+    to ``(out, lse)`` as the backward will read them (the forward rule's
+    ``checkpoint_name``), which for a result that stays is the kernel's own
+    ``[B, Lq, H * Dv]`` rows: named as ``[B, Lq, H, Dv]``, XLA copied them
+    into that shape's layout for the name's sake. ``heads = (H, H_kv)``:
+    needed where an operand is handed as ``[B, L, heads * D]`` rows
+    (:func:`_given_as_rows`); the result is then rows as ``v`` is."""
+    q_rows, k_rows, v_rows = _given_as_rows(q, k, v)
+    h, h_kv = heads or (q.shape[2], k.shape[2])
+    q, k, v = _as_heads(q, h), _as_heads(k, h_kv), _as_heads(v, h_kv)
     b, lq, h, d = q.shape
-    lk, h_kv, dv = k.shape[1], k.shape[2], v.shape[3]
-    d_s = _shared_cols(q, k, k_shared)
+    lk = k.shape[1]
+    d_s = _shared_cols(q, k, k_shared, packed=v is None)
     d_k = d - d_s
+    dv = k.shape[3] - d_k if v is None else v.shape[3]
     group = _kv_group(q, k)
-    kv_row = _kv_row(group)
     scale = 1.0 / (d ** 0.5)
-
-    # Collapse (batch, head) into the grid's first axis: [B*H, L, D].
-    qf = q.transpose(0, 2, 1, 3).reshape(b * h, lq, d)
-    kf = k.transpose(0, 2, 1, 3).reshape(b * h_kv, lk, d_k)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * h_kv, lk, dv)
 
     bq, bk, sub = _forward_blocks(lq, lk, max(d_k, dv), q.dtype.itemsize,
                                   q_block, k_block)
+    # (batch, head) is the grid's first axis. An operand handed as a
+    # projection's rows, its heads whole lane tiles wide, stays where it lies
+    # and the index maps find the head's columns; any other is collapsed to
+    # [B*H, L, D] by XLA.
+    whole = lq % bq == 0 and lk % bk == 0
+    q_in, k_in, v_in = (_stays(d, whole and q_rows), _stays(d_k, whole and k_rows),
+                        _stays(dv, whole and v_rows))
+    k, v, packed = _packed_kv(k, v, d_k, k_in)
+    qf, kf = _head_rows(q, q_in), _head_rows(k, k_in)
+    vf = kf if packed else _head_rows(v, v_in)
     n_q = pl.cdiv(lq, bq)
     if n_q * bq - lq:
         qf = jnp.pad(qf, ((0, 0), (0, n_q * bq - lq), (0, 0)))
@@ -613,6 +750,8 @@ def _flash_forward(q, k, v, causal: bool, q_block, k_block, interpret: bool,
     telemetry.gauge("flash.d_qk").set(d)
     telemetry.gauge("flash.d_v").set(dv)
     telemetry.gauge("flash.shared_key_cols").set(d_s)
+    # q, k, v and o: those XLA transposes around the kernel
+    telemetry.gauge("flash.fwd.operands_relaid").set(4 - q_in - k_in - 2 * v_in)
 
     kernel = functools.partial(_flash_kernel, lk=lk, sub=sub, causal=causal,
                                scale=scale, window=window, groups=groups)
@@ -629,13 +768,14 @@ def _flash_forward(q, k, v, causal: bool, q_block, k_block, interpret: bool,
         def kv_block(i, j):
             return j
 
-    def kv_index(bh, i, j):
-        return kv_row(bh), kv_block(i, j), 0
+    def q_block_of(i, j):
+        return i
 
+    k_part, v_part = _kv_parts(packed)
     in_specs = [
-        pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-        pl.BlockSpec((1, bk, d_k), kv_index),
-        pl.BlockSpec((1, bk, dv), kv_index),
+        _head_spec(bq, d, h, q_in, q_block_of),
+        _head_spec(bk, d_k, h, k_in, kv_block, group, k_part),
+        _head_spec(bk, dv, h, v_in, kv_block, group, v_part),
     ]
     operands = (qf, kf, vf)
     if d_s:     # one row a batch entry, whatever the head
@@ -647,7 +787,7 @@ def _flash_forward(q, k, v, causal: bool, q_block, k_block, interpret: bool,
         grid=(b * h, n_q, n_k),
         in_specs=in_specs,
         out_specs=(
-            pl.BlockSpec((1, bq, dv), lambda bh, i, j: (bh, i, 0)),
+            _head_spec(bq, dv, h, v_in, q_block_of),
             # VMEM bound: the whole [n_q, bq] lse plane (one f32 row per query,
             # ~4*Lq bytes) stays resident per grid row in this kernel and both
             # backward kernels, so max single-shard sequence length is capped at
@@ -658,7 +798,8 @@ def _flash_forward(q, k, v, causal: bool, q_block, k_block, interpret: bool,
             pl.BlockSpec((1, n_q, bq), lambda bh, i, j: (bh, 0, 0)),
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((b * h, n_q * bq, dv), q.dtype),
+            jax.ShapeDtypeStruct((b, lq, h * dv) if v_in
+                                 else (b * h, n_q * bq, dv), q.dtype),
             jax.ShapeDtypeStruct((b * h, n_q, bq), jnp.float32),
         ),
         scratch_shapes=[
@@ -673,8 +814,10 @@ def _flash_forward(q, k, v, causal: bool, q_block, k_block, interpret: bool,
         interpret=interpret,
     )(*operands)
 
-    out = out[:, :lq, :].reshape(b, h, lq, dv).transpose(0, 2, 1, 3)
-    return out, lse
+    if v_in:
+        return kept((out, lse))
+    out, lse = kept((_heads_back(out, b, h, lq, False), lse))
+    return _as_given(out, v_rows), lse
 
 
 def _query_tile_counts(q_lo, k_lo, valid, bq: int, bk: int, sub: int,
@@ -832,6 +975,17 @@ def _finish_dkdv(dk_ref, dv_ref, dk_acc, dv_acc, scale: float,
             dks_ref.dtype)
 
 
+def _dkv_parts(refs, at: int, packed: int):
+    """``refs`` with the one output ``refs[at]`` ``[1, bk, 2 * packed]`` that
+    holds dK and dV side by side (``packed``: the keys' width, 0: two
+    outputs as they are) replaced by its two halves, dK's and dV's."""
+    if not packed:
+        return refs
+    dkv = refs[at]
+    return (refs[:at] + (dkv.at[:, :, :packed], dkv.at[:, :, packed:])
+            + refs[at + 1:])
+
+
 def _shared_refs(refs, shared: bool, n_out: int):
     """A backward kernel's ``refs`` after q, dO, lse, D, k, v — ``[ks,] outs
     [, dks] | scratch [, dks_acc]`` — as ``(ks_ref, dks_ref, dks_acc, the
@@ -848,12 +1002,13 @@ def _shared_refs(refs, shared: bool, n_out: int):
 
 def _flash_bwd_kernel(off_ref, q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
                       *refs, lk: int, sub: int, causal: bool, scale: float,
-                      window=None, shared: bool = False):
+                      window=None, shared: bool = False, packed: int = 0):
     """The one-pass backward: q, dO and the float32 dQ accumulator of one
     (batch, head) stay in VMEM across its K/V blocks (the grid's second axis);
     a grid step finishes dK and dV of its block, the last writes dQ."""
-    ks_ref, dks_ref, dks_acc, refs = _shared_refs(refs, shared, 3)
-    dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = refs
+    ks_ref, dks_ref, dks_acc, refs = _shared_refs(refs, shared,
+                                                  2 if packed else 3)
+    dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = _dkv_parts(refs, 1, packed)
     ki = pl.program_id(1)
     bk = k_ref.shape[1]
 
@@ -887,10 +1042,11 @@ def _flash_bwd_kernel(off_ref, q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
 
 def _flash_bwd_dkdv_kernel(off_ref, q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
                            *refs, lk: int, causal: bool, scale: float,
-                           window=None, shared: bool = False):
+                           window=None, shared: bool = False, packed: int = 0):
     """The split path's dK/dV: a K/V block accumulates over the q blocks."""
-    ks_ref, dks_ref, dks_acc, refs = _shared_refs(refs, shared, 2)
-    dk_ref, dv_ref, dk_acc, dv_acc = refs
+    ks_ref, dks_ref, dks_acc, refs = _shared_refs(refs, shared,
+                                                  1 if packed else 2)
+    dk_ref, dv_ref, dk_acc, dv_acc = _dkv_parts(refs, 0, packed)
     ki = pl.program_id(1)
     qi = pl.program_id(2)
     bq, bk = q_ref.shape[1], k_ref.shape[1]
@@ -963,17 +1119,33 @@ def _count_backward_tiles(n_q: int, lk: int, bq: int, bk: int, causal: bool,
     return plain, need - plain, n_q * n_k - need
 
 
-def prepare_backward_q_side(q, o, g, q_block):
+def prepare_backward_q_side(q, o, g, q_block, in_place=(False, False)):
     """Query-side backward layout: transposed/padded q and dO plus the row term
     D_i = rowsum(dO * O) in the kernels' [bh, n_q, bq] plane layout. Depends only
     on the query side, so ring attention computes it ONCE and reuses it across
-    every ring step."""
+    every ring step. ``in_place`` (:func:`_flash_backward` alone): whether q,
+    and whether dO and O, are left where they lie (:func:`_stays`); of the
+    latter only D's rows change places."""
     b, lq, h, d = q.shape
-    qf = q.transpose(0, 2, 1, 3).reshape(b * h, lq, d)
-    dof = g.transpose(0, 2, 1, 3).reshape(b * h, lq, -1)    # the values' width
-    of = o.transpose(0, 2, 1, 3).reshape(b * h, lq, -1)
-    # D_i = rowsum(dO * O) — elementwise, XLA fuses it.
-    dd = jnp.sum(dof.astype(jnp.float32) * of.astype(jnp.float32), axis=-1)
+    qf = _head_rows(q, in_place[0])
+    if in_place[1]:
+        dof = _head_rows(g, True)
+        # A head's sum is 128 lanes of a [B, L, H * Dv] row: as a product
+        # with the heads' 0 / 1 columns (exact in three bfloat16 passes, the
+        # sum float32), which XLA runs on the rows where they lie; the sum
+        # over the last axis of [B, L, H, Dv] made it copy dO and O into a
+        # layout of their own first.
+        dv = g.shape[-1]
+        ones = jnp.repeat(jnp.eye(h, dtype=jnp.float32), dv, axis=0)   # [H*Dv, H]
+        dd = jnp.einsum(
+            "blc,ch->bhl",
+            dof.astype(jnp.float32) * _head_rows(o, True).astype(jnp.float32),
+            ones, precision=jax.lax.Precision.HIGHEST).reshape(b * h, lq)
+    else:
+        dof = g.transpose(0, 2, 1, 3).reshape(b * h, lq, -1)    # the values' width
+        of = o.transpose(0, 2, 1, 3).reshape(b * h, lq, -1)
+        # D_i = rowsum(dO * O) — elementwise, XLA fuses it.
+        dd = jnp.sum(dof.astype(jnp.float32) * of.astype(jnp.float32), axis=-1)
 
     bq = min(q_block, lq)
     n_q = pl.cdiv(lq, bq)
@@ -988,9 +1160,11 @@ def prepare_backward_q_side(q, o, g, q_block):
 
 def _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, k_block,
                        interpret, q_shape, q_offset=0, k_offset=0,
-                       out_dtype=None, window=None, k_shared=None):
+                       out_dtype=None, window=None, k_shared=None,
+                       stays=(False, False, False)):
     """Backward against one K/V shard from prepared query-side layout. Returns
-    (dq, dk, dv) in [B, L, H, D] (and the shared key columns' gradient ``[B,
+    (dq, dk, dv) in [B, L, H, D] (``v`` None, the values packed behind the
+    keys: ``(dq, dkv, None)``; and the shared key columns' gradient ``[B,
     Lk, Ds]`` after them where ``k_shared`` is given); ``out_dtype`` overrides
     the kernels' output dtype (ring passes f32 so per-step contributions
     accumulate unquantized).
@@ -1004,19 +1178,33 @@ def _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, k_block,
     Grouped KV heads (``k`` / ``v`` with fewer heads than q): every query
     head's kernels read the K/V rows of its KV head through the index map and
     write their own float32 dK / dV, which are summed over the group here;
-    the shared key columns' float32 parts likewise, over all the heads."""
+    the shared key columns' float32 parts likewise, over all the heads.
+
+    ``stays``: whether q (as the query side was prepared), the keys and the
+    values with dO stay where they lie (:func:`_stays`) and are read, and
+    their gradients written, as ``[B, L, heads * width]``; the rest as ``[B *
+    heads, L, width]`` through XLA's transposes. A group's dK / dV leave the
+    kernels as ``[B * heads, Lk, width]`` either way: their sum over the
+    group is a sum over leading dimensions there, where over column blocks
+    of one row XLA copied the float32 array into another layout first."""
     b, lq, h, d = q_shape
-    lk, h_kv, d_k, dv = k.shape[1], k.shape[2], k.shape[3], v.shape[3]
-    d_s = d - d_k
+    lk, h_kv = k.shape[1], k.shape[2]
+    d_s = 0 if k_shared is None else k_shared.shape[-1]
+    d_k = d - d_s
+    one_array = v is None   # the values behind the keys: dK and dV go back so
+    q_in, k_in, v_in = stays
+    k, v, packed = _packed_kv(k, v, d_k, k_in)
+    dv = d_k if packed else v.shape[3]
     group = h // h_kv
-    kv_row = _kv_row(group)
     scale = 1.0 / (d ** 0.5)
+    # dK and dV of a query head: where K and V lie, or one array a group
+    dk_in, dv_in, packed_out = (x and group == 1 for x in (k_in, v_in, packed))
     static_offsets = _is_static(q_offset) and _is_static(k_offset)
     offs = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                       jnp.asarray(k_offset, jnp.int32)])
 
-    kf = k.transpose(0, 2, 1, 3).reshape(b * h_kv, lk, d_k)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * h_kv, lk, dv)
+    kf = _head_rows(k, k_in)
+    vf = kf if packed else _head_rows(v, v_in)
     bk = min(k_block, lk)
     n_k = pl.cdiv(lk, bk)
     k_pad = n_k * bk - lk
@@ -1027,7 +1215,7 @@ def _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, k_block,
             k_shared = jnp.pad(k_shared, pad)
     dq_dtype = out_dtype or qf.dtype
     dk_dtype = out_dtype or k.dtype
-    dv_dtype = out_dtype or v.dtype
+    dv_dtype = out_dtype or (k if packed else v).dtype
     # a group's dK / dV leave the kernels unrounded and are summed below
     head_dk, head_dv = ((dk_dtype, dv_dtype) if group == 1
                         else (jnp.float32, jnp.float32))
@@ -1041,40 +1229,59 @@ def _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, k_block,
     telemetry.gauge("flash.bwd.tiles_plain").set(plain)
     telemetry.gauge("flash.bwd.tiles_masked").set(masked)
     telemetry.gauge("flash.bwd.tiles_skipped").set(skipped)
+    # q, dO, k, v, dQ, dK and dV: those XLA transposes around the kernels
+    telemetry.gauge("flash.bwd.operands_relaid").set(
+        7 - 2 * q_in - 2 * k_in - 3 * v_in)
 
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     kernel_args = dict(lk=lk, causal=causal, scale=scale, window=window)
     if d_s:
         kernel_args["shared"] = True
+    if packed_out:
+        kernel_args["packed"] = d_k
     shared_in = (k_shared,) if d_s else ()
-    dq_shape = jax.ShapeDtypeStruct((b * h, lq_p, d), dq_dtype)
-    dkv_shape = (jax.ShapeDtypeStruct((b * h, n_k * bk, d_k), head_dk),
-                 jax.ShapeDtypeStruct((b * h, n_k * bk, dv), head_dv))
+    k_part, v_part = _kv_parts(packed)
+
+    def per_head(rows, width, dtype, stays):
+        return jax.ShapeDtypeStruct(
+            (b, rows, h * width) if stays else (b * h, rows, width), dtype)
+
+    dq_shape = per_head(lq_p, d, dq_dtype, q_in)
+    # dK and dV of a query head: two outputs, or one where they are packed
+    dkv_shape = ((per_head(n_k * bk, 2 * d_k, head_dk, True),) if packed_out
+                 else (per_head(n_k * bk, d_k, head_dk, dk_in),
+                       per_head(n_k * bk, dv, head_dv, dv_in)))
     dkv_scratch = [pltpu.VMEM((bk, d_k), jnp.float32),
                    pltpu.VMEM((bk, dv), jnp.float32)]
     # a head's part of the shared columns' gradient, summed over heads below
     dks_shape = (jax.ShapeDtypeStruct((b * h, n_k * bk, d_s), jnp.float32),) \
         if d_s else ()
     dks_scratch = [pltpu.VMEM((bk, d_s), jnp.float32)] if d_s else []
-    dks = None
     if one_pass:
-        q_all = pl.BlockSpec((1, lq_p, d), lambda bh, i: (bh, 0, 0))
-        do_all = pl.BlockSpec((1, lq_p, dv), lambda bh, i: (bh, 0, 0))
+        def all_rows(i):
+            return 0
+
+        def own(i):
+            return i
+
+        q_all = _head_spec(lq_p, d, h, q_in, all_rows)
+        do_all = _head_spec(lq_p, dv, h, v_in, all_rows)
         rows = pl.BlockSpec((1, n_q, bq), lambda bh, i: (bh, 0, 0))
-        k_spec = pl.BlockSpec((1, bk, d_k), lambda bh, i: (kv_row(bh), i, 0))
-        v_spec = pl.BlockSpec((1, bk, dv), lambda bh, i: (kv_row(bh), i, 0))
-        dk_spec = pl.BlockSpec((1, bk, d_k), lambda bh, i: (bh, i, 0))
-        dv_spec = pl.BlockSpec((1, bk, dv), lambda bh, i: (bh, i, 0))
+        k_spec = _head_spec(bk, d_k, h, k_in, own, group, k_part)
+        v_spec = _head_spec(bk, dv, h, v_in, own, group, v_part)
+        dkv_specs = ((_head_spec(bk, 2 * d_k, h, True, own),) if packed_out
+                     else (_head_spec(bk, d_k, h, dk_in, own),
+                           _head_spec(bk, dv, h, dv_in, own)))
         ks_in = [pl.BlockSpec((1, bk, d_s), lambda bh, i: (bh // h, i, 0))] \
             if d_s else []
         ks_out = (pl.BlockSpec((1, bk, d_s), lambda bh, i: (bh, i, 0)),) \
             if d_s else ()
-        dq, dk, dv_, *dks = named_pallas_call(
+        dq, *dkv = named_pallas_call(
             "flash_bwd_dkv",
             functools.partial(_flash_bwd_kernel, sub=bq, **kernel_args),
             grid=(b * h, n_k),
             in_specs=[smem, q_all, do_all, rows, rows, k_spec, v_spec] + ks_in,
-            out_specs=(q_all, dk_spec, dv_spec) + ks_out,
+            out_specs=(q_all,) + dkv_specs + ks_out,
             out_shape=(dq_shape,) + dkv_shape + dks_shape,
             scratch_shapes=[pltpu.VMEM((n_q, d, bq), jnp.float32)]
             + dkv_scratch + dks_scratch,
@@ -1108,32 +1315,28 @@ def _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, k_block,
             first = jnp.maximum(q_offset + i * bq - window + 1 - k_offset, 0) // bk
             return jnp.clip(j, jnp.minimum(first, n_k - 1), jnp.maximum(last, 0))
 
-        def spec(rows, width, index):
-            return pl.BlockSpec((1, rows, width), index)
+        def own(i, j):       # a grid row's own block, q's or K/V's
+            return i
 
-        def q_blk(bh, i, j):     # the q block a K/V block's grid step reads
-            return bh, q_of(i, j), 0
+        def q_side(width, stays, block):
+            return _head_spec(bq, width, h, stays, block)
 
-        def q_own(bh, i, j):     # a grid row's own block, q's or dK/dV's
-            return bh, i, 0
-
-        def kv_own(bh, i, j):
-            return kv_row(bh), i, 0
-
-        def kv_blk(bh, i, j):    # the K/V block a q block's grid step reads
-            return kv_row(bh), k_of(i, j), 0
+        def kv_side(width, stays, block, group=1, part=(1, 0)):
+            return _head_spec(bk, width, h, stays, block, group, part)
 
         rows = pl.BlockSpec((1, n_q, bq), lambda bh, i, j: (bh, 0, 0))
         ks_in = [pl.BlockSpec((1, bk, d_s), lambda bh, i, j: (bh // h, i, 0))] \
             if d_s else []
-        dk, dv_, *dks = named_pallas_call(
+        dkv = named_pallas_call(
             "flash_bwd_dkv",
             functools.partial(_flash_bwd_dkdv_kernel, **kernel_args),
             grid=(b * h, n_k, n_q),
-            in_specs=[smem, spec(bq, d, q_blk), spec(bq, dv, q_blk), rows, rows,
-                      spec(bk, d_k, kv_own), spec(bk, dv, kv_own)] + ks_in,
-            out_specs=(spec(bk, d_k, q_own), spec(bk, dv, q_own))
-            + ((spec(bk, d_s, q_own),) if d_s else ()),
+            in_specs=[smem, q_side(d, q_in, q_of), q_side(dv, v_in, q_of),
+                      rows, rows, kv_side(d_k, k_in, own, group, k_part),
+                      kv_side(dv, v_in, own, group, v_part)] + ks_in,
+            out_specs=((kv_side(2 * d_k, True, own),) if packed_out else
+                       (kv_side(d_k, dk_in, own), kv_side(dv, dv_in, own)))
+            + ((kv_side(d_s, False, own),) if d_s else ()),
             out_shape=dkv_shape + dks_shape,
             scratch_shapes=dkv_scratch + dks_scratch,
             interpret=interpret,
@@ -1142,28 +1345,38 @@ def _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, k_block,
         ks_in = [pl.BlockSpec(
             (1, bk, d_s), lambda bh, i, j: (bh // h, k_of(i, j), 0))] \
             if d_s else []
+        kernel_args.pop("packed", None)     # dQ's kernel writes no dK / dV
         dq = named_pallas_call(
             "flash_bwd_dq",
             functools.partial(_flash_bwd_dq_kernel, **kernel_args),
             grid=(b * h, n_q, n_k),
-            in_specs=[smem, spec(bq, d, q_own), spec(bq, dv, q_own), rows, rows,
-                      spec(bk, d_k, kv_blk), spec(bk, dv, kv_blk)] + ks_in,
-            out_specs=spec(bq, d, q_own),
+            in_specs=[smem, q_side(d, q_in, own), q_side(dv, v_in, own),
+                      rows, rows, kv_side(d_k, k_in, k_of, group, k_part),
+                      kv_side(dv, v_in, k_of, group, v_part)] + ks_in,
+            out_specs=q_side(d, q_in, own),
             out_shape=dq_shape,
             scratch_shapes=[pltpu.VMEM((1, d, bq), jnp.float32)],
             interpret=interpret,
         )(offs, qf, dof, lse, dd, kf, vf, *shared_in)
 
-    dq = dq[:, :lq, :].reshape(b, h, lq, d).transpose(0, 2, 1, 3)
+    dq = _heads_back(dq, b, h, lq, q_in)
 
-    def kv_heads(x, dtype):      # [B*H, Lk, D] -> [B, Lk, H_kv, D]
-        width = x.shape[-1]
+    def kv_heads(x, dtype, stays):   # a query head's rows -> [B, Lk, H_kv, D]
         if group == 1:
-            return x[:, :lk, :].reshape(b, h, lk, width).transpose(0, 2, 1, 3)
+            return _heads_back(x, b, h, lk, stays)
+        width = x.shape[-1]             # [B*H, Lk, D]: stays only at group 1
         x = x[:, :lk, :].reshape(b, h_kv, group, lk, width).sum(axis=2)
         return x.astype(dtype).transpose(0, 2, 1, 3)
 
-    grads = dq, kv_heads(dk, dk_dtype), kv_heads(dv_, dv_dtype)
+    n_dkv = 1 if packed_out else 2
+    dkv, dks = dkv[:n_dkv], dkv[n_dkv:]
+    if packed_out:
+        grads = dq, kv_heads(dkv[0], dk_dtype, True), None
+    else:
+        grads = (dq, kv_heads(dkv[0], dk_dtype, dk_in),
+                 kv_heads(dkv[1], dv_dtype, dv_in))
+        if one_array:
+            grads = dq, jnp.concatenate(grads[1:], axis=-1), None
     if d_s:
         grads += (dks[0][:, :lk, :].reshape(b, h, lk, d_s).sum(axis=1).astype(
             out_dtype or k_shared.dtype),)
@@ -1172,13 +1385,27 @@ def _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, k_block,
 
 def _flash_backward(q, k, v, o, lse, g, causal, q_block, k_block, interpret,
                     q_offset=0, k_offset=0, out_dtype=None, window=None,
-                    k_shared=None):
+                    k_shared=None, heads=None):
+    """(dq, dk, dv[, dks]) of one call from its forward's operands and
+    residuals, each in the form its operand was handed in
+    (:func:`_given_as_rows`; ``o`` and ``g`` as ``v``)."""
+    q_rows, k_rows, v_rows = _given_as_rows(q, k, v)
+    h, h_kv = heads or (q.shape[2], k.shape[2])
+    q, k, v = _as_heads(q, h), _as_heads(k, h_kv), _as_heads(v, h_kv)
+    o, g = _as_heads(o, h), _as_heads(g, h)
     bq, bk = _backward_blocks(q.shape[1], k.shape[1], q_block, k_block)
-    qf, dof, dd, bq, n_q = prepare_backward_q_side(q, o, g, bq)
-    return _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, bk,
-                              interpret, q.shape, q_offset=q_offset,
-                              k_offset=k_offset, out_dtype=out_dtype,
-                              window=window, k_shared=k_shared)
+    whole = q.shape[1] % bq == 0 and k.shape[1] % bk == 0
+    d_k = q.shape[3] - (0 if k_shared is None else k_shared.shape[-1])
+    stays = (_stays(q.shape[3], whole and q_rows), _stays(d_k, whole and k_rows),
+             _stays(g.shape[3], whole and v_rows))
+    qf, dof, dd, bq, n_q = prepare_backward_q_side(q, o, g, bq,
+                                                   (stays[0], stays[2]))
+    grads = _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, bk,
+                               interpret, q.shape, q_offset=q_offset,
+                               k_offset=k_offset, out_dtype=out_dtype,
+                               window=window, k_shared=k_shared, stays=stays)
+    return tuple(_as_given(x, rows) for x, rows in zip(
+        grads, (q_rows, k_rows, v_rows))) + grads[3:]
 
 
 def _flash_carry_kernel(off_ref, q_ref, k_ref, v_ref, acc_in_ref, m_in_ref,
@@ -1328,36 +1555,38 @@ def _use_interpret() -> bool:
         f"the default backend is {backend!r}")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash(q, k, v, k_shared, causal, q_block, k_block, window):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash(q, k, v, k_shared, causal, q_block, k_block, window, heads):
     out, _ = _flash_forward(q, k, v, causal, q_block, k_block, _use_interpret(),
-                            window, k_shared)
+                            window, k_shared, heads=heads)
     return out
 
 
-def _flash_fwd(q, k, v, k_shared, causal, q_block, k_block, window):
+def _flash_fwd(q, k, v, k_shared, causal, q_block, k_block, window, heads):
     # Named so that a caller's ``jax.checkpoint`` whose policy lists
     # ``KEPT_NAME`` keeps them and does not launch the forward kernel again
     # for its backward; the identity, lowered to nothing, anywhere else.
-    out, lse = checkpoint_name(
-        _flash_forward(q, k, v, causal, q_block, k_block, _use_interpret(),
-                       window, k_shared), KEPT_NAME)
+    out, lse = _flash_forward(
+        q, k, v, causal, q_block, k_block, _use_interpret(), window, k_shared,
+        kept=lambda x: checkpoint_name(x, KEPT_NAME), heads=heads)
     return out, (q, k, v, k_shared, out, lse)
 
 
-def _flash_bwd(causal, q_block, k_block, window, residuals, g):
+def _flash_bwd(causal, q_block, k_block, window, heads, residuals, g):
     q, k, v, k_shared, o, lse = residuals
     grads = _flash_backward(q, k, v, o, lse, g, causal, q_block, k_block,
-                            _use_interpret(), window=window, k_shared=k_shared)
+                            _use_interpret(), window=window, k_shared=k_shared,
+                            heads=heads)
     return grads if k_shared is not None else grads + (None,)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
+def flash_attention(q: jax.Array, k: jax.Array, v: Optional[jax.Array], *,
                     causal: bool = True, window: Optional[int] = None,
                     k_shared: Optional[jax.Array] = None,
+                    heads: Optional[tuple] = None,
                     q_block: Optional[int] = None,
                     k_block: Optional[int] = None) -> jax.Array:
     """Flash attention over [B, L, H, D] tensors (pallas forward and backward).
@@ -1368,7 +1597,29 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     share them (latent attention's one rotary key head); ``k`` then holds the
     leading ``D - Ds`` columns a head, nothing is repeated in memory, the
     score tile is the sum of two products, and the gradient with respect to
-    ``k_shared`` is the sum over the heads.
+    ``k_shared`` is the sum over the heads. ``v`` None: ``k`` is ``[B, Lk,
+    H_kv, D_k + D_v]``, a head's keys and then its values as ONE projection
+    wrote them (latent attention's ``kv_up``), and its gradient comes back
+    in the same form.
+
+    Where the operands lie. Any of q, k, v may be handed as ``[B, L, heads *
+    D]``, THREE dimensions: a projection's own rows, with ``heads = (H,
+    H_kv)`` to say how many heads they hold. The kernels then read head
+    ``n`` where it lies, column block ``n`` of those rows, through their
+    index maps, and write the operand's gradient the same way, wherever ``D``
+    is whole 128-lane tiles and no row of the call is padded to a block
+    (:func:`_stays`; the packed ``k`` too, at two equal widths); the result
+    comes back as rows, ``[B, Lq, H * D_v]``, where ``v`` was handed so (the
+    packed ``k`` where ``v`` is None). A ``[B, L, heads, D]`` operand, and
+    one of any other width (64, 192), is transposed to ``[B * heads, L, D]``
+    by XLA around the kernels as all were before PR 41;
+    ``flash.fwd.operands_relaid`` / ``flash.bwd.operands_relaid`` count those
+    (0 where every operand stays, 4 / 7 where none does). Hand as rows what
+    goes from a projection into this call, or from it into one, untouched;
+    what is turned in between (a rotary embedding, a norm over the head
+    width) is better left ``[B, L, heads, D]``: XLA computes it in the
+    transposed layout for nothing, and copies float32 arrays across to get
+    from one layout to the other.
 
     ``window=W`` (causal only): the query at ``i`` sees the keys ``i - W < j
     <= i``, itself and the ``W - 1`` before it. Tiles wholly below the band
@@ -1388,10 +1639,18 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     from autodist_tpu.parallel.mesh import per_device
     if window is not None and (not causal or window < 1):
         raise ValueError(f"window={window!r} needs causal=True and window >= 1")
-    _kv_group(q, k)
-    _shared_cols(q, k, k_shared)
-    operands = (q, k, v) if k_shared is None else (q, k, v, k_shared)
-    return per_device(
-        lambda q, k, v, ks=None: _flash(q, k, v, ks, causal, q_block, k_block,
-                                        window),
-        operands, batched=(True,) * len(operands))
+    if heads is None and any(_given_as_rows(q, k, v)):
+        raise ValueError("heads=(H, H_kv) must say how many heads an operand "
+                         "of three dimensions holds")
+    h, h_kv = heads or (q.shape[2], k.shape[2])
+    _kv_group(_as_heads(q, h), _as_heads(k, h_kv))
+    _shared_cols(_as_heads(q, h), _as_heads(k, h_kv), k_shared, packed=v is None)
+    given = [x is not None for x in (q, k, v, k_shared)]
+
+    def call(*operands):
+        operands = iter(operands)
+        q, k, v, ks = (next(operands) if there else None for there in given)
+        return _flash(q, k, v, ks, causal, q_block, k_block, window, heads)
+
+    return per_device(call, [x for x in (q, k, v, k_shared) if x is not None],
+                      batched=(True,) * sum(given))

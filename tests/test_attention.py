@@ -296,6 +296,172 @@ def test_flash_backward_byte_limit_switches_the_path(monkeypatch):
         _close(a, b, atol=2e-5, rtol=1e-5, mxu=0.05, err_msg=f"d{name}")
 
 
+# name -> (L, heads, D, q_block, k_block, dQ byte limit, operands XLA relays
+# around the forward / the backward) of a call whose q, k, v are handed as the
+# projections' rows, [B, L, H * D]: an operand whose heads are whole lane
+# tiles wide is read and written where it lies, through the index maps;
+# head_dim 64 and a call that pads its rows to a block keep the transposes to
+# [B * H, L, D] that every call had (4 / 7).
+_IN_PLACE_CASES = {
+    "128-wide": (256, 3, 128, 128, 128, None, (0, 0)),
+    "128-wide-split": (256, 3, 128, 128, 128, 0, (0, 0)),
+    "128-wide-blocks-from-the-shape": (1024, 2, 128, None, None, None, (0, 0)),
+    "256-wide": (128, 2, 256, 64, 64, None, (0, 0)),
+    "64-wide": (256, 3, 64, 128, 128, None, (4, 7)),
+    "128-wide-ragged": (300, 2, 128, 128, 128, None, (4, 7)),
+}
+
+
+@pytest.mark.parametrize("case", list(_IN_PLACE_CASES))
+def test_flash_operands_stay_where_the_projections_put_them(case, monkeypatch):
+    """Forward and all gradients of a call on rows against plain float32
+    attention, the count of relaid operands, and what the same call gives
+    on [B, L, H, D] operands, every one transposed around the kernels (the
+    layout of every call before PR 41): the tiles and their arithmetic are
+    the same, so the result is, bit for bit; the gradients differ by the
+    order of one float32 sum (the row term D, which the in-place path takes
+    as a product with the heads' 0 / 1 columns)."""
+    import importlib
+    from autodist_tpu import telemetry
+    fa = importlib.import_module("autodist_tpu.ops.flash_attention")
+
+    length, heads, d, q_block, k_block, dq_bytes, relaid = _IN_PLACE_CASES[case]
+    if dq_bytes is not None:
+        monkeypatch.setattr(fa, "_RESIDENT_DQ_BYTES", dq_bytes)
+    rng = np.random.RandomState(41)
+    q, k, v, weights = (_randn(rng, 2, length, heads, d) for _ in range(4))
+    rows = lambda x: x.reshape(2, length, heads * d)  # noqa: E731
+
+    def run(attend, *operands):
+        loss = lambda *a: jnp.sum(attend(*a) * weights.reshape(  # noqa: E731
+            operands[0].shape))
+        return (attend(*operands),) + jax.grad(loss, argnums=(0, 1, 2))(*operands)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, q_block=q_block, k_block=k_block,
+                               heads=(heads, heads))
+
+    def gauges():
+        return (telemetry.gauge("flash.fwd.operands_relaid").value,
+                telemetry.gauge("flash.bwd.operands_relaid").value)
+
+    got = run(flash, rows(q), rows(k), rows(v))
+    assert gauges() == relaid
+    assert all(x.shape == (2, length, heads * d) for x in got)
+    got = [x.reshape(q.shape) for x in got]
+    for a, b, name in zip(got, run(_reference, q, k, v), ("o", "dq", "dk", "dv")):
+        _close(a, b, atol=3e-4, mxu=0.05, err_msg=name)
+    relaid_all = run(flash, q, k, v)
+    assert gauges() == (4, 7)
+    np.testing.assert_array_equal(got[0], relaid_all[0], err_msg="o")
+    for a, b, name in zip(got[1:], relaid_all[1:], ("dq", "dk", "dv")):
+        _close(a, b, atol=2e-5, rtol=1e-5, mxu=0.05, err_msg=name)
+    # one operand a call: v as rows (and with it the result), q and k not
+    mixed = flash(q, k, rows(v))
+    assert mixed.shape == (2, length, heads * d)
+    assert telemetry.gauge("flash.fwd.operands_relaid").value == \
+        (2 if relaid == (0, 0) else 4)
+    np.testing.assert_array_equal(mixed.reshape(q.shape), got[0])
+    with pytest.raises(ValueError, match="heads"):
+        flash_attention(rows(q), rows(k), rows(v))
+
+
+def _transposes_around_the_kernels(lowered, least: int) -> int:
+    """Of the operands and results of every ``tpu_custom_call`` in a lowered
+    module that hold ``least`` elements or more, how many come straight from
+    (or go straight into) a ``stablehlo.transpose``, reshapes and converts
+    looked through."""
+    def walk(op):
+        yield op
+        for region in op.regions:
+            for block in region:
+                for inner in block:
+                    yield from walk(inner.operation)
+
+    def big(value):
+        return int(np.prod(value.type.shape)) >= least
+
+    through = ("stablehlo.reshape", "stablehlo.convert")
+
+    def source(value):
+        owner = value.owner
+        while getattr(owner, "name", None) in through:
+            owner = owner.operands[0].owner
+        return getattr(owner, "name", None)         # None: a block argument
+
+    def sinks(value):
+        for use in value.uses:
+            user = use.owner
+            if user.name in through:
+                yield from sinks(user.results[0])
+            else:
+                yield user.name
+
+    module = lowered.compiler_ir("stablehlo")
+    calls = [op for op in walk(module.operation)
+             if op.name == "stablehlo.custom_call"
+             and "tpu_custom_call" in str(op.attributes["call_target_name"])]
+    assert len(calls) == 2                      # flash_fwd, flash_bwd_dkv
+    return sum(source(v) == "stablehlo.transpose"
+               for call in calls for v in call.operands if big(v)) + sum(
+        "stablehlo.transpose" in set(sinks(r))
+        for call in calls for r in call.results if big(r))
+
+
+# a cell's call -> (B, L, H, H_kv, D_qk, D_v, shared key columns, window,
+# which of q, k, v its model hands as rows), and how many of the kernels' big
+# operands XLA transposes around them
+@pytest.mark.parametrize("shape,relaid", [
+    ((8, 1024, 16, 16, 64, 64, 0, None, ""), 11),      # gpt2m-*: the parent's 4 + 7
+    ((4, 4096, 16, 16, 128, 128, 0, None, "v"), 6),    # olmoe's shape, v rows: q, k | q, k, dQ, dK
+    # trinity-pretrain-8k, sliding: a group's dK passes through its sum first
+    ((1, 8192, 32, 4, 128, 128, 0, 2048, "v"), (5, 6)),
+    ((1, 8192, 32, 2, 128, 128, 0, None, "qkv"), 0),   # nemotron-pretrain-8k
+    ((4, 4096, 16, 16, 128, 128, 0, None, "qkv"), 0),  # olmoe's shape, all rows
+    ((1, 16384, 32, 32, 192, 128, 64, None, "kv"), 3),  # kanana-pretrain-16k: q | q, dQ
+], ids=["gpt2m", "olmoe-v-rows", "trinity", "nemotron", "olmoe-all-rows", "kanana"])
+def test_no_transpose_around_the_kernels_of_operands_that_stay(shape, relaid,
+                                                                monkeypatch):
+    """Forward and backward lowered FOR THE TPU (no chip: Mosaic's lowering
+    checks the block shapes) at the cells' own shapes, the operands handed
+    as Trinity's, Nemotron's and Kanana's models hand them (and OLMoE's
+    shape both ways; its model hands [B, L, H, D]): no ``stablehlo.transpose``
+    feeds or follows a kernel on an operand handed as rows of whole lane
+    tiles a head, and the count agrees with the ``operands_relaid`` gauges
+    everywhere. Kanana's call takes its keys and values packed, as ``kv_up``
+    writes them."""
+    import importlib
+    from autodist_tpu import telemetry
+    fa = importlib.import_module("autodist_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "_use_interpret", lambda: False)
+    b, length, h, h_kv, d, d_v, d_s, window, as_rows = shape
+
+    def struct(name, n, width):
+        return jax.ShapeDtypeStruct(
+            (b, length, n * width) if name in as_rows else (b, length, n, width),
+            jnp.bfloat16)
+
+    q = struct("q", h, d)
+    if d_s:
+        operands = (q, struct("kv", h_kv, d - d_s + d_v),
+                    jax.ShapeDtypeStruct((b, length, d_s), jnp.bfloat16))
+        attend = lambda q, kv, ks: flash_attention(  # noqa: E731
+            q, kv, None, k_shared=ks, heads=(h, h_kv))
+    else:
+        operands = (q, struct("k", h_kv, d), struct("v", h_kv, d_v))
+        attend = lambda q, k, v: flash_attention(  # noqa: E731
+            q, k, v, window=window, heads=(h, h_kv))
+    lowered = jax.jit(jax.grad(
+        lambda *a: attend(*a).astype(jnp.float32).sum(),
+        argnums=tuple(range(len(operands))))).trace(*operands).lower(
+            lowering_platforms=("tpu",))
+    transposes, relaid = relaid if isinstance(relaid, tuple) else (relaid,) * 2
+    assert _transposes_around_the_kernels(lowered, b * length * h_kv * 64) \
+        == transposes
+    assert (telemetry.gauge("flash.fwd.operands_relaid").value
+            + telemetry.gauge("flash.bwd.operands_relaid").value) == relaid
+
+
 def test_kernel_names_unchanged():
     from autodist_tpu.ops import named_call
     assert named_call.KERNEL_NAMES == (

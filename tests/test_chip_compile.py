@@ -378,6 +378,48 @@ def test_flash_at_two_widths_compiles_at_the_kanana_cell_shape(chip, shared):
     assert "flash_bwd_dq" not in text
 
 
+@pytest.mark.parametrize("cell,b,length,h,h_kv,d,window,rows,operand", [
+    ("kanana", 1, 16384, 32, 32, 192, None, "kv", "bf16[1,16384,8192]"),
+    ("nemotron", 1, 8192, 32, 2, 128, None, "qkv", "bf16[1,8192,4096]"),
+    ("olmoe", 4, 4096, 16, 16, 128, None, "v", "bf16[4,4096,2048]"),
+    ("trinity-sliding", 1, 8192, 32, 4, 128, 2048, "v", "bf16[1,8192,512]"),
+])
+def test_flash_on_rows_compiles_at_the_cells_shapes(chip, cell, b, length, h,
+                                                    h_kv, d, window, rows,
+                                                    operand):
+    """Kanana's, Nemotron's and Trinity's calls with the operands their
+    models hand as a projection's rows, ``[B, L, heads * D]`` (and OLMoE's
+    shape with v so, which its model does not: it measured nothing there,
+    PERF.md §6 "PR 41"): the kernels take those rows
+    themselves (the custom calls' operand is the array as handed, a column
+    block a head; Kanana's is ``kv_up``'s whole output, keys at block ``2 *
+    head`` and values at ``2 * head + 1``, and d(kv) comes back as one
+    array), forward and backward in one pass."""
+    def struct(name, n, width):
+        return ((b, length, n * width) if name in rows else (b, length, n, width),
+                jnp.bfloat16)
+
+    if cell == "kanana":
+        shapes = [struct("q", h, d), struct("kv", h_kv, 256),
+                  ((b, length, 64), jnp.bfloat16)]
+
+        def loss(q, kv, ks):
+            return fa.flash_attention(q, kv, None, k_shared=ks, heads=(h, h_kv)
+                                      ).astype(jnp.float32).sum()
+    else:
+        shapes = [struct("q", h, d), struct("k", h_kv, d), struct("v", h_kv, d)]
+
+        def loss(q, k, v):
+            return fa.flash_attention(q, k, v, window=window, heads=(h, h_kv)
+                                      ).astype(jnp.float32).sum()
+
+    text = _compiled_text(
+        jax.value_and_grad(loss, argnums=tuple(range(len(shapes)))), chip, *shapes)
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 2 and "flash_bwd_dq" not in text
+    assert all(operand in line.split("custom-call(")[1] for line in calls)
+
+
 def _kernel_launches(text: str, kernel: str) -> int:
     return sum("custom-call(" in line and kernel in line
                for line in text.splitlines())

@@ -39,17 +39,23 @@ def _operands(length, heads, d_qk, d_v, d_s, dtype=jnp.float32, batch=2):
     return q, k, v, k_shared, g
 
 
+# the last column: operands XLA relays around the forward / the backward
+# where k and v are handed as rows (q in [B, L, H, D] always). A head's 128
+# key columns, v, o, dO, dK and dV then stay where they lie; q and dQ at 192
+# do not (odd heads start half a lane tile in), nor an assembled 192-wide k,
+# nor anything 96 or 64 wide or padded to a block.
 @pytest.mark.parametrize("split", [False, True], ids=["one-pass", "split"])
-@pytest.mark.parametrize("length,heads,d_qk,d_v,d_s", [
-    (384, 4, 192, 128, 64),     # latent attention's widths, the rotary key shared
-    (384, 2, 192, 128, 0),      # the same with k assembled by the caller
-    (300, 3, 96, 64, 32),       # a ragged tail of keys and queries
-    (384, 2, 96, 64, 0),
-    (256, 32, 192, 128, 64),    # the shared key's gradient summed over 32 heads
+@pytest.mark.parametrize("length,heads,d_qk,d_v,d_s,relaid", [
+    (384, 4, 192, 128, 64, (1, 2)),  # latent attention's widths, the rotary key shared
+    (384, 2, 192, 128, 0, (2, 4)),   # the same with k assembled by the caller
+    (300, 3, 96, 64, 32, (4, 7)),    # a ragged tail of keys and queries
+    (384, 2, 96, 64, 0, (4, 7)),
+    (256, 32, 192, 128, 64, (1, 2)),  # the shared key's gradient summed over 32 heads
+    (300, 2, 192, 128, 64, (4, 7)),  # latent attention's widths, rows padded
 ], ids=["192-128-shared", "192-128", "96-64-shared-ragged", "96-64",
-        "32-heads-shared"])
+        "32-heads-shared", "192-128-shared-ragged"])
 def test_two_widths_forward_and_backward_match_dot_attention(
-        monkeypatch, length, heads, d_qk, d_v, d_s, split):
+        monkeypatch, length, heads, d_qk, d_v, d_s, relaid, split):
     if split:
         monkeypatch.setattr(fa, "_RESIDENT_DQ_BYTES", 1)
     q, k, v, k_shared, g = _operands(length, heads, d_qk, d_v, d_s,
@@ -78,6 +84,82 @@ def test_two_widths_forward_and_backward_match_dot_attention(
             gauges["flash.shared_key_cols"], gauges["flash.kv_group"]) \
         == (d_qk, d_v, d_s, 1)
     assert gauges["flash.bwd.passes"] == (2 if split else 1)
+    assert (gauges["flash.fwd.operands_relaid"],
+            gauges["flash.bwd.operands_relaid"]) == (4, 7)
+    # the same call with k and v handed as rows, the result rows
+    rows = lambda x: x.reshape(x.shape[0], length, -1)  # noqa: E731
+
+    def flash_rows(*a):
+        return fa.flash_attention(a[0], rows(a[1]), rows(a[2]), q_block=128,
+                                  k_block=128, heads=(heads, heads),
+                                  k_shared=a[3] if d_s else None)
+
+    out_rows = flash_rows(*args)
+    np.testing.assert_array_equal(out_rows, rows(out))
+    got_rows = jax.grad(lambda *a: jnp.sum(flash_rows(*a) * rows(g)),
+                        argnums=wrt)(*args)
+    for a, b in zip(got_rows, got):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=2e-5)
+    gauges = telemetry.snapshot()
+    assert (gauges["flash.fwd.operands_relaid"],
+            gauges["flash.bwd.operands_relaid"]) == relaid
+
+
+@pytest.mark.parametrize("split", [False, True], ids=["one-pass", "split"])
+@pytest.mark.parametrize("length,heads,kv_heads,d_qk,d_v,d_s,relaid", [
+    (256, 4, 4, 192, 128, 64, (1, 2)),   # kv_up's output: read where it lies
+    (256, 6, 2, 128, 128, 0, (1, 2)),    # grouped heads over a packed pair
+    (256, 2, 2, 96, 64, 32, (4, 7)),     # widths that are cut apart by XLA
+    (300, 2, 2, 192, 128, 64, (4, 7)),   # rows padded: cut apart as well
+], ids=["128+128-shared", "128+128-grouped", "64+64-shared", "128+128-ragged"])
+def test_values_packed_behind_the_keys_are_the_two_operands(
+        monkeypatch, length, heads, kv_heads, d_qk, d_v, d_s, relaid, split):
+    """``flash_attention(q, kv, None)`` with ``kv = [k | v]`` a head, handed
+    as one projection's rows, is ``flash_attention(q, k, v)``: the result bit
+    for bit, every gradient to the order of one float32 sum, d(kv) back in
+    one array; at two equal widths of whole lane tiles the kernels read
+    both parts out of the one array (no operand of theirs relaid) and, but
+    for a group's, write dK and dV into one."""
+    if split:
+        monkeypatch.setattr(fa, "_RESIDENT_DQ_BYTES", 1)
+    keys = jax.random.split(jax.random.PRNGKey(length + heads), 4)
+    d_k = d_qk - d_s
+    q = jax.random.normal(keys[0], (2, length, heads, d_qk))
+    kv = jax.random.normal(keys[1], (2, length, kv_heads, d_k + d_v))
+    k_shared = jax.random.normal(keys[2], (2, length, d_s)) if d_s else None
+    g = jax.random.normal(keys[3], (2, length, heads, d_v))
+
+    def packed(q, kv, ks):     # kv as one projection's rows, the result rows
+        return fa.flash_attention(
+            q, kv.reshape(2, length, -1), None, k_shared=ks, q_block=128,
+            k_block=128, heads=(heads, kv_heads)).reshape(2, length, heads, d_v)
+
+    def apart(q, kv, ks):
+        return fa.flash_attention(q, kv[..., :d_k], kv[..., d_k:], k_shared=ks,
+                                  q_block=128, k_block=128)
+
+    def run(attend):
+        wrt = (0, 1, 2) if d_s else (0, 1)
+        return (attend(q, kv, k_shared),) + jax.grad(
+            lambda *a: jnp.sum(attend(*a) * g), argnums=wrt)(q, kv, k_shared)
+
+    telemetry.registry().clear()
+    got = run(packed)
+    gauges = telemetry.snapshot()
+    assert (gauges["flash.fwd.operands_relaid"],
+            gauges["flash.bwd.operands_relaid"]) == relaid
+    assert got[2].shape == kv.shape
+    want = run(apart)
+    np.testing.assert_array_equal(got[0], want[0])
+    for a, b in zip(got[1:], want[1:]):     # the order of D's float32 sum apart
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=2e-5)
+    want = jax.grad(lambda q, kv, ks: jnp.sum(_dot_attention(
+        q, jnp.repeat(kv[..., :d_k], heads // kv_heads, axis=2),
+        jnp.repeat(kv[..., d_k:], heads // kv_heads, axis=2), ks) * g),
+        argnums=1)(q, kv, k_shared)
+    np.testing.assert_allclose(got[2], want, rtol=1e-4, atol=1e-4)
+    with pytest.raises(ValueError, match="packed"):
+        fa.flash_attention(q, kv[..., :d_k], None, k_shared=k_shared)
 
 
 def test_the_shared_key_in_bfloat16_is_the_assembled_key():
@@ -155,10 +237,14 @@ def test_the_older_cells_calls_pick_what_they_picked(call, blocks, tiles, group,
     assert fa._forward_blocks(length, length, d, 2, None, None) == blocks
     assert fa._backward_blocks(length, length, None, None) == (512, 512)
     gauges = _traced_gauges(batch, length, heads, kv_heads, d, window=window)
+    # (PR 41) operands handed as [B, L, heads, D] go through XLA's transposes
+    # as every operand did, whatever their width
+    relaid = (4, 7)
     assert gauges == {
         "bwd.passes": 1, "kv_group": group, "window": window or 0,
         "d_qk": d, "d_v": d, "shared_key_cols": 0,
         "fwd.tiles_overlapped": overlapped,
+        "fwd.operands_relaid": relaid[0], "bwd.operands_relaid": relaid[1],
         **{f"{side}.tiles_{kind}": n for side in ("fwd", "bwd")
            for kind, n in zip(("plain", "masked", "skipped"), tiles)}}
 
@@ -187,6 +273,7 @@ def test_latent_attentions_call_keeps_its_keys_and_its_schedule_follows_the_widt
     assert (gauges["d_qk"], gauges["d_v"], gauges["shared_key_cols"]) == (192, 128, 64)
     assert gauges["bwd.passes"] == 1
     assert (gauges["fwd.tiles_plain"], gauges["fwd.tiles_masked"]) == (496, 32)
+    assert (gauges["fwd.operands_relaid"], gauges["bwd.operands_relaid"]) == (4, 7)
     assert _traced_gauges(1, 16896, 4, 4, 192, 128, 64)["bwd.passes"] == 2
     assert _traced_gauges(1, 24576, 4, 4, 128)["bwd.passes"] == 1
     assert _traced_gauges(1, 25088, 4, 4, 128)["bwd.passes"] == 2

@@ -7,6 +7,7 @@ split), and the tile counts and gauges against counts made by hand. Tiny
 sizes on the CPU; kernels in interpret mode."""
 
 import importlib
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -81,6 +82,38 @@ def test_window_and_grouped_heads_match_dot_attention(monkeypatch, window, group
     for name, got, want in zip("qkv", grads, ref_grads):
         assert got.shape == want.shape, name
         np.testing.assert_allclose(got, want, atol=2e-4, err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("schedule,length,q_block,k_block,dq_bytes", [
+    ("resident", 1024, None, None, None), ("streamed", 512, 128, 128, None),
+    ("split", 512, 128, 128, 0)], ids=lambda x: x if isinstance(x, str) else "")
+@pytest.mark.parametrize("window", [None, 200], ids=["causal", "window-200"])
+def test_grouped_heads_of_whole_lane_tiles_are_read_in_place(
+        monkeypatch, window, schedule, length, q_block, k_block, dq_bytes):
+    """Heads 128 wide handed as rows, no row padded: q, k, v, o and dQ stay
+    ``[B, L, heads * 128]`` (``operands_relaid`` 0 / 0), a query head finds
+    its KV head's columns through the index map, and a group's dK / dV,
+    summed over the group, come back as rows too."""
+    if dq_bytes is not None:
+        monkeypatch.setattr(fa, "_RESIDENT_DQ_BYTES", dq_bytes)
+    monkeypatch.setattr(sys.modules[__name__], "DEPTH", 128)
+    operands = _inputs(length, 6, 2)
+    rows = [x.reshape(1, length, -1) for x in operands]
+
+    def run(attend, q, k, v, w):
+        loss = lambda q, k, v: jnp.sum(attend(q, k, v) * w)  # noqa: E731
+        return (attend(q, k, v),) + jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    got = run(lambda q, k, v: fa.flash_attention(
+        q, k, v, causal=True, window=window, q_block=q_block, k_block=k_block,
+        heads=(6, 2)), *rows)
+    assert (telemetry.gauge("flash.fwd.operands_relaid").value,
+            telemetry.gauge("flash.bwd.operands_relaid").value) == (0, 0)
+    assert telemetry.gauge("flash.kv_group").value == 3
+    want = run(lambda q, k, v: band_attention(q, k, v, window), *operands)
+    for name, a, b in zip(("o", "dq", "dk", "dv"), got, want):
+        assert a.shape == (1, length, b.shape[2] * 128), name
+        np.testing.assert_allclose(a.reshape(b.shape), b, atol=2e-4, err_msg=name)
 
 
 def test_a_window_of_one_key_returns_v():

@@ -302,6 +302,37 @@ def test_kernel_call_sites_are_counted_when_traced_in_interpret_mode():
     assert _value("jit.kernel_call_sites") == 3
 
 
+@pytest.mark.parametrize("head_dim", [64, 128, 192],
+                         ids=["relaid", "in-place", "latent-packed"])
+def test_one_traced_pair_books_one_site_a_kernel_whatever_the_layout(head_dim):
+    """The layout of a flash call's operands is chosen from their shapes and
+    nothing is traced to choose it: a forward + backward pair books one
+    ``flash_fwd`` and one ``flash_bwd_dkv`` site where the heads go through
+    XLA's transposes (64 wide), where they stay in place (128) and at
+    latent attention's widths with the values packed behind the keys."""
+    from autodist_tpu.ops import flash_attention
+    struct = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.bfloat16)  # noqa: E731
+    if head_dim == 192:     # kv as one projection's rows
+        operands = (struct(1, 256, 2, 192), struct(1, 256, 2 * 256),
+                    struct(1, 256, 64))
+        attend = lambda q, kv, ks: flash_attention(  # noqa: E731
+            q, kv, None, k_shared=ks, heads=(2, 2))
+    elif head_dim == 128:   # q, k, v as their projections' rows
+        operands = (struct(1, 256, 2 * 128),) * 3
+        attend = lambda q, k, v: flash_attention(q, k, v, heads=(2, 2))  # noqa: E731
+    else:
+        operands = (struct(1, 256, 2, head_dim),) * 3
+        attend = flash_attention
+    jax.jit(jax.grad(lambda *a: attend(*a).astype(jnp.float32).sum(),
+                     argnums=(0, 1, 2))).trace(*operands)
+    assert _value("jit.kernel_call_sites.flash_fwd") == 1
+    assert _value("jit.kernel_call_sites.flash_bwd_dkv") == 1
+    assert _value("jit.kernel_call_sites") == 2
+    assert (_value("flash.fwd.operands_relaid"),
+            _value("flash.bwd.operands_relaid")) == {
+                64: (4, 7), 128: (0, 0), 192: (1, 2)}[head_dim]
+
+
 # ------------------------------------------------------- the three old copies
 
 def test_setup_counters_keep_their_names_and_gain_self_seconds():

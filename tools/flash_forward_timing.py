@@ -50,6 +50,23 @@ of the straight-line blocks the forward's walk takes its plain tiles in (0:
 none, every tile a chain of its own), ``--group-q-chunks N`` the pieces of
 the queries a tile is cut into inside one.
 
+Since PR 41 the ``mla-*``, ``trinity-*``, ``nemotron`` and ``olmoe`` shapes
+(and their ``bwd-``) print ``device_ms_all`` beside the kernel's own time:
+every device operation of a call, so what XLA puts around the kernels
+(transposes, slices, the sums over a group) is read stand-alone, with the
+gauges ``flash.fwd.operands_relaid`` / ``flash.bwd.operands_relaid``; and
+they hand the call its operands as the cell's model does (``ROWS``): the
+operands that go from a projection into the call untouched as ``[B, L,
+heads * D]`` rows, which a checkout that takes rows reads in place, the
+others ``[B, L, heads, D]`` (a checkout older than PR 41 is handed the
+reshape, as its models did). ``mla-packed`` / ``bwd-mla-packed``: the
+``shared`` form with ``k_nope`` and ``v`` one ``[k_nope | v]`` array, as the
+layer's ``kv_up`` product hands them and takes their gradient (an older
+checkout cuts them apart inside the call and puts the gradients together).
+What the stand-alone call cannot show is what XLA does to the producers
+and consumers of these arrays in a cell (PR 41: most of Kanana's gain was
+there); compile the layer for a described v5e and read the HLO for that.
+
 ``--blocks bq,bk,sub`` overrides the forward's choice, ``--bwd-blocks bq,bk``
 the backward's, ``--xent-blocks bn,bv`` the named head kernel's (a checkout
 whose kernels run a fixed default takes no override; tiles the compiler
@@ -106,18 +123,27 @@ SHAPES = {
     "lfm2": (2, 8192, 32, 64, True, "fwd"),
     # past what stays resident (PR 39: 4 MiB of K a head): 8 MiB, streamed
     "l32768-d128": (1, 32768, 8, 128, True, "fwd"),
+    "olmoe": (4, 4096, 16, 128, True, "fwd"),         # olmoe-pretrain-4k's call
+    "bwd-nemotron": (1, 8192, 32, 128, True, "bwd"),
 }
 # latent attention's call: name -> form
 MLA_V, MLA_SHARED = 128, 64
 MLA = {f"{kind}mla-{form}": form for kind in ("", "bwd-")
-       for form in ("padded", "assembled", "shared")}
+       for form in ("padded", "assembled", "shared", "packed")}
 SHAPES.update({name: (1, 16384, 32, 192, True,
                       "bwd" if name.startswith("bwd-") else "fwd")
                for name in MLA})
 # name -> (window, KV heads) where they are not (None, H)
 BANDS = {name: (2048 if "-win" in name else None, 32 if name.endswith("-rep") else 4)
          for name in SHAPES if "trinity" in name}
-BANDS.update({"nemotron": (None, 2), "lfm2": (None, 8)})
+BANDS.update({"nemotron": (None, 2), "bwd-nemotron": (None, 2),
+              "lfm2": (None, 8)})
+# the calls whose whole device time is printed beside the kernels' own
+AROUND = {*MLA, *BANDS, "olmoe", "bwd-olmoe"} - {"lfm2"}
+# which of q, k, v the cell's model hands as a projection's rows (PR 41):
+# v where q and k are turned or normed a head first, all three in Nemotron
+ROWS = {**{name: "v" for name in AROUND - set(MLA)},
+        "nemotron": "qkv", "bwd-nemotron": "qkv"}
 # the fused head, bfloat16 rows against a float32 table: name -> (N, D, V,
 # table layout, kind), at olmoe-pretrain-4k's call, at gpt2m-*'s, a chip and
 # call, and at trinity-pretrain-8k's. ``xent-dh`` and ``xent-dw`` both run
@@ -175,6 +201,12 @@ def all_ops_ms(trace_dir: str) -> float:
     return sum(e.duration_ns * 1e-6 for e in device_events(trace_dir))
 
 
+def takes_rows(fa) -> bool:
+    """Whether this checkout's kernels take ``[B, L, heads * D]`` rows."""
+    import inspect
+    return "heads" in inspect.signature(fa._flash_forward).parameters
+
+
 def build_mla(fa, name):
     """Latent attention's call in one of its three forms, from the operands
     the layer has (q, a head's 128 key columns, the 64 all heads share, v)
@@ -191,8 +223,20 @@ def build_mla(fa, name):
     v = jax.random.normal(keys[3], (b, length, h, MLA_V), jnp.bfloat16)
     g = jax.random.normal(keys[4], (b, length, h, MLA_V), jnp.bfloat16)
 
+    packed = form == "packed"
+    rows_taken = takes_rows(fa)
+    if packed:      # kv_up's output: a head's 128 key columns, then its values
+        k_nope = jnp.concatenate([k_nope, v], axis=-1).reshape(b, length, -1)
+        v = None
+        g = g.reshape(b, length, -1)
+
     def operands(k_nope, k_rope, v):
-        if form == "shared":
+        if packed and rows_taken:
+            return k_nope, None, {"k_shared": k_rope, "heads": (h, h)}
+        if packed:
+            k_nope = k_nope.reshape(b, length, h, -1)
+            k_nope, v = k_nope[..., :d - MLA_SHARED], k_nope[..., d - MLA_SHARED:]
+        if form in ("shared", "packed"):
             return k_nope, v, {"k_shared": k_rope}
         k = jnp.concatenate([k_nope, jnp.broadcast_to(
             k_rope[:, :, None, :], (b, length, h, MLA_SHARED))], axis=-1)
@@ -205,8 +249,13 @@ def build_mla(fa, name):
         out, lse = fa._flash_forward(q, k, v, causal, None, None, False, **shared)
         return out, lse
 
+    def forward(q, k_nope, k_rope, v, forward=forward):
+        out, lse = forward(q, k_nope, k_rope, v)
+        return (out.reshape(b, length, -1) if packed else out), lse
+
     if kind == "fwd":
-        return (jax.jit(lambda *a: forward(*a)[0][..., :MLA_V]),
+        return (jax.jit(lambda *a: forward(*a)[0][..., :h * MLA_V if packed
+                                                  else MLA_V]),
                 (q, k_nope, k_rope, v))
     out, lse = jax.jit(forward)(q, k_nope, k_rope, v)
 
@@ -214,8 +263,14 @@ def build_mla(fa, name):
         k, v, shared = operands(k_nope, k_rope, v)
         if form == "padded":
             g = jnp.pad(g, ((0, 0),) * 3 + ((0, d - MLA_V),))
+        if packed and not rows_taken:
+            out, g = (x.reshape(b, length, h, -1) for x in (out, g))
         dq, dk, dv, *dks = fa._flash_backward(q, k, v, out, lse, g, causal,
                                               None, None, False, **shared)
+        if packed:
+            if not rows_taken:
+                dk = jnp.concatenate([dk, dv], axis=-1).reshape(b, length, -1)
+            return dq, dk, dks[0]
         if form == "shared":
             return dq, dk, dks[0], dv
         split = d - MLA_SHARED
@@ -224,6 +279,37 @@ def build_mla(fa, name):
                 dv[..., :MLA_V])
 
     return jax.jit(backward), (q, k_nope, k_rope, v, out, lse, g)
+
+
+def _on_heads(fa, b, length, heads, rows):
+    """``fa``'s forward and backward for a checkout that takes no rows: the
+    operands named in ``rows`` (and with ``v`` the result, its gradient) are
+    reshaped to ``[B, L, heads, D]`` on the way in and back on the way out,
+    inside the timed call."""
+    import types
+
+    def to_heads(x, n):
+        return x.reshape(b, length, n, -1) if x.ndim == 3 else x
+
+    def to_rows(x, c):
+        return x.reshape(b, length, -1) if c in rows else x
+
+    def forward(q, k, v, *a, **kw):
+        out, lse = fa._flash_forward(
+            *(to_heads(x, n) for x, n in zip((q, k, v), heads)), *a, **kw)
+        return to_rows(out, "v"), lse
+
+    def backward(q, k, v, o, lse, g, *a, **kw):
+        grads = fa._flash_backward(
+            *(to_heads(x, n) for x, n in zip((q, k, v), heads)),
+            to_heads(o, heads[0]), lse, to_heads(g, heads[0]), *a, **kw)
+        return tuple(to_rows(x, c) for x, c in zip(grads, "qkv"))
+
+    return types.SimpleNamespace(
+        _flash_forward=forward, _flash_backward=backward,
+        **{name: getattr(fa, name) for name in (
+            "DEFAULT_Q_BLOCK", "_forward_blocks", "_backward_blocks")
+           if hasattr(fa, name)})
 
 
 def build(fa, name, blocks=None):
@@ -239,6 +325,14 @@ def build(fa, name, blocks=None):
     keys = jax.random.split(jax.random.PRNGKey(0), 3)
     q, k, v = (jax.random.normal(key, (b, length, heads, d), jnp.bfloat16)
                for key, heads in zip(keys, (h, kv_heads, kv_heads)))
+    rows = ROWS.get(name, "")
+    if rows:
+        q, k, v = (x.reshape(b, length, -1) if c in rows else x
+                   for c, x in zip("qkv", (q, k, v)))
+        if takes_rows(fa):
+            band["heads"] = (h, kv_heads)
+        else:       # an older checkout: the reshape its models handed it
+            fa = _on_heads(fa, b, length, (h, kv_heads, kv_heads), rows)
     if kind == "fwd":
         chooses = hasattr(fa, "_forward_blocks")
         default = None if chooses else fa.DEFAULT_Q_BLOCK
@@ -253,7 +347,7 @@ def build(fa, name, blocks=None):
         out, lse = jax.jit(lambda q, k, v: fa._flash_forward(
             q, k, v, causal, blocks[0] if blocks else block, block, False,
             **band))(q, k, v)
-        g = jax.random.normal(jax.random.PRNGKey(1), q.shape, q.dtype)
+        g = jax.random.normal(jax.random.PRNGKey(1), out.shape, q.dtype)
         if ring:
             k_offset = length if name == "bwd-ring-diag" else 0
             fn = jax.jit(lambda q, k, v, o, lse, g, q_off, k_off:
@@ -379,7 +473,7 @@ def measure(modules, name, blocks, calls, two_kernels=False):
             call = (time.perf_counter() - t0) / calls * 1e3
         parts = {kernel: sorted(kernel_ms(trace_dir, kernel))
                  for kernel in KERNELS[kind]}
-        device_all = all_ops_ms(trace_dir) / calls if name in MLA else None
+        device_all = all_ops_ms(trace_dir) / calls if name in AROUND else None
     parts = {kernel: ms for kernel, ms in parts.items() if ms}
     if not parts:
         raise SystemExit(f"{name}: the trace holds no {KERNELS[kind]} event")
