@@ -64,19 +64,17 @@ precision, as OLMoE's does.
 """
 
 import dataclasses
-import functools
-from typing import Any, Callable, Optional, Tuple
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from autodist_tpu.models.common import RMSNorm, rope
+from autodist_tpu.models.decoder import Decoder, init_params, make_loss_fn  # noqa: F401
 from autodist_tpu.models.moe import (  # noqa: F401 — the mixture's, under this family's names
-    GatedMLP, _dense, _INIT, balance_expert_bias,
-    balanced_optimizer as make_optimizer, expert_loads, sigmoid_routed_share,
-    sigmoid_topk_route, sown_loads)
+    GatedMLP, RoutedShare, _dense, balance_expert_bias,
+    balanced_optimizer as make_optimizer, check_share, expert_loads, sown_loads)
 from autodist_tpu.models.transformer_lm import (  # noqa: F401 — synthetic_batch re-exported
     dot_product_attention, synthetic_batch)
 
@@ -123,14 +121,7 @@ class AfmoeConfig:
                              f"got {sorted(unknown)}")
         if self.n_heads % self.n_kv_heads or self.head_dim % 2:
             raise ValueError("n_heads must divide over n_kv_heads, head_dim even")
-        if not 0 <= self.n_dense_layers <= len(self.layer_types):
-            raise ValueError("n_dense_layers must be in [0, n_layers]")
-        if not 1 <= self.top_k <= self.n_experts_routed:
-            raise ValueError("top_k must be in [1, n_experts_routed]")
-        if not (0 <= self.first_expert_held and self.experts_held >= 1
-                and self.first_expert_held + self.experts_held
-                <= self.n_experts_routed):
-            raise ValueError("the experts held must lie inside the router's width")
+        check_share(self)
 
     @property
     def n_layers(self) -> int:
@@ -191,32 +182,6 @@ class GatedAttention(nn.Module):
         return _dense(cfg.d_model, cfg.dtype, "out")(ctx)
 
 
-class SharedAndRoutedExperts(nn.Module):
-    """The expert layer's MLP: a shared expert every token passes, beside this
-    chip's share of the sigmoid top-k routed experts
-    (``models/moe.py`` :func:`sigmoid_routed_share`, whose parameters live in
-    this module's scope). ``__call__(h)`` takes the float32 normalised input
-    ``[B, S, d]`` and returns ``(m float32, the bias term of the loss)``."""
-    config: AfmoeConfig
-
-    @nn.compact
-    def __call__(self, h):
-        cfg = self.config
-        with jax.named_scope("moe.shared"):
-            shared = GatedMLP(cfg.d_expert * cfg.n_shared_experts, cfg.dtype,
-                              name="shared")(h.astype(cfg.dtype))
-        y, bias_term = sigmoid_routed_share(
-            self, h, router_width=cfg.n_experts_routed,
-            experts_held=cfg.experts_held,
-            first_expert_held=cfg.first_expert_held, top_k=cfg.top_k,
-            d_expert=cfg.d_expert, rows_bound=cfg.rows_bound,
-            route=functools.partial(sigmoid_topk_route,
-                                    route_norm=cfg.route_norm,
-                                    route_scale=cfg.route_scale),
-            dtype=cfg.dtype)
-        return shared.astype(jnp.float32) + y, bias_term
-
-
 class AfmoeBlock(nn.Module):
     config: AfmoeConfig
     kind: str
@@ -234,64 +199,18 @@ class AfmoeBlock(nn.Module):
             m = GatedMLP(cfg.d_ff, cfg.dtype, name="mlp")(h.astype(cfg.dtype))
             bias_term = jnp.zeros((), jnp.float32)
         else:
-            m, bias_term = SharedAndRoutedExperts(cfg, name="moe")(h)
+            m, bias_term = RoutedShare(
+                cfg, cfg.d_expert * cfg.n_shared_experts, name="moe")(h)
         return x + norm("ln_post_mlp", jnp.float32)(m), bias_term
 
 
-class Afmoe(nn.Module):
+class Afmoe(Decoder):
     """``tokens [B, L] -> (logits or hidden, bias term)``; the bias term is
     the sum over the expert layers of the zero-valued term whose gradient is
     the load error (module docstring)."""
     config: AfmoeConfig
+    block = AfmoeBlock
 
-    @nn.compact
-    def __call__(self, tokens, return_hidden: bool = False):
-        cfg = self.config
-        x = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=jnp.float32,
-                     param_dtype=jnp.float32, embedding_init=_INIT,
-                     name="embed")(tokens)
-        if cfg.mup_enabled:
-            x = x * np.float32(cfg.d_model ** 0.5)
-        bias_term = jnp.zeros((), jnp.float32)
-        for i, kind in enumerate(cfg.layer_types):
-            x, term = AfmoeBlock(cfg, kind, i < cfg.n_dense_layers,
-                                 name=f"block_{i}")(x)
-            bias_term = bias_term + term
-        x = RMSNorm(cfg.rms_eps, cfg.dtype, name="ln_f")(x)
-        if return_hidden:
-            # The fused-head loss owns the projection; the head's parameters
-            # exist from init, which runs the path below.
-            return x, bias_term
-        return _dense(cfg.vocab_size, cfg.dtype, "lm_head")(x), bias_term
-
-
-def make_loss_fn(model: Afmoe) -> Callable:
-    """Mean next-token cross-entropy (+ the expert layers' bias terms, zero in
-    value: module docstring); batch = ``{"tokens": int32 [B, L+1]}``."""
-    cfg = model.config
-
-    def loss_fn(params, batch):
-        tokens = batch["tokens"]
-        inputs, targets = tokens[:, :-1], tokens[:, 1:]
-        if cfg.fused_head:
-            from autodist_tpu.models.common import fused_lm_head_nll
-            h, bias_term = model.apply({"params": params}, inputs,
-                                       return_hidden=True)
-            nll = fused_lm_head_nll(h, params, targets)
-        else:
-            logits, bias_term = model.apply({"params": params}, inputs)
-            logprobs = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-            nll = -jnp.take_along_axis(logprobs, targets[..., None],
-                                       axis=-1)[..., 0]
-        return nll.mean() + bias_term
-
-    return loss_fn
-
-
-def init_params(config: AfmoeConfig, rng: Optional[jax.Array] = None,
-                batch_size: int = 2):
-    from autodist_tpu.models.common import jit_init
-    rng = rng if rng is not None else jax.random.PRNGKey(0)
-    model = Afmoe(config)
-    tokens = jnp.zeros((batch_size, min(8, config.max_len)), jnp.int32)
-    return model, jit_init(model, tokens, rng=rng)
+    def layers(self):
+        return [(kind, i < self.config.n_dense_layers)
+                for i, kind in enumerate(self.config.layer_types)]

@@ -39,8 +39,8 @@ a value ``d_v``, the latent ``r`` (``kv_lora_rank``)::
 No multi-token-prediction module. **One chip's share**, **the expert bias on
 the normal path** and its start from the balancing rule alone are
 ``models/afmoe.py``'s, word for word, and the code is the same code:
-``models/moe.py`` ``sigmoid_routed_share`` (``form="gated_silu"``),
-``balanced_optimizer``, ``balance_expert_bias``.
+``models/moe.py`` ``RoutedShare``, ``balanced_optimizer``,
+``balance_expert_bias``; the stack, the loss and the init ``models/decoder.py``'s.
 
 **The attention core** is one call: ``ops/flash_attention.py``
 ``flash_attention`` with a value width of its own and the rotary key as
@@ -77,8 +77,7 @@ precision, as OLMoE's and AFMoE's do.
 """
 
 import dataclasses
-import functools
-from typing import Any, Callable, Optional
+from typing import Any, Optional
 
 import flax.linen as nn
 import jax
@@ -86,11 +85,12 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from autodist_tpu import telemetry
-from autodist_tpu.models.common import RMSNorm, keeping, rope_pairs
+from autodist_tpu.models.common import RMSNorm, rope_pairs
+from autodist_tpu.models.decoder import Decoder, init_params, make_loss_fn  # noqa: F401
 from autodist_tpu.models.moe import (  # noqa: F401 — the mixture's, under this family's names
-    KEPT_GATE, KEPT_PASS, KEPT_ROUTER_LOGITS, KEPT_UP, GatedMLP, _dense, _INIT,
-    balance_expert_bias, balanced_optimizer as make_optimizer, expert_loads,
-    sigmoid_routed_share, sigmoid_topk_route, sown_loads)
+    KEPT_GATE, KEPT_PASS, KEPT_ROUTER_LOGITS, KEPT_UP, GatedMLP, RoutedShare,
+    _dense, balance_expert_bias, balanced_optimizer as make_optimizer,
+    check_share, expert_loads, sown_loads)
 from autodist_tpu.models.transformer_lm import (  # noqa: F401 — synthetic_batch re-exported
     causal_mask, dot_product_attention, synthetic_batch)
 from autodist_tpu.ops.flash_attention import KEPT_NAME as KEPT_FLASH
@@ -142,14 +142,7 @@ class DeepseekV3Config:
                              f"valid: 'dot', 'flash'")
         if self.qk_rope_head_dim % 2 or self.qk_nope_head_dim % 2:
             raise ValueError("the key's two parts must be even")
-        if not 0 <= self.n_dense_layers <= self.n_layers:
-            raise ValueError("n_dense_layers must be in [0, n_layers]")
-        if not 1 <= self.top_k <= self.n_experts_routed:
-            raise ValueError("top_k must be in [1, n_experts_routed]")
-        if not (0 <= self.first_expert_held and self.experts_held >= 1
-                and self.first_expert_held + self.experts_held
-                <= self.n_experts_routed):
-            raise ValueError("the experts held must lie inside the router's width")
+        check_share(self)
 
     @property
     def qk_head_dim(self) -> int:
@@ -212,34 +205,6 @@ class LatentAttention(nn.Module):
                 ctx.reshape(b, length, heads * d_v))
 
 
-class SharedAndRoutedExperts(nn.Module):
-    """The expert layer's MLP: the shared experts as one gated-SiLU MLP every
-    token passes, beside this chip's share of the sigmoid top-k routed
-    experts (``models/moe.py`` :func:`sigmoid_routed_share`, whose parameters
-    live in this module's scope). ``__call__(h)`` takes the float32
-    normalised input ``[B, S, d]`` and returns ``(m float32, the bias term
-    of the loss)``."""
-    config: DeepseekV3Config
-
-    @nn.compact
-    def __call__(self, h):
-        cfg = self.config
-        with jax.named_scope("moe.shared"):
-            shared = GatedMLP(cfg.d_expert * cfg.n_shared_experts, cfg.dtype,
-                              name="shared")(h.astype(cfg.dtype))
-        y, bias_term = sigmoid_routed_share(
-            self, h, router_width=cfg.n_experts_routed,
-            experts_held=cfg.experts_held,
-            first_expert_held=cfg.first_expert_held, top_k=cfg.top_k,
-            d_expert=cfg.d_expert, rows_bound=cfg.rows_bound,
-            route=functools.partial(sigmoid_topk_route,
-                                    route_norm=cfg.route_norm,
-                                    route_scale=cfg.route_scale,
-                                    route_eps=cfg.route_eps),
-            dtype=cfg.dtype, form="gated_silu")
-        return shared.astype(jnp.float32) + y, bias_term
-
-
 class DeepseekV3Block(nn.Module):
     """``x + Attn(RMSNorm(x))``, then ``+ FFN(RMSNorm(.))``; ``(x, the
     layer's bias term)``."""
@@ -256,65 +221,18 @@ class DeepseekV3Block(nn.Module):
             m = GatedMLP(cfg.d_ff, cfg.dtype, name="mlp")(h.astype(cfg.dtype))
             bias_term = jnp.zeros((), jnp.float32)
         else:
-            m, bias_term = SharedAndRoutedExperts(cfg, name="moe")(h)
+            m, bias_term = RoutedShare(
+                cfg, cfg.d_expert * cfg.n_shared_experts, name="moe")(h)
         return x + m, bias_term
 
 
-class DeepseekV3(nn.Module):
-    """``tokens [B, L] -> (logits or hidden, bias term)``; the bias term is
-    the sum over the expert layers of the zero-valued term whose gradient is
-    the load error (``models/afmoe.py``'s docstring)."""
+class DeepseekV3(Decoder):
+    """``tokens [B, L] -> (logits or hidden, the expert layers' bias terms
+    summed: ``models/afmoe.py``'s docstring)``."""
     config: DeepseekV3Config
+    block = DeepseekV3Block
+    kept = KEPT
 
-    @nn.compact
-    def __call__(self, tokens, return_hidden: bool = False):
-        cfg = self.config
-        x = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=jnp.float32,
-                     param_dtype=jnp.float32, embedding_init=_INIT,
-                     name="embed")(tokens)
-        block = DeepseekV3Block
-        if cfg.remat and not self.is_initializing():
-            block = nn.remat(DeepseekV3Block, policy=keeping(KEPT))
-            telemetry.gauge("remat.layers").set(cfg.n_layers)
-        bias_term = jnp.zeros((), jnp.float32)
-        for i in range(cfg.n_layers):
-            x, term = block(cfg, i < cfg.n_dense_layers, name=f"block_{i}")(x)
-            bias_term = bias_term + term
-        x = RMSNorm(cfg.rms_eps, cfg.dtype, name="ln_f")(x)
-        if return_hidden:
-            # The fused-head loss owns the projection; the head's parameters
-            # exist from init, which runs the path below.
-            return x, bias_term
-        return _dense(cfg.vocab_size, cfg.dtype, "lm_head")(x), bias_term
-
-
-def make_loss_fn(model: DeepseekV3) -> Callable:
-    """Mean next-token cross-entropy (+ the expert layers' bias terms, zero in
-    value); batch = ``{"tokens": int32 [B, L+1]}``."""
-    cfg = model.config
-
-    def loss_fn(params, batch):
-        tokens = batch["tokens"]
-        inputs, targets = tokens[:, :-1], tokens[:, 1:]
-        if cfg.fused_head:
-            from autodist_tpu.models.common import fused_lm_head_nll
-            h, bias_term = model.apply({"params": params}, inputs,
-                                       return_hidden=True)
-            nll = fused_lm_head_nll(h, params, targets)
-        else:
-            logits, bias_term = model.apply({"params": params}, inputs)
-            logprobs = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-            nll = -jnp.take_along_axis(logprobs, targets[..., None],
-                                       axis=-1)[..., 0]
-        return nll.mean() + bias_term
-
-    return loss_fn
-
-
-def init_params(config: DeepseekV3Config, rng: Optional[jax.Array] = None,
-                batch_size: int = 2):
-    from autodist_tpu.models.common import jit_init
-    rng = rng if rng is not None else jax.random.PRNGKey(0)
-    model = DeepseekV3(config)
-    tokens = jnp.zeros((batch_size, min(8, config.max_len)), jnp.int32)
-    return model, jit_init(model, tokens, rng=rng)
+    def layers(self):
+        return [(i < self.config.n_dense_layers,)
+                for i in range(self.config.n_layers)]

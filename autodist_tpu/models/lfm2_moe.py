@@ -38,15 +38,10 @@ source in ``benchmark/configs/lfm2-24b-a2b.json``). ``T`` tokens, width
 
 **One chip's share**, **the expert bias on the normal path** and its start
 from the balancing rule alone are ``models/afmoe.py``'s, word for word, and
-the code is the same code: ``models/moe.py`` ``sigmoid_routed_share``,
-``balanced_optimizer``, ``balance_expert_bias``. ``n_experts_routed`` is the
-router's width; ``experts_held`` of them, from ``first_expert_held`` on, have
-their banks here; the router chooses over all of them and this layer adds its
-own experts' part, ``rows_bound`` held rows a pass: the first pass keeps what
-its backward reads and is computed once, a pass past it (rare: the bound is
-twice the mean held rows) is computed again for the backward, and the layer
-sows how many a step took (``passes``). The conv and attention operators, the
-router and the dense layer are what every rank computes alike.
+the code is the same code: ``models/moe.py`` ``RoutedShare`` (no shared
+expert), ``balanced_optimizer``, ``balance_expert_bias``. The conv and
+attention operators, the router and the dense layer are what every rank
+computes alike. The stack, the loss and the init are ``models/decoder.py``'s.
 
 The gated convolution between the two projections is one operator,
 ``ops/short_conv.py`` ``gated_short_conv``, plain (``conv_impl="xla"``) or as
@@ -58,18 +53,17 @@ precision, as OLMoE's and AFMoE's do.
 """
 
 import dataclasses
-import functools
-from typing import Any, Callable, Optional, Tuple
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
 from autodist_tpu.models.common import RMSNorm, rope
+from autodist_tpu.models.decoder import Decoder, init_params, make_loss_fn  # noqa: F401
 from autodist_tpu.models.moe import (  # noqa: F401 — the mixture's, under this family's names
-    GatedMLP, _dense, _INIT, balance_expert_bias,
-    balanced_optimizer as make_optimizer, expert_loads, sigmoid_routed_share,
-    sigmoid_topk_route, sown_loads)
+    GatedMLP, RoutedShare, _dense, _INIT, balance_expert_bias,
+    balanced_optimizer as make_optimizer, check_share, expert_loads, sown_loads)
 from autodist_tpu.models.transformer_lm import (  # noqa: F401 — synthetic_batch re-exported
     causal_mask, dot_product_attention, synthetic_batch)
 from autodist_tpu.ops.short_conv import IMPLS as CONV_IMPLS, gated_short_conv
@@ -120,14 +114,7 @@ class Lfm2MoeConfig:
                              f"got {sorted(unknown)}")
         if self.n_heads % self.n_kv_heads or self.head_dim % 2:
             raise ValueError("n_heads must divide over n_kv_heads, head_dim even")
-        if not 0 <= self.n_dense_layers <= len(self.layer_types):
-            raise ValueError("n_dense_layers must be in [0, n_layers]")
-        if not 1 <= self.top_k <= self.n_experts_routed:
-            raise ValueError("top_k must be in [1, n_experts_routed]")
-        if not (0 <= self.first_expert_held and self.experts_held >= 1
-                and self.first_expert_held + self.experts_held
-                <= self.n_experts_routed):
-            raise ValueError("the experts held must lie inside the router's width")
+        check_share(self)
 
     @property
     def n_layers(self) -> int:
@@ -183,28 +170,6 @@ class GroupedAttention(nn.Module):
         return _dense(cfg.d_model, cfg.dtype, "out")(ctx.reshape(b, length, wide))
 
 
-class RoutedExperts(nn.Module):
-    """The expert layer's MLP: this chip's share of the sigmoid top-k routed
-    experts and nothing beside them. ``__call__(h)`` takes the float32
-    normalised input ``[B, S, d]`` and returns ``(m float32, the bias term of
-    the loss)``."""
-    config: Lfm2MoeConfig
-
-    @nn.compact
-    def __call__(self, h):
-        cfg = self.config
-        return sigmoid_routed_share(
-            self, h, router_width=cfg.n_experts_routed,
-            experts_held=cfg.experts_held,
-            first_expert_held=cfg.first_expert_held, top_k=cfg.top_k,
-            d_expert=cfg.d_expert, rows_bound=cfg.rows_bound,
-            route=functools.partial(sigmoid_topk_route,
-                                    route_norm=cfg.route_norm,
-                                    route_scale=cfg.route_scale,
-                                    route_eps=cfg.route_eps),
-            dtype=cfg.dtype)
-
-
 class Lfm2MoeBlock(nn.Module):
     config: Lfm2MoeConfig
     kind: str
@@ -224,63 +189,18 @@ class Lfm2MoeBlock(nn.Module):
             m = GatedMLP(cfg.d_ff, cfg.dtype, name="mlp")(h.astype(cfg.dtype))
             bias_term = jnp.zeros((), jnp.float32)
         else:
-            m, bias_term = RoutedExperts(cfg, name="moe")(h)
+            m, bias_term = RoutedShare(cfg, name="moe")(h)   # no shared expert
         return x + m, bias_term
 
 
-class Lfm2Moe(nn.Module):
-    """``tokens [B, L] -> (logits or hidden, bias term)``; the bias term is
-    the sum over the expert layers of the zero-valued term whose gradient is
-    the load error (``models/afmoe.py``'s docstring). The head is the
-    embedding table."""
+class Lfm2Moe(Decoder):
+    """``tokens [B, L] -> (logits or hidden, the expert layers' bias terms
+    summed: ``models/afmoe.py``'s docstring)``. The head is the embedding table."""
     config: Lfm2MoeConfig
+    block = Lfm2MoeBlock
+    final_norm = "embedding_norm"
+    tied = True
 
-    @nn.compact
-    def __call__(self, tokens, return_hidden: bool = False):
-        cfg = self.config
-        embed = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=jnp.float32,
-                         param_dtype=jnp.float32, embedding_init=_INIT,
-                         name="embed")
-        x = embed(tokens)
-        bias_term = jnp.zeros((), jnp.float32)
-        for i, kind in enumerate(cfg.layer_types):
-            x, term = Lfm2MoeBlock(cfg, kind, i < cfg.n_dense_layers,
-                                   name=f"block_{i}")(x)
-            bias_term = bias_term + term
-        x = RMSNorm(cfg.rms_eps, cfg.dtype, name="embedding_norm")(x)
-        if return_hidden:
-            return x, bias_term     # the fused-head loss owns the projection
-        # in the sublayers' dtype, as the other families' heads compute
-        return x @ embed.embedding.astype(cfg.dtype).T, bias_term
-
-
-def make_loss_fn(model: Lfm2Moe) -> Callable:
-    """Mean next-token cross-entropy (+ the expert layers' bias terms, zero in
-    value); batch = ``{"tokens": int32 [B, L+1]}``."""
-    cfg = model.config
-
-    def loss_fn(params, batch):
-        tokens = batch["tokens"]
-        inputs, targets = tokens[:, :-1], tokens[:, 1:]
-        if cfg.fused_head:
-            from autodist_tpu.models.common import fused_lm_head_nll
-            h, bias_term = model.apply({"params": params}, inputs,
-                                       return_hidden=True)
-            nll = fused_lm_head_nll(h, params, targets, tied=True)
-        else:
-            logits, bias_term = model.apply({"params": params}, inputs)
-            logprobs = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-            nll = -jnp.take_along_axis(logprobs, targets[..., None],
-                                       axis=-1)[..., 0]
-        return nll.mean() + bias_term
-
-    return loss_fn
-
-
-def init_params(config: Lfm2MoeConfig, rng: Optional[jax.Array] = None,
-                batch_size: int = 2):
-    from autodist_tpu.models.common import jit_init
-    rng = rng if rng is not None else jax.random.PRNGKey(0)
-    model = Lfm2Moe(config)
-    tokens = jnp.zeros((batch_size, min(8, config.max_len)), jnp.int32)
-    return model, jit_init(model, tokens, rng=rng)
+    def layers(self):
+        return [(kind, i < self.config.n_dense_layers)
+                for i, kind in enumerate(self.config.layer_types)]

@@ -22,13 +22,16 @@ row count is (tokens x k); only the group boundaries are run-time data. No
 ``[tokens, experts, capacity]`` tensor exists and no token is dropped or padded.
 
 Last, what the sigmoid-routed families (``models/afmoe.py``,
-``models/lfm2_moe.py``, ``models/nemotron_h.py``) share and none copies:
-:class:`GatedMLP` and :class:`PlainMLP`, the expert's form as an argument
-(:data:`EXPERT_FORMS`: gated-SiLU over three banks, ``relu2`` over two),
-:func:`sigmoid_routed_share` (one chip's share of the routed experts with the
-``expert_bias`` leaf and its zero-valued loss term), :func:`balanced_optimizer`
-(the aux-loss-free balancing rule as an optax transformation) and
-:func:`balance_expert_bias` (the same rule alone, before training).
+``models/lfm2_moe.py``, ``models/nemotron_h.py``, ``models/deepseek_v3.py``)
+share and none copies: :class:`GatedMLP` and :class:`PlainMLP`, the expert's
+form as an argument (:data:`EXPERT_FORMS`: gated-SiLU over three banks,
+``relu2`` over two), :class:`RoutedShare` (the expert layer's module: one
+chip's share of the routed experts with the ``expert_bias`` leaf and its
+zero-valued loss term, beside an optional shared expert), :func:`check_share`
+(the ranges a share's config holds to), :func:`balanced_optimizer` (the
+aux-loss-free balancing rule as an optax transformation) and
+:func:`balance_expert_bias` (the same rule alone, before training). The
+stack around the layers is ``models/decoder.py``'s.
 """
 
 import dataclasses
@@ -48,7 +51,7 @@ from autodist_tpu.models.transformer_lm import (MultiHeadAttention,
 # (``models/nemotron_h.py`` and ``models/deepseek_v3.py`` ``KEPT``); the
 # identity, lowered to nothing, outside one: :class:`PlainMLP`'s ``up``
 # product, :class:`GatedMLP`'s ``gate`` and ``up``, the router's logits in
-# :func:`sigmoid_routed_share` (six bfloat16 passes to make again), and what
+# :class:`RoutedShare` (six bfloat16 passes to make again), and what
 # pass 0 of :func:`_held_passes` makes for its transpose.
 KEPT_UP = "mlp_up"
 KEPT_GATE = "mlp_gate"
@@ -695,74 +698,105 @@ class PlainMLP(nn.Module):
         return _dense(h.shape[-1], self.dtype, "down")(self.act(up))
 
 
-def sigmoid_routed_share(module: nn.Module, h, *, router_width: int,
-                         experts_held: int, first_expert_held: int, top_k: int,
-                         d_expert: int, rows_bound: Optional[int],
-                         route: Callable[..., Route], dtype,
-                         form: str = "gated_silu"):
-    """This chip's share of a layer's sigmoid top-k routed experts, with its
-    parameters made in ``module``'s own scope (call it from the compact
-    method of the expert layer): ``router [d, router_width]``, ``expert_bias
-    [router_width]`` (float32, zeros) and the banks ``gate``, ``up`` ``[held,
-    d, d_expert]``, ``down [held, d_expert, d]`` of the experts
-    ``[first_expert_held, first_expert_held + experts_held)`` (no ``gate``
-    under ``form="relu2"``: :data:`EXPERT_FORMS`).
+class RoutedShare(nn.Module):
+    """An expert layer's MLP: this chip's share of the layer's sigmoid top-k
+    routed experts and, where ``d_shared`` is not zero, a shared expert of
+    that width and of the experts' ``form`` beside it, which every token
+    passes (:class:`GatedMLP`, or :class:`PlainMLP` under ``"relu2"``;
+    parameters under ``shared``). Its own parameters: ``router [d,
+    n_experts_routed]``, ``expert_bias [n_experts_routed]`` (float32, zeros)
+    and the banks ``gate``, ``up`` ``[held, d, d_expert]``, ``down [held,
+    d_expert, d]`` of the experts ``[first_expert_held, first_expert_held +
+    experts_held)`` (no ``gate`` under ``form="relu2"``: :data:`EXPERT_FORMS`).
+    ``config`` is the family's: those sizes, ``top_k``, ``rows_bound``,
+    ``dtype`` and :func:`sigmoid_topk_route`'s normaliser (``route_norm``,
+    ``route_scale`` and, where the family sets one, ``route_eps``).
 
     ``h`` is the float32 normalised input ``[B, S, d]``: the router's product
     and sigmoid read it as it is at ``HIGHEST`` precision, the experts its
-    cast to ``dtype``. ``route`` is :func:`sigmoid_topk_route` with the
-    family's normaliser (``functools.partial``). Returns ``(y [B, S, d]
-    float32, the bias term)``: the held experts' weighted sum (what the absent
+    cast to ``dtype``. Returns ``(m [B, S, d] float32, the bias term)``: the
+    shared expert's output + the held experts' weighted sum (what the absent
     ones would add is left out), and ``sum_e (b_e - stop_gradient(b_e)) .
     stop_gradient(c_e - mean c) / T``, zero in value, whose gradient with
     respect to ``expert_bias`` is the layer's load error (``c_e``: the rows
     expert ``e`` of the router's whole width received). The loads are sown
     under ``intermediates`` / ``load``, the passes the held rows took under
     ``passes``."""
-    from autodist_tpu.parallel.mesh import per_device
-    b, s, d = h.shape
-    router = module.param("router", _INIT, (d, router_width), jnp.float32)
-    bias = module.param("expert_bias", nn.initializers.zeros, (router_width,),
-                        jnp.float32)
-    if form not in EXPERT_FORMS:
-        raise ValueError(f"Unknown expert form {form!r}; valid: {EXPERT_FORMS}")
-    gated = form != "relu2"
-    bank = [module.param(name, _INIT, shape, jnp.float32) for name, shape in (
-        ("gate", (experts_held, d, d_expert)), ("up", (experts_held, d, d_expert)),
-        ("down", (experts_held, d_expert, d)))[0 if gated else 1:]]
-    if module.is_initializing():
-        # Shapes are all that init needs: no kernel is compiled for the
-        # handful of positions it runs on.
-        return jnp.zeros((b, s, d), jnp.float32), jnp.zeros((), jnp.float32)
-    tokens = h.reshape(b * s, d)
-    scores = jax.nn.sigmoid(checkpoint_name(
-        jnp.dot(tokens.astype(jnp.float32), router,
-                precision=jax.lax.Precision.HIGHEST), KEPT_ROUTER_LOGITS))
-    share = functools.partial(routed_experts, top_k=top_k, route=route,
-                              first_expert=first_expert_held,
-                              rows_bound=rows_bound, form=form)
-    y, sizes = per_device(
-        share if gated else lambda x, s, *rest: share(x, s, None, *rest),
-        (tokens.astype(dtype), scores, *bank, bias),
-        batched=(True, True) + (False,) * (len(bank) + 1))
-    # The load every expert of the router's width received, absent ones
-    # too: the choice is made here for all of them. (The same top_k as the
-    # route's; the compiler keeps one.)
-    _, chosen = jax.lax.top_k(jax.lax.stop_gradient(scores + bias), top_k)
-    load = jnp.sum(chosen[..., None] == jnp.arange(router_width), axis=(0, 1),
-                   dtype=jnp.float32)
-    bias_term = jnp.sum((bias - jax.lax.stop_gradient(bias))
-                        * jax.lax.stop_gradient(load - load.mean())) / (b * s)
-    # Per device the sizes are of its own tokens, [devices * held]: the
-    # passes are those of the device that took most.
-    held_rows = sizes.reshape(-1, experts_held).sum(axis=1)
-    slots = b * s * top_k // held_rows.size
-    bound = slots if rows_bound is None else min(int(rows_bound), slots)
-    passes = _passes(held_rows, bound).max()
-    # for whoever applies with mutable=["intermediates"] (tools/afmoe_load.py)
-    module.sow("intermediates", "load", load)
-    module.sow("intermediates", "passes", passes.astype(jnp.int32))
-    return y.reshape(b, s, d), bias_term
+    config: Any
+    d_shared: int = 0
+    form: str = "gated_silu"
+
+    @nn.compact
+    def __call__(self, h):
+        from autodist_tpu.parallel.mesh import per_device
+        cfg, form = self.config, self.form
+        if form not in EXPERT_FORMS:
+            raise ValueError(f"Unknown expert form {form!r}; valid: {EXPERT_FORMS}")
+        b, s, d = h.shape
+        router_width, held = cfg.n_experts_routed, cfg.experts_held
+        if self.d_shared:
+            with jax.named_scope("moe.shared"):
+                mlp = PlainMLP if form == "relu2" else GatedMLP
+                shared = mlp(self.d_shared, cfg.dtype, name="shared")(
+                    h.astype(cfg.dtype))
+        router = self.param("router", _INIT, (d, router_width), jnp.float32)
+        bias = self.param("expert_bias", nn.initializers.zeros, (router_width,),
+                          jnp.float32)
+        gated = form != "relu2"
+        bank = [self.param(name, _INIT, shape, jnp.float32) for name, shape in (
+            ("gate", (held, d, cfg.d_expert)), ("up", (held, d, cfg.d_expert)),
+            ("down", (held, cfg.d_expert, d)))[0 if gated else 1:]]
+        if self.is_initializing():
+            # Shapes are all that init needs: no kernel is compiled for the
+            # handful of positions it runs on.
+            return jnp.zeros((b, s, d), jnp.float32), jnp.zeros((), jnp.float32)
+        tokens = h.reshape(b * s, d)
+        scores = jax.nn.sigmoid(checkpoint_name(
+            jnp.dot(tokens.astype(jnp.float32), router,
+                    precision=jax.lax.Precision.HIGHEST), KEPT_ROUTER_LOGITS))
+        route = functools.partial(sigmoid_topk_route, **{
+            name: getattr(cfg, name) for name in (
+                "route_norm", "route_scale", "route_eps") if hasattr(cfg, name)})
+        share = functools.partial(routed_experts, top_k=cfg.top_k, route=route,
+                                  first_expert=cfg.first_expert_held,
+                                  rows_bound=cfg.rows_bound, form=form)
+        y, sizes = per_device(
+            share if gated else lambda x, s, *rest: share(x, s, None, *rest),
+            (tokens.astype(cfg.dtype), scores, *bank, bias),
+            batched=(True, True) + (False,) * (len(bank) + 1))
+        # The load every expert of the router's width received, absent ones
+        # too: the choice is made here for all of them. (The same top_k as the
+        # route's; the compiler keeps one.)
+        _, chosen = jax.lax.top_k(jax.lax.stop_gradient(scores + bias), cfg.top_k)
+        load = jnp.sum(chosen[..., None] == jnp.arange(router_width), axis=(0, 1),
+                       dtype=jnp.float32)
+        bias_term = jnp.sum((bias - jax.lax.stop_gradient(bias))
+                            * jax.lax.stop_gradient(load - load.mean())) / (b * s)
+        # Per device the sizes are of its own tokens, [devices * held]: the
+        # passes are those of the device that took most.
+        held_rows = sizes.reshape(-1, held).sum(axis=1)
+        slots = b * s * cfg.top_k // held_rows.size
+        bound = slots if cfg.rows_bound is None else min(int(cfg.rows_bound), slots)
+        passes = _passes(held_rows, bound).max()
+        # for whoever applies with mutable=["intermediates"] (tools/afmoe_load.py)
+        self.sow("intermediates", "load", load)
+        self.sow("intermediates", "passes", passes.astype(jnp.int32))
+        y = y.reshape(b, s, d)
+        return (shared.astype(jnp.float32) + y if self.d_shared else y), bias_term
+
+
+def check_share(config) -> None:
+    """What a family's ``__post_init__`` asks of its share: ``top_k`` inside
+    the router's width, the held experts inside it and, where the family has
+    leading dense layers, their number inside the stack."""
+    if not 0 <= getattr(config, "n_dense_layers", 0) <= config.n_layers:
+        raise ValueError("n_dense_layers must be in [0, n_layers]")
+    if not 1 <= config.top_k <= config.n_experts_routed:
+        raise ValueError("top_k must be in [1, n_experts_routed]")
+    if not (0 <= config.first_expert_held and config.experts_held >= 1
+            and config.first_expert_held + config.experts_held
+            <= config.n_experts_routed):
+        raise ValueError("the experts held must lie inside the router's width")
 
 
 def balanced_optimizer(learning_rate, load_balance_coeff: float,
@@ -770,7 +804,7 @@ def balanced_optimizer(learning_rate, load_balance_coeff: float,
     """AdamW (or ``weights(learning_rate)``) for every leaf but the
     ``expert_bias`` ones, which take ``b += delta - mean(delta)``, ``delta =
     -load_balance_coeff * sign(d loss / d b)``: with
-    :func:`sigmoid_routed_share`'s loss term the published aux-loss-free
+    :class:`RoutedShare`'s loss term the published aux-loss-free
     balancing rule, as an optax transformation."""
     import optax
 

@@ -47,24 +47,21 @@ convolution uniform in ``+-1/sqrt(K)``, every matrix normal(0.02), and under
 
 **One chip's share**, **the expert bias on the normal path** and its start
 from the balancing rule alone are ``models/afmoe.py``'s, word for word, and
-the code is the same code: ``models/moe.py`` ``sigmoid_routed_share`` (told
-the expert's form, ``relu2``: two banks and no gate), ``balanced_optimizer``,
-``balance_expert_bias``. ``n_experts_routed`` is the router's width;
-``experts_held`` of them, from ``first_expert_held`` on, have their banks
-here; the router chooses over all of them and this layer adds its own
-experts' part, ``rows_bound`` held rows a pass. The shared expert, the
-Mamba-2 and attention layers and the router are what every rank computes
-alike.
+the code is the same code: ``models/moe.py`` ``RoutedShare`` (told the
+expert's form, ``relu2``: two banks and no gate, and the shared expert's
+width), ``balanced_optimizer``, ``balance_expert_bias``. The shared expert,
+the Mamba-2 and attention layers and the router are what every rank computes
+alike. The stack, the loss and the init are ``models/decoder.py``'s.
 
 The scan is one operator, ``ops/ssd_scan.py`` ``ssd_scan``, chunked
 (``chunk_size`` positions a chunk, one ``[P, N]`` state a head carried between
 chunks), and the convolution before it ``ops/short_conv.py`` ``conv_silu``:
 both plain (``ssm_impl="xla"``) or each as two Pallas kernels (``"pallas"``).
 
-Under ``remat`` every layer is a ``jax.checkpoint`` whose policy keeps a
-short list of named values (:data:`KEPT`) and recomputes everything else
-from the residual stream. Without any checkpoint the benchmark's cell needs
-17.1 GiB of a 15.75 GiB chip; with a bare checkpoint it has 3 GiB free and
+Under ``remat`` every layer is a ``jax.checkpoint`` (``models/decoder.py``)
+whose policy keeps a short list of named values (:data:`KEPT`) and makes the
+rest again from the residual stream. Without any checkpoint the benchmark's
+cell needs 17.1 GiB of a 15.75 GiB chip; with a bare one it has 3 GiB free and
 its backward runs every forward kernel and matmul a second time. The list is
 what is dearest to make again for the bytes it takes to keep (PERF.md
 section 6, "PR 36", has the milliseconds a MiB of each): attention's q / k /
@@ -95,21 +92,20 @@ AFMoE's do.
 """
 
 import dataclasses
-import functools
 import math
-from typing import Any, Callable, Optional
+from typing import Any, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from autodist_tpu import telemetry
-from autodist_tpu.models.common import RMSNorm, keeping as _keeping
+from autodist_tpu.models.common import RMSNorm
+from autodist_tpu.models.decoder import Decoder, init_params, make_loss_fn  # noqa: F401
 from autodist_tpu.models.moe import (  # noqa: F401 — the mixture's, under this family's names
-    KEPT_PASS, KEPT_ROUTER_LOGITS, KEPT_UP, PlainMLP, _INIT, balance_expert_bias,
-    balanced_optimizer as make_optimizer, expert_loads, sigmoid_routed_share,
-    sigmoid_topk_route, sown_loads)
+    KEPT_PASS, KEPT_ROUTER_LOGITS, KEPT_UP, PlainMLP, RoutedShare, _INIT,
+    balance_expert_bias, balanced_optimizer as make_optimizer, check_share,
+    expert_loads, sown_loads)
 from autodist_tpu.models.transformer_lm import (  # noqa: F401 — synthetic_batch re-exported
     causal_mask, dot_product_attention, synthetic_batch)
 from autodist_tpu.ops.flash_attention import KEPT_NAME as KEPT_FLASH
@@ -182,15 +178,10 @@ class NemotronHConfig:
         if self.n_heads % self.n_kv_heads or self.mamba_heads % self.n_groups:
             raise ValueError("n_heads must divide over n_kv_heads, "
                              "mamba_heads over n_groups")
-        if not 1 <= self.top_k <= self.n_experts_routed:
-            raise ValueError("top_k must be in [1, n_experts_routed]")
+        check_share(self)
         if self.exact_first_layer and self.pattern[0] != MAMBA:
             raise ValueError("exact_first_layer is the Mamba-2 layer's; the "
                              f"pattern starts with {self.pattern[0]!r}")
-        if not (0 <= self.first_expert_held and self.experts_held >= 1
-                and self.first_expert_held + self.experts_held
-                <= self.n_experts_routed):
-            raise ValueError("the experts held must lie inside the router's width")
 
     @property
     def n_layers(self) -> int:
@@ -329,33 +320,6 @@ class GroupedAttention(nn.Module):
         return _out_proj(cfg, "out")(ctx.reshape(b, length, wide))
 
 
-class SharedAndRoutedExperts(nn.Module):
-    """The expert mixer: a shared ``relu2`` expert every token passes, beside
-    this chip's share of the sigmoid top-k routed ``relu2`` experts
-    (``models/moe.py`` :func:`sigmoid_routed_share`, whose parameters live in
-    this module's scope). ``__call__(h)`` takes the float32 normalised input
-    ``[B, S, d]`` and returns ``(m float32, the bias term of the loss)``."""
-    config: NemotronHConfig
-
-    @nn.compact
-    def __call__(self, h):
-        cfg = self.config
-        with jax.named_scope("moe.shared"):
-            shared = PlainMLP(cfg.d_shared, cfg.dtype, name="shared")(
-                h.astype(cfg.dtype))
-        y, bias_term = sigmoid_routed_share(
-            self, h, router_width=cfg.n_experts_routed,
-            experts_held=cfg.experts_held,
-            first_expert_held=cfg.first_expert_held, top_k=cfg.top_k,
-            d_expert=cfg.d_expert, rows_bound=cfg.rows_bound,
-            route=functools.partial(sigmoid_topk_route,
-                                    route_norm=cfg.route_norm,
-                                    route_scale=cfg.route_scale,
-                                    route_eps=cfg.route_eps),
-            dtype=cfg.dtype, form="relu2")
-        return shared.astype(jnp.float32) + y, bias_term
-
-
 class NemotronHBlock(nn.Module):
     """``x + mixer(RMSNorm(x))`` for one mixer; ``(x, the layer's bias term)``."""
     config: NemotronHConfig
@@ -368,7 +332,8 @@ class NemotronHBlock(nn.Module):
         bias_term = jnp.zeros((), jnp.float32)
         if self.kind == EXPERTS:
             h = RMSNorm(cfg.rms_eps, jnp.float32, name="norm")(x)
-            m, bias_term = SharedAndRoutedExperts(cfg, name="moe")(h)
+            m, bias_term = RoutedShare(cfg, cfg.d_shared, "relu2",
+                                       name="moe")(h)
         elif self.kind == MAMBA:
             h = RMSNorm(cfg.rms_eps, jnp.float32 if self.exact else cfg.dtype,
                         name="norm")(x)
@@ -379,62 +344,14 @@ class NemotronHBlock(nn.Module):
         return x + m, bias_term
 
 
-class NemotronH(nn.Module):
-    """``tokens [B, L] -> (logits or hidden, bias term)``; the bias term is
-    the sum over the expert layers of the zero-valued term whose gradient is
-    the load error (``models/afmoe.py``'s docstring)."""
+class NemotronH(Decoder):
+    """``tokens [B, L] -> (logits or hidden, the expert layers' bias terms
+    summed: ``models/afmoe.py``'s docstring)``."""
     config: NemotronHConfig
+    block = NemotronHBlock
+    final_norm = "norm_f"
+    kept = KEPT
 
-    @nn.compact
-    def __call__(self, tokens, return_hidden: bool = False):
-        cfg = self.config
-        x = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=jnp.float32,
-                     param_dtype=jnp.float32, embedding_init=_INIT,
-                     name="embed")(tokens)
-        block = NemotronHBlock
-        if cfg.remat and not self.is_initializing():
-            block = nn.remat(NemotronHBlock, policy=_keeping(KEPT))
-            telemetry.gauge("remat.layers").set(cfg.n_layers)
-        bias_term = jnp.zeros((), jnp.float32)
-        for i, kind in enumerate(cfg.pattern):
-            x, term = block(cfg, kind, cfg.exact_first_layer and i == 0,
-                            name=f"block_{i}")(x)
-            bias_term = bias_term + term
-        x = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm_f")(x)
-        if return_hidden:
-            # The fused-head loss owns the projection; the head's parameters
-            # exist from init, which runs the path below.
-            return x, bias_term
-        return _in_proj(cfg.vocab_size, cfg, "lm_head")(x), bias_term
-
-
-def make_loss_fn(model: NemotronH) -> Callable:
-    """Mean next-token cross-entropy (+ the expert layers' bias terms, zero in
-    value); batch = ``{"tokens": int32 [B, L+1]}``."""
-    cfg = model.config
-
-    def loss_fn(params, batch):
-        tokens = batch["tokens"]
-        inputs, targets = tokens[:, :-1], tokens[:, 1:]
-        if cfg.fused_head:
-            from autodist_tpu.models.common import fused_lm_head_nll
-            h, bias_term = model.apply({"params": params}, inputs,
-                                       return_hidden=True)
-            nll = fused_lm_head_nll(h, params, targets)
-        else:
-            logits, bias_term = model.apply({"params": params}, inputs)
-            logprobs = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-            nll = -jnp.take_along_axis(logprobs, targets[..., None],
-                                       axis=-1)[..., 0]
-        return nll.mean() + bias_term
-
-    return loss_fn
-
-
-def init_params(config: NemotronHConfig, rng: Optional[jax.Array] = None,
-                batch_size: int = 2):
-    from autodist_tpu.models.common import jit_init
-    rng = rng if rng is not None else jax.random.PRNGKey(0)
-    model = NemotronH(config)
-    tokens = jnp.zeros((batch_size, min(8, config.max_len)), jnp.int32)
-    return model, jit_init(model, tokens, rng=rng)
+    def layers(self):
+        return [(kind, self.config.exact_first_layer and i == 0)
+                for i, kind in enumerate(self.config.pattern)]

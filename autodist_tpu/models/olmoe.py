@@ -18,21 +18,23 @@ untied head. No position table, no bias anywhere.
 
 The layers live where the next models find them: ``RMSNorm`` and ``rope`` in
 ``models/common.py``, the routing and the routed FFN in ``models/moe.py``, the
-grouped products in ``ops/grouped_matmul.py``. Parameters and the residual
+grouped products in ``ops/grouped_matmul.py``, the stack, the loss and the
+init in ``models/decoder.py``. Parameters and the residual
 stream ``x`` are float32; the sublayers compute in ``dtype`` (their norms read
 the float32 stream) and the router reads the float32 normalised ``h``, so
 that fewer top-8 choices hang on a rounding (PERF.md §6, PR 25).
 """
 
 import dataclasses
-from typing import Any, Callable, Optional
+from typing import Any
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
 from autodist_tpu.models.common import RMSNorm, rope
-from autodist_tpu.models.moe import RoutedFFN
+from autodist_tpu.models.decoder import Decoder, init_params, make_loss_fn  # noqa: F401
+from autodist_tpu.models.moe import RoutedFFN, _dense
 from autodist_tpu.models.transformer_lm import (  # noqa: F401 — synthetic_batch re-exported
     causal_mask, dot_product_attention, synthetic_batch)
 
@@ -78,10 +80,7 @@ class QKNormAttention(nn.Module):
         cfg = self.config
         b, length, _ = x.shape
         head_dim = cfg.d_model // cfg.n_heads
-        dense = lambda name: nn.Dense(  # noqa: E731
-            cfg.d_model, use_bias=False, dtype=cfg.dtype,
-            param_dtype=jnp.float32, kernel_init=nn.initializers.normal(0.02),
-            name=name)
+        dense = lambda name: _dense(cfg.d_model, cfg.dtype, name)  # noqa: E731
         q = RMSNorm(cfg.rms_eps, cfg.dtype, name="q_norm")(dense("query")(x))
         k = RMSNorm(cfg.rms_eps, cfg.dtype, name="k_norm")(dense("key")(x))
         v = dense("value")(x)
@@ -113,63 +112,20 @@ class OlmoeBlock(nn.Module):
         return x + y, aux
 
 
-class Olmoe(nn.Module):
+class Olmoe(Decoder):
     """``tokens [B, L] -> (logits or hidden, aux)``; ``aux`` holds the two
     router losses, each the mean over the layers."""
     config: OlmoeConfig
+    block = OlmoeBlock
 
-    @nn.compact
     def __call__(self, tokens, return_hidden: bool = False):
+        out, aux = super().__call__(tokens, return_hidden)
+        return out, jax.tree_util.tree_map(
+            lambda a: a / self.config.n_layers, aux)
+
+    def loss(self, nll, aux):
+        """+ ``load_balance_weight`` x the load-balancing loss +
+        ``router_z_weight`` x the router z-loss."""
         cfg = self.config
-        x = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=jnp.float32,
-                     param_dtype=jnp.float32,
-                     embedding_init=nn.initializers.normal(0.02),
-                     name="embed")(tokens)
-        auxes = []
-        for i in range(cfg.n_layers):
-            x, aux = OlmoeBlock(cfg, name=f"block_{i}")(x)
-            auxes.append(aux)
-        aux = jax.tree_util.tree_map(lambda *a: sum(a) / cfg.n_layers, *auxes)
-        x = RMSNorm(cfg.rms_eps, cfg.dtype, name="ln_f")(x)
-        if return_hidden:
-            # The fused-head loss owns the projection; the head's parameters
-            # exist from init, which runs the path below.
-            return x, aux
-        logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
-                          param_dtype=jnp.float32,
-                          kernel_init=nn.initializers.normal(0.02),
-                          name="lm_head")(x)
-        return logits, aux
-
-
-def make_loss_fn(model: Olmoe) -> Callable:
-    """Mean next-token cross-entropy + ``load_balance_weight`` x the
-    load-balancing loss + ``router_z_weight`` x the router z-loss; batch =
-    ``{"tokens": int32 [B, L+1]}``."""
-    cfg = model.config
-
-    def loss_fn(params, batch):
-        tokens = batch["tokens"]
-        inputs, targets = tokens[:, :-1], tokens[:, 1:]
-        if cfg.fused_head:
-            from autodist_tpu.models.common import fused_lm_head_nll
-            h, aux = model.apply({"params": params}, inputs, return_hidden=True)
-            nll = fused_lm_head_nll(h, params, targets)
-        else:
-            logits, aux = model.apply({"params": params}, inputs)
-            logprobs = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-            nll = -jnp.take_along_axis(logprobs, targets[..., None],
-                                       axis=-1)[..., 0]
-        return (nll.mean() + cfg.load_balance_weight * aux["load_balance"]
+        return (nll + cfg.load_balance_weight * aux["load_balance"]
                 + cfg.router_z_weight * aux["router_z"])
-
-    return loss_fn
-
-
-def init_params(config: OlmoeConfig, rng: Optional[jax.Array] = None,
-                batch_size: int = 2):
-    from autodist_tpu.models.common import jit_init
-    rng = rng if rng is not None else jax.random.PRNGKey(0)
-    model = Olmoe(config)
-    tokens = jnp.zeros((batch_size, min(8, config.max_len)), jnp.int32)
-    return model, jit_init(model, tokens, rng=rng)

@@ -31,6 +31,11 @@ TINY = dict(vocab_size=256, d_model=64, n_heads=4, n_kv_heads=2, head_dim=16,
             experts_held=2, first_expert_held=2, top_k=2, window=8, max_len=64)
 
 
+def _share(cfg):
+    """The expert layer's module as ``afmoe.AfmoeBlock`` builds it."""
+    return afmoe.RoutedShare(cfg, cfg.d_expert * cfg.n_shared_experts)
+
+
 def _rel_l2(a, b):
     leaves = lambda t: jax.tree_util.tree_leaves(t)  # noqa: E731
     num = sum(float(jnp.sum(jnp.square(x - y))) for x, y in zip(leaves(a), leaves(b)))
@@ -153,7 +158,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
     cfg = afmoe.AfmoeConfig(dtype=jnp.float32, **dict(
         TINY, experts_held=8, first_expert_held=0))
     d, tokens = cfg.d_model, 40
-    whole = afmoe.SharedAndRoutedExperts(cfg).init(
+    whole = _share(cfg).init(
         jax.random.PRNGKey(2), jnp.zeros((1, 4, d)))["params"]
     whole = _with_bias(whole, scale=0.2)
     h = jax.random.normal(jax.random.PRNGKey(3), (1, tokens, d))
@@ -164,7 +169,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
             TINY, experts_held=2, first_expert_held=first))
         params = dict(whole, **{name: whole[name][first:first + 2]
                                 for name in ("gate", "up", "down")})
-        out, _ = afmoe.SharedAndRoutedExperts(share_cfg).apply(
+        out, _ = _share(share_cfg).apply(
             {"params": params}, h)
         total = total + out
     with jax.default_matmul_precision("highest"):
@@ -178,7 +183,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
                                shared + routed.reshape(1, tokens, d),
                                rtol=1e-4, atol=1e-5)
     # and the whole bank in one layer is the same uncut result
-    uncut, _ = afmoe.SharedAndRoutedExperts(cfg).apply({"params": whole}, h)
+    uncut, _ = _share(cfg).apply({"params": whole}, h)
     np.testing.assert_allclose(uncut, shared + routed.reshape(1, tokens, d),
                                rtol=1e-4, atol=1e-5)
 
@@ -435,7 +440,7 @@ def test_the_layer_sows_the_passes_its_held_rows_took(routing):
     bias = _bias_on(chosen)
     cfg = afmoe.AfmoeConfig(dtype=jnp.float32, **dict(
         TINY, first_expert_held=4, rows_bound=bound))
-    layer = afmoe.SharedAndRoutedExperts(cfg)
+    layer = _share(cfg)
     params = layer.init(jax.random.PRNGKey(2), jnp.zeros((1, 4, 64)))["params"]
     h = jax.random.normal(jax.random.PRNGKey(3), (1, 24, 64))
     _, sown = layer.apply({"params": dict(params, expert_bias=bias)}, h,

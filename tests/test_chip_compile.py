@@ -438,7 +438,7 @@ def test_a_checkpointed_nemotron_layer_launches_each_kept_forward_kernel_once(
     compiled gradient launches those forward kernels once (the convolution's,
     whose output is not on the list, twice); under a bare ``jax.checkpoint``
     (the parent's) it launches each twice."""
-    from autodist_tpu.models import nemotron_h
+    from autodist_tpu.models import common, nemotron_h
     cfg = nemotron_h.NemotronHConfig(attention_impl="flash", ssm_impl="pallas")
     block = nemotron_h.NemotronHBlock(cfg, kind, exact)
     x = ((1, 8192, cfg.d_model), jnp.float32)
@@ -458,7 +458,7 @@ def test_a_checkpointed_nemotron_layer_launches_each_kept_forward_kernel_once(
             params, jax.ShapeDtypeStruct(*x, sharding=chip)).compile().as_text()
         return {k: _kernel_launches(text, k) for k in kept}
 
-    assert launches(nemotron_h._keeping(nemotron_h.KEPT)) == kept
+    assert launches(common.keeping(nemotron_h.KEPT)) == kept
     assert launches(None) == dict(kept, **bare)
 
 

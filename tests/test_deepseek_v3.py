@@ -20,7 +20,8 @@ import numpy as np
 import pytest
 
 from autodist_tpu import AutoDist, telemetry, train
-from autodist_tpu.models import afmoe, deepseek_v3, lfm2_moe, moe, nemotron_h
+from autodist_tpu.models import (afmoe, decoder, deepseek_v3, lfm2_moe, moe,
+                                 nemotron_h, olmoe)
 from autodist_tpu.models.common import keeping, rope, rope_pairs
 from autodist_tpu.strategy import AllReduce
 
@@ -301,8 +302,9 @@ def test_a_checkpointed_layer_keeps_the_listed_values_and_nothing_else(
 
 
 def test_the_four_families_share_the_mixtures_code_and_none_copies_it():
-    for name in ("sigmoid_routed_share", "balance_expert_bias", "expert_loads",
-                 "sown_loads", "sigmoid_topk_route"):
+    # the expert layer's module is one class, and so are the share's checks
+    for name in ("RoutedShare", "check_share", "balance_expert_bias",
+                 "expert_loads", "sown_loads"):
         assert getattr(deepseek_v3, name) is getattr(nemotron_h, name) \
             is getattr(afmoe, name) is getattr(lfm2_moe, name) \
             is getattr(moe, name)
@@ -313,11 +315,32 @@ def test_the_four_families_share_the_mixtures_code_and_none_copies_it():
         source = f.read()
     assert "def balance(" not in source and "routed_experts(" not in source
     assert "gmm(" not in source and "pallas_call" not in source
-    # the checkpoint's policy is one definition too
-    assert nemotron_h._keeping is keeping
+    # the checkpoint's policy is one definition too, and the shell's to apply
+    assert decoder.keeping is keeping
+    # the stack, the loss and the init are the shell's (models/decoder.py):
+    # one object each under the five families' names, and no family embeds,
+    # wraps its layers in a checkpoint, writes a next-token loss or wraps the
+    # share in a module of its own
+    families = (olmoe, afmoe, lfm2_moe, nemotron_h, deepseek_v3)
+    for family in families:
+        assert family.make_loss_fn is decoder.make_loss_fn
+        assert family.init_params is decoder.init_params
+        with open(family.__file__) as f:
+            source = f.read()
+        for copied in ("nn.Embed(", "nn.remat(", "log_softmax", "fused_lm_head_nll",
+                       "jit_init", "sigmoid_topk_route"):
+            assert copied not in source, (family.__name__, copied)
+    models = (olmoe.Olmoe, afmoe.Afmoe, lfm2_moe.Lfm2Moe, nemotron_h.NemotronH,
+              deepseek_v3.DeepseekV3)
+    assert all(issubclass(model, decoder.Decoder) for model in models)
+    assert [model.final_norm for model in models] == [
+        "ln_f", "ln_f", "embedding_norm", "norm_f", "ln_f"]
+    assert [model.tied for model in models] == [False, False, True, False, False]
+    assert nemotron_h.NemotronH.kept is nemotron_h.KEPT
+    assert deepseek_v3.DeepseekV3.kept is deepseek_v3.KEPT
     # the expert's form is an argument of the shared code, traced under its gauge
     cfg = deepseek_v3.DeepseekV3Config(dtype=jnp.float32, **TINY)
-    layer = deepseek_v3.SharedAndRoutedExperts(cfg)
+    layer = deepseek_v3.RoutedShare(cfg, cfg.d_expert * cfg.n_shared_experts)
     h = jnp.zeros((1, 8, 64))
     params = layer.init(jax.random.PRNGKey(0), h)["params"]
     layer.apply({"params": params}, h)

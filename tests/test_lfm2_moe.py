@@ -166,7 +166,7 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
     cfg = lfm2_moe.Lfm2MoeConfig(dtype=jnp.float32, **dict(
         wide, experts_held=64, first_expert_held=0))
     d, tokens = cfg.d_model, 40
-    whole = lfm2_moe.RoutedExperts(cfg).init(
+    whole = lfm2_moe.RoutedShare(cfg).init(
         jax.random.PRNGKey(2), jnp.zeros((1, 4, d)))["params"]
     whole = _with_bias(whole, scale=0.2)
     h = jax.random.normal(jax.random.PRNGKey(3), (1, tokens, d))
@@ -177,7 +177,7 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
             wide, experts_held=8, first_expert_held=first, rows_bound=24))
         params = dict(whole, **{name: whole[name][first:first + 8]
                                 for name in ("gate", "up", "down")})
-        (out, _), sown = lfm2_moe.RoutedExperts(share_cfg).apply(
+        (out, _), sown = lfm2_moe.RoutedShare(share_cfg).apply(
             {"params": params}, h, mutable=["intermediates"])
         total = total + out
         loads.append(sown["intermediates"]["load"][0])
@@ -193,7 +193,7 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
         np.testing.assert_array_equal(load, loads[0])
     assert float(loads[0].sum()) == tokens * 4
     # and the whole bank in one layer is the same uncut result
-    uncut, _ = lfm2_moe.RoutedExperts(cfg).apply({"params": whole}, h)
+    uncut, _ = lfm2_moe.RoutedShare(cfg).apply({"params": whole}, h)
     np.testing.assert_allclose(uncut, routed.reshape(1, tokens, d),
                                rtol=1e-4, atol=1e-5)
 
@@ -206,7 +206,7 @@ def test_a_layer_whose_held_rows_take_three_passes_equals_the_one_pass_layer():
     and the layer sows the three."""
     wide = dict(TINY, d_model=32, d_expert=16, n_experts_routed=64, top_k=4,
                 experts_held=8, first_expert_held=8)
-    layers = {bound: lfm2_moe.RoutedExperts(lfm2_moe.Lfm2MoeConfig(
+    layers = {bound: lfm2_moe.RoutedShare(lfm2_moe.Lfm2MoeConfig(
         dtype=jnp.float32, rows_bound=bound, **wide)) for bound in (None, 48)}
     params = layers[None].init(jax.random.PRNGKey(2),
                                jnp.zeros((1, 4, 32)))["params"]
@@ -236,8 +236,8 @@ def test_a_layer_whose_held_rows_take_three_passes_equals_the_one_pass_layer():
 
 
 def test_the_two_families_share_the_mixtures_code_and_neither_copies_it():
-    for name in ("GatedMLP", "sigmoid_routed_share", "balance_expert_bias",
-                 "expert_loads", "sown_loads", "sigmoid_topk_route"):
+    for name in ("GatedMLP", "RoutedShare", "check_share", "balance_expert_bias",
+                 "expert_loads", "sown_loads"):
         assert getattr(afmoe, name) is getattr(lfm2_moe, name) is getattr(moe, name)
     assert afmoe.make_optimizer is lfm2_moe.make_optimizer is moe.balanced_optimizer
     for module in (afmoe, lfm2_moe):

@@ -21,7 +21,7 @@ import numpy as np
 import pytest
 
 from autodist_tpu import AutoDist, telemetry, train
-from autodist_tpu.models import afmoe, lfm2_moe, moe, nemotron_h
+from autodist_tpu.models import afmoe, common, lfm2_moe, moe, nemotron_h
 from autodist_tpu.strategy import AllReduce
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -36,6 +36,11 @@ TINY = dict(vocab_size=256, d_model=64, pattern="MEMEM*EME", mamba_heads=4,
             n_heads=4, n_kv_heads=2, head_dim=16, d_expert=24, d_shared=40,
             n_experts_routed=8, experts_held=3, first_expert_held=2, top_k=3,
             max_len=64)
+
+
+def _share(cfg):
+    """The expert mixer's module as ``nemotron_h.NemotronHBlock`` builds it."""
+    return nemotron_h.RoutedShare(cfg, cfg.d_shared, "relu2")
 
 
 def _rel_l2(a, b):
@@ -194,7 +199,7 @@ def test_the_sixteen_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer
     cfg = nemotron_h.NemotronHConfig(dtype=jnp.float32, **dict(
         wide, experts_held=128, first_expert_held=0))
     d, tokens = cfg.d_model, 40
-    whole = nemotron_h.SharedAndRoutedExperts(cfg).init(
+    whole = _share(cfg).init(
         jax.random.PRNGKey(2), jnp.zeros((1, 4, d)))["params"]
     whole = _stirred(whole)
     h = jax.random.normal(jax.random.PRNGKey(3), (1, tokens, d))
@@ -207,7 +212,7 @@ def test_the_sixteen_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer
             wide, experts_held=8, first_expert_held=first, rows_bound=24))
         params = dict(whole, **{name: whole[name][first:first + 8]
                                 for name in ("up", "down")})
-        (out, _), sown = nemotron_h.SharedAndRoutedExperts(share_cfg).apply(
+        (out, _), sown = _share(share_cfg).apply(
             {"params": params}, h, mutable=["intermediates"])
         routed_total = routed_total + (out - shared)
         loads.append(sown["intermediates"]["load"][0])
@@ -223,7 +228,7 @@ def test_the_sixteen_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer
         np.testing.assert_array_equal(load, loads[0])
     assert float(loads[0].sum()) == tokens * 6
     # and the whole bank in one layer is the same uncut result
-    one, _ = nemotron_h.SharedAndRoutedExperts(cfg).apply({"params": whole}, h)
+    one, _ = _share(cfg).apply({"params": whole}, h)
     np.testing.assert_allclose(one, uncut.reshape(1, tokens, d),
                                rtol=1e-4, atol=1e-5)
 
@@ -364,7 +369,7 @@ def test_a_checkpointed_layer_keeps_the_listed_values_and_nothing_else(kind):
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 40, cfg.d_model))
     params = block.init(jax.random.PRNGKey(1), x)["params"]
 
-    @functools.partial(jax.checkpoint, policy=nemotron_h._keeping(nemotron_h.KEPT))
+    @functools.partial(jax.checkpoint, policy=common.keeping(nemotron_h.KEPT))
     def layer(params, x):
         y, term = block.apply({"params": params}, x)
         return jnp.sum(jnp.square(y)) + term
@@ -378,8 +383,8 @@ def test_a_checkpointed_layer_keeps_the_listed_values_and_nothing_else(kind):
 
 
 def test_the_three_families_share_the_mixtures_code_and_none_copies_it():
-    for name in ("sigmoid_routed_share", "balance_expert_bias", "expert_loads",
-                 "sown_loads", "sigmoid_topk_route"):
+    for name in ("RoutedShare", "check_share", "balance_expert_bias",
+                 "expert_loads", "sown_loads"):
         assert getattr(nemotron_h, name) is getattr(afmoe, name) \
             is getattr(lfm2_moe, name) is getattr(moe, name)
     assert nemotron_h.make_optimizer is afmoe.make_optimizer \
@@ -391,7 +396,7 @@ def test_the_three_families_share_the_mixtures_code_and_none_copies_it():
     assert "gmm(" not in source and "pallas_call" not in source
     # the expert's form is an argument of the shared code, traced under its gauge
     cfg = nemotron_h.NemotronHConfig(dtype=jnp.float32, **TINY)
-    layer = nemotron_h.SharedAndRoutedExperts(cfg)
+    layer = _share(cfg)
     h = jnp.zeros((1, 8, 64))
     params = layer.init(jax.random.PRNGKey(0), h)["params"]
     layer.apply({"params": params}, h)

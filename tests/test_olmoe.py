@@ -86,6 +86,43 @@ def test_the_loss_is_bit_for_bit_the_parents(dtype, attention, fused, parent_los
     assert float(loss).hex() == parent_loss
 
 
+# PR 42 put one decoder shell (``models/decoder.py``: the stack, the loss, the
+# init) under the five dropless families and one share module under the four
+# sigmoid-routed ones (``models/moe.py`` ``RoutedShare``). Their programs are
+# what they were: each family's loss at its own test file's tiny size is the
+# parent's (commit 8dd3c63) bit for bit, on this backend, plain in float32 and
+# in bfloat16 with every kernel and option its cell runs.
+_KERNELS = dict(dtype=jnp.bfloat16, attention_impl="flash", fused_head=True)
+_SHELLED = {
+    "afmoe": ("AfmoeConfig", {}, "0x1.644fe60000000p+2", "0x1.644b2a0000000p+2"),
+    "lfm2_moe": ("Lfm2MoeConfig", dict(conv_impl="pallas"),
+                 "0x1.675ee60000000p+2", "0x1.675f0c0000000p+2"),
+    "nemotron_h": ("NemotronHConfig",
+                   dict(ssm_impl="pallas", remat=True, exact_first_layer=True),
+                   "0x1.63c8680000000p+2", "0x1.63c91c0000000p+2"),
+    "deepseek_v3": ("DeepseekV3Config", dict(remat=True),
+                    "0x1.6514920000000p+2", "0x1.6513f40000000p+2"),
+}
+
+
+@pytest.mark.parametrize("setting", ["f32-xla", "bf16-kernels"])
+@pytest.mark.parametrize("family", list(_SHELLED))
+def test_the_shelled_families_losses_are_bit_for_bit_the_parents(family, setting):
+    import importlib
+    module = importlib.import_module(f"autodist_tpu.models.{family}")
+    tiny = importlib.import_module(f"test_{family}").TINY
+    config, cells_own, plain_loss, kernels_loss = _SHELLED[family]
+    options, parent_loss = {
+        "f32-xla": (dict(dtype=jnp.float32), plain_loss),
+        "bf16-kernels": (dict(_KERNELS, **cells_own), kernels_loss)}[setting]
+    cfg = getattr(module, config)(**dict(tiny, **options))
+    model, params = module.init_params(cfg, jax.random.PRNGKey(1))
+    batch = {"tokens": jnp.asarray(
+        module.synthetic_batch(cfg, 4, 32, seed=3)["tokens"])}
+    loss = jax.jit(module.make_loss_fn(model))(params, batch)
+    assert float(loss).hex() == parent_loss
+
+
 def test_topk_route_is_dropless():
     tokens, experts, k = 48, 8, 3
     probs = jax.nn.softmax(jax.random.normal(jax.random.PRNGKey(0),

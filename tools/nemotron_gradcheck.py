@@ -39,7 +39,7 @@ def variants(jnp):
         # one kind of mixer in float32 at the highest precision, the rest as
         # the cell has them (the mixer's class is swapped for the variant)
         "f32-mamba": ({"ssm_impl": "xla"}, None, "Mamba2"),
-        "f32-experts": ({}, None, "SharedAndRoutedExperts"),
+        "f32-experts": ({}, None, "RoutedShare"),
         "f32-attention": ({}, None, "GroupedAttention"),
         # the scan alone in float32 (its operands as the bfloat16 layer hands
         # them), and the Mamba-2 layer in float32 around a bfloat16 scan
@@ -73,9 +73,9 @@ def in_float32(nemotron_h, mixer: str):
     import jax.numpy as jnp
     original = getattr(nemotron_h, mixer)
 
-    def build(config, name):
+    def build(config, *arguments, name):
         module = original(dataclasses.replace(config, dtype=jnp.float32),
-                          name=name)
+                          *arguments, name=name)
 
         def call(h):
             with jax.default_matmul_precision("highest"):
