@@ -21,9 +21,9 @@ from autodist_tpu import telemetry
 KERNEL_NAMES = ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq", "flash_carry",
                 "xent_fwd", "xent_bwd_dh", "xent_bwd_dw",
                 "moe_gmm_fwd", "moe_gmm_bwd_dx", "moe_gmm_bwd_dw",
-                "short_conv_fwd", "short_conv_bwd",
-                "moe_rows_gather", "moe_rows_combine",
-                "ssd_fwd", "ssd_bwd", "conv_silu_fwd", "conv_silu_bwd")
+                "short_conv_fwd", "short_conv_bwd", "moe_rows_gather",
+                "moe_rows_combine", "ssd_fwd", "ssd_bwd", "conv_silu_fwd",
+                "conv_silu_bwd", "selective_scan_fwd", "selective_scan_bwd")
 
 
 def named_pallas_call(name: str, kernel, **kwargs):

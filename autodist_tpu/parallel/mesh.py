@@ -130,14 +130,93 @@ def per_device(fn, args: Sequence, batched: Sequence[bool]):
             if a not in manual and mesh.shape[a] > 1]
     if not auto:
         return fn(*args)
-    dp = tuple(a for a in DP_AXES if a in auto)
-    n_dp = int(np.prod([mesh.shape[a] for a in dp]))
+    dp, n_dp = _sized(mesh, tuple(a for a in DP_AXES if a in auto))
     rows = [x.shape[0] for x, b in zip(args, batched) if b]
     split = dp if n_dp > 1 and all(n % n_dp == 0 for n in rows) else None
+    fn, specs = _stored_operands(fn, args, batched, split, dp if n_dp > 1 else ())
     return jax.shard_map(
         fn, mesh=mesh, axis_names=frozenset(mesh.axis_names) - manual,
-        in_specs=tuple(P(split) if b else P() for b in batched),
+        in_specs=specs,
         out_specs=P(split), check_vma=False)(*args)
+
+
+# What follows stands BELOW ``per_device`` on purpose, imports and all: a
+# Mosaic kernel's serialized module carries the line numbers of the frames it
+# was called through, ``per_device``'s among them, so a line added above it
+# changes every cell's compiled step (and its compile-cache key) for nothing.
+
+import contextlib  # noqa: E402
+import contextvars  # noqa: E402
+
+# ``shape -> tensor axis`` of the leaves stored as shares over the data axes
+# (``ShardingPlan.data_shard_axes``), while a step that stores its state so is
+# traced; None otherwise.
+_STORED_SHARDS = contextvars.ContextVar("stored_shards", default=None)
+
+
+@contextlib.contextmanager
+def stored_shards(axes: Optional[Dict[tuple, int]]):
+    """While tracing inside it, :func:`per_device` hands a non-batched operand
+    of a shape in ``axes`` to the device as the share it stores (split along
+    ``axes[shape]`` over the data axes) and gathers it in the body, so that
+    its gradient leaves the body reduce-scattered onto the shares and not
+    all-reduced whole. ``axes`` None or empty: nothing changes."""
+    token = _STORED_SHARDS.set(axes or None)
+    try:
+        yield
+    finally:
+        _STORED_SHARDS.reset(token)
+
+
+def _sized(mesh, axes):
+    """``(axes, the product of their sizes)``."""
+    return axes, int(np.prod([mesh.shape[a] for a in axes]))
+
+
+def _stored_operands(fn, args: Sequence, batched: Sequence[bool], split, dp):
+    """``(the body, its in_specs)`` of :func:`per_device`'s ``shard_map``:
+    ``fn`` itself on operands that arrive split over the batch or whole, or,
+    inside :func:`stored_shards`, a body that first gathers along its stored
+    axis every operand that arrived as its share."""
+    from jax.sharding import PartitionSpec as P
+
+    stored = (_STORED_SHARDS.get() or {}) if dp else {}
+    # tensor axis along which operand i is stored as shares, or None
+    shares = [None if b else stored.get(getattr(x, "shape", None))
+              for x, b in zip(args, batched)]
+    specs = tuple(P(split) if b else
+                  P() if axis is None else P(*([None] * axis), dp)
+                  for b, axis in zip(batched, shares))
+    if all(axis is None for axis in shares):
+        return fn, specs
+
+    def body(*local):
+        return fn(*(x if axis is None else
+                    jax.lax.all_gather(x, dp, axis=axis, tiled=True)
+                    for x, axis in zip(local, shares)))
+    return body, specs
+
+
+def constrain_batch(x):
+    """``x`` held to the batch sharding (its leading dim split over the
+    data-parallel axes of the ambient mesh) where they divide it; ``x`` itself
+    with no mesh, one device, or inside a ``shard_map``. A model calls it at
+    its layers' edges: with parameters stored as shares over the data axis
+    (``strategy.FullySharded``) the partitioner, left to itself, may reshard
+    the activations (an all-to-all a product) where it should gather the
+    weights."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from autodist_tpu.parallel.plan import DP_AXES
+
+    mesh = ambient_mesh()
+    if mesh is None or getattr(mesh, "manual_axes", ()):
+        return x
+    dp, n_dp = _sized(mesh, tuple(a for a in DP_AXES if a in mesh.axis_names
+                                  and mesh.shape[a] > 1))
+    if n_dp == 1 or x.shape[0] % n_dp:
+        return x
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P(dp)))
 
 
 def data_axis_size(mesh: Mesh) -> int:

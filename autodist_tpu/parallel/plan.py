@@ -221,6 +221,37 @@ class ShardingPlan:
         return all(p.pspec == P() for p in self.params.values())
 
     @property
+    def data_sharded(self) -> Dict[str, ParamPlan]:
+        """The parameters stored as shares over a data-parallel axis
+        (``strategy.FullySharded``): every device is a data replica AND holds
+        ``1 / dp`` of the leaf, its gradient and its optimizer state."""
+        return {n: p for n, p in self.params.items()
+                if any(axis in DP_AXES for axis in _spec_axes(p.pspec))}
+
+    @property
+    def update_sharded(self) -> bool:
+        """The step constrains gradients, updates, optimizer state and
+        parameters to the plan's specs (the runner's ZeRO points): under
+        :meth:`with_zero_update`, and where parameters are themselves stored
+        as shares over a data axis. There ``opt_pspec`` is the parameter's own
+        spec, so the gradient is reduce-scattered onto the share, the update
+        runs on it and nothing is gathered at the end."""
+        return bool(self.zero or self.data_sharded)
+
+    def data_shard_axes(self) -> Dict[Tuple[int, ...], int]:
+        """``shape -> tensor axis`` of the data-sharded parameters: what
+        :func:`autodist_tpu.parallel.mesh.stored_shards` needs to hand a
+        kernel such a leaf as its share and gather it there."""
+        return {p.shape: p.partition_axis for p in self.data_sharded.values()}
+
+    def data_shard_bytes(self, model_spec: ModelSpec, dp: int) -> int:
+        """Bytes a device receives when every data-sharded parameter is
+        gathered once (``(dp - 1) / dp`` of each leaf), which is also what
+        it sends when each gradient is reduce-scattered once."""
+        full = sum(model_spec.params[n].byte_size for n in self.data_sharded)
+        return full * (dp - 1) // dp if dp > 1 else 0
+
+    @property
     def has_padding(self) -> bool:
         """True when any parameter uses padded storage (uneven partitioning)."""
         return any(p.padded_dim is not None for p in self.params.values())
@@ -348,6 +379,13 @@ class ShardingPlan:
     def __repr__(self):
         kinds = collections.Counter(p.sync for p in self.params.values())
         return f"ShardingPlan(mesh={dict(self.mesh_axes)}, {dict(kinds)})"
+
+
+def _spec_axes(pspec: P):
+    """The mesh axes a PartitionSpec names, tuples flattened."""
+    for entry in pspec or ():
+        if entry is not None:
+            yield from (entry if isinstance(entry, tuple) else (entry,))
 
 
 def _first_tiling_axis_pspec(shape, base_pspec: P, axis_token,

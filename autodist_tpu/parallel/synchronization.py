@@ -214,7 +214,7 @@ def make_grad_fn(sharding_plan: ShardingPlan, model_spec: ModelSpec, mesh: Mesh,
         return grads, loss, aux, ef_state
 
     if not use_explicit:
-        return implicit
+        return _with_stored_shards(sharding_plan, implicit)
 
     if not sharding_plan.all_params_replicated:
         if sharding_plan.has_compression:
@@ -227,7 +227,7 @@ def make_grad_fn(sharding_plan: ShardingPlan, model_spec: ModelSpec, mesh: Mesh,
         from autodist_tpu.utils import logging
         logging.info("Sparse all-gather wire disabled: model has partitioned "
                      "parameters; using implicit dense synchronization")
-        return implicit
+        return _with_stored_shards(sharding_plan, implicit)
 
     from autodist_tpu.model_spec import _path_name as name_of
     plans_by_name = dict(sharding_plan.params)
@@ -358,6 +358,24 @@ def make_grad_fn(sharding_plan: ShardingPlan, model_spec: ModelSpec, mesh: Mesh,
         return out
 
     return explicit
+
+
+def _with_stored_shards(sharding_plan: ShardingPlan, grad_fn: Callable):
+    """``grad_fn`` itself, or, where the plan stores parameters as shares over
+    a data axis (``strategy.FullySharded``), ``grad_fn`` traced inside
+    ``parallel.mesh.stored_shards``: a kernel under ``per_device`` then takes
+    such a leaf as its share and gathers it in its body. (Called at the
+    implicit lowering's two returns and defined down here: the lines above
+    are frames of every kernel's call.)"""
+    stored = sharding_plan.data_shard_axes()
+    if not stored:
+        return grad_fn
+    from autodist_tpu.parallel.mesh import stored_shards
+
+    def in_stored_shards(*args):
+        with stored_shards(stored):
+            return grad_fn(*args)
+    return in_stored_shards
 
 
 def _batch_leaf_by_name(batch: PyTree, leaf_name: str):

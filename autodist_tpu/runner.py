@@ -357,7 +357,7 @@ class DistributedRunner:
         accum = self._accum
         # ZeRO update sharding: constraint points for the jitted step. Captured
         # as (plan, mesh) statics so the body stays a pure function of state.
-        zero_plan = self.plan if self.plan.zero else None
+        zero_plan = self.plan if self.plan.update_sharded else None
         mesh = self.mesh
         # Health bundle: a TRACE-TIME static — the disabled program carries
         # nothing (an empty tuple output), the enabled one a few fused
@@ -929,6 +929,14 @@ class DistributedRunner:
         program with different shardings than the real run."""
         if self._state_shardings is not None:
             return
+        # State stored as shares over the data axis (strategy.FullySharded):
+        # what one gather of every such parameter brings a device, and one
+        # reduce-scatter of every such gradient takes from it, from the plan.
+        moved = self.plan.data_shard_bytes(
+            self._model_spec, synchronization.mesh_dp_size(self.mesh))
+        if moved:
+            telemetry.gauge("step.param_gather_bytes").set(moved)
+            telemetry.gauge("step.grad_scatter_bytes").set(moved)
         self._state_shardings = TrainState(
             step=NamedSharding(self.mesh, P()),
             params=self.plan.param_sharding_tree(self.mesh, state.params),

@@ -14,6 +14,7 @@ from autodist_tpu.strategy.partitioned_ps_strategy import PartitionedPS
 from autodist_tpu.strategy.uneven_partition_ps_strategy import UnevenPartitionedPS
 from autodist_tpu.strategy.all_reduce_strategy import AllReduce
 from autodist_tpu.strategy.partitioned_all_reduce_strategy import PartitionedAR
+from autodist_tpu.strategy.fully_sharded_strategy import FullySharded
 from autodist_tpu.strategy.random_axis_partition_all_reduce_strategy import RandomAxisPartitionAR
 from autodist_tpu.strategy.parallax_strategy import Parallax
 from autodist_tpu.strategy.expert_parallel_strategy import ExpertParallel
@@ -29,7 +30,7 @@ from autodist_tpu.strategy.autotune import (Candidate, TunedPlan, autotune,
 __all__ = [
     "Strategy", "StrategyBuilder", "StrategyCompiler",
     "PS", "PSLoadBalancing", "byte_size_load_fn", "PartitionedPS",
-    "UnevenPartitionedPS", "AllReduce", "PartitionedAR",
+    "UnevenPartitionedPS", "AllReduce", "PartitionedAR", "FullySharded",
     "RandomAxisPartitionAR", "Parallax", "ExpertParallel", "Pipeline",
     "SequenceParallel", "AutoStrategy", "tune_strategy", "TuneResult",
     "measure_candidate", "CandidateResult",

@@ -6,9 +6,16 @@ the uneven variant (``uneven_partition_ps_strategy.py:125-135``), which delibera
 exercises remainder handling (on TPU: pad-and-mask shards).
 """
 
-from typing import Optional, Tuple
+from typing import Optional, Sequence, Tuple
+
+import numpy as np
 
 from autodist_tpu.model_spec import ParamSpec
+
+# Elements from which ``FullySharded`` stores a leaf as shares (1 MiB of
+# float32): matrices are above it, norms, biases and a state-space layer's
+# ``[E, N]`` decay below.
+MIN_SHARDED_SIZE = 1 << 18
 
 
 def smallest_divisor_at_least_2(n: int, cap: Optional[int] = None) -> Optional[int]:
@@ -55,3 +62,16 @@ def partitionable_axis(spec: ParamSpec) -> Optional[int]:
 def make_num_shards(rank: int, axis: int, k: int) -> Tuple[int, ...]:
     """Per-axis shard counts with one active axis (reference partitioner str "k,1,..")."""
     return tuple(k if i == axis else 1 for i in range(max(rank, 1)))
+
+
+def data_shard_axis(shape: Sequence[int], dp: int) -> Optional[int]:
+    """The tensor axis along which a leaf of ``shape`` is stored as ``dp``
+    shares over the data axis, or None where it stays whole: the first axis
+    that ``dp`` divides, for a leaf of ``MIN_SHARDED_SIZE`` elements or more. A
+    function of the shape alone, so that whoever lays parameters out before
+    the plan exists (a caller that cannot hold them whole) lays them out as
+    the plan will."""
+    if dp <= 1 or not shape or int(np.prod(shape)) < MIN_SHARDED_SIZE:
+        return None
+    return next((axis for axis, dim in enumerate(shape)
+                 if dim > 0 and dim % dp == 0), None)

@@ -470,7 +470,8 @@ def test_kernel_names_unchanged():
         "moe_gmm_fwd", "moe_gmm_bwd_dx", "moe_gmm_bwd_dw",
         "short_conv_fwd", "short_conv_bwd",
         "moe_rows_gather", "moe_rows_combine",
-        "ssd_fwd", "ssd_bwd", "conv_silu_fwd", "conv_silu_bwd")
+        "ssd_fwd", "ssd_bwd", "conv_silu_fwd", "conv_silu_bwd",
+        "selective_scan_fwd", "selective_scan_bwd")
 
 
 @_NEEDS_MESH

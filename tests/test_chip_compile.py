@@ -12,11 +12,13 @@ pass here is not a chip run. Skipped where the topology cannot be described
 
 import importlib
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.experimental import topologies
 from jax.experimental.compilation_cache import compilation_cache
@@ -616,3 +618,116 @@ def test_kernels_compile_sharded_over_four_chips(topology, kernel):
     assert "tpu_custom_call" in text
     if kernel == "fused_xent":   # the table's gradient crosses devices
         assert "all-reduce" in text or "reduce-scatter" in text
+
+
+@pytest.mark.parametrize("length,chunks", [(16384, 128), (1000, 8)],
+                         ids=["cell-1x16384", "ragged-L1000"])
+def test_selective_scan_fwd_bwd_compiles_at_the_jamba_cell_shape(chip, length,
+                                                                 chunks):
+    """jamba2-sharded4-16k's call, a chip's share: one sequence of 5,120
+    channels and 16 states, chunks of 128, bfloat16 ``x`` and float32 ``dt``,
+    ``B``, ``C``: the forward and the backward kernel, each a Mosaic call
+    under its own name, through the operator's custom VJP (``B`` and ``C`` as
+    SMEM scalars, a bfloat16 block of 8 x 128 a token, 26 MiB of scoped VMEM
+    in the backward); a length the chunk does not divide is padded."""
+    from autodist_tpu.ops.selective_scan import selective_scan
+
+    def loss(x, dt, a, b, c, d):
+        return selective_scan(x, dt, a, b, c, d, chunk=128,
+                              impl="pallas").astype(jnp.float32).sum()
+
+    wide, narrow = (1, length, 5120), (1, length, 16)
+    text = _compiled_text(jax.value_and_grad(loss, argnums=tuple(range(6))), chip,
+                          (wide, jnp.bfloat16), (wide, jnp.float32),
+                          ((5120, 16), jnp.float32), (narrow, jnp.float32),
+                          (narrow, jnp.float32), ((5120,), jnp.float32))
+    assert "tpu_custom_call" in text
+    assert "selective_scan_fwd" in text and "selective_scan_bwd" in text
+    assert f"f32[1,{chunks},16,40,128]" in text      # one [E, N] state a chunk
+    assert f"f32[1,{chunks * 128},5120,16]" not in text     # never one a token
+
+
+def _whole_all_reduces(text: str, min_elements: int):
+    """The all-reduces of ``min_elements`` or more whose result is used
+    otherwise than by a ``dynamic-slice``: a reduction that leaves the leaf
+    whole on every chip. (This compiler spells a reduce-scatter as an
+    all-reduce of the padded leaf with the share sliced out.)"""
+    lines = text.splitlines()
+    whole = []
+    for line in lines:
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (.*?) all-reduce(?:-start)?\(", line)
+        if not m:
+            continue
+        sizes = [int(np.prod([int(dim) for dim in dims.split(",") if dim]))
+                 for dims in re.findall(r"[a-z0-9]+\[([0-9,]*)\]", m.group(2))]
+        if max(sizes) < min_elements:
+            continue
+        users = [u for u in lines if re.search(
+            r"[(, ]%" + re.escape(m.group(1)) + r"[,)]", u)]
+        # several leaves reduced as one tuple are not sliced either
+        if len(sizes) > 1 or not users \
+                or not all(" dynamic-slice(" in u for u in users):
+            whole.append((m.group(1), max(sizes)))
+    return whole
+
+
+def test_fully_sharded_step_gathers_weights_and_scatters_gradients(topology):
+    """A two-matrix step under ``strategy.FullySharded`` compiled for the four
+    described chips: every large leaf arrives as a quarter, the step gathers
+    the weights (as bfloat16: the cast moves before the gather) and no
+    all-reduce leaves a large gradient whole; under ``AllReduce`` the same
+    step all-reduces both whole."""
+    import optax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from autodist_tpu import ResourceSpec
+    from autodist_tpu.model_spec import ModelSpec
+    from autodist_tpu.parallel.mesh import build_mesh, constrain_batch
+    from autodist_tpu.parallel.plan import ShardingPlan
+    from autodist_tpu.runner import DistributedRunner
+    from autodist_tpu.strategy import AllReduce, FullySharded
+
+    d, f, rows = 1024, 2048, 4096
+
+    def loss(p, b):
+        h = constrain_batch(b["x"]).astype(jnp.bfloat16)
+        a = jnp.tanh(h @ p["up"].astype(jnp.bfloat16))
+        out = constrain_batch((a @ p["down"].astype(jnp.bfloat16)))
+        return jnp.mean(jnp.square(out.astype(jnp.float32))) + jnp.sum(p["bias"])
+
+    params = {"up": jax.ShapeDtypeStruct((d, f), jnp.float32),
+              "down": jax.ShapeDtypeStruct((f, d), jnp.float32),
+              "bias": jax.ShapeDtypeStruct((d,), jnp.float32)}
+    batch = {"x": np.zeros((rows, d), np.float32)}
+    spec = ResourceSpec(resource_info={
+        "nodes": [{"address": "localhost", "tpus": 4, "chief": True}],
+        "mesh": {"data": 4}})
+    mesh = build_mesh(axes={"data": 4}, devices=list(topology.devices)[:4])
+
+    def compiled_text(builder):
+        model_spec = ModelSpec.from_loss_fn(loss, params, batch)
+        strategy = builder.build(model_spec, spec)
+        runner = DistributedRunner(
+            strategy, model_spec, loss, optax.adamw(1e-3), mesh=mesh,
+            plan=ShardingPlan.from_strategy(strategy, model_spec))
+        state = runner._abstract_state(params)
+        runner._ensure_state_shardings(state)
+        state = jax.tree_util.tree_map(
+            lambda leaf, sharding: jax.ShapeDtypeStruct(
+                leaf.shape, leaf.dtype, sharding=sharding),
+            state, runner._state_shardings)
+        x = jax.ShapeDtypeStruct((rows, d), jnp.float32,
+                                 sharding=NamedSharding(mesh, P("data")))
+        with mesh:
+            return runner, runner._build_step(None).lower(
+                state, {"x": x}).compile().as_text()
+
+    runner, text = compiled_text(FullySharded())
+    assert runner._state_shardings.params["up"].spec == P("data", None)
+    assert runner._state_shardings.opt_state[0].mu["down"].spec == P("data", None)
+    assert runner._state_shardings.params["bias"].spec == P()
+    assert "all-gather(" in text and " all-to-all(" not in text
+    assert _whole_all_reduces(text, d * f) == []
+    _, replicated = compiled_text(AllReduce())
+    assert len(_whole_all_reduces(replicated, d * f)) >= 1
+    assert "all-gather(" not in replicated

@@ -86,7 +86,7 @@ def test_lowered_step_names_kernels_and_phases(backward, monkeypatch):
     step_kernels = [k for k in named_call.KERNEL_NAMES
                     if k != "flash_carry"
                     and not k.startswith(("moe_", "short_conv_", "ssd_",
-                                          "conv_silu_"))
+                                          "conv_silu_", "selective_scan_"))
                     and (k != "flash_bwd_dq" or backward == "split")]
     assert _scopes(text, named_call.KERNEL_NAMES) == set(step_kernels)
     # ZeRO's constrain_update is the reduction under AllReduce (the implicit
@@ -281,6 +281,65 @@ def test_ssd_and_expert_form_gauges_are_set_when_the_step_is_traced():
     assert telemetry.gauge("moe.expert_form").value == 2        # relu2: up, down
     _olmoe_step_lowered()
     assert telemetry.gauge("moe.expert_form").value == 3        # gate, up, down
+
+
+JAMBA_SCOPES = ("jamba.mamba", "jamba.attention", "jamba.mlp", "ssm.in_proj",
+                "ssm.conv", "ssm.x_proj", "ssm.scan", "ssm.out_proj")
+
+
+@functools.lru_cache(maxsize=1)
+def _jamba_step_text() -> str:       # one lowering for the cases below
+    """The tiny Jamba step (a Mamba-1 layer, the attention layer, every layer
+    recomputed, the tied fused head) through ``AutoDist`` under
+    ``FullySharded`` on the 8-device mesh: 1,024 channels, the narrowest the
+    scan's kernels take."""
+    from autodist_tpu.models import jamba
+    from autodist_tpu.strategy import FullySharded
+    cfg = jamba.JambaConfig(
+        vocab_size=256, d_model=512, n_layers=2, attn_period=2, attn_offset=1,
+        d_state=16, dt_rank=8, n_heads=4, n_kv_heads=1, d_ff=64, max_len=64,
+        dtype=jnp.float32, chunk=64, attention_impl="flash", ssm_impl="pallas",
+        fused_head=True, remat=True)
+    model, params = jamba.init_params(cfg, rng=jax.random.PRNGKey(0))
+    batch = jamba.synthetic_batch(cfg, batch_size=8, seq_len=64)
+    import optax
+    runner = AutoDist(strategy_builder=FullySharded()) \
+        .create_distributed_session(jamba.make_loss_fn(model), params,
+                                    optax.adamw(1e-3), example_batch=batch)
+    state = runner.init(params)
+    with runner.mesh:
+        return runner._build_step(None).lower(
+            state, runner.shard_batch(batch)).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("name", [k for k in named_call.KERNEL_NAMES
+                                  if k.startswith("selective_scan_")]
+                         + ["conv_silu_fwd", "conv_silu_bwd", "flash_fwd",
+                            "xent_fwd", "step.grad_sync"] + list(JAMBA_SCOPES))
+def test_jamba_step_names_its_scan_kernels_and_scopes(name):
+    """The selective scan's two kernels by their device names
+    (``pallas:selective_scan_fwd`` / ``pallas:selective_scan_bwd`` in a
+    trace), the convolution's before them, the three spans of a Jamba layer
+    and the five scopes of its Mamba-1 mixer, beside the kernels the step
+    shares with the other families; with the state stored as shares the
+    gradient's landing on them sits under ``step.grad_sync``."""
+    assert _scopes(_jamba_step_text(), [name]) == {name}
+
+
+def test_selective_scan_and_sharding_gauges_are_set_when_the_step_is_traced():
+    calls = telemetry.counter("selective_scan.calls").value
+    _jamba_step_text.cache_clear()
+    _jamba_step_text()
+    # the call as the model makes it, all devices: 8 sequences of 64
+    assert [telemetry.gauge(f"selective_scan.{k}").value for k in
+            ("chunk", "chunks", "channels", "state")] == [64, 8, 1024, 16]
+    assert telemetry.counter("selective_scan.calls").value >= calls + 1
+    # the leaves of 2^18 elements or more, float32, 7/8 of each a device:
+    # in_proj 512 x 2,048, out_proj 1,024 x 512, q and o 512 x 512 (the table
+    # 256 x 512 and k and v 512 x 128 are under it, and whole)
+    stored = 512 * 2048 + 1024 * 512 + 2 * 512 * 512
+    assert telemetry.gauge("step.param_gather_bytes").value == stored * 4 * 7 // 8
+    assert telemetry.gauge("step.grad_scatter_bytes").value == stored * 4 * 7 // 8
 
 
 def test_short_conv_gauges_are_set_when_the_operator_is_traced():

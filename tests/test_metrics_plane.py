@@ -792,6 +792,10 @@ def test_ps_server_arms_wall_clock_history(tmp_path, monkeypatch):
             time.sleep(0.05)
         assert h.samples(), "wall-clock beat produced no sample in 30s"
         assert h.samples()[0]["reason"] == "timer"
+        # the sample is in memory before its line is on disk: under six
+        # loaded workers the assert raced the write (my run on PR 43)
+        while not h.shards() and time.monotonic() < deadline:
+            time.sleep(0.05)
         assert h.shards()              # and the series reached disk
     finally:
         server.close()

@@ -131,3 +131,42 @@ def test_divisor_helpers():
     assert smallest_non_divisor_at_least_2(12) == 5
     assert smallest_non_divisor_at_least_2(7) == 2
     assert smallest_non_divisor_at_least_2(1) is None
+
+
+def test_fully_sharded_partitions_large_leaves_over_the_data_axis():
+    """Beyond the reference's eight: every leaf of ``MIN_SHARDED_SIZE``
+    elements or more gets a partitioner of ``dp`` shares on the first axis ``dp`` divides,
+    mapped onto the ``data`` mesh axis, an AllReduce synchronizer a share;
+    smaller leaves (and ones no axis of which ``dp`` divides) stay whole."""
+    from autodist_tpu.parallel.plan import ShardingPlan
+    from autodist_tpu.strategy import FullySharded
+    from jax.sharding import PartitionSpec as P
+
+    res = ResourceSpec("{nodes: [{address: localhost, tpus: 4}], mesh: {data: 4}}")
+    model = ModelSpec({
+        "emb": jnp.zeros((1024, 512)),
+        "w1": jnp.zeros((513, 513)),      # large, and 4 divides no axis
+        "w2": jnp.zeros((512, 512)),      # MIN_SHARDED_SIZE exactly
+        "b": jnp.zeros((512,)),
+        "s": jnp.zeros(()),
+    })
+    s = FullySharded().build(model, res)
+    assert s.mesh_axes()["data"] == 4 and s.mesh_axes()["model"] == 1
+    nodes = {n.var_name: n for n in s.node_config}
+    assert list(nodes["emb"].partitioner.num_shards) == [4, 1]
+    assert nodes["emb"].partitioner.mesh_axis == "data"
+    assert [p.var_name for p in nodes["emb"].part_config] == [
+        f"emb/part_{k}" for k in range(4)]
+    assert all(p.WhichOneof("synchronizer") == "all_reduce_synchronizer"
+               for p in nodes["emb"].part_config)
+    assert list(nodes["w2"].partitioner.num_shards) == [4, 1]
+    for whole in ("w1", "b", "s"):
+        assert not nodes[whole].HasField("partitioner")
+        assert nodes[whole].WhichOneof("synchronizer") == "all_reduce_synchronizer"
+    plan = ShardingPlan.from_strategy(s, model)
+    assert plan.params["emb"].pspec == plan.params["emb"].opt_pspec == P("data", None)
+    assert plan.params["w1"].pspec == P()
+    assert set(plan.data_sharded) == {"emb", "w2"}
+    # the reference-parity toy is all below the threshold: whole
+    assert not ShardingPlan.from_strategy(
+        FullySharded().build(_model(), res), _model()).data_sharded
