@@ -44,8 +44,11 @@ The scan is one operator, ``ops/selective_scan.py`` ``selective_scan``
 (chunked, one float32 ``[E, N]`` state carried between chunks, never a state
 a token), and the convolution before it ``ops/short_conv.py`` ``conv_silu``:
 both plain (``ssm_impl="xla"``) or each as two Pallas kernels (``"pallas"``).
-``silu(z)`` gating is outside the scan (XLA fuses it into ``W_out``'s
-operand), the ``D`` term inside.
+The scan's kernels read ``x`` and ``dt`` as the ``[B, L, E]`` rows the
+convolution and ``W_dt`` wrote and hand ``y`` (backward: ``dx``, ``ddt``) back
+the same way, so nothing is laid out anew between the mixer's products and
+its three scan calls a step. ``silu(z)`` gating is outside the scan (XLA fuses
+it into ``W_out``'s operand), the ``D`` term inside.
 
 Under ``remat`` every layer is a ``jax.checkpoint`` (``models/decoder.py``)
 whose policy keeps the values named in :data:`KEPT` and makes the rest again

@@ -27,19 +27,28 @@ cotangent ``ds_{t-1} = exp(dt_t A) ds_t`` carried in fast memory.
   chunk's function on the saved state, chunk by chunk in reverse. Init, the
   CPU and the comparison run it.
 - ``impl="pallas"``: ``selective_scan_fwd`` and ``selective_scan_bwd``. The
-  channels fill whole vector registers: ``[B, L, E]`` is read as ``[B, L, E /
-  128, 128]`` and a grid step holds 1,024 channels (8 sublanes x 128 lanes) of
-  ``chunk`` tokens, so a token's ``x``, ``dt`` and ``y`` are one register each
-  and the state is ``N`` registers, carried through the token loop in
-  registers and between chunks in VMEM. ``B_t[n]`` and ``C_t[n]`` are scalars
-  (SMEM), so nothing is broadcast across lanes and ``y``'s sum over ``n`` is a
-  sum of registers. All arithmetic is float32 whatever ``x``'s dtype. Forward
-  grid (sequence, channel tile, chunk); backward grid (sequence, chunk
-  reversed, channel tile): the sums over the channels that ``dB`` and ``dC``
-  need are gathered a (token, state) register over the channel tiles in VMEM
-  and reduced once a chunk, sublanes on the XLU and lanes by a product with
-  ones on the otherwise idle MXU, which also lays the result out with the
-  states on the lanes.
+  channels fill whole vector registers: a grid step holds 1,024 channels (8
+  sublanes x 128 lanes) of ``chunk`` tokens, so a token's ``x``, ``dt`` and
+  ``y`` are one register each and the state is ``N`` registers, carried
+  through the token loop in registers and between chunks in VMEM. ``x``,
+  ``dt``, ``dy`` are read and ``y``, ``dx``, ``ddt`` written as the ``[B, L,
+  E]`` arrays the model holds, in blocks of ``[chunk, 1,024]`` rows: a row is
+  one sublane of eight neighbouring ``[8, 128]`` tiles, which a load or a
+  store with a sublane stride of 8 makes the token's register with no
+  arithmetic (:func:`_row`), so XLA lays nothing out anew around a call
+  (``[B, L, E / 128, 128]``, one token a tile, is another tiling on the chip:
+  a pass over memory an operand). A packed dtype has no single row to load
+  (a bfloat16 tile holds 16 tokens): such ``x`` / ``dy`` are widened a chunk
+  at a time into a float32 VMEM block and ``y`` / ``dx`` narrowed out of one,
+  the conversion a token's loop would otherwise make. ``B_t[n]`` and
+  ``C_t[n]`` are scalars (SMEM), so nothing is broadcast across lanes and
+  ``y``'s sum over ``n`` is a sum of registers. All arithmetic is float32
+  whatever ``x``'s dtype. Forward grid (sequence, channel tile, chunk);
+  backward grid (sequence, chunk reversed, channel tile): the sums over the
+  channels that ``dB`` and ``dC`` need are gathered a (token, state) register
+  over the channel tiles in VMEM and reduced once a chunk, sublanes on the XLU
+  and lanes by a product with ones on the otherwise idle MXU, which also lays
+  the result out with the states on the lanes.
 
 ``silu(z)`` gating stays outside (XLA fuses it into the output projection's
 operand); the ``D`` term is inside.
@@ -135,18 +144,46 @@ def _xla_backward(x, dt, A, B, C, D, states, dy, chunk: int):
 
 # ----------------------------------------------------------------- kernels
 
+def _row(ref, t):
+    """Token ``t`` of a float32 ``[chunk, 1,024]`` block as one register,
+    ``[8, 128]`` with channel ``128 j + lane`` on sublane ``j``. In VMEM the
+    row is one sublane of eight neighbouring tiles, so this is a load with a
+    sublane stride of 8 and no arithmetic: the kernel reads the rows where
+    the projections wrote them."""
+    return ref[pl.ds(t, 1), :].reshape(8, _LANES)
+
+
+def _put_row(ref, t, register):
+    """The store that mirrors :func:`_row`."""
+    ref[pl.ds(t, 1), :] = register.reshape(1, _TILE)
+
+
+def _float32_rows(ref, scratch_ref, fill: bool = True):
+    """The block the token loop reads (or, ``fill=False``, writes) its rows
+    in: ``ref`` itself, or, of a packed dtype (a bfloat16 tile holds 16
+    tokens, two a sublane: no row of it can be loaded or stored alone),
+    ``scratch_ref``, float32, filled with ``ref``'s values."""
+    if ref.dtype == jnp.float32:
+        return ref
+    if fill:
+        scratch_ref[...] = ref[...].astype(jnp.float32)
+    return scratch_ref
+
+
 def _fwd_kernel(b_ref, c_ref, x_ref, dt_ref, a_ref, d_ref, y_ref, states_ref,
-                state_ref, *, n: int, chunk: int):
+                state_ref, xs_ref, ys_ref, *, n: int, chunk: int):
     @pl.when(pl.program_id(2) == 0)
     def _first_chunk_of_a_sequence():
         state_ref[...] = jnp.zeros_like(state_ref)
 
     states_ref[...] = state_ref[...]
     d = d_ref[...]
+    xs_ref = _float32_rows(x_ref, xs_ref)
+    ys_ref = _float32_rows(y_ref, ys_ref, fill=False)
 
     def token(t, state):
-        x = x_ref[t].astype(jnp.float32)
-        dt = dt_ref[t]
+        x = _row(xs_ref, t)
+        dt = _row(dt_ref, t)
         dtx = dt * x
         y = d * x
         after = []
@@ -154,19 +191,21 @@ def _fwd_kernel(b_ref, c_ref, x_ref, dt_ref, a_ref, d_ref, y_ref, states_ref,
             s = jnp.exp2(dt * a_ref[i]) * state[i] + dtx * b_ref[t * n + i]
             y = y + s * c_ref[t * n + i]
             after.append(s)
-        y_ref[t] = y.astype(y_ref.dtype)
+        _put_row(ys_ref, t, y)
         return tuple(after)
 
     state = jax.lax.fori_loop(0, chunk, token,
                               tuple(state_ref[i] for i in range(n)))
     for i in range(n):
         state_ref[i] = state[i]
+    if ys_ref is not y_ref:
+        y_ref[...] = ys_ref[...].astype(y_ref.dtype)
 
 
 def _bwd_kernel(b_ref, c_ref, x_ref, dt_ref, dy_ref, a_ref, d_ref, states_ref,
                 dx_ref, ddt_ref, da_ref, dd_ref, db_ref, dc_ref,
-                before_ref, d_state_ref, pb_ref, pc_ref, *, n: int, chunk: int,
-                tiles: int):
+                before_ref, d_state_ref, pb_ref, pc_ref, xs_ref, dys_ref,
+                dxs_ref, *, n: int, chunk: int, tiles: int):
     first_chunk = pl.program_id(1) == 0          # the sequence's last
     j = pl.program_id(2)
     rows = pl.ds(pl.multiple_of(j * 8, 8), 8)
@@ -182,11 +221,15 @@ def _bwd_kernel(b_ref, c_ref, x_ref, dt_ref, dy_ref, a_ref, d_ref, states_ref,
         pb_ref[...] = jnp.zeros_like(pb_ref)
         pc_ref[...] = jnp.zeros_like(pc_ref)
 
+    xs_ref = _float32_rows(x_ref, xs_ref)
+    dys_ref = _float32_rows(dy_ref, dys_ref)
+    dxs_ref = _float32_rows(dx_ref, dxs_ref, fill=False)
+
     # the chunk's states again, from the one that entered it: before_ref[t]
     # is the state token t finds, before_ref[t + 1] the one it leaves
     def forward(t, state):
-        dt = dt_ref[t]
-        dtx = dt * x_ref[t].astype(jnp.float32)
+        dt = _row(dt_ref, t)
+        dtx = dt * _row(xs_ref, t)
         after = []
         for i in range(n):
             before_ref[t, i] = state[i]
@@ -204,9 +247,9 @@ def _bwd_kernel(b_ref, c_ref, x_ref, dt_ref, dy_ref, a_ref, d_ref, states_ref,
     def backward(step, carry):
         d_state, d_a, d_d = carry
         t = chunk - 1 - step
-        x = x_ref[t].astype(jnp.float32)
-        dt = dt_ref[t]
-        dy = dy_ref[t].astype(jnp.float32)
+        x = _row(xs_ref, t)
+        dt = _row(dt_ref, t)
+        dy = _row(dys_ref, t)
         dtx = dt * x
         d_dtx, d_dt = zero, zero
         next_state, next_a = [], []
@@ -221,8 +264,8 @@ def _bwd_kernel(b_ref, c_ref, x_ref, dt_ref, dy_ref, a_ref, d_ref, states_ref,
             d_dt = d_dt + moved * a
             next_a.append(d_a[i] + moved * dt)
             next_state.append(through)
-        dx_ref[t] = (d_dtx * dt + d * dy).astype(dx_ref.dtype)
-        ddt_ref[t] = d_dt * _LN2 + d_dtx * x
+        _put_row(dxs_ref, t, d_dtx * dt + d * dy)
+        _put_row(ddt_ref, t, d_dt * _LN2 + d_dtx * x)
         return tuple(next_state), tuple(next_a), d_d + dy * x
 
     d_state, d_a, d_d = jax.lax.fori_loop(
@@ -232,6 +275,8 @@ def _bwd_kernel(b_ref, c_ref, x_ref, dt_ref, dy_ref, a_ref, d_ref, states_ref,
         d_state_ref[j, i] = d_state[i]
     da_ref[:, rows, :] += jnp.stack(d_a)
     dd_ref[rows, :] += d_d
+    if dxs_ref is not dx_ref:
+        dx_ref[...] = dxs_ref[...].astype(dx_ref.dtype)
 
     @pl.when(j == tiles - 1)
     def _last_tile_of_a_chunk():
@@ -262,15 +307,15 @@ def _sizes(x, A):
 
 
 def _layouts(x, dt, A, B, C, D):
-    """The arrays as the kernels read them: the channels as ``[E / 128, 128]``
-    (1,024 of them a register), ``A / ln 2`` a state index major (the decay
-    is ``exp2`` of its product with ``dt``: the EUP's own power, one multiply
-    less an element), ``B`` and ``C`` float32 and flat (scalars in SMEM,
-    ``[t * N + n]``)."""
+    """The arrays as the kernels read them: ``x`` and ``dt`` the ``[B, L,
+    E]`` rows they are (:func:`_row`), ``A / ln 2`` a state index major with
+    the channels as ``[E / 128, 128]`` (the decay is ``exp2`` of its product
+    with ``dt``: the EUP's own power, one multiply less an element), ``D``
+    likewise, ``B`` and ``C`` float32 and flat (scalars in SMEM, ``[t * N +
+    n]``)."""
     b, length, e, n = _sizes(x, A)
-    wide = lambda t: t.reshape(b, length, e // _LANES, _LANES)  # noqa: E731
     flat = lambda t: t.astype(jnp.float32).reshape(b, length * n)  # noqa: E731
-    return (flat(B), flat(C), wide(x), wide(dt.astype(jnp.float32)),
+    return (flat(B), flat(C), x, dt.astype(jnp.float32),
             (A.astype(jnp.float32) / _LN2).T.reshape(n, e // _LANES, _LANES),
             D.astype(jnp.float32).reshape(e // _LANES, _LANES))
 
@@ -280,7 +325,7 @@ def _forward_call(x, dt, A, B, C, D, chunk: int, interpret: bool):
     nc, tiles = length // chunk, e // _TILE
     scalars = pl.BlockSpec((None, chunk * n), lambda s, j, c: (s, c),
                            memory_space=pltpu.SMEM)
-    wide = pl.BlockSpec((None, chunk, 8, _LANES), lambda s, j, c: (s, c, j, 0))
+    wide = pl.BlockSpec((None, chunk, _TILE), lambda s, j, c: (s, c, j))
     y, states = named_pallas_call(
         "selective_scan_fwd", functools.partial(_fwd_kernel, n=n, chunk=chunk),
         grid=(b, tiles, nc),
@@ -291,17 +336,17 @@ def _forward_call(x, dt, A, B, C, D, chunk: int, interpret: bool):
                    pl.BlockSpec((None, None, n, 8, _LANES),
                                 lambda s, j, c: (s, c, 0, j, 0))],
         out_shape=[
-            jax.ShapeDtypeStruct((b, length, e // _LANES, _LANES), x.dtype),
+            jax.ShapeDtypeStruct(x.shape, x.dtype),
             jax.ShapeDtypeStruct((b, nc, n, e // _LANES, _LANES), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((n, 8, _LANES), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((n, 8, _LANES), jnp.float32)]
+        + [pltpu.VMEM((chunk, _TILE), jnp.float32)] * 2,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(*_layouts(x, dt, A, B, C, D))
     # [b, chunks, N, E] -> [b, chunks, E, N], as the plain path keeps them
-    return (y.reshape(x.shape),
-            jnp.swapaxes(states.reshape(b, nc, n, e), 2, 3))
+    return y, jnp.swapaxes(states.reshape(b, nc, n, e), 2, 3)
 
 
 def _backward_call(x, dt, A, B, C, D, states, dy, chunk: int, interpret: bool):
@@ -310,12 +355,10 @@ def _backward_call(x, dt, A, B, C, D, states, dy, chunk: int, interpret: bool):
     back = lambda c: nc - 1 - c  # noqa: E731
     scalars = pl.BlockSpec((None, chunk * n), lambda s, c, j: (s, back(c)),
                            memory_space=pltpu.SMEM)
-    wide = pl.BlockSpec((None, chunk, 8, _LANES),
-                        lambda s, c, j: (s, back(c), j, 0))
+    wide = pl.BlockSpec((None, chunk, _TILE), lambda s, c, j: (s, back(c), j))
     sums = pl.BlockSpec((None, None, 8, chunk * n),
                         lambda s, c, j: (s, back(c), 0, 0))
-    b2, c2, x4, dt4, a3, d2 = _layouts(x, dt, A, B, C, D)
-    shape4 = (b, length, e // _LANES, _LANES)
+    b2, c2, _, dt32, a3, d2 = _layouts(x, dt, A, B, C, D)
     dx, ddt, dA, dD, dB, dC = named_pallas_call(
         "selective_scan_bwd",
         functools.partial(_bwd_kernel, n=n, chunk=chunk, tiles=tiles),
@@ -331,8 +374,8 @@ def _backward_call(x, dt, A, B, C, D, states, dy, chunk: int, interpret: bool):
                    pl.BlockSpec((None, e // _LANES, _LANES),
                                 lambda s, c, j: (s, 0, 0)),
                    sums, sums],
-        out_shape=[jax.ShapeDtypeStruct(shape4, x.dtype),
-                   jax.ShapeDtypeStruct(shape4, jnp.float32),
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+                   jax.ShapeDtypeStruct(x.shape, jnp.float32),
                    jax.ShapeDtypeStruct((b, n, e // _LANES, _LANES), jnp.float32),
                    jax.ShapeDtypeStruct((b, e // _LANES, _LANES), jnp.float32),
                    jax.ShapeDtypeStruct((b, nc, 8, chunk * n), jnp.float32),
@@ -340,15 +383,16 @@ def _backward_call(x, dt, A, B, C, D, states, dy, chunk: int, interpret: bool):
         scratch_shapes=[pltpu.VMEM((chunk + 1, n, 8, _LANES), jnp.float32),
                         pltpu.VMEM((tiles, n, 8, _LANES), jnp.float32),
                         pltpu.VMEM((chunk, n, 8, _LANES), jnp.float32),
-                        pltpu.VMEM((chunk, n, 8, _LANES), jnp.float32)],
+                        pltpu.VMEM((chunk, n, 8, _LANES), jnp.float32)]
+        + [pltpu.VMEM((chunk, _TILE), jnp.float32)] * 3,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
-    )(b2, c2, x4, dt4, dy.astype(x.dtype).reshape(shape4), a3, d2,
+    )(b2, c2, x, dt32, dy.astype(x.dtype), a3, d2,
       jnp.swapaxes(states, 2, 3).reshape(b, nc, n, e // _LANES, _LANES))
     small = lambda t, like: t[:, :, 0].reshape(like.shape).astype(like.dtype)  # noqa: E731
-    return (dx.reshape(x.shape), ddt.reshape(x.shape).astype(dt.dtype),
+    return (dx, ddt.astype(dt.dtype),
             jnp.sum(dA, axis=0).reshape(n, e).T.astype(A.dtype),
             small(dB, B), small(dC, C),
             jnp.sum(dD, axis=0).reshape(e).astype(D.dtype))
@@ -419,11 +463,13 @@ def selective_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
             f"{B.shape}, C {C.shape}, D {D.shape}; want [B, L, E] twice, "
             f"[E, N], [B, L, N] twice, [E]")
     n = A.shape[1]
-    if impl == "pallas" and (e % _TILE or _LANES % n or chunk % (8 * _LANES // n)):
+    # a chunk: whole bfloat16 tiles of 16 rows, dB / dC's sums whole lane tiles
+    whole = max(16, 8 * _LANES // n)
+    if impl == "pallas" and (e % _TILE or _LANES % n or chunk % whole):
         raise ValueError(
             f"selective_scan kernels: {e} channels must be a multiple of "
             f"{_TILE}, state {n} a divisor of {_LANES}, chunk {chunk} a "
-            f"multiple of {8 * _LANES // n}")
+            f"multiple of {whole}")
     chunks = -(-length // chunk)
     telemetry.counter("selective_scan.calls").inc()
     telemetry.gauge("selective_scan.chunk").set(chunk)

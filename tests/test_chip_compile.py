@@ -620,16 +620,37 @@ def test_kernels_compile_sharded_over_four_chips(topology, kernel):
         assert "all-reduce" in text or "reduce-scatter" in text
 
 
-@pytest.mark.parametrize("length,chunks", [(16384, 128), (1000, 8)],
-                         ids=["cell-1x16384", "ragged-L1000"])
+def _relayouts(text: str, elements: set):
+    """The ``copy``, ``transpose`` and ``reshape`` instructions of a compiled
+    text whose result holds one of ``elements`` values: whole arrays laid out
+    anew. (A reshape that moves nothing is spelled ``bitcast`` there, a move
+    to another memory space ``copy-start`` / ``copy-done``.)"""
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([0-9,]+)\]\S* "
+                     r"(copy|transpose|reshape)\(", line)
+        if m and int(np.prod([int(d) for d in m.group(1).split(",")])) in elements:
+            found.append(line.strip()[:160])
+    return found
+
+
+@pytest.mark.parametrize("length,chunks,dtype", [
+    (16384, 128, jnp.bfloat16), (1000, 8, jnp.bfloat16), (16384, 128, jnp.float32),
+], ids=["cell-1x16384", "ragged-L1000", "cell-float32-x"])
 def test_selective_scan_fwd_bwd_compiles_at_the_jamba_cell_shape(chip, length,
-                                                                 chunks):
+                                                                 chunks, dtype):
     """jamba2-sharded4-16k's call, a chip's share: one sequence of 5,120
-    channels and 16 states, chunks of 128, bfloat16 ``x`` and float32 ``dt``,
-    ``B``, ``C``: the forward and the backward kernel, each a Mosaic call
-    under its own name, through the operator's custom VJP (``B`` and ``C`` as
-    SMEM scalars, a bfloat16 block of 8 x 128 a token, 26 MiB of scoped VMEM
-    in the backward); a length the chunk does not divide is padded."""
+    channels and 16 states, chunks of 128, bfloat16 ``x`` (float32 as the
+    precise first layer hands it) and float32 ``dt``, ``B``, ``C``: the
+    forward and the backward kernel, each a Mosaic call under its own name,
+    through the operator's custom VJP (``B`` and ``C`` as SMEM scalars, a
+    token's 1,024 channels one sublane-strided row of a ``[128, 1024]``
+    block, 28 MiB of scoped VMEM in the backward); a length the chunk does
+    not divide is padded. The kernels take ``x``, ``dt``, ``dy`` and hand
+    back ``y``, ``dx``, ``ddt`` as ``[1, L, 5120]`` where XLA holds them: no
+    ``copy``, ``transpose`` or non-bitcast ``reshape`` of an array of that
+    size (``[1, L, 40, 128]`` is another tiling: 94 ms a step of the cell
+    before PR 44), the ragged length's own pad and slice aside."""
     from autodist_tpu.ops.selective_scan import selective_scan
 
     def loss(x, dt, a, b, c, d):
@@ -638,13 +659,14 @@ def test_selective_scan_fwd_bwd_compiles_at_the_jamba_cell_shape(chip, length,
 
     wide, narrow = (1, length, 5120), (1, length, 16)
     text = _compiled_text(jax.value_and_grad(loss, argnums=tuple(range(6))), chip,
-                          (wide, jnp.bfloat16), (wide, jnp.float32),
+                          (wide, dtype), (wide, jnp.float32),
                           ((5120, 16), jnp.float32), (narrow, jnp.float32),
                           (narrow, jnp.float32), ((5120,), jnp.float32))
     assert "tpu_custom_call" in text
     assert "selective_scan_fwd" in text and "selective_scan_bwd" in text
     assert f"f32[1,{chunks},16,40,128]" in text      # one [E, N] state a chunk
     assert f"f32[1,{chunks * 128},5120,16]" not in text     # never one a token
+    assert not _relayouts(text, {length * 5120, chunks * 128 * 5120})
 
 
 def _whole_all_reduces(text: str, min_elements: int):

@@ -4,8 +4,9 @@ kernels (interpret mode on the CPU) against the plain path, value and the
 gradient of every one of the six inputs; a chunk boundary inside the sequence
 and a length that is no whole number of chunks; nothing crosses from one
 sequence of a batch to the next; no ``[L, E, N]`` array anywhere in the
-forward or the backward; the gauges say what a call moves; under a mesh the
-kernels run per device."""
+forward or the backward; the wide operands reach the kernels as the ``[B, L,
+E]`` rows they are; the gauges say what a call moves; under a mesh the kernels
+run per device."""
 
 import jax
 import jax.numpy as jnp
@@ -64,13 +65,21 @@ def test_plain_path_is_the_token_by_token_loop(length, chunk):
         assert got.shape == want.shape and _distance(got, want) < 1e-5, name
 
 
-@pytest.mark.parametrize("length,dtype,tol", [
-    (128, jnp.float32, 1e-5),      # two chunks: a boundary inside the sequence
-    (96, jnp.float32, 1e-5),       # padded to two chunks
-    (128, jnp.bfloat16, 1e-2),     # x, B, C as the model hands them
-], ids=["two-chunks", "ragged", "bfloat16"])
-def test_kernels_are_the_plain_path_value_and_all_six_gradients(length, dtype, tol):
-    inputs = _operands(2, length, 1024, 16, seed=1, dtype=dtype)
+@pytest.mark.parametrize("length,e,dtype,tol", [
+    (128, 1024, jnp.float32, 1e-5),    # two chunks: a boundary inside the sequence
+    (96, 1024, jnp.float32, 1e-5),     # padded to two chunks
+    (128, 1024, jnp.bfloat16, 1e-2),   # x, B, C as the model hands them
+    # rows of [B, L, E] read where they lie: a bfloat16 tile packs 16 tokens, so
+    # a length that is no multiple of 16 (padded to the chunk), alone and over
+    # two channel tiles of a row; float32 x over two tiles and a ragged length
+    (100, 1024, jnp.bfloat16, 1e-2),
+    (72, 2048, jnp.bfloat16, 1e-2),
+    (72, 2048, jnp.float32, 1e-5),
+], ids=["two-chunks", "ragged", "bfloat16", "bfloat16-ragged-not-16",
+        "bfloat16-two-tiles-ragged", "float32-two-tiles-ragged"])
+def test_kernels_are_the_plain_path_value_and_all_six_gradients(length, e, dtype,
+                                                                tol):
+    inputs = _operands(2, length, e, 16, seed=1, dtype=dtype)
     want_y, want_g = _value_and_grads(
         lambda *a: selective_scan(*a, chunk=64, impl="xla"), inputs)
     got_y, got_g = _value_and_grads(
@@ -97,15 +106,21 @@ def test_two_channel_tiles_and_sequences_share_nothing():
         selective_scan(*alone, chunk=64, impl="pallas"), got_y[:1], rtol=1e-6)
 
 
-def _avals(jaxpr):
-    """Every value of a jaxpr and of the jaxprs inside it."""
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
     for eqn in jaxpr.eqns:
-        yield from (v.aval for v in eqn.outvars)
+        yield eqn
         for param in eqn.params.values():
             for inner in (param if isinstance(param, (list, tuple)) else [param]):
                 inner = getattr(inner, "jaxpr", inner)
                 if hasattr(inner, "eqns"):
-                    yield from _avals(inner)
+                    yield from _eqns(inner)
+
+
+def _avals(jaxpr):
+    """Every value of a jaxpr and of the jaxprs inside it."""
+    for eqn in _eqns(jaxpr):
+        yield from (v.aval for v in eqn.outvars)
 
 
 @pytest.mark.parametrize("impl,e", [("xla", 128), ("pallas", 1024)])
@@ -128,6 +143,33 @@ def test_no_state_a_token_is_ever_built(impl, e):
     assert [r.shape for r in residuals] == [t.shape for t in inputs] + [
         (b, length // chunk, e, n)]
     assert residuals[-1].dtype == jnp.float32
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+def test_the_wide_operands_reach_the_kernels_as_the_rows_they_are(dtype):
+    """``x``, ``dt``, ``dy`` go into the two kernels and ``y``, ``dx``, ``ddt``
+    come out of them as ``[B, L, E]``: no value of the forward or the backward
+    is one of them in another shape (``[B, L, E / 128, 128]`` is another
+    tiling on the chip, a pass over memory each way)."""
+    b, length, e, n = 2, 128, 2048, 16
+    inputs = _operands(b, length, e, n, dtype=dtype)
+
+    def loss(*a):
+        return jnp.sum(selective_scan(*a, chunk=64, impl="pallas")
+                       .astype(jnp.float32))
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=tuple(range(6))))(*inputs)
+    calls = [eqn for eqn in _eqns(jaxpr.jaxpr)
+             if eqn.primitive.name == "pallas_call"]
+    assert len(calls) == 2
+    rows = (b, length, e)
+    for eqn, wide_in, wide_out in zip(calls, (2, 3), (1, 2)):
+        assert [v.aval.shape for v in eqn.invars].count(rows) == wide_in
+        assert [v.aval.shape for v in eqn.outvars].count(rows) == wide_out
+    assert not [a for a in _avals(jaxpr.jaxpr) if hasattr(a, "shape")
+                and a.shape != rows and a.ndim > 3
+                and int(np.prod(a.shape)) == b * length * e]
 
 
 def test_gauges_say_what_a_call_moves_and_wrong_shapes_raise():
