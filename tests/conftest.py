@@ -6,7 +6,12 @@ tests sharding semantics on a faked multi-chip backend instead:
 mesh with real XLA collectives. Must run before the first ``import jax``.
 """
 
+import contextlib
+import faulthandler
 import os
+import signal
+import sys
+import threading
 
 os.environ["JAX_PLATFORMS"] = "cpu"  # tests run on an 8-device virtual CPU mesh
 _flags = os.environ.get("XLA_FLAGS", "")
@@ -52,4 +57,43 @@ def _graftsan_thread_fence():
         yield
         return
     with sanitizer.thread_fence(grace_s=2.0):
+        yield
+
+
+# Seconds a test may take, set-up and teardown of its other fixtures aside:
+# three times the longest case of the suite (CHANGES.md, PR 45) and the limit
+# the multi-process tests give their subprocesses. The driver's window is for
+# the whole suite (ROADMAP D12); without a clock of its own a test that hangs
+# spends all of it and the count does not say which test it was.
+LIMIT = 300.0
+
+
+@contextlib.contextmanager
+def clock(nodeid: str):
+    """Fail the test ``nodeid`` once it has run ``LIMIT`` seconds, with every
+    thread's stack on stderr first. ``SIGALRM`` reaches the main thread only
+    and only between bytecodes: a test stuck inside one native call fails when
+    that call returns. No timer is left armed on the way out."""
+    if not hasattr(signal, "SIGALRM") \
+            or threading.current_thread() is not threading.main_thread():
+        yield
+        return
+    limit = LIMIT
+
+    def expired(signum, frame):
+        faulthandler.dump_traceback(file=sys.__stderr__)
+        pytest.fail(f"{nodeid} exceeded {limit:g} s", pytrace=False)
+
+    previous = signal.signal(signal.SIGALRM, expired)
+    signal.setitimer(signal.ITIMER_REAL, limit)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, previous)
+
+
+@pytest.fixture(autouse=True)
+def _clock(request):
+    with clock(request.node.nodeid):
         yield

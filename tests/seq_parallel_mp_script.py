@@ -96,17 +96,12 @@ def main(out_path: str):
             json.dump(result, f)
 
 
-def run_single_reference(out_path: str, workdir: str, timeout: int = 300):
-    """Run this script once, single-process, on a 4-device sim mesh (the
-    strategy matrix's shared env recipe, ``tests/mp_env.py``)."""
-    import subprocess
-
-    from tests.mp_env import repo_root, single_reference_env
-    env = single_reference_env(workdir, device_count=4)
-    return subprocess.run(
-        [sys.executable, os.path.abspath(__file__), out_path],
-        env=env, cwd=repo_root(), capture_output=True, text=True,
-        timeout=timeout)
+def start_single_reference(out_path: str, workdir: str):
+    """Start this script once, single-process, on a 4-device sim mesh (the
+    strategy matrix's shared env recipe, ``tests/mp_env.py``);
+    ``mp_env.alongside`` waits for it."""
+    from tests.mp_env import start_single_reference as start
+    return start([os.path.abspath(__file__), out_path], workdir, device_count=4)
 
 
 if __name__ == "__main__":

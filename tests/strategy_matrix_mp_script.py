@@ -264,20 +264,25 @@ def _write_result(out_path, config, runner, state, losses, extra):
         json.dump(result, f)
 
 
-def run_single_reference(out_path: str, config: str, workdir: str,
-                         timeout: int = 300, phase: str = ""):
-    """Run this script once, single-process, on a sim mesh matching the
-    multi-process run's global device count (2 devices per process)."""
-    import subprocess
-
-    from tests.mp_env import repo_root, single_reference_env
+def start_single_reference(out_path: str, config: str, workdir: str,
+                           phase: str = ""):
+    """Start this script once, single-process, on a sim mesh matching the
+    multi-process run's global device count (2 devices per process);
+    ``mp_env.collect`` waits for it."""
+    from tests.mp_env import start_single_reference as start
     procs = int(os.environ.get("AUTODIST_MATRIX_PROCS", "2"))
-    env = single_reference_env(workdir, device_count=2 * procs)
-    args = [sys.executable, os.path.abspath(__file__), out_path, config]
+    args = [os.path.abspath(__file__), out_path, config]
     if phase:
         args.append(phase)
-    return subprocess.run(args, env=env, cwd=repo_root(), capture_output=True,
-                          text=True, timeout=timeout)
+    return start(args, workdir, device_count=2 * procs)
+
+
+def run_single_reference(out_path: str, config: str, workdir: str,
+                         timeout: int = 300, phase: str = ""):
+    """``start_single_reference`` and wait: the completed process."""
+    from tests.mp_env import collect
+    return collect(start_single_reference(out_path, config, workdir, phase),
+                   timeout)
 
 
 if __name__ == "__main__":

@@ -23,6 +23,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from tests import reference_programs  # noqa: E402
+
 # Two layer kinds, a leading dense layer, a window shorter than the sequence,
 # 2 query heads a KV head, the share: experts 2-3 of 8, top-2.
 TINY = dict(vocab_size=256, d_model=64, n_heads=4, n_kv_heads=2, head_dim=16,
@@ -73,7 +75,6 @@ def _with_bias(params, scale=0.05):
 ], ids=["f32-xla", "f32-kernels", "bf16-kernels"])
 def test_loss_and_gradients_match_the_plain_reference(dtype, attention, fused,
                                                       loss_tol, grad_tol):
-    from benchmark.reference import afmoe as reference
     cfg = afmoe.AfmoeConfig(dtype=dtype, attention_impl=attention,
                             fused_head=fused, **TINY)
     model, params = afmoe.init_params(cfg, jax.random.PRNGKey(1))
@@ -83,9 +84,8 @@ def test_loss_and_gradients_match_the_plain_reference(dtype, attention, fused,
     loss, grads = jax.jit(jax.value_and_grad(afmoe.make_loss_fn(model)))(
         params, batch)
     with jax.default_matmul_precision("highest"):
-        ref_loss, ref_grads = jax.jit(jax.value_and_grad(
-            lambda p, b: reference.loss(p, b, **_reference_kwargs(cfg))))(
-                params, batch)
+        ref_loss, ref_grads = reference_programs.value_and_grad(
+            "afmoe", **_reference_kwargs(cfg))(params, batch)
     assert abs(float(loss) - float(ref_loss)) / float(ref_loss) <= loss_tol
     assert _rel_l2(grads, ref_grads) <= grad_tol
     assert {str(g.dtype) for g in jax.tree_util.tree_leaves(grads)} == {"float32"}
@@ -213,11 +213,17 @@ def _bank_of(form, bank):
     return bank if form == "gated_silu" else [None, *bank[1:]]
 
 
-def _grads(fn, args, argnums):
-    """Gradients of ``fn(*args).sum()`` for the ``argnums`` that hold an
-    array (a form without a gate has None in its place)."""
+def _value_and_grads(fn, args, argnums, weight=1.0):
+    """``fn(*args)`` and the gradients of ``(fn(*args) * weight).sum()`` for
+    the ``argnums`` that hold an array (a form without a gate has None in its
+    place): one compiled program, one forward."""
     argnums = tuple(i for i in argnums if args[i] is not None)
-    return jax.grad(lambda *a: fn(*a).sum(), argnums=argnums)(*args)
+
+    def summed(*a):
+        y = fn(*a)
+        return (y * weight).sum(), y
+    grads, y = jax.jit(jax.grad(summed, argnums=argnums, has_aux=True))(*args)
+    return y, grads
 
 
 def _all_avals(jaxpr):
@@ -253,9 +259,9 @@ def test_a_share_equals_its_experts_under_a_mask_and_keeps_absent_rows_out(bound
     held_rows = int(route(scores, k, bias, first_expert=first,
                           n_held=held).group_sizes.sum())
     assert 16 < held_rows <= 56 < tokens * k
-    np.testing.assert_allclose(share(*args), dense(*args), rtol=1e-5, atol=1e-5)
-    got = _grads(share, args, (0, 1, 2, 3, 4))
-    want = _grads(dense, args, (0, 1, 2, 3, 4))
+    y, got = _value_and_grads(share, args, (0, 1, 2, 3, 4))
+    want_y, want = _value_and_grads(dense, args, (0, 1, 2, 3, 4))
+    np.testing.assert_allclose(y, want_y, rtol=1e-5, atol=1e-5)
     assert len(got) == (5 if form == "gated_silu" else 4)
     for g, r in zip(got, want):
         np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-5)
@@ -284,25 +290,41 @@ def test_more_held_rows_than_the_bound_take_more_passes_and_nothing_is_dropped()
     dense = functools.partial(_dense_share, k=k, first=4, scale=1.0)
     both = jnp.zeros(8).at[jnp.asarray([4, 5])].set(10.0)   # everyone chooses 4 and 5
     one = jnp.zeros(8).at[4].set(10.0).at[0].set(9.0)       # everyone chooses 4 (and 0)
-    for bias, want_sizes in ((both, [tokens, tokens]), (one, [tokens, 0])):
-        for bound in (tokens // 2, tokens, tokens * k, None):
-            call = lambda x, *bank: moe.routed_experts(  # noqa: E731
-                x, scores, *bank, bias, top_k=k, route=moe.sigmoid_topk_route,
-                first_expert=4, rows_bound=bound)
-            y, sizes = call(x, *mine)
+
+    def with_gradients(layer):
+        """y, what ``layer`` returns beside it, and the gradients of
+        ``y.sum()`` for x and the gate bank: one compiled program, the bias
+        an argument."""
+        def summed(x, gate, bias):
+            y, *rest = layer(x, gate, bias)
+            return y.sum(), (y, *rest)
+
+        @jax.jit
+        def run(bias):
+            grads, outs = jax.grad(summed, argnums=(0, 1), has_aux=True)(
+                x, mine[0], bias)
+            return (*outs, grads)
+        return run
+
+    dense_of = with_gradients(
+        lambda x, gate, bias: (dense(x, scores, gate, *mine[1:], bias),))
+    for bound in (tokens // 2, tokens, tokens * k, None):
+        share_of = with_gradients(lambda x, gate, bias: moe.routed_experts(
+            x, scores, gate, *mine[1:], bias, top_k=k,
+            route=moe.sigmoid_topk_route, first_expert=4,
+            rows_bound=bound))  # noqa: B023
+        for bias, want_sizes in ((both, [tokens, tokens]), (one, [tokens, 0])):
+            y, sizes, got = share_of(bias)
+            want_y, want = dense_of(bias)
             np.testing.assert_array_equal(sizes, want_sizes)
-            np.testing.assert_allclose(y, dense(x, scores, *mine, bias),
-                                       rtol=1e-5, atol=1e-5)
-            got = jax.grad(lambda *a: call(*a)[0].sum(), argnums=(0, 1))(x, *mine)
-            want = jax.grad(lambda x, g: dense(x, scores, g, *mine[1:], bias).sum(),
-                            argnums=(0, 1))(x, mine[0])
+            np.testing.assert_allclose(y, want_y, rtol=1e-5, atol=1e-5)
             for g, r in zip(got, want):
                 np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-5)
     # none of the held experts chosen by anyone: no pass at all, zeros
     nobody = jnp.zeros(8).at[jnp.asarray([0, 1])].set(10.0)
-    y, sizes = moe.routed_experts(x, scores, *mine, nobody, top_k=k,
-                                  route=moe.sigmoid_topk_route, first_expert=4,
-                                  rows_bound=tokens // 2)
+    y, sizes = jax.jit(lambda bias: moe.routed_experts(
+        x, scores, *mine, bias, top_k=k, route=moe.sigmoid_topk_route,
+        first_expert=4, rows_bound=tokens // 2))(nobody)
     assert int(sizes.sum()) == 0 and not np.asarray(y).any()
     # and the model's loss stays finite under a bound far below the held rows
     cfg = afmoe.AfmoeConfig(dtype=jnp.float32, **dict(TINY, rows_bound=4))
@@ -312,10 +334,11 @@ def test_more_held_rows_than_the_bound_take_more_passes_and_nothing_is_dropped()
     params = jax.tree_util.tree_map_with_path(
         lambda path, p: jnp.zeros(8).at[jnp.asarray([2, 3])].set(10.0)
         if path[-1].key == "expert_bias" else p, params)
-    loss, grads = jax.value_and_grad(afmoe.make_loss_fn(model))(params, batch)
+    loss, grads = jax.jit(jax.value_and_grad(afmoe.make_loss_fn(model)))(
+        params, batch)
     wide = afmoe.Afmoe(afmoe.AfmoeConfig(dtype=jnp.float32, **TINY))
-    np.testing.assert_allclose(loss, afmoe.make_loss_fn(wide)(params, batch),
-                               rtol=1e-6)
+    np.testing.assert_allclose(
+        loss, jax.jit(afmoe.make_loss_fn(wide))(params, batch), rtol=1e-6)
     assert all(np.isfinite(g).all() for g in jax.tree_util.tree_leaves(grads))
 
 
@@ -422,11 +445,9 @@ def test_kept_and_recomputed_passes_give_the_dense_shares_gradients(
         x.astype(jnp.float32), scores, *bank, bias, k=2, first=4, scale=1.0)
     assert bound < x.shape[0] * 2
     target = jax.random.normal(jax.random.PRNGKey(7), x.shape)
-    loss = lambda fn: lambda *a: (fn(*a) * target).sum()  # noqa: E731
-    np.testing.assert_allclose(share(x, scores, *mine), dense(x, scores, *mine),
-                               rtol=tol, atol=tol)
-    got = _grads(loss(share), (x, scores, *mine), range(5))
-    want = _grads(loss(dense), (x, scores, *mine), range(5))
+    y, got = _value_and_grads(share, (x, scores, *mine), range(5), target)
+    want_y, want = _value_and_grads(dense, (x, scores, *mine), range(5), target)
+    np.testing.assert_allclose(y, want_y, rtol=tol, atol=tol)
     assert got[0].dtype == dtype and got[2].dtype == jnp.float32
     for g, r in zip(got, want):
         assert _rel_l2(g.astype(jnp.float32), r.astype(jnp.float32)) <= tol
@@ -485,12 +506,13 @@ def test_a_step_through_the_normal_path_moves_the_bias_by_the_rule():
     loss_fn = afmoe.make_loss_fn(model)
     optimizer = afmoe.make_optimizer(1e-2, cfg.load_balance_coeff)
     # the load error the rule reads is the gradient of the loss's bias term
-    grads = jax.grad(loss_fn)(params, {"tokens": jnp.asarray(batch["tokens"])})
+    grads = jax.jit(jax.grad(loss_fn))(
+        params, {"tokens": jnp.asarray(batch["tokens"])})
+    ad = AutoDist(strategy_builder=AllReduce())
+    runner = ad.create_distributed_session(loss_fn, params, optimizer,
+                                           example_batch=batch)
 
-    def one_run(steps):
-        ad = AutoDist(strategy_builder=AllReduce())
-        runner = ad.create_distributed_session(loss_fn, params, optimizer,
-                                               example_batch=batch)
+    def one_run(steps):     # one session, one compiled step, for both runs
         losses = []
         final = train(runner, params, iter([batch] * steps), steps=steps,
                       log_every=1,

@@ -68,8 +68,8 @@ def test_blockwise_gradients_match_reference():
     def f_blk(q, k, v):
         return jnp.sum(blockwise_attention(q, k, v, block_size=16) ** 2)
 
-    g_ref = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
-    g_blk = jax.grad(f_blk, argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.jit(jax.grad(f_ref, argnums=(0, 1, 2)))(q, k, v)
+    g_blk = jax.jit(jax.grad(f_blk, argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(g_ref, g_blk):
         _close(a, b, atol=3e-4, mxu=0.05)
 
@@ -96,12 +96,12 @@ def test_flash_gradients_flow():
     def f(q, k, v):
         return jnp.sum(flash_attention(q, k, v, q_block=32, k_block=32) ** 2)
 
-    grads = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+    grads = jax.jit(jax.grad(f, argnums=(0, 1, 2)))(q, k, v)
 
     def f_ref(q, k, v):
         return jnp.sum(_reference(q, k, v) ** 2)
 
-    want = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
+    want = jax.jit(jax.grad(f_ref, argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(grads, want):
         _close(a, b, atol=3e-4, mxu=0.05)
 
@@ -144,8 +144,8 @@ def test_flash_block_classes_match_reference(case):
         return _reference(q, k, v, causal)
 
     _close(flash(q, k, v), plain(q, k, v), atol=2e-5)
-    got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
-    want = jax.grad(loss(plain), argnums=(0, 1, 2))(q, k, v)
+    got = jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2)))(q, k, v)
+    want = jax.jit(jax.grad(loss(plain), argnums=(0, 1, 2)))(q, k, v)
     for a, b, name in zip(got, want, "qkv"):
         _close(a, b, atol=3e-4, mxu=0.05, err_msg=f"d{name}")
 
@@ -283,8 +283,8 @@ def test_flash_backward_byte_limit_switches_the_path(monkeypatch):
     q, k, v = (_randn(rng, 1, 96, 2, 16) for _ in range(3))
 
     def grads():
-        return jax.grad(lambda q, k, v: jnp.sum(flash_attention(
-            q, k, v, q_block=32, k_block=32) ** 2), argnums=(0, 1, 2))(q, k, v)
+        return jax.jit(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, q_block=32, k_block=32) ** 2), argnums=(0, 1, 2)))(q, k, v)
 
     resident = 96 * 16 * 4          # dQ of one (batch, head), float32
     got = []
@@ -333,9 +333,14 @@ def test_flash_operands_stay_where_the_projections_put_them(case, monkeypatch):
     rows = lambda x: x.reshape(2, length, heads * d)  # noqa: E731
 
     def run(attend, *operands):
-        loss = lambda *a: jnp.sum(attend(*a) * weights.reshape(  # noqa: E731
-            operands[0].shape))
-        return (attend(*operands),) + jax.grad(loss, argnums=(0, 1, 2))(*operands)
+        # one compiled program a call: the output and the three gradients of
+        # one forward (the gauges are set when it is traced)
+        def loss(*a):
+            out = attend(*a)
+            return jnp.sum(out * weights.reshape(out.shape)), out
+        grads, out = jax.jit(jax.grad(loss, argnums=(0, 1, 2), has_aux=True))(
+            *operands)
+        return (out,) + grads
 
     def flash(q, k, v):
         return flash_attention(q, k, v, q_block=q_block, k_block=k_block,
@@ -357,7 +362,7 @@ def test_flash_operands_stay_where_the_projections_put_them(case, monkeypatch):
     for a, b, name in zip(got[1:], relaid_all[1:], ("dq", "dk", "dv")):
         _close(a, b, atol=2e-5, rtol=1e-5, mxu=0.05, err_msg=name)
     # one operand a call: v as rows (and with it the result), q and k not
-    mixed = flash(q, k, rows(v))
+    mixed = jax.jit(flash)(q, k, rows(v))
     assert mixed.shape == (2, length, heads * d)
     assert telemetry.gauge("flash.fwd.operands_relaid").value == \
         (2 if relaid == (0, 0) else 4)
@@ -483,9 +488,10 @@ def test_ring_attention_matches_single_device(causal):
     want = _reference(q, k, v, causal)
 
     spec = P(const.MESH_AXIS_DATA, const.MESH_AXIS_SEQ, None, None)
-    fn = jax.shard_map(
+    # jitted: a bare shard_map runs primitive by primitive on the CPU mesh
+    fn = jax.jit(jax.shard_map(
         lambda q, k, v: ring_attention(q, k, v, causal=causal, block_size=16),
-        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False)
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False))
     got = fn(q, k, v)
     _close(got, want, atol=2e-5)
 
@@ -502,12 +508,12 @@ def test_ring_attention_gradients_flow():
     def loss(q, k, v):
         return jnp.sum(fn(q, k, v) ** 2)
 
-    grads = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    grads = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
 
     def loss_ref(q, k, v):
         return jnp.sum(_reference(q, k, v) ** 2)
 
-    want = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    want = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(grads, want):
         _close(a, b, atol=3e-4, mxu=0.05)
 
@@ -638,8 +644,7 @@ def test_ring_flash_matches_ring_blockwise(causal):
             return jnp.sum(fn(q_, k_, v_) ** 2)
 
         with mesh:
-            val, grads = jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
-        return val, grads
+            return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))(q, k, v)
 
     val_bw, g_bw = run("blockwise")
     val_fl, g_fl = run("flash")

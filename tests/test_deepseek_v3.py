@@ -29,6 +29,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from tests import reference_programs  # noqa: E402
+
 # One dense layer and two expert layers; keys 24 wide (16 + 8 shared) over
 # values 16 through a latent of 24; the share: experts 2-4 of 8, top-3.
 TINY = dict(vocab_size=256, d_model=64, n_layers=3, n_heads=4,
@@ -81,7 +83,6 @@ def _batch(cfg, sequences=2, length=40, seed=3):
 ], ids=["f32-dot", "f32-flash-remat", "bf16-dot", "bf16-flash-remat"])
 def test_loss_and_gradients_match_the_plain_reference(dtype, attention, fused,
                                                       remat, loss_tol, grad_tol):
-    from benchmark.reference import deepseek_v3 as reference
     cfg = deepseek_v3.DeepseekV3Config(dtype=dtype, attention_impl=attention,
                                        fused_head=fused, remat=remat,
                                        rows_bound=40, **TINY)
@@ -91,9 +92,8 @@ def test_loss_and_gradients_match_the_plain_reference(dtype, attention, fused,
     loss, grads = jax.jit(jax.value_and_grad(deepseek_v3.make_loss_fn(model)))(
         params, batch)
     with jax.default_matmul_precision("highest"):
-        ref_loss, ref_grads = jax.jit(jax.value_and_grad(
-            lambda p, b: reference.loss(p, b, **_reference_kwargs(cfg))))(
-                params, batch)
+        ref_loss, ref_grads = reference_programs.value_and_grad(
+            "deepseek_v3", **_reference_kwargs(cfg))(params, batch)
     assert abs(float(loss) - float(ref_loss)) / float(ref_loss) <= loss_tol
     assert _rel_l2(grads, ref_grads) <= grad_tol
     assert {str(g.dtype) for g in jax.tree_util.tree_leaves(grads)} == {"float32"}
@@ -374,7 +374,8 @@ def test_a_step_through_the_normal_path_moves_the_bias_by_the_rule():
     batch = deepseek_v3.synthetic_batch(cfg, batch_size=8, seq_len=32)
     loss_fn = deepseek_v3.make_loss_fn(model)
     optimizer = deepseek_v3.make_optimizer(1e-2, cfg.load_balance_coeff)
-    grads = jax.grad(loss_fn)(params, {"tokens": jnp.asarray(batch["tokens"])})
+    grads = jax.jit(jax.grad(loss_fn))(
+        params, {"tokens": jnp.asarray(batch["tokens"])})
 
     ad = AutoDist(strategy_builder=AllReduce())
     runner = ad.create_distributed_session(loss_fn, params, optimizer,

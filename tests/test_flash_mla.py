@@ -74,8 +74,8 @@ def test_two_widths_forward_and_backward_match_dot_attention(
     assert out.shape == q.shape[:3] + (d_v,)
     np.testing.assert_allclose(out, dot(*args), rtol=2e-5, atol=2e-5)
     wrt = tuple(range(len(args)))
-    got = jax.grad(lambda *a: jnp.sum(flash(*a) * g), argnums=wrt)(*args)
-    want = jax.grad(lambda *a: jnp.sum(dot(*a) * g), argnums=wrt)(*args)
+    got = jax.jit(jax.grad(lambda *a: jnp.sum(flash(*a) * g), argnums=wrt))(*args)
+    want = jax.jit(jax.grad(lambda *a: jnp.sum(dot(*a) * g), argnums=wrt))(*args)
     for a, b in zip(got, want):
         assert a.shape == b.shape and a.dtype == b.dtype
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
@@ -96,8 +96,8 @@ def test_two_widths_forward_and_backward_match_dot_attention(
 
     out_rows = flash_rows(*args)
     np.testing.assert_array_equal(out_rows, rows(out))
-    got_rows = jax.grad(lambda *a: jnp.sum(flash_rows(*a) * rows(g)),
-                        argnums=wrt)(*args)
+    got_rows = jax.jit(jax.grad(lambda *a: jnp.sum(flash_rows(*a) * rows(g)),
+                                argnums=wrt))(*args)
     for a, b in zip(got_rows, got):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=2e-5)
     gauges = telemetry.snapshot()
@@ -140,8 +140,8 @@ def test_values_packed_behind_the_keys_are_the_two_operands(
 
     def run(attend):
         wrt = (0, 1, 2) if d_s else (0, 1)
-        return (attend(q, kv, k_shared),) + jax.grad(
-            lambda *a: jnp.sum(attend(*a) * g), argnums=wrt)(q, kv, k_shared)
+        return (attend(q, kv, k_shared),) + jax.jit(jax.grad(
+            lambda *a: jnp.sum(attend(*a) * g), argnums=wrt))(q, kv, k_shared)
 
     telemetry.registry().clear()
     got = run(packed)
@@ -153,10 +153,10 @@ def test_values_packed_behind_the_keys_are_the_two_operands(
     np.testing.assert_array_equal(got[0], want[0])
     for a, b in zip(got[1:], want[1:]):     # the order of D's float32 sum apart
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=2e-5)
-    want = jax.grad(lambda q, kv, ks: jnp.sum(_dot_attention(
+    want = jax.jit(jax.grad(lambda q, kv, ks: jnp.sum(_dot_attention(
         q, jnp.repeat(kv[..., :d_k], heads // kv_heads, axis=2),
         jnp.repeat(kv[..., d_k:], heads // kv_heads, axis=2), ks) * g),
-        argnums=1)(q, kv, k_shared)
+        argnums=1))(q, kv, k_shared)
     np.testing.assert_allclose(got[2], want, rtol=1e-4, atol=1e-4)
     with pytest.raises(ValueError, match="packed"):
         fa.flash_attention(q, kv[..., :d_k], None, k_shared=k_shared)
@@ -177,8 +177,9 @@ def test_the_shared_key_in_bfloat16_is_the_assembled_key():
     np.testing.assert_allclose(
         shared(q, k, v, k_shared).astype(jnp.float32),
         whole(q, assembled, v).astype(jnp.float32), rtol=2e-2, atol=2e-2)
-    dq, dk, dv, dks = jax.grad(loss(shared), argnums=(0, 1, 2, 3))(q, k, v, k_shared)
-    wq, wk, wv = jax.grad(loss(whole), argnums=(0, 1, 2))(q, assembled, v)
+    dq, dk, dv, dks = jax.jit(jax.grad(loss(shared), argnums=(0, 1, 2, 3)))(
+        q, k, v, k_shared)
+    wq, wk, wv = jax.jit(jax.grad(loss(whole), argnums=(0, 1, 2)))(q, assembled, v)
     close = lambda a, b: np.testing.assert_allclose(  # noqa: E731
         a.astype(jnp.float32), b.astype(jnp.float32), rtol=5e-2, atol=5e-2)
     close(dq, wq), close(dk, wk[..., :128]), close(dv, wv)

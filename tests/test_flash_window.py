@@ -69,7 +69,7 @@ def test_window_and_grouped_heads_match_dot_attention(monkeypatch, window, group
 
     def run(attend):
         loss = lambda q, k, v: jnp.sum(attend(q, k, v) * w)  # noqa: E731
-        return attend(q, k, v), jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+        return attend(q, k, v), jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
 
     out, grads = run(lambda q, k, v: fa.flash_attention(
         q, k, v, causal=True, window=window, q_block=q_block, k_block=k_block))
@@ -102,7 +102,7 @@ def test_grouped_heads_of_whole_lane_tiles_are_read_in_place(
 
     def run(attend, q, k, v, w):
         loss = lambda q, k, v: jnp.sum(attend(q, k, v) * w)  # noqa: E731
-        return (attend(q, k, v),) + jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+        return (attend(q, k, v),) + jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
 
     got = run(lambda q, k, v: fa.flash_attention(
         q, k, v, causal=True, window=window, q_block=q_block, k_block=k_block,
@@ -171,8 +171,8 @@ def test_tile_counts_under_the_band_equal_a_hand_count(length, tile, window, wan
 
 def test_the_gauges_count_the_band():
     q, k, v, w = _inputs(256, 2, 1)
-    jax.grad(lambda q: jnp.sum(fa.flash_attention(
-        q, k, v, window=128, q_block=64, k_block=64) * w))(q)
+    jax.jit(jax.grad(lambda q: jnp.sum(fa.flash_attention(
+        q, k, v, window=128, q_block=64, k_block=64) * w)))(q)
     for pass_ in ("fwd", "bwd"):
         got = tuple(telemetry.gauge(f"flash.{pass_}.tiles_{name}").value
                     for name in ("plain", "masked", "skipped"))

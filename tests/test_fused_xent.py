@@ -59,8 +59,8 @@ def test_grads_match_reference_f32():
     def ref(h, w, b):
         return jnp.sum(_ref_lse(h, w, b) * 0.01)
 
-    gf = jax.grad(fused, argnums=(0, 1, 2))(h, w, b)
-    gr = jax.grad(ref, argnums=(0, 1, 2))(h, w, b)
+    gf = jax.jit(jax.grad(fused, argnums=(0, 1, 2)))(h, w, b)
+    gr = jax.jit(jax.grad(ref, argnums=(0, 1, 2)))(h, w, b)
     for a, e in zip(gf, gr):
         np.testing.assert_allclose(a, e, rtol=2e-4, atol=2e-5)
 
@@ -181,16 +181,16 @@ def test_kernels_on_different_blocks_stay_value_exact(monkeypatch):
     b = b.at[7].set(95.0)
     np.testing.assert_allclose(fx.matmul_logsumexp(h, w, b), _ref_lse(h, w, b),
                                **_f32_tol())
-    gf = jax.grad(lambda h, w, b: jnp.sum(fx.matmul_logsumexp(h, w, b) * 0.01),
-                  argnums=(0, 1, 2))(h, w, b)
-    gr = jax.grad(lambda h, w, b: jnp.sum(_ref_lse(h, w, b) * 0.01),
-                  argnums=(0, 1, 2))(h, w, b)
+    gf = jax.jit(jax.grad(lambda h, w, b: jnp.sum(fx.matmul_logsumexp(h, w, b) * 0.01),
+                          argnums=(0, 1, 2)))(h, w, b)
+    gr = jax.jit(jax.grad(lambda h, w, b: jnp.sum(_ref_lse(h, w, b) * 0.01),
+                          argnums=(0, 1, 2)))(h, w, b)
     for a, e in zip(gf, gr):
         assert np.isfinite(np.asarray(a)).all()
         np.testing.assert_allclose(a, e, **_f32_tol(rtol=2e-4, atol=2e-5))
     # the stored [V, D] layout through the same three tilings
-    g_vd = jax.grad(lambda h, w, b: jnp.sum(fx.matmul_logsumexp(
-        h, w, b, w_layout="vd") * 0.01), argnums=(0, 1, 2))(h, w.T, b)
+    g_vd = jax.jit(jax.grad(lambda h, w, b: jnp.sum(fx.matmul_logsumexp(
+        h, w, b, w_layout="vd") * 0.01), argnums=(0, 1, 2)))(h, w.T, b)
     np.testing.assert_allclose(g_vd[1], gr[1].T, **_f32_tol(rtol=2e-4, atol=2e-5))
 
 
@@ -237,10 +237,10 @@ def test_shrunken_blocks_stay_value_exact(monkeypatch):
     h, w, b = _data(128, 64, 320, jnp.float32, seed=6)
     got = fx.matmul_logsumexp(h, w, b, 64, 256)
     np.testing.assert_allclose(got, _ref_lse(h, w, b), **_f32_tol())
-    gf = jax.grad(lambda h, w, b: jnp.sum(
-        fx.matmul_logsumexp(h, w, b, 64, 256) * 0.01), argnums=(0, 1, 2))(h, w, b)
-    gr = jax.grad(lambda h, w, b: jnp.sum(
-        _ref_lse(h, w, b) * 0.01), argnums=(0, 1, 2))(h, w, b)
+    gf = jax.jit(jax.grad(lambda h, w, b: jnp.sum(
+        fx.matmul_logsumexp(h, w, b, 64, 256) * 0.01), argnums=(0, 1, 2)))(h, w, b)
+    gr = jax.jit(jax.grad(lambda h, w, b: jnp.sum(
+        _ref_lse(h, w, b) * 0.01), argnums=(0, 1, 2)))(h, w, b)
     for a, e in zip(gf, gr):
         np.testing.assert_allclose(a, e, **_f32_tol(rtol=2e-4, atol=2e-5))
 
@@ -251,9 +251,9 @@ def test_grads_bf16_track_f32():
     def fused(h, w, b):
         return jnp.mean(matmul_logsumexp(h, w, b, 64, 128))
 
-    gf = jax.grad(fused, argnums=(0, 1))(h, w, b)
-    gr = jax.grad(
-        lambda h, w, b: jnp.mean(_ref_lse(h, w, b)), argnums=(0, 1))(
+    gf = jax.jit(jax.grad(fused, argnums=(0, 1)))(h, w, b)
+    gr = jax.jit(jax.grad(
+        lambda h, w, b: jnp.mean(_ref_lse(h, w, b)), argnums=(0, 1)))(
             h.astype(jnp.float32), w.astype(jnp.float32), b)
     for a, e in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a, np.float32), e,
@@ -273,13 +273,13 @@ def test_fused_xent_matches_composed_loss():
     np.testing.assert_allclose(nll, expected, **_f32_tol())
 
     # Full loss gradient (both the lse and the gathered true-logit paths).
-    gf = jax.grad(lambda h, w: jnp.mean(fused_softmax_xent(h, w, targets, b,
-                                                           64, 128)),
-                  argnums=(0, 1))(h, w)
-    gr = jax.grad(
+    gf = jax.jit(jax.grad(lambda h, w: jnp.mean(fused_softmax_xent(h, w, targets, b,
+                                                                   64, 128)),
+                          argnums=(0, 1)))(h, w)
+    gr = jax.jit(jax.grad(
         lambda h, w: jnp.mean(-jnp.take_along_axis(
             jax.nn.log_softmax(h @ w + b, axis=-1),
-            targets[:, None], axis=-1)[:, 0]), argnums=(0, 1))(h, w)
+            targets[:, None], axis=-1)[:, 0]), argnums=(0, 1)))(h, w)
     tol = _f32_tol(rtol=2e-4, atol=2e-5)
     for a, e in zip(gf, gr):
         np.testing.assert_allclose(a, e, **tol)
@@ -298,8 +298,8 @@ def test_vd_layout_matches_dv():
         return jnp.sum(matmul_logsumexp(h, w_vd, b, 64, 128, None, "vd") * 0.01)
 
     np.testing.assert_allclose(f_vd(h, w_vd, b), f_dv(h, w, b), rtol=1e-6)
-    g_dv = jax.grad(f_dv, argnums=(0, 1, 2))(h, w, b)
-    g_vd = jax.grad(f_vd, argnums=(0, 1, 2))(h, w_vd, b)
+    g_dv = jax.jit(jax.grad(f_dv, argnums=(0, 1, 2)))(h, w, b)
+    g_vd = jax.jit(jax.grad(f_vd, argnums=(0, 1, 2)))(h, w_vd, b)
     np.testing.assert_allclose(g_vd[0], g_dv[0], rtol=2e-4, atol=2e-5)
     np.testing.assert_allclose(g_vd[1], g_dv[1].T, rtol=2e-4, atol=2e-5)
     np.testing.assert_allclose(g_vd[2], g_dv[2], rtol=2e-4, atol=2e-5)
@@ -313,12 +313,13 @@ def test_large_bias_with_padding_rows_stays_finite():
     overflows exp in the pad rows and NaNs the whole dw/db."""
     h, w, b = _data(100, 64, 256, jnp.float32, seed=9)   # 28 pad rows at bn=128
     b = b.at[5].set(95.0)
-    grads = jax.grad(lambda h, w, b: jnp.mean(matmul_logsumexp(h, w, b, 128, 128)),
-                     argnums=(0, 1, 2))(h, w, b)
+    grads = jax.jit(jax.grad(
+        lambda h, w, b: jnp.mean(matmul_logsumexp(h, w, b, 128, 128)),
+        argnums=(0, 1, 2)))(h, w, b)
     for g_ in grads:
         assert np.isfinite(np.asarray(g_)).all()
-    gr = jax.grad(lambda h, w, b: jnp.mean(_ref_lse(h, w, b)),
-                  argnums=(0, 1, 2))(h, w, b)
+    gr = jax.jit(jax.grad(lambda h, w, b: jnp.mean(_ref_lse(h, w, b)),
+                          argnums=(0, 1, 2)))(h, w, b)
     for a, e in zip(grads, gr):
         np.testing.assert_allclose(a, e, rtol=2e-4, atol=2e-5)
 
@@ -340,8 +341,8 @@ def test_jit_and_value_under_jit():
 
 
 def _grads(h, w, b, coef, layout, n_block, v_block):
-    return jax.grad(lambda h, w, b: jnp.sum(matmul_logsumexp(
-        h, w, b, n_block, v_block, None, layout) * coef), argnums=(0, 1, 2))(h, w, b)
+    return jax.jit(jax.grad(lambda h, w, b: jnp.sum(matmul_logsumexp(
+        h, w, b, n_block, v_block, None, layout) * coef), argnums=(0, 1, 2)))(h, w, b)
 
 
 @pytest.mark.parametrize("rows", ["bf16-ragged", "f32-whole"])

@@ -51,10 +51,10 @@ def test_gmm_and_its_gradients_match_a_per_expert_loop(small_tiles, sizes, rows)
     got = gmm(x, w, group_sizes)
     np.testing.assert_allclose(got, _loop(x, w, sizes), rtol=1e-5, atol=1e-5)
 
-    dx, dw = jax.grad(lambda x, w: jnp.sum(gmm(x, w, group_sizes) * ct),
-                      argnums=(0, 1))(x, w)
-    rx, rw = jax.grad(lambda x, w: jnp.sum(_loop(x, w, sizes) * ct),
-                      argnums=(0, 1))(x, w)
+    dx, dw = jax.jit(jax.grad(lambda x, w: jnp.sum(gmm(x, w, group_sizes) * ct),
+                              argnums=(0, 1)))(x, w)
+    rx, rw = jax.jit(jax.grad(lambda x, w: jnp.sum(_loop(x, w, sizes) * ct),
+                              argnums=(0, 1)))(x, w)
     np.testing.assert_allclose(dx, rx, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(dw, rw, rtol=1e-5, atol=1e-5)
 
@@ -74,8 +74,8 @@ def test_gmm_bf16_rows_against_a_float32_bank(small_tiles):
     # one bfloat16 rounding of the result (2^-8 relative) on values of a few units
     np.testing.assert_allclose(got.astype(jnp.float32), want, rtol=1e-2,
                                atol=5e-2)
-    dx, dw = jax.grad(lambda x, w: gmm(x, w, group_sizes)
-                      .astype(jnp.float32).sum(), argnums=(0, 1))(x, w)
+    dx, dw = jax.jit(jax.grad(lambda x, w: gmm(x, w, group_sizes)
+                              .astype(jnp.float32).sum(), argnums=(0, 1)))(x, w)
     assert dx.dtype == jnp.bfloat16 and dw.dtype == jnp.float32
     assert float(jnp.abs(dw[1]).max()) == 0.0       # the empty group
 
@@ -111,10 +111,10 @@ def test_gmm_at_widths_that_are_no_multiple_of_128(small_tiles, k, n):
     group_sizes = jnp.asarray(sizes, jnp.int32)
     np.testing.assert_allclose(gmm(x, w, group_sizes), _loop(x, w, sizes),
                                rtol=1e-4, atol=1e-4)
-    got = jax.grad(lambda x, w: jnp.sum(gmm(x, w, group_sizes) * ct),
-                   argnums=(0, 1))(x, w)
-    want = jax.grad(lambda x, w: jnp.sum(_loop(x, w, sizes) * ct),
-                    argnums=(0, 1))(x, w)
+    got = jax.jit(jax.grad(lambda x, w: jnp.sum(gmm(x, w, group_sizes) * ct),
+                           argnums=(0, 1)))(x, w)
+    want = jax.jit(jax.grad(lambda x, w: jnp.sum(_loop(x, w, sizes) * ct),
+                            argnums=(0, 1)))(x, w)
     for g, r in zip(got, want):
         np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-4)
 
@@ -149,9 +149,9 @@ def test_gmm_at_the_nemotron_widths_in_real_tiles():
                                    dense(x, w), rtol=2e-2, atol=2e-2)
         loss = lambda fn: lambda x, w: jnp.sum(  # noqa: E731
             fn(x, w).astype(jnp.float32) * ct.astype(jnp.float32))
-        got = jax.grad(loss(lambda x, w: gmm(x, w, group_sizes)),
-                       argnums=(0, 1))(x, w)
-        want = jax.grad(loss(dense), argnums=(0, 1))(x, w)
+        got = jax.jit(jax.grad(loss(lambda x, w: gmm(x, w, group_sizes)),
+                               argnums=(0, 1)))(x, w)
+        want = jax.jit(jax.grad(loss(dense), argnums=(0, 1)))(x, w)
         for g, r in zip(got, want):
             scale = float(jnp.abs(r.astype(jnp.float32)).max())
             np.testing.assert_allclose(g.astype(jnp.float32),

@@ -22,6 +22,7 @@ if ROOT not in sys.path:
 from autodist_tpu import telemetry  # noqa: E402
 from autodist_tpu.models import jamba  # noqa: E402
 from benchmark.reference import jamba as reference  # noqa: E402
+from tests import reference_programs  # noqa: E402
 
 # the published rule (attn_layer_period 14, offset 7) at a toy width
 TOY = jamba.JambaConfig(vocab_size=203, d_model=64, n_layers=14, d_state=4,
@@ -214,18 +215,20 @@ def test_a_precise_layer_has_float32s_value_and_the_ordinary_derivative():
                   ("ordinary", cfg, False), ("precise", cfg, True),
                   ("float32", dataclasses.replace(cfg, dtype=jnp.float32), False))}
     params = blocks["ordinary"].init(jax.random.PRNGKey(6), x)
-    outs, pullbacks = {}, {}
+    cotangent = jnp.cos(jnp.arange(x.size, dtype=jnp.float32)).reshape(x.shape)
+    outs, pulled = {}, {}
     for name, block in blocks.items():
-        outs[name], pullbacks[name] = jax.vjp(
-            lambda p, x: block.apply(p, x)[0], params, x)  # noqa: B023
+        @jax.jit
+        def value_and_pullback(params, x, block=block):
+            out, pullback = jax.vjp(lambda p, x: block.apply(p, x)[0], params, x)
+            return out, pullback(cotangent)
+        outs[name], pulled[name] = value_and_pullback(params, x)
     distance = lambda got: float(  # noqa: E731
         jnp.linalg.norm(got - outs["float32"]) / jnp.linalg.norm(outs["float32"]))
     assert distance(outs["ordinary"]) > 1e-3
     assert distance(outs["precise"]) < 3e-5
-    cotangent = jnp.cos(jnp.arange(x.size, dtype=jnp.float32)).reshape(x.shape)
-    for got, plain in zip(
-            jax.tree_util.tree_leaves(pullbacks["precise"](cotangent)),
-            jax.tree_util.tree_leaves(pullbacks["ordinary"](cotangent))):
+    for got, plain in zip(jax.tree_util.tree_leaves(pulled["precise"]),
+                          jax.tree_util.tree_leaves(pulled["ordinary"])):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(plain))
 
 
@@ -256,8 +259,9 @@ def test_the_precise_first_layer_brings_bfloat16_gradients_nearer(remat,
                             dtype=jnp.bfloat16, chunk=16, remat=remat)
     _, params, batch = _case(cfg, 48)
     with jax.default_matmul_precision("highest"):
-        want = jax.jit(jax.grad(
-            lambda p: reference.loss(p, batch, **_reference_config(cfg))))(params)
+        # one program for [plain] and [remat]: the reference knows neither
+        _, want = reference_programs.value_and_grad(
+            "jamba", **_reference_config(cfg))(params, batch)
     distances = []
     for layers in (0, 1):
         monkeypatch.setattr(jamba, "PRECISE_LAYERS", layers)

@@ -25,6 +25,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from tests import reference_programs  # noqa: E402
+
 # Both layer kinds behind a dense conv layer, 2 query heads a KV head, the
 # share: experts 2-3 of 8, top-2; d a multiple of 128 for the conv kernels.
 TINY = dict(vocab_size=256, d_model=128, n_heads=4, n_kv_heads=2, head_dim=32,
@@ -69,7 +71,6 @@ def _with_bias(params, scale=0.05):
 ], ids=["f32-xla", "f32-kernels", "bf16-kernels"])
 def test_loss_and_gradients_match_the_plain_reference(dtype, attention, conv,
                                                       fused, loss_tol, grad_tol):
-    from benchmark.reference import lfm2_moe as reference
     cfg = lfm2_moe.Lfm2MoeConfig(dtype=dtype, attention_impl=attention,
                                  conv_impl=conv, fused_head=fused, **TINY)
     model, params = lfm2_moe.init_params(cfg, jax.random.PRNGKey(1))
@@ -79,9 +80,8 @@ def test_loss_and_gradients_match_the_plain_reference(dtype, attention, conv,
     loss, grads = jax.jit(jax.value_and_grad(lfm2_moe.make_loss_fn(model)))(
         params, batch)
     with jax.default_matmul_precision("highest"):
-        ref_loss, ref_grads = jax.jit(jax.value_and_grad(
-            lambda p, b: reference.loss(p, b, **_reference_kwargs(cfg))))(
-                params, batch)
+        ref_loss, ref_grads = reference_programs.value_and_grad(
+            "lfm2_moe", **_reference_kwargs(cfg))(params, batch)
     assert abs(float(loss) - float(ref_loss)) / float(ref_loss) <= loss_tol
     assert _rel_l2(grads, ref_grads) <= grad_tol
     assert {str(g.dtype) for g in jax.tree_util.tree_leaves(grads)} == {"float32"}
@@ -220,8 +220,8 @@ def test_a_layer_whose_held_rows_take_three_passes_equals_the_one_pass_layer():
             (y, bias_term), sown = layers[bound].apply(
                 {"params": params}, h, mutable=["intermediates"])
             return (y * target).sum() + bias_term, (y, sown["intermediates"])
-        (_, (y, sown)), grads = jax.value_and_grad(
-            loss, argnums=(0, 1), has_aux=True)(params, h)
+        (_, (y, sown)), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True))(params, h)
         return y, grads, sown
 
     y, grads, sown = run(48)
@@ -317,12 +317,13 @@ def test_a_step_through_the_normal_path_moves_the_bias_by_the_rule():
     batch = lfm2_moe.synthetic_batch(cfg, batch_size=8, seq_len=32)
     loss_fn = lfm2_moe.make_loss_fn(model)
     optimizer = lfm2_moe.make_optimizer(1e-2, cfg.load_balance_coeff)
-    grads = jax.grad(loss_fn)(params, {"tokens": jnp.asarray(batch["tokens"])})
+    grads = jax.jit(jax.grad(loss_fn))(
+        params, {"tokens": jnp.asarray(batch["tokens"])})
+    ad = AutoDist(strategy_builder=AllReduce())
+    runner = ad.create_distributed_session(loss_fn, params, optimizer,
+                                           example_batch=batch)
 
-    def one_run(steps):
-        ad = AutoDist(strategy_builder=AllReduce())
-        runner = ad.create_distributed_session(loss_fn, params, optimizer,
-                                               example_batch=batch)
+    def one_run(steps):     # one session, one compiled step, for both runs
         losses = []
         final = train(runner, params, iter([batch] * steps), steps=steps,
                       log_every=1,

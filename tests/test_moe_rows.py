@@ -210,8 +210,8 @@ def test_a_pass_on_the_kernels_is_the_pass_on_xlas_operations(c, dtype, tol):
     np.testing.assert_allclose(
         moe._held_pass(c, *args, perm, offsets, k, bound),
         _xla_held_pass(c, *args, perm, offsets, k, bound), rtol=tol, atol=tol)
-    got = jax.grad(loss(moe._held_pass), argnums=range(5))(*args)
-    want = jax.grad(loss(_xla_held_pass), argnums=range(5))(*args)
+    got = jax.jit(jax.grad(loss(moe._held_pass), argnums=range(5)))(*args)
+    want = jax.jit(jax.grad(loss(_xla_held_pass), argnums=range(5)))(*args)
     for g, w, name in zip(got, want, ("x", "weights", "gate", "up", "down")):
         assert g.dtype == w.dtype, name
         np.testing.assert_allclose(np.asarray(g, np.float32),
@@ -235,8 +235,10 @@ def test_the_passes_on_the_kernels_are_the_passes_on_xlas_operations(bound,
     ours = lambda *a: moe._held_passes(*a, perm, offsets, k, bound)  # noqa: E731
     xla = lambda *a: _xla_held_passes(*a, perm, offsets, k, bound, passes)  # noqa: E731
     np.testing.assert_allclose(ours(*args), xla(*args), rtol=1e-5, atol=1e-5)
-    got = jax.grad(lambda *a: (ours(*a) * target).sum(), argnums=range(5))(*args)
-    want = jax.grad(lambda *a: (xla(*a) * target).sum(), argnums=range(5))(*args)
+    got = jax.jit(jax.grad(lambda *a: (ours(*a) * target).sum(),
+                           argnums=range(5)))(*args)
+    want = jax.jit(jax.grad(lambda *a: (xla(*a) * target).sum(),
+                            argnums=range(5)))(*args)
     for g, w, name in zip(got, want, ("x", "weights", "gate", "up", "down")):
         np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5, err_msg=name)
 

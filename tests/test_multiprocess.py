@@ -12,6 +12,10 @@ same script as the worker (loopback, no SSH), both call
 collectives (gloo). Value-exactness is asserted against a hand-computed
 single-process SGD run — the reference's c0 criterion
 (``tests/integration/cases/c0.py:88-121``) across a process boundary.
+
+This file holds the strategy matrix; checkpoint / resume, sequence parallelism
+and the examples are ``test_multiprocess_checkpoint.py`` (one file is one
+``xdist`` worker's, so the two halves run side by side).
 """
 
 import json
@@ -19,6 +23,7 @@ import json
 import numpy as np
 
 import examples.multiprocess_linear_regression as mp_script
+from tests.mp_env import run_matrix_config as _run_matrix_config
 
 
 def _expected_params():
@@ -151,45 +156,6 @@ def test_cross_process_bounded_staleness_ps(tmp_path):
               f"version invariant held: {versions}")
 
 
-def _run_matrix_config(tmp_path, config):
-    """Run one strategy-matrix config in BOTH modes and return (single, two)."""
-    import os
-
-    import tests.strategy_matrix_mp_script as matrix
-
-    script = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                          "strategy_matrix_mp_script.py")
-    single_out = tmp_path / f"{config}_single.json"
-    proc = matrix.run_single_reference(str(single_out), config,
-                                       str(tmp_path / "workdir_single"))
-    assert proc.returncode == 0, (
-        f"single-process reference failed (rc={proc.returncode})\n"
-        f"--- stdout ---\n{proc.stdout}\n--- stderr ---\n{proc.stderr}")
-    two_out = tmp_path / f"{config}_two.json"
-    proc = mp_script.run_two_process_chief(
-        str(two_out), str(tmp_path / "workdir_two"), script=script,
-        extra_args=(config,))
-    assert proc.returncode == 0, (
-        f"2-process chief failed (rc={proc.returncode})\n"
-        f"--- stdout ---\n{proc.stdout}\n--- stderr ---\n{proc.stderr}")
-    single = json.loads(single_out.read_text())
-    two = json.loads(two_out.read_text())
-    procs = int(os.environ.get("AUTODIST_MATRIX_PROCS", "2"))
-    assert two["process_count"] == procs \
-        and two["device_count"] == 2 * procs
-    assert single["process_count"] == 1 \
-        and single["device_count"] == 2 * procs
-    # Same global mesh => the distributed run must be value-exact vs the
-    # single-process reference (the reference's c0 criterion per strategy,
-    # tests/integration/test_dist.py:14-42).
-    np.testing.assert_allclose(two["losses"], single["losses"],
-                               rtol=1e-5, atol=1e-6)
-    for k in single["params"]:
-        np.testing.assert_allclose(two["params"][k], single["params"][k],
-                                   rtol=1e-5, atol=1e-6, err_msg=k)
-    return single, two
-
-
 def test_cross_process_ps_zero_sharded_opt_state(tmp_path):
     """PS/ZeRO across 2 real processes: Adam moments physically sharded along
     the reduce axis that spans the process boundary, training value-exact."""
@@ -265,209 +231,3 @@ def test_cross_process_powersgd(tmp_path):
     exact vs the single-process run (deterministic QR + same shard count)."""
     single, two = _run_matrix_config(tmp_path, "powersgd")
     assert two["ef_params_dp"] == []  # PowerSGDState, not EFState, carries EF
-
-
-def _run_matrix_ckpt(tmp_path, monkeypatch, config):
-    """The reference c10 contract against cross-process-sharded state: a
-    2-process run saves (collective sharded write), DIES, a fresh 2-process
-    run restores and continues — and the stitched trajectory must match an
-    uninterrupted single-process run value-exactly."""
-    import os
-
-    import tests.strategy_matrix_mp_script as matrix
-
-    script = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                          "strategy_matrix_mp_script.py")
-    ckpt_dir = tmp_path / "ckpt"
-    ckpt_dir.mkdir()
-    monkeypatch.setenv("AUTODIST_MATRIX_CKPT_DIR", str(ckpt_dir))
-
-    straight_out = tmp_path / "straight.json"
-    proc = matrix.run_single_reference(str(straight_out), config,
-                                       str(tmp_path / "wd_straight"),
-                                       phase="straight")
-    assert proc.returncode == 0, (
-        f"straight reference failed (rc={proc.returncode})\n"
-        f"--- stdout ---\n{proc.stdout}\n--- stderr ---\n{proc.stderr}")
-
-    save_out = tmp_path / "save.json"
-    proc = mp_script.run_two_process_chief(
-        str(save_out), str(tmp_path / "wd_save"), script=script,
-        extra_args=(config, "ckpt_save"))
-    assert proc.returncode == 0, (
-        f"2-process save phase failed (rc={proc.returncode})\n"
-        f"--- stdout ---\n{proc.stdout}\n--- stderr ---\n{proc.stderr}")
-
-    restore_out = tmp_path / "restore.json"
-    proc = mp_script.run_two_process_chief(
-        str(restore_out), str(tmp_path / "wd_restore"), script=script,
-        extra_args=(config, "ckpt_restore"))
-    assert proc.returncode == 0, (
-        f"2-process restore phase failed (rc={proc.returncode})\n"
-        f"--- stdout ---\n{proc.stdout}\n--- stderr ---\n{proc.stderr}")
-
-    straight = json.loads(straight_out.read_text())
-    saved = json.loads(save_out.read_text())
-    restored = json.loads(restore_out.read_text())
-    assert saved["process_count"] == 2 and restored["process_count"] == 2
-
-    # The checkpoint is in the sharded format (per-process shard files +
-    # manifest) and no monolithic <name>-<step>.npz was ever assembled.
-    # Whether BOTH processes wrote depends on the config's layout (ownership
-    # dedups replicas to the lowest device id): the ZeRO test asserts it.
-    files = saved["ckpt_files"]
-    assert any(".shard00000-of-00002" in f for f in files), files
-    assert any(f == "model-3.json" for f in files), files
-    assert not any(f.endswith(".npz") and ".shard" not in f for f in files), files
-
-    # Stitched = straight, value-exact: losses before the kill, losses after
-    # the restore, and the final logical params.
-    np.testing.assert_allclose(saved["losses"],
-                               straight["losses"][:matrix.STEPS],
-                               rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(restored["losses"],
-                               straight["losses"][matrix.STEPS:],
-                               rtol=1e-5, atol=1e-6)
-    for k in straight["params"]:
-        np.testing.assert_allclose(restored["params"][k], straight["params"][k],
-                                   rtol=1e-5, atol=1e-6, err_msg=k)
-    return saved, restored
-
-
-def test_cross_process_checkpoint_zero_opt_state(tmp_path, monkeypatch):
-    """Save/kill/restore/continue with Adam moments physically sharded along
-    the process-spanning reduce axis (the state device_get cannot assemble)."""
-    saved, restored = _run_matrix_ckpt(tmp_path, monkeypatch, "ps")
-    # The restored run re-sharded the moments across processes again.
-    assert restored["w2_opt_shard_shapes"] == [[1, 4]]
-    # ZeRO moments span the process boundary, so BOTH processes wrote shards.
-    assert any(".shard00001-of-00002" in f for f in saved["ckpt_files"]), \
-        saved["ckpt_files"]
-
-
-def test_cross_process_checkpoint_padded_uneven(tmp_path, monkeypatch):
-    """Save/kill/restore/continue with the 7-row padded-to-8 parameter (and
-    its Adam moments) stored model-sharded across both processes; the
-    checkpoint itself holds logical (unpadded) shapes."""
-    saved, restored = _run_matrix_ckpt(tmp_path, monkeypatch, "partitioned")
-    assert restored["wu_storage_shape"] == [8, 4]
-    assert restored["wu_shard_shapes"] == [[4, 4]]
-
-
-def test_cross_process_train_loop_checkpoint_resume(tmp_path, monkeypatch):
-    """training.train's own save path inside a real 2-process run: collective
-    final save, then a fresh 2-process train() resumes from the latest
-    checkpoint automatically and finishes — params exactly match an
-    uninterrupted single-process straight run."""
-    import os
-
-    import tests.strategy_matrix_mp_script as matrix
-
-    script = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                          "strategy_matrix_mp_script.py")
-    ckpt_dir = tmp_path / "ckpt"
-    ckpt_dir.mkdir()
-    monkeypatch.setenv("AUTODIST_MATRIX_CKPT_DIR", str(ckpt_dir))
-
-    straight_out = tmp_path / "straight.json"
-    proc = matrix.run_single_reference(str(straight_out), "ps",
-                                       str(tmp_path / "wd_straight"),
-                                       phase="straight")
-    assert proc.returncode == 0, proc.stderr
-
-    for phase, out in (("train_save", tmp_path / "a.json"),
-                       ("train_resume", tmp_path / "b.json")):
-        proc = mp_script.run_two_process_chief(
-            str(out), str(tmp_path / f"wd_{phase}"), script=script,
-            extra_args=("ps", phase))
-        assert proc.returncode == 0, (
-            f"{phase} failed (rc={proc.returncode})\n"
-            f"--- stdout ---\n{proc.stdout}\n--- stderr ---\n{proc.stderr}")
-
-    straight = json.loads(straight_out.read_text())
-    resumed = json.loads((tmp_path / "b.json").read_text())
-    assert resumed["step"] == matrix.STEPS_TOTAL
-    # trainloop-3 was rotated/kept and trainloop-5 exists as sharded files.
-    assert any("trainloop-5" in f and ".shard" in f
-               for f in resumed["ckpt_files"]), resumed["ckpt_files"]
-    for k in straight["params"]:
-        np.testing.assert_allclose(resumed["params"][k], straight["params"][k],
-                                   rtol=1e-5, atol=1e-6, err_msg=k)
-
-
-def test_cross_process_ring_attention_sequence_parallel(tmp_path):
-    """Long-context across REAL processes: a 4-way seq axis spanning the
-    2-process boundary, so ring attention's K/V ppermute hops cross between
-    OS processes — value-exact vs the single-process run on the same mesh."""
-    import os
-
-    import tests.seq_parallel_mp_script as sp
-
-    script = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                          "seq_parallel_mp_script.py")
-    single_out = tmp_path / "sp_single.json"
-    proc = sp.run_single_reference(str(single_out), str(tmp_path / "wd_single"))
-    assert proc.returncode == 0, (
-        f"single-process SP reference failed (rc={proc.returncode})\n"
-        f"--- stdout ---\n{proc.stdout}\n--- stderr ---\n{proc.stderr}")
-
-    two_out = tmp_path / "sp_two.json"
-    proc = mp_script.run_two_process_chief(
-        str(two_out), str(tmp_path / "wd_two"), script=script)
-    assert proc.returncode == 0, (
-        f"2-process SP chief failed (rc={proc.returncode})\n"
-        f"--- stdout ---\n{proc.stdout}\n--- stderr ---\n{proc.stderr}")
-
-    single = json.loads(single_out.read_text())
-    two = json.loads(two_out.read_text())
-    assert two["process_count"] == 2 and two["mesh"]["seq"] == 4
-    np.testing.assert_allclose(two["losses"], single["losses"],
-                               rtol=1e-5, atol=1e-6)
-    for k in single["params_sample"]:
-        np.testing.assert_allclose(two["params_sample"][k],
-                                   single["params_sample"][k],
-                                   rtol=1e-5, atol=1e-6, err_msg=k)
-
-
-def test_async_ps_example_runs(tmp_path):
-    """The documented async-PS example (examples/async_ps_train.py) runs
-    end-to-end: 2 processes, all updates applied, wire accounting reported."""
-    import os
-
-    script = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                          "examples", "async_ps_train.py")
-    out = tmp_path / "example_summary.json"
-    proc = mp_script.run_two_process_chief(
-        str(out), str(tmp_path / "workdir"), script=script,
-        extra_args=("--steps", "4", "--out", str(out)))
-    assert proc.returncode == 0, (
-        f"example failed (rc={proc.returncode})\n"
-        f"--- stdout ---\n{proc.stdout}\n--- stderr ---\n{proc.stderr}")
-    summary = json.loads(out.read_text())
-    assert summary["applied_updates"] == 8  # 4 chief + 4 worker
-    assert summary["worker_wire_received_bytes"] > 0
-
-
-def test_auto_wired_cross_process_async_ps(tmp_path):
-    """The public API alone (2-node spec + PS(staleness)) wires the whole async
-    protocol: worker launch, transport address shipping, chief-side serving,
-    worker-side remote stepping — no manual plumbing in the user script."""
-    import os
-
-    import tests.auto_async_script as aas
-
-    script = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                          "auto_async_script.py")
-    out = tmp_path / "auto_async.json"
-    proc = mp_script.run_two_process_chief(
-        str(out), str(tmp_path / "workdir"), script=script)
-    assert proc.returncode == 0, (
-        f"chief failed (rc={proc.returncode})\n"
-        f"--- stdout ---\n{proc.stdout}\n--- stderr ---\n{proc.stderr}")
-    result = json.loads(out.read_text())
-
-    assert result["num_worker_slots"] == 2
-    # Every step from BOTH processes was applied by the chief's service.
-    assert result["final_version"] == result["chief_steps"] + result["worker_steps"]
-    assert result["chief_losses"][-1] < result["chief_losses"][0]
-    assert np.isfinite(result["w"]) and result["w"] != 0.0

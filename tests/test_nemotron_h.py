@@ -4,13 +4,12 @@ against the plain reference the benchmark checks it with on the chip
 ``MEMEM*EME``, the parameter tree the equations name, the gated grouped norm
 and the initialisation by hand, one chip's share of the ``relu2`` experts (the
 16 shares of a 128-wide router and the shared expert once add up to the uncut
-layer), per-layer recomputation, the code the three sigmoid-routed families
-share (``models/moe.py``) and a step through the normal path. Tiny widths on
+layer), the code the three sigmoid-routed families share (``models/moe.py``)
+and a step through the normal path; per-layer recomputation is
+``test_nemotron_h_recompute.py``. Tiny widths (``tests/nemotron_tiny.py``) on
 the CPU mesh; kernels in interpret mode."""
 
-import collections
 import dataclasses
-import functools
 import math
 import os
 import sys
@@ -21,21 +20,15 @@ import numpy as np
 import pytest
 
 from autodist_tpu import AutoDist, telemetry, train
-from autodist_tpu.models import afmoe, common, lfm2_moe, moe, nemotron_h
+from autodist_tpu.models import afmoe, lfm2_moe, moe, nemotron_h
 from autodist_tpu.strategy import AllReduce
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-# The cell's pattern; 2 heads a group and 2 query heads a KV head; the share:
-# experts 2-4 of 8, top-3; a state and a group's heads x head_dim of 128 lanes
-# and chunks of 128 for the scan's kernels.
-TINY = dict(vocab_size=256, d_model=64, pattern="MEMEM*EME", mamba_heads=4,
-            mamba_head_dim=64, n_groups=2, d_state=128, conv_kernel=4, chunk=128,
-            n_heads=4, n_kv_heads=2, head_dim=16, d_expert=24, d_shared=40,
-            n_experts_routed=8, experts_held=3, first_expert_held=2, top_k=3,
-            max_len=64)
+from tests import reference_programs  # noqa: E402
+from tests.nemotron_tiny import TINY, stirred  # noqa: E402
 
 
 def _share(cfg):
@@ -59,19 +52,6 @@ def _reference_kwargs(cfg):
                 first_expert_held=cfg.first_expert_held)
 
 
-def _stirred(params, scale=0.2):
-    """The leaves that init sets to constants (zeros, ones, a ramp), drawn:
-    an ``expert_bias`` large enough to change choices, a ``D``, a norm weight
-    and a convolution bias that a dropped factor would show in."""
-    def draw(path, x):
-        if path[-1].key not in ("expert_bias", "D", "A_log", "norm", "scale",
-                                "conv_bias"):
-            return x
-        key = jax.random.PRNGKey(sum(map(ord, jax.tree_util.keystr(path))))
-        return x + scale * jax.random.normal(key, x.shape)
-    return jax.tree_util.tree_map_with_path(draw, params)
-
-
 # The tolerances are OLMoE's, AFMoE's and LFM2's, for their reasons: float32
 # activations agree to rounding, bfloat16 to parts in a thousand of the loss
 # and a few percent of the gradient; a dropped term moves either by far more.
@@ -87,21 +67,19 @@ def _stirred(params, scale=0.2):
 def test_loss_and_gradients_match_the_plain_reference(dtype, attention, ssm,
                                                       fused, remat, exact,
                                                       loss_tol, grad_tol):
-    from benchmark.reference import nemotron_h as reference
     cfg = nemotron_h.NemotronHConfig(dtype=dtype, attention_impl=attention,
                                      ssm_impl=ssm, fused_head=fused, remat=remat,
                                      exact_first_layer=exact, rows_bound=40,
                                      **TINY)
     model, params = nemotron_h.init_params(cfg, jax.random.PRNGKey(1))
-    params = _stirred(params)
+    params = stirred(params)
     batch = {"tokens": jnp.asarray(
         nemotron_h.synthetic_batch(cfg, 2, 40, seed=3)["tokens"])}
     loss, grads = jax.jit(jax.value_and_grad(nemotron_h.make_loss_fn(model)))(
         params, batch)
     with jax.default_matmul_precision("highest"):
-        ref_loss, ref_grads = jax.jit(jax.value_and_grad(
-            lambda p, b: reference.loss(p, b, **_reference_kwargs(cfg))))(
-                params, batch)
+        ref_loss, ref_grads = reference_programs.value_and_grad(
+            "nemotron_h", **_reference_kwargs(cfg))(params, batch)
     assert abs(float(loss) - float(ref_loss)) / float(ref_loss) <= loss_tol
     assert _rel_l2(grads, ref_grads) <= grad_tol
     assert {str(g.dtype) for g in jax.tree_util.tree_leaves(grads)} == {"float32"}
@@ -201,7 +179,7 @@ def test_the_sixteen_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer
     d, tokens = cfg.d_model, 40
     whole = _share(cfg).init(
         jax.random.PRNGKey(2), jnp.zeros((1, 4, d)))["params"]
-    whole = _stirred(whole)
+    whole = stirred(whole)
     h = jax.random.normal(jax.random.PRNGKey(3), (1, tokens, d))
     shared = moe.PlainMLP(cfg.d_shared, jnp.float32).apply(
         {"params": whole["shared"]}, h)
@@ -231,155 +209,6 @@ def test_the_sixteen_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer
     one, _ = _share(cfg).apply({"params": whole}, h)
     np.testing.assert_allclose(one, uncut.reshape(1, tokens, d),
                                rtol=1e-4, atol=1e-5)
-
-
-@pytest.mark.parametrize("dtype,kernels,rtol,atol", [
-    (jnp.float32, False, 1e-5, 1e-7),
-    # the kept values are the values a second forward would make: bfloat16
-    # through the kernels agrees as float32 does (the tolerance is XLA's, which
-    # fuses the two programs differently, not bfloat16's)
-    (jnp.bfloat16, True, 1e-5, 1e-7),
-], ids=["f32-xla", "bf16-kernels"])
-def test_recomputing_every_layer_changes_no_number(dtype, kernels, rtol, atol):
-    cfg = nemotron_h.NemotronHConfig(
-        dtype=dtype, rows_bound=40, exact_first_layer=kernels,
-        **(dict(attention_impl="flash", ssm_impl="pallas") if kernels else {}),
-        **TINY)
-    model, params = nemotron_h.init_params(cfg, jax.random.PRNGKey(1))
-    params = _stirred(params)
-    batch = {"tokens": jnp.asarray(
-        nemotron_h.synthetic_batch(cfg, 2, 24, seed=5)["tokens"])}
-    plain = jax.jit(jax.value_and_grad(nemotron_h.make_loss_fn(model)))(
-        params, batch)
-    again = jax.jit(jax.value_and_grad(nemotron_h.make_loss_fn(
-        nemotron_h.NemotronH(dataclasses.replace(cfg, remat=True)))))(params, batch)
-    jax.tree_util.tree_map(
-        lambda a, b: np.testing.assert_allclose(a, b, rtol=rtol, atol=atol),
-        plain, again)
-    # and the layer's loads and passes are sown under it as without it
-    _, sown = nemotron_h.NemotronH(dataclasses.replace(cfg, remat=True)).apply(
-        {"params": params}, batch["tokens"][:, :-1], return_hidden=True,
-        mutable=["intermediates"])
-    loads = nemotron_h.sown_loads(sown["intermediates"])
-    assert loads.shape == (4, 8) and float(loads.sum()) == 4 * 2 * 24 * 3
-    assert moe.sown_passes(sown["intermediates"]).shape == (4,)
-
-
-def _kernel_calls(jaxpr, counts=None):
-    """Pallas calls by kernel name, sub-programs included."""
-    counts = collections.Counter() if counts is None else counts
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            counts[str(eqn.params["name"])] += 1
-            continue
-        for param in eqn.params.values():
-            for sub in (param if isinstance(param, (tuple, list)) else [param]):
-                sub = getattr(sub, "jaxpr", sub)
-                if hasattr(sub, "eqns"):
-                    _kernel_calls(sub, counts)
-    return counts
-
-
-@pytest.fixture(scope="module")
-def gradient_programs():
-    """``{remat: (kernel calls of jax.grad(loss) by name, the remat.* gauges
-    its trace left)}`` of the cell's own settings at the tiny widths."""
-    found = {}
-    for remat in (False, True):
-        telemetry.registry().clear()
-        cfg = nemotron_h.NemotronHConfig(
-            attention_impl="flash", ssm_impl="pallas", remat=remat,
-            exact_first_layer=True, rows_bound=40, **TINY)
-        model, params = nemotron_h.init_params(cfg, jax.random.PRNGKey(1))
-        batch = {"tokens": jnp.asarray(
-            nemotron_h.synthetic_batch(cfg, 2, 40, seed=3)["tokens"])}
-        program = jax.make_jaxpr(jax.grad(nemotron_h.make_loss_fn(model)))(
-            params, batch)
-        found[remat] = (_kernel_calls(program.jaxpr),
-                        {k: v for k, v in telemetry.snapshot().items()
-                         if k.startswith("remat.")})
-    return found
-
-
-@pytest.mark.parametrize("kernel,calls,under_remat", [
-    ("flash_fwd", 1, 1), ("flash_bwd_dkv", 1, 1), ("ssd_fwd", 4, 4),
-    ("ssd_bwd", 4, 4), ("conv_silu_fwd", 4, 8), ("conv_silu_bwd", 4, 4),
-    ("moe_gmm_fwd", 24, 24)])
-def test_a_checkpointed_layer_runs_each_kept_forward_kernel_once(
-        gradient_programs, kernel, calls, under_remat):
-    """Four Mamba-2 layers and one attention layer: under ``remat`` the
-    policy keeps what flash's and the scan's forward rules hand their
-    backward, so the gradient program launches those forward kernels once a
-    layer, as without ``remat`` (a bare ``jax.checkpoint`` launched each
-    twice), and pass 0 of the routed share likewise (its two forward products
-    ran again: 32); the convolution's output is not on the list and its
-    forward kernel runs again."""
-    assert gradient_programs[False][0][kernel] == calls
-    assert gradient_programs[True][0][kernel] == under_remat
-
-
-# What a layer of each kind keeps at the tiny widths, 2 sequences of 40: a
-# Mamba-2 layer its [z | xBC | dt], the scan's y (whole chunks of 128) and one
-# [64, 128] state a chunk and head; an expert layer the shared expert's up
-# product, the router's logits and what pass 0 over the 40 rows of the bound
-# makes for its transpose (the gathered rows, the up product, its relu and its
-# mask, relu2, the down product that the weights' gradient reads, the rows'
-# weights and their indices); attention q, k, v, flash's output and its
-# log-sum-exp.
-KEPT_BY_KIND = {
-    nemotron_h.MAMBA: [((2, 40, 1028), "bfloat16"), ((2, 128, 4, 64), "bfloat16"),
-                       ((2, 1, 2, 2, 64, 128), "float32")],
-    nemotron_h.EXPERTS: [((2, 40, 40), "bfloat16"), ((80, 8), "float32"),
-                         ((40, 64), "bfloat16"), ((40, 24), "bfloat16"),
-                         ((40, 24), "bfloat16"), ((40, 24), "bool"),
-                         ((40, 24), "bfloat16"), ((40, 64), "bfloat16"),
-                         ((40,), "float32"), ((40,), "float32"), ((40,), "bool"),
-                         ((40,), "int32"), ((40,), "int32"), ((40, 1), "int32"),
-                         ((3,), "int32"), ((2,), "int32"), ((), "int32")],
-    nemotron_h.ATTENTION: [((2, 40, 64), "bfloat16"), ((2, 40, 32), "bfloat16"),
-                           ((2, 40, 32), "bfloat16"), ((2, 40, 4, 16), "bfloat16"),
-                           ((8, 1, 40), "float32")],
-}
-
-
-def _bytes(kept):
-    return sum(math.prod(shape) * jnp.dtype(dtype).itemsize for shape, dtype in kept)
-
-
-def test_the_kept_values_are_booked_and_absent_without_remat(gradient_programs):
-    kept = [v for kind in TINY["pattern"] for v in KEPT_BY_KIND[kind]]
-    assert gradient_programs[True][1] == {
-        "remat.layers": 9, "remat.kept_values": len(kept),
-        # layer 0's [z | xBC | dt] is float32 under exact_first_layer
-        "remat.kept_bytes": _bytes(kept) + 2 * 40 * 1028 * 2}
-    assert gradient_programs[False][1] == {}
-
-
-@pytest.mark.parametrize("kind", KEPT_BY_KIND, ids=["mamba", "experts", "attention"])
-def test_a_checkpointed_layer_keeps_the_listed_values_and_nothing_else(kind):
-    """What one layer under the model's policy hands its backward, by JAX's
-    own account: its arguments and exactly the listed values, so no mixer's
-    last product (``out_proj``'s, ``down``'s: the next layer keeps the sum as
-    its own input) and nothing the elementwise rest makes."""
-    from jax._src.ad_checkpoint import saved_residuals
-    telemetry.registry().clear()
-    cfg = nemotron_h.NemotronHConfig(attention_impl="flash", ssm_impl="pallas",
-                                     rows_bound=40, **TINY)
-    block = nemotron_h.NemotronHBlock(cfg, kind)
-    x = jax.random.normal(jax.random.PRNGKey(0), (2, 40, cfg.d_model))
-    params = block.init(jax.random.PRNGKey(1), x)["params"]
-
-    @functools.partial(jax.checkpoint, policy=common.keeping(nemotron_h.KEPT))
-    def layer(params, x):
-        y, term = block.apply({"params": params}, x)
-        return jnp.sum(jnp.square(y)) + term
-
-    made = [(aval.shape, str(aval.dtype))
-            for aval, how in saved_residuals(layer, params, x)
-            if "from the argument" not in how]
-    assert sorted(made) == sorted(KEPT_BY_KIND[kind])
-    assert telemetry.gauge("remat.kept_values").value == len(made)
-    assert telemetry.gauge("remat.kept_bytes").value == _bytes(made)
 
 
 def test_the_three_families_share_the_mixtures_code_and_none_copies_it():
@@ -432,16 +261,17 @@ def test_a_step_through_the_normal_path_moves_the_bias_by_the_rule():
         fused_head=True, remat=True, load_balance_coeff=1e-3,
         **dict(TINY, pattern="ME*M"))
     model, params = nemotron_h.init_params(cfg)
-    params = _stirred(params, scale=0.05)
+    params = stirred(params, scale=0.05)
     batch = nemotron_h.synthetic_batch(cfg, batch_size=8, seq_len=32)
     loss_fn = nemotron_h.make_loss_fn(model)
     optimizer = nemotron_h.make_optimizer(1e-2, cfg.load_balance_coeff)
-    grads = jax.grad(loss_fn)(params, {"tokens": jnp.asarray(batch["tokens"])})
+    grads = jax.jit(jax.grad(loss_fn))(
+        params, {"tokens": jnp.asarray(batch["tokens"])})
+    ad = AutoDist(strategy_builder=AllReduce())
+    runner = ad.create_distributed_session(loss_fn, params, optimizer,
+                                           example_batch=batch)
 
-    def one_run(steps):
-        ad = AutoDist(strategy_builder=AllReduce())
-        runner = ad.create_distributed_session(loss_fn, params, optimizer,
-                                               example_batch=batch)
+    def one_run(steps):     # one session, one compiled step, for both runs
         losses = []
         final = train(runner, params, iter([batch] * steps), steps=steps,
                       log_every=1,

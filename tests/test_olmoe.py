@@ -21,6 +21,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from tests import reference_programs  # noqa: E402
+
 TINY = dict(vocab_size=256, d_model=64, n_heads=4, n_layers=2, d_expert=32,
             n_experts=8, top_k=2, max_len=32)
 
@@ -45,7 +47,6 @@ def _rel_l2(a, b):
 ], ids=["f32-xla", "f32-kernels", "bf16-kernels"])
 def test_loss_and_gradients_match_the_plain_reference(dtype, attention, fused,
                                                       loss_tol, grad_tol):
-    from benchmark.reference import olmoe as reference
     cfg = olmoe.OlmoeConfig(dtype=dtype, attention_impl=attention,
                             fused_head=fused, **TINY)
     model, params = olmoe.init_params(cfg, jax.random.PRNGKey(1))
@@ -54,12 +55,11 @@ def test_loss_and_gradients_match_the_plain_reference(dtype, attention, fused,
     loss, grads = jax.jit(jax.value_and_grad(olmoe.make_loss_fn(model)))(
         params, batch)
     with jax.default_matmul_precision("highest"):
-        ref_loss, ref_grads = jax.jit(jax.value_and_grad(
-            lambda p, b: reference.loss(
-                p, b, n_heads=cfg.n_heads, n_layers=cfg.n_layers,
-                top_k=cfg.top_k, rms_eps=cfg.rms_eps, rope_theta=cfg.rope_theta,
-                load_balance_weight=cfg.load_balance_weight,
-                router_z_weight=cfg.router_z_weight)))(params, batch)
+        ref_loss, ref_grads = reference_programs.value_and_grad(
+            "olmoe", n_heads=cfg.n_heads, n_layers=cfg.n_layers,
+            top_k=cfg.top_k, rms_eps=cfg.rms_eps, rope_theta=cfg.rope_theta,
+            load_balance_weight=cfg.load_balance_weight,
+            router_z_weight=cfg.router_z_weight)(params, batch)
     assert abs(float(loss) - float(ref_loss)) / float(ref_loss) <= loss_tol
     assert _rel_l2(grads, ref_grads) <= grad_tol
     assert {str(g.dtype) for g in jax.tree_util.tree_leaves(grads)} == {"float32"}
@@ -169,8 +169,8 @@ def test_routed_experts_equal_every_expert_under_a_mask_and_build_no_capacity_te
                                dense(x, probs, gate, up, down),
                                rtol=1e-5, atol=1e-5)
     args = (x, probs, gate, up, down)
-    got = jax.grad(lambda *a: routed(*a).sum(), argnums=(0, 1, 2, 3, 4))(*args)
-    want = jax.grad(lambda *a: dense(*a).sum(), argnums=(0, 1, 2, 3, 4))(*args)
+    got = jax.jit(jax.grad(lambda *a: routed(*a).sum(), argnums=(0, 1, 2, 3, 4)))(*args)
+    want = jax.jit(jax.grad(lambda *a: dense(*a).sum(), argnums=(0, 1, 2, 3, 4)))(*args)
     for g, r in zip(got, want):
         np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-5)
     # Nothing in the routed program, forward or backward, is as large as a

@@ -202,3 +202,32 @@ def test_dump_observed_writes_meta_for_edge_free_run(tmp_path):
         lines = [json.loads(line) for line in open(out, encoding="utf-8")]
         assert lines and "meta" in lines[0]
         assert lines[0]["meta"]["edges"] == 0
+
+
+# ------------------------------------------------- every test's own clock
+
+def test_a_test_past_its_limit_fails_by_name_and_leaves_no_timer(request,
+                                                                 monkeypatch):
+    """``conftest.clock`` (the autouse fixture around every test): past
+    ``LIMIT`` the test fails with its own node id, and neither the timer nor
+    the handler outlives it."""
+    import signal
+    import time
+
+    import conftest
+
+    monkeypatch.setattr(conftest, "LIMIT", 0.2)
+    before = signal.getsignal(signal.SIGALRM)
+    started = time.monotonic()
+    with pytest.raises(pytest.fail.Exception) as caught:
+        with conftest.clock(request.node.nodeid):
+            time.sleep(30)
+    assert time.monotonic() - started < 5
+    assert str(caught.value) == f"{request.node.nodeid} exceeded 0.2 s"
+    assert "test_a_test_past_its_limit" in str(caught.value)
+    assert signal.getitimer(signal.ITIMER_REAL) == (0.0, 0.0)
+    assert signal.getsignal(signal.SIGALRM) is before
+    # a test inside its limit is left alone, and disarms on its way out too
+    with conftest.clock(request.node.nodeid):
+        assert signal.getitimer(signal.ITIMER_REAL)[0] > 0
+    assert signal.getitimer(signal.ITIMER_REAL) == (0.0, 0.0)

@@ -43,9 +43,15 @@ def _operands(b, length, e, n, seed=0, dtype=jnp.float32):
 
 
 def _value_and_grads(fn, inputs):
+    """``fn``'s result and the six gradients of a loss on it: one compiled
+    program and one forward (eagerly the interpreted kernels and the token
+    loop run operation by operation, the forward twice)."""
     def loss(*a):
-        return jnp.sum(jnp.sin(fn(*a).astype(jnp.float32)))
-    return fn(*inputs), jax.grad(loss, argnums=tuple(range(6)))(*inputs)
+        y = fn(*a)
+        return jnp.sum(jnp.sin(y.astype(jnp.float32))), y
+    (_, y), grads = jax.jit(jax.value_and_grad(
+        loss, argnums=tuple(range(6)), has_aux=True))(*inputs)
+    return y, grads
 
 
 def _distance(a, b):
@@ -103,7 +109,8 @@ def test_two_channel_tiles_and_sequences_share_nothing():
         assert _distance(got, want) < 1e-5, name
     alone = tuple(t[:1] if t.ndim == 3 else t for t in inputs)
     np.testing.assert_allclose(
-        selective_scan(*alone, chunk=64, impl="pallas"), got_y[:1], rtol=1e-6)
+        jax.jit(lambda *a: selective_scan(*a, chunk=64, impl="pallas"))(*alone),
+        got_y[:1], rtol=1e-6)
 
 
 def _eqns(jaxpr):
@@ -204,7 +211,7 @@ def test_kernels_run_per_device_under_a_mesh():
     def loss(*a):
         return jnp.sum(jnp.sin(selective_scan(*a, chunk=64, impl="pallas")))
 
-    want = jax.grad(loss, argnums=(0, 2, 5))(*inputs)
+    want = jax.jit(jax.grad(loss, argnums=(0, 2, 5)))(*inputs)
     placed = tuple(jax.device_put(t, NamedSharding(
         mesh, P("data") if t.ndim == 3 else P())) for t in inputs)
     with mesh:

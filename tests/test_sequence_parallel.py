@@ -52,7 +52,7 @@ def test_sp_loss_and_grads_match_single_device():
     batch = _batch(cfg)
 
     ref_loss_fn = transformer_lm.make_loss_fn(model_dot)
-    ref_loss, ref_grads = jax.value_and_grad(ref_loss_fn)(params, batch)
+    ref_loss, ref_grads = jax.jit(jax.value_and_grad(ref_loss_fn))(params, batch)
 
     ad = AutoDist(strategy_builder=SequenceParallel(seq_axis_size=4))
     runner = create_sequence_parallel_session(ad, model_ring, params,
@@ -190,8 +190,8 @@ def test_ulysses_sp_loss_and_grads_match_single_device():
     model_dot, _, _ = _model("dot")
     batch = _batch(cfg)
 
-    ref_loss, ref_grads = jax.value_and_grad(
-        transformer_lm.make_loss_fn(model_dot))(params, batch)
+    ref_loss, ref_grads = jax.jit(jax.value_and_grad(
+        transformer_lm.make_loss_fn(model_dot)))(params, batch)
 
     ad = AutoDist(strategy_builder=SequenceParallel(seq_axis_size=2))
     runner = create_sequence_parallel_session(ad, model_ul, params, optax.sgd(0.1))

@@ -62,9 +62,15 @@ def _operands(b, length, h, p, g, n, seed=0, dtype=jnp.float32):
 
 
 def _value_and_grads(fn, inputs, weight):
-    return jax.value_and_grad(
-        lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * weight),
-        argnums=tuple(range(6)), has_aux=False)(*inputs), fn(*inputs)
+    """``((loss, its six gradients), fn's result)``: one compiled program and
+    one forward (eagerly the interpreted kernels and the recurrence run
+    operation by operation, the forward twice)."""
+    def loss(*a):
+        y = fn(*a)
+        return jnp.sum(y.astype(jnp.float32) * weight), y
+    (value, y), grads = jax.jit(jax.value_and_grad(
+        loss, argnums=tuple(range(6)), has_aux=True))(*inputs)
+    return (value, grads), y
 
 
 # (b, L, H, P, G, N, chunk): the kernels want N, a group's heads x P and the
@@ -81,14 +87,21 @@ CASES = {
 }
 
 
+@functools.lru_cache(maxsize=None)
+def _scanned(case):
+    """A case's operands and what ``ssd_scan`` makes of them, once for the two
+    forms it is held against."""
+    impl, (*shape, chunk) = CASES[case]
+    inputs, weight = _operands(*shape)
+    fn = lambda *a: ssd_scan(*a, chunk=chunk, impl=impl)  # noqa: E731
+    return inputs, weight, _value_and_grads(fn, inputs, weight)
+
+
 @pytest.mark.parametrize("against", ["recurrence", "quadratic"])
 @pytest.mark.parametrize("case", list(CASES))
 def test_values_and_every_gradient_match(case, against):
-    impl, (*shape, chunk) = CASES[case]
-    inputs, weight = _operands(*shape)
+    inputs, weight, ((_, got), y) = _scanned(case)
     plain = {"recurrence": recurrence, "quadratic": quadratic}[against]
-    fn = lambda *a: ssd_scan(*a, chunk=chunk, impl=impl)  # noqa: E731
-    (_, got), y = _value_and_grads(fn, inputs, weight)
     (_, want), want_y = _value_and_grads(plain, inputs, weight)
     np.testing.assert_allclose(y, want_y, rtol=2e-4, atol=2e-4)
     for name, g, r in zip(("dx", "ddt", "dA", "dB", "dC", "dD"), got, want):
@@ -106,7 +119,7 @@ def test_no_state_crosses_from_one_sequence_to_the_next(impl, shape):
     second's outputs."""
     *dims, chunk = shape
     inputs, weight = _operands(*dims)
-    fn = lambda *a: ssd_scan(*a, chunk=chunk, impl=impl)  # noqa: E731
+    fn = jax.jit(lambda *a: ssd_scan(*a, chunk=chunk, impl=impl))
     alone = fn(*(t[1:] if t.ndim > 1 else t for t in inputs))
     np.testing.assert_allclose(fn(*inputs)[1:], alone, rtol=1e-5, atol=1e-5)
     loud = tuple(t.at[0].multiply(50.0) if t.ndim == 4 else t for t in inputs)
@@ -201,7 +214,7 @@ def _shifted_silu(x, w, b):
         "kernels-ragged", "kernels-shorter-than-a-step", "kernels-two-channel-blocks"])
 def test_conv_silu_and_its_three_gradients_match_shifted_products(impl, batch,
                                                                   length, d, k):
-    conv = functools.partial(short_conv.conv_silu, impl=impl)
+    conv = jax.jit(functools.partial(short_conv.conv_silu, impl=impl))
     keys = jax.random.split(jax.random.PRNGKey(0), 4)
     x = jax.random.normal(keys[0], (batch, length, d))
     w = jax.random.normal(keys[1], (d, k))
@@ -210,8 +223,8 @@ def test_conv_silu_and_its_three_gradients_match_shifted_products(impl, batch,
     loss = lambda fn: lambda *a: jnp.sum(fn(*a) * weight)  # noqa: E731
     np.testing.assert_allclose(conv(x, w, b), _shifted_silu(x, w, b),
                                rtol=1e-5, atol=1e-5)
-    got = jax.grad(loss(conv), argnums=(0, 1, 2))(x, w, b)
-    want = jax.grad(loss(_shifted_silu), argnums=(0, 1, 2))(x, w, b)
+    got = jax.jit(jax.grad(loss(conv), argnums=(0, 1, 2)))(x, w, b)
+    want = jax.jit(jax.grad(loss(_shifted_silu), argnums=(0, 1, 2)))(x, w, b)
     for g, r in zip(got, want):
         np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-4 * float(jnp.abs(r).max()))
     # each sequence on its own, and only x, w and b kept for the backward
