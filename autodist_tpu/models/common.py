@@ -27,12 +27,22 @@ class RMSNorm(nn.Module):
         return (x * rms * scale).astype(self.dtype)
 
 
-def rope(x, positions, theta: float = 10000.0):
-    """Rotary position embedding, rotate-half form over the whole head dim.
+def rope(x, positions, theta: float = 10000.0,
+         rotary_dim: Optional[int] = None):
+    """Rotary position embedding, rotate-half form over the first
+    ``rotary_dim`` columns of the head dim (None: the whole of it).
 
-    x: ``[..., L, H, D]`` (D even); positions: ``[L]``. The pair
-    ``(x[i], x[i + D/2])`` is rotated by ``position * theta^(-2i/D)``; the
-    rotation is computed in float32 and cast back to ``x.dtype``."""
+    x: ``[..., L, H, D]``; positions: ``[L]``. With ``R = rotary_dim`` (even),
+    the pair ``(x[i], x[i + R/2])``, ``i < R/2``, is rotated by ``position *
+    theta^(-2i/R)`` and the columns from ``R`` on pass as they are (a partial
+    rotary factor: MiMo-V2 turns 64 of 192); the rotation is computed in
+    float32 and cast back to ``x.dtype``."""
+    if rotary_dim is not None and rotary_dim < x.shape[-1]:
+        if rotary_dim % 2:
+            raise ValueError(f"rotary_dim {rotary_dim} must be even")
+        return jnp.concatenate(
+            [rope(x[..., :rotary_dim], positions, theta), x[..., rotary_dim:]],
+            axis=-1)
     d = x.shape[-1]
     inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     angles = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]

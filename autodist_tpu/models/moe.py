@@ -728,7 +728,7 @@ class RoutedShare(nn.Module):
 
     @nn.compact
     def __call__(self, h):
-        from autodist_tpu.parallel.mesh import per_device
+        from autodist_tpu.parallel.mesh import per_device, stored_axis
         cfg, form = self.config, self.form
         if form not in EXPERT_FORMS:
             raise ValueError(f"Unknown expert form {form!r}; valid: {EXPERT_FORMS}")
@@ -760,6 +760,15 @@ class RoutedShare(nn.Module):
         share = functools.partial(routed_experts, top_k=cfg.top_k, route=route,
                                   first_expert=cfg.first_expert_held,
                                   rows_bound=cfg.rows_bound, form=form)
+        # A bank stored as shares over the data axis (strategy.FullySharded)
+        # is gathered in ``per_device``'s body, ahead of the share and so of
+        # its loop over the later passes (whose trip count differs a chip:
+        # no collective may sit in it). Cast in front of that gather, as
+        # ``gmm`` would cast behind it: half the bytes move, and the bank's
+        # gradient is reduce-scattered in ``dtype`` (each chip's float32 sum
+        # rounded once).
+        bank = [w.astype(cfg.dtype) if stored_axis(w.shape) is not None else w
+                for w in bank]
         y, sizes = per_device(
             share if gated else lambda x, s, *rest: share(x, s, None, *rest),
             (tokens.astype(cfg.dtype), scores, *bank, bias),

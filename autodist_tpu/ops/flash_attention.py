@@ -62,6 +62,16 @@ key head): the score tile is then the sum of two products, ``q_nope k_nope^T
 ignores the head, and each head's float32 part of its gradient is summed
 outside the kernels as a group's is.
 
+A sink (``sink`` ``[H]`` float32, learned): one more logit a query head in
+every query's softmax, with no value. The forward's state starts from ``(m,
+l, acc) = (sink_n, 1, 0)`` where it otherwise starts from ``(-inf, 0, 0)``,
+so the saved logsumexp includes it; the backward kernels are untouched (``p =
+exp(s - lse)`` is then each key's share of a softmax that the sink is part
+of, and ``D = rowsum(dO * O)`` stands because the sink's value is zero), and
+``d sink_n = - sum_i exp(sink_n - lse_i) D_i`` is elementwise work in XLA on
+the two planes the kernels are handed. The three kernels of such a call carry
+their own device names (``flash_sink_*``).
+
 Where the operands lie (PR 41). The grid's first axis is the (batch, head)
 pair and every operand is addressed through its BlockSpec's index map. An
 operand the caller hands as ``[B, L, heads, D]`` is transposed by XLA to ``[B
@@ -446,10 +456,13 @@ def _loop(lo, hi, body, state):
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, *refs, lk: int, sub: int, causal: bool,
-                  scale: float, window=None, groups=()):
-    # refs: [the keys' shared columns,] o, lse | acc, m, l
-    ks_ref = refs[0] if len(refs) == 6 else None
-    o_ref, lse_ref, acc_ref, m_ref, l_ref = refs[-5:]
+                  scale: float, window=None, groups=(), sink_heads: int = 0):
+    # refs: [the keys' shared columns,] [the heads' sinks,] o, lse | acc, m, l
+    *given, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
+    sink_ref = given.pop() if sink_heads else None
+    ks_ref = given[0] if given else None
+    if sink_heads:      # this grid row's head (read here: not inside a branch)
+        head = jax.lax.rem(pl.program_id(0), sink_heads)
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     n_k = pl.num_programs(2)
@@ -458,8 +471,16 @@ def _flash_kernel(q_ref, k_ref, v_ref, *refs, lk: int, sub: int, causal: bool,
     @pl.when(ki == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+        if sink_ref is None:
+            m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[:] = jnp.zeros_like(l_ref)
+        else:
+            # The head's sink has joined the softmax before any key: a logit
+            # as it is (the scale is the keys'), with no value, so the running
+            # maximum starts from it, the denominator from exp(0) and the
+            # accumulator from nothing; the log-sum-exp below then holds it.
+            m_ref[:] = jnp.full(m_ref.shape, sink_ref[head], m_ref.dtype)
+            l_ref[:] = jnp.ones_like(l_ref)
 
     q_start = qi * bq
     k_start = ki * bk
@@ -691,7 +712,8 @@ def _packed_kv(k, v, d_k: int, k_in: bool):
 
 
 def _flash_forward(q, k, v, causal: bool, q_block, k_block, interpret: bool,
-                   window=None, k_shared=None, kept=lambda x: x, heads=None):
+                   window=None, k_shared=None, kept=lambda x: x, heads=None,
+                   sink=None):
     """Returns (out [B, Lq, H, Dv], lse [B*H, n_q, bq] f32). ``k`` / ``v``
     may hold fewer heads than ``q`` (grouped KV heads), ``v`` another width
     than ``q`` and ``k`` (the scale is the key width's), and ``k_shared``
@@ -703,7 +725,11 @@ def _flash_forward(q, k, v, causal: bool, q_block, k_block, interpret: bool,
     ``[B, Lq, H * Dv]`` rows: named as ``[B, Lq, H, Dv]``, XLA copied them
     into that shape's layout for the name's sake. ``heads = (H, H_kv)``:
     needed where an operand is handed as ``[B, L, heads * D]`` rows
-    (:func:`_given_as_rows`); the result is then rows as ``v`` is."""
+    (:func:`_given_as_rows`); the result is then rows as ``v`` is. ``sink``
+    ``[H]`` float32: a logit a query head that joins every query's softmax
+    and carries no value (:func:`flash_attention`); it reaches the kernel
+    whole in SMEM, ``lse`` includes it, and the kernel's device name is
+    ``flash_sink_fwd``."""
     q_rows, k_rows, v_rows = _given_as_rows(q, k, v)
     h, h_kv = heads or (q.shape[2], k.shape[2])
     q, k, v = _as_heads(q, h), _as_heads(k, h_kv), _as_heads(v, h_kv)
@@ -755,6 +781,8 @@ def _flash_forward(q, k, v, causal: bool, q_block, k_block, interpret: bool,
 
     kernel = functools.partial(_flash_kernel, lk=lk, sub=sub, causal=causal,
                                scale=scale, window=window, groups=groups)
+    if sink is not None:
+        kernel = functools.partial(kernel, sink_heads=h)
     if causal and n_k > 1:
         # A K/V block above the diagonal names the last one below it again
         # (and one below the band the first one inside it), so its (skipped)
@@ -782,8 +810,11 @@ def _flash_forward(q, k, v, causal: bool, q_block, k_block, interpret: bool,
         in_specs.append(pl.BlockSpec(
             (1, bk, d_s), lambda bh, i, j: (bh // h, kv_block(i, j), 0)))
         operands += (k_shared,)
+    if sink is not None:
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+        operands += (sink.astype(jnp.float32),)
     out, lse = named_pallas_call(
-        "flash_fwd", kernel,
+        "flash_fwd" if sink is None else "flash_sink_fwd", kernel,
         grid=(b * h, n_q, n_k),
         in_specs=in_specs,
         out_specs=(
@@ -1161,7 +1192,7 @@ def prepare_backward_q_side(q, o, g, q_block, in_place=(False, False)):
 def _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, k_block,
                        interpret, q_shape, q_offset=0, k_offset=0,
                        out_dtype=None, window=None, k_shared=None,
-                       stays=(False, False, False)):
+                       stays=(False, False, False), sink: bool = False):
     """Backward against one K/V shard from prepared query-side layout. Returns
     (dq, dk, dv) in [B, L, H, D] (``v`` None, the values packed behind the
     keys: ``(dq, dkv, None)``; and the shared key columns' gradient ``[B,
@@ -1186,7 +1217,14 @@ def _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, k_block,
     heads, L, width]`` through XLA's transposes. A group's dK / dV leave the
     kernels as ``[B * heads, Lk, width]`` either way: their sum over the
     group is a sum over leading dimensions there, where over column blocks
-    of one row XLA copied the float32 array into another layout first."""
+    of one row XLA copied the float32 array into another layout first.
+
+    ``sink``: the forward's softmax held a sink a head (``lse`` includes it).
+    The kernels' arithmetic is the same (``p = exp(s - lse)``, ``dS = p (dP -
+    D)``: the sink has no value, so ``D = rowsum(dO * O)`` stands); their
+    device names are ``flash_sink_bwd_dkv`` / ``flash_sink_bwd_dq``, so that a
+    trace tells such a layer's time from another's."""
+    name = "flash_sink_bwd_" if sink else "flash_bwd_"
     b, lq, h, d = q_shape
     lk, h_kv = k.shape[1], k.shape[2]
     d_s = 0 if k_shared is None else k_shared.shape[-1]
@@ -1277,7 +1315,7 @@ def _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, k_block,
         ks_out = (pl.BlockSpec((1, bk, d_s), lambda bh, i: (bh, i, 0)),) \
             if d_s else ()
         dq, *dkv = named_pallas_call(
-            "flash_bwd_dkv",
+            name + "dkv",
             functools.partial(_flash_bwd_kernel, sub=bq, **kernel_args),
             grid=(b * h, n_k),
             in_specs=[smem, q_all, do_all, rows, rows, k_spec, v_spec] + ks_in,
@@ -1328,7 +1366,7 @@ def _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, k_block,
         ks_in = [pl.BlockSpec((1, bk, d_s), lambda bh, i, j: (bh // h, i, 0))] \
             if d_s else []
         dkv = named_pallas_call(
-            "flash_bwd_dkv",
+            name + "dkv",
             functools.partial(_flash_bwd_dkdv_kernel, **kernel_args),
             grid=(b * h, n_k, n_q),
             in_specs=[smem, q_side(d, q_in, q_of), q_side(dv, v_in, q_of),
@@ -1347,7 +1385,7 @@ def _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, k_block,
             if d_s else []
         kernel_args.pop("packed", None)     # dQ's kernel writes no dK / dV
         dq = named_pallas_call(
-            "flash_bwd_dq",
+            name + "dq",
             functools.partial(_flash_bwd_dq_kernel, **kernel_args),
             grid=(b * h, n_q, n_k),
             in_specs=[smem, q_side(d, q_in, own), q_side(dv, v_in, own),
@@ -1385,10 +1423,13 @@ def _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, k_block,
 
 def _flash_backward(q, k, v, o, lse, g, causal, q_block, k_block, interpret,
                     q_offset=0, k_offset=0, out_dtype=None, window=None,
-                    k_shared=None, heads=None):
-    """(dq, dk, dv[, dks]) of one call from its forward's operands and
-    residuals, each in the form its operand was handed in
-    (:func:`_given_as_rows`; ``o`` and ``g`` as ``v``)."""
+                    k_shared=None, heads=None, sink=None):
+    """(dq, dk, dv[, dks][, dsink]) of one call from its forward's operands
+    and residuals, each in the form its operand was handed in
+    (:func:`_given_as_rows`; ``o`` and ``g`` as ``v``). ``sink`` ``[H]``:
+    the forward's, whose gradient ``d sink_n = - sum_i exp(sink_n - lse_i)
+    D_i`` (the sink's share of row ``i``'s softmax times ``-D_i``, its value
+    being zero) is elementwise work on what the kernels are handed anyway."""
     q_rows, k_rows, v_rows = _given_as_rows(q, k, v)
     h, h_kv = heads or (q.shape[2], k.shape[2])
     q, k, v = _as_heads(q, h), _as_heads(k, h_kv), _as_heads(v, h_kv)
@@ -1403,7 +1444,15 @@ def _flash_backward(q, k, v, o, lse, g, causal, q_block, k_block, interpret,
     grads = _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, bk,
                                interpret, q.shape, q_offset=q_offset,
                                k_offset=k_offset, out_dtype=out_dtype,
-                               window=window, k_shared=k_shared, stays=stays)
+                               window=window, k_shared=k_shared, stays=stays,
+                               sink=sink is not None)
+    if sink is not None:
+        with jax.named_scope("attn.sink_grad"):
+            b, h = q.shape[0], q.shape[2]
+            share = jnp.exp(sink.astype(jnp.float32)[None, :, None]
+                            - lse.reshape(b, h, -1))     # padded rows: D is 0
+            grads += ((-jnp.sum(share * dd.reshape(b, h, -1), axis=(0, 2))
+                       ).astype(sink.dtype),)
     return tuple(_as_given(x, rows) for x, rows in zip(
         grads, (q_rows, k_rows, v_rows))) + grads[3:]
 
@@ -1555,29 +1604,31 @@ def _use_interpret() -> bool:
         f"the default backend is {backend!r}")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash(q, k, v, k_shared, causal, q_block, k_block, window, heads):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _flash(q, k, v, k_shared, sink, causal, q_block, k_block, window, heads):
     out, _ = _flash_forward(q, k, v, causal, q_block, k_block, _use_interpret(),
-                            window, k_shared, heads=heads)
+                            window, k_shared, heads=heads, sink=sink)
     return out
 
 
-def _flash_fwd(q, k, v, k_shared, causal, q_block, k_block, window, heads):
+def _flash_fwd(q, k, v, k_shared, sink, causal, q_block, k_block, window, heads):
     # Named so that a caller's ``jax.checkpoint`` whose policy lists
     # ``KEPT_NAME`` keeps them and does not launch the forward kernel again
     # for its backward; the identity, lowered to nothing, anywhere else.
     out, lse = _flash_forward(
         q, k, v, causal, q_block, k_block, _use_interpret(), window, k_shared,
-        kept=lambda x: checkpoint_name(x, KEPT_NAME), heads=heads)
-    return out, (q, k, v, k_shared, out, lse)
+        kept=lambda x: checkpoint_name(x, KEPT_NAME), heads=heads, sink=sink)
+    return out, (q, k, v, k_shared, sink, out, lse)
 
 
 def _flash_bwd(causal, q_block, k_block, window, heads, residuals, g):
-    q, k, v, k_shared, o, lse = residuals
-    grads = _flash_backward(q, k, v, o, lse, g, causal, q_block, k_block,
-                            _use_interpret(), window=window, k_shared=k_shared,
-                            heads=heads)
-    return grads if k_shared is not None else grads + (None,)
+    q, k, v, k_shared, sink, o, lse = residuals
+    dq, dk, dv, *rest = _flash_backward(
+        q, k, v, o, lse, g, causal, q_block, k_block, _use_interpret(),
+        window=window, k_shared=k_shared, heads=heads, sink=sink)
+    # the shared key columns' and the sinks' gradients, where there are such
+    optional = [rest.pop(0) if x is not None else None for x in (k_shared, sink)]
+    return (dq, dk, dv, *optional)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -1588,7 +1639,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: Optional[jax.Array], *,
                     k_shared: Optional[jax.Array] = None,
                     heads: Optional[tuple] = None,
                     q_block: Optional[int] = None,
-                    k_block: Optional[int] = None) -> jax.Array:
+                    k_block: Optional[int] = None,
+                    sink: Optional[jax.Array] = None) -> jax.Array:
     """Flash attention over [B, L, H, D] tensors (pallas forward and backward).
 
     ``v`` may be of another width than ``q`` and ``k`` (the result is ``v``'s
@@ -1629,6 +1681,17 @@ def flash_attention(q: jax.Array, k: jax.Array, v: Optional[jax.Array], *,
     KV head ``n // (H / H_kv)`` through the kernels' index maps, nothing is
     repeated in memory, and dK / dV are the sum over a group's query heads.
 
+    ``sink`` ``[H]`` (float32; learned): query head ``n``'s softmax has one
+    more logit, ``sink[n]``, in its denominator, which carries no value:
+    ``p_ij = exp(s_ij) / (exp(sink_n) + sum_j' exp(s_ij'))``. It is a logit
+    as it is (``1 / sqrt(D)`` is the keys'), the online softmax starts from
+    ``(m, l, acc) = (sink_n, 1, 0)``, the saved log-sum-exp includes it, the
+    backward's ``dS = p (dP - D)`` stands, and ``d sink_n = - sum_i
+    exp(sink_n - lse_i) D_i`` is computed beside the kernels. Such a call's
+    kernels carry device names of their own (``flash_sink_fwd``,
+    ``flash_sink_bwd_dkv``, ``flash_sink_bwd_dq``); a call without one traces
+    and lowers what it did before there were sinks.
+
     ``q_block`` / ``k_block`` left at None: the forward and the backward pick
     their blocks from the shape (:func:`_forward_blocks`,
     :func:`_backward_blocks`), and the backward its schedule: one pass while
@@ -1645,12 +1708,34 @@ def flash_attention(q: jax.Array, k: jax.Array, v: Optional[jax.Array], *,
     h, h_kv = heads or (q.shape[2], k.shape[2])
     _kv_group(_as_heads(q, h), _as_heads(k, h_kv))
     _shared_cols(_as_heads(q, h), _as_heads(k, h_kv), k_shared, packed=v is None)
-    given = [x is not None for x in (q, k, v, k_shared)]
+    if sink is not None and sink.shape != (h,):
+        raise ValueError(f"sink is {sink.shape}, one logit a query head is ({h},)")
+    given = [x is not None for x in (q, k, v, k_shared, sink)]
 
     def call(*operands):
         operands = iter(operands)
-        q, k, v, ks = (next(operands) if there else None for there in given)
-        return _flash(q, k, v, ks, causal, q_block, k_block, window, heads)
+        q, k, v, ks, sinks = (next(operands) if there else None
+                              for there in given)
+        return _flash(q, k, v, ks, sinks, causal, q_block, k_block, window, heads)
 
-    return per_device(call, [x for x in (q, k, v, k_shared) if x is not None],
-                      batched=(True,) * sum(given))
+    operands = [x for x in (q, k, v, k_shared, sink) if x is not None]
+    # the sinks are the heads', whole on every device
+    return per_device(call, operands, batched=tuple(
+        x is not sink for x in operands))
+
+
+def band_pairs(lq: int, lk: int, causal: bool = True, window=None,
+               d: int = 128, itemsize: int = 2) -> tuple:
+    """``(visible, computed)`` (query, key) pairs of one (batch, head) of a
+    forward call under zero offsets: the pairs the mask keeps, and the pairs
+    of the ``[bq, sub]`` score tiles the walk runs (plain and masked), at the
+    blocks :func:`_forward_blocks` picks for keys ``d`` wide. Their ratio is
+    how full the computed tiles are: a window far narrower than a tile leaves
+    most of every tile it touches masked."""
+    bq, bk, sub = _forward_blocks(lq, lk, d, itemsize, None, None)
+    plain, masked, _ = _count_tiles(lq, lk, bq, bk, sub, causal, window)
+    i = np.arange(lq) + (lk - lq)           # a query's own position
+    last = np.minimum(i, lk - 1) if causal else np.full(lq, lk - 1)
+    first = np.zeros(lq, int) if window is None else np.maximum(i - window + 1, 0)
+    visible = int(np.clip(last - first + 1, 0, None).sum())
+    return visible, (plain + masked) * bq * sub

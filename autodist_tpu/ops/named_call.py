@@ -23,7 +23,8 @@ KERNEL_NAMES = ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq", "flash_carry",
                 "moe_gmm_fwd", "moe_gmm_bwd_dx", "moe_gmm_bwd_dw",
                 "short_conv_fwd", "short_conv_bwd", "moe_rows_gather",
                 "moe_rows_combine", "ssd_fwd", "ssd_bwd", "conv_silu_fwd",
-                "conv_silu_bwd", "selective_scan_fwd", "selective_scan_bwd")
+                "conv_silu_bwd", "selective_scan_fwd", "selective_scan_bwd",
+                "flash_sink_fwd", "flash_sink_bwd_dkv", "flash_sink_bwd_dq")
 
 
 def named_pallas_call(name: str, kernel, **kwargs):

@@ -168,6 +168,14 @@ def stored_shards(axes: Optional[Dict[tuple, int]]):
         _STORED_SHARDS.reset(token)
 
 
+def stored_axis(shape) -> Optional[int]:
+    """The tensor axis along which a leaf of ``shape`` arrives at a
+    :func:`per_device` body as its share, inside :func:`stored_shards`; None
+    where it arrives whole (no such context, or no such leaf). For a caller
+    that would rather cast a leaf before it is gathered than after."""
+    return (_STORED_SHARDS.get() or {}).get(tuple(shape))
+
+
 def _sized(mesh, axes):
     """``(axes, the product of their sizes)``."""
     return axes, int(np.prod([mesh.shape[a] for a in axes]))

@@ -476,7 +476,8 @@ def test_kernel_names_unchanged():
         "short_conv_fwd", "short_conv_bwd",
         "moe_rows_gather", "moe_rows_combine",
         "ssd_fwd", "ssd_bwd", "conv_silu_fwd", "conv_silu_bwd",
-        "selective_scan_fwd", "selective_scan_bwd")
+        "selective_scan_fwd", "selective_scan_bwd",
+        "flash_sink_fwd", "flash_sink_bwd_dkv", "flash_sink_bwd_dq")
 
 
 @_NEEDS_MESH

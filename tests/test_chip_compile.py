@@ -753,3 +753,118 @@ def test_fully_sharded_step_gathers_weights_and_scatters_gradients(topology):
     _, replicated = compiled_text(AllReduce())
     assert len(_whole_all_reduces(replicated, d * f)) >= 1
     assert "all-gather(" not in replicated
+
+
+@pytest.mark.parametrize("sliding", [True, False], ids=["sliding-sink", "full"])
+def test_flash_with_a_sink_and_a_128_key_window_compiles_at_the_mimo_cell_shape(
+        chip, sliding):
+    """mimo-sharded4-8k's two calls, 1 x 8,192 x 64 query heads, keys 192 over
+    values 128, as the model hands them (q and k ``[B, L, heads, 192]``, v its
+    projection's rows): a sliding layer's (window 128 under 512 x 512 tiles,
+    8 KV heads, the heads' sinks whole in SMEM: kernels ``flash_sink_*``) and
+    a full layer's (4 KV heads, no sink: the plain names). K/V of a head stay
+    resident (3 MiB of keys) and the backward is one pass (6 MiB of dQ)."""
+    b, length, h, kv = 1, 8192, 64, 8 if sliding else 4
+    shapes = [((b, length, h, 192), jnp.bfloat16),
+              ((b, length, kv, 192), jnp.bfloat16),
+              ((b, length, kv * 128), jnp.bfloat16)]
+    if sliding:
+        shapes.append(((h,), jnp.float32))
+
+    def loss(q, k, v, sink=None):
+        return fa.flash_attention(
+            q, k, v, causal=True, window=128 if sliding else None,
+            heads=(h, kv), sink=sink).astype(jnp.float32).sum()
+
+    text = _compiled_text(
+        jax.value_and_grad(loss, argnums=tuple(range(len(shapes)))), chip, *shapes)
+    assert fa._forward_blocks(length, length, 192, 2, None, None) == \
+        (512, 8192, 512)
+    assert length * 192 * 4 <= fa._RESIDENT_DQ_BYTES
+    names = ("flash_sink_fwd", "flash_sink_bwd_dkv") if sliding \
+        else ("flash_fwd", "flash_bwd_dkv")
+    for name in names:
+        assert name in text
+    assert ("flash_sink" in text) == sliding
+    assert "bwd_dq" not in text
+    # the band's walk: two masked tiles a q block, none plain, none overlapped
+    if sliding:
+        from autodist_tpu import telemetry
+        assert [telemetry.gauge(f"flash.fwd.tiles_{k}").value for k in
+                ("plain", "masked", "overlapped")] == [0, 31, 0]
+
+
+def test_fully_sharded_mimo_step_gathers_the_expert_banks_outside_the_pass_loop(
+        topology):
+    """A two-layer MiMo share (the dense layer, a sliding expert layer; banks
+    of 8 x 512 x 256) under ``strategy.FullySharded`` compiled for the four
+    described chips: the banks arrive as quarters and are gathered as
+    bfloat16 OUTSIDE the run-time loop over the passes past the first (whose
+    trip count differs a chip: a collective inside it would hang the host),
+    and no all-reduce leaves a large gradient whole (the form of
+    ``test_fully_sharded_step_gathers_weights_and_scatters_gradients``)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from autodist_tpu import ResourceSpec
+    from autodist_tpu.model_spec import ModelSpec
+    from autodist_tpu.models import mimo_v2
+    from autodist_tpu.parallel.mesh import build_mesh
+    from autodist_tpu.parallel.plan import ShardingPlan
+    from autodist_tpu.runner import DistributedRunner
+    from autodist_tpu.strategy import FullySharded
+
+    cfg = mimo_v2.MimoV2Config(
+        vocab_size=1024, d_model=512, n_heads=4, n_kv_heads=1, swa_n_kv_heads=2,
+        head_dim=192, v_head_dim=128, layer_pattern=(0, 1), moe_layer_freq=(0, 1),
+        d_ff=1024, d_expert=256, n_experts_routed=32, experts_held=8, top_k=4,
+        rows_bound=256, window=128, max_len=1024, attention_impl="flash",
+        fused_head=True, remat=True)
+    model = mimo_v2.MimoV2(cfg)
+    params = jax.eval_shape(lambda key: mimo_v2.init_params(cfg, rng=key)[1],
+                            jax.random.PRNGKey(0))
+    loss = mimo_v2.make_loss_fn(model)
+    batch = {"tokens": np.zeros((4, 1025), np.int32)}
+    spec = ResourceSpec(resource_info={
+        "nodes": [{"address": "localhost", "tpus": 4, "chief": True}],
+        "mesh": {"data": 4}})
+    mesh = build_mesh(axes={"data": 4}, devices=list(topology.devices)[:4])
+    model_spec = ModelSpec.from_loss_fn(loss, params, batch)
+    strategy = FullySharded().build(model_spec, spec)
+    runner = DistributedRunner(
+        strategy, model_spec, loss,
+        mimo_v2.make_optimizer(1e-3, cfg.load_balance_coeff), mesh=mesh,
+        plan=ShardingPlan.from_strategy(strategy, model_spec))
+    state = runner._abstract_state(params)
+    runner._ensure_state_shardings(state)
+    assert runner._state_shardings.params["block_1"]["moe"]["up"].spec == \
+        P("data", None, None)
+    state = jax.tree_util.tree_map(
+        lambda leaf, sharding: jax.ShapeDtypeStruct(
+            leaf.shape, leaf.dtype, sharding=sharding),
+        state, runner._state_shardings)
+    tokens = jax.ShapeDtypeStruct((4, 1025), jnp.int32,
+                                  sharding=NamedSharding(mesh, P("data")))
+    with mesh:
+        text = runner._build_step(None).lower(
+            state, {"tokens": tokens}).compile().as_text()
+    # the banks are gathered, as bfloat16 (a quarter [2, 512, 256] in, the
+    # whole [8, 512, 256] out), and the sink's kernels are in the step
+    gathers = [line for line in text.splitlines()
+               if re.search(r"= bf16\[8,(512,256|256,512)\]\S* all-gather", line)]
+    assert gathers, "no bfloat16 gather of an expert bank"
+    assert "flash_sink_fwd" in text and "moe_gmm_fwd" in text
+    # no collective inside a while loop's body or condition: the loops of
+    # the later passes run another number of times on every chip
+    bodies = set(re.findall(r"(?:body|condition)=%?([\w.\-]+)", text))
+    assert bodies, "the pass loops are gone: rows_bound no longer forces them"
+    computation, inside = None, []
+    for line in text.splitlines():
+        start = re.match(r"\s*(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{\s*$", line)
+        if start:
+            computation = start.group(1)
+        elif computation in bodies and re.search(
+                r" (all-gather|all-reduce|reduce-scatter|collective-permute|"
+                r"all-to-all)(-start)?\(", line):
+            inside.append((computation, line.strip()[:120]))
+    assert inside == []
+    assert _whole_all_reduces(text, 8 * 512 * 256) == []

@@ -86,7 +86,8 @@ def test_lowered_step_names_kernels_and_phases(backward, monkeypatch):
     step_kernels = [k for k in named_call.KERNEL_NAMES
                     if k != "flash_carry"
                     and not k.startswith(("moe_", "short_conv_", "ssd_",
-                                          "conv_silu_", "selective_scan_"))
+                                          "conv_silu_", "selective_scan_",
+                                          "flash_sink_"))
                     and (k != "flash_bwd_dq" or backward == "split")]
     assert _scopes(text, named_call.KERNEL_NAMES) == set(step_kernels)
     # ZeRO's constrain_update is the reduction under AllReduce (the implicit
@@ -340,6 +341,74 @@ def test_selective_scan_and_sharding_gauges_are_set_when_the_step_is_traced():
     stored = 512 * 2048 + 1024 * 512 + 2 * 512 * 512
     assert telemetry.gauge("step.param_gather_bytes").value == stored * 4 * 7 // 8
     assert telemetry.gauge("step.grad_scatter_bytes").value == stored * 4 * 7 // 8
+
+
+MIMO_SCOPES = ("attn.rope_partial", "attn.sink_grad", "moe.route",
+               "moe.dispatch", "moe.experts", "moe.combine")
+
+
+@functools.lru_cache(maxsize=1)
+def _mimo_step_text() -> str:       # one lowering for the cases below
+    """The tiny MiMo-V2 step (the dense full layer, a sliding expert layer
+    with its sinks, every layer recomputed, the fused head) through
+    ``AutoDist`` under ``FullySharded`` on the 8-device mesh; the banks (8 x
+    256 x 128) are stored as eighths."""
+    from autodist_tpu.models import mimo_v2
+    from autodist_tpu.strategy import FullySharded
+    cfg = mimo_v2.MimoV2Config(
+        vocab_size=256, d_model=256, n_heads=4, n_kv_heads=1, swa_n_kv_heads=2,
+        head_dim=48, v_head_dim=32, layer_pattern=(0, 1), moe_layer_freq=(0, 1),
+        d_ff=64, d_expert=128, n_experts_routed=16, experts_held=8, top_k=2,
+        rows_bound=16, window=16, max_len=64, dtype=jnp.float32,
+        attention_impl="flash", fused_head=True, remat=True)
+    model, params = mimo_v2.init_params(cfg, rng=jax.random.PRNGKey(0))
+    batch = mimo_v2.synthetic_batch(cfg, batch_size=8, seq_len=32)
+    runner = AutoDist(strategy_builder=FullySharded()) \
+        .create_distributed_session(
+            mimo_v2.make_loss_fn(model), params,
+            mimo_v2.make_optimizer(1e-3, cfg.load_balance_coeff),
+            example_batch=batch)
+    state = runner.init(params)
+    with runner.mesh:
+        return runner._build_step(None).lower(
+            state, runner.shard_batch(batch)).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("name", [k for k in named_call.KERNEL_NAMES
+                                  if k.startswith("flash_sink_")
+                                  and k != "flash_sink_bwd_dq"]
+                         + ["flash_fwd", "flash_bwd_dkv", "moe_gmm_fwd",
+                            "xent_fwd", "step.grad_sync"] + list(MIMO_SCOPES))
+def test_mimo_step_names_its_sink_kernels_and_scopes(name):
+    """A sliding layer's flash kernels by their own device names
+    (``pallas:flash_sink_fwd`` / ``pallas:flash_sink_bwd_dkv`` in a trace)
+    beside the full layer's plain ones, the partial rotary turn and the
+    sinks' gradient as scopes, and the share's, with the state stored as
+    shares."""
+    assert _scopes(_mimo_step_text(), [name]) == {name}
+
+
+def test_sink_and_band_gauges_are_set_when_the_step_is_traced():
+    _mimo_step_text()       # traced by the cases above, or here when run alone
+    assert telemetry.gauge("attn.sink_layers").value == 1
+    # 8 sequences x 4 heads x one sliding layer: 32 queries see 16 keys at
+    # most, and the one 32 x 32 tile a head is computed whole
+    assert telemetry.gauge("attn.band_pairs_visible").value == \
+        32 * (16 * 17 // 2 + 16 * 16)
+    assert telemetry.gauge("attn.band_pairs_computed").value == 32 * 32 * 32
+    assert telemetry.gauge("moe.experts_held").value == 8
+    assert telemetry.gauge("moe.router_width").value == 16
+
+
+def test_the_split_backward_of_a_sink_call_is_named_flash_sink_bwd_dq(monkeypatch):
+    fa = importlib.import_module("autodist_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "_RESIDENT_DQ_BYTES", 0)
+    q = jnp.ones((1, 32, 2, 8), jnp.float32)
+    text = jax.jit(jax.grad(lambda q, s: fa.flash_attention(
+        q, q, q, window=8, sink=s).sum(), argnums=(0, 1))).lower(
+            q, jnp.zeros((2,))).as_text(debug_info=True)
+    assert _scopes(text, named_call.KERNEL_NAMES) == {
+        "flash_sink_fwd", "flash_sink_bwd_dkv", "flash_sink_bwd_dq"}
 
 
 def test_short_conv_gauges_are_set_when_the_operator_is_traced():

@@ -67,6 +67,16 @@ What the stand-alone call cannot show is what XLA does to the producers
 and consumers of these arrays in a cell (PR 41: most of Kanana's gain was
 there); compile the layer for a described v5e and read the HLO for that.
 
+``mimo-swa`` / ``mimo-full`` and their ``bwd-`` (PR 46): MiMo-V2.5's two
+calls at ``mimo-sharded4-8k``'s shape, 1 x 8,192 x 64 query heads, keys 192
+over values 128, handed as the model hands them (q and k ``[B, L, heads,
+192]``, turned since their projections; v its projection's rows): a sliding
+layer's (window 128, 8 KV heads, a sink a head: the kernels' device names are
+``flash_sink_*``) and a full layer's (4 KV heads, no sink). ``--check``
+compares the result and dq, dk, dv and d sink of both with the dot path with
+the sink as a concatenated column, ON THE CHIP at 2,048 positions, and exits
+1 where they differ.
+
 ``--blocks bq,bk,sub`` overrides the forward's choice, ``--bwd-blocks bq,bk``
 the backward's, ``--xent-blocks bn,bv`` the named head kernel's (a checkout
 whose kernels run a fixed default takes no override; tiles the compiler
@@ -133,13 +143,20 @@ MLA = {f"{kind}mla-{form}": form for kind in ("", "bwd-")
 SHAPES.update({name: (1, 16384, 32, 192, True,
                       "bwd" if name.startswith("bwd-") else "fwd")
                for name in MLA})
+# MiMo-V2.5's two calls: name -> form -> (window, KV heads, a sink a head)
+MIMO_V = 128
+MIMO_FORMS = {"swa": (128, 8, True), "full": (None, 4, False)}
+MIMO = {f"{kind}mimo-{form}": form for kind in ("", "bwd-") for form in MIMO_FORMS}
+SHAPES.update({name: (1, 8192, 64, 192, True,
+                      "bwd" if name.startswith("bwd-") else "fwd")
+               for name in MIMO})
 # name -> (window, KV heads) where they are not (None, H)
 BANDS = {name: (2048 if "-win" in name else None, 32 if name.endswith("-rep") else 4)
          for name in SHAPES if "trinity" in name}
 BANDS.update({"nemotron": (None, 2), "bwd-nemotron": (None, 2),
               "lfm2": (None, 8)})
 # the calls whose whole device time is printed beside the kernels' own
-AROUND = {*MLA, *BANDS, "olmoe", "bwd-olmoe"} - {"lfm2"}
+AROUND = {*MLA, *MIMO, *BANDS, "olmoe", "bwd-olmoe"} - {"lfm2"}
 # which of q, k, v the cell's model hands as a projection's rows (PR 41):
 # v where q and k are turned or normed a head first, all three in Nemotron
 ROWS = {**{name: "v" for name in AROUND - set(MLA)},
@@ -174,8 +191,24 @@ GAUGES = {"fwd": "flash.fwd.", "bwd": "flash.bwd.",
 CHECK_TOLERANCE = {"dh": 4e-3, "dw": 1e-4, "db": 1e-4}
 
 
+# relative L2 distance up to which the kernels on bfloat16 operands agree
+# with the dot path on the same operands in float32 (2^-8 a rounding of p and
+# dS where they enter a product)
+MIMO_CHECK_TOLERANCE = 2e-2
+MIMO_CHECK_LENGTH = 2048
+
+
 def kind_of(name: str) -> str:
     return (SHAPES.get(name) or HEAD_SHAPES[name])[-1]
+
+
+def kernels_of(name: str) -> tuple:
+    """The device names a shape's kernels carry: a call with a sink has its
+    own."""
+    names = KERNELS[kind_of(name)]
+    if name in MIMO and MIMO_FORMS[MIMO[name]][2]:
+        return tuple(n.replace("flash_", "flash_sink_") for n in names)
+    return names
 
 
 def device_events(trace_dir: str):
@@ -281,6 +314,88 @@ def build_mla(fa, name):
     return jax.jit(backward), (q, k_nope, k_rope, v, out, lse, g)
 
 
+def mimo_operands(name, length=None):
+    """``(q, k, v, sink or None, g, the call's keywords)`` of one of
+    MiMo-V2.5's calls, as ``models/mimo_v2.py`` hands them."""
+    import jax
+    import jax.numpy as jnp
+
+    b, full_length, h, d, _, _ = SHAPES[name]
+    length = length or full_length
+    window, kv_heads, has_sink = MIMO_FORMS[MIMO[name]]
+    keys = jax.random.split(jax.random.PRNGKey(0), 5)
+    q = jax.random.normal(keys[0], (b, length, h, d), jnp.bfloat16)
+    k = jax.random.normal(keys[1], (b, length, kv_heads, d), jnp.bfloat16)
+    v = jax.random.normal(keys[2], (b, length, kv_heads * MIMO_V), jnp.bfloat16)
+    sink = 2.0 * jax.random.normal(keys[3], (h,), jnp.float32) if has_sink else None
+    g = jax.random.normal(keys[4], (b, length, h * MIMO_V), jnp.bfloat16)
+    return q, k, v, sink, g, dict(window=window, heads=(h, kv_heads))
+
+
+def build_mimo(fa, name):
+    """One of MiMo-V2.5's calls, forward or backward on a forward's
+    residuals."""
+    import jax
+
+    q, k, v, sink, g, call = mimo_operands(name)
+    sinks = () if sink is None else (sink,)
+
+    def forward(q, k, v, *sinks):
+        return fa._flash_forward(q, k, v, True, None, None, False, **call,
+                                 sink=sinks[0] if sinks else None)
+
+    if kind_of(name) == "fwd":
+        return jax.jit(forward), (q, k, v, *sinks)
+    out, lse = jax.jit(forward)(q, k, v, *sinks)
+    return (jax.jit(lambda q, k, v, o, lse, g, *sinks: fa._flash_backward(
+        q, k, v, o, lse, g, True, None, None, False, **call,
+        sink=sinks[0] if sinks else None)), (q, k, v, out, lse, g, *sinks))
+
+
+def check_mimo(fa, name):
+    """The call's result and every gradient (d sink among them) against the
+    dot path with the sink as a concatenated column, on the chip at
+    ``MIMO_CHECK_LENGTH`` positions: relative L2 distance of each, and whether
+    all are within ``MIMO_CHECK_TOLERANCE``."""
+    import jax
+    import jax.numpy as jnp
+
+    from autodist_tpu.models.mimo_v2 import sink_dot_attention
+
+    q, k, v, sink, g, call = mimo_operands(name, MIMO_CHECK_LENGTH)
+    b, length, h, _ = q.shape
+    kv_heads = call["heads"][1]
+    sinks = () if sink is None else (sink,)
+
+    def run(attend):
+        def loss(q, k, v, *sinks):
+            out = attend(q, k, v, sinks[0] if sinks else None)
+            out = out.reshape(b, length, -1)
+            return jnp.sum(out.astype(jnp.float32) * g.astype(jnp.float32)), out
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=tuple(range(3 + len(sinks))), has_aux=True))(
+                q, k, v, *sinks)
+        return (out, *grads)
+
+    got = run(lambda q, k, v, s: fa.flash_attention(q, k, v, causal=True,
+                                                    sink=s, **call))
+    with jax.default_matmul_precision("highest"):
+        want = run(lambda q, k, v, s: sink_dot_attention(
+            q.astype(jnp.float32), k.astype(jnp.float32),
+            v.astype(jnp.float32).reshape(b, length, kv_heads, -1),
+            call["window"], s, jnp.float32))
+
+    def distance(a, b):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+
+    parts = ("out", "dq", "dk", "dv", "dsink")[:len(got)]
+    record = {"shape": name, "check": True, "length": MIMO_CHECK_LENGTH,
+              **{part: distance(a, b) for part, a, b in zip(parts, got, want)}}
+    record["agree"] = all(record[part] <= MIMO_CHECK_TOLERANCE for part in parts)
+    return record
+
+
 def _on_heads(fa, b, length, heads, rows):
     """``fa``'s forward and backward for a checkout that takes no rows: the
     operands named in ``rows`` (and with ``v`` the result, its gradient) are
@@ -318,6 +433,8 @@ def build(fa, name, blocks=None):
 
     if name in MLA:
         return build_mla(fa, name)
+    if name in MIMO:
+        return build_mimo(fa, name)
     b, length, h, d, causal, kind = SHAPES[name]
     window, kv_heads = BANDS.get(name, (None, h))
     # a checkout older than the window takes no such argument
@@ -472,11 +589,11 @@ def measure(modules, name, blocks, calls, two_kernels=False):
             jax.block_until_ready(outs)
             call = (time.perf_counter() - t0) / calls * 1e3
         parts = {kernel: sorted(kernel_ms(trace_dir, kernel))
-                 for kernel in KERNELS[kind]}
+                 for kernel in kernels_of(name)}
         device_all = all_ops_ms(trace_dir) / calls if name in AROUND else None
     parts = {kernel: ms for kernel, ms in parts.items() if ms}
     if not parts:
-        raise SystemExit(f"{name}: the trace holds no {KERNELS[kind]} event")
+        raise SystemExit(f"{name}: the trace holds no {kernels_of(name)} event")
     record = {"shape": name, "blocks": blocks,
               **({"two_kernels": two_kernels} if kind == "xent-bwd" else {}),
               "events": sum(len(ms) for ms in parts.values()),
@@ -519,7 +636,9 @@ def main(argv=None):
                              "(batch, head) up to which the backward is one pass")
     parser.add_argument("--check", action="store_true",
                         help="xent-bwd* shapes: compare the one pass's dh, dw, "
-                             "db with the two kernels' instead of timing them")
+                             "db with the two kernels' instead of timing them; "
+                             "mimo-* shapes: the call and its gradients with "
+                             "the dot path's")
     parser.add_argument("--calls", type=int, default=20)
     args = parser.parse_args(argv)
 
@@ -554,6 +673,8 @@ def main(argv=None):
         for blocks in overrides.get(kind, [None]):
             if args.check and kind == "xent-bwd":
                 agree &= emit(check_head(modules["fused_xent"], name, blocks))
+            elif args.check and name in MIMO:
+                agree &= emit(check_mimo(modules["flash_attention"], name))
             else:
                 emit(measure(modules, name, blocks, args.calls))
         if kind == "xent-bwd" and not args.check:   # beside it, the two kernels
