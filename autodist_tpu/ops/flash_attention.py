@@ -49,9 +49,23 @@ A sliding window (``window=W``: a query sees itself and the ``W - 1`` keys
 before it) is a second edge of the same classes: tiles wholly below the band
 are skipped as those above the diagonal are (not walked inside a block, not
 copied where they are a grid step), tiles the lower edge crosses run the masked
-body. Grouped KV heads: K and V keep their ``H_kv`` heads in memory and query
-head ``n`` reads head ``n // (H / H_kv)`` through the index maps; a group's
-float32 dK / dV are summed outside the kernels.
+body. A window NARROWER than a key tile (128 keys under 512 x 512 tiles) would
+touch two tiles a q block, both masked and an eighth full, so there the walk is
+fitted to the band (:func:`_band_span`): the forward cuts the q block into
+chunks of 128 queries, each of which meets ONE ``[256 keys, 128 queries]`` tile
+out of the resident K/V, the keys that end with the chunk's own, with both
+edges of the band inside it; the one-pass backward mirrors it along the
+queries, a 128-key chunk of its K/V block against the 256 queries from its own
+on, out of the resident q and dO (lse, D and the dQ accumulator in rows of 128
+queries). Half of such a tile is visible; every chunk's first products are
+issued before any softmax, as in a block of plain tiles. The same online
+softmax and the same mask arithmetic at another tile shape, decided from
+static ints: a call without a window, or with one at least a tile wide, traces
+what it traced before.
+
+Grouped KV heads: K and V keep their ``H_kv`` heads in memory and query head
+``n`` reads head ``n // (H / H_kv)`` through the index maps; a group's float32
+dK / dV are summed outside the kernels.
 
 Two widths (latent attention): the values may be narrower than the keys (the
 accumulator, ``o``, ``dO`` and ``dV`` are the values' wide, ``q``, ``k``,
@@ -175,6 +189,31 @@ from autodist_tpu.ops.named_call import named_pallas_call
 # (1,764); two tiles an iteration with two static buffers reached 1,641, the
 # block of four without any carried state 1,480.
 #
+# The walk fitted to a narrow band, from stand-alone timings on the same chip
+# (PR 47; same tool, `--shapes mimo-swa,bwd-mimo-swa`, PERF.md §6 "PR 47"; ms a
+# call, the kernels' own device time, parent -> change): MiMo-V2.5's sliding
+# layer, 1 x 8,192 x 64 query heads over 8 KV heads, keys 192 over values 128, a
+# window of 128 and a sink a head. Under 512 x 512 tiles a q block touched two
+# masked tiles, an eighth full (31 a head, fill 12.8%); fitted, 64 tiles of
+# [256, 128] a head, fill 49.6%.
+#
+#   flash_sink_fwd       4.076 -> 1.249   (its bytes need 0.46: 11.3% -> 36.8%)
+#   flash_sink_bwd_dkv   6.811 -> 3.151   (its bytes need 0.92: 13.5% -> 29.2%)
+#   trinity's sliding layers (window 2,048: four key tiles wide, not fitted)
+#                        3.025 -> 3.025 forward, 4.971 -> 4.971 backward
+#
+# What lost: the parent's bodies in blocks picked from the window (q block x key
+# tile 128 x 128: 4.918 forward, fill 50%, 4,096 grid steps a call; 256 x 256:
+# 3.343, fill 25%; 256 x 128 4.027, 128 x 256 4.572; the backward's q tile x K/V
+# block 128 x 128 6.568, 256 x 256 4.849, 256 x 128 5.389, 128 x 256 5.468, 128
+# x 512 6.470): a grid step and a one-tile chain for every small tile cost more
+# than the masked pairs they spare. What the fitted walk still leaves: eight
+# chunks a q block (`--blocks 1024,8192,512`) 1.003 and four chunks of a 256-row
+# block 1.840; the backward over K/V blocks of 1,024 rows 2.923, of 256 3.571: a
+# grid step costs 0.4 us and a (batch, head) of the backward about 11 (q and dO
+# arriving, dQ's 6 MiB zeroed and turned), so larger blocks would give 0.25 ms a
+# call each, left where the other calls' blocks are.
+#
 # The backward's schedule, from stand-alone timings of `_flash_backward` on the same
 # chip (same tool, PERF.md §6 "PR 26"; ms a call, the kernels' own device time).
 # GPT-2-medium's call, two row-major kernels of 512 x 512 blocks: 0.722 + 0.619 =
@@ -294,6 +333,53 @@ def _band_tile_counts(q_lo, k_lo, valid, bq: int, bk: int, sub: int,
     return n_lo, n_ps, xp.clip(n_plain, n_ps, n_need), n_need
 
 
+def _band_span(window, rows: int, other: int, tile: int) -> int:
+    """The fitted walk's tile, or 0 where the walk is the tiles' own. Under a
+    window narrower than a score tile (``tile`` rows of the side the walk
+    runs along) a tile the band crosses is mostly masked: 128 keys under 512
+    x 512 tiles touch two tiles a q block, an eighth of which the mask
+    keeps. The walk is then fitted to the band: the block's ``rows`` are cut
+    into chunks of 128 (a lane tile), and a chunk meets ONE tile of the rows
+    of the other side (``other`` of them in reach) that hold everything it
+    sees, as many whole lane tiles as the window and the chunk's own width
+    cover: 256 at a window of 128, half of them visible. Static ints alone:
+    a call without a window, or with one at least a tile wide, asks nothing
+    more than this."""
+    if window is None or window >= tile or rows % _LANES or other % _LANES:
+        return 0
+    return min((pl.cdiv(window - 1, _LANES) + 1) * _LANES, other)
+
+
+def _band_start(first, other: int, span: int):
+    """First row of the ``span``-row tile of the fitted walk whose unclipped
+    first row is ``first`` (a multiple of 128, traced or not), held inside
+    the ``other`` rows there are: at an end of the sequence the tile covers
+    rows the chunk cannot see, which the mask removes like any other."""
+    if _is_static(first):
+        return int(np.clip(first, 0, other - span))
+    return pl.multiple_of(jnp.clip(first, 0, other - span), _LANES)
+
+
+def _band_key_starts(q_lo, k_lo, bq: int, bk: int, span: int) -> list:
+    """The forward's fitted walk of one (q block, K/V block) pair: for every
+    128-query chunk of the block the first key, from the block's first on,
+    of the one ``[span keys, 128 queries]`` tile it meets, which ENDS with
+    the chunk's last query's own key. One definition for the kernel and the
+    counts."""
+    return [_band_start(q_lo + c + _LANES - span - k_lo, bk, span)
+            for c in range(0, bq, _LANES)]
+
+
+def _band_query_starts(q_lo, k_lo, rows: int, bk: int, span: int) -> list:
+    """The backward's fitted walk of one K/V block against the ``rows``
+    queries of its (batch, head): for every 128-key chunk of the block the
+    first query, from the head's first on, of the one ``[128 keys, span
+    queries]`` tile it meets, which STARTS with the chunk's first key's own
+    query."""
+    return [_band_start(k_lo + c - q_lo, rows, span)
+            for c in range(0, bk, _LANES)]
+
+
 def _attend_block(q_ref, k_ref, v_ref, state, *, q_lo, k_lo, valid, sub: int,
                   causal: bool, scale: float, guard_empty_rows: bool,
                   window=None, ks_ref=None, groups=()):
@@ -332,22 +418,24 @@ def _attend_block(q_ref, k_ref, v_ref, state, *, q_lo, k_lo, valid, sub: int,
         q = q * jnp.asarray(scale, q.dtype)
     if ks_ref is not None:
         q, q_s = q[:, :k_ref.shape[2]], q[:, k_ref.shape[2]:]
-    n_lo, n_ps, n_pe, n_need = _band_tile_counts(q_lo, k_lo, valid, bq, bk, sub,
-                                                 causal, window)
-
     n_tiles = bk // sub
+
+    def keys_of(j):
+        """``(first key, keys)`` of tile ``j`` of the block's ``sub``-key
+        tiles, or of the fitted walk's tile, which is handed as that pair."""
+        return j if isinstance(j, tuple) else (_tile_start(j, sub, n_tiles), sub)
 
     def scores(j, cols=None):
         """Tile ``j``'s raw score product(s), for the queries ``cols`` (a
         slice of the block's; None: all of them)."""
-        start = _tile_start(j, sub, n_tiles)
+        start, size = keys_of(j)
         q_c = q if cols is None else q[cols]
         s = jax.lax.dot_general(
-            k_ref[0, pl.ds(start, sub), :], q_c, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [sub, queries]
+            k_ref[0, pl.ds(start, size), :], q_c, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)       # [keys, queries]
         if ks_ref is not None:
             s += jax.lax.dot_general(
-                ks_ref[0, pl.ds(start, sub), :],
+                ks_ref[0, pl.ds(start, size), :],
                 q_s if cols is None else q_s[cols], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
         return s
@@ -358,8 +446,8 @@ def _attend_block(q_ref, k_ref, v_ref, state, *, q_lo, k_lo, valid, sub: int,
         what a tile does, whoever made its scores and when."""
         m_prev, l_prev, acc = (state if cols is None
                                else [x[:, cols] for x in state])
-        start = _tile_start(j, sub, n_tiles)
-        v_t = v_ref[0, pl.ds(start, sub), :]
+        start, size = keys_of(j)
+        v_t = v_ref[0, pl.ds(start, size), :]
         if not prescale:
             scores = scale * scores
         if masked:
@@ -416,7 +504,22 @@ def _attend_block(q_ref, k_ref, v_ref, state, *, q_lo, k_lo, valid, sub: int,
                 jnp.concatenate(x, axis=1) for x in zip(*parts))
         return state
 
-    return _walk((n_lo, n_ps, n_pe, n_need), groups, tile, group, state)
+    span = _band_span(window, bq, bk, sub)
+    if span:
+        # The walk fitted to a narrow band: a 128-query chunk meets one tile
+        # of ``span`` keys, both edges of the band inside it, and nothing
+        # else of the block. Every chunk's score product first, as in a
+        # block of plain tiles: the chunks share no state, so one's products
+        # run under another's softmax.
+        chunks = [slice(c, c + _LANES) for c in range(0, bq, _LANES)]
+        tiles = [(start, span) for start in _band_key_starts(
+            q_lo, k_lo, bq, bk, span)]
+        raw = [scores(j, cols) for j, cols in zip(tiles, chunks)]
+        parts = [update(j, s, state, True, cols)
+                 for j, s, cols in zip(tiles, raw, chunks)]
+        return tuple(jnp.concatenate(x, axis=1) for x in zip(*parts))
+    counts = _band_tile_counts(q_lo, k_lo, valid, bq, bk, sub, causal, window)
+    return _walk(counts, groups, tile, group, state)
 
 
 def _walk(counts, groups, tile, group, state):
@@ -566,24 +669,33 @@ def _forward_vmem_limit(kv_bytes: int):
 def _walks(lq: int, lk: int, bq: int, bk: int, sub: int, causal: bool,
            window=None) -> tuple:
     """``(needed, plain)`` tiles of every walk, one (q block, K/V block)
-    pair, of one (batch, head) under zero offsets."""
+    pair, of one (batch, head) under zero offsets. Under the fitted walk
+    (:func:`_band_span`) a pair the grid runs takes one tile a 128-query
+    chunk, none of them plain."""
+    span = _band_span(window, bq, bk, sub)
     walks = []
     for qi in range(pl.cdiv(lq, bq)):
         for ki in range(pl.cdiv(lk, bk)):
             n_lo, n_ps, n_pe, n_need = _band_tile_counts(
                 qi * bq, ki * bk, _valid_keys(lk, ki * bk, bk), bq, bk, sub,
                 causal, window)
-            walks.append((int(n_need - n_lo), int(n_pe - n_ps)))
+            if span:
+                walks.append((bq // _LANES if n_need > n_lo else 0, 0))
+            else:
+                walks.append((int(n_need - n_lo), int(n_pe - n_ps)))
     return tuple(walks)
 
 
 def _count_tiles(lq: int, lk: int, bq: int, bk: int, sub: int, causal: bool,
                  window=None):
     """(plain, masked, skipped) score tiles of one (batch, head) under zero
-    offsets, at the granularity the body runs them: [bq, sub]."""
+    offsets, at the granularity the body runs them: ``[sub, bq]``, or the
+    fitted walk's ``[span, 128]``."""
     walks = _walks(lq, lk, bq, bk, sub, causal, window)
     need, plain = (sum(column) for column in zip(*walks))
-    return plain, need - plain, len(walks) * (bk // sub) - need
+    span = _band_span(window, bq, bk, sub)
+    a_walk = bq // _LANES * pl.cdiv(bk, span) if span else bk // sub
+    return plain, need - plain, len(walks) * a_walk - need
 
 
 def _kv_group(q, k) -> int:
@@ -764,13 +876,17 @@ def _flash_forward(q, k, v, causal: bool, q_block, k_block, interpret: bool,
             k_shared = jnp.pad(k_shared, pad)
 
     plain, masked, skipped = _count_tiles(lq, lk, bq, bk, sub, causal, window)
-    runs = [run for _, run in _walks(lq, lk, bq, bk, sub, causal, window)]
+    walks = _walks(lq, lk, bq, bk, sub, causal, window)
+    runs = [run for _, run in walks]
     groups = _walk_groups(max(runs))
     telemetry.gauge("flash.fwd.tiles_plain").set(plain)
     telemetry.gauge("flash.fwd.tiles_masked").set(masked)
     telemetry.gauge("flash.fwd.tiles_skipped").set(skipped)
-    telemetry.gauge("flash.fwd.tiles_overlapped").set(
-        sum(_grouped(run, groups) for run in runs))
+    if _band_span(window, bq, bk, sub):     # all but the first chunk's of a walk
+        overlapped = sum(need - 1 for need, _ in walks if need)
+    else:
+        overlapped = sum(_grouped(run, groups) for run in runs)
+    telemetry.gauge("flash.fwd.tiles_overlapped").set(overlapped)
     telemetry.gauge("flash.window").set(window or 0)
     telemetry.gauge("flash.kv_group").set(group)
     telemetry.gauge("flash.d_qk").set(d)
@@ -995,6 +1111,80 @@ def _backward_block(q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
         _loop(t_need, t_end, tile, None)
 
 
+def _backward_band(q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
+                   dq_acc, dk_acc, dv_acc, *, q_lo, k_lo, valid, scale: float,
+                   window: int, ks_ref, dks_acc, span: int):
+    """:func:`_backward_block` with the walk fitted to a narrow band: every
+    128-key chunk of the K/V block meets ONE ``[128 keys, span queries]``
+    tile, the queries from its first key's own on, both edges of the band
+    inside it (:func:`_band_query_starts`), in place of the two ``[bk, bq]``
+    tiles the block touches, an eighth full at a window of 128. The same
+    five products a tile, in the same precisions. q and dO are the (batch,
+    head)'s whole rows, the lse / D planes and ``dq_acc`` are laid out in
+    rows of 128 queries (``[rows // 128, 128]``, ``[rows // 128, d, 128]``),
+    so that a tile that starts at any lane tile reads and adds whole rows.
+    The products that need the operands alone (scores and dP) of EVERY chunk
+    are issued first, straight-line: the chunks share nothing but dQ's rows,
+    so one's products run under another's exponentials."""
+    rows, bk = q_ref.shape[1], k_ref.shape[1]
+    prescale = _scale_is_exact(scale)
+    k, v = k_ref[0], v_ref[0]
+    d_k = k.shape[1]
+    ks = None if ks_ref is None else ks_ref[0]
+    contract_width = (((1,), (1,)), ((), ()))
+    contract_keys = (((0,), (0,)), ((), ()))
+    starts = _band_query_starts(q_lo, k_lo, rows, bk, span)
+    chunks = [slice(c, c + _LANES) for c in range(0, bk, _LANES)]
+
+    staged = []
+    for keys, start in zip(chunks, starts):
+        q = q_ref[0, pl.ds(start, span), :]                   # [span, d]
+        do = do_ref[0, pl.ds(start, span), :]
+        if prescale:
+            q = q * jnp.asarray(scale, q.dtype)
+        q, q_s = (q, None) if ks is None else (q[:, :d_k], q[:, d_k:])
+        scores = jax.lax.dot_general(
+            k[keys], q, contract_width,
+            preferred_element_type=jnp.float32)               # [128, span]
+        if ks is not None:
+            scores += jax.lax.dot_general(
+                ks[keys], q_s, contract_width,
+                preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(v[keys], do, contract_width,
+                                 preferred_element_type=jnp.float32)
+        staged.append((q, q_s, do, scores, dp))
+
+    for keys, start, (q, q_s, do, scores, dp) in zip(chunks, starts, staged):
+        first = start // _LANES          # the tile's first row of the planes
+        lse, dd = (jnp.concatenate(
+            [ref[0, pl.ds(first + i, 1), :] for i in range(span // _LANES)],
+            axis=1) for ref in (lse_ref, dd_ref))             # [1, span]
+        if not prescale:
+            scores = scale * scores
+        p = jnp.exp(scores - lse)
+        key = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
+        query = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+        ahead = q_lo + start - k_lo - keys.start    # the first query, past the first key
+        invalid = (key - query > ahead) | (key - query <= ahead - window)
+        if valid is not None:
+            invalid |= key >= valid - keys.start
+        p = jnp.where(invalid, 0.0, p)
+        ds = (p * (dp - dd)).astype(q.dtype)
+        dv_acc[keys, :] += jnp.dot(p.astype(do.dtype), do,
+                                   preferred_element_type=jnp.float32)
+        dk_acc[keys, :] += jnp.dot(ds, q, preferred_element_type=jnp.float32)
+        dq = jax.lax.dot_general(k[keys], ds, contract_keys,
+                                 preferred_element_type=jnp.float32)  # [d, span]
+        if ks is not None:
+            dks_acc[keys, :] += jnp.dot(ds, q_s,
+                                        preferred_element_type=jnp.float32)
+            dq = jnp.concatenate([dq, jax.lax.dot_general(
+                ks[keys], ds, contract_keys,
+                preferred_element_type=jnp.float32)], axis=0)
+        for i in range(span // _LANES):
+            dq_acc[first + i] += dq[:, i * _LANES:(i + 1) * _LANES]
+
+
 def _finish_dkdv(dk_ref, dv_ref, dk_acc, dv_acc, scale: float,
                  dks_ref=None, dks_acc=None):
     # dK's q carried the scale where that is exact
@@ -1033,10 +1223,13 @@ def _shared_refs(refs, shared: bool, n_out: int):
 
 def _flash_bwd_kernel(off_ref, q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
                       *refs, lk: int, sub: int, causal: bool, scale: float,
-                      window=None, shared: bool = False, packed: int = 0):
+                      window=None, shared: bool = False, packed: int = 0,
+                      span: int = 0):
     """The one-pass backward: q, dO and the float32 dQ accumulator of one
     (batch, head) stay in VMEM across its K/V blocks (the grid's second axis);
-    a grid step finishes dK and dV of its block, the last writes dQ."""
+    a grid step finishes dK and dV of its block, the last writes dQ. ``sub``:
+    the queries a row of the lse / D planes and of the accumulator holds, the
+    q tile, or 128 under the fitted walk (``span``, :func:`_band_span`)."""
     ks_ref, dks_ref, dks_acc, refs = _shared_refs(refs, shared,
                                                   2 if packed else 3)
     dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = _dkv_parts(refs, 1, packed)
@@ -1052,11 +1245,16 @@ def _flash_bwd_kernel(off_ref, q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
     if shared:
         dks_acc[:] = jnp.zeros_like(dks_acc)
     k_start = ki * bk
-    _backward_block(q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
-                    dq_acc, dk_acc, dv_acc, row0=0, q_lo=off_ref[0],
-                    k_lo=off_ref[1] + k_start,
-                    valid=_valid_keys(lk, k_start, bk), sub=sub, causal=causal,
-                    scale=scale, window=window, ks_ref=ks_ref, dks_acc=dks_acc)
+    block = dict(q_lo=off_ref[0], k_lo=off_ref[1] + k_start,
+                 valid=_valid_keys(lk, k_start, bk), scale=scale, window=window,
+                 ks_ref=ks_ref, dks_acc=dks_acc)
+    if span:
+        _backward_band(q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
+                       dq_acc, dk_acc, dv_acc, span=span, **block)
+    else:
+        _backward_block(q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref,
+                        dq_acc, dk_acc, dv_acc, row0=0, sub=sub, causal=causal,
+                        **block)
     _finish_dkdv(dk_ref, dv_ref, dk_acc, dv_acc, scale, dks_ref, dks_acc)
 
     @pl.when(ki == pl.num_programs(1) - 1)
@@ -1137,10 +1335,14 @@ def _backward_blocks(lq: int, lk: int, q_block, k_block):
 
 
 def _count_backward_tiles(n_q: int, lk: int, bq: int, bk: int, causal: bool,
-                          window=None):
+                          window=None, span: int = 0):
     """(plain, masked, skipped) ``[bk, bq]`` score tiles of one (batch, head)
-    under zero offsets."""
+    under zero offsets; under the fitted walk (``span``) its ``[128, span]``
+    tiles, one a 128-key chunk, none of them plain."""
     n_k = pl.cdiv(lk, bk)
+    if span:
+        need = n_k * bk // _LANES
+        return 0, need, need * (pl.cdiv(n_q * bq, span) - 1)
     plain = need = 0
     for ki in range(n_k):
         t_need, t_plain, t_band, t_end = _query_tile_counts(
@@ -1261,8 +1463,12 @@ def _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, k_block,
     dq_bytes = lq_p * d * 4
     one_pass = dq_bytes <= _RESIDENT_DQ_BYTES
 
+    # the walk fitted to a narrow band: the one pass under zero offsets
+    span = 0
+    if one_pass and static_offsets and q_offset == 0 == k_offset:
+        span = _band_span(window, bk, lq_p, bq)
     plain, masked, skipped = _count_backward_tiles(n_q, lk, bq, bk, causal,
-                                                   window)
+                                                   window, span)
     telemetry.gauge("flash.bwd.passes").set(1 if one_pass else 2)
     telemetry.gauge("flash.bwd.tiles_plain").set(plain)
     telemetry.gauge("flash.bwd.tiles_masked").set(masked)
@@ -1302,9 +1508,13 @@ def _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, k_block,
         def own(i):
             return i
 
+        sub = bq        # the queries a row of the planes and of dQ's scratch
+        if span:
+            kernel_args["span"], sub = span, _LANES
+            lse, dd = (x.reshape(b * h, lq_p // sub, sub) for x in (lse, dd))
         q_all = _head_spec(lq_p, d, h, q_in, all_rows)
         do_all = _head_spec(lq_p, dv, h, v_in, all_rows)
-        rows = pl.BlockSpec((1, n_q, bq), lambda bh, i: (bh, 0, 0))
+        rows = pl.BlockSpec((1, lq_p // sub, sub), lambda bh, i: (bh, 0, 0))
         k_spec = _head_spec(bk, d_k, h, k_in, own, group, k_part)
         v_spec = _head_spec(bk, dv, h, v_in, own, group, v_part)
         dkv_specs = ((_head_spec(bk, 2 * d_k, h, True, own),) if packed_out
@@ -1316,12 +1526,12 @@ def _flash_backward_kv(qf, dof, lse, dd, k, v, causal, bq, n_q, k_block,
             if d_s else ()
         dq, *dkv = named_pallas_call(
             name + "dkv",
-            functools.partial(_flash_bwd_kernel, sub=bq, **kernel_args),
+            functools.partial(_flash_bwd_kernel, sub=sub, **kernel_args),
             grid=(b * h, n_k),
             in_specs=[smem, q_all, do_all, rows, rows, k_spec, v_spec] + ks_in,
             out_specs=(q_all,) + dkv_specs + ks_out,
             out_shape=(dq_shape,) + dkv_shape + dks_shape,
-            scratch_shapes=[pltpu.VMEM((n_q, d, bq), jnp.float32)]
+            scratch_shapes=[pltpu.VMEM((lq_p // sub, d, sub), jnp.float32)]
             + dkv_scratch + dks_scratch,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary"),
@@ -1676,7 +1886,15 @@ def flash_attention(q: jax.Array, k: jax.Array, v: Optional[jax.Array], *,
     ``window=W`` (causal only): the query at ``i`` sees the keys ``i - W < j
     <= i``, itself and the ``W - 1`` before it. Tiles wholly below the band
     are neither computed nor, where they are a grid step, copied; tiles either
-    edge crosses run the masked body. ``k`` / ``v`` may hold fewer heads than
+    edge crosses run the masked body. A window narrower than a key tile (``W <
+    512`` at the default blocks, lengths of whole lane tiles) runs the walk
+    fitted to the band instead: each 128-query chunk of a q block against the
+    one tile of ``128 * (ceil((W - 1) / 128) + 1)`` keys that ends with its
+    own (256 at ``W = 128``, half of them visible where two 512 x 512 tiles
+    were an eighth full), and the one-pass backward each 128-key chunk
+    against as many queries from its own on; the two-kernel backward (past
+    ``_RESIDENT_DQ_BYTES``) and explicit blocks that are no whole lane tiles
+    keep the tiles' own walk. ``k`` / ``v`` may hold fewer heads than
     ``q``, ``H_kv`` dividing ``H`` (grouped KV heads): query head ``n`` reads
     KV head ``n // (H / H_kv)`` through the kernels' index maps, nothing is
     repeated in memory, and dK / dV are the sum over a group's query heads.
@@ -1728,14 +1946,16 @@ def band_pairs(lq: int, lk: int, causal: bool = True, window=None,
                d: int = 128, itemsize: int = 2) -> tuple:
     """``(visible, computed)`` (query, key) pairs of one (batch, head) of a
     forward call under zero offsets: the pairs the mask keeps, and the pairs
-    of the ``[bq, sub]`` score tiles the walk runs (plain and masked), at the
-    blocks :func:`_forward_blocks` picks for keys ``d`` wide. Their ratio is
-    how full the computed tiles are: a window far narrower than a tile leaves
-    most of every tile it touches masked."""
+    of the score tiles the walk runs (plain and masked), at the blocks
+    :func:`_forward_blocks` picks for keys ``d`` wide. Their ratio is how
+    full the computed tiles are: a window of 128 fills an eighth of the two
+    512 x 512 tiles a q block it would touch, and half of the fitted walk's
+    four ``[256, 128]`` (:func:`_band_span`)."""
     bq, bk, sub = _forward_blocks(lq, lk, d, itemsize, None, None)
     plain, masked, _ = _count_tiles(lq, lk, bq, bk, sub, causal, window)
     i = np.arange(lq) + (lk - lq)           # a query's own position
     last = np.minimum(i, lk - 1) if causal else np.full(lq, lk - 1)
     first = np.zeros(lq, int) if window is None else np.maximum(i - window + 1, 0)
     visible = int(np.clip(last - first + 1, 0, None).sum())
-    return visible, (plain + masked) * bq * sub
+    span = _band_span(window, bq, bk, sub)
+    return visible, (plain + masked) * (_LANES * span if span else bq * sub)

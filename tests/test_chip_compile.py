@@ -760,10 +760,13 @@ def test_flash_with_a_sink_and_a_128_key_window_compiles_at_the_mimo_cell_shape(
         chip, sliding):
     """mimo-sharded4-8k's two calls, 1 x 8,192 x 64 query heads, keys 192 over
     values 128, as the model hands them (q and k ``[B, L, heads, 192]``, v its
-    projection's rows): a sliding layer's (window 128 under 512 x 512 tiles,
-    8 KV heads, the heads' sinks whole in SMEM: kernels ``flash_sink_*``) and
-    a full layer's (4 KV heads, no sink: the plain names). K/V of a head stay
-    resident (3 MiB of keys) and the backward is one pass (6 MiB of dQ)."""
+    projection's rows): a sliding layer's (window 128: the walk fitted to the
+    band, ``[256, 128]`` tiles cut at run-time starts out of the resident K/V
+    and out of the resident q and dO, 8 KV heads, the heads' sinks whole in
+    SMEM: kernels ``flash_sink_*``) and a full layer's (4 KV heads, no sink:
+    the plain names). K/V of a head stay resident (3 MiB of keys) and the
+    backward is one pass (6 MiB of dQ), both inside the scoped VMEM the
+    kernels ask for."""
     b, length, h, kv = 1, 8192, 64, 8 if sliding else 4
     shapes = [((b, length, h, 192), jnp.bfloat16),
               ((b, length, kv, 192), jnp.bfloat16),
@@ -787,11 +790,15 @@ def test_flash_with_a_sink_and_a_128_key_window_compiles_at_the_mimo_cell_shape(
         assert name in text
     assert ("flash_sink" in text) == sliding
     assert "bwd_dq" not in text
-    # the band's walk: two masked tiles a q block, none plain, none overlapped
+    # the fitted walk: four masked tiles a q block, none plain, all but the
+    # first of a block issued under another's softmax; the backward's likewise
     if sliding:
         from autodist_tpu import telemetry
+        assert fa._band_span(128, 512, 8192, 512) == 256
         assert [telemetry.gauge(f"flash.fwd.tiles_{k}").value for k in
-                ("plain", "masked", "overlapped")] == [0, 31, 0]
+                ("plain", "masked", "overlapped")] == [0, 64, 48]
+        assert [telemetry.gauge(f"flash.bwd.tiles_{k}").value for k in
+                ("plain", "masked")] == [0, 64]
 
 
 def test_fully_sharded_mimo_step_gathers_the_expert_banks_outside_the_pass_loop(

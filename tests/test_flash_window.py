@@ -3,10 +3,12 @@
 fewer heads than q): forward and all three gradients against dot attention
 with the band mask, in every schedule the kernels have (K/V resident and
 walked in tiles, K/V streamed in blocks; the backward in one pass and
-split), and the tile counts and gauges against counts made by hand. Tiny
-sizes on the CPU; kernels in interpret mode."""
+split), the walk fitted to a window narrower than a key tile against the
+dot path with and without a sink, and the tile counts and gauges against
+counts made by hand. Tiny sizes on the CPU; kernels in interpret mode."""
 
 import importlib
+import itertools
 import sys
 
 import jax
@@ -130,6 +132,93 @@ def test_window_needs_a_causal_mask_and_heads_that_divide():
         fa.flash_attention(q, k, v, window=0)
     with pytest.raises(ValueError, match="KV heads"):
         fa.flash_attention(q[:, :, :3], k, v)
+
+
+# ---------------------------------------------------------- the fitted walk
+
+# Under a window narrower than a key tile (512) the default blocks run the
+# walk fitted to the band (``_band_span``): every window of the list on each
+# side of a lane tile, lengths that are one q block, two with a ragged second,
+# and four whole ones. The forms take turns (seven of them over the 28 pairs,
+# so every length meets every form and every window four of them): a sink or
+# none, 8 query heads over one KV head, keys 192 over values 128, bfloat16
+# operands, and the backward as its two kernels (which keep the tiles' own
+# walk; the forward is fitted all the same).
+FITTED_WINDOWS = (1, 64, 100, 128, 200, 256, 511)
+FITTED_LENGTHS = (256, 640, 1000, 2048)
+FITTED_FORMS = (
+    dict(sink=True, group=1, widths=(32, 32), dtype="float32", split=False),
+    dict(sink=False, group=8, widths=(32, 32), dtype="float32", split=False),
+    dict(sink=True, group=1, widths=(192, 128), dtype="float32", split=False),
+    dict(sink=False, group=1, widths=(32, 32), dtype="bfloat16", split=False),
+    dict(sink=True, group=1, widths=(32, 32), dtype="float32", split=True),
+    dict(sink=True, group=8, widths=(192, 128), dtype="bfloat16", split=False),
+    dict(sink=False, group=1, widths=(128, 128), dtype="float32", split=True),
+)
+
+
+def _fitted_cases():
+    pairs = itertools.product(FITTED_WINDOWS, FITTED_LENGTHS)
+    return [(window, length, FITTED_FORMS[i % len(FITTED_FORMS)])
+            for i, (window, length) in enumerate(pairs)]
+
+
+def _fitted_id(x):
+    if not isinstance(x, dict):
+        return str(x)
+    return "-".join([f"g{x['group']}", "x".join(map(str, x["widths"])),
+                     x["dtype"]] + ["sink"] * x["sink"] + ["split"] * x["split"])
+
+
+@pytest.mark.parametrize("window,length,form", _fitted_cases(), ids=_fitted_id)
+def test_the_walk_fitted_to_a_narrow_band_matches_the_dot_path(
+        monkeypatch, window, length, form):
+    """Forward and the gradients of q, k, v and the sink against
+    ``sink_dot_attention`` on the same operands in float32."""
+    from autodist_tpu.models.mimo_v2 import sink_dot_attention
+    if form["split"]:
+        monkeypatch.setattr(fa, "_RESIDENT_DQ_BYTES", 0)
+    (d_qk, d_v), dtype = form["widths"], jnp.dtype(form["dtype"])
+    heads = form["group"]
+    keys = jax.random.split(jax.random.PRNGKey(window + length), 5)
+    q = jax.random.normal(keys[0], (1, length, heads, d_qk)).astype(dtype)
+    k = jax.random.normal(keys[1], (1, length, 1, d_qk)).astype(dtype)
+    v = jax.random.normal(keys[2], (1, length, 1, d_v)).astype(dtype)
+    sink = 2.0 * jax.random.normal(keys[3], (heads,)) if form["sink"] else None
+    w = jax.random.normal(keys[4], (1, length, heads, d_v))
+    args = (q, k, v) + ((sink,) if form["sink"] else ())
+
+    def run(attend):
+        def loss(q, k, v, sink=None):
+            out = attend(q, k, v, sink).astype(jnp.float32)
+            return jnp.sum(out * w), out
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=tuple(range(len(args))), has_aux=True))(*args)
+        return (out, *grads)
+
+    got = run(lambda q, k, v, s: fa.flash_attention(
+        q, k, v, causal=True, window=window, sink=s))
+    bq, bk, sub = fa._forward_blocks(length, length, max(d_qk, d_v),
+                                     dtype.itemsize, None, None)
+    # one q block and one key tile of 256 at that length: a window that
+    # reaches all of it keeps the tile's own walk
+    fitted = window < sub
+    assert (fa._band_span(window, bq, bk, sub) > 0) == fitted
+    if fitted:
+        assert telemetry.gauge("flash.fwd.tiles_plain").value == 0
+        assert telemetry.gauge("flash.fwd.tiles_masked").value == \
+            -(-length // bq) * (bq // 128)
+    assert telemetry.gauge("flash.bwd.passes").value == (2 if form["split"] else 1)
+    want = run(lambda q, k, v, s: sink_dot_attention(
+        *(x.astype(jnp.float32) for x in (q, k, v)), window, s, jnp.float32))
+    # float32: summation order alone; bfloat16: p and dS are rounded (2^-8)
+    # where they enter a product, the sums are float32
+    atol = 3e-5 if dtype == jnp.float32 else 3e-2
+    for name, a, b in zip(("out", "dq", "dk", "dv", "dsink"), got, want):
+        assert a.shape == b.shape, name
+        scale = float(jnp.max(jnp.abs(b))) or 1.0
+        np.testing.assert_allclose(a.astype(jnp.float32) / scale, b / scale,
+                                   atol=atol, err_msg=name)
 
 
 # ------------------------------------------------------------- tile counts

@@ -75,7 +75,13 @@ layer's (window 128, 8 KV heads, a sink a head: the kernels' device names are
 ``flash_sink_*``) and a full layer's (4 KV heads, no sink). ``--check``
 compares the result and dq, dk, dv and d sink of both with the dot path with
 the sink as a concatenated column, ON THE CHIP at 2,048 positions, and exits
-1 where they differ.
+1 where they differ. Since PR 47 ``--blocks`` / ``--bwd-blocks`` reach these
+shapes (the forward that makes the backward's residuals is asked for the
+backward's q tile), and every causal flash shape prints ``fill_pct`` beside
+its time and its ``tiles_*`` gauges: of the pairs of the score tiles a
+(batch, head) runs, the share the mask keeps (``mimo-swa``: 12.8 under 512 x
+512 tiles, 49.6 under the walk fitted to the band; ``--root`` of a checkout
+older than the fitted walk reads the former).
 
 ``--blocks bq,bk,sub`` overrides the forward's choice, ``--bwd-blocks bq,bk``
 the backward's, ``--xent-blocks bn,bv`` the named head kernel's (a checkout
@@ -332,16 +338,19 @@ def mimo_operands(name, length=None):
     return q, k, v, sink, g, dict(window=window, heads=(h, kv_heads))
 
 
-def build_mimo(fa, name):
+def build_mimo(fa, name, blocks=None):
     """One of MiMo-V2.5's calls, forward or backward on a forward's
-    residuals."""
+    residuals. ``blocks``: the backward's ``(bq, bk)`` override, whose q
+    tile the forward that makes the residuals is asked for too (the lse
+    plane's rows are the forward's q block)."""
     import jax
 
     q, k, v, sink, g, call = mimo_operands(name)
     sinks = () if sink is None else (sink,)
 
     def forward(q, k, v, *sinks):
-        return fa._flash_forward(q, k, v, True, None, None, False, **call,
+        return fa._flash_forward(q, k, v, True, blocks[0] if blocks else None,
+                                 None, False, **call,
                                  sink=sinks[0] if sinks else None)
 
     if kind_of(name) == "fwd":
@@ -434,7 +443,7 @@ def build(fa, name, blocks=None):
     if name in MLA:
         return build_mla(fa, name)
     if name in MIMO:
-        return build_mimo(fa, name)
+        return build_mimo(fa, name, blocks)
     b, length, h, d, causal, kind = SHAPES[name]
     window, kv_heads = BANDS.get(name, (None, h))
     # a checkout older than the window takes no such argument
@@ -560,6 +569,34 @@ def check_head(fx, name, blocks):
     return record
 
 
+def fill_pct(fa, name, blocks, gauges):
+    """Of the (query, key) pairs of the score tiles one (batch, head) of the
+    call runs (``tiles_plain`` + ``tiles_masked`` of its gauges, at the tile
+    its walk takes), the share the mask keeps, in percent: 50 under the full
+    diagonal's tiles at their best, 12.8 under a window of 128 in 512 x 512
+    tiles, 49.6 under the walk fitted to it. None for a shape without a
+    mask, or a checkout without the gauges."""
+    _, length, _, d, causal, kind = SHAPES[name]
+    if not causal or kind not in ("fwd", "bwd") or not gauges:
+        return None
+    window = MIMO_FORMS[MIMO[name]][0] if name in MIMO else \
+        BANDS.get(name, (None,))[0]
+    band_span = getattr(fa, "_band_span", lambda *a: 0)  # older: tiles alone
+    if kind == "fwd":
+        bq, bk, sub = blocks or fa._forward_blocks(length, length, d, 2, None, None)
+        span = band_span(window, bq, bk, sub)
+        tile = 128 * span if span else bq * sub
+    else:
+        bq, bk = blocks or fa._backward_blocks(length, length, None, None)
+        span = band_span(window, bk, length, bq) \
+            if gauges.get("flash.bwd.passes") == 1 else 0
+        tile = 128 * span if span else bq * bk
+    tiles = sum(gauges[f"flash.{kind}.tiles_{c}"] for c in ("plain", "masked"))
+    seen = min(window or length, length)        # keys a late query sees
+    visible = seen * (seen + 1) // 2 + (length - seen) * seen
+    return 100.0 * visible / (tiles * tile)
+
+
 def measure(modules, name, blocks, calls, two_kernels=False):
     import jax
 
@@ -610,6 +647,10 @@ def measure(modules, name, blocks, calls, two_kernels=False):
               if prefix and k.startswith(prefix)}
     if gauges:      # a checkout older than the gauges has none
         record["gauges"] = gauges
+    if name in SHAPES:
+        fill = fill_pct(modules["flash_attention"], name, blocks, gauges)
+        if fill is not None:
+            record["fill_pct"] = round(fill, 2)
     return record
 
 
