@@ -162,6 +162,39 @@ def _powersgd_sync(g: jax.Array, ef: PowerSGDState, pmean=None) -> _SyncResult:
 
 # ------------------------------------------------------------------ grad functions
 
+def _lowering(sharding_plan: ShardingPlan, mesh: Mesh):
+    """What decides :func:`make_grad_fn`'s lowering on this mesh: ``(dp,
+    sparse_wire, hierarchical_ok, requested_dcn, honor_dcn, use_explicit)``."""
+    dp = mesh_dp_size(mesh)
+    sparse_wire = sharding_plan.sparse_wire_params if dp > 1 else {}
+    spec_dcn = plan_lib.strategy_pb2.AllReduceSynchronizer.DCN
+    # Two-phase reduce needs both DP axes populated (inner = intra-slice tier).
+    hierarchical_ok = all(mesh.shape.get(a, 1) > 1 for a in plan_lib.DP_AXES)
+    requested_dcn = any(p.spec == spec_dcn
+                        for p in sharding_plan.params.values())
+    # A DCN (hierarchical-reduce) request is itself a reason to take the
+    # explicit lowering: on the implicit path XLA owns the reduction schedule
+    # and the knob would silently do nothing.
+    honor_dcn = (requested_dcn and dp > 1 and hierarchical_ok
+                 and sharding_plan.all_params_replicated)
+    use_explicit = (sharding_plan.has_compression or bool(sparse_wire)
+                    or honor_dcn)
+    return (dp, sparse_wire, hierarchical_ok, requested_dcn, honor_dcn,
+            use_explicit)
+
+
+def batch_trace_shards(sharding_plan: ShardingPlan, mesh: Mesh) -> int:
+    """How many data shards one trace of :func:`make_grad_fn`'s loss stands
+    for: the data-parallel size under the implicit lowering, whose trace sees
+    the global batch, and 1 under the explicit one, traced a shard at a time.
+    What a trace counts of its own arrays (the bytes a ``KEPT`` list keeps)
+    over this is a chip's share — an upper bound of it where the compiler
+    also splits such an array over a model axis."""
+    dp, *_, use_explicit = _lowering(sharding_plan, mesh)
+    explicit = use_explicit and sharding_plan.all_params_replicated
+    return 1 if explicit else dp
+
+
 def make_grad_fn(sharding_plan: ShardingPlan, model_spec: ModelSpec, mesh: Mesh,
                  loss_fn: Callable, has_aux: bool = False) -> Callable:
     """Build ``grad_fn(params, batch, ef_state) -> (grads, loss, aux, new_ef_state)``.
@@ -182,20 +215,9 @@ def make_grad_fn(sharding_plan: ShardingPlan, model_spec: ModelSpec, mesh: Mesh,
       per-replica residual: x = g + ef; send compress(x);
       ef' = x - decompress(compress(x)).
     """
-    dp = mesh_dp_size(mesh)
-    sparse_wire = sharding_plan.sparse_wire_params if dp > 1 else {}
+    dp, sparse_wire, hierarchical_ok, requested_dcn, honor_dcn, use_explicit \
+        = _lowering(sharding_plan, mesh)
     spec_dcn = plan_lib.strategy_pb2.AllReduceSynchronizer.DCN
-    # Two-phase reduce needs both DP axes populated (inner = intra-slice tier).
-    hierarchical_ok = all(mesh.shape.get(a, 1) > 1 for a in plan_lib.DP_AXES)
-    requested_dcn = any(p.spec == spec_dcn
-                        for p in sharding_plan.params.values())
-    # A DCN (hierarchical-reduce) request is itself a reason to take the
-    # explicit lowering: on the implicit path XLA owns the reduction schedule
-    # and the knob would silently do nothing.
-    honor_dcn = (requested_dcn and dp > 1 and hierarchical_ok
-                 and sharding_plan.all_params_replicated)
-    use_explicit = (sharding_plan.has_compression or bool(sparse_wire)
-                    or honor_dcn)
     if requested_dcn and dp > 1 and not honor_dcn:
         msg = ("spec=DCN (hierarchical two-phase reduce) was requested but "
                "cannot be honored on this mesh/strategy (%s); gradients use a "

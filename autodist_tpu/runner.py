@@ -287,6 +287,9 @@ class DistributedRunner:
         # step cache) is collected, silently suppressing its compile record.
         self._compile_sigs: set = set()
         self._mem_analysis_warned: set = set()
+        # What the checkpointed layers of the step most lately traced keep on
+        # a chip (noted at trace time, booked with the step's HBM account).
+        self._kept_bytes = 0
         self._fetch_tokens: "weakref.WeakKeyDictionary" = \
             weakref.WeakKeyDictionary()
         self._fetch_token_next = 0
@@ -363,6 +366,9 @@ class DistributedRunner:
         # nothing (an empty tuple output), the enabled one a few fused
         # reductions over intermediates the step already has.
         health_on = self.health
+        # The data shards one trace of the loss stands for: what divides the
+        # bytes a trace counts of its own arrays into a chip's share.
+        trace_shards = synchronization.batch_trace_shards(self.plan, mesh)
 
         def accumulate(params, batch, ef_state):
             """Gradient accumulation: scan grad_fn over the micro axis, summing
@@ -404,6 +410,14 @@ class DistributedRunner:
             return grads, jnp.mean(losses), aux, ef_state
 
         def step_fn(state: TrainState, batch: PyTree):
+            # Trace time: what this step's checkpointed layers keep for their
+            # backward (the gauge `remat.kept_bytes`, booked by the policy of
+            # models/common.py `keeping` as this trace differentiates them)
+            # is noted as a chip's share, the batch axis being split over
+            # `data`, for the step's HBM account (`_dispatch_span`).
+            kept = telemetry.registry().get("remat.kept_bytes")
+            if kept is not None:
+                kept.set(0)
             if accum > 1:
                 grads, loss, aux, ef_state = accumulate(state.params, batch,
                                                         state.ef_state)
@@ -411,6 +425,10 @@ class DistributedRunner:
                 with jax.named_scope("step.grad"):
                     grads, loss, aux, ef_state = grad_fn(state.params, batch,
                                                          state.ef_state)
+            kept = telemetry.registry().get("remat.kept_bytes")
+            # graftlint: disable=GL004(a Python int read from a host gauge: the note is of THIS trace by design — a later trace of the model elsewhere overwrites the gauge — and no traced value reaches it)
+            self._kept_bytes = 0 if kept is None \
+                else int(kept.value) // trace_shards
             if zero_plan is not None:
                 # ZeRO weight-update sharding (arXiv 2004.13336): constraining
                 # the gradient to the opt-state shards makes XLA materialize it
@@ -829,27 +847,47 @@ class DistributedRunner:
             # working set does not multiply with its trip count). Optional
             # on some backends, but named when absent — a silently-None
             # ledger is how the memory plane goes dark.
-            for field in ("output_bytes", "argument_bytes", "temp_bytes",
-                          "generated_code_bytes"):
-                out[field] = None
-            try:
-                mem = compiled.memory_analysis()
-                out["output_bytes"] = int(mem.output_size_in_bytes)
-                out["argument_bytes"] = int(mem.argument_size_in_bytes)
-                out["temp_bytes"] = int(mem.temp_size_in_bytes)
-                out["generated_code_bytes"] = \
-                    int(mem.generated_code_size_in_bytes)
-            except Exception as e:  # noqa: BLE001 — optional on some backends
-                backend = jax.default_backend()
-                if backend not in self._mem_analysis_warned:
-                    self._mem_analysis_warned.add(backend)
-                    logging.debug(
-                        "memory_analysis() unavailable on the %r backend "
-                        "(%s); the per-program memory ledger will be empty",
-                        backend, e)
+            out.update(dict.fromkeys(_profiling.MEMORY_FIELDS),
+                       **self._compiled_memory(compiled))
             return out
         except Exception:  # noqa: BLE001
             return None
+
+    def _compiled_memory(self, compiled) -> dict:
+        """A compiled program's ``memory_analysis()`` as a
+        ``telemetry.profiling.MEMORY_FIELDS`` dict of a device's bytes
+        (``alias_bytes``: the outputs that live in donated arguments), empty
+        where the backend has none."""
+        try:
+            mem = compiled.memory_analysis()
+            return {"argument_bytes": int(mem.argument_size_in_bytes),
+                    "output_bytes": int(mem.output_size_in_bytes),
+                    "temp_bytes": int(mem.temp_size_in_bytes),
+                    "alias_bytes": int(mem.alias_size_in_bytes),
+                    "generated_code_bytes":
+                        int(mem.generated_code_size_in_bytes)}
+        except Exception as e:  # noqa: BLE001 — optional on some backends
+            backend = jax.default_backend()
+            if backend not in self._mem_analysis_warned:
+                self._mem_analysis_warned.add(backend)
+                logging.debug(
+                    "memory_analysis() unavailable on the %r backend "
+                    "(%s); the per-program memory ledger will be empty",
+                    backend, e)
+            return {}
+
+    def _step_memory(self, jitted, args) -> dict:
+        """The HBM account's once-a-signature reading: ``memory_analysis()``
+        of the step as it was just dispatched. ``args`` are the dispatch's
+        arguments as shapes and shardings, so the trace and the lowering are
+        the call path's cached ones and the executable is the one that ran:
+        nothing is asked of the backend. Never breaks a step."""
+        try:
+            with self.mesh:
+                return self._compiled_memory(jitted.lower(*args).compile())
+        except Exception as e:  # noqa: BLE001
+            logging.debug("step memory account unavailable: %s", e)
+            return {}
 
     def _maybe_record_oom(self, where: str, exc: BaseException) -> None:
         """OOM forensics at the dispatch sites: when a step died of
@@ -891,15 +929,30 @@ class DistributedRunner:
             return _StepAnnotated(telemetry.span(name, **span_args), step_num)
         self._compile_sigs.add(sig)
         cost_cb = None
-        if cost_probe is not None and _profiling.active():
+        if cost_probe is not None:
             jitted, jit_args = cost_probe
+            # Shapes and shardings, taken while the donated state is alive.
+            jit_args = jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(
+                    x.shape, x.dtype, sharding=x.sharding)
+                if isinstance(x, jax.Array) else x, jit_args)
+            with_cost = _profiling.active()
 
             def cost_cb(compile_s, _d=digest, _k=kind, _s=steps,
                         _fn=jitted, _a=jit_args):
-                _profiling.record_program_cost(
-                    _d, _k, _s,
-                    self._extract_program_cost(_fn, _a, steps=_s),
-                    compile_s=compile_s)
+                # The step's own HBM account (telemetry/memplane.py), and
+                # with the profiling plane on XLA's cost analysis beside it.
+                from autodist_tpu.telemetry import memplane as _memplane
+                t0 = time.perf_counter()
+                cost = None
+                if with_cost:
+                    cost = self._extract_program_cost(_fn, _a, steps=_s)
+                    _profiling.record_program_cost(_d, _k, _s, cost,
+                                                   compile_s=compile_s)
+                memory = cost or self._step_memory(_fn, _a)
+                _profiling.record_program_memory(_d, memory)
+                _memplane.book_step_hbm(memory, time.perf_counter() - t0,
+                                        kept_bytes=self._kept_bytes)
         return _StepAnnotated(_CompileProbe(telemetry.span(
             "jit.compile", kind=kind, sig=digest, **span_args), cost_cb),
             step_num)

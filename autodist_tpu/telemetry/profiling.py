@@ -196,8 +196,12 @@ class ProgramCost:
     # code), UNscaled by steps (unlike flops, a K-step block's working set
     # does not multiply). ``temp_bytes`` is the term the cost model adds to
     # resident state for its peak-HBM estimate.
+    # ``alias_bytes`` is the part of the outputs that lives in donated
+    # arguments (the new state in the old one's buffers): arguments + temps +
+    # outputs - alias is what a dispatch needs on a device.
     argument_bytes: Optional[int] = None
     temp_bytes: Optional[int] = None
+    alias_bytes: Optional[int] = None
     generated_code_bytes: Optional[int] = None
 
     def to_dict(self) -> Dict[str, Any]:
@@ -208,6 +212,7 @@ class ProgramCost:
                 "source": self.source,
                 "argument_bytes": self.argument_bytes,
                 "temp_bytes": self.temp_bytes,
+                "alias_bytes": self.alias_bytes,
                 "generated_code_bytes": self.generated_code_bytes}
 
 
@@ -315,15 +320,35 @@ def note_dispatch(sig: str, kind: str, steps: int = 1):
         rec.dispatches += 1
 
 
+MEMORY_FIELDS = ("argument_bytes", "output_bytes", "temp_bytes",
+                 "alias_bytes", "generated_code_bytes")
+
+
+def _set_memory(rec: ProgramCost, memory: Optional[Dict[str, Any]]):
+    for field in MEMORY_FIELDS:
+        if memory and memory.get(field) is not None:
+            setattr(rec, field, int(memory[field]))
+
+
+def record_program_memory(sig: str, memory: Optional[Dict[str, Any]]):
+    """Attach a compiled program's ``memory_analysis()`` (the runner's
+    ``MEMORY_FIELDS`` dict) to the record its dispatch count made: the memory
+    ledger of a telemetry-enabled run whose attribution plane is off."""
+    with _STATE.lock:
+        rec = _STATE.costs.get(sig)
+        if rec is not None:
+            _set_memory(rec, memory)
+
+
 def record_program_cost(sig: str, kind: str, steps: int,
                         cost: Optional[Dict[str, float]],
                         compile_s: Optional[float] = None) -> ProgramCost:
     """Attach a compiled program's static costs to its signature record
     (creating it if the dispatch count never touched it). ``cost`` is the
     runner-extracted ``{"flops", "bytes_accessed", "output_bytes"}`` dict
-    (plus the ``argument_bytes``/``temp_bytes``/``generated_code_bytes``
-    memory ledger), or None when the backend reported nothing — the analytic
-    fallback (scaled by ``steps``) stands in then."""
+    (plus the :data:`MEMORY_FIELDS` memory ledger), or None when the backend
+    reported nothing — the analytic fallback (scaled by ``steps``) stands in
+    then."""
     with _STATE.lock:
         rec = _STATE.costs.get(sig)
         if rec is None:
@@ -333,13 +358,9 @@ def record_program_cost(sig: str, kind: str, steps: int,
         rec.steps = int(steps)
         if compile_s is not None:
             rec.compile_s = float(compile_s)
-        if cost:
-            # The memory ledger rides independently of the flops report: a
-            # pallas-opaque program can still name its working set.
-            for field in ("argument_bytes", "temp_bytes",
-                          "generated_code_bytes"):
-                if cost.get(field) is not None:
-                    setattr(rec, field, int(cost[field]))
+        # The memory ledger rides independently of the flops report: a
+        # pallas-opaque program can still name its working set.
+        _set_memory(rec, cost)
         analytic = None
         if _STATE.analytic_flops_per_step is not None:
             analytic = float(_STATE.analytic_flops_per_step) * int(steps)

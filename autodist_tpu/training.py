@@ -337,6 +337,10 @@ def train(runner, params: PyTree,
                 pull, shard, depth=prefetch_depth,
                 workers=_prefetch.default_prefetch_workers(),
                 name="train-feed") if prefetch_depth > 0 else None
+            if telemetry.enabled():
+                # The HBM account (telemetry/memplane.py) notes the
+                # allocator's lifetime peak as the loop finds it.
+                _memplane.open_hbm_account()
             try:
                 return _loop(
                     runner, attempt_state, source, producer, pull, use_blocks,
@@ -597,12 +601,11 @@ def _log_boundary(runner, state: TrainState, step: int, losses, rate: float,
             with telemetry.span("train.boundary.planes"):
                 # Memory gauges first so the snapshot emitted below carries
                 # this boundary's live-buffer/HBM readings (and the opt-state
-                # footprint ZeRO sharding divides). The census tags re-point
-                # at THIS boundary's state — the step donates its inputs, so
-                # last boundary's claims are dead weakrefs by now.
-                _memplane.tag("params", state.params)
-                _memplane.tag("opt_state", state.opt_state)
-                telemetry.sample_device_memory(opt_state=state.opt_state)
+                # footprint ZeRO sharding divides). One walk of the state
+                # re-points the census tags at THIS boundary's arrays — the
+                # step donates its inputs, so last boundary's claims are dead
+                # weakrefs by now — and counts it for the HBM account.
+                telemetry.sample_device_memory(state=state)
                 telemetry.emit_metrics(global_step=step)
         if monitor is not None:
             _observe_health(monitor, runner, step,

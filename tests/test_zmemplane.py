@@ -211,12 +211,19 @@ def test_memory_snapshot_and_section_when_armed():
     assert snap["live_bytes"] >= 2048
     assert snap["owned"]["params"] == 2048
     assert snap["budget_source"] in ("default", "env", "measured")
+    # The autopsy's opening line (test_zmemplane_hbm.py): what the HBM account
+    # predicted a chip holds while a step runs, from the gauge a boundary
+    # booked, against the allocator's own peak (the live bytes where, as
+    # here, the backend keeps no statistics).
+    telemetry.gauge("train.hbm.predicted_bytes").set(5000)
     section = memplane.memory_section()
     for key in ("programs", "history", "predicted_peak_bytes",
                 "live_peak_bytes", "peak_delta_bytes"):
         assert key in section
-    # The autopsy's opening line: predicted resident covers the claims.
-    assert section["predicted_peak_bytes"] >= 2048
+    live = section["live_bytes"]
+    assert live >= 2048
+    assert (section["predicted_peak_bytes"], section["live_peak_bytes"],
+            section["peak_delta_bytes"]) == (5000, live, live - 5000)
     json.dumps(section)                                # wire/manifest-encodable
     del arr
 
@@ -329,6 +336,15 @@ def test_record_oom_writes_memory_autopsy(tmp_path):
     owned = manifest["memory"]["owned"]
     assert owned["params"] == 4096 and owned["kv_pages"] == 64
     assert max(memplane.OWNERS, key=lambda o: owned[o]) == "params"
+    # The prediction is the HBM account's last boundary's, the live peak the
+    # allocator's (the live bytes on this backend), the delta theirs.
+    booked = telemetry.registry().get("train.hbm.predicted_bytes")
+    predicted = None if booked is None else int(booked.value)
+    live = manifest["memory"]["live_bytes"]
+    assert manifest["memory"]["predicted_peak_bytes"] == predicted
+    assert manifest["memory"]["live_peak_bytes"] == live >= 4096
+    assert manifest["memory"]["peak_delta_bytes"] == (
+        None if predicted is None else live - predicted)
     del arr
 
 
