@@ -55,8 +55,16 @@ alike. The stack, the loss and the init are ``models/decoder.py``'s.
 
 The scan is one operator, ``ops/ssd_scan.py`` ``ssd_scan``, chunked
 (``chunk_size`` positions a chunk, one ``[P, N]`` state a head carried between
-chunks), and the convolution before it ``ops/short_conv.py`` ``conv_silu``:
-both plain (``ssm_impl="xla"``) or each as two Pallas kernels (``"pallas"``).
+chunks), the convolution before it ``ops/short_conv.py`` ``conv_silu`` and the
+gated norm after it ``ops/gated_norm.py`` ``gated_norm``: all three plain
+(``ssm_impl="xla"``) or each as two Pallas kernels (``"pallas"``). Between
+``in_proj`` and ``out_proj`` the mixer holds ONE layout, ``[B, L, columns]``
+rows (:class:`Mamba2`): the convolution reads ``xBC`` as columns ``d_inner :
+d_inner + conv_dim`` of ``in_proj``'s output, the scan reads ``x``, ``B``,
+``C`` as column blocks of the convolution's output and writes ``y`` as ``[B,
+L, d_inner]`` rows, the norm reads those and ``z`` as the first ``d_inner``
+columns of ``in_proj``'s output; every cut is on a lane tile's edge (4,096;
+10,240; 4,096 and 5,120 within ``xBC``; a run's 512, a group's 128).
 
 Under ``remat`` every layer is a ``jax.checkpoint`` (``models/decoder.py``)
 whose policy keeps a short list of named values (:data:`KEPT`) and makes the
@@ -109,6 +117,7 @@ from autodist_tpu.models.moe import (  # noqa: F401 — the mixture's, under thi
 from autodist_tpu.models.transformer_lm import (  # noqa: F401 — synthetic_batch re-exported
     causal_mask, dot_product_attention, synthetic_batch)
 from autodist_tpu.ops.flash_attention import KEPT_NAME as KEPT_FLASH
+from autodist_tpu.ops.gated_norm import gated_group_norm, gated_norm  # noqa: F401
 from autodist_tpu.ops.short_conv import conv_silu
 from autodist_tpu.ops.ssd_scan import (IMPLS as SSM_IMPLS, KEPT_NAME as KEPT_SCAN,
                                        ssd_scan)
@@ -224,20 +233,15 @@ def _uniform(bound: float):
         key, shape, dtype, -bound, bound)
 
 
-def gated_group_norm(y, z, scale, groups: int, eps: float):
-    """``RMSNorm_grouped(y * silu(z)) * scale``: the mean square over each of
-    ``groups`` equal runs of the last axis, float32."""
-    gated = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-    runs = gated.reshape(*gated.shape[:-1], groups, -1)
-    runs = runs * jax.lax.rsqrt(jnp.mean(jnp.square(runs), axis=-1,
-                                         keepdims=True) + eps)
-    return runs.reshape(gated.shape) * scale
-
-
 class Mamba2(nn.Module):
     """The state-space mixer: input projection to ``[z | xBC | dt]``, the
     depthwise causal convolution with bias and SiLU, the chunked scan, the
-    gated grouped RMSNorm, output projection.
+    gated grouped RMSNorm, output projection. ``h``: ``[B, L, d_model]``;
+    inside, ``[z | xBC | dt]`` is ``[B, L, 2 d_inner + 2 G N + H]``, the
+    convolution's ``[x | B | C]`` ``[B, L, d_inner + 2 G N]``, ``dt`` ``[B,
+    L, H]`` float32, ``y`` and the normed rows ``[B, L, d_inner]``: the
+    operators are handed these arrays whole and told where their columns
+    lie, never a slice or another shape of them.
 
     ``exact``: everything but the scan's products in float32, the two
     projections at ``Precision.HIGH`` (three bfloat16 passes). The first
@@ -253,9 +257,7 @@ class Mamba2(nn.Module):
         cfg = self.config
         dtype, precision = ((jnp.float32, jax.lax.Precision.HIGH) if self.exact
                             else (cfg.dtype, None))
-        b, length, _ = h.shape
-        heads, p, g, n = (cfg.mamba_heads, cfg.mamba_head_dim, cfg.n_groups,
-                          cfg.d_state)
+        heads, g, n = cfg.mamba_heads, cfg.n_groups, cfg.d_state
         d_inner, conv_dim = cfg.d_inner, cfg.d_inner + 2 * g * n
         taps = self.param("conv", _uniform(cfg.conv_kernel ** -0.5),
                           (conv_dim, cfg.conv_kernel), jnp.float32)
@@ -268,23 +270,23 @@ class Mamba2(nn.Module):
         scale = self.param("norm", nn.initializers.ones, (d_inner,), jnp.float32)
         # init runs the plain paths: shapes are all it needs
         impl = "xla" if self.is_initializing() else cfg.ssm_impl
+        # one layout from here to ``out_proj`` (module docstring): a split or
+        # a reshape here is a pass over memory on the chip, 2 GB a layer
+        # before PR 49 (PERF.md section 6)
         with jax.named_scope("ssm.in_proj"):
             zxbcdt = checkpoint_name(
                 _in_proj(d_inner + conv_dim + heads, cfg, "in_proj", dtype,
                          precision)(h), KEPT_IN_PROJ)
-            z, xbc, dt = jnp.split(zxbcdt, [d_inner, d_inner + conv_dim], axis=-1)
         with jax.named_scope("ssm.conv"):
-            xbc = conv_silu(xbc, taps, conv_bias, impl).astype(cfg.dtype)
-            x, bmat, cmat = jnp.split(xbc, [d_inner, d_inner + g * n], axis=-1)
+            xbc = conv_silu(zxbcdt, taps, conv_bias, impl,
+                            at=d_inner).astype(cfg.dtype)
         with jax.named_scope("ssm.scan"):
-            dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
-            y = ssd_scan(x.reshape(b, length, heads, p), dt, -jnp.exp(a_log),
-                         bmat.reshape(b, length, g, n),
-                         cmat.reshape(b, length, g, n), d_skip, chunk=cfg.chunk,
-                         impl=impl)
+            dt = jax.nn.softplus(
+                zxbcdt[..., d_inner + conv_dim:].astype(jnp.float32) + dt_bias)
+            y = ssd_scan(xbc, dt, -jnp.exp(a_log), None, None, d_skip,
+                         chunk=cfg.chunk, impl=impl, groups=(g, n))
         with jax.named_scope("ssm.gate_norm"):
-            y = gated_group_norm(y.reshape(b, length, d_inner), z, scale, g,
-                                 cfg.rms_eps).astype(dtype)
+            y = gated_norm(y, zxbcdt, scale, g, cfg.rms_eps, dtype, impl)
         with jax.named_scope("ssm.out_proj"):
             return _out_proj(cfg, "out_proj", dtype, precision)(y)
 

@@ -42,12 +42,15 @@ backend the kernels run in pallas interpret mode; ``tests/test_chip_compile.py``
 compiles them for a described v5e at the LFM2 cell's shape.
 
 Beside it the ungated form the state-space hybrids run before their scan
-(Mamba-2's): :func:`conv_silu`, ``silu(causal_conv_w(x) + b)``, plain
-``jax.numpy`` alone; its custom VJP keeps ``x``, ``w`` and ``b`` and nothing
-else, as the gated form's does. It has no kernel yet and is worth one:
-stand-alone at Nemotron's ``[1, 8192, 6144]`` XLA's lowering takes 1.74 ms
-forward and 5.76 ms backward where the bytes allow 0.25 and 0.37 (PERF.md §6,
-"PR 35"; §7 has the walk such a kernel would take).
+(Mamba-2's and Mamba-1's): :func:`conv_silu`, ``silu(causal_conv_w(x) + b)``,
+plain and as two kernels of the same walk, ``conv_silu_fwd`` and
+``conv_silu_bwd`` (grid (channel block, sequence, row block)); its custom VJP
+keeps ``x``, ``w`` and ``b`` and nothing else, as the gated form's does. Its
+operand may be a column window of a wider array (``at``: Nemotron's
+``in_proj`` output ``[z | xBC | dt]`` handed whole, of which the kernels read
+channel blocks ``at / block`` onward where they lie): the caller's slice was
+a pass over memory of its own, 100 MB a call (PERF.md section 6, "PR 49").
+An operand as wide as the taps traces what it always traced.
 """
 
 import functools
@@ -441,8 +444,8 @@ def _backward_call(bcu, w, dy, interpret: bool, block_rows=None, channels=None):
 _SILU_BLOCK_D = 2048    # channels a grid step holds: 6,144 = 3 x 2,048
 
 
-def _silu_tiles(x, w, block_rows: int):
-    if x.ndim != 3 or w.ndim != 2 or x.shape[2] != w.shape[0]:
+def _silu_tiles(x, w, block_rows: int, at: int = 0):
+    if x.ndim != 3 or w.ndim != 2 or x.shape[2] < at + w.shape[0]:
         raise ValueError(f"conv_silu: x {x.shape} against taps {w.shape}; "
                          f"want [B, L, d] and [d, K]")
     d, k = w.shape
@@ -450,23 +453,50 @@ def _silu_tiles(x, w, block_rows: int):
         raise ValueError(f"conv_silu kernels: d {d} must be a multiple of 128 "
                          f"and K {k} at most {_SUB + 1}")
     block_rows, block_d = _tiles(x.shape[1], d, block_rows, _SILU_BLOCK_D)
+    block_d = math.gcd(at, block_d)     # a window starts on a block's edge
     return block_rows, block_d, _tiles(x.shape[1], block_d, block_rows,
                                        _CHANNELS)[1]
 
 
-def _silu_forward_call(x, w, b, interpret: bool):
-    batch, length, d = x.shape
-    k = w.shape[1]
-    block_rows, block_d, channels = _silu_tiles(x, w, FWD_BLOCK_ROWS)
+def _is_window(x, w, at: int, impl: str) -> bool:
+    """Whether ``x`` is a wider array that holds the convolution's ``d``
+    channels as its columns ``at : at + d``, which the kernels read as channel
+    blocks of the array as it lies (what stands beside them takes a zero
+    gradient). Static shapes alone."""
+    if x.ndim != 3 or w.ndim != 2 or not (at or x.shape[2] > w.shape[0]):
+        return False
+    d = w.shape[0]
+    if at < 0 or at + d > x.shape[2]:
+        raise ValueError(f"conv_silu: columns {at}:{at + d} of x {x.shape}")
+    if impl == "pallas" and at % 128:
+        raise ValueError(f"conv_silu kernels: the window's first column {at} "
+                         f"is not on a lane tile's edge (a multiple of 128)")
+    return True
+
+
+def _window_block(first: int):
+    """Channel block ``c`` of the operand -> its block of a wider ``x`` whose
+    window begins at block ``first`` (0: the index as it is, so that an
+    operand as wide as the taps traces no ``+ 0``)."""
+    return (lambda c: c + first) if first else (lambda c: c)
+
+
+def _silu_forward_call(x, w, b, interpret: bool, at: int = 0):
+    batch, length, _ = x.shape
+    d, k = w.shape
+    block_rows, block_d, channels = _silu_tiles(x, w, FWD_BLOCK_ROWS, at)
     block = pl.BlockSpec((1, block_rows, block_d), lambda c, s, i: (s, i, c))
     taps = lambda rows: pl.BlockSpec((rows, block_d), lambda c, s, i: (0, c))  # noqa: E731
+    col = _window_block(at // block_d)
+    x_block = pl.BlockSpec((1, block_rows, block_d),
+                           lambda c, s, i: (s, i, col(c)))
     return named_pallas_call(
         "conv_silu_fwd",
         functools.partial(_silu_fwd_kernel, k=k, channels=channels),
         grid=(d // block_d, batch, pl.cdiv(length, block_rows)),
-        in_specs=[block, taps(k), taps(1)],
+        in_specs=[x_block, taps(k), taps(1)],
         out_specs=block,
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        out_shape=jax.ShapeDtypeStruct((batch, length, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((_SUB, block_d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
@@ -475,10 +505,10 @@ def _silu_forward_call(x, w, b, interpret: bool):
     )(x, w.astype(jnp.float32).T, b.astype(jnp.float32)[None])
 
 
-def _silu_backward_call(x, w, b, dy, interpret: bool):
-    batch, length, d = x.shape
-    k = w.shape[1]
-    block_rows, block_d, channels = _silu_tiles(x, w, BWD_BLOCK_ROWS)
+def _silu_backward_call(x, w, b, dy, interpret: bool, at: int = 0):
+    batch, length, _ = x.shape
+    d, k = w.shape
+    block_rows, block_d, channels = _silu_tiles(x, w, BWD_BLOCK_ROWS, at)
     last_halo = pl.cdiv(length, _SUB) - 1
     per_block = block_rows // _SUB
     block = pl.BlockSpec((1, block_rows, block_d), lambda c, s, i: (s, i, c))
@@ -486,15 +516,20 @@ def _silu_backward_call(x, w, b, dy, interpret: bool):
         (1, _SUB, block_d),
         lambda c, s, i: (s, jnp.minimum((i + 1) * per_block, last_halo), c))
     taps = lambda rows: pl.BlockSpec((rows, block_d), lambda c, s, i: (0, c))  # noqa: E731
+    col = _window_block(at // block_d)
+    x_block = pl.BlockSpec((1, block_rows, block_d),
+                           lambda c, s, i: (s, i, col(c)))
+    x_after = pl.BlockSpec((1, _SUB, block_d), lambda c, s, i: (
+        s, jnp.minimum((i + 1) * per_block, last_halo), col(c)))
     dy = dy.astype(x.dtype)
     dx, dw, db = named_pallas_call(
         "conv_silu_bwd",
         functools.partial(_silu_bwd_kernel, k=k, channels=channels,
                           length=length),
         grid=(d // block_d, batch, pl.cdiv(length, block_rows)),
-        in_specs=[block, block, after, after, taps(k), taps(1)],
+        in_specs=[x_block, block, x_after, after, taps(k), taps(1)],
         out_specs=[block, taps(k), taps(1)],
-        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+        out_shape=[jax.ShapeDtypeStruct((batch, length, d), x.dtype),
                    jax.ShapeDtypeStruct((k, d), jnp.float32),
                    jax.ShapeDtypeStruct((1, d), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((_SUB, block_d), jnp.float32)],
@@ -572,19 +607,50 @@ def _conv_silu_bwd(impl, residuals, dy):
 _conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _conv_silu_window(x, w, b, at):
+    """:func:`_conv_silu`'s kernels on columns ``at : at + d`` of a wider
+    ``x``, read where they lie."""
+    return _silu_forward_call(x, w, b, _flash._use_interpret(), at)
+
+
+def _conv_silu_window_fwd(x, w, b, at):
+    return _conv_silu_window(x, w, b, at), (x, w, b)
+
+
+def _conv_silu_window_bwd(at, residuals, dy):
+    x, w = residuals[:2]
+    dx, dw, db = _silu_backward_call(*residuals, dy, _flash._use_interpret(), at)
+    return (jnp.pad(dx, ((0, 0), (0, 0), (at, x.shape[2] - at - w.shape[0]))),
+            dw, db)
+
+
+_conv_silu_window.defvjp(_conv_silu_window_fwd, _conv_silu_window_bwd)
+
+
 def conv_silu(x: jax.Array, w: jax.Array, b: jax.Array,
-              impl: str = "xla") -> jax.Array:
+              impl: str = "xla", at: int = 0) -> jax.Array:
     """``y_t = silu(sum_j w[:, j] * x_{t-(K-1)+j} + b)``, depthwise over the
     channels, causal, each sequence on its own (``x_s = 0`` for ``s < 0``).
-    x: ``[batch, L, d]``; w: ``[d, K]``; b: ``[d]``; ``impl``: ``"xla"`` or
-    ``"pallas"`` (``d`` a multiple of 128). Returns ``[batch, L, d]`` in
-    ``x.dtype``; the arithmetic is float32. Differentiable in all three; only
-    they are kept for the backward. Under a mesh of several devices the
-    kernels run per device, as :func:`gated_short_conv`'s do."""
+    x: ``[batch, L, d]``, or a wider array whose columns ``at : at + d`` are
+    the operand (a projection's output handed whole: the kernels read the
+    window where it lies, ``at`` a multiple of 128, and nothing is cut out
+    first); w: ``[d, K]``; b: ``[d]``; ``impl``: ``"xla"`` or ``"pallas"``
+    (``d`` a multiple of 128). Returns ``[batch, L, d]`` in ``x.dtype``; the
+    arithmetic is float32. Differentiable in all three; only they are kept
+    for the backward. Under a mesh of several devices the kernels run per
+    device, as :func:`gated_short_conv`'s do. The gauge
+    ``short_conv.operands_relaid`` counts the wide operands a kernel call was
+    handed cut apart (1, or 0 for a window)."""
     if impl not in IMPLS:
         raise ValueError(f"Unknown conv impl {impl!r}; valid: {IMPLS}")
+    wide = _is_window(x, w, at, impl)
     if impl == "xla":
+        if wide:
+            x = x[..., at:at + w.shape[0]]
         return _conv_silu(x, w, b, impl)
     from autodist_tpu.parallel.mesh import per_device
-    return per_device(functools.partial(_conv_silu, impl=impl), (x, w, b),
-                      batched=(True, False, False))
+    telemetry.gauge("short_conv.operands_relaid").set(0 if wide else 1)
+    run = (functools.partial(_conv_silu_window, at=at) if wide
+           else functools.partial(_conv_silu, impl=impl))
+    return per_device(run, (x, w, b), batched=(True, False, False))

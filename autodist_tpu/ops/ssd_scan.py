@@ -47,6 +47,27 @@ gradients follow from the chunk alone.
   gradients (of ``cum``, ``dt``, ``D``) leave the kernel the same way and XLA
   finishes them (a reversed cumsum, three reductions over ``[B, L, H]``).
 
+**What a caller hands, and what that costs around the kernels.** The kernels
+address ``x`` and ``y`` as ``[b, L, H P]`` rows, ``B`` and ``C`` as ``[b, L, G
+N]`` rows and the states as ``[b, chunks, G, R P, N]``. A call that hands
+``x [b, L, H, P]`` and ``B``, ``C [b, L, G, N]`` (cut out of the
+convolution's output and reshaped, as every caller did before PR 49) has XLA
+write each of them out first and put ``dx``, ``dB``, ``dC`` together after:
+on the chip ``[b, L, H, P]`` and ``[b, L, H P]`` are different bytes, tiles
+of 8 x 128 over the last two dimensions. A call that leaves ``B`` and ``C``
+out hands the convolution's ``[b, L, H P + 2 G N]`` rows whole (``groups =
+(G, N)`` says how the columns divide): the three are read as column blocks
+of that one array (``B`` begins at block ``H P / N``, so ``H P`` is a
+multiple of ``N``), ``y`` comes back, is named and is kept as the ``[b, L, H
+P]`` rows ``ssd_fwd`` wrote, ``dy`` is taken as rows, and ``ssd_bwd`` writes
+``dx`` into d ``[x | B | C]`` where it lies, whose last ``2 G N`` columns the
+two narrow results then fill in place. The operand handed is the signal;
+the values are the same bit for bit, and a four-dimensional call traces
+what it always traced. ``dt`` and ``cum`` still cross the boundary in
+column form ``[b, G, L, R]`` whose last dimension the chip pads to 128
+lanes (six arrays of 33.5 MB a backward call at the Nemotron cell's shape:
+PERF.md section 7, "Open after PR 49").
+
 On the CPU backend the kernels run in pallas interpret mode;
 ``tests/test_chip_compile.py`` compiles them for a described v5e at the
 Nemotron cell's shape.
@@ -291,14 +312,13 @@ def _bwd_kernel(x_ref, b_ref, c_ref, dy_ref, dtc_ref, cumc_ref, cumr_ref,
 
 # ------------------------------------------------------------------- calls
 
-def _layouts(x, dt, A, B, C, D, chunk: int):
+def _layouts(x, dt, A, B, C, D, chunk: int, sizes):
     """The arrays as the kernels read them: heads and groups folded into the
-    lanes of ``x``, ``B`` and ``C``; ``dt`` and ``cum`` a group in column
-    form ``[b, G, L, R]`` and ``cum`` in row form ``[b, G, R, L]`` too; ``D``
-    a row of ``H * P`` lanes a group."""
-    b, length, h, p = x.shape
-    g, n = B.shape[2:]
-    r = h // g
+    lanes of ``x``, ``B`` and ``C`` (``B`` None: ``x`` is ``[b, L, H P + 2 G
+    N]`` rows that hold all three, handed three times as they lie); ``dt``
+    and ``cum`` a group in column form ``[b, G, L, R]`` and ``cum`` in row
+    form ``[b, G, R, L]`` too; ``D`` a row of ``H * P`` lanes a group."""
+    b, length, h, p, g, n, r = sizes
     dtf = dt.astype(jnp.float32)
     a = dtf * A.astype(jnp.float32)
     # heads on the lanes for the running sum ([.., chunk, 64]): a [.., 8, 8]
@@ -306,18 +326,27 @@ def _layouts(x, dt, A, B, C, D, chunk: int):
     cum = jnp.cumsum(a.reshape(b, length // chunk, chunk, h), axis=2)
     cum = cum.reshape(b, length, g, r)
     col = lambda t: jnp.moveaxis(t.reshape(b, length, g, r), 1, 2)  # noqa: E731
-    return (x.reshape(b, length, h * p), B.reshape(b, length, g * n),
-            C.reshape(b, length, g * n), col(dtf), col(cum),
-            jnp.moveaxis(cum, 1, 3),
+    wide = (x, x, x) if B is None else (
+        x.reshape(b, length, h * p), B.reshape(b, length, g * n),
+        C.reshape(b, length, g * n))
+    return (*wide, col(dtf), col(cum), jnp.moveaxis(cum, 1, 3),
             jnp.repeat(D.astype(jnp.float32), p).reshape(g, 1, r * p))
 
 
-def _specs(chunk: int, r: int, p: int, n: int, order):
+def _specs(chunk: int, sizes, order, rows: bool):
     """Block specs by name, for a grid (sequence, group, chunk) whose chunk
-    index maps through ``order`` (the backward walks down)."""
+    index maps through ``order`` (the backward walks down). ``rows``: ``B``
+    and ``C`` are read out of the ``[b, L, H P + 2 G N]`` rows behind ``x``,
+    ``B`` from column block ``H P / N`` on and ``C`` ``G`` blocks further."""
+    _, _, h, p, g, n, r = sizes
+    group = pl.BlockSpec((1, chunk, n), lambda b, g, c: (b, order(c), g))
+    behind = lambda first: pl.BlockSpec(  # noqa: E731
+        (1, chunk, n), lambda b, g, c: (b, order(c), g + first))
     return dict(
         lanes=pl.BlockSpec((1, chunk, r * p), lambda b, g, c: (b, order(c), g)),
-        group=pl.BlockSpec((1, chunk, n), lambda b, g, c: (b, order(c), g)),
+        group=group,
+        B=behind(h * p // n) if rows else group,
+        C=behind(h * p // n + g) if rows else group,
         col=pl.BlockSpec((1, 1, chunk, r), lambda b, g, c: (b, g, order(c), 0)),
         row=pl.BlockSpec((1, 1, r, chunk), lambda b, g, c: (b, g, 0, order(c))),
         d=pl.BlockSpec((1, 1, r * p), lambda b, g, c: (g, 0, 0)),
@@ -325,20 +354,28 @@ def _specs(chunk: int, r: int, p: int, n: int, order):
                             lambda b, g, c: (b, order(c), g, 0, 0)))
 
 
-def _sizes(x, B):
-    b, length, h, p = x.shape
-    g, n = B.shape[2:]
+def _sizes(x, dt, B, groups=None):
+    """``(b, L, H, P, G, N, R)``; ``B`` None: of ``x [b, L, H P + 2 G N]``
+    rows and ``groups = (G, N)``."""
+    b, length, h = dt.shape
+    if B is None:
+        g, n = groups
+        p = (x.shape[2] - 2 * g * n) // h
+    else:
+        p, (g, n) = x.shape[3], B.shape[2:]
     return b, length, h, p, g, n, h // g
 
 
-def _forward_call(x, dt, A, B, C, D, chunk: int, interpret: bool):
-    b, length, h, p, g, n, r = _sizes(x, B)
+def _forward_call(x, dt, A, B, C, D, chunk: int, interpret: bool,
+                  groups=None):
+    sizes = b, length, h, p, g, n, r = _sizes(x, dt, B, groups)
+    rows = B is None
     nc = length // chunk
-    spec = _specs(chunk, r, p, n, lambda c: c)
+    spec = _specs(chunk, sizes, lambda c: c, rows)
     y, states = named_pallas_call(
         "ssd_fwd", functools.partial(_fwd_kernel, heads=r, p=p),
         grid=(b, g, nc),
-        in_specs=[spec["lanes"], spec["group"], spec["group"], spec["col"],
+        in_specs=[spec["lanes"], spec["B"], spec["C"], spec["col"],
                   spec["col"], spec["row"], spec["d"]],
         out_specs=[spec["lanes"], spec["states"]],
         out_shape=[jax.ShapeDtypeStruct((b, length, h * p), x.dtype),
@@ -348,27 +385,35 @@ def _forward_call(x, dt, A, B, C, D, chunk: int, interpret: bool):
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
-    )(*_layouts(x, dt, A, B, C, D, chunk))
+    )(*_layouts(x, dt, A, B, C, D, chunk, sizes))
+    if rows:
+        return y, states
     return y.reshape(x.shape), states.reshape(b, nc, g, r, p, n)
 
 
-def _backward_call(x, dt, A, B, C, D, states, dy, chunk: int, interpret: bool):
-    b, length, h, p, g, n, r = _sizes(x, B)
+def _backward_call(x, dt, A, B, C, D, states, dy, chunk: int, interpret: bool,
+                   groups=None):
+    sizes = b, length, h, p, g, n, r = _sizes(x, dt, B, groups)
+    rows = B is None
     nc = length // chunk
-    spec = _specs(chunk, r, p, n, lambda c: nc - 1 - c)
-    x2, b2, c2, dtc, cumc, cumr, d2 = _layouts(x, dt, A, B, C, D, chunk)
+    spec = _specs(chunk, sizes, lambda c: nc - 1 - c, rows)
+    x2, b2, c2, dtc, cumc, cumr, d2 = _layouts(x, dt, A, B, C, D, chunk, sizes)
     small = jax.ShapeDtypeStruct((b, g, length, r), jnp.float32)
+    narrow = jax.ShapeDtypeStruct((b, length, g * n), x.dtype)
     dx, db, dc, d_cum_col, d_cum_row, d_dt, dyx = named_pallas_call(
         "ssd_bwd", functools.partial(_bwd_kernel, heads=r, p=p),
         grid=(b, g, nc),
-        in_specs=[spec["lanes"], spec["group"], spec["group"], spec["lanes"],
+        in_specs=[spec["lanes"], spec["B"], spec["C"], spec["lanes"],
                   spec["col"], spec["col"], spec["row"], spec["d"],
                   spec["states"]],
         out_specs=[spec["lanes"], spec["group"], spec["group"], spec["col"],
                    spec["row"], spec["col"], spec["col"]],
+        # rows: dx is written where it lies in d [x | B | C], whose other
+        # columns the two narrow results then fill
         out_shape=[jax.ShapeDtypeStruct(x2.shape, x.dtype),
-                   jax.ShapeDtypeStruct(b2.shape, B.dtype),
-                   jax.ShapeDtypeStruct(c2.shape, C.dtype), small,
+                   narrow if rows else jax.ShapeDtypeStruct(b2.shape, B.dtype),
+                   narrow if rows else jax.ShapeDtypeStruct(c2.shape, C.dtype),
+                   small,
                    jax.ShapeDtypeStruct((b, g, r, length), jnp.float32),
                    small, small],
         scratch_shapes=[pltpu.VMEM((r * p, n), jnp.float32)],
@@ -376,8 +421,8 @@ def _backward_call(x, dt, A, B, C, D, states, dy, chunk: int, interpret: bool):
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
-    )(x2, b2, c2, dy.astype(x.dtype).reshape(x2.shape), dtc, cumc, cumr, d2,
-      states.reshape(b, nc, g, r * p, n))
+    )(x2, b2, c2, dy.astype(x.dtype).reshape(b, length, h * p), dtc, cumc,
+      cumr, d2, states.reshape(b, nc, g, r * p, n))
     # [b, G, L, R] -> [b, L, H]; the gradient of a running sum is the sum of
     # what follows, inside the chunk
     flat = lambda t: jnp.moveaxis(t, 2, 1).reshape(b, length, h)  # noqa: E731
@@ -387,22 +432,28 @@ def _backward_call(x, dt, A, B, C, D, states, dy, chunk: int, interpret: bool):
     ).reshape(b, length, h)
     Af, dtf = A.astype(jnp.float32), dt.astype(jnp.float32)
     d_dt = flat(d_dt) + d_a * Af
-    return (dx.reshape(x.shape), d_dt.astype(dt.dtype),
-            jnp.sum(d_a * dtf, axis=(0, 1)).astype(A.dtype),
-            db.reshape(B.shape), dc.reshape(C.shape),
-            jnp.sum(flat(dyx), axis=(0, 1)).astype(D.dtype))
+    given = (lambda t, like: t) if rows else (  # noqa: E731
+        lambda t, like: t.reshape(like.shape))
+    grads = (given(dx, x), d_dt.astype(dt.dtype),
+             jnp.sum(d_a * dtf, axis=(0, 1)).astype(A.dtype), given(db, B),
+             given(dc, C), jnp.sum(flat(dyx), axis=(0, 1)).astype(D.dtype))
+    if rows:        # d [x | B | C] whole: the narrow two into their columns
+        dx = jax.lax.dynamic_update_slice(dx, db, (0, 0, h * p))
+        dx = jax.lax.dynamic_update_slice(dx, dc, (0, 0, h * p + g * n))
+        return (dx, *grads[1:3], None, None, grads[5])
+    return grads
 
 
 # --------------------------------------------------------------- public op
 
-def moved_bytes(x, B, chunk: int):
+def moved_bytes(sizes, dtype, chunk: int):
     """``(forward, backward)`` bytes one call must move, each operand once:
     forward ``x``, ``B``, ``C`` read, ``y`` and one float32 state a chunk and
     head written; backward those read again with ``dy`` and ``dx``, ``dB``,
     ``dC`` written. ``dt`` and ``cum`` ([b, L, H] float32) are nothing beside
     them and left out."""
-    b, length, h, p, g, n, _ = _sizes(x, B)
-    size = jnp.dtype(x.dtype).itemsize
+    b, length, h, p, g, n, _ = sizes
+    size = jnp.dtype(dtype).itemsize
     wide, narrow = b * length * h * p * size, b * length * g * n * size
     states = b * -(-length // chunk) * h * p * n * 4
     return (2 * wide + 2 * narrow + states, 3 * wide + 4 * narrow + states)
@@ -434,9 +485,56 @@ def _scan_bwd(chunk, impl, residuals, dy):
 _scan.defvjp(_scan_fwd, _scan_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _scan_rows(xbc, dt, A, D, chunk, groups):
+    """The kernels on ``[x | B | C]`` rows as one array (``groups = (G, N)``):
+    ``y [b, L, H P]`` rows, named and kept in the shape ``ssd_fwd`` wrote."""
+    return _scan_rows_fwd(xbc, dt, A, D, chunk, groups)[0]
+
+
+def _scan_rows_fwd(xbc, dt, A, D, chunk, groups):
+    y, states = checkpoint_name(
+        _forward_call(xbc, dt, A, None, None, D, chunk, _flash._use_interpret(),
+                      groups), KEPT_NAME)
+    return y, (xbc, dt, A, D, states)
+
+
+def _scan_rows_bwd(chunk, groups, residuals, dy):
+    xbc, dt, A, D, states = residuals
+    dxbc, d_dt, d_a, _, _, d_d = _backward_call(
+        xbc, dt, A, None, None, D, states, dy, chunk, _flash._use_interpret(),
+        groups)
+    return dxbc, d_dt, d_a, d_d
+
+
+_scan_rows.defvjp(_scan_rows_fwd, _scan_rows_bwd)
+
+
+def _check_rows(x, dt, groups, impl: str):
+    """The sizes of a call that hands ``[x | B | C]`` as one array of rows,
+    or a refusal that names what is wrong with it."""
+    if (x.ndim != 3 or dt.ndim != 3 or x.shape[:2] != dt.shape[:2]
+            or groups is None or len(groups) != 2):
+        raise ValueError(
+            f"ssd_scan: B and C left out, so x {x.shape} holds [x | B | C] as "
+            f"[B, L, H P + 2 G N] rows beside dt {dt.shape} [B, L, H], and "
+            f"groups {groups} says (G, N)")
+    h, (g, n) = dt.shape[2], groups
+    wide = x.shape[2] - 2 * g * n
+    if wide <= 0 or wide % h or h % g:
+        raise ValueError(
+            f"ssd_scan: {x.shape[2]} columns are not {h} heads of P and twice "
+            f"{g} groups of {n}, G dividing H")
+    if impl == "pallas" and wide % n:
+        raise ValueError(
+            f"ssd_scan kernels: B begins at column {wide} of the rows, off a "
+            f"block of {n} (the state's width, a multiple of a lane tile's 128)")
+    return _sizes(x, dt, None, groups)
+
+
 def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
              C: jax.Array, D: jax.Array, chunk: int = 128,
-             impl: str = "xla") -> jax.Array:
+             impl: str = "xla", groups=None) -> jax.Array:
     """``y_t = S_t C_t + D x_t`` with ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t
     B_t^T`` (module docstring). x: ``[batch, L, H, P]``; dt: ``[batch, L, H]``
     (positive: after its softplus); A, D: ``[H]``; B, C: ``[batch, L, G, N]``,
@@ -445,24 +543,49 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
     in ``x.dtype``. Differentiable in all six; the inputs and one ``[P, N]``
     float32 state a chunk and head are kept for the backward.
 
+    **Rows.** ``B`` and ``C`` None: ``x`` is ``[batch, L, H P + 2 G N]``, the
+    convolution's ``[x | B | C]`` as it wrote it, and ``groups = (G, N)``
+    says how its columns divide. The kernels read the three where they lie
+    (``H P`` a multiple of ``N``), the result is ``[batch, L, H P]`` rows as
+    ``ssd_fwd`` writes them, and the backward writes d ``[x | B | C]`` as one
+    array: nothing is cut, reshaped or put together around the kernels. The
+    values are the four-dimensional call's, bit for bit. The gauge
+    ``ssd.operands_relaid`` counts the wide operands a kernel call was handed
+    cut apart (x, B, C and the result: 4, or 0 for rows).
+
     Under a mesh of several devices the kernels run per device
     (:func:`autodist_tpu.parallel.mesh.per_device`), the batch split over the
     data axes."""
     if impl not in IMPLS:
         raise ValueError(f"Unknown ssd impl {impl!r}; valid: {IMPLS}")
-    b, length, h, p = x.shape
-    if (dt.shape != (b, length, h) or A.shape != (h,) or D.shape != (h,)
-            or B.shape != C.shape or B.shape[:2] != (b, length)
-            or B.ndim != 4 or h % B.shape[2]):
-        raise ValueError(
-            f"ssd_scan: x {x.shape}, dt {dt.shape}, A {A.shape}, B {B.shape}, "
-            f"C {C.shape}, D {D.shape}; want [B, L, H, P], [B, L, H], [H], "
-            f"[B, L, G, N] twice with G dividing H, [H]")
-    g, n = B.shape[2:]
-    if impl == "pallas" and (n % 128 or (h // g * p) % 128 or chunk % 128):
+    rows = B is None and C is None
+    if rows:
+        sizes = b, length, h, p, g, n, r = _check_rows(x, dt, groups, impl)
+        if impl == "xla":       # the plain path on the three cut apart
+            cut = lambda lo, hi, *dims: x[..., lo:hi].reshape(  # noqa: E731
+                b, length, *dims)
+            y = ssd_scan(cut(0, h * p, h, p), dt, A,
+                         cut(h * p, h * p + g * n, g, n),
+                         cut(h * p + g * n, h * p + 2 * g * n, g, n), D, chunk)
+            return y.reshape(b, length, h * p)
+    else:
+        if (x.ndim != 4 or dt.shape != x.shape[:3] or B is None or C is None
+                or B.shape != C.shape or B.shape[:2] != x.shape[:2]
+                or B.ndim != 4 or x.shape[2] % B.shape[2]):
+            raise ValueError(
+                f"ssd_scan: x {x.shape}, dt {dt.shape}, A {A.shape}, B "
+                f"{getattr(B, 'shape', None)}, C {getattr(C, 'shape', None)}, D "
+                f"{D.shape}; want [B, L, H, P], [B, L, H], [H], "
+                f"[B, L, G, N] twice with G dividing H, [H]")
+        sizes = _sizes(x, dt, B)
+    b, length, h, p, g, n, r = sizes
+    if A.shape != (h,) or D.shape != (h,):
+        raise ValueError(f"ssd_scan: A {A.shape}, D {D.shape}; want [H] = "
+                         f"[{h}] twice")
+    if impl == "pallas" and (n % 128 or (r * p) % 128 or chunk % 128):
         raise ValueError(
             f"ssd_scan kernels: state {n}, a group's heads x head_dim "
-            f"{h // g * p} and chunk {chunk} must be multiples of 128")
+            f"{r * p} and chunk {chunk} must be multiples of 128")
     chunks = -(-length // chunk)
     telemetry.counter("ssd.calls").inc()
     telemetry.gauge("ssd.chunk").set(chunk)
@@ -470,18 +593,25 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
     telemetry.gauge("ssd.heads").set(h)
     telemetry.gauge("ssd.groups").set(g)
     telemetry.gauge("ssd.state").set(n)
-    fwd_bytes, bwd_bytes = moved_bytes(x, B, chunk)
+    fwd_bytes, bwd_bytes = moved_bytes(sizes, x.dtype, chunk)
     telemetry.gauge("ssd.fwd.bytes").set(fwd_bytes)
     telemetry.gauge("ssd.bwd.bytes").set(bwd_bytes)
+    if impl == "pallas":
+        telemetry.gauge("ssd.operands_relaid").set(0 if rows else 4)
     pad = chunks * chunk - length
     if pad:
-        rows = lambda t: jnp.pad(t, [(0, 0), (0, pad)] + [(0, 0)] * (t.ndim - 2))  # noqa: E731
-        x, dt, B, C = rows(x), rows(dt), rows(B), rows(C)
-    run = functools.partial(_scan, chunk=chunk, impl=impl)
+        padded = lambda t: jnp.pad(t, [(0, 0), (0, pad)] + [(0, 0)] * (t.ndim - 2))  # noqa: E731
+        x, dt = padded(x), padded(dt)
+        if not rows:
+            B, C = padded(B), padded(C)
+    from autodist_tpu.parallel.mesh import per_device
     if impl == "xla":
-        y = run(x, dt, A, B, C, D)
+        y = _scan(x, dt, A, B, C, D, chunk=chunk, impl=impl)
+    elif rows:
+        y = per_device(functools.partial(_scan_rows, chunk=chunk, groups=groups),
+                       (x, dt, A, D), batched=(True, True, False, False))
     else:
-        from autodist_tpu.parallel.mesh import per_device
-        y = per_device(run, (x, dt, A, B, C, D),
+        y = per_device(functools.partial(_scan, chunk=chunk, impl=impl),
+                       (x, dt, A, B, C, D),
                        batched=(True, True, False, True, True, False))
     return y[:, :length] if pad else y

@@ -477,7 +477,8 @@ def test_kernel_names_unchanged():
         "moe_rows_gather", "moe_rows_combine",
         "ssd_fwd", "ssd_bwd", "conv_silu_fwd", "conv_silu_bwd",
         "selective_scan_fwd", "selective_scan_bwd",
-        "flash_sink_fwd", "flash_sink_bwd_dkv", "flash_sink_bwd_dq")
+        "flash_sink_fwd", "flash_sink_bwd_dkv", "flash_sink_bwd_dq",
+        "gated_norm_fwd", "gated_norm_bwd")
 
 
 @_NEEDS_MESH

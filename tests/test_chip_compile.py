@@ -308,29 +308,41 @@ def test_grouped_matmul_fwd_bwd_compiles_at_the_nemotron_cell_shapes(chip, k, n)
         assert name in text
 
 
-@pytest.mark.parametrize("length,chunks", [(8192, 64), (1000, 8)],
-                         ids=["cell-1x8192", "ragged-L1000"])
+@pytest.mark.parametrize("length,chunks,rows", [
+    (8192, 64, False), (1000, 8, False), (8192, 64, True), (1000, 8, True)],
+    ids=["cell-1x8192", "ragged-L1000", "cell-rows", "ragged-rows"])
 def test_ssd_scan_fwd_bwd_compiles_at_the_nemotron_cell_shape(chip, length,
-                                                              chunks):
+                                                              chunks, rows):
     """nemotron-pretrain-8k's call: 64 heads of 64 in 8 groups, state 128,
     chunks of 128, bfloat16 ``x``, ``B``, ``C`` and float32 ``dt``: the
     forward and the backward kernel, each a Mosaic call under its own name,
     through the operator's custom VJP; a length the chunk does not divide is
-    padded."""
+    padded. ``rows``: as the cell hands it since PR 49, ``[x | B | C]`` one
+    array of 6,144 columns as the convolution wrote it; around the two calls
+    nothing of its size or of ``y``'s is then laid out anew."""
     from autodist_tpu.ops.ssd_scan import ssd_scan
 
-    def loss(x, dt, a, b, c, d):
-        return ssd_scan(x, dt, a, b, c, d, impl="pallas").astype(jnp.float32).sum()
-
-    wide = ((1, length, 64, 64), jnp.bfloat16)
-    narrow = ((1, length, 8, 128), jnp.bfloat16)
-    text = _compiled_text(jax.value_and_grad(loss, argnums=tuple(range(6))), chip,
-                          wide, ((1, length, 64), jnp.float32),
-                          ((64,), jnp.float32), narrow, narrow,
-                          ((64,), jnp.float32))
+    small = (((1, length, 64), jnp.float32), ((64,), jnp.float32))
+    if rows:
+        def loss(xbc, dt, a, d):
+            return ssd_scan(xbc, dt, a, None, None, d, impl="pallas",
+                            groups=(8, 128)).astype(jnp.float32).sum()
+        shapes = (((1, length, 6144), jnp.bfloat16), *small, ((64,), jnp.float32))
+    else:
+        def loss(x, dt, a, b, c, d):
+            return ssd_scan(x, dt, a, b, c, d, impl="pallas").astype(jnp.float32).sum()
+        narrow = ((1, length, 8, 128), jnp.bfloat16)
+        shapes = (((1, length, 64, 64), jnp.bfloat16), *small, narrow, narrow,
+                  ((64,), jnp.float32))
+    text = _compiled_text(jax.value_and_grad(
+        loss, argnums=tuple(range(len(shapes)))), chip, *shapes)
     assert "tpu_custom_call" in text
     assert "ssd_fwd" in text and "ssd_bwd" in text
     assert f"f32[1,{chunks},8,512,128]" in text      # one state a chunk and head
+    if rows:
+        padded = chunks * 128
+        assert not _relayouts(text, {n * w for n in (length, padded)
+                                     for w in (4096, 6144)})
 
 
 def test_flash_sixteen_query_heads_a_kv_head_compile_at_the_nemotron_cell_shape(chip):
@@ -428,8 +440,8 @@ def _kernel_launches(text: str, kernel: str) -> int:
 
 
 @pytest.mark.parametrize("kind,exact,kept,bare", [
-    ("M", True, dict(ssd_fwd=1, conv_silu_fwd=2, ssd_bwd=1, conv_silu_bwd=1),
-     dict(ssd_fwd=2)),
+    ("M", True, dict(ssd_fwd=1, conv_silu_fwd=2, ssd_bwd=1, conv_silu_bwd=1,
+                     gated_norm_fwd=2, gated_norm_bwd=1), dict(ssd_fwd=2)),
     ("*", False, dict(flash_fwd=1, flash_bwd_dkv=1), dict(flash_fwd=2)),
 ], ids=["mamba-exact", "attention"])
 def test_a_checkpointed_nemotron_layer_launches_each_kept_forward_kernel_once(
@@ -437,9 +449,9 @@ def test_a_checkpointed_nemotron_layer_launches_each_kept_forward_kernel_once(
     """One layer of nemotron-pretrain-8k at its widths and 8,192 positions
     under ``jax.checkpoint`` with the model's policy (``KEPT``): what the
     kernels' forward rules hand their backward is kept by name, so the
-    compiled gradient launches those forward kernels once (the convolution's,
-    whose output is not on the list, twice); under a bare ``jax.checkpoint``
-    (the parent's) it launches each twice."""
+    compiled gradient launches those forward kernels once (the convolution's
+    and the gated norm's, whose outputs are not on the list, twice); under a
+    bare ``jax.checkpoint`` (the parent's) it launches each twice."""
     from autodist_tpu.models import common, nemotron_h
     cfg = nemotron_h.NemotronHConfig(attention_impl="flash", ssm_impl="pallas")
     block = nemotron_h.NemotronHBlock(cfg, kind, exact)
@@ -462,6 +474,37 @@ def test_a_checkpointed_nemotron_layer_launches_each_kept_forward_kernel_once(
 
     assert launches(common.keeping(nemotron_h.KEPT)) == kept
     assert launches(None) == dict(kept, **bare)
+
+
+@pytest.mark.parametrize("exact", [False, True], ids=["bfloat16", "exact"])
+def test_a_checkpointed_mamba2_layer_lays_no_wide_array_out_anew(chip, exact):
+    """One Mamba-2 layer of nemotron-pretrain-8k under its checkpoint,
+    compiled for the described v5e: between ``in_proj`` and ``out_proj`` the
+    convolution, the scan and the gated norm read and write ``[B, L,
+    columns]`` rows where their neighbours hold them, so the text holds no
+    ``copy``, ``transpose`` or non-bitcast ``reshape`` of ``8192 x 4096`` (z,
+    x, y and the norm's rows) or ``8192 x 6144`` (xBC) elements, and no slice
+    that cuts them out of ``[z | xBC | dt]`` (1,342 + 503 + 134 MB of such
+    passes a layer on the parent: PERF.md section 6, "PR 49")."""
+    from autodist_tpu.models import common, nemotron_h
+    cfg = nemotron_h.NemotronHConfig(attention_impl="flash", ssm_impl="pallas")
+    mixer = nemotron_h.Mamba2(cfg, exact)
+    dtype = jnp.float32 if exact else jnp.bfloat16
+    params = jax.eval_shape(lambda: mixer.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8, cfg.d_model), dtype)))
+    params = jax.tree_util.tree_map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=chip), params)
+    layer = jax.checkpoint(
+        lambda params, h: mixer.apply(params, h).astype(jnp.float32).sum(),
+        policy=common.keeping(nemotron_h.KEPT))
+    text = jax.jit(jax.grad(layer, argnums=(0, 1))).lower(
+        params, jax.ShapeDtypeStruct((1, 8192, cfg.d_model), dtype, sharding=chip)
+    ).compile().as_text()
+    assert not _relayouts(text, {8192 * 4096, 8192 * 6144})
+    assert not re.search(r"\[1,8192,(4096|6144)\]\S* slice\(", text)
+    assert {k: _kernel_launches(text, k) for k in
+            ("ssd_fwd", "ssd_bwd", "conv_silu_bwd", "gated_norm_bwd")} == dict(
+        ssd_fwd=1, ssd_bwd=1, conv_silu_bwd=1, gated_norm_bwd=1)
 
 
 @pytest.mark.slow    # a whole step: about 100 s
@@ -548,27 +591,58 @@ def test_short_conv_fwd_bwd_compiles(chip, batch, length, d, taps):
     assert "short_conv_fwd" in text and "short_conv_bwd" in text
 
 
-@pytest.mark.parametrize("length,dtype", [(8192, jnp.bfloat16),
-                                          (8192, jnp.float32),
-                                          (1000, jnp.bfloat16)],
-                         ids=["cell-1x8192x6144", "cell-layer-0-f32",
-                              "ragged-L1000"])
+@pytest.mark.parametrize("length,dtype,window", [
+    (8192, jnp.bfloat16, False), (8192, jnp.float32, False),
+    (1000, jnp.bfloat16, False), (8192, jnp.bfloat16, True),
+    (8192, jnp.float32, True), (1000, jnp.bfloat16, True)],
+    ids=["cell-1x8192x6144", "cell-layer-0-f32", "ragged-L1000", "cell-window",
+         "cell-layer-0-f32-window", "ragged-window"])
 def test_conv_silu_fwd_bwd_compiles_at_the_nemotron_cell_shape(chip, length,
-                                                               dtype):
+                                                               dtype, window):
     """nemotron-pretrain-8k's call: the ungated convolution with bias and SiLU
     over 6,144 channels (3 channel blocks of 2,048), four taps, in bfloat16
     and, for layer 0, float32: two Mosaic calls under their own names through
-    the operator's custom VJP."""
+    the operator's custom VJP. ``window``: as the cell hands it since PR 49,
+    columns 4,096 : 10,240 of ``in_proj``'s ``[z | xBC | dt]`` (10,304 wide,
+    half a lane tile past a whole one) read where they lie: no slice of
+    6,144 columns is written first."""
     from autodist_tpu.ops.short_conv import conv_silu
 
     def loss(x, w, b):
-        return conv_silu(x, w, b, "pallas").astype(jnp.float32).sum()
+        return conv_silu(x, w, b, "pallas", at=4096 if window else 0
+                         ).astype(jnp.float32).sum()
 
     text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1, 2)), chip,
-                          ((1, length, 6144), dtype), ((6144, 4), jnp.float32),
-                          ((6144,), jnp.float32))
+                          ((1, length, 10304 if window else 6144), dtype),
+                          ((6144, 4), jnp.float32), ((6144,), jnp.float32))
     assert "tpu_custom_call" in text
     assert "conv_silu_fwd" in text and "conv_silu_bwd" in text
+    if window:      # (the layer's test holds that nothing is laid out anew:
+        # here the sum of the loss asks for a layout of its own)
+        assert not re.search(rf"\[1,{length},6144\]\S* slice\(", text)
+
+
+@pytest.mark.parametrize("length,y_dtype,z_dtype", [
+    (8192, jnp.bfloat16, jnp.bfloat16), (8192, jnp.bfloat16, jnp.float32),
+    (1000, jnp.bfloat16, jnp.bfloat16)],
+    ids=["cell-1x8192x4096", "cell-layer-0-f32", "ragged-L1000"])
+def test_gated_norm_fwd_bwd_compiles_at_the_nemotron_cell_shape(chip, length,
+                                                                y_dtype, z_dtype):
+    """nemotron-pretrain-8k's call: the scan's 4,096 columns of ``y`` gated by
+    the first 4,096 of ``in_proj``'s 10,304 and normed over 8 runs of 512, in
+    bfloat16 and, for layer 0, a float32 ``z`` and result: two Mosaic calls
+    under their own names, and no float32 copy of the rows beside them."""
+    from autodist_tpu.ops.gated_norm import gated_norm
+
+    def loss(y, z, scale):
+        return gated_norm(y, z, scale, 8, 1e-5, z_dtype, "pallas"
+                          ).astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1, 2)), chip,
+                          ((1, length, 4096), y_dtype),
+                          ((1, length, 10304), z_dtype), ((4096,), jnp.float32))
+    assert "gated_norm_fwd" in text and "gated_norm_bwd" in text
+    assert f"f32[{length},8,512]" not in text and "[1024,8,8,512]" not in text
 
 
 def test_fused_xent_forward_compiles_at_lm1b_vocab(chip):

@@ -87,7 +87,7 @@ def test_lowered_step_names_kernels_and_phases(backward, monkeypatch):
                     if k != "flash_carry"
                     and not k.startswith(("moe_", "short_conv_", "ssd_",
                                           "conv_silu_", "selective_scan_",
-                                          "flash_sink_"))
+                                          "flash_sink_", "gated_norm_"))
                     and (k != "flash_bwd_dq" or backward == "split")]
     assert _scopes(text, named_call.KERNEL_NAMES) == set(step_kernels)
     # ZeRO's constrain_update is the reduction under AllReduce (the implicit
@@ -253,7 +253,8 @@ def _nemotron_step_text() -> str:       # one lowering for the cases below
 
 
 @pytest.mark.parametrize("name", [k for k in named_call.KERNEL_NAMES
-                                  if k.startswith(("ssd_", "conv_silu_"))]
+                                  if k.startswith(("ssd_", "conv_silu_",
+                                                   "gated_norm_"))]
                          + ["moe_rows_combine", "moe_gmm_fwd", "flash_fwd",
                             "xent_fwd"] + list(SSM_SCOPES) + list(MOE_SCOPES))
 def test_nemotron_step_names_its_scan_kernels_and_scopes(name):

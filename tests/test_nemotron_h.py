@@ -164,6 +164,69 @@ def test_the_gated_norm_is_a_mean_square_over_each_groups_run():
     np.testing.assert_allclose(got, want * np.asarray(scale), rtol=1e-5, atol=1e-6)
 
 
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("y_dtype,z_dtype,out_dtype,length", [
+    (jnp.float32, jnp.float32, jnp.float32, 40),
+    (jnp.bfloat16, jnp.bfloat16, jnp.bfloat16, 64),
+    # the exact first layer's: the scan's bfloat16 y beside a float32 z, a
+    # float32 result; and a length past one block of rows, ragged
+    (jnp.bfloat16, jnp.float32, jnp.float32, 1100),
+], ids=["float32", "bfloat16", "exact-layer-ragged"])
+def test_the_gated_norm_operator_is_gated_group_norms_equations(
+        impl, y_dtype, z_dtype, out_dtype, length):
+    """``ops/gated_norm.py`` handed ``z`` as the first columns of a wider
+    array (``[z | xBC | dt]`` as ``in_proj`` wrote it) against the equations
+    on the cut-out ``z``, float32 arithmetic either way: the value, ``dy``,
+    ``dz`` (zero behind ``z``'s columns) and the scale's gradient, in the
+    operands' dtypes."""
+    from autodist_tpu.ops.gated_norm import gated_norm
+    d, groups, behind = 256, 2, 128 + 64
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    y = jax.random.normal(keys[0], (2, length, d), y_dtype)
+    wide = jax.random.normal(keys[1], (2, length, d + behind), z_dtype)
+    scale = 1.0 + 0.1 * jax.random.normal(keys[2], (d,))
+    weight = jax.random.normal(keys[3], (2, length, d))
+
+    def value_and_grads(fn):
+        def loss(y, wide, scale):
+            out = fn(y, wide, scale)
+            return jnp.sum(out.astype(jnp.float32) * weight), out
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True))(y, wide, scale)
+        return out, grads
+
+    got, got_grads = value_and_grads(lambda y, wide, scale: gated_norm(
+        y, wide, scale, groups, 1e-5, out_dtype, impl))
+    want, want_grads = value_and_grads(
+        lambda y, wide, scale: nemotron_h.gated_group_norm(
+            y, wide[..., :d], scale, groups, 1e-5).astype(out_dtype))
+    assert got.dtype == out_dtype and got.shape == y.shape
+    # float32: sums in another order; bfloat16: one rounding of them
+    tolerance = 1e-5 if out_dtype == jnp.float32 else 2 ** -7
+    np.testing.assert_allclose(got.astype(jnp.float32), want.astype(jnp.float32),
+                               rtol=tolerance, atol=tolerance)
+    for name, g, r in zip(("dy", "dz", "dscale"), got_grads, want_grads):
+        assert g.shape == r.shape and g.dtype == r.dtype, name
+        g, r = g.astype(jnp.float32), r.astype(jnp.float32)
+        loose = 1e-4 if y_dtype == jnp.float32 else 2e-2   # a bfloat16 dy
+        assert float(jnp.linalg.norm(g - r)) <= loose * float(jnp.linalg.norm(r)), name
+    assert float(jnp.abs(got_grads[1][..., d:]).max()) == 0.0
+
+
+def test_the_gated_norm_operator_refuses_what_it_cannot_take():
+    from autodist_tpu.ops.gated_norm import gated_norm
+    y, scale = jnp.zeros((1, 16, 192)), jnp.ones((192,))
+    with pytest.raises(ValueError, match="Unknown gated norm impl"):
+        gated_norm(y, y, scale, 2, 1e-5, impl="mosaic")
+    with pytest.raises(ValueError, match=r"want \[B, L, d\]"):
+        gated_norm(y, y[..., :128], scale, 2, 1e-5)
+    with pytest.raises(ValueError, match="groups dividing d"):
+        gated_norm(y, y, scale, 5, 1e-5)
+    with pytest.raises(ValueError, match="a run of 96 columns"):
+        gated_norm(y, y, scale, 2, 1e-5, impl="pallas")
+    assert gated_norm(y, y, scale, 2, 1e-5).shape == y.shape
+
+
 def test_the_sixteen_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer():
     """What the guide asks of a share: the routed parts that the shares
     ``first_expert_held`` = 0, 8, ..., 120 of a 128-wide router give, with the

@@ -121,8 +121,9 @@ def test_a_checkpointed_layer_runs_each_kept_forward_kernel_once(
 # weights and their indices); attention q, k, v, flash's output and its
 # log-sum-exp.
 KEPT_BY_KIND = {
-    nemotron_h.MAMBA: [((2, 40, 1028), "bfloat16"), ((2, 128, 4, 64), "bfloat16"),
-                       ((2, 1, 2, 2, 64, 128), "float32")],
+    # [z | xBC | dt]; the scan's y and its states in the shapes ssd_fwd wrote
+    nemotron_h.MAMBA: [((2, 40, 1028), "bfloat16"), ((2, 128, 256), "bfloat16"),
+                       ((2, 1, 2, 128, 128), "float32")],
     nemotron_h.EXPERTS: [((2, 40, 40), "bfloat16"), ((80, 8), "float32"),
                          ((40, 64), "bfloat16"), ((40, 24), "bfloat16"),
                          ((40, 24), "bfloat16"), ((40, 24), "bool"),

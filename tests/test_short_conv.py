@@ -3,7 +3,11 @@ three shifted products written out here, value and all four gradients (dB,
 dC, du, dw), with a block edge on the sequence edge, a sequence the block does
 not divide, three and four taps; nothing crosses from one sequence of a batch
 to the next; the custom VJP keeps ``bcu`` and ``w`` and nothing else; the
-gauges say what a call moves. The kernels run in interpret mode on the CPU."""
+gauges say what a call moves; the cells' calls of this file's two operators
+trace the jaxprs they traced before ``conv_silu`` took a window of a wider
+array. The kernels run in interpret mode on the CPU."""
+
+import hashlib
 
 import jax
 import jax.numpy as jnp
@@ -162,3 +166,41 @@ def test_arguments_the_operator_cannot_take_are_refused():
         gated_short_conv(bcu[..., :192], w[:64], "pallas")
     # the plain path takes any width
     assert gated_short_conv(bcu[..., :192], w[:64], "xla").shape == (1, 16, 64)
+
+
+F32, BF16 = jnp.float32, jnp.bfloat16
+# a cell's call of this file's operators -> the SHA-256 of its jaxpr at the
+# commit before ``conv_silu`` took a window (75acf8b; regenerate there with
+# the test's own lines if the kernels change on purpose)
+CELL_CALLS = {
+    "jamba2-sharded4-16k": (
+        lambda x, w, b: short_conv.conv_silu(x, w, b, "pallas"),
+        (((1, 16384, 5120), BF16), ((5120, 4), F32), ((5120,), F32)),
+        "f1eefb0f64e4d29467b801224b5d9eeb8ec24fb5d729aa9307b08e78f3c5416b"),
+    "jamba2-sharded4-16k-precise-layer": (
+        lambda x, w, b: short_conv.conv_silu(x, w, b, "pallas"),
+        (((1, 16384, 5120), F32), ((5120, 4), F32), ((5120,), F32)),
+        "a7b96ee517856e11ea9c3117e9e5bc2c59381dca708ca7fca2f3f43850009693"),
+    "nemotron-cut-out": (
+        lambda x, w, b: short_conv.conv_silu(x, w, b, "pallas"),
+        (((1, 8192, 6144), BF16), ((6144, 4), F32), ((6144,), F32)),
+        "d5e387193601ca1f738d38870b01c05aa9cca39a19a810abfb26ebf8042a837a"),
+    "lfm2-pretrain-8k": (
+        lambda bcu, w: gated_short_conv(bcu, w, "pallas"),
+        (((2, 8192, 6144), BF16), ((2048, 3), F32)),
+        "8750114dd667fbaa9e9cccf7347ca24d55a68905cb1f29cd88c7c6d3b9b2e8ba"),
+}
+
+
+@pytest.mark.parametrize("name", list(CELL_CALLS))
+def test_a_cells_call_traces_the_jaxpr_it_traced_before(name):
+    """The jaxpr of a call and its gradients AT THE CELL'S SIZE, the kernels'
+    bodies and block specs included (traced from shapes: nothing is lowered
+    or run): an operand as wide as the taps is what it always was, so Jamba's
+    and LFM2's steps compile what they compiled."""
+    fn, shapes, parent = CELL_CALLS[name]
+    args = [jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in shapes]
+    text = str(jax.make_jaxpr(jax.value_and_grad(
+        lambda *a: fn(*a).astype(jnp.float32).sum(),
+        argnums=tuple(range(len(args)))))(*args))
+    assert hashlib.sha256(text.encode()).hexdigest() == parent

@@ -4,10 +4,14 @@ quadratic form of the benchmark's reference, value and the gradient of every
 input; lengths that are and are not whole chunks; nothing crosses from one
 sequence of a batch to the next; chunk 128 and a smaller one; the custom VJP
 keeps the inputs and one state a chunk and head; the gauges say what a call
-moves; ``conv_silu`` (``ops/short_conv.py``) against shifted products. The
-kernels run in interpret mode on the CPU."""
+moves; ``conv_silu`` (``ops/short_conv.py``) against shifted products; both
+operators handed their operands as column windows of a wider array (the rows
+a neighbour wrote) give the cut-apart call's numbers bit for bit, and a call
+that hands what it always did traces the jaxpr it always traced. The kernels
+run in interpret mode on the CPU."""
 
 import functools
+import hashlib
 import os
 import sys
 
@@ -87,20 +91,50 @@ CASES = {
 }
 
 
+# the same calls with [x | B | C] handed as one array of rows, as the
+# convolution writes them: case -> the cut-apart case it must equal
+ROWS = {"xla-rows-ragged": "xla-ragged",
+        "pallas-rows-two-chunks-two-sequences": "pallas-two-chunks-two-sequences",
+        "pallas-rows-ragged": "pallas-ragged"}
+
+
+def _as_rows(chunk, impl):
+    """``ssd_scan`` on ``[x | B | C]`` put together first, result and
+    gradients back in the cut-apart call's shapes."""
+    def fn(x, dt, A, B, C, D):
+        flat = lambda t: t.reshape(*t.shape[:2], -1)  # noqa: E731
+        y = ssd_scan(jnp.concatenate([flat(x), flat(B), flat(C)], axis=-1), dt,
+                     A, None, None, D, chunk=chunk, impl=impl,
+                     groups=B.shape[2:])
+        return y.reshape(x.shape)
+    return fn
+
+
 @functools.lru_cache(maxsize=None)
 def _scanned(case):
-    """A case's operands and what ``ssd_scan`` makes of them, once for the two
+    """A case's operands and what ``ssd_scan`` makes of them, once for the
     forms it is held against."""
-    impl, (*shape, chunk) = CASES[case]
+    impl, (*shape, chunk) = CASES[ROWS.get(case, case)]
     inputs, weight = _operands(*shape)
-    fn = lambda *a: ssd_scan(*a, chunk=chunk, impl=impl)  # noqa: E731
+    fn = (_as_rows(chunk, impl) if case in ROWS
+          else lambda *a: ssd_scan(*a, chunk=chunk, impl=impl))
     return inputs, weight, _value_and_grads(fn, inputs, weight)
 
 
 @pytest.mark.parametrize("against", ["recurrence", "quadratic"])
-@pytest.mark.parametrize("case", list(CASES))
+@pytest.mark.parametrize("case", list(CASES) + list(ROWS))
 def test_values_and_every_gradient_match(case, against):
     inputs, weight, ((_, got), y) = _scanned(case)
+    if case in ROWS:
+        # the rows are the cut-apart call's: the kernels' bit for bit (the
+        # same blocks of the same numbers), the plain path's to a float32
+        # rounding (XLA fuses the cuts into its products as it likes)
+        _, _, ((_, cut), cut_y) = _scanned(ROWS[case])
+        for a, b in zip((y, *got), (cut_y, *cut)):
+            if CASES[ROWS[case]][0] == "pallas":
+                np.testing.assert_array_equal(a, b)
+            else:
+                np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
     plain = {"recurrence": recurrence, "quadratic": quadratic}[against]
     (_, want), want_y = _value_and_grads(plain, inputs, weight)
     np.testing.assert_allclose(y, want_y, rtol=2e-4, atol=2e-4)
@@ -165,11 +199,18 @@ def test_the_backward_keeps_the_inputs_and_one_state_a_chunk_and_head(impl):
     assert states.dtype == jnp.float32 and states.size == 2 * 4 * 64 * 128
 
 
-def test_gauges_and_the_counter_are_set_when_the_operator_is_traced():
+@pytest.mark.parametrize("rows,relaid", [(False, 4), (True, 0)],
+                         ids=["cut-apart", "rows"])
+def test_gauges_and_the_counter_are_set_when_the_operator_is_traced(rows, relaid):
+    """``ssd.operands_relaid``: x, B, C and the result cross the kernels'
+    boundary in another shape on a cut-apart call, none of them as rows."""
     calls = telemetry.counter("ssd.calls").value
     inputs, _ = _operands(2, 200, 4, 64, 2, 128, dtype=jnp.bfloat16)
-    jax.eval_shape(lambda *a: ssd_scan(*a, impl="pallas"), *inputs)
+    fn = (_as_rows(128, "pallas") if rows
+          else lambda *a: ssd_scan(*a, impl="pallas"))
+    jax.eval_shape(fn, *inputs)
     assert telemetry.counter("ssd.calls").value == calls + 1
+    assert telemetry.gauge("ssd.operands_relaid").value == relaid
     assert [telemetry.gauge(f"ssd.{k}").value for k in
             ("chunk", "chunks", "heads", "groups", "state")] == [128, 4, 4, 2, 128]
     wide, narrow = 2 * 200 * 4 * 64 * 2, 2 * 200 * 2 * 128 * 2
@@ -190,6 +231,58 @@ def test_mismatched_arguments_and_unknown_impls_are_refused():
         ssd_scan(x, dt, A, B, C, D, chunk=16, impl="pallas")
 
 
+@pytest.mark.parametrize("impl,heads,width,groups,refusal", [
+    # 2 heads of 64 end at column 128, but 3 heads of 64 at 192: B would
+    # begin inside a block of the state's 128 columns
+    ("pallas", 3, 192 + 2 * 128, (1, 128), "off a block of 128"),
+    ("pallas", 2, 128 + 2 * 128, None, r"groups None says \(G, N\)"),
+    ("xla", 2, 128 + 2 * 128 + 1, (1, 128), "are not 2 heads of P"),
+    ("xla", 3, 192 + 4 * 128, (2, 128), "G dividing H"),
+], ids=["cut-off-a-lane-tile", "groups-left-out", "columns-do-not-add-up",
+        "groups-do-not-divide-the-heads"])
+def test_rows_the_operator_cannot_take_are_refused_by_name(impl, heads, width,
+                                                           groups, refusal):
+    xbc, dt = jnp.zeros((1, 128, width)), jnp.ones((1, 128, heads))
+    with pytest.raises(ValueError, match=refusal):
+        ssd_scan(xbc, dt, -jnp.ones(heads), None, None, jnp.ones(heads),
+                 impl=impl, groups=groups)
+    if impl == "pallas" and groups:     # the plain path cuts anywhere
+        assert ssd_scan(xbc, dt, -jnp.ones(heads), None, None, jnp.ones(heads),
+                        groups=groups).shape == (1, 128, 192)
+
+
+def _sha(fn, *shapes):
+    args = [jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in shapes]
+    return hashlib.sha256(str(jax.make_jaxpr(jax.value_and_grad(
+        lambda *a: fn(*a).astype(jnp.float32).sum(),
+        argnums=tuple(range(len(args)))))(*args)).encode()).hexdigest()
+
+
+# (B, L, H, P, G, N), x's dtype -> the SHA-256 of the call's jaxpr at the
+# commit before the rows (75acf8b; regenerate there with ``_sha`` if the
+# kernels change on purpose)
+PARENT_SCANS = {
+    "nemotron-cell": ((1, 8192, 64, 64, 8, 128), jnp.bfloat16,
+                      "cd3488e4ba9fc216f8e4a6792f75eba196e79b53d194cbbf77ff1e6842a0f34e"),
+    "ragged-float32": ((1, 1000, 4, 64, 2, 128), jnp.float32,
+                       "0782fa523e8acd760a8908e2c6df2a1570042e49668d93b38d636d149a3099b8"),
+}
+
+
+@pytest.mark.parametrize("name", list(PARENT_SCANS))
+def test_a_four_dimensional_call_traces_the_jaxpr_it_traced_before(name):
+    """The jaxpr of a cut-apart call and its six gradients, the kernels'
+    bodies and block specs included (traced from shapes: nothing is lowered
+    or run): what the caller hands is the signal, so a call that hands what
+    it always did compiles what it always did."""
+    (b, length, h, p, g, n), dtype, parent = PARENT_SCANS[name]
+    f32 = jnp.float32
+    assert _sha(lambda *a: ssd_scan(*a, impl="pallas"),
+                ((b, length, h, p), dtype), ((b, length, h), f32), ((h,), f32),
+                ((b, length, g, n), dtype), ((b, length, g, n), dtype),
+                ((h,), f32)) == parent
+
+
 # ------------------------------------------- the convolution before the scan
 
 def _shifted_silu(x, w, b):
@@ -203,36 +296,60 @@ def _shifted_silu(x, w, b):
     return jax.nn.silu(total + b)
 
 
-@pytest.mark.parametrize("impl,batch,length,d,k", [
-    ("xla", 2, 24, 48, 4), ("xla", 1, 3, 16, 4), ("xla", 2, 17, 32, 3),
+@pytest.mark.parametrize("impl,batch,length,d,k,window", [
+    ("xla", 2, 24, 48, 4, None), ("xla", 1, 3, 16, 4, None),
+    ("xla", 2, 17, 32, 3, None),
     # the kernels: a length of whole row blocks, a ragged one of three row
     # blocks whose last holds 8 rows, one shorter than a walk step, and two
     # channel blocks of a grid (2,560 = 2 x 1,280)
-    ("pallas", 2, 512, 256, 4), ("pallas", 2, 520, 128, 4),
-    ("pallas", 1, 3, 128, 4), ("pallas", 2, 40, 2560, 3),
+    ("pallas", 2, 512, 256, 4, None), ("pallas", 2, 520, 128, 4, None),
+    ("pallas", 1, 3, 128, 4, None), ("pallas", 2, 40, 2560, 3, None),
+    # the operand as columns ``at : at + d`` of a wider array, (at, width):
+    # anywhere on the plain path; for the kernels behind one lane tile, at
+    # the front of an array that ends off one (as [z | xBC | dt] does), and
+    # two channel blocks behind two more
+    ("xla", 2, 24, 48, 4, (5, 64)), ("pallas", 2, 520, 256, 4, (128, 640)),
+    ("pallas", 2, 40, 256, 4, (0, 320)), ("pallas", 2, 40, 2560, 3, (2560, 5184)),
 ], ids=["four-taps", "shorter-than-the-taps", "three-taps", "kernels-whole-blocks",
-        "kernels-ragged", "kernels-shorter-than-a-step", "kernels-two-channel-blocks"])
-def test_conv_silu_and_its_three_gradients_match_shifted_products(impl, batch,
-                                                                  length, d, k):
-    conv = jax.jit(functools.partial(short_conv.conv_silu, impl=impl))
+        "kernels-ragged", "kernels-shorter-than-a-step", "kernels-two-channel-blocks",
+        "window", "kernels-window-ragged", "kernels-window-in-front",
+        "kernels-window-two-channel-blocks"])
+def test_conv_silu_and_its_three_gradients_match_shifted_products(
+        impl, batch, length, d, k, window):
+    at, width = window or (0, d)
+    conv = jax.jit(functools.partial(short_conv.conv_silu, impl=impl, at=at))
+    cut = lambda x: x[..., at:at + d]  # noqa: E731
+    plain = lambda x, w, b: _shifted_silu(cut(x), w, b)  # noqa: E731
     keys = jax.random.split(jax.random.PRNGKey(0), 4)
-    x = jax.random.normal(keys[0], (batch, length, d))
+    x = jax.random.normal(keys[0], (batch, length, width))
     w = jax.random.normal(keys[1], (d, k))
     b = jax.random.normal(keys[2], (d,))
-    weight = jax.random.normal(keys[3], x.shape)
+    weight = jax.random.normal(keys[3], (batch, length, d))
     loss = lambda fn: lambda *a: jnp.sum(fn(*a) * weight)  # noqa: E731
-    np.testing.assert_allclose(conv(x, w, b), _shifted_silu(x, w, b),
+    np.testing.assert_allclose(conv(x, w, b), plain(x, w, b),
                                rtol=1e-5, atol=1e-5)
     got = jax.jit(jax.grad(loss(conv), argnums=(0, 1, 2)))(x, w, b)
-    want = jax.jit(jax.grad(loss(_shifted_silu), argnums=(0, 1, 2)))(x, w, b)
+    want = jax.jit(jax.grad(loss(plain), argnums=(0, 1, 2)))(x, w, b)
     for g, r in zip(got, want):
+        assert g.shape == r.shape
         np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-4 * float(jnp.abs(r).max()))
     # each sequence on its own, and only x, w and b kept for the backward
     np.testing.assert_allclose(conv(x, w, b)[-1:], conv(x[-1:], w, b), rtol=1e-6)
-    _, residuals = short_conv._conv_silu_fwd(x, w, b, impl)
-    assert [r is a for r, a in zip(residuals, (x, w, b))] == [True] * 3
+    if window and impl == "pallas":     # the wide array itself, nothing cut
+        kept = (x, w, b)
+        _, residuals = short_conv._conv_silu_window_fwd(*kept, at)
+    else:
+        kept = (cut(x), w, b)
+        _, residuals = short_conv._conv_silu_fwd(*kept, impl)
+    assert [r is a for r, a in zip(residuals, kept)] == [True] * 3
     out = conv(x.astype(jnp.bfloat16), w, b)
-    assert out.dtype == jnp.bfloat16
+    assert out.dtype == jnp.bfloat16 and out.shape == (batch, length, d)
+    if window:      # the window's numbers are the cut-out operand's, bit for bit
+        apart = jax.jit(lambda x, w, b: short_conv.conv_silu(cut(x), w, b, impl))
+        np.testing.assert_array_equal(conv(x, w, b), apart(x, w, b))
+        for g, r in zip(got, jax.jit(jax.grad(loss(apart), argnums=(0, 1, 2)))(
+                x, w, b)):
+            np.testing.assert_array_equal(g, r)
 
 
 def test_conv_silu_refuses_what_its_kernels_cannot_take():
@@ -242,4 +359,22 @@ def test_conv_silu_refuses_what_its_kernels_cannot_take():
     with pytest.raises(ValueError, match="Unknown conv impl"):
         short_conv.conv_silu(x, w, b, impl="mosaic")
     with pytest.raises(ValueError, match=r"want \[B, L, d\]"):
-        short_conv.conv_silu(x, jnp.zeros((32, 4)), b, impl="pallas")
+        short_conv.conv_silu(x, jnp.zeros((64, 4)), b, impl="pallas")
+    # a wider x is a window: it must lie inside, and for the kernels begin
+    # on a lane tile's edge
+    wide, w, b = jnp.zeros((1, 8, 512)), jnp.zeros((128, 4)), jnp.zeros((128,))
+    with pytest.raises(ValueError, match="columns 448:576"):
+        short_conv.conv_silu(wide, w, b, at=448)
+    with pytest.raises(ValueError, match="first column 64 is not on a lane tile"):
+        short_conv.conv_silu(wide, w, b, impl="pallas", at=64)
+    assert short_conv.conv_silu(wide, w, b, at=64).shape == (1, 8, 128)
+
+
+@pytest.mark.parametrize("window,relaid", [(False, 1), (True, 0)],
+                         ids=["cut-out", "window"])
+def test_the_convolutions_gauge_counts_an_operand_handed_cut_out(window, relaid):
+    x = jax.ShapeDtypeStruct((1, 64, 512 if window else 256), jnp.bfloat16)
+    w, b = jnp.zeros((256, 4)), jnp.zeros((256,))
+    jax.eval_shape(lambda x: short_conv.conv_silu(
+        x, w, b, "pallas", at=256 if window else 0), x)
+    assert telemetry.gauge("short_conv.operands_relaid").value == relaid

@@ -18,6 +18,10 @@ step fell by 81 ms of 938; OLMoE's 3,087 with q and k transposed and 3,691
 with them handed as rows, which is why they are not) and shows WHICH
 operation pays (a float32 copy of q into the transposed layout, both
 shifted copies of a rotary turn written out). Seven seconds a layer here.
+``nemotron-mamba`` (PR 49) is that cell's Mamba-2 layer, the first that is not
+attention: 2,024 MB on its parent, 361 since, of which 201 are two
+``dynamic-update-slice`` fusions counted at their array's size that write 34
+in place (``--xla_dump_to`` names the buffers: one offset for all three).
 """
 
 import argparse
@@ -26,7 +30,8 @@ import os
 import re
 import sys
 
-CELLS = ("kanana", "nemotron", "olmoe", "trinity-sliding", "trinity-full")
+CELLS = ("kanana", "nemotron", "nemotron-mamba", "olmoe", "trinity-sliding",
+         "trinity-full")
 _BYTES = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1, "s8": 1, "u8": 1}
 _QUIET = ("parameter", "constant", "get-tuple-element", "tuple", "bitcast",
           "copy-start", "copy-done", "slice-start", "slice-done")
@@ -46,11 +51,13 @@ def layer(cell: str):
             rope_theta=1e6)
         return (nn.remat(m.LatentAttention, policy=keeping(m.KEPT))(cfg),
                 m.LatentAttention(cfg), (1, 16384, 2048))
-    if cell == "nemotron":
+    if cell.startswith("nemotron"):
         m = importlib.import_module("autodist_tpu.models.nemotron_h")
-        cfg = m.NemotronHConfig(attention_impl="flash", remat=True)
-        return (nn.remat(m.GroupedAttention, policy=keeping(m.KEPT))(cfg),
-                m.GroupedAttention(cfg), (1, 8192, 2688))
+        cfg = m.NemotronHConfig(attention_impl="flash", remat=True,
+                                ssm_impl="pallas")
+        mixer = m.Mamba2 if cell == "nemotron-mamba" else m.GroupedAttention
+        return (nn.remat(mixer, policy=keeping(m.KEPT))(cfg), mixer(cfg),
+                (1, 8192, 2688))
     if cell == "olmoe":
         m = importlib.import_module("autodist_tpu.models.olmoe")
         module = m.QKNormAttention(m.OlmoeConfig(attention_impl="flash"))
