@@ -13,14 +13,19 @@ class RMSNorm(nn.Module):
     """``x / sqrt(mean(x^2) + eps) * scale`` over the last axis, no bias and
     no mean subtraction; the arithmetic is float32 whatever comes in, the
     result is cast to ``dtype`` (float32 for a reader that must not see the
-    activation dtype's rounding, an MoE router for one)."""
+    activation dtype's rounding, an MoE router for one). ``unit_offset``:
+    the weight is ``1 + scale`` and ``scale`` starts at zero (EvaByte's
+    ``norm_add_unit_offset``), so weight decay pulls the weight to one."""
     eps: float = 1e-5
     dtype: Any = jnp.float32
+    unit_offset: bool = False
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
-                           jnp.float32)
+        init = nn.initializers.zeros if self.unit_offset else nn.initializers.ones
+        scale = self.param("scale", init, (x.shape[-1],), jnp.float32)
+        if self.unit_offset:
+            scale = 1.0 + scale
         x = x.astype(jnp.float32)
         rms = jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True)
                             + self.eps)

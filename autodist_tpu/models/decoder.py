@@ -39,7 +39,15 @@ class Decoder(nn.Module):
     * ``kept``: under ``config.remat`` every layer is a ``jax.checkpoint``
       that keeps the values of these names for its backward and makes the
       rest again (gauge ``remat.layers``; ``keeping`` books what is kept).
-      Init runs the plain layers: shapes are all it needs."""
+      Init runs the plain layers: shapes are all it needs.
+
+    What a family's config may say of the stack (absent: as the families
+    before it): ``init`` (the embedding's and the head's initializer),
+    ``norm_unit_offset`` (the final norm's weight is ``1 + scale``),
+    ``n_pred_heads`` (the untied head is that many heads of ``vocab_size``
+    side by side, head ``j`` the columns ``[vocab_size * j, vocab_size * (j
+    + 1))``, scored by :func:`make_loss_fn` against the token ``j + 1``
+    ahead) and ``logits_dtype`` (what the head's product is made in)."""
     config: Any
 
     block: ClassVar[Any] = None
@@ -65,8 +73,9 @@ class Decoder(nn.Module):
     @nn.compact
     def __call__(self, tokens, return_hidden: bool = False):
         cfg = self.config
+        init = getattr(cfg, "init", _INIT)
         embed = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=jnp.float32,
-                         param_dtype=jnp.float32, embedding_init=_INIT,
+                         param_dtype=jnp.float32, embedding_init=init,
                          name="embed")
         x = embed(tokens)
         if getattr(cfg, "mup_enabled", False):
@@ -81,13 +90,36 @@ class Decoder(nn.Module):
             if second is None:   # from zero, as each family's own sum began
                 second = jax.tree_util.tree_map(jnp.zeros_like, term)
             second = jax.tree_util.tree_map(jnp.add, second, term)
-        x = RMSNorm(cfg.rms_eps, cfg.dtype, name=self.final_norm)(x)
+        x = RMSNorm(cfg.rms_eps, cfg.dtype,
+                    getattr(cfg, "norm_unit_offset", False),
+                    name=self.final_norm)(x)
         if return_hidden:
             return x, second
         if self.tied:
             # in the sublayers' dtype, as the untied heads compute
             return x @ embed.embedding.astype(cfg.dtype).T, second
-        return _dense(cfg.vocab_size, cfg.dtype, "lm_head")(x), second
+        return _dense(cfg.vocab_size * getattr(cfg, "n_pred_heads", 1),
+                      getattr(cfg, "logits_dtype", cfg.dtype), "lm_head",
+                      init)(x), second
+
+
+def ahead_nll(logits, tokens, n_heads: int):
+    """The mean over ``n_heads`` prediction heads of each head's mean
+    cross-entropy over its own valid positions: ``logits [B, L, n_heads *
+    V]`` (head ``j`` the columns ``[V j, V (j + 1))``), ``tokens [B, L + 1]``;
+    head ``j`` at position ``t`` is scored against ``tokens[t + 1 + j]`` where
+    the batch has one, ``L - j`` positions a sequence."""
+    b, length, width = logits.shape
+    logprobs = jax.nn.log_softmax(
+        logits.astype(jnp.float32).reshape(b, length, n_heads, width // n_heads),
+        axis=-1)
+    at = jnp.arange(length)[:, None] + 1 + jnp.arange(n_heads)[None, :]
+    valid = at <= length                                         # [L, heads]
+    targets = tokens[:, jnp.minimum(at, length)]                 # [B, L, heads]
+    nll = -jnp.take_along_axis(logprobs, targets[..., None], axis=-1)[..., 0]
+    per_head = jnp.sum(jnp.where(valid, nll, 0.0), axis=(0, 1)) \
+        / (b * jnp.sum(valid, axis=0))
+    return per_head.mean()
 
 
 def make_loss_fn(model: Decoder) -> Callable:
@@ -95,12 +127,20 @@ def make_loss_fn(model: Decoder) -> Callable:
     second output (``model.loss``: the expert layers' bias terms, zero in
     value, or OLMoE's weighted router losses); batch = ``{"tokens": int32
     [B, L+1]}``. Under ``config.fused_head`` the head and the loss are one
-    kernel (``ops/fused_xent``)."""
+    kernel (``ops/fused_xent``). A family of ``n_pred_heads`` heads is
+    scored by :func:`ahead_nll` (gauge ``loss.pred_heads``)."""
     cfg = model.config
+    n_pred = getattr(cfg, "n_pred_heads", 1)
+    if n_pred > 1 and (cfg.fused_head or model.tied):
+        raise ValueError("several prediction heads need the untied XLA head")
 
     def loss_fn(params, batch):
         tokens = batch["tokens"]
         inputs, targets = tokens[:, :-1], tokens[:, 1:]
+        telemetry.gauge("loss.pred_heads").set(n_pred)
+        if n_pred > 1:
+            logits, second = model.apply({"params": params}, inputs)
+            return model.loss(ahead_nll(logits, tokens, n_pred), second)
         if cfg.fused_head:
             h, second = model.apply({"params": params}, inputs,
                                     return_hidden=True)

@@ -666,23 +666,27 @@ class RoutedFFN(nn.Module):
 _INIT = nn.initializers.normal(0.02)
 
 
-def _dense(features: int, dtype, name: str) -> nn.Dense:
+def _dense(features: int, dtype, name: str, init=_INIT) -> nn.Dense:
     return nn.Dense(features, use_bias=False, dtype=dtype,
-                    param_dtype=jnp.float32, kernel_init=_INIT, name=name)
+                    param_dtype=jnp.float32, kernel_init=init, name=name)
 
 
 class GatedMLP(nn.Module):
     """``W_down(silu(W_gate h) * W_up h)``: a dense layer's MLP, a shared
-    expert."""
+    expert. ``init``: the three matrices' initializer, where a family
+    publishes another than ``normal(0.02)``."""
     width: int
     dtype: Any
+    init: Callable = _INIT
 
     @nn.compact
     def __call__(self, h):
-        gate = checkpoint_name(_dense(self.width, self.dtype, "gate")(h),
-                               KEPT_GATE)
-        up = checkpoint_name(_dense(self.width, self.dtype, "up")(h), KEPT_UP)
-        return _dense(h.shape[-1], self.dtype, "down")(nn.silu(gate) * up)
+        gate = checkpoint_name(
+            _dense(self.width, self.dtype, "gate", self.init)(h), KEPT_GATE)
+        up = checkpoint_name(
+            _dense(self.width, self.dtype, "up", self.init)(h), KEPT_UP)
+        return _dense(h.shape[-1], self.dtype, "down", self.init)(
+            nn.silu(gate) * up)
 
 
 class PlainMLP(nn.Module):

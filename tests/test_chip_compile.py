@@ -50,6 +50,7 @@ def compile_not_interpret(monkeypatch):
     steer them to compile, here and not through an option of the program.
     A compile for a described chip can be written to the persistent cache
     but not read back without the chip, so the cache is off around it."""
+    # the kernels of the other ops modules look it up in ``fa`` at call time
     monkeypatch.setattr(fa, "_use_interpret", lambda: False)
     monkeypatch.setattr(fx, "_use_interpret", lambda: False)
     was_enabled = jax.config.jax_enable_compilation_cache
@@ -949,3 +950,30 @@ def test_fully_sharded_mimo_step_gathers_the_expert_banks_outside_the_pass_loop(
             inside.append((computation, line.strip()[:120]))
     assert inside == []
     assert _whole_all_reduces(text, 8 * 512 * 256) == []
+
+
+def test_eva_attention_compiles_at_the_evabyte_cell_shape(chip):
+    """evabyte-pretrain-16k's call, 1 x 16,384 x 8 heads held of 128, window
+    2,048, chunk 16, as the model hands it (q and k rotated ``[B, L, heads,
+    128]``, v its projection's rows reshaped): the forward with a window's K
+    and V and the (batch, head)'s 1,024 summaries resident, the one-pass
+    backward with a window's q, dO and float32 dQ resident and the
+    summaries' float32 gradients resident across the windows, both inside
+    the scoped VMEM the kernels ask for; the pooling stays XLA's."""
+    from autodist_tpu import telemetry
+    from autodist_tpu.ops import eva_attention as ea
+    b, length, h, d = 1, 16384, 8, 128
+    rows = ((b, length, h, d), jnp.bfloat16)
+    vectors = ((h, d), jnp.float32)
+
+    def loss(q, k, v, phi, mu):
+        return ea.eva_attention(q, k, v, phi, mu, window=2048,
+                                chunk=16).astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)),
+                          chip, rows, rows, rows, vectors, vectors)
+    assert "eva_fwd" in text and "eva_bwd" in text
+    assert text.count("tpu_custom_call") == 2 and "flash_fwd" not in text
+    assert ea._blocks(2048, 16) == (512, 512, 128)
+    assert telemetry.gauge("eva.windows").value == 8
+    assert telemetry.gauge("eva.summaries").value == 1024

@@ -87,7 +87,7 @@ def test_lowered_step_names_kernels_and_phases(backward, monkeypatch):
                     if k != "flash_carry"
                     and not k.startswith(("moe_", "short_conv_", "ssd_",
                                           "conv_silu_", "selective_scan_",
-                                          "flash_sink_", "gated_norm_"))
+                                          "flash_sink_", "gated_norm_", "eva_"))
                     and (k != "flash_bwd_dq" or backward == "split")]
     assert _scopes(text, named_call.KERNEL_NAMES) == set(step_kernels)
     # ZeRO's constrain_update is the reduction under AllReduce (the implicit
@@ -399,6 +399,50 @@ def test_sink_and_band_gauges_are_set_when_the_step_is_traced():
     assert telemetry.gauge("attn.band_pairs_computed").value == 32 * 32 * 32
     assert telemetry.gauge("moe.experts_held").value == 8
     assert telemetry.gauge("moe.router_width").value == 16
+
+
+@functools.lru_cache(maxsize=1)
+def _evabyte_step_text() -> str:    # one lowering for the cases below
+    from autodist_tpu.models import evabyte
+    cfg = evabyte.EvaByteConfig(
+        vocab_size=40, d_model=64, n_layers=2, n_heads=4, heads_held=2,
+        head_dim=16, d_ff=96, window=32, chunk=4, n_pred_heads=3, max_len=128,
+        dtype=jnp.float32, attention_impl="kernel", remat=True)
+    model, params = evabyte.init_params(cfg, rng=jax.random.PRNGKey(0))
+    batch = evabyte.synthetic_batch(cfg, batch_size=8, seq_len=64)
+    runner = AutoDist(strategy_builder=AllReduce()).create_distributed_session(
+        evabyte.make_loss_fn(model), params, optax.adamw(1e-3),
+        example_batch=batch)
+    state = runner.init(params)
+    with runner.mesh:
+        return runner._build_step(None).lower(
+            state, runner.shard_batch(batch)).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("name", ["eva_fwd", "eva_bwd", "eva_pool", "attn.rope"])
+def test_evabyte_step_names_its_eva_kernels_and_the_pooling_scope(name):
+    """EVA's two kernels by their own device names (``pallas:eva_fwd`` /
+    ``pallas:eva_bwd`` in a trace, apart from other cells' ``flash_*``) and
+    the pooling of the summaries as a scope of its own."""
+    text = _evabyte_step_text()
+    assert _scopes(text, [name]) == {name}
+    assert _scopes(text, named_call.KERNEL_NAMES) == {"eva_fwd", "eva_bwd"}
+
+
+def test_eva_gauges_are_set_when_the_step_is_traced():
+    _evabyte_step_text()    # traced by the cases above, or here when run alone
+    # per device: 8 sequences of 64 over 8 devices, two windows of 32
+    assert telemetry.gauge("eva.windows").value == 2
+    assert telemetry.gauge("eva.summaries").value == 16
+    assert telemetry.gauge("attention.heads_held").value == 2
+    assert telemetry.gauge("loss.pred_heads").value == 3
+    # 8 sequences x 2 heads held x 2 layers; a query sees its window's keys
+    # up to itself and window 0's 8 summaries from window 1
+    pairs = 2 * (32 * 33 // 2) + 32 * 8
+    assert telemetry.gauge("eva.pairs.visible").value == 32 * pairs
+    # the one 32 x 32 tile a window whole, and window 1's 8 x 32 of summaries
+    assert telemetry.gauge("eva.pairs.computed").value == 32 * (
+        2 * 32 * 32 + 32 * 8)
 
 
 def test_the_split_backward_of_a_sink_call_is_named_flash_sink_bwd_dq(monkeypatch):
