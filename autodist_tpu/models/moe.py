@@ -261,14 +261,27 @@ def _sorted_route(indices, weights, router_width: int, first_expert: int = 0,
     if n_held != router_width:
         local = flat - first_expert
         flat = jnp.where((local >= 0) & (local < n_held), local, n_held)
-    perm = jnp.argsort(flat, stable=True).astype(jnp.int32)
     rows = jnp.arange(n_slots, dtype=jnp.int32)
-    inv_perm = jnp.zeros_like(perm).at[perm].set(rows, unique_indices=True)
-    ends = jnp.searchsorted(flat[perm], jnp.arange(n_held, dtype=jnp.int32),
+    # the sort's own first output is ``flat[perm]``: no gather by ``perm``
+    by_expert, perm = jax.lax.sort((flat, rows), num_keys=1, is_stable=True)
+    # and the inverse is the rows sorted by ``perm``: no scatter by it
+    _, inv_perm = jax.lax.sort((perm, rows), num_keys=1)
+    ends = jnp.searchsorted(by_expert, jnp.arange(n_held, dtype=jnp.int32),
                             side="right").astype(jnp.int32)
     group_sizes = jnp.diff(ends, prepend=0)
     return Route(indices.astype(jnp.int32), weights, group_sizes, perm,
                  inv_perm)
+
+
+def _chosen(scores, indices):
+    """``scores[t, indices[t, j]]``, ``[T, E]`` by ``[T, k]``, as a select
+    against the router's width and a sum over it (one term is not zero, so
+    the sum is that term to the bit). ``take_along_axis`` is a gather of
+    ``T*k`` scalars by index and its transpose a scatter-add of as many,
+    7-10 ns a scalar on the chip; this and its transpose (the same select,
+    summed over ``k``) are one elementwise pass each."""
+    hot = indices[..., None] == jnp.arange(scores.shape[-1], dtype=indices.dtype)
+    return jnp.where(hot, scores[:, None, :], 0).sum(axis=-1)
 
 
 def topk_route(probs: jax.Array, k: int, bias=None, *, first_expert: int = 0,
@@ -281,8 +294,11 @@ def topk_route(probs: jax.Array, k: int, bias=None, *, first_expert: int = 0,
     order. This router has no ``bias``."""
     if bias is not None:
         raise ValueError("topk_route takes no bias")
-    weights, indices = jax.lax.top_k(probs, k)
-    return _sorted_route(indices, weights, probs.shape[1], first_expert, n_held)
+    # ``top_k``'s own values would do, but their transpose is a scatter-add
+    # of ``T*k`` scalars by index
+    _, indices = jax.lax.top_k(probs, k)
+    return _sorted_route(indices, _chosen(probs, indices), probs.shape[1],
+                         first_expert, n_held)
 
 
 def sigmoid_topk_route(scores: jax.Array, k: int, bias=None, *,
@@ -298,8 +314,13 @@ def sigmoid_topk_route(scores: jax.Array, k: int, bias=None, *,
     :func:`topk_route`'s."""
     choice = scores if bias is None else scores + jax.lax.stop_gradient(bias)
     _, indices = jax.lax.top_k(choice, k)
-    weights = jnp.take_along_axis(scores, indices, axis=-1)
+    weights = _chosen(scores, indices)
     if route_norm:
+        # made before their sum over ``k`` is begun: folded into the select's
+        # sum over the width, one reduction over both, the chip adds the
+        # ``k`` terms up in another order than ``take_along_axis``'s did (a
+        # last bit of the weights) and takes five times as long over it
+        weights = jax.lax.optimization_barrier(weights)
         weights = weights / (weights.sum(axis=-1, keepdims=True) + route_eps)
     return _sorted_route(indices, weights * route_scale, scores.shape[1],
                          first_expert, n_held)
@@ -584,6 +605,11 @@ def routed_experts(x, scores, gate, up, down, bias=None, *, top_k: int,
     # token's rows; its two gathers are XLA's, as all four of the whole
     # bank's are (a permutation has no rows to add up)
     telemetry.gauge("moe.rows.by_kernel").set(0 if whole else 2)
+    # scalars the routing still moves one by one by index, forward and
+    # transpose: a pass's ``take(weights, kept)`` and its scatter-add back
+    # (the chosen scores, the sorted keys and ``inv_perm`` made three more of
+    # each layer before they came from a select and from sorts)
+    telemetry.gauge("moe.route.indexed_scalar_ops").set(0 if whole else 2)
     # banks an expert has: 3 gated-SiLU (gate, up, down), 2 relu2 (up, down)
     telemetry.gauge("moe.expert_form").set(3 if gate is not None else 2)
     if whole:

@@ -551,6 +551,57 @@ def test_row_kernels_compile_at_the_share_cells_shapes(chip, tokens, rows, d,
     assert "moe_rows_gather" in text and "moe_rows_combine" in text
 
 
+def _indexed_scalar_moves(text: str, at_least: int):
+    """The ``gather`` and ``scatter`` instructions of a compiled text, inside
+    its fusions too, that move ``at_least`` scalars or more one by one (every
+    slice of one element): a gather by its result's elements, a scatter by
+    its updates'."""
+    elements = {m[1]: int(np.prod([int(d) for d in m[2].split(",") if d] or [1]))
+                for m in re.finditer(r"%([\w.-]+) = \w+\[([0-9,]*)\]", text)}
+    found = []
+    for m in re.finditer(r"%([\w.-]+) = \S+ (gather|scatter)\(([^)]*)\)(.*)", text):
+        name, op, operands, rest = m.groups()
+        if op == "gather":
+            one_by_one = set(re.search(r"slice_sizes=\{([0-9,]*)\}", rest)[1]
+                             .split(",")) == {"1"}
+            moved = elements[name]
+        else:
+            one_by_one = "update_window_dims={}" in rest
+            moved = elements[operands.split(",")[-1].strip().lstrip("%")]
+        if one_by_one and moved >= at_least:
+            found.append(m[0][:160])
+    return found
+
+
+@pytest.mark.parametrize("form,moves", [("now", 0), ("before PR 51", 3)])
+def test_a_shares_routing_at_the_lfm2_cell_shape_moves_no_scalar_by_index(
+        chip, form, moves):
+    """One expert layer's routing of lfm2-pretrain-8k (16,384 tokens, the top
+    4 of 64 sigmoid scores under a bias, 8 experts held), forward and
+    gradient, in scope ``moe.route``: the optimized text for the described
+    v5e holds no gather or scatter of the 65,536 routed choices one scalar at
+    a time. The forms it had before PR 51 (``take_along_axis`` and its
+    transpose, ``flat[perm]``: 6.3 ms of the cell's step, PERF.md section 6)
+    hold three, which shows the search finds them."""
+    from autodist_tpu.models import moe
+    from tests.test_moe_route import route_before
+    tokens, width, top_k = 16_384, 64, 4
+    route = moe.sigmoid_topk_route if form == "now" else route_before
+
+    def loss(scores, bias, ct):
+        with jax.named_scope("moe.route"):
+            r = route(scores, top_k, bias, n_held=8, route_eps=1e-6)
+        # what ``routed_experts`` reads of a share's routing
+        return jnp.sum(r.weights * ct), (r.indices, r.group_sizes, r.perm)
+
+    text = _compiled_text(jax.value_and_grad(loss, has_aux=True), chip,
+                          ((tokens, width), jnp.float32), ((width,), jnp.float32),
+                          ((tokens, top_k), jnp.float32))
+    assert "moe.route" in text
+    found = _indexed_scalar_moves(text, tokens * top_k)
+    assert len(found) == moves, found
+
+
 def test_flash_grouped_heads_of_64_compile_at_the_lfm2_cell_shape(chip):
     """lfm2-pretrain-8k's call: 2 x 8,192 x 32 query heads over 8 KV heads of
     64, causal, no window. K of a head is 1 MiB, the most the forward kept

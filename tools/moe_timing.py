@@ -12,6 +12,7 @@ name); one JSON line a measurement on standard output.
     python tools/moe_timing.py --phases gmm --tiles 256,512 --tiles 512,512
     python tools/moe_timing.py --phases flash,xent,flips
     python tools/moe_timing.py --phases rows            # ~2 min
+    python tools/moe_timing.py --phases route           # ~5 min
 
 Phases: ``gmm`` (forward, and dX + dW, at the gate/up and the down
 shape: 131,072 rows in 64 groups as a top-8 of random logits sorts them, against
@@ -35,7 +36,17 @@ bring, at both share cells' shapes: T 16,384 / R 16,384 / top-4 of 64 and T
 8,192 / R 8,192 / top-8 of 128, 8 experts held, d 2,048, the held rows those
 of a real top-k of random scores; ``plan`` is the sort by token the two
 combines of a pass share; ``ns_a_held_row`` is the busy time over the held
-rows).
+rows), ``route`` (not in a whole run; the routing alone, ``models/moe.py``
+``sigmoid_topk_route`` / ``topk_route`` as they stand beside the form they had
+before PR 51 (``take_along_axis`` or ``top_k``'s own values for the chosen
+scores, ``argsort`` and then ``flat[perm]`` for the sorted keys, a scatter for
+``inv_perm``), forward and forward + gradient with respect to the scores, the
+two compared value for value, at the shapes of ``lfm2-pretrain-8k``,
+``trinity-pretrain-8k``, ``olmoe-pretrain-4k`` (the whole bank) and one chip
+of ``mimo-sharded4-8k``; and, as lines of their own, ``inv_perm`` as a
+scatter and as a second sort by key (the whole bank's: a share reads none),
+and a pass's ``take(weights, kept)`` beside the weights sorted by key with a
+sort for its transpose (not taken: it loses under 8,192 rows a pass)).
 Needs the TPU: a time from the CPU's interpreter says nothing.
 """
 
@@ -431,11 +442,167 @@ def phase_rows(calls, _tiles):
                    "max_abs_kernel_minus_xla": worst}
 
 
+# (cell, tokens, router width, top_k, experts held (None: the whole bank,
+# ``topk_route``), rows a pass)
+ROUTE_SHAPES = (("lfm2-pretrain-8k", 16384, 64, 4, 8, 16384),
+                ("trinity-pretrain-8k", 8192, 128, 8, 8, 8192),
+                ("olmoe-pretrain-4k", 16384, 64, 8, None, 131072),
+                ("mimo-sharded4-8k", 8192, 256, 8, 8, 4096))
+
+
+def route_before(scores, k, bias, n_held, chosen="take"):
+    """The routing as it stood before PR 51, its by-index forms kept:
+    ``take_along_axis`` for the chosen scores (``chosen="take"``;
+    ``"top_k"``: ``top_k``'s own values, as ``topk_route`` had them),
+    ``argsort`` followed by ``flat[perm]``, and ``inv_perm`` by a scatter.
+    Returns ``Route``'s fields."""
+    import jax
+    import jax.numpy as jnp
+    choice = scores if bias is None else scores + jax.lax.stop_gradient(bias)
+    weights, indices = jax.lax.top_k(choice, k)
+    if chosen == "take":
+        weights = jnp.take_along_axis(scores, indices, axis=-1)
+        weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-20)
+    flat = indices.reshape(-1).astype(jnp.int32)
+    width = scores.shape[1]
+    n_held = width if n_held is None else n_held
+    if n_held != width:
+        flat = jnp.where(flat < n_held, flat, n_held)
+    perm = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    rows = jnp.arange(flat.size, dtype=jnp.int32)
+    inv_perm = jnp.zeros_like(perm).at[perm].set(rows, unique_indices=True)
+    ends = jnp.searchsorted(flat[perm], jnp.arange(n_held, dtype=jnp.int32),
+                            side="right").astype(jnp.int32)
+    return indices, weights, jnp.diff(ends, prepend=0), perm, inv_perm
+
+
+def phase_route(calls, _tiles):
+    """The routing of one expert layer, forward and forward + gradient, as it
+    stood and as it stands, and two by-index scalar operations alone beside
+    the sorts that replace them (``inv_perm``: taken; a pass's weights: not)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from autodist_tpu import telemetry
+    from autodist_tpu.models import moe
+
+    def sort_by(keys, values):
+        return jax.lax.sort((keys, values), num_keys=1)[1]
+
+    @jax.custom_vjp
+    def sorted_weights(weights, perm, inv_perm):
+        """``weights[perm]`` with no gather, and no scatter in its transpose."""
+        return sort_by(inv_perm, weights)
+
+    sorted_weights.defvjp(
+        lambda weights, perm, inv_perm: (sort_by(inv_perm, weights), perm),
+        lambda perm, g: (sort_by(perm, g), None, None))
+
+    for cell, n_tokens, width, top_k, n_held, bound in ROUTE_SHAPES:
+        whole = n_held is None
+        keys = jax.random.split(jax.random.PRNGKey(0), 4)
+        logits = jax.random.normal(keys[0], (n_tokens, width))
+        scores = jax.nn.softmax(logits) if whole else jax.nn.sigmoid(logits)
+        bias = None if whole else 0.05 * jax.random.normal(keys[1], (width,))
+        n_slots = n_tokens * top_k
+        ct = jax.random.normal(keys[2], (n_tokens, top_k))
+        yield {"phase": "route", "cell": cell, "tokens": n_tokens,
+               "router_width": width, "top_k": top_k, "choices": n_slots,
+               "experts_held": width if whole else n_held}
+
+        def now(scores, bias):
+            if whole:
+                return moe.topk_route(scores, top_k)
+            return moe.sigmoid_topk_route(scores, top_k, bias, n_held=n_held)
+
+        def before(scores, bias):
+            return route_before(scores, top_k, bias, n_held,
+                                "top_k" if whole else "take")
+
+        forms = {"before": before, "now": now}
+
+        def used(route):
+            # what the step reads of a routing: a share never reads inv_perm
+            return tuple(route)[:None if whole else 4]
+
+        results = {}
+        for impl, form in forms.items():
+            fwd = jax.jit(lambda s, b, form=form: used(form(s, b)))
+
+            def loss(s, b, form=form):
+                r = used(form(s, b))
+                return jnp.sum(r[1] * ct), (r[0],) + r[2:]
+            both = jax.jit(jax.grad(loss, has_aux=True))
+            for what, fn in (("fwd", fwd), ("fwd + grad", both)):
+                results[what, impl] = jax.device_get(fn(scores, bias))
+                busy, groups = device_ms(fn, (scores, bias), calls)
+                yield {"phase": "route", "cell": cell, "what": what,
+                       "impl": impl, "busy_ms": busy, "groups_ms": groups}
+
+        r = jax.jit(now)(scores, bias)
+        rows = jnp.arange(n_slots, dtype=jnp.int32)
+        candidates = [
+            ("inv_perm", "scatter", lambda perm: jnp.zeros_like(perm).at[perm].set(
+                rows, unique_indices=True), (r.perm,)),
+            ("inv_perm", "sort", lambda perm: sort_by(perm, rows), (r.perm,))]
+        if not whole:
+            count = jnp.minimum(r.group_sizes.sum(), bound).astype(jnp.int32)
+            flat_weights = r.weights.reshape(-1)
+            g = jax.random.normal(keys[3], (bound,))
+
+            def masked(weight):
+                return jnp.where(jnp.arange(bound) < count, weight, 0.0)
+
+            def taken(weights, perm):
+                return masked(jnp.take(weights, perm[:bound]))
+
+            def by_sort(weights, perm):
+                return masked(sorted_weights(
+                    weights, perm, sort_by(perm, rows))[:bound])
+
+            for impl, fn in (("take", taken), ("sorts", by_sort)):
+                candidates += [
+                    ("a pass's weights, fwd", impl, fn, (flat_weights, r.perm)),
+                    ("a pass's weights, fwd + grad", impl,
+                     jax.value_and_grad(lambda w, p, fn=fn: jnp.sum(fn(w, p) * g)),
+                     (flat_weights, r.perm))]
+        for what, impl, fn, args in candidates:
+            fn = jax.jit(fn)
+            results[what, impl] = jax.device_get(fn(*args))
+            busy, groups = device_ms(fn, args, calls)
+            yield {"phase": "route", "cell": cell, "what": what, "impl": impl,
+                   "busy_ms": busy, "groups_ms": groups}
+        # the gauges a step's trace of this layer sets (shapes only: nothing runs)
+        bank = [jax.ShapeDtypeStruct(shape, jnp.bfloat16) for shape in (
+            (width if whole else n_held, D_MODEL, D_EXPERT),) * 2
+            + ((width if whole else n_held, D_EXPERT, D_MODEL),)]
+        jax.eval_shape(
+            lambda x, s, *b: moe.routed_experts(
+                x, s, *b, bias, top_k=top_k,
+                route=moe.topk_route if whole else moe.sigmoid_topk_route,
+                rows_bound=None if whole else bound),
+            jax.ShapeDtypeStruct((n_tokens, D_MODEL), jnp.bfloat16), scores, *bank)
+        print(cell, {k: v for k, v in telemetry.snapshot().items()
+                     if k.startswith("moe.")}, file=sys.stderr)
+        # every form of one thing against its first, value for value, on the
+        # chip: equal to the bit unless the compiler adds a float32 sum up in
+        # another order (the weights' normaliser is a sum of top_k terms)
+        for what in dict.fromkeys(w for w, _ in results):
+            (_, first), *others = [(impl, jax.tree_util.tree_leaves(v))
+                                   for (w, impl), v in results.items() if w == what]
+            yield {"phase": "route", "cell": cell, "what": what,
+                   "max_abs_difference_from_the_first_form": {
+                       impl: max(float(np.max(np.abs(a.astype(np.float64) - b)))
+                                 for a, b in zip(first, other))
+                       for impl, other in others}}
+
+
 PHASES = {"gmmcheck": phase_gmmcheck, "gradcheck": phase_gradcheck,
           "gmm": phase_gmm, "flash": phase_flash, "xent": phase_xent,
           "flips": phase_flips, "rows": phase_rows}
 # not in a whole run (PERF.md's 12 minutes are the phases above)
-EXTRA_PHASES = {"gmmshare": phase_gmmshare}
+EXTRA_PHASES = {"gmmshare": phase_gmmshare, "route": phase_route}
 
 
 def main(argv=None):
