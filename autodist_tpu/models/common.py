@@ -95,6 +95,14 @@ def rope_pairs(x, positions, theta: float = 10000.0,
     return (x32 * cos + partner * sin).astype(x.dtype)
 
 
+def head_columns(heads: int, d: int, dtype=jnp.float32):
+    """``[heads, heads d]`` of 0 / 1: row ``h`` marks head ``h``'s ``d``
+    columns of ``[..., heads d]`` rows. A product with it sums a head's
+    columns or spreads a head's value over them without laying the rows out
+    as ``[..., heads, d]`` (on TPU other bytes: PERF.md section 6, "PR 41")."""
+    return jnp.repeat(jnp.eye(heads, dtype=dtype), d, axis=1)
+
+
 def keeping(names):
     """The ``jax.checkpoint`` policy that keeps the values named in ``names``
     and nothing else, and books what it keeps as it decides (when a

@@ -85,7 +85,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from autodist_tpu import telemetry
-from autodist_tpu.models.common import RMSNorm, rope_pairs
+from autodist_tpu.models.common import RMSNorm, head_columns, rope_pairs
 from autodist_tpu.models.decoder import Decoder, init_params, make_loss_fn  # noqa: F401
 from autodist_tpu.models.moe import (  # noqa: F401 — the mixture's, under this family's names
     KEPT_GATE, KEPT_PASS, KEPT_ROUTER_LOGITS, KEPT_UP, GatedMLP, RoutedShare,
@@ -152,15 +152,30 @@ class DeepseekV3Config:
 class LatentAttention(nn.Module):
     """Causal multi-head latent attention: the keys' position-free part and
     the values through a normed ``kv_lora_rank``-wide latent, one rotary key
-    head for all query heads, no bias, no gate."""
-    config: DeepseekV3Config
+    head for all query heads, no bias. ``config`` is a family's: the widths,
+    ``n_heads``, ``rope_theta``, ``rms_eps``, ``dtype``, ``attention_impl``,
+    ``remat`` (``models/bailing_hybrid.py`` hands its own).
+
+    ``heads_held`` (None: the layer's ``n_heads``): one chip's share of the
+    heads. ``query``, ``kv_up`` and ``out`` are the held heads' columns and
+    rows; the latent, its norm and the rotary key are every chip's alike; what
+    the other heads would add to the output projection's sum is left out
+    (gauge ``attention.heads_held``). ``head_gate``: each head's output is
+    multiplied by ``sigmoid(h.W_gate)`` of its own, ``W_gate [d, heads]``,
+    before the output projection (a head-wise output gate)."""
+    config: Any
+    heads_held: Optional[int] = None
+    head_gate: bool = False
 
     @nn.compact
     def __call__(self, h):
         cfg = self.config
         b, length, _ = h.shape
-        heads, d_n, d_r, d_v = (cfg.n_heads, cfg.qk_nope_head_dim,
-                                cfg.qk_rope_head_dim, cfg.v_head_dim)
+        heads, d_n, d_r, d_v = (self.heads_held or cfg.n_heads,
+                                cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                                cfg.v_head_dim)
+        if self.heads_held is not None:
+            telemetry.gauge("attention.heads_held").set(heads)
         kept = []       # bytes of what the layer's checkpoint keeps of this
 
         def name(x, what):
@@ -200,6 +215,13 @@ class LatentAttention(nn.Module):
                                         causal_mask(length, cfg.dtype), cfg.dtype)
         telemetry.gauge("mla.kept_bytes_per_token").set(
             sum(kept) // (b * length) if cfg.remat else 0)
+        if self.head_gate:
+            with jax.named_scope("mla.head_gate"):
+                # a head's gate over its d_v columns as a product with 0 / 1
+                # columns: the rows stay the rows flash wrote
+                gate = jax.nn.sigmoid(_dense(heads, cfg.dtype, "gate")(h))
+                ctx = ctx.reshape(b, length, heads * d_v) * (
+                    gate @ head_columns(heads, d_v, cfg.dtype))
         with jax.named_scope("mla.out_proj"):
             return _dense(cfg.d_model, cfg.dtype, "out")(
                 ctx.reshape(b, length, heads * d_v))

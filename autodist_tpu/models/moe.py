@@ -301,18 +301,47 @@ def topk_route(probs: jax.Array, k: int, bias=None, *, first_expert: int = 0,
                          first_expert, n_held)
 
 
+def group_limited(choice: jax.Array, n_group: int, topk_group: int):
+    """``choice [T, E]`` with the experts outside each token's ``topk_group``
+    best groups at ``-inf``: the experts lie in ``n_group`` equal groups in
+    their order, a group's score is the sum of its two largest entries, and
+    the ``topk_group`` groups that score highest stay (DeepSeek-V3's
+    ``noaux_tc``; ties go to the lower group, as ``top_k``'s do). The groups
+    kept are marked by a select against the ``n_group`` group numbers, not
+    gathered or scattered by index (gauges ``moe.route.groups``,
+    ``moe.route.groups_kept``)."""
+    from autodist_tpu import telemetry
+    tokens, width = choice.shape
+    if width % n_group or not 1 <= topk_group <= n_group:
+        raise ValueError(f"{width} experts are not {n_group} equal groups of "
+                         f"which {topk_group} stay")
+    telemetry.gauge("moe.route.groups").set(n_group)
+    telemetry.gauge("moe.route.groups_kept").set(topk_group)
+    grouped = choice.reshape(tokens, n_group, width // n_group)
+    best_two, _ = jax.lax.top_k(grouped, min(2, width // n_group))
+    _, kept = jax.lax.top_k(best_two.sum(axis=-1), topk_group)     # [T, kept]
+    stays = (kept[..., None] == jnp.arange(n_group, dtype=kept.dtype)
+             ).any(axis=1)                                         # [T, n_group]
+    return jnp.where(stays[..., None], grouped, -jnp.inf).reshape(tokens, width)
+
+
 def sigmoid_topk_route(scores: jax.Array, k: int, bias=None, *,
                        route_norm: bool = True, route_scale: float = 1.0,
                        route_eps: float = 1e-20, first_expert: int = 0,
-                       n_held: Optional[int] = None) -> Route:
+                       n_held: Optional[int] = None, n_group: int = 1,
+                       topk_group: int = 1) -> Route:
     """The router of the sigmoid-scored mixtures: ``scores = sigmoid(h.Wr)``
     in float32, the ``k`` experts with the largest ``scores + bias`` are
-    chosen, and their weights are the scores themselves, without the bias
-    (which steers the load and takes no gradient), divided by their sum over
-    the chosen (+ ``route_eps``: AFMoE's 1e-20, LFM2's 1e-6) under
-    ``route_norm`` and multiplied by ``route_scale``. The sort is
+    chosen (under ``n_group > 1`` among the ``topk_group`` best groups only:
+    :func:`group_limited`), and their weights are the scores themselves,
+    without the bias (which steers the load and takes no gradient), divided
+    by their sum over the chosen (+ ``route_eps``: AFMoE's 1e-20, LFM2's
+    1e-6) under ``route_norm`` and multiplied by ``route_scale``. The sort is
     :func:`topk_route`'s."""
     choice = scores if bias is None else scores + jax.lax.stop_gradient(bias)
+    if n_group > 1:
+        choice = group_limited(jax.lax.stop_gradient(choice), n_group,
+                               topk_group)
     _, indices = jax.lax.top_k(choice, k)
     weights = _chosen(scores, indices)
     if route_norm:
@@ -786,7 +815,8 @@ class RoutedShare(nn.Module):
                     precision=jax.lax.Precision.HIGHEST), KEPT_ROUTER_LOGITS))
         route = functools.partial(sigmoid_topk_route, **{
             name: getattr(cfg, name) for name in (
-                "route_norm", "route_scale", "route_eps") if hasattr(cfg, name)})
+                "route_norm", "route_scale", "route_eps", "n_group",
+                "topk_group") if hasattr(cfg, name)})
         share = functools.partial(routed_experts, top_k=cfg.top_k, route=route,
                                   first_expert=cfg.first_expert_held,
                                   rows_bound=cfg.rows_bound, form=form)
@@ -806,7 +836,10 @@ class RoutedShare(nn.Module):
         # The load every expert of the router's width received, absent ones
         # too: the choice is made here for all of them. (The same top_k as the
         # route's; the compiler keeps one.)
-        _, chosen = jax.lax.top_k(jax.lax.stop_gradient(scores + bias), cfg.top_k)
+        choice = jax.lax.stop_gradient(scores + bias)
+        if getattr(cfg, "n_group", 1) > 1:
+            choice = group_limited(choice, cfg.n_group, cfg.topk_group)
+        _, chosen = jax.lax.top_k(choice, cfg.top_k)
         load = jnp.sum(chosen[..., None] == jnp.arange(router_width), axis=(0, 1),
                        dtype=jnp.float32)
         bias_term = jnp.sum((bias - jax.lax.stop_gradient(bias))

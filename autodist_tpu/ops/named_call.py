@@ -25,7 +25,8 @@ KERNEL_NAMES = ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq", "flash_carry",
                 "moe_rows_combine", "ssd_fwd", "ssd_bwd", "conv_silu_fwd",
                 "conv_silu_bwd", "selective_scan_fwd", "selective_scan_bwd",
                 "flash_sink_fwd", "flash_sink_bwd_dkv", "flash_sink_bwd_dq",
-                "gated_norm_fwd", "gated_norm_bwd", "eva_fwd", "eva_bwd")
+                "gated_norm_fwd", "gated_norm_bwd", "eva_fwd", "eva_bwd",
+                "kda_fwd", "kda_bwd")
 
 
 def named_pallas_call(name: str, kernel, **kwargs):

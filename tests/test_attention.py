@@ -478,7 +478,8 @@ def test_kernel_names_unchanged():
         "ssd_fwd", "ssd_bwd", "conv_silu_fwd", "conv_silu_bwd",
         "selective_scan_fwd", "selective_scan_bwd",
         "flash_sink_fwd", "flash_sink_bwd_dkv", "flash_sink_bwd_dq",
-        "gated_norm_fwd", "gated_norm_bwd", "eva_fwd", "eva_bwd")
+        "gated_norm_fwd", "gated_norm_bwd", "eva_fwd", "eva_bwd",
+        "kda_fwd", "kda_bwd")
 
 
 @_NEEDS_MESH

@@ -1028,3 +1028,82 @@ def test_eva_attention_compiles_at_the_evabyte_cell_shape(chip):
     assert ea._blocks(2048, 16) == (512, 512, 128)
     assert telemetry.gauge("eva.windows").value == 8
     assert telemetry.gauge("eva.summaries").value == 1024
+
+
+@pytest.mark.parametrize("mixer", ["kda", "mla"])
+@pytest.mark.parametrize("heads", [16, 8], ids=["16-heads", "8-heads-fallback"])
+def test_ling_mixers_compile_at_the_ling_cell_shape(chip, heads, mixer):
+    """ling-pretrain-8k's two mixers as the model runs them, 1 x 8,192 x 2,560
+    with 16 of a layer's 32 heads held (and the fallback's 8), forward and
+    backward: a KDA layer is ``kda_fwd`` / ``kda_bwd`` over ``[1, 8192, heads x
+    128]`` rows (128 chunks of 64 a head, the head's float32 state in VMEM)
+    behind three ``conv_silu`` calls at ``heads x 128`` channels without a
+    bias; the gated latent layer is flash at keys 192 over values 128 with the
+    shared rotary key. What XLA keeps between the projections and the kernels
+    lays no ``[1, 8192, heads, 128]`` array out: the rows stay rows."""
+    import flax.linen as nn
+
+    from autodist_tpu import telemetry
+    from autodist_tpu.models import bailing_hybrid as bh
+    from autodist_tpu.models.deepseek_v3 import LatentAttention
+    cfg = bh.BailingHybridConfig(
+        n_layers=6, heads_held=heads, attention_impl="flash", kda_impl="pallas")
+    module: nn.Module = (bh.KimiDeltaAttention(cfg) if mixer == "kda" else
+                         LatentAttention(cfg, heads_held=heads, head_gate=True))
+    h = jax.ShapeDtypeStruct((1, 8, cfg.d_model), jnp.bfloat16)
+    shapes = jax.eval_shape(lambda x: module.init(jax.random.PRNGKey(0), x), h)
+
+    def loss(params, h):
+        return module.apply(params, h).astype(jnp.float32).sum()
+
+    leaves, tree = jax.tree_util.tree_flatten(shapes)
+    text = _compiled_text(
+        lambda *args: jax.value_and_grad(loss, argnums=(0, 1))(
+            jax.tree_util.tree_unflatten(tree, args[:-1]), args[-1]),
+        chip, *((x.shape, x.dtype) for x in leaves),
+        ((1, 8192, cfg.d_model), jnp.bfloat16))
+    if mixer == "kda":
+        assert "kda_fwd" in text and "kda_bwd" in text
+        assert "conv_silu_fwd" in text and "conv_silu_bwd" in text
+        assert text.count("tpu_custom_call") == 8       # 3 + 1 forward, 3 + 1 backward
+        assert telemetry.gauge("kda.chunks").value == 128
+        assert telemetry.gauge("kda.heads_held").value == heads
+        assert telemetry.gauge("kda.state_kept_bytes").value == \
+            128 * heads * 128 * 128 * 4
+        assert not re.search(rf"\[1,8192,{heads},128\]", text)
+    else:
+        assert "flash_fwd" in text and "flash_bwd_dkv" in text
+        assert telemetry.gauge("attention.heads_held").value == heads
+
+
+def test_ling_kda_mixer_compiles_to_float32s_precision(chip):
+    """The first layers' second forward (``models/bailing_hybrid.py``
+    ``PRECISE_LAYERS``): the KDA mixer on float32 rows, ``kda_fwd`` and the
+    three convolutions on float32 operands, every product of the kernel at
+    full precision; no backward (the derivative is the ordinary layer's)."""
+    from autodist_tpu.models import bailing_hybrid as bh
+    cfg = bh.BailingHybridConfig(n_layers=6, heads_held=16, kda_impl="pallas")
+    module = bh.KimiDeltaAttention(cfg)
+    shapes = jax.eval_shape(
+        lambda x: module.init(jax.random.PRNGKey(0), x),
+        jax.ShapeDtypeStruct((1, 8, cfg.d_model), jnp.float32))
+    leaves, tree = jax.tree_util.tree_flatten(shapes)
+    text = _compiled_text(
+        lambda *args: module.apply(
+            jax.tree_util.tree_unflatten(tree, args[:-1]), args[-1], True),
+        chip, *((x.shape, x.dtype) for x in leaves),
+        ((1, 8192, cfg.d_model), jnp.float32))
+    assert "kda_fwd" in text and "kda_bwd" not in text
+    assert text.count("tpu_custom_call") == 4
+
+
+def test_fused_xent_compiles_at_the_ling_cell_shape(chip):
+    """ling-pretrain-8k's sliced head: 8,192 rows of 2,560 against 19,648
+    vocabulary rows, the widest hidden size the fused head has been given."""
+    def loss(h, w, targets):
+        return fx.fused_softmax_xent(h, w, targets).mean()
+
+    text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1)), chip,
+                          ((8192, 2560), jnp.bfloat16),
+                          ((2560, 19_648), jnp.float32), ((8192,), jnp.int32))
+    assert "xent_fwd" in text and "xent_bwd_dw" in text

@@ -87,7 +87,8 @@ def test_lowered_step_names_kernels_and_phases(backward, monkeypatch):
                     if k != "flash_carry"
                     and not k.startswith(("moe_", "short_conv_", "ssd_",
                                           "conv_silu_", "selective_scan_",
-                                          "flash_sink_", "gated_norm_", "eva_"))
+                                          "flash_sink_", "gated_norm_", "eva_",
+                                          "kda_"))
                     and (k != "flash_bwd_dq" or backward == "split")]
     assert _scopes(text, named_call.KERNEL_NAMES) == set(step_kernels)
     # ZeRO's constrain_update is the reduction under AllReduce (the implicit
@@ -443,6 +444,65 @@ def test_eva_gauges_are_set_when_the_step_is_traced():
     # the one 32 x 32 tile a window whole, and window 1's 8 x 32 of summaries
     assert telemetry.gauge("eva.pairs.computed").value == 32 * (
         2 * 32 * 32 + 32 * 8)
+
+
+@functools.lru_cache(maxsize=1)
+def _ling_step_text() -> str:       # one lowering for the cases below
+    """A tiny Ling / Ring hybrid step with every kernel option: a KDA layer
+    (heads of 128, the kernels' width) and a gated latent layer, one of two
+    heads held, a grouped router over a share."""
+    from autodist_tpu.models import bailing_hybrid
+    cfg = bailing_hybrid.BailingHybridConfig(
+        vocab_size=64, d_model=32, n_layers=2, layer_group_size=2, n_heads=2,
+        heads_held=1, first_head_held=1, head_dim=128, qk_nope_head_dim=8,
+        qk_rope_head_dim=4, v_head_dim=8, kv_lora_rank=12, n_dense_layers=1,
+        d_ff=48, d_expert=16, d_shared=16, n_experts_routed=8, experts_held=2,
+        top_k=2, n_group=4, topk_group=2, rows_bound=32, max_len=128,
+        dtype=jnp.float32, attention_impl="flash", kda_impl="pallas",
+        fused_head=True, remat=True)
+    model, params = bailing_hybrid.init_params(cfg, rng=jax.random.PRNGKey(0))
+    batch = bailing_hybrid.synthetic_batch(cfg, batch_size=8, seq_len=64)
+    runner = AutoDist(strategy_builder=AllReduce()).create_distributed_session(
+        bailing_hybrid.make_loss_fn(model), params,
+        bailing_hybrid.make_optimizer(1e-3, cfg.load_balance_coeff),
+        example_batch=batch)
+    state = runner.init(params)
+    with runner.mesh:
+        return runner._build_step(None).lower(
+            state, runner.shard_batch(batch)).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("name", ["kda_fwd", "kda_bwd", "conv_silu_fwd",
+                                  "conv_silu_bwd", "kda_qk_norm", "kda_gate",
+                                  "kda_out_norm", "mla.head_gate", "moe.route"])
+def test_ling_step_names_its_kda_kernels_and_scopes(name):
+    """The recurrence's two kernels by their own device names
+    (``pallas:kda_fwd`` / ``pallas:kda_bwd`` in a trace) and what XLA keeps of
+    the mixer between the projections and the kernels under scopes of its
+    own: the two L2 norms, the decay and beta, the output norm under its
+    gate."""
+    text = _ling_step_text()
+    assert _scopes(text, [name]) == {name}
+    assert _scopes(text, named_call.KERNEL_NAMES) >= {
+        "kda_fwd", "kda_bwd", "conv_silu_fwd", "conv_silu_bwd", "flash_fwd",
+        "flash_bwd_dkv", "xent_fwd", "moe_gmm_fwd"}
+    assert not _scopes(text, ["ssd_fwd", "eva_fwd", "gated_norm_fwd"])
+
+
+def test_kda_gauges_are_set_when_the_step_is_traced():
+    _ling_step_text()       # traced by the cases above, or here when run alone
+    # of the call, all devices: 8 sequences of 64, one chunk of 64 each
+    assert telemetry.gauge("kda.chunks").value == 8
+    assert telemetry.gauge("kda.chunk").value == 64
+    assert telemetry.gauge("kda.heads_held").value == 1
+    assert telemetry.gauge("kda.state_kept_bytes").value == 8 * 128 * 128 * 4
+    assert telemetry.gauge("attention.heads_held").value == 1
+    assert telemetry.gauge("moe.route.groups").value == 4
+    assert telemetry.gauge("moe.route.groups_kept").value == 2
+    assert telemetry.gauge("moe.experts_held").value == 2
+    assert telemetry.gauge("moe.router_width").value == 8
+    assert telemetry.gauge("remat.layers").value == 2
+    assert telemetry.gauge("remat.kept_bytes").value > 0
 
 
 def test_the_split_backward_of_a_sink_call_is_named_flash_sink_bwd_dq(monkeypatch):

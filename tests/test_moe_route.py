@@ -22,6 +22,7 @@ from jax.interpreters import partial_eval as pe
 
 from autodist_tpu import telemetry
 from autodist_tpu.models import moe
+from benchmark.reference import bailing_hybrid as reference
 
 TOKENS, WIDTH, TOP_K = 96, 16, 4
 SHARE = dict(first_expert=4, n_held=4)
@@ -203,3 +204,101 @@ def test_the_gauge_counts_the_scalar_moves_the_layers_gradient_still_makes(whole
                                     argnums=tuple(range(len(args)))), *args)
     found = [e.primitive.name for e in indexed_scalar_ops(live, TOKENS * TOP_K)]
     assert telemetry.gauge("moe.route.indexed_scalar_ops").value == len(found), found
+
+
+# ------------------------------------------------------ the grouped choice
+#
+# ``group_limited`` / ``sigmoid_topk_route(n_group, topk_group)`` (PR 52):
+# against plain ``top_k`` calls (``benchmark/reference/bailing_hybrid.py``
+# ``grouped_choice``), ties included; one group is the choice as it was; the
+# weights carry no bias; one chip's share sorts the held rows first.
+
+def _grouped_scores(tokens=96, width=32, seed=0, levels=None):
+    scores = jax.nn.sigmoid(jax.random.normal(jax.random.PRNGKey(seed),
+                                              (tokens, width)))
+    if levels:      # few distinct values: ties inside groups and between them
+        scores = jnp.round(scores * levels) / levels
+    bias = 0.1 * jax.random.normal(jax.random.PRNGKey(seed + 1), (width,))
+    return scores, (jnp.round(bias * 8) / 8 if levels else bias)
+
+
+def _chosen_mask(route, width):
+    return np.asarray(jax.nn.one_hot(route.indices, width).sum(axis=1) > 0)
+
+
+@pytest.mark.parametrize("n_group,topk_group,top_k,levels", [
+    (4, 2, 4, None), (8, 4, 8, None), (8, 1, 3, None), (4, 4, 6, None),
+    (4, 2, 4, 4), (8, 4, 8, 3)],
+    ids=["4-2-4", "8-4-8", "one-group-kept", "all-groups-kept", "ties-4",
+         "ties-3"])
+def test_the_grouped_choice_is_the_plain_top_k_calls(n_group, topk_group, top_k,
+                                                     levels):
+    scores, bias = _grouped_scores(levels=levels)
+    route = moe.sigmoid_topk_route(scores, top_k, bias, n_group=n_group,
+                                   topk_group=topk_group, route_scale=2.5)
+    want = np.asarray(reference.grouped_choice(scores + bias, top_k, n_group,
+                                               topk_group))
+    np.testing.assert_array_equal(_chosen_mask(route, 32), want)
+    assert want.sum(axis=1).tolist() == [top_k] * 96
+    # every chosen expert lies in one of topk_group groups
+    groups = np.asarray(route.indices) // (32 // n_group)
+    assert max(len(set(row)) for row in groups) <= topk_group
+    # the weights are the scores without the bias, normalised and scaled
+    picked = np.take_along_axis(np.asarray(scores), np.asarray(route.indices), 1)
+    np.testing.assert_allclose(
+        route.weights, 2.5 * picked / (picked.sum(axis=1, keepdims=True) + 1e-20),
+        rtol=1e-6)
+    assert telemetry.gauge("moe.route.groups").value == n_group
+    assert telemetry.gauge("moe.route.groups_kept").value == topk_group
+
+
+def test_one_group_is_the_choice_as_it_was():
+    scores, bias = _grouped_scores()
+    plain = moe.sigmoid_topk_route(scores, 4, bias)
+    for same in (moe.sigmoid_topk_route(scores, 4, bias, n_group=1, topk_group=1),
+                 moe.sigmoid_topk_route(scores, 4, bias, n_group=4, topk_group=4)):
+        for a, b in zip(plain, same):
+            np.testing.assert_array_equal(a, b)
+    text = lambda **groups: jax.jit(  # noqa: E731
+        lambda s, b: moe.sigmoid_topk_route(s, 4, b, **groups)).lower(
+            scores, bias).as_text()
+    assert text() == text(n_group=1, topk_group=1)
+    assert text() != text(n_group=4, topk_group=2)
+
+
+def test_a_group_scores_by_its_two_best_and_ties_go_to_the_lower_group():
+    # groups of 4: group 1 has the single best expert, group 2 the best pair
+    choice = jnp.asarray([[0.1, 0.1, 0.1, 0.1, 0.9, 0.0, 0.0, 0.0,
+                           0.6, 0.6, 0.0, 0.0, 0.3, 0.3, 0.3, 0.3]])
+    kept = moe.group_limited(choice, 4, 1)
+    assert np.isfinite(np.asarray(kept))[0].tolist() == [False] * 8 + [True] * 4 \
+        + [False] * 4
+    # groups 0 and 3 tie at 0.6 behind group 2 and group 1: the lower stays
+    kept = moe.group_limited(choice.at[0, :4].set(0.3), 4, 3)
+    assert np.isfinite(np.asarray(kept))[0].tolist() == [True] * 12 + [False] * 4
+    with pytest.raises(ValueError, match="equal groups"):
+        moe.group_limited(choice, 3, 1)
+
+
+def test_the_choice_takes_no_gradient_and_the_weights_do():
+    scores, bias = _grouped_scores()
+
+    def total(scores, bias):
+        return moe.sigmoid_topk_route(scores, 4, bias, n_group=4,
+                                      topk_group=2).weights[:, 0].sum()
+
+    d_scores, d_bias = jax.grad(total, argnums=(0, 1))(scores, bias)
+    assert np.any(d_scores) and not np.any(d_bias)
+
+
+def test_a_share_of_a_grouped_router_sorts_its_held_rows_first():
+    scores, bias = _grouped_scores()
+    whole = moe.sigmoid_topk_route(scores, 4, bias, n_group=4, topk_group=2)
+    share = moe.sigmoid_topk_route(scores, 4, bias, n_group=4, topk_group=2,
+                                   first_expert=8, n_held=8)
+    np.testing.assert_array_equal(share.indices, whole.indices)
+    np.testing.assert_array_equal(share.group_sizes, whole.group_sizes[8:16])
+    held = int(share.group_sizes.sum())
+    flat = np.asarray(whole.indices).reshape(-1)
+    assert sorted(np.asarray(share.perm[:held]).tolist()) == \
+        np.nonzero((flat >= 8) & (flat < 16))[0].tolist()

@@ -22,6 +22,8 @@ shifted copies of a rotary turn written out). Seven seconds a layer here.
 attention: 2,024 MB on its parent, 361 since, of which 201 are two
 ``dynamic-update-slice`` fusions counted at their array's size that write 34
 in place (``--xla_dump_to`` names the buffers: one offset for all three).
+``ling-kda`` / ``ling-mla`` (PR 52) are ``ling-pretrain-8k``'s two mixers at 16
+of 32 heads held.
 """
 
 import argparse
@@ -31,7 +33,7 @@ import re
 import sys
 
 CELLS = ("kanana", "nemotron", "nemotron-mamba", "olmoe", "trinity-sliding",
-         "trinity-full")
+         "trinity-full", "ling-kda", "ling-mla")
 _BYTES = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1, "s8": 1, "u8": 1}
 _QUIET = ("parameter", "constant", "get-tuple-element", "tuple", "bitcast",
           "copy-start", "copy-done", "slice-start", "slice-done")
@@ -51,6 +53,16 @@ def layer(cell: str):
             rope_theta=1e6)
         return (nn.remat(m.LatentAttention, policy=keeping(m.KEPT))(cfg),
                 m.LatentAttention(cfg), (1, 16384, 2048))
+    if cell.startswith("ling"):
+        m = importlib.import_module("autodist_tpu.models.bailing_hybrid")
+        cfg = m.BailingHybridConfig(n_layers=6, heads_held=16, remat=True,
+                                    attention_impl="flash", kda_impl="pallas")
+        if cell == "ling-kda":
+            return (nn.remat(m.KimiDeltaAttention, policy=keeping(m.KEPT))(cfg),
+                    m.KimiDeltaAttention(cfg), (1, 8192, 2560))
+        kind = dict(heads_held=16, head_gate=True)
+        return (nn.remat(m.LatentAttention, policy=keeping(m.KEPT))(cfg, **kind),
+                m.LatentAttention(cfg, **kind), (1, 8192, 2560))
     if cell.startswith("nemotron"):
         m = importlib.import_module("autodist_tpu.models.nemotron_h")
         cfg = m.NemotronHConfig(attention_impl="flash", remat=True,
